@@ -4,7 +4,7 @@
 IMG_OPERATOR ?= datatunerx-tpu/operator:latest
 IMG_TRAINER  ?= datatunerx-tpu/trainer:latest
 
-.PHONY: test test-fast native bench graft-check aot-certify docker-build deploy undeploy fmt lint lint-fix
+.PHONY: test test-fast native bench chip-smoke graft-check aot-certify docker-build deploy undeploy fmt lint lint-fix
 
 test:            ## full test suite (8-device virtual CPU mesh)
 	python -m pytest tests/ -q
@@ -21,8 +21,11 @@ test-fast:       ## skip the slow live-pipeline e2e
 native:          ## build the C++ data-path extension
 	python -c "from datatunerx_tpu import native; assert native.available(); print('native OK')"
 
-bench:           ## headline benchmark (one JSON line)
+bench:           ## headline benchmark (one JSON line; fails without a TPU)
 	python bench.py
+
+chip-smoke:      ## trainer + server + every Pallas kernel, on the chip (fails without one)
+	python chip_smoke.py
 
 graft-check:     ## driver contract: entry() + dryrun_multichip(8)
 	python scripts/graft_check.py

@@ -1,0 +1,936 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main path once, through the entry points a user calls, at the full
+width AND depth of ``preset:tinyllama-1.1b`` with seeded random weights:
+
+  trainer  ``python -m datatunerx_tpu.tuning.train`` — LoRA, flash attention,
+           remat=dots, B8 x T1024, the prefetch pipeline on, a final
+           checkpoint + manifest. Passes when it exits 0, every logged loss is
+           finite, the last loss is below the first, and the manifest exists.
+  server   ``python -m datatunerx_tpu.serving.server`` — 4 slots, paged KV
+           (block 16), prefill budget 256, ``--paged_kernel`` and
+           ``--sampling_epilogue`` left at ``auto``. ``/healthz`` must reach
+           200; then five ``/chat/completions`` requests (short greedy; a
+           prompt longer than two prefill chunks concurrent with a
+           temperature-only one; temperature + top_p concurrent with a
+           greedy one), each answering 200 with exactly the tokens asked
+           for; ``/metrics`` must show the kernel decode path and the fused
+           sampler at work.
+  kernels  every Pallas kernel compiled by Mosaic (no interpret mode) and
+           compared with its in-repo oracle at tinyllama and llama2-7b
+           geometry, one PASS line per kernel and geometry.
+
+One process holds the chip at a time: this parent never imports JAX; the
+trainer, the server and the kernel check are children run one after another.
+Each child prints what it runs on (jax, libtpu, backend, device kind and
+count, compile-cache directory) and the smoke fails unless that is a TPU.
+Nothing here is a measurement: no rate, no utilization, no peak is printed.
+
+    python chip_smoke.py                       # everything, on the chip
+    python chip_smoke.py --mesh dp=1,fsdp=4,tp=1 --phases trainer
+    python chip_smoke.py --cpu-rehearsal       # debug size, on the CPU
+
+``--cpu-rehearsal`` exists to debug this script's control flow in a sandbox
+without a chip. It proves nothing about the chip and says so in its output;
+it is never what the bare command does.
+
+The last line of standard output is ``{"ok": true, "device": {...}}`` with
+the device as JAX reported it. On any failure the exit code is non-zero and
+no such line is printed. Every phase runs even after one has failed, so one
+call reports everything that is wrong. Logs land in ``chiprun_out/chip_smoke/``;
+the data and the checkpoints (gigabytes) live in ``.chip_smoke_work/`` and are
+removed at the end.
+"""
+
+from __future__ import annotations
+
+import argparse
+import csv
+import json
+import math
+import os
+import random
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import threading
+import time
+import urllib.error
+import urllib.request
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+OUT = os.path.join(REPO, "chiprun_out", "chip_smoke")   # logs: brought back
+WORK = os.path.join(REPO, ".chip_smoke_work")  # data + checkpoints: removed
+DEADLINE_S = 1150.0  # the contract allows 1200 s, compilation included
+SEED = 20260926
+
+MODEL = "preset:tinyllama-1.1b"
+TRAIN_STEPS = 10
+TRAIN_BATCH = 8       # per device
+TRAIN_BLOCK = 1024
+SERVE_SLOTS = 4       # --slots default; also the sampler check's S
+SERVE_BLOCK = 16      # --kv_block_size in the README, CI and bench.py
+SERVE_BUDGET = 256    # = default --prefill_chunk: one chunk per tick
+SERVE_SEQ = 1024
+
+# Shapes the kernel phase checks (and scripts/aot_certify.py lowers
+# devicelessly): name -> (heads, kv_heads, head_dim, hidden, intermediate)
+GEOMETRIES = {
+    "tinyllama-1.1b": (32, 4, 64, 2048, 5632),
+    "llama2-7b": (32, 32, 128, 4096, 11008),
+}
+# q_len values the engine really traces the multi-token kernel at: prefill
+# chunks are multiples of DECODE_BUCKET=64 up to --prefill_chunk 256; chain
+# verify is k+1 for k <= --spec_k 4; a 4x3 tree step is 1 + 12 columns
+CHUNK_QLENS = (64, 128, 192, 256)
+VERIFY_QLENS = (2, 3, 4, 5, 13)
+
+
+class SmokeFailure(Exception):
+    """A phase failed; the phases after it still run."""
+
+
+class NoChip(SmokeFailure):
+    """A child did not get a TPU: nothing after it can pass either."""
+
+
+def _say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+# ------------------------------------------------------------------ children
+
+class Child:
+    """One chip-holding subprocess: output to a log file, its own process
+    group so a timeout or a failure elsewhere takes everything it started."""
+
+    def __init__(self, name: str, argv: list, env: dict):
+        self.name = name
+        self.log_path = os.path.join(OUT, f"{name}.log")
+        self._log = open(self.log_path, "w")
+        self.t0 = time.monotonic()
+        self.proc = subprocess.Popen(
+            argv, cwd=REPO, env=env, stdout=self._log,
+            stderr=subprocess.STDOUT, start_new_session=True)
+
+    def wait(self, timeout_s: float) -> int:
+        try:
+            return self.proc.wait(timeout=max(timeout_s, 1.0))
+        except subprocess.TimeoutExpired:
+            self.stop()
+            raise SmokeFailure(
+                f"{self.name}: still running after {timeout_s:.0f}s — killed"
+                f"\n{self.tail()}")
+
+    def stop(self) -> None:
+        if self.proc.poll() is None:
+            for sig, grace in ((signal.SIGTERM, 15), (signal.SIGKILL, 5)):
+                try:
+                    os.killpg(self.proc.pid, sig)
+                except ProcessLookupError:
+                    break
+                try:
+                    self.proc.wait(timeout=grace)
+                    break
+                except subprocess.TimeoutExpired:
+                    continue
+        self._log.close()
+
+    def lines(self) -> list:
+        if not self._log.closed:
+            self._log.flush()
+        with open(self.log_path, errors="replace") as f:
+            return f.read().splitlines()
+
+    def tail(self, n: int = 40) -> str:
+        return "\n".join(f"    | {ln}" for ln in self.lines()[-n:])
+
+    def tagged(self, tag: str) -> list:
+        """JSON payloads of this child's ``<tag> {...}`` log lines."""
+        out = []
+        for ln in self.lines():
+            if ln.startswith(tag + " "):
+                brace = ln.find("{")
+                if brace >= 0:
+                    try:
+                        out.append(json.loads(ln[brace:]))
+                    except ValueError:
+                        pass
+        return out
+
+
+def _child_env(rehearsal: bool) -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONUNBUFFERED"] = "1"
+    if rehearsal:
+        env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
+def _check_runtime(child: Child, tag: str, rehearsal: bool) -> dict:
+    """The child's own ``[runtime] <tag> {...}`` line, read as soon as it is
+    written (it is each entry point's first act): the child must run on a
+    TPU, with real Mosaic kernels, and say where its compile cache lives."""
+    deadline = time.monotonic() + 180
+    while not (docs := child.tagged(f"[runtime] {tag}")):
+        if child.proc.poll() is not None or time.monotonic() > deadline:
+            child.stop()
+            raise NoChip(f"{child.name}: no '[runtime] {tag}' line (exit "
+                         f"code {child.proc.returncode})\n{child.tail()}")
+        time.sleep(0.5)
+    info = docs[0]
+    _say(f"  {child.name}: jax={info['jax']} libtpu={info['libtpu']} "
+         f"backend={info['backend']} platform={info['platform']} "
+         f"device_kind={info['device_kind']!r} devices={info['count']} "
+         f"pallas_interpret={info['pallas_interpret']} "
+         f"compile_cache={info['compile_cache']}"
+         + (f" native_packer={info['native_packer']}"
+            if "native_packer" in info else ""))
+    if not rehearsal:
+        if info["backend"] != "tpu" or info["platform"] != "tpu":
+            child.stop()
+            raise NoChip(f"{child.name}: backend is {info['backend']!r}, "
+                         "not tpu")
+        if info["pallas_interpret"]:
+            child.stop()
+            raise SmokeFailure(f"{child.name}: Pallas resolved to interpret "
+                               "mode on a TPU backend")
+    if not info["compile_cache"]:
+        child.stop()
+        raise SmokeFailure(f"{child.name}: no compile cache directory")
+    return info
+
+
+def _report_cache(child: Child, stats: dict) -> None:
+    _say(f"  {child.name}: compile cache dir={stats.get('dir')} "
+         f"requests={stats.get('requests')} hits={stats.get('hits')}")
+
+
+# ------------------------------------------------------------------- trainer
+
+def _write_train_csv(path: str, rows: int) -> None:
+    """Seeded instruction/response pairs, each filling most of a 1024-token
+    block under the byte-level tokenizer. The text is a few words repeated,
+    so even a frozen random base with a rank-8 adapter has something to
+    learn inside ten steps."""
+    rng = random.Random(SEED)
+    words = ["tensor", "mesh", "shard", "adapter", "block", "token", "cache",
+             "slot", "kernel", "batch", "prompt", "decode"]
+    with open(path, "w", newline="") as f:
+        w = csv.writer(f)
+        w.writerow(["instruction", "response"])
+        for _ in range(rows):
+            key = rng.sample(words, 3)
+            w.writerow([" ".join(key * 8), " ".join(key * 36)])
+
+
+def phase_trainer(rehearsal: bool, mesh: str, budget_s: float) -> dict:
+    tag = "trainer" + (f"[{mesh}]" if mesh else "")
+    _say(f"== {tag}")
+    model, block, steps = ((MODEL, TRAIN_BLOCK, TRAIN_STEPS) if not rehearsal
+                           else ("preset:debug", 128, 6))
+    slug = mesh.replace("=", "").replace(",", "_") if mesh else "default"
+    run = f"run_{slug}"
+    data = os.path.join(WORK, "train.csv")
+    storage = os.path.join(WORK, "storage")
+    # enough rows for ten steps at B8 on every chip of a four-chip host
+    _write_train_csv(data, rows=TRAIN_BATCH * 4 * (steps + 2))
+    argv = [sys.executable, "-m", "datatunerx_tpu.tuning.train",
+            "--model_name_or_path", model, "--train_path", data,
+            "--template", "vanilla", "--finetuning_type", "lora",
+            "--attention", "flash", "--remat", "dots",
+            "--block_size", str(block),
+            "--per_device_train_batch_size", str(TRAIN_BATCH),
+            "--max_steps", str(steps), "--logging_steps", "1",
+            "--learning_rate", "1e-3", "--lr_scheduler_type", "constant",
+            "--lora_dropout", "0", "--seed", str(SEED),
+            "--output_dir", os.path.join(WORK, run),
+            "--storage_path", storage, "--uid", run]
+    if mesh:
+        argv += ["--mesh", mesh]
+    child = Child(f"trainer_{slug}" if mesh else "trainer", argv,
+                  _child_env(rehearsal))
+    try:
+        info = _check_runtime(child, "trainer", rehearsal)
+        rc = child.wait(budget_s - (time.monotonic() - child.t0))
+    finally:
+        child.stop()
+    wall = time.monotonic() - child.t0
+    if rc != 0:
+        raise SmokeFailure(f"{tag}: exited {rc}\n{child.tail()}")
+
+    att = [ln for ln in child.lines() if ln.startswith("[attention] ")]
+    for ln in att:
+        _say(f"  {child.name}: {ln}")
+    if not any(f"traced=flash T={block} " in ln for ln in att):
+        raise SmokeFailure(f"{tag}: the train step did not trace flash "
+                           f"attention at T={block}\n{child.tail()}")
+    for doc in child.tagged("[mesh]"):
+        _say(f"  {child.name}: mesh={doc['shape']} devices={doc['devices']}")
+        for kind, per_dev in sorted(doc["per_device_bytes"].items()):
+            _say(f"  {child.name}:   {kind} bytes per device: "
+                 + " ".join(f"{d}:{n}" for d, n in sorted(per_dev.items())))
+
+    recs = child.tagged("[train]")
+    losses = [r.get("loss") for r in recs]
+    _say(f"  {child.name}: {len(recs)} logged steps, loss "
+         + " ".join(f"{x:.4f}" for x in losses if isinstance(x, float)))
+    if len(recs) < steps:
+        raise SmokeFailure(f"{tag}: {len(recs)} logged steps, wanted {steps}")
+    if not all(isinstance(x, float) and math.isfinite(x) for x in losses):
+        raise SmokeFailure(f"{tag}: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise SmokeFailure(f"{tag}: loss did not fall: first {losses[0]} "
+                           f"last {losses[-1]}")
+    manifest = os.path.join(storage, run, "manifest.json")
+    done = [ln for ln in child.lines() if ln.startswith("[done] ")]
+    if done:
+        manifest = done[-1].rsplit("manifest: ", 1)[-1].strip() or manifest
+    if not os.path.isfile(manifest):
+        raise SmokeFailure(f"{tag}: no completion manifest at {manifest}")
+    _say(f"  {child.name}: manifest {os.path.relpath(manifest, REPO)}")
+    for stats in child.tagged("[runtime] compile_cache"):
+        _report_cache(child, stats)
+    _say(f"  {child.name}: PASS in {wall:.0f}s wall")
+    return info
+
+
+# -------------------------------------------------------------------- server
+
+def _http(method: str, url: str, body=None, headers=None,
+          timeout: float = 30.0):
+    data = json.dumps(body).encode() if body is not None else None
+    hdrs = {"Content-Type": "application/json", **(headers or {})}
+    req = urllib.request.Request(url, data=data, method=method, headers=hdrs)
+    try:
+        with urllib.request.urlopen(req, timeout=timeout) as r:
+            return r.status, r.read().decode("utf-8", "replace")
+    except urllib.error.HTTPError as e:
+        return e.code, e.read().decode("utf-8", "replace")
+
+
+def _metric(text: str, name: str, labels: str = "") -> float:
+    head = name + (("{" + labels + "}") if labels else "")
+    for ln in text.splitlines():
+        if ln.startswith(head + " "):
+            return float(ln.rsplit(" ", 1)[1])
+    return float("nan")
+
+
+def phase_server(rehearsal: bool, budget_s: float) -> dict:
+    _say("== server")
+    deadline = time.monotonic() + budget_s
+    model, seq = (MODEL, SERVE_SEQ) if not rehearsal else ("preset:debug", 256)
+    budget = SERVE_BUDGET if not rehearsal else 64
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    argv = [sys.executable, "-m", "datatunerx_tpu.serving.server",
+            "--model_path", model, "--template", "vanilla",
+            "--max_seq_len", str(seq), "--slots", str(SERVE_SLOTS),
+            "--kv_block_size", str(SERVE_BLOCK),
+            "--prefill_token_budget", str(budget), "--port", str(port)]
+    if rehearsal:
+        argv += ["--prefill_chunk", str(budget)]
+    base = f"http://127.0.0.1:{port}"
+    child = Child("server", argv, _child_env(rehearsal))
+    try:
+        info = _check_runtime(child, "server", rehearsal)
+        # ---- /healthz must reach 200; 500 FAILED or an exit is a failure
+        while True:
+            if child.proc.poll() is not None:
+                raise SmokeFailure(f"server: exited {child.proc.returncode} "
+                                   f"before it was healthy\n{child.tail()}")
+            if time.monotonic() > deadline:
+                raise SmokeFailure("server: not healthy in time"
+                                   f"\n{child.tail()}")
+            try:
+                code, body = _http("GET", base + "/healthz", timeout=5)
+            except OSError:
+                code, body = 0, ""
+            if code == 200:
+                health = json.loads(body)
+                break
+            if code == 500:
+                raise SmokeFailure(f"server: /healthz 500 {body}"
+                                   f"\n{child.tail()}")
+            time.sleep(1.0)
+        t_healthy = time.monotonic() - child.t0
+        _say(f"  server: /healthz 200 after {t_healthy:.0f}s: {health}")
+        for key in ("platform", "device_kind", "count"):
+            if health.get(key) != info[key]:
+                raise SmokeFailure(f"server: /healthz {key}="
+                                   f"{health.get(key)!r} != {info[key]!r}")
+
+        eng = child.tagged("[engine]")
+        if not eng:
+            raise SmokeFailure(f"server: no [engine] line\n{child.tail()}")
+        _say(f"  server: engine resolved {eng[0]}")
+        if not rehearsal:
+            want = {"decode_path": "pallas", "sampling_epilogue": "on",
+                    "epilogue_impl": "kernel", "pallas_interpret": False}
+            bad = {k: eng[0].get(k) for k, v in want.items()
+                   if eng[0].get(k) != v}
+            if bad:
+                raise SmokeFailure(f"server: auto resolved to {bad}, "
+                                   f"wanted {want}")
+
+        # ---- the requests. Byte-level tokenizer: one character, one token.
+        long_chars = 2 * budget + budget // 2  # longer than two chunks
+        reqs = {
+            "short-greedy": dict(prompt="hello there", max_tokens=16),
+            "long-greedy": dict(prompt="a long prompt. " * (long_chars // 15),
+                                max_tokens=12),
+            "temperature": dict(prompt="sample something", max_tokens=24,
+                                temperature=0.8),
+            "temperature-top_p": dict(prompt="sample a nucleus",
+                                      max_tokens=24, temperature=0.8,
+                                      top_p=0.9),
+            "greedy-beside-top_p": dict(prompt="second greedy",
+                                        max_tokens=16),
+        }
+        results: dict = {}
+
+        def ask(name: str) -> None:
+            spec = dict(reqs[name])
+            body = {"messages": [{"role": "user",
+                                  "content": spec.pop("prompt")}], **spec}
+            left = max(deadline - time.monotonic(), 5.0)
+            try:
+                results[name] = _http(
+                    "POST", base + "/chat/completions", body,
+                    headers={"X-DTX-Trace-Id": f"smoke-{name}"},
+                    timeout=left)
+            except OSError as e:
+                results[name] = (0, repr(e))
+
+        for group in (["short-greedy"], ["long-greedy", "temperature"],
+                      ["temperature-top_p", "greedy-beside-top_p"]):
+            threads = [threading.Thread(target=ask, args=(n,)) for n in group]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=max(deadline - time.monotonic(), 5.0) + 10)
+            if any(t.is_alive() for t in threads):
+                raise SmokeFailure(f"server: {group} did not answer in time"
+                                   f"\n{child.tail()}")
+
+        total = 0
+        for name, spec in reqs.items():
+            code, body = results.get(name, (0, "no result"))
+            if code != 200:
+                raise SmokeFailure(f"server: {name} answered {code}: "
+                                   f"{body[:300]}\n{child.tail()}")
+            tcode, tbody = _http("GET", f"{base}/debug/trace/smoke-{name}")
+            if tcode != 200:
+                raise SmokeFailure(f"server: no trace for {name}: {tbody}")
+            span = json.loads(tbody)
+            span = span["spans"][0] if "spans" in span else span
+            n_tok = span["attrs"]["n_tokens"]
+            chunks = [e["tokens"] for e in span["events"]
+                      if e["name"] == "prefill"]
+            _say(f"  server: {name}: 200, {n_tok} tokens "
+                 f"(asked {spec['max_tokens']}), prefill chunks {chunks}")
+            if n_tok != spec["max_tokens"]:
+                raise SmokeFailure(f"server: {name} produced {n_tok} tokens, "
+                                   f"asked {spec['max_tokens']}")
+            if name == "long-greedy" and len(chunks) < 3:
+                raise SmokeFailure(f"server: long prompt took {chunks} "
+                                   "prefill chunks, wanted more than two")
+            total += n_tok
+
+        code, metrics = _http("GET", base + "/metrics")
+        if code != 200:
+            raise SmokeFailure(f"server: /metrics answered {code}")
+        gen = _metric(metrics, "dtx_serving_generated_tokens_total")
+        fused = _metric(metrics, "dtx_serving_sampling_fused_steps_total",
+                        'path="fused"')
+        legacy = _metric(metrics, "dtx_serving_sampling_fused_steps_total",
+                         'path="legacy"')
+        path = [ln for ln in metrics.splitlines()
+                if ln.startswith("dtx_serving_decode_path{")]
+        epilogue = _metric(metrics, "dtx_serving_sampling_epilogue")
+        _say(f"  server: /metrics generated_tokens={gen:.0f} "
+             f"fused_steps={fused:.0f} legacy_steps={legacy:.0f} "
+             f"sampling_epilogue={epilogue:.0f} {' '.join(path)}")
+        if gen != total:
+            raise SmokeFailure(f"server: generated-token counter {gen} != "
+                               f"{total} tokens returned")
+        if not rehearsal:
+            if not fused > 0:
+                raise SmokeFailure("server: no decode tick took the fused "
+                                   "sampler")
+            if path != ['dtx_serving_decode_path{path="pallas"} 1']:
+                raise SmokeFailure(f"server: decode path is {path}")
+            if epilogue != 2:
+                raise SmokeFailure("server: sampling epilogue is not the "
+                                   f"Pallas kernel ({epilogue})")
+        _report_cache(child, {
+            "dir": info["compile_cache"],
+            "requests": int(_metric(
+                metrics, "dtx_serving_compile_cache_requests_total")),
+            "hits": int(_metric(
+                metrics, "dtx_serving_compile_cache_hits_total"))})
+        if child.proc.poll() is not None:
+            raise SmokeFailure(f"server: died ({child.proc.returncode})"
+                               f"\n{child.tail()}")
+    finally:
+        child.stop()
+    _say(f"  server: PASS in {time.monotonic() - child.t0:.0f}s wall "
+         f"(healthy after {t_healthy:.0f}s)")
+    return info
+
+
+# ------------------------------------------------------------------- kernels
+
+def phase_kernels(rehearsal: bool, budget_s: float) -> dict:
+    _say("== kernels")
+    argv = [sys.executable, os.path.abspath(__file__), "--child-kernels"]
+    if rehearsal:
+        argv.append("--cpu-rehearsal")
+    child = Child("kernels", argv, _child_env(rehearsal))
+    try:
+        info = _check_runtime(child, "kernels", rehearsal)
+        rc = child.wait(budget_s - (time.monotonic() - child.t0))
+    finally:
+        child.stop()
+    verdicts = [ln for ln in child.lines()
+                if ln.startswith(("PASS ", "FAIL "))]
+    for ln in verdicts:
+        _say(f"  {ln}")
+    for stats in child.tagged("[runtime] compile_cache"):
+        _report_cache(child, stats)
+    failed = [ln for ln in verdicts if ln.startswith("FAIL ")]
+    if rc != 0 or failed or not verdicts:
+        raise SmokeFailure(f"kernels: exited {rc}, {len(failed)} FAIL of "
+                           f"{len(verdicts)}\n{child.tail()}")
+    _say(f"  kernels: PASS {len(verdicts)}/{len(verdicts)} in "
+         f"{time.monotonic() - child.t0:.0f}s wall")
+    return info
+
+
+def child_kernels(rehearsal: bool) -> int:
+    """Runs IN the chip-holding child: compile every Pallas kernel with
+    Mosaic and compare it with its oracle. On the CPU rehearsal the same
+    checks run in interpret mode at toy sizes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from datatunerx_tpu.ops.attention import (
+        attention_allow,
+        kv_dequantize,
+        kv_quantize,
+        make_causal_bias,
+        xla_attention,
+    )
+    from datatunerx_tpu.ops.flash_attention import flash_attention
+    from datatunerx_tpu.ops.paged_attention import POS_SENTINEL
+    from datatunerx_tpu.ops.pallas_lora import pallas_lora_matmul
+    from datatunerx_tpu.ops.pallas_paged_attention import (
+        paged_decode_attention,
+        paged_multitoken_attention,
+    )
+    from datatunerx_tpu.ops.pallas_quant import (
+        pallas_matmul_int8,
+        pallas_matmul_nf4,
+    )
+    from datatunerx_tpu.ops.pallas_sampling import fused_sample
+    from datatunerx_tpu.ops.quant import (
+        matmul_int8,
+        matmul_nf4,
+        quantize_int8,
+        quantize_nf4,
+    )
+    from datatunerx_tpu.utils import runtime
+
+    # no kernel below is told how to lower: each takes the backend default
+    # (ops/_pallas.py), which the parent asserts is Mosaic on the chip
+    runtime.startup("kernels")
+    results = []
+    clock = [time.monotonic()]
+
+    def check(name, got, want, atol, rtol=0.0, exact=False):
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        err = np.abs(got - want)
+        ok = bool(np.all(np.isfinite(got))) and (
+            bool(np.array_equal(got, want)) if exact
+            else bool(np.all(err <= atol + rtol * np.abs(want))))
+        results.append(ok)
+        now = time.monotonic()
+        print(f"{'PASS' if ok else 'FAIL'} kernel/{name}: "
+              f"max_abs_err={err.max():.3e}"
+              + (" (exact)" if exact else f" (atol={atol:g} rtol={rtol:g})")
+              + f" [{now - clock[0]:.1f}s]", flush=True)
+        clock[0] = now
+
+    def guarded(name, fn):
+        """A kernel Mosaic refuses is a FAIL line carrying the compiler's
+        own message — never a swapped-in oracle."""
+        try:
+            fn()
+        except Exception as e:  # noqa: BLE001 — reported as the verdict
+            results.append(False)
+            msg = " ".join(str(e).split())[:600]
+            print(f"FAIL kernel/{name}: {type(e).__name__}: {msg}",
+                  flush=True)
+
+    geoms = GEOMETRIES if not rehearsal else {"debug": (4, 2, 16, 64, 128)}
+    # inputs are drawn on the host: an eager jax.random call is one more
+    # program to compile, and this child compiles hundreds as it is
+    rng = np.random.default_rng(SEED)
+
+    def normal(shape, dtype=jnp.bfloat16, scale=1.0):
+        return jnp.asarray(
+            rng.standard_normal(shape, np.float32) * scale, dtype)
+
+    # ---- flash attention fwd/bwd: causal GQA, and packed segments
+    def flash(gname, H, KV, d):
+        B, T = (2, 1024) if not rehearsal else (2, 128)
+        q, k, v = (normal((B, T, n, d)) for n in (H, KV, KV))
+        pos = jnp.asarray(np.broadcast_to(np.arange(T, dtype=np.int32),
+                                          (B, T)))
+        seg = jnp.asarray(np.broadcast_to(
+            np.where(np.arange(T) < T // 2, 1, 2).astype(np.int32), (B, T)))
+        for label, s in (("causal_gqa", None), ("segmented", seg)):
+            def f_kernel(q, k, v, s=s):
+                return flash_attention(q, k, v, segment_ids=s)
+
+            def f_oracle(q, k, v, s=s):
+                return xla_attention(q, k, v, make_causal_bias(
+                    pos, pos, q_segment_ids=s, kv_segment_ids=s))
+
+            check(f"flash_fwd_{label} [{gname} B{B} T{T}]",
+                  jax.jit(f_kernel)(q, k, v), jax.jit(f_oracle)(q, k, v),
+                  atol=3e-2)
+
+            def loss(f):
+                return lambda q, k, v: (
+                    f(q, k, v).astype(jnp.float32) ** 2).sum()
+
+            gk = jax.jit(jax.grad(loss(f_kernel), argnums=(0, 1, 2)))(q, k, v)
+            go = jax.jit(jax.grad(loss(f_oracle), argnums=(0, 1, 2)))(q, k, v)
+            for nm, a, b in zip(("dq", "dk", "dv"), gk, go):
+                scale = float(np.abs(np.asarray(b, np.float32)).max())
+                check(f"flash_bwd_{label}_{nm} [{gname} B{B} T{T}]", a, b,
+                      atol=3e-2 * max(scale, 1.0))
+
+    # ---- quantized matmuls at the model's projection shapes
+    def quant(gname, D, F):
+        M = 512 if not rehearsal else 64
+        for K, N in ((D, D), (D, F), (F, D)):
+            w = normal((K, N), jnp.float32, 0.05)
+            x, g = normal((M, K)), normal((M, N))
+            q4 = jax.jit(quantize_nf4)(w)
+            q8 = jax.jit(quantize_int8)(w)
+            tag = f"[{gname} M{M} K{K} N{N}]"
+
+            # weights travel as ARGUMENTS: a closed-over array is baked into
+            # the program as a constant, and XLA then spends its compile
+            # time folding a 45 MB dequantisation
+            def nf4_k(x, q4, q8):
+                return pallas_matmul_nf4(x, q4, (K, N))
+
+            def nf4_o(x, q4, q8):
+                return matmul_nf4(x, q4, (K, N))
+
+            def int8_k(x, q4, q8):
+                return pallas_matmul_int8(x, q8["q"], q8["scale"])
+
+            def int8_o(x, q4, q8):
+                return matmul_int8(x, q8["q"], q8["scale"])
+
+            def fwd(f):
+                return jax.jit(f)(x, q4, q8)
+
+            def dx(f):  # the backward each custom VJP really runs
+                return jax.jit(lambda x, g, q4, q8: jax.vjp(
+                    lambda x: f(x, q4, q8), x)[1](g)[0])(x, g, q4, q8)
+
+            check(f"nf4_matmul_fwd {tag}", fwd(nf4_k), fwd(nf4_o),
+                  atol=2e-2, rtol=2e-2)
+            check(f"nf4_matmul_bwd_transposed {tag}", dx(nf4_k), dx(nf4_o),
+                  atol=5e-1, rtol=3e-2)
+            check(f"int8_matmul_fwd {tag}", fwd(int8_k), fwd(int8_o),
+                  atol=2e-2, rtol=2e-2)
+            check(f"int8_matmul_bwd {tag}", dx(int8_k), dx(int8_o),
+                  atol=5e-1, rtol=3e-2)
+
+    def lora(gname, D):
+        M, r, scale = (512 if not rehearsal else 64), 8, 4.0
+        w, a, b = (normal(sh, scale=0.05) for sh in ((D, D), (D, r), (r, D)))
+        x = normal((M, D))
+
+        def oracle(x, w, a, b):
+            xf = x.astype(jnp.float32)
+            return xf @ w.astype(jnp.float32) + (
+                xf @ a.astype(jnp.float32)) @ b.astype(jnp.float32) * scale
+
+        check(f"lora_fused_fwd [{gname} M{M} K{D} N{D} r{r}]",
+              jax.jit(lambda *t: pallas_lora_matmul(*t, scale=scale))(
+                  x, w, a, b),
+              jax.jit(oracle)(x, w, a, b), atol=5e-1, rtol=3e-2)
+
+    # ---- paged attention: a shuffled block pool at the engine's geometry
+    def paged_pool(B, KV, d, lens, quantized):
+        bs = SERVE_BLOCK
+        W = SERVE_SEQ if not rehearsal else 128
+        nbps = W // bs
+        NB = B * nbps
+        k_pool, v_pool = normal((NB, bs, KV, d)), normal((NB, bs, KV, d))
+        perm = rng.permutation(NB).reshape(B, nbps)
+        live = (np.arange(nbps)[None] * bs) < np.asarray(lens)[:, None]
+        lane = np.arange(W).reshape(nbps, bs)
+        pos = np.full((NB, bs), POS_SENTINEL, np.int32)
+        for b in range(B):
+            for j in range(nbps):
+                if live[b, j]:
+                    pos[perm[b, j]] = np.where(lane[j] < lens[b], lane[j],
+                                               POS_SENTINEL)
+        ks = vs = None
+        if quantized:
+            k_pool, ks = jax.jit(kv_quantize)(k_pool)
+            v_pool, vs = jax.jit(kv_quantize)(v_pool)
+        return (k_pool, v_pool, ks, vs,
+                jnp.asarray(np.where(live, perm, -1), jnp.int32),
+                jnp.asarray(pos))
+
+    def gather_attention(q, q_pos, k_pool, v_pool, ks, vs, tables, pos):
+        """The XLA gather path: each slot's linear view through its table,
+        causal bias from the gathered positions, ``xla_attention``."""
+        B = tables.shape[0]
+        tbl = jnp.where(tables >= 0, tables, 0)
+        k_all = k_pool[tbl].reshape(B, -1, *k_pool.shape[-2:])
+        v_all = v_pool[tbl].reshape(B, -1, *v_pool.shape[-2:])
+        if ks is not None:
+            k_all = kv_dequantize(
+                k_all, ks[tbl].reshape(B, -1, ks.shape[-1]), jnp.bfloat16)
+            v_all = kv_dequantize(
+                v_all, vs[tbl].reshape(B, -1, vs.shape[-1]), jnp.bfloat16)
+        kv_pos = jnp.where((tables >= 0)[:, :, None], pos[tbl],
+                           POS_SENTINEL).reshape(B, -1)
+        return xla_attention(q, k_all, v_all, make_causal_bias(q_pos, kv_pos))
+
+    def multitoken(q, q_pos, k_pool, v_pool, ks, vs, tables, pos):
+        tbl = jnp.where(tables >= 0, tables, 0)
+        kv_pos = jnp.where((tables >= 0)[:, :, None], pos[tbl],
+                           POS_SENTINEL).reshape(tables.shape[0], -1)
+        return paged_multitoken_attention(
+            q, k_pool, v_pool, ks, vs, tables, attention_allow(q_pos, kv_pos))
+
+    def paged(gname, H, KV, d):
+        W = SERVE_SEQ if not rehearsal else 128
+        for quantized in (False, True):
+            kvtag = "int8_kv" if quantized else "bf16"
+            B = SERVE_SLOTS
+            lens = [W, W // 2 + 3, 17, 1][:B]
+            pool = paged_pool(B, KV, d, lens, quantized)
+            q = normal((B, H, d))
+            qpos = jnp.asarray([n - 1 for n in lens], jnp.int32)
+            check(f"paged_decode_{kvtag} [{gname} B{B} bs{SERVE_BLOCK} "
+                  f"W{W}]",
+                  jax.jit(paged_decode_attention)(q, *pool, qpos),
+                  jax.jit(gather_attention)(q[:, None], qpos[:, None],
+                                            *pool)[:, 0],
+                  atol=2e-2, rtol=2e-2)
+
+            if rehearsal:
+                qlens = [(1, 64), (B, 5)]
+            elif gname == "tinyllama-1.1b" and not quantized:
+                qlens = ([(1, t) for t in CHUNK_QLENS]
+                         + [(B, t) for t in VERIFY_QLENS])
+            else:
+                qlens = [(1, CHUNK_QLENS[0]), (1, CHUNK_QLENS[-1]),
+                         (B, VERIFY_QLENS[3])]
+            for Bq, T in qlens:
+                # the last T written tokens of each slot are the queries
+                mlens = [max(n, T) for n in (lens[:Bq] if Bq > 1 else [W])]
+                mpool = paged_pool(Bq, KV, d, mlens, quantized)
+                qm = normal((Bq, T, H, d))
+                qp = jnp.asarray([[n - T + i for i in range(T)]
+                                  for n in mlens], jnp.int32)
+                check(f"paged_multitoken_{kvtag} [{gname} B{Bq} q_len{T} "
+                      f"bs{SERVE_BLOCK} W{W}]",
+                      jax.jit(multitoken)(qm, qp, *mpool),
+                      jax.jit(gather_attention)(qm, qp, *mpool),
+                      atol=2e-2, rtol=2e-2)
+
+    # ---- fused sampler at S = slots: greedy bitwise, simple exact by seed
+    def sampler(V):
+        S = SERVE_SLOTS
+        logits = normal((S, V), jnp.float32, 3.0)
+        temps = jnp.asarray([0.7, 1.0, 0.0, 1.3][:S], jnp.float32)
+        top_ps = jnp.ones((S,), jnp.float32)
+        keys = jnp.asarray(rng.integers(0, 2 ** 32, (S, 2), np.uint32))
+        for mode in ("greedy", "simple"):
+            run = lambda impl: jax.jit(  # noqa: E731
+                lambda lg, t, p, k: fused_sample(
+                    lg, t, p, k, mode=mode, impl=impl))(
+                        logits, temps, top_ps, keys)
+            check(f"fused_sample_{mode} [S{S} V{V}]", run("kernel"),
+                  run("xla"), atol=0, exact=True)
+
+    # ---- one QLoRA train step, --quant_impl pallas against xla: the fused
+    # kernels forward AND backward inside the real step program with remat
+    def qlora_step():
+        from datatunerx_tpu.models import get_config, init_params
+        from datatunerx_tpu.ops.quant import quantize_model_params
+        from datatunerx_tpu.training import TrainConfig, Trainer
+        from datatunerx_tpu.training.loss import IGNORE_INDEX
+
+        B, T = 4, 128
+        out = {}
+        for impl in ("pallas", "xla"):
+            cfg = get_config("debug", quantization="int4", quant_impl=impl,
+                             remat="full")
+            tr = Trainer(cfg, TrainConfig(
+                finetuning_type="lora", lora_rank=8, lora_alpha=32.0,
+                lora_dropout=0.0, lora_targets=("q_proj", "v_proj"),
+                learning_rate=2e-4, optimizer="adamw", total_steps=10,
+                compute_dtype=jnp.bfloat16))
+            params = quantize_model_params(
+                init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16),
+                "int4")
+            state = tr.init_state(params, jax.random.PRNGKey(1))
+            toks = jax.random.randint(jax.random.PRNGKey(2), (B, T), 0,
+                                      cfg.vocab_size, jnp.int32)
+            labels = jnp.where(jnp.arange(T)[None, :] < T // 4,
+                               IGNORE_INDEX, toks)
+            state, m = tr.train_step(state,
+                                     {"input_ids": toks, "labels": labels})
+            out[impl] = (np.asarray(m["loss"], np.float32).reshape(1),
+                         np.concatenate([
+                             np.asarray(x, np.float32).ravel()
+                             for x in jax.tree_util.tree_leaves(state.lora)]))
+        check("qlora_step_loss_pallas_vs_xla [debug B4 T128]",
+              out["pallas"][0], out["xla"][0], atol=5e-2, rtol=1e-2)
+        check("qlora_step_lora_update_pallas_vs_xla [debug B4 T128]",
+              out["pallas"][1], out["xla"][1], atol=5e-4, rtol=5e-2)
+
+    for gname, (H, KV, d, D, F) in geoms.items():
+        guarded(f"flash [{gname}]", lambda: flash(gname, H, KV, d))
+        guarded(f"quant [{gname}]", lambda: quant(gname, D, F))
+        guarded(f"lora [{gname}]", lambda: lora(gname, D))
+        guarded(f"paged [{gname}]", lambda: paged(gname, H, KV, d))
+    # both models share vocab 32000 (lowering at 151936 is tier-1's:
+    # tests/test_aot_certify.py; its XLA twin alone compiles for minutes)
+    for V in ((32000,) if not rehearsal else (512,)):
+        guarded(f"fused_sample [V{V}]", lambda: sampler(V))
+    if not rehearsal:  # interpret-mode QLoRA steps are slow and tier-1's job
+        guarded("qlora_step", qlora_step)
+
+    print("[runtime] compile_cache "
+          + json.dumps(runtime.compile_cache_stats(), sort_keys=True),
+          flush=True)
+    print(f"{sum(results)}/{len(results)} kernel checks passed", flush=True)
+    return 0 if results and all(results) else 1
+
+
+# ---------------------------------------------------------------------- main
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cpu-rehearsal", action="store_true",
+                    help="debug-size run on the CPU to debug THIS SCRIPT; "
+                         "proves nothing about the chip")
+    ap.add_argument("--phases", default="trainer,server,kernels",
+                    help="comma list out of trainer,server,kernels")
+    ap.add_argument("--mesh", action="append", default=None,
+                    help="trainer --mesh (e.g. dp=1,fsdp=4,tp=1); repeat to "
+                         "run the trainer once per mesh. 'auto' (the "
+                         "default) is the trainer's own choice: every local "
+                         "device on dp")
+    ap.add_argument("--child-kernels", action="store_true",
+                    help=argparse.SUPPRESS)
+    args = ap.parse_args(argv)
+
+    for var in ("DTX_PALLAS_INTERPRET", "DTX_SAMPLING_EPILOGUE_KERNEL"):
+        if os.environ.get(var, "").strip():
+            print(f"chip_smoke: refusing to run with {var} set — the smoke "
+                  "proves what the backend default resolves to",
+                  file=sys.stderr)
+            return 2
+    if args.child_kernels:
+        return child_kernels(args.cpu_rehearsal)
+    if not os.path.isdir(os.path.join(REPO, "datatunerx_tpu")):
+        print("chip_smoke: no datatunerx_tpu package beside this script",
+              file=sys.stderr)
+        return 2
+    phases = [p.strip() for p in args.phases.split(",") if p.strip()]
+    unknown = set(phases) - {"trainer", "server", "kernels"}
+    if unknown:
+        ap.error(f"unknown phases {sorted(unknown)}")
+
+    rehearsal = args.cpu_rehearsal
+    if not rehearsal and os.environ.get(
+            "JAX_PLATFORMS", "").strip().lower() == "cpu":
+        print("chip_smoke: JAX_PLATFORMS=cpu — there is no chip to prove "
+              "anything on (the CPU run is --cpu-rehearsal, by name)",
+              file=sys.stderr)
+        return 2
+    if rehearsal:
+        _say("CPU REHEARSAL at debug size: this run debugs chip_smoke.py's "
+             "own control flow and proves NOTHING about the chip.")
+    for path in (OUT, WORK):
+        shutil.rmtree(path, ignore_errors=True)
+        os.makedirs(path)
+    _say("chip_smoke: env " + json.dumps(
+        {k: os.environ.get(k) for k in
+         ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR", "TPU_VISIBLE_CHIPS",
+          "TPU_ACCELERATOR_TYPE", "XLA_FLAGS")}, sort_keys=True))
+    t0 = time.monotonic()
+    runs = []
+    if "trainer" in phases:
+        runs += [(f"trainer[{m}]" if m else "trainer",
+                  lambda left, m=m: phase_trainer(rehearsal, m, left))
+                 for m in ("" if m == "auto" else m
+                           for m in (args.mesh or ["auto"]))]
+    if "server" in phases:
+        runs.append(("server", lambda left: phase_server(rehearsal, left)))
+    if "kernels" in phases:
+        runs.append(("kernels", lambda left: phase_kernels(rehearsal, left)))
+
+    devices, failed = [], []
+    try:
+        for name, run in runs:
+            left = DEADLINE_S - (time.monotonic() - t0)
+            try:
+                if left < 30:
+                    raise SmokeFailure(f"{name}: no time left to start it")
+                devices.append(run(left))
+            except SmokeFailure as e:
+                failed.append(name)
+                print(f"chip_smoke: FAILED {e}", file=sys.stderr, flush=True)
+                if isinstance(e, NoChip):
+                    break
+    finally:
+        shutil.rmtree(WORK, ignore_errors=True)
+    wall = time.monotonic() - t0
+    if failed or not devices:
+        print(f"chip_smoke: FAILED phases {failed or 'none ran'} after "
+              f"{wall:.0f}s wall", file=sys.stderr, flush=True)
+        return 1
+    dev = {"platform": devices[0]["platform"],
+           "kind": devices[0]["device_kind"], "count": devices[0]["count"]}
+    if any((d["platform"], d["device_kind"], d["count"])
+           != (dev["platform"], dev["kind"], dev["count"]) for d in devices):
+        print(f"chip_smoke: children disagree on the device: {devices}",
+              file=sys.stderr)
+        return 1
+    _say(f"chip_smoke: phases {','.join(n for n, _ in runs)} passed in "
+         f"{wall:.0f}s wall")
+    if rehearsal:
+        _say(json.dumps({"rehearsal": "cpu — proves nothing about the chip",
+                         "device": dev}))
+        return 0
+    _say(json.dumps({"ok": True, "device": dev}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

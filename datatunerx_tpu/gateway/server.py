@@ -1558,11 +1558,18 @@ class ManagedReplicaSet:
 
     def _reconcile_locked(self):
         with self._lock:
-            dead = [name for name, proc in self._procs.items()
+            dead = [(name, proc.returncode)
+                    for name, proc in self._procs.items()
                     if proc.poll() is not None]
-            for name in dead:
+            for name, _ in dead:
                 self._procs.pop(name, None)
-        for name in dead:
+        for name, code in dead:
+            # a replica that could not load its engine (e.g. a second
+            # replica on a one-chip host: the chip belongs to the first)
+            # exits non-zero with the real error in its log — say so, or
+            # the respawn below turns it into a silent crash loop
+            print(f"[gateway] {name} exited with code {code}; its log: "
+                  f"{os.path.join(self.workdir, name + '.log')}", flush=True)
             self.pool.remove(name)
         with self._lock:
             managed = set(self._procs)

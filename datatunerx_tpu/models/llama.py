@@ -24,7 +24,9 @@ Param tree (HF-compatible leaf names so weight conversion is mechanical):
 
 from __future__ import annotations
 
+import functools
 import os
+import sys
 from typing import Any, Optional
 
 import jax
@@ -168,6 +170,23 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
         cache["k"] = jnp.zeros(shape, dtype)
         cache["v"] = jnp.zeros(shape, dtype)
     return cache
+
+
+@functools.lru_cache(maxsize=64)
+def _log_attention_once(requested: str, traced: str, seq_len: int,
+                        sliding_window, packed: bool) -> None:
+    """Say, once per distinct case, which attention implementation a
+    cache-less forward (training / eval) actually traced — a flash or ring
+    request that this shape or config cannot take becomes plain einsum
+    attention, and that must be visible in the run's log."""
+    note = ""
+    if traced != requested:
+        note = (" — DOWNGRADED: flash needs T % 128 == 0 or T < 128, ring "
+                "takes no packed segments, and neither takes a sliding "
+                "window")
+    print(f"[attention] requested={requested} traced={traced} T={seq_len} "
+          f"sliding_window={sliding_window} packed={packed}{note}",
+          file=sys.stderr, flush=True)
 
 
 def lm_logits(params: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
@@ -326,6 +345,9 @@ def forward(
     att_impl = cfg.attention_impl if _flash_ok else (
         "xla" if cfg.attention_impl in ("flash", "ring") else cfg.attention_impl
     )
+    if cache is None:
+        _log_attention_once(cfg.attention_impl, att_impl, T,
+                            cfg.sliding_window, segment_ids is not None)
 
     def block(x, scanned):
         lp, ll, ck, cv, cks, cvs, layer_idx = scanned

@@ -284,6 +284,8 @@ class LocalProcessBackend:
     def delete(self, name: str) -> None:
         with self._lock:
             procs = self._procs.pop(name, None)
+        if isinstance(procs, _PendingGroup):
+            return  # nothing spawned yet; the popped token voids its thread
         for proc in procs or []:
             if proc.poll() is None:
                 proc.terminate()
@@ -293,11 +295,19 @@ class LocalProcessBackend:
                     proc.kill()
 
     def has_active_jobs(self) -> bool:
-        """True while any trainer subprocess is live (the device health probe
-        must not contend with a running job for the single-client TPU)."""
+        """True while any trainer subprocess is live or about to be (the
+        device health probe must not contend with a job for the chip, which
+        belongs to one process at a time). A multi-host group still queued
+        behind the spawn gate counts: its processes start at any moment."""
         with self._lock:
-            return any(p.poll() is None
-                       for procs in self._procs.values() for p in procs)
+            groups = list(self._procs.values())
+        for procs in groups:
+            if isinstance(procs, _PendingGroup):
+                if not procs.failed:
+                    return True
+            elif any(p.poll() is None for p in procs):
+                return True
+        return False
 
     def metrics_series(self, name: str, max_points: int = 2000) -> dict:
         """Parsed trainer/eval jsonl curves for the UI (the data the reference

@@ -51,7 +51,7 @@ class FinetuneController:
         self.backend = backend
         self.storage_path = storage_path or config.get_storage_path()
         # optional DeviceHealthProbe (operator/health.py): while unhealthy,
-        # hold new submissions instead of queueing onto a wedged device
+        # hold new submissions instead of queueing onto an unhealthy device
         self.health_probe = health_probe
         # optional SlicePool (operator/placement.py): concurrent jobs onto
         # disjoint sub-slices; no pool = single-tenant, no gating

@@ -229,7 +229,7 @@ def main(argv=None):
                         "--backend local only — cluster backends rely on "
                         "kubelet/JobSet health); while unhealthy, new "
                         "Finetunes hold in Pending instead of submitting "
-                        "onto a wedged device")
+                        "onto an unhealthy device")
     args = p.parse_args(argv)
     if args.device_health_interval > 0 and args.backend != "local":
         print("[controller-manager] warning: --device-health-interval only "

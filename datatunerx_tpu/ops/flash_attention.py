@@ -427,20 +427,16 @@ def flash_attention(
                 return _flash_local(q, k, v, None, block_q, block_k,
                                     interpret)
 
-            from datatunerx_tpu.parallel.sharding import compat_shard_map
-
-            return compat_shard_map(local3, mesh=mesh, in_specs=(qkv_spec,) * 3,
-                                    out_specs=qkv_spec, check=False)(q, k, v)
+            return jax.shard_map(local3, mesh=mesh, in_specs=(qkv_spec,) * 3,
+                                 out_specs=qkv_spec, check_vma=False)(q, k, v)
 
         def local(q, k, v, seg):
             return _flash_local(q, k, v, seg, block_q, block_k, interpret)
 
-        from datatunerx_tpu.parallel.sharding import compat_shard_map
-
-        return compat_shard_map(local, mesh=mesh,
-                                in_specs=(qkv_spec, qkv_spec, qkv_spec,
-                                          seg_spec),
-                                out_specs=qkv_spec, check=False)(
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(qkv_spec, qkv_spec, qkv_spec,
+                                       seg_spec),
+                             out_specs=qkv_spec, check_vma=False)(
             q, k, v, segment_ids)
     return _flash_local(q, k, v, segment_ids, block_q, block_k, interpret)
 

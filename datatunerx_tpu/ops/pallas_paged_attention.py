@@ -279,25 +279,50 @@ def paged_attention_decode_step(q, ck, cv, cks, cvs, cache: dict,
 # 2·W·KV·d·itemsize — noise). Invalid table entries are still skipped
 # outright by ``pl.when``.
 #
-# q enters kv-major as ``[B, H·T, d]`` (row (kv·G + g)·T + t): per-(kv, g)
-# extraction stays a static sublane slice and each score tile is one
-# [T, d] × [d, bs] MXU pass, reusing the decode kernel's merged-trailing-dim
-# pool layout unchanged.
+# q enters kv-major and TILED over the query rows: ``T`` is padded to a
+# sublane multiple and cut into ``nT`` tiles of ``tq`` rows, laid out
+# ``[B, nT, H·tq, d]`` (row (kv·G + g)·tq + t), and the grid grows a tile
+# axis ``(B, nT, 2·nbps)``. Per-(kv, g) extraction stays a static sublane
+# slice and each score tile is one [tq, d] × [d, bs] MXU pass, reusing the
+# decode kernel's merged-trailing-dim pool layout unchanged. The tiling is
+# what bounds VMEM: the three f32 scratch buffers plus the double-buffered
+# q/out blocks cost ~2.5 KB per query-head row, so a whole
+# ``T = prefill_chunk`` (H·T = 8192 rows at tinyllama width) overflows the
+# 16 MB scoped limit Mosaic grants a kernel; ``_MT_ROW_CAP`` rows keeps a
+# grid step near 5 MB. The price is one K/V re-stream per tile.
+#
+# ``allow`` travels as ``[B, nT, nbps, tq, bs]`` so its block's trailing
+# dims EQUAL the array's — the only legal tiling for ``bs < 128`` (a
+# ``(1, T, bs)`` window of a ``[B, T, W]`` array is refused by Mosaic).
 #
 # Garbage contract: a fully-masked query row (inactive slot in a verify
-# batch) normalizes over NEG_INF scores — finite uniform-ish junk, like the
-# oracle's sentinel-masked softmax but not bit-equal to it. Such rows only
-# exist where the engine's emit mask discards them; parity is asserted on
-# rows with at least one attendable lane.
+# batch, or a pad row of the last tile) normalizes over NEG_INF scores —
+# finite uniform-ish junk, like the oracle's sentinel-masked softmax but not
+# bit-equal to it. Such rows only exist where the engine's emit mask
+# discards them (pad rows are sliced off here); parity is asserted on rows
+# with at least one attendable lane.
+
+_MT_ROW_CAP = 2048  # query-head rows (H·tq) resident per grid step
+
+
+def _mt_tiling(q_len: int, heads: int) -> tuple[int, int]:
+    """(padded q_len, rows per tile): q_len rounded up to the f32 sublane
+    count, and the largest sublane-multiple divisor of it that keeps
+    ``heads · tq`` within ``_MT_ROW_CAP``."""
+    tp = -(-q_len // 8) * 8
+    cap = max(8, _MT_ROW_CAP // heads // 8 * 8)
+    tq = max(t for t in range(8, min(cap, tp) + 1, 8) if tp % t == 0)
+    return tp, tq
 
 
 def _multitoken_kernel(tables_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                        allow_ref, o_ref, acc_ref, m_ref, l_ref,
                        *, nbps: int, kv_heads: int, group: int, q_len: int,
                        scale: float, quant: bool):
-    """One (slot, table-entry, phase) grid step for q_len query rows."""
+    """One (slot, query tile, table-entry × phase) grid step for ``q_len``
+    query rows per head."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    j = pl.program_id(2)
     jj = j - (j // nbps) * nbps
     stats_phase = j < nbps
     entry = tables_ref[b, jj]
@@ -324,13 +349,13 @@ def _multitoken_kernel(tables_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     def _masked_scores(k_heads):
         """Masked f32 score tiles: one ([q_len, bs], row slice) per head."""
-        mask = allow_ref[0] != 0  # [q_len, bs] — the oracle's bias == 0
+        mask = allow_ref[0, 0, 0] != 0  # [q_len, bs] — the oracle's bias == 0
         out = []
         for kv in range(kv_heads):
             for g in range(group):
                 rows = slice((kv * group + g) * q_len,
                              (kv * group + g + 1) * q_len)
-                qg = q_ref[0, rows, :].astype(jnp.float32)
+                qg = q_ref[0, 0, rows, :].astype(jnp.float32)
                 s = jax.lax.dot_general(
                     qg, k_heads[kv], (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
@@ -365,7 +390,7 @@ def _multitoken_kernel(tables_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     @pl.when(j == 2 * nbps - 1)
     def _finish():
-        o_ref[0] = acc_ref[:].astype(o_ref.dtype)
+        o_ref[0, 0] = acc_ref[:].astype(o_ref.dtype)
 
 
 def paged_multitoken_attention(
@@ -391,30 +416,39 @@ def paged_multitoken_attention(
     quant = k_scale is not None
     assert allow.shape == (B, T, nbps * bs), (
         f"allow {allow.shape} != {(B, T, nbps * bs)}")
+    Tp, tq = _mt_tiling(T, H)
+    nT = Tp // tq
 
     scale = float(np.float32(1.0) / np.sqrt(np.float32(d)))  # dtxlint: disable=DTX001 — host numpy scalar (d is a static shape), no device sync
     kernel = functools.partial(
-        _multitoken_kernel, nbps=nbps, kv_heads=KV, group=G, q_len=T,
+        _multitoken_kernel, nbps=nbps, kv_heads=KV, group=G, q_len=tq,
         scale=scale, quant=quant)
 
-    def kv_index(b, j, tables_ref):
+    def kv_index(b, i, j, tables_ref):
         return (jnp.maximum(tables_ref[b, j - (j // nbps) * nbps], 0), 0, 0)
 
     scale_index = kv_index
 
-    def v_index(b, j, tables_ref):
+    def v_index(b, i, j, tables_ref):
         jj = j - (j // nbps) * nbps
         return (jnp.maximum(tables_ref[b, jj], 0) * (j >= nbps), 0, 0)
 
-    def allow_index(b, j, tables_ref):
-        # the allow tensor is laid out linearly — column block jj of slot b
-        return (b, 0, j - (j // nbps) * nbps)
+    def allow_index(b, i, j, tables_ref):
+        return (b, i, j - (j // nbps) * nbps, 0, 0)
 
-    # q kv-major [B, H·T, d]: row (kv·G + g)·T + t, so per-(kv, g) rows are
-    # a static sublane slice; pools keep the merged (KV, d) trailing dims
-    q_km = q.transpose(0, 2, 1, 3).reshape(B, H * T, d)
+    def q_index(b, i, j, tables_ref):
+        return (b, i, 0, 0)
+
+    # pad rows are never attendable (allow 0) and are sliced off the output
+    allow = allow.astype(jnp.int32)
+    if Tp != T:
+        q = jnp.pad(q, ((0, 0), (0, Tp - T), (0, 0), (0, 0)))
+        allow = jnp.pad(allow, ((0, 0), (0, Tp - T), (0, 0)))
+    q_km = q.reshape(B, nT, tq, H, d).transpose(0, 1, 3, 2, 4).reshape(
+        B, nT, H * tq, d)
+    allow_t = allow.reshape(B, nT, tq, nbps, bs).transpose(0, 1, 3, 2, 4)
     in_specs = [
-        pl.BlockSpec((1, H * T, d), lambda b, j, t: (b, 0, 0)),
+        pl.BlockSpec((1, 1, H * tq, d), q_index),
         pl.BlockSpec((1, bs, KV * d), kv_index),
         pl.BlockSpec((1, bs, KV * d), v_index),
     ]
@@ -424,8 +458,8 @@ def paged_multitoken_attention(
         in_specs += [pl.BlockSpec((1, bs, KV), scale_index),
                      pl.BlockSpec((1, bs, KV), scale_index)]
         args += [k_scale, v_scale]
-    in_specs.append(pl.BlockSpec((1, T, bs), allow_index))
-    args.append(allow.astype(jnp.int32))
+    in_specs.append(pl.BlockSpec((1, 1, 1, tq, bs), allow_index))
+    args.append(allow_t)
 
     kernel_args = kernel if quant else functools.partial(
         _no_scale_mt_kernel, kernel)
@@ -433,19 +467,20 @@ def paged_multitoken_attention(
         kernel_args,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, 2 * nbps),
+            grid=(B, nT, 2 * nbps),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, H * T, d), lambda b, j, t: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, H * tq, d), q_index),
             scratch_shapes=[
-                pltpu.VMEM((H * T, d), jnp.float32),
-                pltpu.VMEM((H * T, _LANES), jnp.float32),
-                pltpu.VMEM((H * T, _LANES), jnp.float32),
+                pltpu.VMEM((H * tq, d), jnp.float32),
+                pltpu.VMEM((H * tq, _LANES), jnp.float32),
+                pltpu.VMEM((H * tq, _LANES), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H * T, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nT, H * tq, d), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
     )(tables.astype(jnp.int32), *args)
-    return out.reshape(B, H, T, d).transpose(0, 2, 1, 3)
+    out = out.reshape(B, nT, H, tq, d).transpose(0, 1, 3, 2, 4)
+    return out.reshape(B, Tp, H, d)[:, :T]
 
 
 def _no_scale_mt_kernel(kernel, tables_ref, q_ref, k_ref, v_ref, allow_ref,

@@ -37,12 +37,27 @@ def _pad_rows(x2d: jnp.ndarray, bm: int) -> Tuple[jnp.ndarray, int]:
 
 # ----------------------------------------------------------------- int8
 
-def _int8_kernel(x_ref, q_ref, s_ref, o_ref):
-    acc = jnp.dot(
+_INT8_BLOCK_K = 2048  # K tile: whole-K blocks overflow scoped VMEM at 7B
+
+
+def _int8_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, nk: int):
+    # One [bm, bk] x [bk, bn] MXU pass per grid step, accumulated in f32
+    # across the K grid dim; the per-channel scale applies once at the end.
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    acc_ref[:] += jnp.dot(
         x_ref[:], q_ref[:].astype(x_ref.dtype),
         preferred_element_type=jnp.float32,
     )
-    o_ref[:] = (acc * s_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
+
+    @pl.when(kk == nk - 1)
+    def _finish():
+        o_ref[:] = (acc_ref[:] * s_ref[:].astype(jnp.float32)).astype(
+            o_ref.dtype)
 
 
 def pallas_matmul_int8(x: jnp.ndarray, q: jnp.ndarray, scale: jnp.ndarray,
@@ -88,17 +103,25 @@ def _pallas_matmul_int8_impl(
     from datatunerx_tpu.ops._pallas import pick_block_n
 
     bn = pick_block_n(N, block_n)
+    # K is tiled once it no longer fits one block: an x[bm, K] + q[K, bn]
+    # pair (plus q's in-kernel bf16 copy) at K = 11008 (llama2-7b down_proj)
+    # needs 16.4 MB of the 16 MB of scoped VMEM Mosaic grants a kernel. A K
+    # within the tile stays one whole-K step, which is legal for ANY K
+    # (block dim == array dim); real models' larger K are 128-multiples.
+    bk = K if K <= _INT8_BLOCK_K else pick_block_n(K, _INT8_BLOCK_K)
+    nk = K // bk
 
     out = pl.pallas_call(
-        _int8_kernel,
-        grid=(M // block_m, N // bn),
+        functools.partial(_int8_kernel, nk=nk),
+        grid=(M // block_m, N // bn, nk),
         in_specs=[
-            pl.BlockSpec((block_m, K), lambda i, j: (i, 0)),
-            pl.BlockSpec((K, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((block_m, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_m, bn), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block_m, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        scratch_shapes=[pltpu.VMEM((block_m, bn), jnp.float32)],
         interpret=_interpret(),
     )(x2d, q, scale.reshape(1, N))
     return out[:m_real].reshape(*lead, N)
@@ -149,17 +172,24 @@ def _nf4_kernel(x_ref, packed_ref, scales_ref, o_ref, w_vmem, acc_ref,
         o_ref[:] = acc_ref[:].astype(o_ref.dtype)
 
 
-def _pick_chunk(nb_total: int, block_size: int, cap_nb: int = 16) -> int:
+def _pick_chunk(nb_total: int, block_size: int, cap_nb: int = 16,
+                lane_aligned: bool = False) -> int:
     """Largest divisor of nb_total ≤ cap_nb (chunk = that many nf4 blocks).
 
-    Any divisor is Mosaic-legal: the chunk axis is hoisted to a leading array
-    dim on the host, so every BlockSpec's last-two dims EQUAL their array
-    dims regardless of nb (no 8/128-multiple requirement to satisfy)."""
-    best = 1
+    For chunk-major OPERANDS any divisor is Mosaic-legal: the chunk axis is
+    hoisted to a leading array dim on the host, so every BlockSpec's
+    last-two dims EQUAL their array dims regardless of nb. A chunk that
+    tiles an array's LANE dim in place (the transposed kernel's [M, K]
+    output) must be a 128-multiple: ``lane_aligned`` prefers such divisors
+    (K = 5632 → 8 blocks = 512 lanes instead of 11 = 704, which Mosaic
+    refuses) and exists for every K that is itself a 128-multiple."""
+    best = aligned = 0
     for d in range(1, cap_nb + 1):
         if nb_total % d == 0:
             best = d
-    return best * block_size
+            if (d * block_size) % 128 == 0:
+                aligned = d
+    return ((aligned or best) if lane_aligned else best) * block_size
 
 
 def pallas_matmul_nf4(x: jnp.ndarray, qw: Dict[str, jnp.ndarray],
@@ -259,7 +289,7 @@ def _pallas_matmul_nf4_t_impl(
     *lead, N2 = g.shape
     assert N2 == N, (N2, N)
     nb_per_channel = K // block_size
-    ck = _pick_chunk(nb_per_channel, block_size)
+    ck = _pick_chunk(nb_per_channel, block_size, lane_aligned=True)
     nb_chunk = ck // block_size
     nk = K // ck
     half = block_size // 2

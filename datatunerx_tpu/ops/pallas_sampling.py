@@ -77,6 +77,21 @@ def default_impl() -> str:
     return "kernel" if jax.default_backend() == "tpu" else "xla"
 
 
+def _tile_cumsum(e, lane, roll):
+    """Inclusive prefix sum along the lanes of one ``[rows, bn]`` tile as a
+    log-step shift-and-add scan (any ``bn``). Mosaic has no ``cumsum``
+    lowering; it does have a lane rotate and a masked add. Kernel and XLA
+    twin call this with their own ``roll`` so both add the same operands in
+    the same order and the CDF they compare against ``u·Z`` is the same f32
+    value."""
+    bn = e.shape[-1]
+    shift = 1
+    while shift < bn:
+        e = e + jnp.where(lane >= shift, roll(e, shift), 0.0)
+        shift *= 2
+    return e
+
+
 def _prep(logits, temps, *, mode):
     """Shared pre-scale + lane-pad: both impls consume the SAME padded
     array, so scaling can never diverge between them. Padding is NEG_INF
@@ -105,7 +120,7 @@ def _sample_kernel(temps_ref, us_ref, x_ref, tok_ref, fbuf, ibuf, *,
     i = pl.program_id(0)
     p = pl.program_id(1)
     t = pl.program_id(2)
-    tile = x_ref[...]  # (1, bn) f32
+    tile = x_ref[0]  # (1, bn) f32 — row i's tile t of the [S, 1, vp] view
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
 
     @pl.when((p == 0) & (t == 0))
@@ -129,7 +144,7 @@ def _sample_kernel(temps_ref, us_ref, x_ref, tok_ref, fbuf, ibuf, *,
     if greedy:
         @pl.when((p == 0) & (t == nt - 1))
         def _emit_greedy():
-            tok_ref[0, 0] = ibuf[0]
+            tok_ref[i] = ibuf[0]
         return
 
     @pl.when((p == 1) & (t == 0))
@@ -149,7 +164,8 @@ def _sample_kernel(temps_ref, us_ref, x_ref, tok_ref, fbuf, ibuf, *,
     @pl.when(p == 2)
     def _phase_cdf():
         e = jnp.exp(tile - fbuf[0])
-        cum = fbuf[2] + jnp.cumsum(e, axis=1)
+        cum = fbuf[2] + _tile_cumsum(
+            e, lane, lambda a, k: pltpu.roll(a, k, 1))
         thresh = us_ref[i] * fbuf[1]
         hit = cum > thresh
         first = jnp.min(jnp.where(hit, lane, bn))
@@ -166,31 +182,35 @@ def _sample_kernel(temps_ref, us_ref, x_ref, tok_ref, fbuf, ibuf, *,
             # no crossing (u·Z at/after the float tail) falls back to the
             # argmax; rows with temp <= 0 are greedy regardless of draw
             sampled = jnp.where(ibuf[2] == 1, ibuf[1], ibuf[0])
-            tok_ref[0, 0] = jnp.where(temps_ref[i] <= 0.0, ibuf[0], sampled)
+            tok_ref[i] = jnp.where(temps_ref[i] <= 0.0, ibuf[0], sampled)
 
 
 def _kernel_sample(x, temps, us, *, bn, greedy, interpret):
     s, vp = x.shape
     nt = vp // bn
     phases = 1 if greedy else 3
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_sample_kernel, bn=bn, nt=nt, greedy=greedy),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s, phases, nt),
-            in_specs=[pl.BlockSpec((1, bn), lambda i, p, t, *_: (i, t))],
-            out_specs=pl.BlockSpec(
-                (1, 1), lambda i, p, t, *_: (i, 0),
-                memory_space=pltpu.SMEM),
+            # rows ride a unit MIDDLE dim so the block's trailing dims are
+            # (1 == array dim, lane-aligned bn): a (1, bn) window of an
+            # [S, vp] array is refused by Mosaic for every S > 1
+            in_specs=[pl.BlockSpec((1, 1, bn),
+                                   lambda i, p, t, *_: (i, 0, t))],
+            # the whole [S] token vector stays resident in SMEM across the
+            # grid (row i writes element i): a per-row (1, 1) output block
+            # is as illegal a tiling as the per-row input block was
+            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
             scratch_shapes=[
                 pltpu.SMEM((4,), jnp.float32),
                 pltpu.SMEM((4,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((s, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((s,), jnp.int32),
         interpret=_interpret() if interpret is None else interpret,
-    )(temps.astype(jnp.float32), us.astype(jnp.float32), x)
-    return out[:, 0]
+    )(temps.astype(jnp.float32), us.astype(jnp.float32), x[:, None, :])
 
 
 # ----------------------------------------------------------- XLA oracle
@@ -224,7 +244,8 @@ def _xla_sample(x, temps, us, *, bn, greedy):
     for t in range(nt):
         tile = x[:, t * bn:(t + 1) * bn]
         e = jnp.exp(tile - m[:, None])
-        cum = c[:, None] + jnp.cumsum(e, axis=1)
+        cum = c[:, None] + _tile_cumsum(
+            e, lane, lambda a, k: jnp.roll(a, k, axis=1))
         hit = cum > thresh[:, None]
         first = jnp.min(jnp.where(hit, lane, bn), axis=1)
         got = first < bn

@@ -59,14 +59,6 @@ def _chunk_attention(q, k, v, q_pos, k_pos, scale):
     return o, m.transpose(perm), l.transpose(perm)
 
 
-def _axis_size(axis_name):
-    """jax.lax.axis_size is jax >= 0.6; psum(1, axis) is the classic
-    spelling and constant-folds to the same static mesh-axis size."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def ring_attention(
     q: jnp.ndarray,  # [B, T_local, H, d]  (local sequence shard)
     k: jnp.ndarray,  # [B, T_local, KV, d]
@@ -82,7 +74,7 @@ def ring_attention(
     q = q.reshape(B, T, KV, G, d)
     scale = 1.0 / (d ** 0.5)
 
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     q_pos = my * T + jnp.arange(T, dtype=jnp.int32)[None].repeat(B, 0)
 
@@ -117,7 +109,7 @@ def ring_attention(
 # ------------------------------------------------------- ring of flash
 
 def _ring_steps(axis_name: str):
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     return n, my, perm
@@ -270,12 +262,10 @@ def ring_attention_sharded(
     impl = os.environ.get("DTX_RING_IMPL", "flash").strip().lower()
     base = ring_flash_attention if impl != "xla" else ring_attention
     fn = functools.partial(base, axis_name=axis_name)
-    from datatunerx_tpu.parallel.sharding import compat_shard_map
-
-    return compat_shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec_q, spec_kv, spec_kv),
         out_specs=spec_q,
-        check=False,
+        check_vma=False,
     )(q, k, v)

@@ -81,13 +81,8 @@ def make_mesh(
     if len(shape) != 4:
         raise ValueError(f"expected 4-axis shape {MESH_AXES}, got {shape}")
     # Auto axis types = classic GSPMD: the compiler propagates shardings from
-    # NamedSharding annotations (jax>=0.9 defaults to Explicit mode otherwise).
-    # jax < 0.5 has no AxisType — every axis is implicitly Auto there, so the
-    # kwarg is simply omitted and the same programs compile unchanged.
-    if hasattr(jax.sharding, "AxisType"):
-        axis_kw = {"axis_types": (jax.sharding.AxisType.Auto,) * 4}
-    else:
-        axis_kw = {}
+    # NamedSharding annotations (jax 0.9 defaults to Explicit mode otherwise)
+    axis_kw = {"axis_types": (jax.sharding.AxisType.Auto,) * 4}
     if dcn_dp <= 1:
         return jax.make_mesh(shape, MESH_AXES, devices=devices, **axis_kw)
 

@@ -91,6 +91,19 @@ def shard_tree(tree, mesh: Mesh) -> Any:
     )
 
 
+def per_device_bytes(tree) -> dict:
+    """Bytes of ``tree`` resident on each local device, by device id (from
+    ``addressable_shards``: a replicated leaf counts once per device) — how a
+    layout that silently keeps everything on device 0 shows up."""
+    out: dict = {}
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, jax.Array):
+            for shard in leaf.addressable_shards:
+                key = str(shard.device.id)
+                out[key] = out.get(key, 0) + shard.data.nbytes
+    return out
+
+
 def batch_pspec(rank: int = 2, accum: bool = False) -> P:
     """Token batches [B, T, ...]: batch over (dp, fsdp), sequence over sp.
 
@@ -107,20 +120,6 @@ def batch_shardings(batch, mesh: Mesh, accum: bool = False) -> Any:
     return jax.tree_util.tree_map(
         lambda x: NamedSharding(mesh, batch_pspec(x.ndim, accum=accum)), batch
     )
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions: the top-level API (with
-    ``check_vma``) exists from jax 0.6; older jax ships it as
-    ``jax.experimental.shard_map.shard_map`` with the ``check_rep`` spelling
-    of the same knob."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
 
 
 def place_batch(batch: dict, mesh: Optional[Mesh], accum: bool = False) -> dict:

@@ -14,6 +14,7 @@ import socket
 import subprocess
 import sys
 import threading
+import urllib.error
 import urllib.request
 from typing import Dict, Optional
 
@@ -124,7 +125,17 @@ class LocalServingBackend:
                 f"http://127.0.0.1:{port}/healthz", timeout=2
             ) as resp:
                 return json.load(resp).get("status", "PENDING")
-        except Exception:
+        except urllib.error.HTTPError as e:
+            # 503 LOADING and 500 FAILED state themselves in the body. A
+            # server whose engine failed to load also exits (caught by
+            # proc.poll() above); this covers the moment in between, so a
+            # replica that could not open its chip never reads as loading
+            try:
+                failed = json.load(e).get("status") == "FAILED"
+            except ValueError:
+                failed = False
+            return "FAILED" if failed else "PENDING"
+        except (OSError, ValueError):  # not listening yet / half-written body
             return "PENDING"
 
     def endpoint(self, name: str) -> Optional[str]:

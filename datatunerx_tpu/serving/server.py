@@ -16,6 +16,7 @@ import json
 import sys
 import threading
 import time
+import traceback
 import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
@@ -37,6 +38,13 @@ class ServingState:
         self.engine = None
         self.error: Optional[str] = None
         self.model_path = ""
+        # platform / device_kind / count the process runs on (set by main()
+        # from utils.runtime.require_backend) — carried in the /healthz body
+        # so a caller outside can tell a chip replica from a CPU one
+        self.device: dict = {}
+        # () -> {"requests", "hits"} of the persistent compile cache; set by
+        # main() (the module itself stays importable without jax)
+        self.compile_cache_stats = None
         # disaggregation role this replica declares to the fleet:
         # "prefill" (long-prompt specialist), "decode", or "mixed" (the
         # default — role-less routing, byte-identical to older fleets).
@@ -345,6 +353,27 @@ def _metrics_text_locked(with_exemplars: bool = True) -> str:
     if isinstance(samp_stats, dict):
         sp_fused.set(samp_stats.get("fused_steps", 0), {"path": "fused"})
         sp_fused.set(samp_stats.get("legacy_steps", 0), {"path": "legacy"})
+    gen_toks = reg.counter("dtx_serving_generated_tokens_total",
+                           "Tokens emitted to finished requests.")
+    path_g = reg.gauge("dtx_serving_decode_path",
+                       "How decode attention reads the KV cache, one-hot by "
+                       "label (pallas = in-place block-table kernel, gather "
+                       "= paged XLA oracle, dense).")
+    gen_toks.clear()
+    path_g.clear()
+    if getattr(eng, "generated_tokens", None) is not None:
+        gen_toks.set(eng.generated_tokens)
+    if getattr(eng, "decode_path", None):
+        path_g.set(1, {"path": eng.decode_path})
+    # persistent compile cache (utils/runtime.py): a replica that started
+    # against a warm cache shows hits == requests
+    cc_req = reg.counter("dtx_serving_compile_cache_requests_total",
+                         "Compiles that consulted the persistent cache.")
+    cc_hit = reg.counter("dtx_serving_compile_cache_hits_total",
+                         "Compiles served from the persistent cache.")
+    cc = STATE.compile_cache_stats() if STATE.compile_cache_stats else {}
+    cc_req.set(cc.get("requests", 0))
+    cc_hit.set(cc.get("hits", 0))
     # KV migration fabric: session export/import outcomes (restated from
     # the engine's scheduler-thread counters, cleared first like the rest)
     s_exp = reg.counter("dtx_serving_session_export_total",
@@ -466,11 +495,13 @@ class Handler(BaseHTTPRequestHandler):
     def do_GET(self):
         if self.path == "/healthz":
             if STATE.engine is not None:
-                self._json(200, {"status": "HEALTHY", "model": STATE.model_path})
+                self._json(200, {"status": "HEALTHY",
+                                 "model": STATE.model_path, **STATE.device})
             elif STATE.error:
-                self._json(500, {"status": "FAILED", "error": STATE.error})
+                self._json(500, {"status": "FAILED", "error": STATE.error,
+                                 **STATE.device})
             else:
-                self._json(503, {"status": "LOADING"})
+                self._json(503, {"status": "LOADING", **STATE.device})
         elif self.path == "/v1/models":
             self._json(200, {"object": "list", "data": [
                 {"id": STATE.model_path, "object": "model"}]})
@@ -1026,7 +1057,13 @@ def load_engine_async(model_path, checkpoint_path, template, max_seq_len,
                       spec_draft=None, spec_k=4, spec_mode="auto",
                       spec_tree=None, sampling_epilogue="auto",
                       trace_ring=256, trace_log_path=None,
-                      tenants_config=None, host_adapter_cache_mb=0.0):
+                      tenants_config=None, host_adapter_cache_mb=0.0,
+                      on_failure=None):
+    """Build the engine on a background thread. A failure is recorded in
+    ``STATE.error`` (``/healthz`` → 500 FAILED), its traceback printed, and
+    ``on_failure()`` called — ``main`` passes the HTTP server's shutdown so
+    the process EXITS non-zero instead of answering 500 for ever: a replica
+    that could not open its chip must not look like one still loading."""
     def _load():
         try:
             STATE.model_path = model_path
@@ -1093,8 +1130,11 @@ def load_engine_async(model_path, checkpoint_path, template, max_seq_len,
                     model_path, checkpoint_path or None, template=template,
                     max_seq_len=max_seq_len, quantization=quantization or None,
                 )
-        except Exception as e:  # noqa: BLE001
-            STATE.error = str(e)
+        except Exception as e:  # noqa: BLE001 — reported, then fatal in main
+            STATE.error = str(e) or type(e).__name__
+            traceback.print_exc()
+            if on_failure is not None:
+                on_failure()
 
     t = threading.Thread(target=_load, daemon=True)
     t.start()
@@ -1254,6 +1294,11 @@ def main(argv=None):
                         "on /debug/slo)")
     args = p.parse_args(argv)
 
+    from datatunerx_tpu.utils import runtime
+
+    info = runtime.startup("server")
+    STATE.device = {k: info[k] for k in ("platform", "device_kind", "count")}
+    STATE.compile_cache_stats = runtime.compile_cache_stats
     STATE.role = args.role
     if args.slo_config:
         from datatunerx_tpu.obs.slo import load_slos
@@ -1264,6 +1309,7 @@ def main(argv=None):
     if args.slo_sample_s > 0:
         slo_evaluator().start(args.slo_sample_s)
 
+    srv = ThreadingHTTPServer(("0.0.0.0", args.port), Handler)
     load_engine_async(args.model_path, args.checkpoint_path, args.template,
                       args.max_seq_len, quantization=args.quantization,
                       slots=args.slots, decode_chunk=args.decode_chunk,
@@ -1287,13 +1333,17 @@ def main(argv=None):
                       trace_ring=args.trace_ring,
                       trace_log_path=args.trace_log,
                       tenants_config=args.tenants_config,
-                      host_adapter_cache_mb=args.host_adapter_cache_mb)
-    srv = ThreadingHTTPServer(("0.0.0.0", args.port), Handler)
+                      host_adapter_cache_mb=args.host_adapter_cache_mb,
+                      on_failure=srv.shutdown)
     print(f"[serving] listening on :{args.port} (model loading async)", flush=True)
     try:
         srv.serve_forever()
     except KeyboardInterrupt:
         pass
+    if STATE.error:
+        print(f"[serving] engine failed to load: {STATE.error}",
+              file=sys.stderr, flush=True)
+        return 1
     return 0
 
 

@@ -35,7 +35,11 @@ from datatunerx_tpu.models.lora import (
     lora_scaling,
 )
 from datatunerx_tpu.data.prefetch import PlacedBatch
-from datatunerx_tpu.parallel.sharding import place_batch, shard_tree
+from datatunerx_tpu.parallel.sharding import (
+    place_batch,
+    shard_tree,
+    tree_shardings,
+)
 from datatunerx_tpu.training.loss import IGNORE_INDEX, causal_lm_loss
 from datatunerx_tpu.training.optimizer import make_optimizer, make_schedule
 
@@ -171,10 +175,7 @@ class Trainer:
         # share one jitted callable — and with it jax's in-memory executable
         # cache. Spinning up N trainers in one process (scoring controller
         # sweeps, the test suite's dozens of e2e runs) compiles each distinct
-        # step program once instead of once per Trainer. This matters doubly
-        # on jax 0.4.x, where the persistent compilation cache is unusable
-        # (XLA:CPU executable serialization corrupts the heap — see
-        # tests/conftest.py).
+        # step program once instead of once per Trainer.
         key = _step_memo_key(model_cfg, train_cfg, mesh, type(self))
         cached = None if key is None else _STEP_MEMO.get(key)
         if cached is None:
@@ -214,9 +215,20 @@ class Trainer:
         trainable = self._trainable(params, lora)
         if self.cfg.finetuning_type == "none":
             opt_state = ()
+        elif self.mesh is None:
+            opt_state = jax.jit(self.optimizer.init)(trainable)
         else:
-            with self.mesh or _nullcontext():
-                opt_state = jax.jit(self.optimizer.init)(trainable)
+            # Adam moments mirror the trainable tree's paths, so the param
+            # rules shard them like their params (scalars replicate). Left to
+            # output-sharding propagation, the all-zeros moments depend on no
+            # sharded input and the WHOLE state lands on device 0 — found by
+            # the [mesh] line on a four-chip host; at 7B full-parameter that
+            # is two extra copies of the model on one chip before step 1.
+            out_sh = tree_shardings(
+                jax.eval_shape(self.optimizer.init, trainable), self.mesh)
+            with self.mesh:
+                opt_state = jax.jit(self.optimizer.init,
+                                    out_shardings=out_sh)(trainable)
         step = jnp.zeros((), jnp.int32)
         if self.mesh is not None:
             # replicate scalars/keys on the mesh so checkpoint-restore templates
@@ -500,11 +512,3 @@ def optax_global_norm(tree) -> jnp.ndarray:
     if not leaves:
         return jnp.zeros(())
     return jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32))) for x in leaves))
-
-
-class _nullcontext:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *a):
-        return False

@@ -15,6 +15,7 @@ train_path (reference train.py:346-348 loads the train file twice).
 
 from __future__ import annotations
 
+import json
 import math
 import os
 import sys
@@ -33,7 +34,7 @@ from datatunerx_tpu.data.prefetch import (
 from datatunerx_tpu.data.preprocess import preprocess_preference_records
 from datatunerx_tpu.parallel.distributed import maybe_initialize_distributed
 from datatunerx_tpu.parallel.mesh import make_mesh, mesh_shape_for
-from datatunerx_tpu.parallel.sharding import place_batch
+from datatunerx_tpu.parallel.sharding import per_device_bytes, place_batch
 from datatunerx_tpu.training import TrainConfig, Trainer
 from datatunerx_tpu.training.checkpoint import (
     CheckpointManager,
@@ -42,12 +43,22 @@ from datatunerx_tpu.training.checkpoint import (
 )
 from datatunerx_tpu.training.metrics_log import MetricsLogger
 from datatunerx_tpu.tuning.parser import TrainArgs, parse_train_args
+from datatunerx_tpu.utils import runtime
 from datatunerx_tpu.utils.model_loader import load_model_and_tokenizer
 
 
 def run(args: TrainArgs) -> dict:
+    # cache placement must precede the first compile; the distributed
+    # runtime must be up before the backend is first asked for its devices
+    runtime.configure_compile_cache()
     dist = maybe_initialize_distributed(args.num_workers)
     is_main = dist["process_id"] == 0
+    runtime.require_backend()
+    if is_main:
+        from datatunerx_tpu import native
+
+        # the packer's Python fallback is silent by design; say which runs
+        runtime.announce("trainer", native_packer=native.available())
 
     # ----- model -------------------------------------------------------
     overrides = dict(
@@ -281,6 +292,16 @@ def run(args: TrainArgs) -> dict:
     else:
         trainer = Trainer(cfg, tcfg, mesh=mesh)
     state = trainer.init_state(params, jax.random.PRNGKey(args.seed))
+    if is_main:
+        # where the state actually landed: a mesh that silently keeps
+        # everything on device 0 shows here, not in the loss
+        print("[mesh] " + json.dumps({
+            "shape": dict(zip(("dp", "fsdp", "tp", "sp"), shape)),
+            "devices": n_dev,
+            "per_device_bytes": {
+                "params": per_device_bytes((state.params, state.lora)),
+                "opt_state": per_device_bytes(state.opt_state),
+            }}, sort_keys=True), flush=True)
 
     from datatunerx_tpu.utils import storage
 
@@ -576,6 +597,10 @@ def run(args: TrainArgs) -> dict:
                 scaling=trainer.scaling,
             )
     ckpt.close()
+    if is_main:
+        print("[runtime] compile_cache "
+              f"{json.dumps(runtime.compile_cache_stats(), sort_keys=True)}",
+              flush=True)
     return {
         "steps": step,
         "metrics": final_metrics,

@@ -2,7 +2,7 @@
 via eval_shape + shard divisors, analytic activation peaks, and the
 admission gate that fails provably-oversized Finetunes before submission.
 
-These tests ARE the BASELINE.md rows-4/5 capacity claims: if a stated
+These tests ARE the BASELINE.json configurations 3-4 capacity claims: if a stated
 configuration stops fitting its stated hardware, they fail loudly.
 """
 
@@ -85,7 +85,7 @@ def test_grad_accum_reduces_activations_not_grads():
     assert four.grads == one.grads
 
 
-# --------------------------------------------------- BASELINE.md rows 4-5
+# ------------------------------------------ BASELINE.json configurations 3-4
 
 def test_baseline_mistral_7b_full_param_fits_v5e16():
     """BASELINE row 4: Mistral-7B full-parameter FSDP on v5e-16."""

@@ -105,6 +105,29 @@ def test_pallas_kernels_match_xla(mode):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3, rtol=2e-3)
 
 
+def test_pallas_int8_tiles_k_beyond_one_block():
+    """K above the kernel's K tile (llama2-7b's down_proj K=11008 overflowed
+    scoped VMEM as one whole-K block on the chip) runs as a multi-step K grid
+    with an f32 accumulator, its K block the largest 128-multiple divisor
+    of K within the tile."""
+    from datatunerx_tpu.ops._pallas import pick_block_n
+    from datatunerx_tpu.ops.pallas_quant import (
+        _INT8_BLOCK_K,
+        pallas_matmul_int8,
+    )
+
+    K, N = 2 * _INT8_BLOCK_K + 256, 128  # 4352 = 128 · 34
+    assert pick_block_n(K, _INT8_BLOCK_K) == 256  # 17 steps of 256
+    rng = np.random.default_rng(12)
+    w = _w(rng, (K, N))
+    x = _w(rng, (24, K), scale=1.0)
+    qw = quantize_int8(w)
+    ref = matmul_int8(x, qw["q"], qw["scale"])
+    out = pallas_matmul_int8(x, qw["q"], qw["scale"], block_m=64, block_n=128)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-3, rtol=2e-3)
+
+
 def test_pallas_nf4_odd_chunk_k():
     """Real-model K values (5632, 11008) are not 128·64-multiples: with
     K=1408 the kernel runs 2 chunks of 11 blocks — odd blocks-per-chunk and
@@ -251,10 +274,19 @@ def test_pallas_nf4_transposed_kernel_matches_reference():
 
     import jax.numpy as jnp
 
-    from datatunerx_tpu.ops.pallas_quant import _pallas_matmul_nf4_t_impl
+    from datatunerx_tpu.ops.pallas_quant import (
+        _pallas_matmul_nf4_t_impl,
+        _pick_chunk,
+    )
 
+    # the [M, K] output is tiled on its LANE dim by the chunk, so the chunk
+    # must be a 128-multiple: 22 blocks → 2 (128 lanes), not the forward
+    # kernel's 11 (704 — refused by Mosaic at tinyllama's K=5632 on the chip)
+    assert _pick_chunk(22, 64) == 11 * 64
+    assert _pick_chunk(22, 64, lane_aligned=True) == 2 * 64
     rng = np.random.default_rng(11)
-    for K, N, M in ((320, 256, 8), (384, 512, 33), (128, 384, 64)):
+    for K, N, M in ((320, 256, 8), (384, 512, 33), (128, 384, 64),
+                    (1408, 128, 8)):
         w = _w(rng, (K, N))
         q4 = quantize_nf4(w)
         wd = np.asarray(dequant_nf4(q4, (K, N)))

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 from datatunerx_tpu.ops.attention import make_causal_bias, xla_attention
 from datatunerx_tpu.ops.ring_attention import ring_attention_sharded
 from datatunerx_tpu.parallel.mesh import make_mesh
-from datatunerx_tpu.parallel.sharding import compat_shard_map
 
 
 @pytest.mark.parametrize("shape", [(1, 1, 1, 8), (2, 1, 1, 4)])
@@ -133,13 +132,13 @@ def test_ring_flash_matches_xla_ring_fwd_and_grads():
         fn = functools.partial(base, axis_name="sp")
 
         def loss(q, k, v):
-            return (compat_shard_map(fn, mesh=mesh,
-                                     in_specs=(spec, spec, spec),
-                                     out_specs=spec, check=False)
+            return (jax.shard_map(fn, mesh=mesh,
+                                  in_specs=(spec, spec, spec),
+                                  out_specs=spec, check_vma=False)
                     (q, k, v).astype(jnp.float32) ** 2).sum()
 
-        out = compat_shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                               out_specs=spec, check=False)(q, k, v)
+        out = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec, check_vma=False)(q, k, v)
         grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
         return out, grads
 

@@ -1,0 +1,138 @@
+"""Start-up policy (utils/runtime.py): a CPU backend is an error unless it
+was asked for by name, the compile cache is placed from outside or at one
+fixed path, and a server that cannot load its engine exits instead of
+answering 500 for ever. Each case needs its own interpreter — the platform
+and the cache directory latch at first use."""
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _py(code: str, env_set: dict, env_unset=()) -> subprocess.CompletedProcess:
+    env = dict(os.environ,
+               PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    for k in env_unset:
+        env.pop(k, None)
+    env.update(env_set)
+    return subprocess.run([sys.executable, "-c", code], env=env, cwd="/tmp",
+                          capture_output=True, text=True, timeout=300)
+
+
+_PROBE = ("from datatunerx_tpu.utils import runtime\n"
+          "import jax, json\n"
+          "before = jax.config.jax_compilation_cache_dir\n"
+          "used = runtime.configure_compile_cache()\n"
+          "print('CACHE', json.dumps({'before': before, 'used': used,\n"
+          "    'after': jax.config.jax_compilation_cache_dir,\n"
+          "    'fixed': runtime.REPO_CACHE_DIR}), flush=True)\n"
+          "print('DEVICE', json.dumps(runtime.require_backend()))\n")
+
+
+def _tagged(out: subprocess.CompletedProcess, tag: str) -> dict:
+    for ln in out.stdout.splitlines():
+        if ln.startswith(tag + " "):
+            return json.loads(ln[len(tag) + 1:])
+    raise AssertionError(f"no {tag} line:\n{out.stdout}\n{out.stderr[-1500:]}")
+
+
+@pytest.fixture(scope="module")
+def named(tmp_path_factory):
+    """Everything placed from outside: the CPU asked for by name, the cache
+    directory given in the environment."""
+    outside = str(tmp_path_factory.mktemp("cache") / "placed-from-outside")
+    return outside, _py(_PROBE, {"JAX_PLATFORMS": "cpu",
+                                 "JAX_COMPILATION_CACHE_DIR": outside})
+
+
+@pytest.fixture(scope="module")
+def unnamed():
+    """Nothing named: no platform, no cache directory."""
+    return _py(_PROBE, {}, env_unset=(
+        "JAX_PLATFORMS", "JAX_PLATFORM_NAME", "JAX_COMPILATION_CACHE_DIR"))
+
+
+def test_cpu_asked_for_by_name_runs(named):
+    _, out = named
+    assert out.returncode == 0, out.stderr[-1500:]
+    assert _tagged(out, "DEVICE")["platform"] == "cpu"
+
+
+def test_cpu_without_being_asked_for_is_an_error(unnamed):
+    """No JAX_PLATFORMS and no chip: JAX falls back to the CPU with a log
+    line. The guard turns that into a failure."""
+    if unnamed.returncode == 0:
+        assert _tagged(unnamed, "DEVICE")["platform"] != "cpu"
+        pytest.skip("this machine has an accelerator")
+    assert "JAX_PLATFORMS=cpu was not requested" in unnamed.stderr
+
+
+def test_compile_cache_env_set_is_left_alone(named):
+    outside, out = named
+    doc = _tagged(out, "CACHE")
+    assert doc["before"] == doc["after"] == doc["used"] == outside
+
+
+def test_compile_cache_defaults_to_the_fixed_in_checkout_path(unnamed):
+    doc = _tagged(unnamed, "CACHE")
+    assert doc["before"] is None
+    assert doc["used"] == doc["after"] == doc["fixed"]
+    # a fixed path: the directory is part of the cache key, so nothing that
+    # varies between runs (pid, time, host fingerprint, tmp name) may be in it
+    assert doc["fixed"] == os.path.join(REPO, ".jax_compilation_cache")
+
+
+def test_local_serving_backend_reports_failed_when_the_engine_cannot_load(
+        tmp_path):
+    """A replica whose engine fails to load (here: no such model; on a
+    one-chip host: the chip is held by another replica) must EXIT with the
+    real error in its log. It used to keep answering 500, which status()
+    read as PENDING — a dead replica that looked like a loading one."""
+    from datatunerx_tpu.serving.local_backend import LocalServingBackend
+
+    backend = LocalServingBackend(str(tmp_path / "jobs"),
+                                  extra_env={"JAX_PLATFORMS": "cpu"})
+    backend.deploy("broken", {"model_path": str(tmp_path / "no-such-model"),
+                              "template": "vanilla"})
+    try:
+        deadline = time.time() + 180
+        status = backend.status("broken")
+        while status != "FAILED" and time.time() < deadline:
+            assert status == "PENDING", status
+            time.sleep(0.2)
+            status = backend.status("broken")
+        assert status == "FAILED"
+        proc = backend._procs["broken"]
+        assert proc.wait(timeout=60) == 1  # exited, non-zero
+    finally:
+        backend.delete("broken")
+
+
+def test_launchers_do_not_take_the_chip():
+    """One process per chip: a parent that has initialised a JAX backend
+    holds the chip and the trainer or server it spawns cannot open it. The
+    operator manager imports jax (through training/checkpoint.py), so what
+    must hold is that neither importing the launchers nor constructing
+    their backends initialises a backend."""
+    code = (
+        "import datatunerx_tpu.operator.manager\n"
+        "import datatunerx_tpu.gateway.server, datatunerx_tpu.cli\n"
+        "import datatunerx_tpu.experiment.runner, datatunerx_tpu.loadgen.replay\n"
+        "from datatunerx_tpu.operator.backends import LocalProcessBackend\n"
+        "from datatunerx_tpu.serving.local_backend import LocalServingBackend\n"
+        "import sys, tempfile\n"
+        "d = tempfile.mkdtemp()\n"
+        "LocalProcessBackend(d); LocalServingBackend(d)\n"
+        "if 'jax' in sys.modules:\n"
+        "    from jax._src import xla_bridge\n"
+        "    assert not xla_bridge.backends_are_initialized()\n"
+        "print('CLEAN')\n")
+    out = _py(code, {"JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-1500:]
+    assert "CLEAN" in out.stdout

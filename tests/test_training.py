@@ -163,6 +163,14 @@ def test_full_param_fsdp_sharding(devices8):
     tr.mesh = mesh
     params = init_params(CFG, jax.random.PRNGKey(0))
     state = tr.init_state(params, jax.random.PRNGKey(3))
+    # the optimizer state is sharded from INIT, not only after step 1: left
+    # to output-sharding propagation the all-zeros moments landed whole on
+    # device 0 (seen on a four-chip host, PR 21)
+    from datatunerx_tpu.parallel.sharding import per_device_bytes
+
+    per_dev = per_device_bytes(state.opt_state)
+    assert len(per_dev) == 8, per_dev
+    assert max(per_dev.values()) < 2 * min(per_dev.values()), per_dev
     batch = _batch(np.random.default_rng(2), B=8, T=16)
     losses = []
     for _ in range(6):
