@@ -1,0 +1,8 @@
+"""Engine scheduler: device-idle milliseconds of the traced window whose innermost covering
+scheduler span is ``dtx_engine_decode`` or ``dtx_engine_prefill_chunk`` (a dispatch and the
+building of its arguments), per ``dtx_engine_decode`` span in the window."""
+import tick_readers
+
+
+def read(obs):
+    return tick_readers.gap_ms(obs, tick_readers.DISPATCH)

@@ -1,0 +1,9 @@
+"""Model step, serve: self time of the decode program's device ops under ``dtx.qkv``,
+``dtx.attn_out``, ``dtx.mlp`` or ``dtx.unembed``: the regions that stream the weights; per token
+step (executions of ``_decode_impl`` in the window times its scanned steps). The closed-loop
+batch cell's reading of it."""
+import scope_readers
+
+
+def read(obs):
+    return scope_readers.decode_region_ms(obs, scope_readers.WEIGHTS)

@@ -26,6 +26,8 @@ import time
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional
 
+import jax
+
 from datatunerx_tpu.adapters.store import AdapterStore, validate_adapter
 from datatunerx_tpu.models.lora import lora_scaling
 
@@ -339,6 +341,12 @@ class AdapterRegistry:
         self._publish_locked()
 
     def _load_worker(self, ent: _Entry, slot: int):
+        # the loader thread's span in the profiler's trace, checkpoint read
+        # to pool insert: what ``load_observer`` times, on the device's clock
+        with jax.profiler.TraceAnnotation("dtx_adapter_load"):
+            self._load(ent, slot)
+
+    def _load(self, ent: _Entry, slot: int):
         """Loader thread: checkpoint read + validation run UNLOCKED (the
         multi-second part); only the device insert + bookkeeping take the
         lock. Failure frees the reserved slot and parks the error on the

@@ -117,20 +117,24 @@ def _proj(h, p, lora_p, lora_scale, drop_key=None, drop_rate=0.0,
     if "bias" in p:
         out = out + p["bias"].astype(h.dtype)
     if lora_p is not None:
-        a = lora_p["a"].astype(h.dtype)
-        b = lora_p["b"].astype(h.dtype)
-        hl = h
-        if drop_key is not None and drop_rate > 0.0:
-            keep = jax.random.bernoulli(drop_key, 1.0 - drop_rate, h.shape)
-            hl = jnp.where(keep, h / (1.0 - drop_rate), 0.0).astype(h.dtype)
-        if lora_idx is not None:
-            a_sel = a[lora_idx]  # [B, d_in, r]
-            b_sel = b[lora_idx]  # [B, r, d_out]
-            scale = jnp.asarray(lora_scale, h.dtype)[lora_idx][:, None, None]
-            delta = jnp.einsum("btd,bdr->btr", hl, a_sel)
-            out = out + jnp.einsum("btr,bro->bto", delta, b_sel) * scale
-        else:
-            out = out + ((hl @ a) @ b) * jnp.asarray(lora_scale, h.dtype)
+        with jax.named_scope("dtx.lora"):
+            a = lora_p["a"].astype(h.dtype)
+            b = lora_p["b"].astype(h.dtype)
+            hl = h
+            if drop_key is not None and drop_rate > 0.0:
+                keep = jax.random.bernoulli(drop_key, 1.0 - drop_rate,
+                                            h.shape)
+                hl = jnp.where(keep, h / (1.0 - drop_rate),
+                               0.0).astype(h.dtype)
+            if lora_idx is not None:
+                a_sel = a[lora_idx]  # [B, d_in, r]
+                b_sel = b[lora_idx]  # [B, r, d_out]
+                scale = jnp.asarray(
+                    lora_scale, h.dtype)[lora_idx][:, None, None]
+                delta = jnp.einsum("btd,bdr->btr", hl, a_sel)
+                out = out + jnp.einsum("btr,bro->bto", delta, b_sel) * scale
+            else:
+                out = out + ((hl @ a) @ b) * jnp.asarray(lora_scale, h.dtype)
     return out
 
 
@@ -361,18 +365,23 @@ def forward(
         qm, qp = cfg.quantization, cfg.quant_impl == "pallas"
         D, F = cfg.hidden_size, cfg.intermediate_size
 
-        h = rms_norm(x, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
-        q = _proj(h, lp["q_proj"], lget("q_proj"), lora_scale, kget(0), drop,
-                  qm, (D, cfg.q_dim), qp, lora_adapter_idx)
-        k = _proj(h, lp["k_proj"], lget("k_proj"), lora_scale, kget(1), drop,
-                  qm, (D, cfg.kv_dim), qp, lora_adapter_idx)
-        v = _proj(h, lp["v_proj"], lget("v_proj"), lora_scale, kget(2), drop,
-                  qm, (D, cfg.kv_dim), qp, lora_adapter_idx)
-        q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        # one named scope per region, so that every op of a layer is in
+        # exactly one of them (benchmarks/scope_readers.py reads them from
+        # the device trace); what the scan itself moves carries dtx.layers
+        # and no inner scope
+        with jax.named_scope("dtx.qkv"):
+            h = rms_norm(x, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
+            q = _proj(h, lp["q_proj"], lget("q_proj"), lora_scale, kget(0),
+                      drop, qm, (D, cfg.q_dim), qp, lora_adapter_idx)
+            k = _proj(h, lp["k_proj"], lget("k_proj"), lora_scale, kget(1),
+                      drop, qm, (D, cfg.kv_dim), qp, lora_adapter_idx)
+            v = _proj(h, lp["v_proj"], lget("v_proj"), lora_scale, kget(2),
+                      drop, qm, (D, cfg.kv_dim), qp, lora_adapter_idx)
+            q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
+            k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+            v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
         if ck is not None and paged_kernel:
             # in-place decode: scatter the token's K/V into its blocks, then
@@ -382,10 +391,12 @@ def forward(
                 paged_attention_decode_step,
             )
 
-            ck, cv, cks, cvs = kv_cache_write_paged(
-                cache, ck, cv, cks, cvs, k, v)
-            attn = paged_attention_decode_step(
-                q, ck, cv, cks, cvs, cache, cache_pos, positions)
+            with jax.named_scope("dtx.kv_write"):
+                ck, cv, cks, cvs = kv_cache_write_paged(
+                    cache, ck, cv, cks, cvs, k, v)
+            with jax.named_scope("dtx.attn"):
+                attn = paged_attention_decode_step(
+                    q, ck, cv, cks, cvs, cache, cache_pos, positions)
         elif ck is not None and paged_kernel_mt:
             # multi-token in-place: same scatter-then-read-through-the-table
             # scheme with the precomputed attendability operand standing in
@@ -394,36 +405,45 @@ def forward(
                 paged_attention_multitoken_step,
             )
 
-            ck, cv, cks, cvs = kv_cache_write_paged(
-                cache, ck, cv, cks, cvs, k, v)
-            attn = paged_attention_multitoken_step(
-                q, ck, cv, cks, cvs, cache, allow)
+            with jax.named_scope("dtx.kv_write"):
+                ck, cv, cks, cvs = kv_cache_write_paged(
+                    cache, ck, cv, cks, cvs, k, v)
+            with jax.named_scope("dtx.attn"):
+                attn = paged_attention_multitoken_step(
+                    q, ck, cv, cks, cvs, cache, allow)
         else:
             if ck is not None:
                 # dense (scalar/per-slot cursor) or paged (block-table)
                 # write + full-width read via the shared cache interface
-                ck, cv, cks, cvs, k_att, v_att = kv_cache_update(
-                    cache, ck, cv, cks, cvs, k, v)
+                # (the gather counts as pool traffic)
+                with jax.named_scope("dtx.kv_write"):
+                    ck, cv, cks, cvs, k_att, v_att = kv_cache_update(
+                        cache, ck, cv, cks, cvs, k, v)
             else:
                 k_att, v_att = k, v
 
-            attn = attention(
-                q, k_att, v_att, bias, impl=att_impl,
-                segment_ids=segment_ids if att_impl == "flash" else None)
-        attn = attn.reshape(B, T, cfg.q_dim)
-        x = x + _proj(attn, lp["o_proj"], lget("o_proj"), lora_scale, kget(3),
-                      drop, qm, (cfg.q_dim, D), qp, lora_adapter_idx)
+            with jax.named_scope("dtx.attn"):
+                attn = attention(
+                    q, k_att, v_att, bias, impl=att_impl,
+                    segment_ids=segment_ids if att_impl == "flash" else None)
+        with jax.named_scope("dtx.attn_out"):
+            attn = attn.reshape(B, T, cfg.q_dim)
+            x = x + _proj(attn, lp["o_proj"], lget("o_proj"), lora_scale,
+                          kget(3), drop, qm, (cfg.q_dim, D), qp,
+                          lora_adapter_idx)
 
-        h = rms_norm(x, lp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
-        gate = _proj(h, lp["gate_proj"], lget("gate_proj"), lora_scale, kget(4),
-                     drop, qm, (D, F), qp, lora_adapter_idx)
-        up = _proj(h, lp["up_proj"], lget("up_proj"), lora_scale, kget(5),
-                   drop, qm, (D, F), qp, lora_adapter_idx)
-        mlp = _proj(
-            jax.nn.silu(gate) * up, lp["down_proj"], lget("down_proj"),
-            lora_scale, kget(6), drop, qm, (F, D), qp, lora_adapter_idx,
-        )
-        x = x + mlp
+        with jax.named_scope("dtx.mlp"):
+            h = rms_norm(x, lp["post_attention_layernorm"]["scale"],
+                         cfg.rms_norm_eps)
+            gate = _proj(h, lp["gate_proj"], lget("gate_proj"), lora_scale,
+                         kget(4), drop, qm, (D, F), qp, lora_adapter_idx)
+            up = _proj(h, lp["up_proj"], lget("up_proj"), lora_scale,
+                       kget(5), drop, qm, (D, F), qp, lora_adapter_idx)
+            mlp = _proj(
+                jax.nn.silu(gate) * up, lp["down_proj"], lget("down_proj"),
+                lora_scale, kget(6), drop, qm, (F, D), qp, lora_adapter_idx,
+            )
+            x = x + mlp
         return x, (ck, cv, cks, cvs)
 
     if cfg.remat == "full":
@@ -449,11 +469,13 @@ def forward(
     # compiling at unroll=1 vs unroll=2 and differencing recovers the exact
     # per-layer cost. Default 1 = production behavior, byte-identical program.
     _unroll = int(os.environ.get("DTX_SCAN_UNROLL", "1"))
-    x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(block, x, xs,
-                                                     unroll=_unroll)
+    with jax.named_scope("dtx.layers"):
+        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(block, x, xs,
+                                                         unroll=_unroll)
 
-    x = rms_norm(x, params["norm"]["scale"], cfg.rms_norm_eps)
-    logits = None if skip_logits else lm_logits(params, x, cfg)
+    with jax.named_scope("dtx.unembed"):
+        x = rms_norm(x, params["norm"]["scale"], cfg.rms_norm_eps)
+        logits = None if skip_logits else lm_logits(params, x, cfg)
 
     new_cache = None
     if cache is not None:

@@ -30,9 +30,9 @@ LATENCY_BUCKETS = (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
                    60.0, float("inf"))
 
 # Millisecond-scale buckets for the serving latency histograms
-# (dtx_serving_ttft_ms / dtx_serving_tpot_ms / dtx_gateway_queue_wait_ms /
-# dtx_serving_prefill_chunk_ms). Spans sub-ms decode ticks on a warm TPU up
-# to multi-second cold prefills; fixed edges so replicas aggregate.
+# (dtx_serving_ttft_ms / dtx_serving_tpot_ms / dtx_gateway_queue_wait_ms).
+# Spans sub-ms decode ticks on a warm TPU up to multi-second cold prefills;
+# fixed edges so replicas aggregate.
 MS_BUCKETS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
               1000.0, 2500.0, 5000.0, 10000.0, 30000.0, float("inf"))
 
@@ -308,8 +308,8 @@ class Registry:
 
 
 def serving_latency_histograms(
-        registry: Registry) -> Tuple[Histogram, Histogram, Histogram]:
-    """The serving plane's (ttft, tpot, prefill_chunk) histograms,
+        registry: Registry) -> Tuple[Histogram, Histogram]:
+    """The serving plane's (ttft, tpot) histograms,
     declared ONCE here: the engine records into them and the serving
     server pre-declares them at scrape time, and Registry keeps the first
     registration — two call sites with their own HELP text would make the
@@ -322,11 +322,6 @@ def serving_latency_histograms(
         registry.histogram(
             "dtx_serving_tpot_ms",
             "Per-request mean inter-token time after the first token.",
-            buckets=MS_BUCKETS),
-        registry.histogram(
-            "dtx_serving_prefill_chunk_ms",
-            "Wall time per chunked-prefill program as seen by the "
-            "scheduler (dispatch + any queue drain on async backends).",
             buckets=MS_BUCKETS),
     )
 

@@ -182,6 +182,7 @@ def _fwd(q, k, v, q_seg, kv_seg, *, block_q, block_k, interpret, H, G,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="dtx_flash_fwd",
     )(q, k, v, q_seg3, kv_seg3)
     return out, lse[:, :, 0]
 
@@ -316,6 +317,7 @@ def _bwd(block_q, block_k, interpret, G, res, do, causal: bool = True):
         out_shape=jax.ShapeDtypeStruct((BH, T, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="dtx_flash_bwd_dq",
     )(q, k, v, do, lse_b, dsum_b, q_seg3, kv_seg3)
 
     dk, dv = pl.pallas_call(
@@ -345,6 +347,7 @@ def _bwd(block_q, block_k, interpret, G, res, do, causal: bool = True):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="dtx_flash_bwd_dkv",
     )(q, k, v, do, lse_b, dsum_b, q_seg3, kv_seg3)
     if G > 1:
         dk = dk.reshape(BKV, G, S, d).sum(axis=1)

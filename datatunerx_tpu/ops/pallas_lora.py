@@ -69,5 +69,6 @@ def pallas_lora_matmul(
         out_specs=pl.BlockSpec((block_m, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=_interpret(),
+        name="dtx_lora_fused",
     )(x2d, w, a, b)
     return out[:m].reshape(*lead, N)

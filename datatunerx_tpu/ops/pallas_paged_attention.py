@@ -237,6 +237,7 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, d), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
+        name="dtx_paged_decode",
     )(tables.astype(jnp.int32), q_positions.astype(jnp.int32), *args)
     return out
 
@@ -478,6 +479,7 @@ def paged_multitoken_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, nT, H * tq, d), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
+        name="dtx_paged_multitoken",
     )(tables.astype(jnp.int32), *args)
     out = out.reshape(B, nT, H, tq, d).transpose(0, 1, 3, 2, 4)
     return out.reshape(B, Tp, H, d)[:, :T]

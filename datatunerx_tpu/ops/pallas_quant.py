@@ -123,6 +123,7 @@ def _pallas_matmul_int8_impl(
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, bn), jnp.float32)],
         interpret=_interpret(),
+        name="dtx_quant_int8",
     )(x2d, q, scale.reshape(1, N))
     return out[:m_real].reshape(*lead, N)
 
@@ -323,6 +324,7 @@ def _pallas_matmul_nf4_t_impl(
             pltpu.VMEM((block_m, ck), jnp.float32),
         ],
         interpret=_interpret(),
+        name="dtx_quant_nf4_t",
     )(
         g2d.reshape(M, nn, bn).transpose(1, 0, 2),        # [nn, M, bn]
         packedk.transpose(1, 0, 2, 3),                    # [nk, N, nbc, half]
@@ -380,5 +382,6 @@ def _pallas_matmul_nf4_impl(
             pltpu.VMEM((block_m, bn), jnp.float32),
         ],
         interpret=_interpret(),
+        name="dtx_quant_nf4",
     )(xk, packedk, scalesk)
     return out[:m_real].reshape(*lead, N)

@@ -210,6 +210,7 @@ def _kernel_sample(x, temps, us, *, bn, greedy, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct((s,), jnp.int32),
         interpret=_interpret() if interpret is None else interpret,
+        name="dtx_fused_sample",
     )(temps.astype(jnp.float32), us.astype(jnp.float32), x[:, None, :])
 
 

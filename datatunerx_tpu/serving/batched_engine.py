@@ -567,13 +567,14 @@ class _Programs:
         argsort sampler, byte-identical to the pre-epilogue program."""
         def step(carry, _):
             logits, cache, pos, remaining, active, rng = carry
-            if mode == "off" or self.epilogue == "off":
-                split = jax.vmap(jax.random.split)(rng)
-                rng, sub = split[:, 0], split[:, 1]
-                nxt = jax.vmap(_sample_jit)(logits, temps, top_ps, sub)
-            else:
-                nxt, rng = sample_rows(logits, temps, top_ps, rng,
-                                       mode=mode, impl=self.epilogue)
+            with jax.named_scope("dtx.sample"):
+                if mode == "off" or self.epilogue == "off":
+                    split = jax.vmap(jax.random.split)(rng)
+                    rng, sub = split[:, 0], split[:, 1]
+                    nxt = jax.vmap(_sample_jit)(logits, temps, top_ps, sub)
+                else:
+                    nxt, rng = sample_rows(logits, temps, top_ps, rng,
+                                           mode=mode, impl=self.epilogue)
             is_stop = jnp.any(nxt[:, None] == stops, axis=1)
             emit = active & ~is_stop & (remaining > 0)
             emitted = jnp.where(emit, nxt, -1)
@@ -1038,11 +1039,10 @@ class BatchedEngine:
         self.prefill_stats = {"full": 0, "reuse": 0, "extend": 0}
         # Shared-registry latency histograms. Recording is BUFFERED off the
         # hot path: token stamps are plain attribute writes in Request.push;
-        # the observes below fire once per completed request (TTFT/TPOT) or
-        # once per prefill chunk — never per token.
+        # the observes below fire once per completed request (TTFT/TPOT) —
+        # never per token.
         self.registry = registry or Registry()
-        (self._h_ttft, self._h_tpot,
-         self._h_prefill_chunk) = serving_latency_histograms(self.registry)
+        self._h_ttft, self._h_tpot = serving_latency_histograms(self.registry)
         self._h_adapter_load = adapter_load_histogram(self.registry)
         if self.spec is not None:
             from datatunerx_tpu.obs.metrics import spec_accept_len_histogram
@@ -1078,7 +1078,10 @@ class BatchedEngine:
         block-table kernel), ``gather`` (paged XLA oracle), or ``dense``."""
         if not self.paged:
             return "dense"
-        return "pallas" if self.paged_kernel else "gather"
+        # the path forward() takes, not the flag: a sliding window keeps
+        # the model on the gather whatever paged_kernel asked for
+        takes_kernel = self.paged_kernel and self.cfg.sliding_window is None
+        return "pallas" if takes_kernel else "gather"
 
     @property
     def total_kv_blocks(self) -> Optional[int]:
@@ -1435,8 +1438,10 @@ class BatchedEngine:
             # non-blocking: a miss kicks an ASYNC load and returns None —
             # decode keeps ticking while the checkpoint reads; the request
             # parks at its FIFO position until the load resolves
-            idx = self.adapter_registry.acquire(
-                req.adapter_name, count_hit=not req.adapter_stats_counted)
+            with jax.profiler.TraceAnnotation("dtx_engine_adapter_acquire"):
+                idx = self.adapter_registry.acquire(
+                    req.adapter_name,
+                    count_hit=not req.adapter_stats_counted)
             if idx is not None:
                 req.adapter_stats_counted = True
             if idx is None:
@@ -1913,9 +1918,9 @@ class BatchedEngine:
                 c = min(self.prefill_chunk, st["plen"] - st["done"],
                         budget - spent)
                 lo = st["done"]
-                t0 = time.perf_counter()
                 try:
-                    with jax.profiler.TraceAnnotation("dtx_engine_prefill_chunk"):
+                    with jax.profiler.TraceAnnotation(
+                            "dtx_engine_prefill_chunk", tokens=c, slot=slot):
                         logits, self._cache = self._prefill_chunk_fn(
                             self.params, self._lora_arg(), self._cache,
                             jnp.asarray(slot, jnp.int32),
@@ -1929,20 +1934,15 @@ class BatchedEngine:
                     self._release_slot(slot)
                     self._complete(req, error=str(e))
                     break
-                # wall time as the scheduler sees it: on a synchronous
-                # backend this is the chunk's execution; under async
-                # dispatch it is dispatch + queue drain — no extra sync is
-                # added here to make it "exact" (the budget bound, not this
-                # number, is the scheduling contract)
-                self._h_prefill_chunk.observe(
-                    (time.perf_counter() - t0) * 1e3)
                 st["done"] += c
                 spent += c
                 self._trace("prefill", slot, c)
                 if self.tracing:
                     req.mark("prefill", slot=slot, tokens=c)
                 if st["done"] >= st["plen"]:
-                    self._finish_prefill(slot, st, logits)
+                    with jax.profiler.TraceAnnotation("dtx_engine_activate",
+                                                      slot=slot):
+                        self._finish_prefill(slot, st, logits)
                     break
             if spent >= budget:
                 break
@@ -3308,7 +3308,8 @@ class BatchedEngine:
             self._trace("spec", k, len(obs))
         else:
             emode = self._epilogue_mode()
-            with jax.profiler.TraceAnnotation("dtx_engine_decode"):
+            with jax.profiler.TraceAnnotation("dtx_engine_decode",
+                                              live=sum(self._decode_ready)):
                 (emitted, self._cache, self._spec_pending, self._pos,
                  self._remaining, self._active, self._rng) = progs.decode(
                     self.params, self._lora_arg(), self._cache,
@@ -3316,14 +3317,16 @@ class BatchedEngine:
                     self._active, self._rng, self._temps, self._top_ps,
                     self._stops, self._adapter_idx, K=self.chunk,
                     mode=emode)
-            out_rows.append(np.asarray(emitted))  # [K, S]  # dtxlint: disable=DTX001
+            with jax.profiler.TraceAnnotation("dtx_engine_decode_sync"):
+                out_rows.append(np.asarray(emitted))  # [K, S]  # dtxlint: disable=DTX001
             self.spec_stats["plain_steps"] += 1
             self.sampling_stats["fused_steps" if emode != "off"
                                 else "legacy_steps"] += 1
             self.spec_ctrl.note_plain_step()
             self._trace("decode", self.chunk)
 
-        active_np = np.asarray(self._active)  # dtxlint: disable=DTX001
+        with jax.profiler.TraceAnnotation("dtx_engine_decode_sync"):
+            active_np = np.asarray(self._active)  # dtxlint: disable=DTX001
         return np.concatenate(out_rows, axis=0), active_np
 
     def spec_info(self) -> Optional[dict]:
@@ -3372,53 +3375,71 @@ class BatchedEngine:
         return info
 
     def _scheduler(self):
+        # every pass and every phase in it is a host span in the profiler's
+        # own trace (one clock with the device ops): with no profiler
+        # session open a TraceAnnotation is a flag test
         while not self._shutdown.is_set():
-            # migrations first: an imported session is already mid-decode
-            # (its prefill budget was spent on the source replica), so it
-            # outranks cold admissions for free slots
+            with jax.profiler.TraceAnnotation("dtx_engine_tick"):
+                self._tick()
+
+    def _tick(self):
+        """One pass of the scheduler: admissions, at most a budget of
+        prefill, then one decode chunk and the delivery of its tokens."""
+        span = jax.profiler.TraceAnnotation
+        # migrations first: an imported session is already mid-decode
+        # (its prefill budget was spent on the source replica), so it
+        # outranks cold admissions for free slots
+        with span("dtx_engine_migrate"):
             self._service_migrations()
+        with span("dtx_engine_resume"):
             self._resume_preempted_tick()
+        with span("dtx_engine_admit"):
             self._admit_waiting()
-            self._prefill_tick()
+        self._prefill_tick()
+        with span("dtx_engine_grow"):
             self._grow_tick()
 
-            if not any(self._decode_ready):
-                if self._pending:
-                    continue  # keep prefilling; nothing to decode yet
+        if not any(self._decode_ready):
+            if self._pending:
+                return  # keep prefilling; nothing to decode yet
+            with span("dtx_engine_wait"):
                 self._wake.wait(timeout=0.1)
                 self._wake.clear()
-                continue
+            return
 
-            try:
-                if self.spec is not None:
-                    emitted_np, active_np = self._spec_decode_tick()
-                else:
-                    emode = self._epilogue_mode()
-                    with jax.profiler.TraceAnnotation("dtx_engine_decode"):
-                        (emitted, self._logits, self._cache, self._pos,
-                         self._remaining, self._active, self._rng) = \
-                            self._decode(
-                                self.params, self._lora_arg(), self._cache,
-                                self._logits, self._pos,
-                                self._remaining, self._active, self._rng,
-                                self._temps, self._top_ps, self._stops,
-                                self._adapter_idx, K=self.chunk,
-                                mode=emode,
-                            )
-                    self.sampling_stats["fused_steps" if emode != "off"
-                                        else "legacy_steps"] += 1
-                    self._trace("decode", self.chunk)
-                    # the decode loop's ONE designed sync point: K tokens per
-                    # chunk cross to host here so req.push can stream them
+        try:
+            if self.spec is not None:
+                emitted_np, active_np = self._spec_decode_tick()
+            else:
+                emode = self._epilogue_mode()
+                with span("dtx_engine_decode",
+                          live=sum(self._decode_ready)):
+                    (emitted, self._logits, self._cache, self._pos,
+                     self._remaining, self._active, self._rng) = \
+                        self._decode(
+                            self.params, self._lora_arg(), self._cache,
+                            self._logits, self._pos,
+                            self._remaining, self._active, self._rng,
+                            self._temps, self._top_ps, self._stops,
+                            self._adapter_idx, K=self.chunk,
+                            mode=emode,
+                        )
+                self.sampling_stats["fused_steps" if emode != "off"
+                                    else "legacy_steps"] += 1
+                self._trace("decode", self.chunk)
+                # the decode loop's ONE designed sync point: K tokens per
+                # chunk cross to host here so req.push can stream them
+                with span("dtx_engine_decode_sync"):
                     emitted_np = np.asarray(emitted)  # [K, S]  # dtxlint: disable=DTX001
                     active_np = np.asarray(self._active)  # [S]  # dtxlint: disable=DTX001
-            except Exception as e:  # noqa: BLE001 — device fault: fail all in-flight
-                for slot, req in enumerate(self._slot_req):
-                    if req is not None:
-                        self._release_slot(slot)
-                        self._complete(req, error=str(e))
-                continue
+        except Exception as e:  # noqa: BLE001 — device fault: fail all in-flight
+            for slot, req in enumerate(self._slot_req):
+                if req is not None:
+                    self._release_slot(slot)
+                    self._complete(req, error=str(e))
+            return
 
+        with span("dtx_engine_emit"):
             for k in range(emitted_np.shape[0]):
                 for slot in range(self.slots):
                     # emitted_np is host-side numpy already — no device sync

@@ -72,7 +72,8 @@ class _EnginePrograms:
         out0 = jnp.zeros((max_new_tokens,), jnp.int32)
 
         def sample(logits, rng):
-            return _sample_jit(logits, temperature, top_p, rng)
+            with jax.named_scope("dtx.sample"):
+                return _sample_jit(logits, temperature, top_p, rng)
 
         def cond(carry):
             i, logits, cache, rng, out, stopped = carry

@@ -774,20 +774,23 @@ class SpecPrograms:
         (``mode == "off"``) — byte-identical pre-epilogue programs — else
         the fused epilogue with the same key-split order, so the per-slot
         PRNG stream evolves identically either way."""
-        if mode == "off" or self.epilogue == "off":
-            split = jax.vmap(jax.random.split)(rng)
-            rng2, sub = split[:, 0], split[:, 1]
-            return jax.vmap(_sample_jit)(logits, temps, top_ps, sub), rng2
-        return sample_rows(logits, temps, top_ps, rng, mode=mode,
-                           impl=self.epilogue)
+        with jax.named_scope("dtx.sample"):
+            if mode == "off" or self.epilogue == "off":
+                split = jax.vmap(jax.random.split)(rng)
+                rng2, sub = split[:, 0], split[:, 1]
+                return (jax.vmap(_sample_jit)(logits, temps, top_ps, sub),
+                        rng2)
+            return sample_rows(logits, temps, top_ps, rng, mode=mode,
+                               impl=self.epilogue)
 
     def _draw_keys(self, logits, temps, top_ps, keys, mode: str):
         """One draw from PRE-SPLIT per-row keys (the tree step's W iid
         sibling draws)."""
-        if mode == "off" or self.epilogue == "off":
-            return jax.vmap(_sample_jit)(logits, temps, top_ps, keys)
-        return fused_sample(logits, temps, top_ps, keys, mode=mode,
-                            impl=self.epilogue)
+        with jax.named_scope("dtx.sample"):
+            if mode == "off" or self.epilogue == "off":
+                return jax.vmap(_sample_jit)(logits, temps, top_ps, keys)
+            return fused_sample(logits, temps, top_ps, keys, mode=mode,
+                                impl=self.epilogue)
 
     # ---- logits-form → pending-form transition (first emitted token)
     def _enter_impl(self, logits, pending, remaining, active, rng,

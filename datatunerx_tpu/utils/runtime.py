@@ -11,7 +11,8 @@ jitted:
   touched here; otherwise the cache lives at a FIXED path inside the checkout
   (the directory is part of every cache key, so a path that moves never
   hits). Spawned trainers and servers resolve the same directory on their
-  own.
+  own. The key covers op metadata (named scopes), so that a cached program
+  never shows a profile names it was not traced with.
 - ``require_backend()`` — JAX falls back to the CPU with one log line when
   libtpu cannot open the chip (absent, or held by another process). A
   trainer or server that silently continues there looks healthy and is not,
@@ -27,15 +28,16 @@ from __future__ import annotations
 import collections
 import json
 import os
+import re
 from importlib import metadata
 
 import jax
 
 from datatunerx_tpu.ops._pallas import interpret_default
 
-REPO_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    ".jax_compilation_cache")
+REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+REPO_CACHE_DIR = os.path.join(REPO_ROOT, ".jax_compilation_cache")
 
 _CACHE_EVENTS = {
     "/jax/compilation_cache/compile_requests_use_cache": "requests",
@@ -60,6 +62,15 @@ def configure_compile_cache() -> str:
     if not path:
         path = REPO_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", path)
+    # The names a profile shows inside a program (jax.named_scope: dtx.attn,
+    # dtx.sample, ...) are op metadata, which the cache's key leaves out by
+    # default: a program cached before a scope was added or renamed would
+    # load with the old names, and the trace's readers would find nothing.
+    # Key on the metadata too, with source paths taken relative to the
+    # checkout so that a checkout at another path still hits.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(REPO_ROOT + os.sep))
     if not _listener_on:
         jax.monitoring.register_event_listener(_count_cache_event)
         _listener_on = True

@@ -65,23 +65,50 @@ _SERVING_PROBE = r"""
 import json, os, sys
 sys.path.insert(0, os.environ["DTX_REPO"])
 from scripts.aot_certify import (
-    TOPOLOGY_1CHIP, _topo, lower_mosaic, serving_kernel_cases)
+    ENGINE_BLOCK, ENGINE_HEADS, ENGINE_SEQ, ENGINE_SLOTS, TOPOLOGY_1CHIP,
+    _topo, lower_mosaic, serving_kernel_cases)
+import jax
+import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
+from datatunerx_tpu.ops.pallas_paged_attention import paged_decode_attention
 
 sh = SingleDeviceSharding(_topo(TOPOLOGY_1CHIP).devices[0])
-done, failed = [], {}
-for name, fn, args in serving_kernel_cases(sh):
-    if not ("fused_sample" in name or name.endswith((
+
+
+def sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+
+# the single-token decode kernel at the same geometry (the shared list holds
+# the multi-token kernel and the sampler)
+H, KV, d = ENGINE_HEADS["tinyllama"]
+nbps = ENGINE_SEQ // ENGINE_BLOCK
+pool = sds((ENGINE_SLOTS * nbps, ENGINE_BLOCK, KV, d), jnp.bfloat16)
+cases = list(serving_kernel_cases(sh)) + [(
+    "kernel/paged_decode_bf16_tinyllama",
+    lambda q, k, v, t, p, qp: paged_decode_attention(
+        q, k, v, None, None, t, p, qp),
+    (sds((ENGINE_SLOTS, H, d), jnp.bfloat16), pool, pool,
+     sds((ENGINE_SLOTS, nbps), jnp.int32),
+     sds((ENGINE_SLOTS * nbps, ENGINE_BLOCK), jnp.int32),
+     sds((ENGINE_SLOTS,), jnp.int32)))]
+KERNEL_NAMES = ("dtx_paged_decode", "dtx_paged_multitoken", "dtx_fused_sample")
+done, failed, named = [], {}, {}
+for name, fn, args in cases:
+    if not ("fused_sample" in name or "paged_decode" in name or name.endswith((
             "bf16_tinyllama_T5", "bf16_tinyllama_T13", "bf16_tinyllama_T64",
             "bf16_tinyllama_T256", "int8_kv_tinyllama_T5",
             "int8_kv_tinyllama_T256"))):
         continue  # the full list is `make aot-certify`'s
     try:
-        lower_mosaic(fn, *args)
+        text = lower_mosaic(fn, *args).as_text()
         done.append(name)
+        # the pallas_call's name= after Mosaic and XLA's TPU pipeline: the
+        # custom call's instruction name, which the chip's trace shows
+        named[name] = [k for k in KERNEL_NAMES if "%" + k in text]
     except Exception as e:
         failed[name] = f"{type(e).__name__}: {str(e)[:400]}"
-print(json.dumps({"done": done, "failed": failed}))
+print(json.dumps({"done": done, "failed": failed, "named": named}))
 """
 
 
@@ -108,6 +135,14 @@ def test_serving_kernels_lower_through_mosaic_at_engine_geometry():
                  "kernel/fused_sample_greedy_S4_V32000",
                  "kernel/fused_sample_simple_S4_V151936"):
         assert want in names, (want, sorted(names))
+    # each kernel keeps the name its pallas_call was given through Mosaic:
+    # the device trace's readers (benchmarks/scope_readers.py) find it by that
+    assert "kernel/paged_decode_bf16_tinyllama" in names
+    for case, kernels in doc["named"].items():
+        want = ("dtx_fused_sample" if "fused_sample" in case else
+                "dtx_paged_decode" if "paged_decode" in case else
+                "dtx_paged_multitoken")
+        assert kernels == [want], (case, kernels)
 
 
 @pytest.mark.slow

@@ -88,6 +88,41 @@ def test_compile_cache_defaults_to_the_fixed_in_checkout_path(unnamed):
     assert doc["fixed"] == os.path.join(REPO, ".jax_compilation_cache")
 
 
+_SCOPE_PROBE = (
+    "import os, re, json, jax, jax.numpy as jnp\n"
+    "from datatunerx_tpu.utils import runtime\n"
+    "runtime.configure_compile_cache()\n"
+    "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+    "jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)\n"
+    "def f(x):\n"
+    "    with jax.named_scope(os.environ['PROBE_SCOPE']):\n"
+    "        return jnp.tanh(x @ x)\n"
+    "step = jax.jit(f)\n"
+    "step(jnp.ones((32, 32))).block_until_ready()\n"
+    "text = step.lower(jnp.ones((32, 32))).compile().as_text()\n"
+    "print('SCOPES', json.dumps({'names': sorted(set(re.findall(\n"
+    "    r'dtx\\.[a-z]+', text))), **runtime.compile_cache_stats()}))\n")
+
+
+def test_a_cached_program_never_loads_with_names_it_was_not_traced_with(
+        tmp_path):
+    """A named scope is op metadata, which JAX's cache key leaves out by
+    default: the same program under a renamed scope would load from the cache
+    with the OLD name, and the profile's readers would find nothing
+    (PR 24 met this on the chip: the training step came back unscoped)."""
+    env = {"JAX_PLATFORMS": "cpu", "JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+    docs = []
+    for scope in ("dtx.before", "dtx.before", "dtx.after"):
+        out = _py(_SCOPE_PROBE, dict(env, PROBE_SCOPE=scope))
+        assert out.returncode == 0, out.stderr[-1500:]
+        docs.append(_tagged(out, "SCOPES"))
+    assert [d["names"] for d in docs] == [
+        ["dtx.before"], ["dtx.before"], ["dtx.after"]]
+    # the second run loaded everything it asked for; the renamed one did not
+    assert docs[1]["requests"] > 0 and docs[1]["hits"] == docs[1]["requests"]
+    assert docs[2]["hits"] < docs[2]["requests"]
+
+
 def test_local_serving_backend_reports_failed_when_the_engine_cannot_load(
         tmp_path):
     """A replica whose engine fails to load (here: no such model; on a

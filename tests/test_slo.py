@@ -230,7 +230,7 @@ def test_engine_tracing_off_observes_no_exemplars():
     from datatunerx_tpu.obs.metrics import serving_latency_histograms
 
     reg = Registry()
-    ttft, tpot, _ = serving_latency_histograms(reg)
+    ttft, tpot = serving_latency_histograms(reg)
     ttft.observe(5.0)   # what _complete does with tracing=False
     tpot.observe(1.0)
     assert ttft.exemplars() == {} and tpot.exemplars() == {}
