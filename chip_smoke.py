@@ -760,11 +760,11 @@ def child_kernels(rehearsal: bool) -> int:
                       jax.jit(gather_attention)(qm, qp, *mpool),
                       atol=2e-2, rtol=2e-2)
 
-    # ---- fused sampler at S = slots: greedy bitwise, simple exact by seed
-    def sampler(V):
-        S = SERVE_SLOTS
+    # ---- fused sampler at S = slots and at the benchmark cells' 16: greedy
+    # bitwise, simple exact by seed
+    def sampler(S, V):
         logits = normal((S, V), jnp.float32, 3.0)
-        temps = jnp.asarray([0.7, 1.0, 0.0, 1.3][:S], jnp.float32)
+        temps = jnp.asarray(([0.7, 1.0, 0.0, 1.3] * S)[:S], jnp.float32)
         top_ps = jnp.ones((S,), jnp.float32)
         keys = jnp.asarray(rng.integers(0, 2 ** 32, (S, 2), np.uint32))
         for mode in ("greedy", "simple"):
@@ -817,10 +817,11 @@ def child_kernels(rehearsal: bool) -> int:
         guarded(f"quant [{gname}]", lambda: quant(gname, D, F))
         guarded(f"lora [{gname}]", lambda: lora(gname, D))
         guarded(f"paged [{gname}]", lambda: paged(gname, H, KV, d))
-    # both models share vocab 32000 (lowering at 151936 is tier-1's:
-    # tests/test_aot_certify.py; its XLA twin alone compiles for minutes)
-    for V in ((32000,) if not rehearsal else (512,)):
-        guarded(f"fused_sample [V{V}]", lambda: sampler(V))
+    # both models' vocab 32000 and Qwen's 151936 (several tiles, the last
+    # one ragged)
+    for V in ((32000, 151936) if not rehearsal else (512,)):
+        for S in sorted({SERVE_SLOTS, 16}):
+            guarded(f"fused_sample [S{S} V{V}]", lambda: sampler(S, V))
     if not rehearsal:  # interpret-mode QLoRA steps are slow and tier-1's job
         guarded("qlora_step", qlora_step)
 

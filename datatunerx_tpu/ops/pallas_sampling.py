@@ -21,18 +21,59 @@ materialize the ``[S, vocab]`` distribution on host:
 
 Two implementations share one tile walk:
 
-  impl="kernel" — a Pallas kernel (grid ``(S, phases, vocab-tiles)``,
-      per-row SMEM carries) for greedy/simple. Engaged on real TPU
+  impl="kernel" — a Pallas kernel for greedy/simple. Engaged on real TPU
       backends; interpret mode emulates it for CPU tests.
-  impl="xla"    — a blocked XLA twin that mirrors the kernel's tile walk
-      op-for-op (same tile width, same sequential carry adds, same
-      first-max-wins / first-crossing tie rules). It is the PARITY ORACLE
-      (PR 13 pattern): greedy tokens agree with the kernel bitwise by
-      construction (max/compare are order-exact), and sampled tokens agree
-      under a fixed seed because both sides consume the same precomputed
-      per-row uniforms over the identical tile schedule — asserted by
-      tests/test_pallas_sampling.py. It is also a genuine CPU win over
-      ``_sample_jit``: no full-vocab argsort per decoded token.
+  impl="xla"    — a blocked XLA twin that runs the SAME per-tile step
+      functions (``_max_step``, ``_tile_total``, ``_first_crossing``) over
+      the same tiles in the same order. It is the PARITY ORACLE (PR 13
+      pattern): greedy tokens agree with the kernel bitwise by construction
+      (max/compare are order-exact), and sampled tokens agree under a fixed
+      seed because both sides consume the same precomputed per-row uniforms
+      and add the same f32 operands in the same order — asserted by
+      tests/test_pallas_sampling.py and, on the chip, by chip_smoke.py. It
+      is also a genuine CPU win over ``_sample_jit``: no full-vocab argsort
+      per decoded token.
+
+The walk. One grid step handles EVERY row of one wide vocabulary tile:
+grid ``(phases, tiles)``, no row axis (a grid step costs about 0.4 us
+whatever it moves; the walk this replaced took one row and 128 lanes a
+step, 57,000 steps at 16 x 151,936, and was all step cost). The logits
+operand stays ``f32[S, 1, V]`` (rows on a unit middle dim, so every row is
+contiguous in HBM and a block's trailing dims ``(1, bn)`` are legal for
+any S); each step's block is ``(S, 1, bn)`` and the kernel reads it as one
+dense ``(S, bn)`` value, rows on sublanes.
+
+  Width   ``_tile_width(rows, vp)``: the largest power-of-two multiple of
+          128 lanes whose dense f32 tile (rows rounded up to 8 sublanes)
+          fits ``_TILE_BYTES``, and no wider than the padded vocabulary.
+          Two pipeline buffers plus the handful of tile-sized temporaries
+          of the CDF scan then stay well inside Mosaic's 16 MiB scoped
+          VMEM. 16 rows -> 8,192 lanes (19 tiles a phase at 151,936, 4 at
+          32,000); 4 rows -> 16,384. It need not divide the vocabulary.
+  Ragged  the grid is ``cdiv(vp, bn)`` and the last tile hangs over the
+          end. What a block holds out of bounds is unspecified, so every
+          tile is masked by index (``where(col < vp, tile, NEG_INF)``),
+          never by arithmetic. ``_prep`` pads to a multiple of 128 only.
+  Carries vectors over the rows in VMEM scratch: ``fbuf`` = running max m,
+          normaliser Z, CDF cursor c; ``ibuf`` = argmax, sampled token,
+          found flag. The ``[S]`` tokens leave once, at the last step.
+  Phases  0: m and the first argmax (min index among a tile's maxima, a
+          strict > across tiles == ``jnp.argmax``). 1: Z as the running
+          sum of tile totals. 2: the first index whose CDF crosses u*Z.
+          Greedy runs phase 0 only.
+  Sums    a tile's total is an explicit tree: halves folded onto each
+          other down to 128 lanes (vreg-aligned adds), then a 7-step
+          rotate-and-add butterfly. Neither Mosaic nor XLA picks the
+          order, so both get the same f32 value. Phases 1 and 2 add the
+          same totals in the same order, so the cursor ends at Z exactly
+          and every u*Z < Z has a crossing tile.
+  Scan    a row's crossing lies in the one tile where c <= u*Z < c + total,
+          so the log-step prefix scan over the tile's lanes (``_tile_cumsum``,
+          13 steps at 8,192) runs only in tiles where some row crosses, at
+          most once a row. If rounding leaves no lane of that tile above
+          u*Z (the scan's last partial sum and the tree's total group their
+          adds differently), or no tile crosses at all (u*Z == Z), the row
+          falls back to its argmax.
 
 The residual/acceptance math in ``serving/speculative.py`` keeps its full
 device-resident ``q = sampling_probs(...)`` distributions (a top-k
@@ -54,10 +95,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from datatunerx_tpu.ops._pallas import interpret_default, pick_block_n
+from datatunerx_tpu.ops._pallas import interpret_default
 
 NEG_INF = -1e30
-_BLOCK_CAP = 512
+# one dense (rows, bn) f32 tile; see "Width" above
+_TILE_BYTES = 512 * 1024
+# "no lane": above every vocabulary index, so a min over lanes ignores it
+_NO_COL = 2 ** 30
 
 MODES = ("greedy", "simple", "topp")
 
@@ -77,13 +121,46 @@ def default_impl() -> str:
     return "kernel" if jax.default_backend() == "tpu" else "xla"
 
 
+def _tile_width(rows: int, vp: int) -> int:
+    """Lanes of one vocabulary tile for ``rows`` rows of a ``vp``-wide
+    (128-aligned) vocabulary: a function of the shapes alone."""
+    rows8 = -(-rows // 8) * 8
+    lanes = max(128, min(_TILE_BYTES // (4 * rows8), vp))
+    return 128 << ((lanes // 128).bit_length() - 1)
+
+
+# ------------------------------------------------- per-tile steps (shared)
+# Kernel and twin call these on the same ``[rows, bn]`` tiles with their own
+# ``roll`` (``pltpu.roll`` / ``jnp.roll``), so both add the same operands in
+# the same order. Every per-row quantity is a ``[rows, 1]`` column.
+
+def _max_step(tile, col, m, idx):
+    """Fold one tile into the running max ``m`` and its first index."""
+    tmax = jnp.max(tile, axis=1, keepdims=True)
+    targ = jnp.min(jnp.where(tile == tmax, col, _NO_COL), axis=1,
+                   keepdims=True)
+    better = tmax > m
+    return jnp.where(better, tmax, m), jnp.where(better, targ, idx)
+
+
+def _tile_total(e, roll):
+    """Row sums of one tile in an order nobody else chooses: fold halves
+    down to 128 lanes, then a rotate-and-add butterfly (each lane ends with
+    the same total, bit for bit, because f32 add commutes)."""
+    while e.shape[-1] > 128:
+        half = e.shape[-1] // 2
+        e = e[:, :half] + e[:, half:]
+    shift = 64
+    while shift:
+        e = e + roll(e, shift)
+        shift //= 2
+    return e[:, :1]
+
+
 def _tile_cumsum(e, lane, roll):
     """Inclusive prefix sum along the lanes of one ``[rows, bn]`` tile as a
     log-step shift-and-add scan (any ``bn``). Mosaic has no ``cumsum``
-    lowering; it does have a lane rotate and a masked add. Kernel and XLA
-    twin call this with their own ``roll`` so both add the same operands in
-    the same order and the CDF they compare against ``u·Z`` is the same f32
-    value."""
+    lowering; it does have a lane rotate and a masked add."""
     bn = e.shape[-1]
     shift = 1
     while shift < bn:
@@ -92,11 +169,31 @@ def _tile_cumsum(e, lane, roll):
     return e
 
 
+def _first_crossing(e, lane, col, c, thresh, roll):
+    """First vocabulary index of the tile whose CDF (cursor ``c`` plus the
+    tile's prefix sums) passes ``thresh``; ``_NO_COL`` where none does."""
+    hit = c + _tile_cumsum(e, lane, roll) > thresh
+    return jnp.min(jnp.where(hit, col, _NO_COL), axis=1, keepdims=True)
+
+
+def _cdf_pick(cross, first, idx, tok):
+    """Rows that cross in this tile take its first crossing lane (their
+    argmax where rounding left none); the others keep what they have."""
+    return jnp.where(cross, jnp.where(first < _NO_COL, first, idx), tok)
+
+
+def _emit(temps, idx, tok, found):
+    """No crossing falls back to the argmax; rows with temp <= 0 are greedy
+    regardless of the draw."""
+    return jnp.where(temps <= 0.0, idx, jnp.where(found > 0, tok, idx))
+
+
 def _prep(logits, temps, *, mode):
     """Shared pre-scale + lane-pad: both impls consume the SAME padded
     array, so scaling can never diverge between them. Padding is NEG_INF
     *after* scaling — dead lanes lose every argmax and contribute
-    ``exp(NEG_INF - m) == 0`` to the normalizer and CDF."""
+    ``exp(NEG_INF - m) == 0`` to the normalizer and CDF. The pad is to a
+    multiple of 128 and no more: the tile width need not divide it."""
     x = logits.astype(jnp.float32)
     if mode != "greedy":
         x = x / jnp.maximum(temps, 1e-6).astype(jnp.float32)[:, None]
@@ -104,158 +201,146 @@ def _prep(logits, temps, *, mode):
     vp = -(-v // 128) * 128
     if vp != v:
         x = jnp.pad(x, ((0, 0), (0, vp - v)), constant_values=NEG_INF)
-    return x, pick_block_n(vp, _BLOCK_CAP)
+    return x, _tile_width(x.shape[0], vp)
 
 
 # --------------------------------------------------------------- kernel
 
 def _sample_kernel(temps_ref, us_ref, x_ref, tok_ref, fbuf, ibuf, *,
-                   bn, nt, greedy):
-    """One (row, phase, tile) step. SMEM carries per row:
-    fbuf = [running max m, normalizer Z, CDF cursor c]
-    ibuf = [argmax, sampled token, crossing-found flag]
-    Phase 0 finds m/argmax; phase 1 accumulates Z = sum exp(x - m);
-    phase 2 finds the first index whose running cumsum crosses u·Z.
-    Greedy mode runs phase 0 only (the wrapper shrinks the grid)."""
-    i = pl.program_id(0)
-    p = pl.program_id(1)
-    t = pl.program_id(2)
-    tile = x_ref[0]  # (1, bn) f32 — row i's tile t of the [S, 1, vp] view
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+                   bn, nt, vp, greedy):
+    """One (phase, tile) step over every row. VMEM carries, ``[rows, 1]``
+    each: fbuf = [running max m, normalizer Z, CDF cursor c];
+    ibuf = [argmax, sampled token, crossing-found flag]."""
+    p = pl.program_id(0)
+    t = pl.program_id(1)
+    rows = x_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, bn), 1)
+    col = t * bn + lane
+    # the (rows, 1, bn) block read densely, rows on sublanes; the last
+    # tile's out-of-bounds lanes hold anything, so mask by index
+    tile = jnp.where(col < vp, x_ref[:, 0, :], NEG_INF)
+
+    def roll(a, k):
+        return pltpu.roll(a, k, 1)
 
     @pl.when((p == 0) & (t == 0))
-    def _init_max():
-        fbuf[0] = NEG_INF
-        ibuf[0] = 0
+    def _init():
+        fbuf[...] = jnp.zeros(fbuf.shape, jnp.float32)
+        fbuf[0] = jnp.full((rows, 1), NEG_INF, jnp.float32)
+        ibuf[...] = jnp.zeros(ibuf.shape, jnp.int32)
 
     @pl.when(p == 0)
     def _phase_max():
-        tmax = jnp.max(tile)
-        # first-max-wins inside the tile (min index among maxima) plus a
-        # strict > across tiles == jnp.argmax's first-occurrence rule
-        targ = jnp.min(jnp.where(tile == tmax, lane, bn))
-        better = tmax > fbuf[0]
-
-        @pl.when(better)
-        def _():
-            fbuf[0] = tmax
-            ibuf[0] = t * bn + targ
+        fbuf[0], ibuf[0] = _max_step(tile, col, fbuf[0], ibuf[0])
 
     if greedy:
-        @pl.when((p == 0) & (t == nt - 1))
+        @pl.when(t == nt - 1)
         def _emit_greedy():
-            tok_ref[i] = ibuf[0]
+            tok_ref[...] = ibuf[0]
         return
-
-    @pl.when((p == 1) & (t == 0))
-    def _init_z():
-        fbuf[1] = 0.0
 
     @pl.when(p == 1)
     def _phase_z():
-        fbuf[1] = fbuf[1] + jnp.sum(jnp.exp(tile - fbuf[0]))
-
-    @pl.when((p == 2) & (t == 0))
-    def _init_cdf():
-        fbuf[2] = 0.0
-        ibuf[1] = 0
-        ibuf[2] = 0
+        fbuf[1] = fbuf[1] + _tile_total(jnp.exp(tile - fbuf[0]), roll)
 
     @pl.when(p == 2)
     def _phase_cdf():
         e = jnp.exp(tile - fbuf[0])
-        cum = fbuf[2] + _tile_cumsum(
-            e, lane, lambda a, k: pltpu.roll(a, k, 1))
-        thresh = us_ref[i] * fbuf[1]
-        hit = cum > thresh
-        first = jnp.min(jnp.where(hit, lane, bn))
-        take = (first < bn) & (ibuf[2] == 0)
+        c, thresh = fbuf[2], us_ref[...] * fbuf[1]
+        c_next = c + _tile_total(e, roll)
+        cross = (ibuf[2] == 0) & (c_next > thresh)
 
-        @pl.when(take)
+        @pl.when(jnp.max(cross.astype(jnp.int32)) > 0)
         def _():
-            ibuf[1] = t * bn + first
-            ibuf[2] = 1
-        fbuf[2] = fbuf[2] + jnp.sum(e)
+            first = _first_crossing(e, lane, col, c, thresh, roll)
+            ibuf[1] = _cdf_pick(cross, first, ibuf[0], ibuf[1])
+        ibuf[2] = jnp.where(cross, 1, ibuf[2])
+        fbuf[2] = c_next
 
         @pl.when(t == nt - 1)
-        def _emit():
-            # no crossing (u·Z at/after the float tail) falls back to the
-            # argmax; rows with temp <= 0 are greedy regardless of draw
-            sampled = jnp.where(ibuf[2] == 1, ibuf[1], ibuf[0])
-            tok_ref[i] = jnp.where(temps_ref[i] <= 0.0, ibuf[0], sampled)
+        def _emit_sampled():
+            tok_ref[...] = _emit(temps_ref[...], ibuf[0], ibuf[1], ibuf[2])
 
 
 def _kernel_sample(x, temps, us, *, bn, greedy, interpret):
     s, vp = x.shape
-    nt = vp // bn
-    phases = 1 if greedy else 3
-    return pl.pallas_call(
-        functools.partial(_sample_kernel, bn=bn, nt=nt, greedy=greedy),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(s, phases, nt),
+    nt = pl.cdiv(vp, bn)
+    col_spec = pl.BlockSpec((s, 1), lambda p, t: (0, 0))
+    toks = pl.pallas_call(
+        functools.partial(_sample_kernel, bn=bn, nt=nt, vp=vp,
+                          greedy=greedy),
+        grid=(1 if greedy else 3, nt),
+        in_specs=[
+            col_spec, col_spec,
             # rows ride a unit MIDDLE dim so the block's trailing dims are
-            # (1 == array dim, lane-aligned bn): a (1, bn) window of an
-            # [S, vp] array is refused by Mosaic for every S > 1
-            in_specs=[pl.BlockSpec((1, 1, bn),
-                                   lambda i, p, t, *_: (i, 0, t))],
-            # the whole [S] token vector stays resident in SMEM across the
-            # grid (row i writes element i): a per-row (1, 1) output block
-            # is as illegal a tiling as the per-row input block was
-            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-            scratch_shapes=[
-                pltpu.SMEM((4,), jnp.float32),
-                pltpu.SMEM((4,), jnp.int32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((s,), jnp.int32),
+            # (1 == array dim, lane-aligned bn): legal for every S, and
+            # each row's bn lanes are one contiguous run in HBM
+            pl.BlockSpec((s, 1, bn), lambda p, t: (0, 0, t)),
+        ],
+        out_specs=col_spec,
+        scratch_shapes=[
+            pltpu.VMEM((3, s, 1), jnp.float32),
+            pltpu.VMEM((3, s, 1), jnp.int32),
+        ],
+        out_shape=jax.ShapeDtypeStruct((s, 1), jnp.int32),
         interpret=_interpret() if interpret is None else interpret,
         name="dtx_fused_sample",
-    )(temps.astype(jnp.float32), us.astype(jnp.float32), x[:, None, :])
+    )(temps.astype(jnp.float32)[:, None], us.astype(jnp.float32)[:, None],
+      x[:, None, :])
+    return toks[:, 0]
 
 
 # ----------------------------------------------------------- XLA oracle
 
 def _xla_sample(x, temps, us, *, bn, greedy):
-    """Blocked XLA twin: the kernel's tile walk verbatim (python loop over
-    the same bn-wide tiles, sequential carry adds, identical tie rules) —
-    the parity oracle AND the CPU fast path."""
+    """Blocked XLA twin: the kernel's tile walk verbatim (a loop over the
+    same bn-wide tiles, the same step functions, identical tie rules) —
+    the parity oracle AND the CPU fast path. The loops are ``fori_loop``s,
+    so the program's size does not grow with the number of tiles."""
     s, vp = x.shape
-    nt = vp // bn
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
-    m = jnp.full((s,), NEG_INF, jnp.float32)
-    idx = jnp.zeros((s,), jnp.int32)
-    for t in range(nt):
-        tile = x[:, t * bn:(t + 1) * bn]
-        tmax = jnp.max(tile, axis=1)
-        targ = jnp.min(jnp.where(tile == tmax[:, None], lane, bn), axis=1)
-        better = tmax > m
-        idx = jnp.where(better, t * bn + targ, idx)
-        m = jnp.where(better, tmax, m)
+    nt = pl.cdiv(vp, bn)
+    # the kernel masks its ragged last tile to NEG_INF by index; pad to the
+    # same values
+    x = jnp.pad(x, ((0, 0), (0, nt * bn - vp)), constant_values=NEG_INF)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (s, bn), 1)
+
+    def roll(a, k):
+        return jnp.roll(a, k, axis=1)
+
+    def tile_at(t):
+        return jax.lax.dynamic_slice_in_dim(x, t * bn, bn, axis=1)
+
+    def max_body(t, carry):
+        return _max_step(tile_at(t), t * bn + lane, *carry)
+
+    m, idx = jax.lax.fori_loop(
+        0, nt, max_body,
+        (jnp.full((s, 1), NEG_INF, jnp.float32), jnp.zeros((s, 1), jnp.int32)))
     if greedy:
-        return idx
-    z = jnp.zeros((s,), jnp.float32)
-    for t in range(nt):
-        tile = x[:, t * bn:(t + 1) * bn]
-        z = z + jnp.sum(jnp.exp(tile - m[:, None]), axis=1)
-    thresh = us.astype(jnp.float32) * z
-    c = jnp.zeros((s,), jnp.float32)
-    token = jnp.zeros((s,), jnp.int32)
-    found = jnp.zeros((s,), bool)
-    for t in range(nt):
-        tile = x[:, t * bn:(t + 1) * bn]
-        e = jnp.exp(tile - m[:, None])
-        cum = c[:, None] + _tile_cumsum(
-            e, lane, lambda a, k: jnp.roll(a, k, axis=1))
-        hit = cum > thresh[:, None]
-        first = jnp.min(jnp.where(hit, lane, bn), axis=1)
-        got = first < bn
-        take = got & ~found
-        token = jnp.where(take, t * bn + first, token)
-        found = found | got
-        c = c + jnp.sum(e, axis=1)
-    sampled = jnp.where(found, token, idx)
-    return jnp.where(temps.astype(jnp.float32) <= 0.0, idx, sampled)
+        return idx[:, 0]
+
+    def z_body(t, z):
+        return z + _tile_total(jnp.exp(tile_at(t) - m), roll)
+
+    z = jax.lax.fori_loop(0, nt, z_body, jnp.zeros((s, 1), jnp.float32))
+    thresh = us.astype(jnp.float32)[:, None] * z
+
+    def cdf_body(t, carry):
+        c, tok, found = carry
+        e = jnp.exp(tile_at(t) - m)
+        c_next = c + _tile_total(e, roll)
+        cross = (found == 0) & (c_next > thresh)
+        # the kernel skips the scan where no row crosses; a row that does
+        # not cross keeps its token either way
+        first = _first_crossing(e, lane, t * bn + lane, c, thresh, roll)
+        return (c_next, _cdf_pick(cross, first, idx, tok),
+                jnp.where(cross, 1, found))
+
+    _, tok, found = jax.lax.fori_loop(
+        0, nt, cdf_body,
+        (jnp.zeros((s, 1), jnp.float32), jnp.zeros((s, 1), jnp.int32),
+         jnp.zeros((s, 1), jnp.int32)))
+    return _emit(temps.astype(jnp.float32)[:, None], idx, tok, found)[:, 0]
 
 
 def _topp_sample(logits, temps, top_ps, us):

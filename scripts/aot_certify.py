@@ -291,6 +291,10 @@ ENGINE_SLOTS, ENGINE_BLOCK, ENGINE_SEQ = 4, 16, 1024
 ENGINE_QLENS = (64, 128, 192, 256) + (2, 3, 4, 5, 13)
 ENGINE_HEADS = {"tinyllama": (32, 4, 64), "llama2_7b": (32, 32, 128)}
 ENGINE_VOCABS = (32000, 151936)
+# the sampler as the benchmark's serving cells run it (16 slots; BENCHMARK.json:
+# qwen-serve-steady samples at vocab 151,936, mistral-serve-batch is greedy at
+# 32,000): (slots, vocab, mode)
+CELL_SAMPLERS = ((16, 151936, "simple"), (16, 32000, "greedy"))
 
 
 def serving_kernel_cases(sh):
@@ -329,15 +333,15 @@ def serving_kernel_cases(sh):
                     tag = "bf16"
                 cases.append(
                     (f"kernel/paged_multitoken_{tag}_{gname}_T{T}", fn, args))
-    S = ENGINE_SLOTS
-    for V in ENGINE_VOCABS:
-        for mode in ("greedy", "simple"):
-            def fn(lg, t, p, k, mode=mode):
-                return fused_sample(lg, t, p, k, mode=mode, impl="kernel")
-            cases.append((
-                f"kernel/fused_sample_{mode}_S{S}_V{V}", fn,
-                (sds((S, V), jnp.float32), sds((S,), jnp.float32),
-                 sds((S,), jnp.float32), sds((S, 2), jnp.uint32))))
+    samplers = [(ENGINE_SLOTS, V, mode) for V in ENGINE_VOCABS
+                for mode in ("greedy", "simple")]
+    for S, V, mode in samplers + list(CELL_SAMPLERS):
+        def fn(lg, t, p, k, mode=mode):
+            return fused_sample(lg, t, p, k, mode=mode, impl="kernel")
+        cases.append((
+            f"kernel/fused_sample_{mode}_S{S}_V{V}", fn,
+            (sds((S, V), jnp.float32), sds((S,), jnp.float32),
+             sds((S,), jnp.float32), sds((S, 2), jnp.uint32))))
     return cases
 
 

@@ -128,12 +128,15 @@ def test_serving_kernels_lower_through_mosaic_at_engine_geometry():
     names = set(doc["done"])
     # the default engine's shapes are all there: prefill chunk = 256, the
     # widest chain verify (spec_k 4 -> 5 columns), both KV dtypes, and the
-    # sampler's two kernel modes at S = 4
+    # sampler's two kernel modes at S = 4 (not a multiple of 8 sublanes) and
+    # at the benchmark cells' 16 slots
     for want in ("kernel/paged_multitoken_bf16_tinyllama_T256",
                  "kernel/paged_multitoken_int8_kv_tinyllama_T256",
                  "kernel/paged_multitoken_bf16_tinyllama_T5",
                  "kernel/fused_sample_greedy_S4_V32000",
-                 "kernel/fused_sample_simple_S4_V151936"):
+                 "kernel/fused_sample_simple_S4_V151936",
+                 "kernel/fused_sample_simple_S16_V151936",
+                 "kernel/fused_sample_greedy_S16_V32000"):
         assert want in names, (want, sorted(names))
     # each kernel keeps the name its pallas_call was given through Mosaic:
     # the device trace's readers (benchmarks/scope_readers.py) find it by that
