@@ -487,14 +487,17 @@ def phase_server(rehearsal: bool, budget_s: float) -> dict:
 
 # ------------------------------------------------------------------- kernels
 
-def phase_kernels(rehearsal: bool, budget_s: float) -> dict:
-    _say("== kernels")
-    argv = [sys.executable, os.path.abspath(__file__), "--child-kernels"]
+def phase_kernels(rehearsal: bool, budget_s: float, name: str = "kernels") -> dict:
+    """A child of this script that prints PASS/FAIL verdicts: ``kernels``
+    (every Pallas kernel against its oracle) or ``hybrid`` (a model of several
+    layer kinds served by the batched engine, against the plain reference)."""
+    _say(f"== {name}")
+    argv = [sys.executable, os.path.abspath(__file__), f"--child-{name}"]
     if rehearsal:
         argv.append("--cpu-rehearsal")
-    child = Child("kernels", argv, _child_env(rehearsal))
+    child = Child(name, argv, _child_env(rehearsal))
     try:
-        info = _check_runtime(child, "kernels", rehearsal)
+        info = _check_runtime(child, name, rehearsal)
         rc = child.wait(budget_s - (time.monotonic() - child.t0))
     finally:
         child.stop()
@@ -506,11 +509,84 @@ def phase_kernels(rehearsal: bool, budget_s: float) -> dict:
         _report_cache(child, stats)
     failed = [ln for ln in verdicts if ln.startswith("FAIL ")]
     if rc != 0 or failed or not verdicts:
-        raise SmokeFailure(f"kernels: exited {rc}, {len(failed)} FAIL of "
+        raise SmokeFailure(f"{name}: exited {rc}, {len(failed)} FAIL of "
                            f"{len(verdicts)}\n{child.tail()}")
-    _say(f"  kernels: PASS {len(verdicts)}/{len(verdicts)} in "
+    _say(f"  {name}: PASS {len(verdicts)}/{len(verdicts)} in "
          f"{time.monotonic() - child.t0:.0f}s wall")
     return info
+
+
+def child_hybrid(rehearsal: bool) -> int:
+    """Runs IN the chip-holding child: the batched engine on the debug preset
+    of a model with window and global attention layers and sparse experts
+    (``preset:debug-hybrid``), two adapters, paged pool. Served greedy tokens
+    are held against the plain float32 reference (benchmarks/reference/
+    mimo_v2.py, which imports nothing of the program) as logits."""
+    import dataclasses
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    from reference import mimo_v2 as reference
+
+    from datatunerx_tpu.serving.adapters import make_adapter_checkpoint
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+    from datatunerx_tpu.utils import runtime
+
+    runtime.startup("hybrid")
+    ok = True
+
+    def verdict(name, passed, detail):
+        nonlocal ok
+        ok &= bool(passed)
+        print(f"{'PASS' if passed else 'FAIL'} {name} {detail}", flush=True)
+
+    work = tempfile.mkdtemp(prefix="smoke_hybrid_")
+    adapters = {f"ad{i}": make_adapter_checkpoint(
+        f"{work}/ad{i}", "preset:debug-hybrid", seed=20 + i, rank=4) for i in range(2)}
+    eng = BatchedEngine("preset:debug-hybrid", adapters=adapters, slots=4, decode_chunk=4,
+                        kv_block_size=8, kv_blocks=96, max_seq_len=256, prefill_chunk=64)
+    try:
+        verdict("hybrid/decode_path", eng.decode_path == "gather", eng.decode_path)
+        rng = np.random.default_rng(1)
+        work_items = []
+        for name in ("", "ad0", "ad1", "ad0"):
+            prompt = rng.integers(10, 3000, size=int(rng.integers(40, 160))).tolist()
+            work_items.append((prompt, name, eng.submit(prompt, max_new_tokens=24, adapter=name)))
+        mc = dataclasses.asdict(eng.cfg)
+        gaps = []
+        for prompt, name, req in work_items:
+            if not req.done.wait(600) or req.error:
+                verdict(f"hybrid/serve[{name or 'base'}]", False, str(req.error))
+                continue
+            tokens = list(prompt) + list(req.tokens)
+            rows = list(range(len(prompt) - 1, len(tokens) - 1))
+            lora = None
+            if name:
+                e = eng.adapter_ids[name]
+                lora = jax.tree_util.tree_map(lambda a: a[:, e], eng.lora_stack[0]["layers"])
+            ref = reference.sequence_logits(
+                eng.params, mc, tokens, rows, lora,
+                float(eng.lora_stack[1][eng.adapter_ids[name]]) if name else 0.0)
+            got = jnp.take_along_axis(ref, jnp.asarray(req.tokens)[:, None], axis=-1)[:, 0]
+            gaps.append(np.asarray(jnp.max(ref, axis=-1) - got))
+        gaps = np.concatenate(gaps) if gaps else np.asarray([np.inf])
+        # bf16 program against the float32 reference at debug widths: a served
+        # token may trail the reference's best by rounding, never by a logit
+        verdict("hybrid/served_vs_reference", float(gaps.max()) <= 0.05,
+                f"gap_max {gaps.max():.4f} gap_mean {gaps.mean():.5f} tokens {gaps.size}")
+        stats = eng.moe_stats
+        verdict("hybrid/expert_counters",
+                stats["decode_local_rows"] > 0 and stats["prefill_local_rows"] > 0
+                and 0 < stats["decode_experts_hit"] <= 4 * stats["decode_layer_steps"],
+                json.dumps(stats))
+    finally:
+        eng.close()
+        shutil.rmtree(work, ignore_errors=True)
+    return 0 if ok else 1
 
 
 def child_kernels(rehearsal: bool) -> int:
@@ -839,14 +915,16 @@ def main(argv=None) -> int:
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="debug-size run on the CPU to debug THIS SCRIPT; "
                          "proves nothing about the chip")
-    ap.add_argument("--phases", default="trainer,server,kernels",
-                    help="comma list out of trainer,server,kernels")
+    ap.add_argument("--phases", default="trainer,server,kernels,hybrid",
+                    help="comma list out of trainer,server,kernels,hybrid")
     ap.add_argument("--mesh", action="append", default=None,
                     help="trainer --mesh (e.g. dp=1,fsdp=4,tp=1); repeat to "
                          "run the trainer once per mesh. 'auto' (the "
                          "default) is the trainer's own choice: every local "
                          "device on dp")
     ap.add_argument("--child-kernels", action="store_true",
+                    help=argparse.SUPPRESS)
+    ap.add_argument("--child-hybrid", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
@@ -858,12 +936,14 @@ def main(argv=None) -> int:
             return 2
     if args.child_kernels:
         return child_kernels(args.cpu_rehearsal)
+    if args.child_hybrid:
+        return child_hybrid(args.cpu_rehearsal)
     if not os.path.isdir(os.path.join(REPO, "datatunerx_tpu")):
         print("chip_smoke: no datatunerx_tpu package beside this script",
               file=sys.stderr)
         return 2
     phases = [p.strip() for p in args.phases.split(",") if p.strip()]
-    unknown = set(phases) - {"trainer", "server", "kernels"}
+    unknown = set(phases) - {"trainer", "server", "kernels", "hybrid"}
     if unknown:
         ap.error(f"unknown phases {sorted(unknown)}")
 
@@ -895,6 +975,9 @@ def main(argv=None) -> int:
         runs.append(("server", lambda left: phase_server(rehearsal, left)))
     if "kernels" in phases:
         runs.append(("kernels", lambda left: phase_kernels(rehearsal, left)))
+    if "hybrid" in phases:
+        runs.append(("hybrid",
+                     lambda left: phase_kernels(rehearsal, left, "hybrid")))
 
     devices, failed = [], []
     try:

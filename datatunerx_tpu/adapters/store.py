@@ -31,7 +31,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from datatunerx_tpu.models.config import ModelConfig
-from datatunerx_tpu.models.lora import DEFAULT_TARGETS, LORA_TARGETS, target_dims
+from datatunerx_tpu.models.lora import (
+    DEFAULT_TARGETS,
+    LORA_TARGETS,
+    adapter_leaves,
+    group_tree,
+    lora_groups,
+)
 
 
 class AdapterRankError(ValueError):
@@ -45,7 +51,8 @@ class AdapterTargetError(ValueError):
 
 def adapter_rank(layers: dict) -> int:
     """The (max, across targets) rank of a loaded adapter layer tree."""
-    return max(np.asarray(leaf["a"]).shape[-1] for leaf in layers.values())
+    return max(np.asarray(leaf["a"]).shape[-1]
+               for leaf in adapter_leaves(layers))
 
 
 def validate_adapter(layers: dict, rank_max: int,
@@ -56,7 +63,10 @@ def validate_adapter(layers: dict, rank_max: int,
     label = f"adapter {name!r}" if name else "adapter"
     if not layers:
         raise ValueError(f"{label}: empty lora layer tree")
-    extra = sorted(set(layers) - set(targets))
+    named = set()  # targets, whether the tree is flat or one group per run
+    for key, value in layers.items():
+        named |= {key} if "a" in value else set(value)
+    extra = sorted(named - set(targets))
     if extra:
         raise AdapterTargetError(
             f"{label}: targets {extra} not in the pool's target set "
@@ -93,14 +103,25 @@ class AdapterStore:
         self.pool_slots = int(pool_slots)  # usable slots, device idx 1..P
         self.rank_max = int(rank_max)
         self.targets = targets
-        L, E = cfg.num_layers, self.pool_slots + 1  # + base zero slot 0
-        self._buffers: Dict[str, Dict[str, jnp.ndarray]] = {}
-        for t in targets:
-            d_in, d_out = target_dims(cfg, t)
-            self._buffers[t] = {
-                "a": jnp.zeros((L, E, d_in, rank_max), jnp.float32),
-                "b": jnp.zeros((L, E, rank_max, d_out), jnp.float32),
-            }
+        E = self.pool_slots + 1  # + base zero slot 0
+        # one buffer per (group of like layers, target): a model whose layers
+        # are all of one kind has the single group None (models/lora.py)
+        self._groups = lora_groups(cfg)
+        self._buffers: Dict[tuple, Dict[str, jnp.ndarray]] = {}
+        for gkey, L, dims in self._groups:
+            for t in targets:
+                if t not in dims:
+                    continue
+                d_in, d_out = dims[t]
+                self._buffers[(gkey, t)] = {
+                    "a": jnp.zeros((L, E, d_in, rank_max), jnp.float32),
+                    "b": jnp.zeros((L, E, rank_max, d_out), jnp.float32),
+                }
+        held = {t for _, t in self._buffers}
+        if held != set(targets):
+            raise ValueError(
+                f"lora targets {sorted(set(targets) - held)} exist in no layer "
+                f"of model {cfg.name!r}")
         self._scales = jnp.zeros((E,), jnp.float32)
         self.tree: Tuple[dict, jnp.ndarray] = self._republish()
         # Warm the clear() update programs now (clearing slot 1 is a no-op
@@ -126,7 +147,10 @@ class AdapterStore:
 
     # ------------------------------------------------------------ mutations
     def _republish(self):
-        layers = {t: dict(buf) for t, buf in self._buffers.items()}
+        layers: dict = {}
+        for (gkey, t), buf in self._buffers.items():
+            group = layers if gkey is None else layers.setdefault(gkey, {})
+            group[t] = dict(buf)
         self.tree = ({"layers": layers}, self._scales)
         return self.tree
 
@@ -138,12 +162,12 @@ class AdapterStore:
         self._check_slot(slot)
         rank = validate_adapter(layers, self.rank_max, self.targets,
                                 name=name)
-        L = self.cfg.num_layers
-        for t in self.targets:
-            buf = self._buffers[t]
-            if t in layers:
-                ar = np.asarray(layers[t]["a"], np.float32)  # [L, d_in, r]
-                br = np.asarray(layers[t]["b"], np.float32)  # [L, r, d_out]
+        for (gkey, t), buf in self._buffers.items():
+            L = buf["a"].shape[0]
+            given = group_tree(layers, gkey)
+            if t in given:
+                ar = np.asarray(given[t]["a"], np.float32)  # [L, d_in, r]
+                br = np.asarray(given[t]["b"], np.float32)  # [L, r, d_out]
                 if ar.shape[0] != L:
                     raise ValueError(
                         f"adapter {name!r}: {t} has {ar.shape[0]} layers, "
@@ -185,9 +209,10 @@ def hbm_bytes(cfg: ModelConfig, pool_slots: int, rank_max: int,
               targets: Sequence[str] = DEFAULT_TARGETS) -> int:
     """Pool HBM for a geometry WITHOUT building it — the operator-facing
     sizing helper the README table uses."""
-    L, E = cfg.num_layers, pool_slots + 1
+    E = pool_slots + 1
     total = E * 4  # scales float32
-    for t in sorted(set(targets)):
-        d_in, d_out = target_dims(cfg, t)
-        total += 4 * L * E * rank_max * (d_in + d_out)
+    for _, L, dims in lora_groups(cfg):
+        for t in sorted(set(targets) & set(dims)):
+            d_in, d_out = dims[t]
+            total += 4 * L * E * rank_max * (d_in + d_out)
     return total

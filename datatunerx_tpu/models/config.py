@@ -51,6 +51,32 @@ class ModelConfig:
     # the gather oracle). Resolved by the serving engine from its
     # --paged_kernel auto|on|off flag; training never sets it.
     paged_kernel: bool = False
+    # ---- layers of different kinds in one model (models/hybrid.py). Every
+    # default is today's single kind: all layers attend alike (the fields
+    # above) and feed forward through one dense SwiGLU. ``layer_types`` names
+    # each layer's attention ("global" | "window") and ``ffn_types`` its
+    # feed-forward ("dense" | "experts"); a model that sets either is run by
+    # runs of like layers (``layer_runs``), each stacked and scanned. The
+    # fields above keep their published meaning for such a model:
+    # num_kv_heads/rope_theta are the global layers', sliding_window the
+    # window layers' window.
+    layer_types: Optional[tuple] = None
+    ffn_types: Optional[tuple] = None
+    v_head_dim: Optional[int] = None  # value/output head width; head_dim if None
+    partial_rotary_factor: float = 1.0  # RoPE on the first int(head_dim*f) dims
+    attention_value_scale: float = 1.0  # v <- scale * v before cache and product
+    window_num_kv_heads: Optional[int] = None  # KV heads of window layers
+    window_rope_theta: Optional[float] = None
+    window_sink: bool = False  # learned per-head sink logit in window layers
+    # sparse experts: the router scores all ``experts_total``; this chip holds
+    # ``experts_held`` of them, from ``first_held`` on, and computes their part
+    experts_total: int = 0
+    experts_held: int = 0
+    first_held: int = 0
+    experts_per_token: int = 0
+    expert_intermediate_size: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -58,6 +84,21 @@ class ModelConfig:
         assert self.num_heads % self.num_kv_heads == 0
         if self.rope_scaling_type is not None:
             assert self.rope_scaling_type in ("linear", "dynamic"), self.rope_scaling_type
+        for name in ("layer_types", "ffn_types"):
+            value = getattr(self, name)
+            if value is not None:  # a list from JSON: keep the config hashable
+                assert len(value) == self.num_layers, (name, len(value), self.num_layers)
+                object.__setattr__(self, name, tuple(value))
+        if self.ffn_types is not None and "experts" in self.ffn_types:
+            assert 0 < self.experts_held <= self.experts_total
+            assert 0 <= self.first_held <= self.experts_total - self.experts_held
+            assert 0 < self.experts_per_token <= self.experts_total
+            assert self.expert_intermediate_size > 0
+
+    @property
+    def hybrid(self) -> bool:
+        """Layers of more than one kind: run by models/hybrid.py."""
+        return self.layer_types is not None or self.ffn_types is not None
 
     @property
     def q_dim(self) -> int:
@@ -66,6 +107,89 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """One kind of attention layer: what it projects, rotates, caches, sees."""
+    name: str  # "global" | "window": also names its KV pool (k_<name>, v_<name>)
+    num_kv_heads: int
+    head_dim: int  # q and k
+    v_head_dim: int  # v and the attention output
+    rope_theta: float
+    rotary_dim: int
+    window: Optional[int]
+    sink: bool
+    value_scale: float
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerRun:
+    """``count`` consecutive layers of one kind, stacked and scanned together.
+    ``kind_start`` is where they lie among the layers of their attention kind
+    (the layer axis of that kind's KV pool)."""
+    attn: AttentionKind
+    ffn: str  # "dense" | "experts"
+    count: int
+    kind_start: int
+
+
+def attention_kinds(cfg: ModelConfig) -> dict:
+    """{name: AttentionKind} of the kinds this model has, global first."""
+    types = cfg.layer_types or (
+        ("window" if cfg.sliding_window else "global",) * cfg.num_layers)
+    rotary = int(cfg.head_dim * cfg.partial_rotary_factor)  # dtxlint: disable=DTX001 — config scalars, host only
+    common = dict(head_dim=cfg.head_dim, v_head_dim=cfg.v_head_dim or cfg.head_dim,
+                  rotary_dim=rotary - rotary % 2, value_scale=cfg.attention_value_scale)
+    kinds = {}
+    if "global" in types:
+        kinds["global"] = AttentionKind(
+            name="global", num_kv_heads=cfg.num_kv_heads, rope_theta=cfg.rope_theta,
+            window=None, sink=False, **common)
+    if "window" in types:
+        kinds["window"] = AttentionKind(
+            name="window", num_kv_heads=cfg.window_num_kv_heads or cfg.num_kv_heads,
+            rope_theta=cfg.window_rope_theta or cfg.rope_theta,
+            window=cfg.sliding_window, sink=cfg.window_sink, **common)
+    return kinds
+
+
+def layer_runs(cfg: ModelConfig) -> tuple:
+    """The model as runs of like layers, in order: the one description that
+    ``forward``, the cache constructors, the adapters and the estimators read."""
+    kinds = attention_kinds(cfg)
+    types = cfg.layer_types or (next(iter(kinds)),) * cfg.num_layers
+    ffns = cfg.ffn_types or ("dense",) * cfg.num_layers
+    runs, seen = [], {name: 0 for name in kinds}
+    for i, (t, f) in enumerate(zip(types, ffns)):
+        if t not in kinds or f not in ("dense", "experts"):
+            raise ValueError(f"layer {i}: unknown kind {t!r}/{f!r}")
+        last = runs[-1] if runs else None
+        if last is not None and last.attn.name == t and last.ffn == f:
+            runs[-1] = dataclasses.replace(last, count=last.count + 1)
+        else:
+            runs.append(LayerRun(attn=kinds[t], ffn=f, count=1,
+                                 kind_start=seen[t]))
+        seen[t] += 1
+    return tuple(runs)
+
+
+def kind_layers(cfg: ModelConfig) -> dict:
+    """{attention kind name: how many layers are of it} (a KV pool's layer axis)."""
+    out = {}
+    for run in layer_runs(cfg):
+        out[run.attn.name] = out.get(run.attn.name, 0) + run.count
+    return out
+
+
+def refuse_hybrid(cfg: ModelConfig, what: str) -> None:
+    """One clear message from every entry that handles only the single-kind
+    decoder: a model with layers of several kinds is served, not ``what``."""
+    if cfg.hybrid:
+        raise NotImplementedError(
+            f"model {cfg.name!r} has layers of several kinds (window and global "
+            f"attention, sparse experts): it is served by the batched engine, "
+            f"and {what} does not handle it yet")
 
 
 PRESETS = {
@@ -103,6 +227,20 @@ PRESETS = {
         intermediate_size=13696, num_layers=40, num_heads=40, num_kv_heads=40,
         max_seq_len=8192, rope_theta=1_000_000.0, attention_bias=True,
         rms_norm_eps=1e-6,
+    ),
+    # Debug size of a model with window and global attention layers of their
+    # own KV geometry and sparse experts of which this chip holds a share:
+    # tests and the CPU smoke of the serving path (models/hybrid.py).
+    "debug-hybrid": ModelConfig(
+        name="debug-hybrid", vocab_size=512, hidden_size=64, intermediate_size=128,
+        num_layers=5, num_heads=4, num_kv_heads=1, head_dim=24, v_head_dim=16,
+        max_seq_len=512, rope_theta=1e7, sliding_window=24,
+        partial_rotary_factor=0.334, attention_value_scale=0.707,
+        layer_types=("global", "window", "window", "window", "global"),
+        ffn_types=("dense", "experts", "experts", "experts", "experts"),
+        window_num_kv_heads=2, window_rope_theta=1e4, window_sink=True,
+        experts_total=8, experts_held=4, first_held=0, experts_per_token=2,
+        expert_intermediate_size=32,
     ),
     "qwen1.5-7b": ModelConfig(
         name="qwen1.5-7b", vocab_size=151936, hidden_size=4096,

@@ -57,6 +57,10 @@ def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Params:
+    if cfg.hybrid:  # layers of several kinds: models/hybrid.py
+        from datatunerx_tpu.models import hybrid
+
+        return hybrid.init_params(cfg, key, dtype=dtype)
     keys = jax.random.split(key, 16)
     D, F, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
 
@@ -154,6 +158,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
     ``quantize="int8"`` stores k/v as int8 with a per-vector (over head_dim)
     scale — half the cache HBM of bf16, so double the slot × context budget
     for serving; dequantized on read inside the same program."""
+    if cfg.hybrid:  # a KV pool per attention kind: models/hybrid.py
+        from datatunerx_tpu.models import hybrid
+
+        return hybrid.init_cache(cfg, batch, max_len, dtype=dtype,
+                                 per_slot=per_slot, quantize=quantize)
     L = cfg.num_layers
     shape = (L, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
     cache = {
@@ -239,6 +248,23 @@ def forward(
     to before the parameter existed."""
     if skip_logits and not return_hidden:
         raise ValueError("skip_logits without return_hidden returns nothing")
+    if cfg.hybrid:
+        # the one dispatch on the layer description: a model whose layers are
+        # of several kinds is run by models/hybrid.py (inference only)
+        from datatunerx_tpu.models import hybrid
+
+        unsupported = {"segment_ids": segment_ids, "dropout_rng": dropout_rng,
+                       "window_mask": window_mask, "window_start": window_start}
+        given = sorted(k for k, v in unsupported.items() if v is not None)
+        if given or lora_dropout or neftune_alpha:
+            raise NotImplementedError(
+                f"model {cfg.name!r} has layers of several kinds and is served, "
+                f"not trained: {given or 'dropout/noise'} is not handled for it")
+        return hybrid.forward(
+            params, tokens, cfg, positions=positions,
+            attention_mask=attention_mask, cache=cache, lora=lora,
+            lora_adapter_idx=lora_adapter_idx, compute_dtype=compute_dtype,
+            return_hidden=return_hidden, skip_logits=skip_logits)
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))

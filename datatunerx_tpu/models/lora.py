@@ -48,6 +48,42 @@ def target_dims(cfg: ModelConfig, name: str) -> tuple[int, int]:
     }[name]
 
 
+def lora_groups(cfg: ModelConfig) -> list:
+    """The groups of layers whose adapter leaves are stacked together, as
+    ``[(key, layers, {target: (d_in, d_out)})]``. A model whose layers are all
+    of one kind is one group with key ``None`` (tree ``layers.<target>``, the
+    only layout there was); a model of several kinds has one group per run of
+    like layers (tree ``layers.<run>.<target>``), each with its own geometry
+    (``v_proj`` is as wide as that run's KV heads). Experts take no adapter."""
+    if not cfg.hybrid:
+        return [(None, cfg.num_layers,
+                 {t: target_dims(cfg, t) for t in LORA_TARGETS})]
+    from datatunerx_tpu.models.config import layer_runs
+    from datatunerx_tpu.models.hybrid import attn_dims, run_key
+
+    D, F = cfg.hidden_size, cfg.intermediate_size
+    groups = []
+    for i, run in enumerate(layer_runs(cfg)):
+        dims = dict(attn_dims(cfg, run.attn))
+        if run.ffn == "dense":
+            dims.update(gate_proj=(D, F), up_proj=(D, F), down_proj=(F, D))
+        groups.append((run_key(i), run.count, dims))
+    return groups
+
+
+def group_tree(layers: dict, key):
+    """One group's ``{target: {a, b}}`` of an adapter's ``layers`` tree."""
+    return layers if key is None else layers.get(key, {})
+
+
+def adapter_leaves(layers: dict) -> list:
+    """Every ``{a, b}`` leaf pair of an adapter's ``layers`` tree, whichever layout."""
+    out = []
+    for value in layers.values():
+        out.extend([value] if "a" in value else list(value.values()))
+    return out
+
+
 def lora_scaling(alpha: float, rank: int) -> float:
     return float(alpha) / float(rank)
 
@@ -62,17 +98,19 @@ def init_lora_params(
     for t in targets:
         if t not in LORA_TARGETS:
             raise ValueError(f"invalid lora target {t!r}; choices: {LORA_TARGETS}")
-    L = cfg.num_layers
     layers = {}
-    for i, t in enumerate(sorted(set(targets))):
-        d_in, d_out = target_dims(cfg, t)
-        # kaiming-uniform(a=sqrt(5)) over fan_in, like torch Linear / peft LoRA A:
-        # bound = sqrt(6 / ((1 + a^2) * fan_in)) = 1 / sqrt(fan_in)
-        bound = 1.0 / math.sqrt(d_in)
-        a = jax.random.uniform(
-            jax.random.fold_in(key, i), (L, d_in, rank), jnp.float32, -bound, bound
-        ).astype(dtype)
-        layers[t] = {"a": a, "b": jnp.zeros((L, rank, d_out), dtype)}
+    for g, (gkey, L, dims) in enumerate(lora_groups(cfg)):
+        group = layers if gkey is None else layers.setdefault(gkey, {})
+        for i, t in enumerate(sorted(set(targets) & set(dims))):
+            d_in, d_out = dims[t]
+            # kaiming-uniform(a=sqrt(5)) over fan_in, like torch Linear / peft LoRA A:
+            # bound = sqrt(6 / ((1 + a^2) * fan_in)) = 1 / sqrt(fan_in)
+            bound = 1.0 / math.sqrt(d_in)
+            k = jax.random.fold_in(key, i) if gkey is None else \
+                jax.random.fold_in(jax.random.fold_in(key, 7919 + g), i)
+            a = jax.random.uniform(
+                k, (L, d_in, rank), jnp.float32, -bound, bound).astype(dtype)
+            group[t] = {"a": a, "b": jnp.zeros((L, rank, d_out), dtype)}
     return {"layers": layers}
 
 
@@ -82,18 +120,29 @@ def num_lora_params(lora_params) -> int:
 
 def merge_lora(params, lora_params, scaling: float):
     """Fold adapters into base kernels: W' = W + A·B·scaling (per layer)."""
-    layers = dict(params["layers"])
-    for t, ab in lora_params["layers"].items():
-        delta = jnp.einsum(
-            "lir,lro->lio",
-            ab["a"].astype(jnp.float32),
-            ab["b"].astype(jnp.float32),
-        ) * scaling
-        proj = dict(layers[t])
-        proj["kernel"] = (proj["kernel"].astype(jnp.float32) + delta).astype(
-            layers[t]["kernel"].dtype
-        )
-        layers[t] = proj
+
+    def fold(layers, tree):  # one group of like layers
+        layers = dict(layers)
+        for t, ab in tree.items():
+            delta = jnp.einsum(
+                "lir,lro->lio",
+                ab["a"].astype(jnp.float32),
+                ab["b"].astype(jnp.float32),
+            ) * scaling
+            proj = dict(layers[t])
+            proj["kernel"] = (proj["kernel"].astype(jnp.float32) + delta).astype(
+                layers[t]["kernel"].dtype
+            )
+            layers[t] = proj
+        return layers
+
+    given = lora_params["layers"]
+    if all("a" in leaf for leaf in given.values()):
+        layers = fold(params["layers"], given)
+    else:  # one group per run of like layers: layers.<run>.<target>
+        layers = dict(params["layers"])
+        for gkey, tree in given.items():
+            layers[gkey] = fold(layers[gkey], tree)
     out = dict(params)
     out["layers"] = layers
     return out

@@ -353,6 +353,45 @@ def spec_accept_len_histogram(registry: Registry) -> Histogram:
         "bonus token).", buckets=(0, 1, 2, 3, 4, 6, 8, 12, 16))
 
 
+def export_moe_stats(registry: Registry, engine) -> None:
+    """The expert layers' counts and the window layers' stranded blocks, as
+    the serving server restates them at scrape time (declared every scrape:
+    stable series on an engine whose model has neither). The counts are
+    running sums since the engine started, restated as gauges."""
+    rows = registry.gauge(
+        "dtx_serving_moe_local_rows",
+        "(token, expert) rows routed to the experts this chip holds, summed "
+        "over expert layers, by phase (decode / prefill).")
+    hit = registry.gauge(
+        "dtx_serving_moe_experts_hit",
+        "Held experts that got at least one row, summed over expert layers "
+        "and steps, by phase.")
+    most = registry.gauge(
+        "dtx_serving_moe_max_rows",
+        "Most rows on one held expert, summed over expert layers and steps, "
+        "by phase.")
+    steps = registry.gauge(
+        "dtx_serving_moe_layer_steps",
+        "Expert-layer executions (steps times expert layers), by phase.")
+    behind = registry.gauge(
+        "dtx_serving_kv_behind_window_bytes",
+        "Bytes of the window layers' KV pool held by blocks that no later "
+        "query can see (they stay allocated until the request ends).")
+    for m in (rows, hit, most, steps, behind):
+        m.clear()
+    stats = getattr(engine, "moe_stats", None) or {}
+    for phase in ("decode", "prefill"):
+        if f"{phase}_local_rows" in stats:
+            label = {"phase": phase}
+            rows.set(stats[f"{phase}_local_rows"], label)
+            hit.set(stats[f"{phase}_experts_hit"], label)
+            most.set(stats[f"{phase}_max_rows"], label)
+            steps.set(stats[f"{phase}_layer_steps"], label)
+    window_fn = getattr(engine, "kv_window_stats", None)
+    window = window_fn() if callable(window_fn) else None
+    behind.set(window["behind_bytes"] if window else 0)
+
+
 # ------------------------------------------------------------ process plumbing
 
 _PROCESS_START = time.monotonic()

@@ -100,6 +100,11 @@ def check_admission(
     cfg = resolve_model_config(model_path, overrides)
     if cfg is None:
         return None
+    if cfg.hybrid:
+        # no footprint is estimated for it: the trainer refuses it outright
+        return (f"model {cfg.name!r} has layers of several kinds (window and "
+                "global attention, sparse experts): it is served by the "
+                "batched engine, and the trainer does not handle it yet", {})
 
     from datatunerx_tpu.parallel.memory import check_fits
     from datatunerx_tpu.training.train_lib import TrainConfig

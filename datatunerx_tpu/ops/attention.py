@@ -105,8 +105,14 @@ def xla_attention(
     k: jnp.ndarray,
     v: jnp.ndarray,
     bias: jnp.ndarray,
+    sink: jnp.ndarray | None = None,  # [H] one learned logit per head
 ) -> jnp.ndarray:
-    """Reference attention: f32 softmax, GQA via reshape. Returns [B, T, H, d]."""
+    """Reference attention: f32 softmax, GQA via reshape. q and k share a
+    head width, v may have another: returns [B, T, H, v's width].
+
+    ``sink`` is one more column of every row's softmax, ``p = softmax([s_i,:,
+    sink_h])``, dropped before ``p V``: the rows of ``p`` then sum to less
+    than one."""
     B, T, H, d = q.shape
     S, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -119,9 +125,15 @@ def xla_attention(
         logits = logits + bias4[:, :, None, :, :]
     else:
         logits = logits + bias4.reshape(B, KV, G, T, S)
-    probs = jax.nn.softmax(logits, axis=-1)
+    if sink is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        col = sink.astype(jnp.float32).reshape(1, KV, G, 1, 1)
+        top = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), col)
+        e = jnp.exp(logits - top)
+        probs = e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(col - top))
     out = jnp.einsum("bkgts,bskd->btkgd", probs.astype(v.dtype), v)
-    return out.reshape(B, T, H, d)
+    return out.reshape(B, T, H, v.shape[-1])
 
 
 # ------------------------------------------------------- KV cache interface

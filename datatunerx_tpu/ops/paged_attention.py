@@ -176,12 +176,27 @@ def blocks_for_depth(depth: int, block_size: int, overshoot: int = 0,
     return -(-total // block_size)
 
 
+def kv_leaf_keys(cache: Dict) -> List[str]:
+    """The cache's KV leaves: ``k``/``v`` (and the int8 ``k_scale``/
+    ``v_scale``) of a model whose layers are all of one kind, or one
+    ``k_<kind>``/``v_<kind>`` pool per attention kind (models/hybrid.py).
+    Every one is laid out ``[layers, blocks | rows, offset | lane, ...]``, so
+    whatever moves a row (insert, extract, trim, copy-on-write, the migration
+    wire) moves each alike; ``len``, ``pos`` and ``block_tables`` are shared."""
+    return [key for key in cache if key[:1] in ("k", "v")]
+
+
 def init_paged_cache(cfg, slots: int, num_blocks: int, block_size: int,
                      blocks_per_slot: int, dtype=jnp.bfloat16,
                      quantize: Optional[str] = None) -> Dict:
     """Block-pool KV cache. ``block_tables`` is ``[slots, blocks_per_slot]``
     int32 (-1 = unallocated); ``len`` is the per-slot linear write cursor;
     ``pos`` records each written token's rope position per (block, offset)."""
+    if cfg.hybrid:
+        from datatunerx_tpu.models.hybrid import init_paged_cache as init
+
+        return init(cfg, slots, num_blocks, block_size, blocks_per_slot,
+                    dtype=dtype, quantize=quantize)
     L = cfg.num_layers
     shape = (L, num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
     cache: Dict = {
@@ -204,7 +219,7 @@ def init_paged_cache(cfg, slots: int, num_blocks: int, block_size: int,
 
 def paged_view_width(cache: Dict) -> int:
     """Linear width of the gathered per-slot view (= dense-row equivalent)."""
-    return cache["block_tables"].shape[1] * cache["k"].shape[2]
+    return cache["block_tables"].shape[1] * cache["pos"].shape[1]
 
 
 def _write_targets(tables: jnp.ndarray, lens: jnp.ndarray, T: int,
@@ -240,6 +255,34 @@ def _gather_tables(tables: jnp.ndarray) -> jnp.ndarray:
     """Table with -1 entries clamped to block 0 (gather must stay in
     bounds; the garbage it reads is masked via sentinel positions)."""
     return jnp.where(tables >= 0, tables, 0)
+
+
+def window_tables(tables: jnp.ndarray, lens: jnp.ndarray, T: int,
+                  window: int, block_size: int) -> jnp.ndarray:
+    """Of each slot's block table, the columns that can hold a key visible to
+    some query of this step through a sliding ``window``: the step's queries
+    lie at linear indices ``len .. len+T-1`` and a row's pads lie at its
+    left, so linear index and position differ by a constant per row and the
+    visible keys lie in ``(len - window, len + T)``. That is
+    ``ceil((window + T - 1) / block_size) + 1`` columns from the block of
+    ``len - window + 1``; columns past the table read as unallocated (-1).
+    The mask is still made from positions. A table no wider is returned whole."""
+    nbps = tables.shape[1]
+    n = -(-(window + T - 1) // block_size) + 1
+    if n >= nbps:
+        return tables
+    first = jnp.maximum(lens - (window - 1), 0) // block_size
+    cols = first[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
+    tbl = jnp.take_along_axis(tables, jnp.clip(cols, 0, nbps - 1), axis=1)
+    return jnp.where(cols < nbps, tbl, -1)
+
+
+def gathered_positions(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
+    """The linear position view ``[B, columns * block_size]`` of the given
+    table columns; lanes backed by no block read as POS_SENTINEL."""
+    gathered = pool[_gather_tables(tables)]  # [B, n, bs]
+    gathered = jnp.where((tables >= 0)[:, :, None], gathered, POS_SENTINEL)
+    return gathered.reshape(tables.shape[0], -1)
 
 
 def paged_record_positions(cache: Dict, pos_update: jnp.ndarray,
@@ -318,18 +361,13 @@ def paged_insert_row(cache: Dict, slot, table_row: jnp.ndarray,
     so writing the full width doubles as the block scrub. Linear positions
     past the slot's allocation are dropped (no block — nothing to strand)."""
     num_blocks, block_size = cache["pos"].shape
-    W = row_cache["k"].shape[2]
+    W = row_cache["pos"].shape[1]
     phys, off = _row_targets(table_row, W, block_size, num_blocks)
     out = dict(cache)
     out["block_tables"] = jax.lax.dynamic_update_slice(
         cache["block_tables"], table_row[None], (slot, 0))
-    out["k"] = cache["k"].at[:, phys, off].set(row_cache["k"][:, 0])
-    out["v"] = cache["v"].at[:, phys, off].set(row_cache["v"][:, 0])
-    if "k_scale" in cache:
-        out["k_scale"] = cache["k_scale"].at[:, phys, off].set(
-            row_cache["k_scale"][:, 0])
-        out["v_scale"] = cache["v_scale"].at[:, phys, off].set(
-            row_cache["v_scale"][:, 0])
+    for key in kv_leaf_keys(cache):
+        out[key] = cache[key].at[:, phys, off].set(row_cache[key][:, 0])
     out["pos"] = cache["pos"].at[phys, off].set(row_cache["pos"][0])
     return out
 
@@ -341,13 +379,10 @@ def row_trim(row: Dict, width: int) -> Dict:
     slicing, so the host transfer that follows moves ``width`` columns
     instead of the full ``max_seq_len`` row. The inverse (sentinel-padding
     back to full width) lives in ``serving/migration.unpack_kv_row``."""
-    width = min(width, row["k"].shape[2])
+    width = min(width, row["pos"].shape[1])
     out: Dict = {"len": row.get("len")}
-    out["k"] = row["k"][:, :, :width]
-    out["v"] = row["v"][:, :, :width]
-    if "k_scale" in row:
-        out["k_scale"] = row["k_scale"][:, :, :width]
-        out["v_scale"] = row["v_scale"][:, :, :width]
+    for key in kv_leaf_keys(row):
+        out[key] = row[key][:, :, :width]
     out["pos"] = row["pos"][:, :width]
     return out
 
@@ -361,13 +396,8 @@ def paged_copy_block(cache: Dict, src, dst, keep) -> Dict:
     this copy is the at-most-once COW event per shared tail block)."""
     out = dict(cache)
     block_size = cache["pos"].shape[1]
-    for key in ("k", "v"):
+    for key in kv_leaf_keys(cache):
         out[key] = cache[key].at[:, dst].set(cache[key][:, src])
-    if "k_scale" in cache:
-        out["k_scale"] = cache["k_scale"].at[:, dst].set(
-            cache["k_scale"][:, src])
-        out["v_scale"] = cache["v_scale"].at[:, dst].set(
-            cache["v_scale"][:, src])
     row = jnp.where(jnp.arange(block_size, dtype=jnp.int32) < keep,
                     cache["pos"][src], POS_SENTINEL)
     out["pos"] = cache["pos"].at[dst].set(row)
@@ -388,23 +418,18 @@ def paged_extract_row(cache: Dict, slot, cursor, *,
     Default None keeps the full-table gather (width = blocks_per_slot ×
     block_size = max_seq_len)."""
     nbps_total = cache["block_tables"].shape[1]
-    block_size = cache["k"].shape[2]
+    block_size = cache["pos"].shape[1]
     nbps = nbps_total if width is None else max(
         1, min(nbps_total, -(-int(width) // block_size)))
     table_row = jax.lax.dynamic_slice(
         cache["block_tables"], (slot, 0), (1, nbps))[0]
     tbl = _gather_tables(table_row)
-    L = cache["k"].shape[0]
-    kv, d = cache["k"].shape[-2], cache["k"].shape[-1]
-    W = nbps * cache["k"].shape[2]
-    row: Dict = {
-        "k": cache["k"][:, tbl].reshape(L, 1, W, kv, d),
-        "v": cache["v"][:, tbl].reshape(L, 1, W, kv, d),
-        "len": jnp.asarray(cursor, jnp.int32),
-    }
-    if "k_scale" in cache:
-        row["k_scale"] = cache["k_scale"][:, tbl].reshape(L, 1, W, kv)
-        row["v_scale"] = cache["v_scale"][:, tbl].reshape(L, 1, W, kv)
+    W = nbps * block_size
+    row: Dict = {"len": jnp.asarray(cursor, jnp.int32)}
+    for key in kv_leaf_keys(cache):
+        leaf = cache[key]  # [L, NB, bs, ...] -> [L, 1, W, ...]
+        row[key] = leaf[:, tbl].reshape(
+            (leaf.shape[0], 1, W) + leaf.shape[3:])
     pos = cache["pos"][tbl]  # [nbps, bs]
     pos = jnp.where((table_row >= 0)[:, None], pos, POS_SENTINEL)
     row["pos"] = pos.reshape(1, W)

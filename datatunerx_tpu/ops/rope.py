@@ -41,7 +41,14 @@ def rope_cos_sin(
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
-    """x: [B, T, H, head_dim]; cos/sin: [B, T, head_dim//2]."""
+    """x: [B, T, H, head_dim]; cos/sin: [B, T, rotary_dim//2]. Where the
+    tables are narrower than half the head (partial rotation), the first
+    ``rotary_dim`` dims of each head are rotated, half-split among themselves,
+    and the rest pass through."""
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     c = cos[:, :, None, :].astype(x.dtype)

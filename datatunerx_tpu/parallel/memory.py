@@ -111,9 +111,12 @@ def estimate_footprint(
     missing axes default to 1 (single chip = all 1s).
     """
     from datatunerx_tpu.models import init_params
+    from datatunerx_tpu.models.config import refuse_hybrid
     from datatunerx_tpu.models.lora import init_lora_params
     from datatunerx_tpu.training.optimizer import make_optimizer
 
+    # the activation terms below are the single-kind decoder's
+    refuse_hybrid(model_cfg, "the train-step memory estimate")
     mesh_shape = dict(mesh_shape or {})
     cdt = jnp.dtype(compute_dtype).itemsize
     key = jax.random.PRNGKey(0)

@@ -30,11 +30,16 @@ def make_adapter_checkpoint(path: str, model: str, seed: int,
     cfg = get_config(model.split(":")[-1])
     key = jax.random.PRNGKey(seed)
     lora = init_lora_params(cfg, key, rank=rank, targets=tuple(targets))
-    layers = {}
-    for i, (t, leaf) in enumerate(sorted(lora["layers"].items())):
-        b = 0.05 * jax.random.normal(
-            jax.random.fold_in(key, 1000 + i), leaf["b"].shape, jnp.float32)
-        layers[t] = {"a": leaf["a"], "b": b}
+    def with_b(tree, salt):  # one group's {target: {a, b}}
+        return {t: {"a": leaf["a"], "b": 0.05 * jax.random.normal(
+            jax.random.fold_in(key, salt + i), leaf["b"].shape, jnp.float32)}
+            for i, (t, leaf) in enumerate(sorted(tree.items()))}
+
+    if cfg.hybrid:  # per-run geometry: layers.<run>.<target>
+        layers = {run: with_b(tree, 1000 * (g + 1))
+                  for g, (run, tree) in enumerate(sorted(lora["layers"].items()))}
+    else:
+        layers = with_b(lora["layers"], 1000)
     mngr = CheckpointManager(path)
     mngr.maybe_save({"lora": {"layers": layers}}, step=1, force=True)
     mngr.close()

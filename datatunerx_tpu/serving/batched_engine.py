@@ -62,6 +62,7 @@ from datatunerx_tpu.ops.paged_attention import (
     BlockAllocator,
     blocks_for_depth,
     init_paged_cache,
+    kv_leaf_keys,
     paged_copy_block,
     paged_extract_row,
     paged_insert_row,
@@ -325,16 +326,13 @@ def _pad_row(row: Dict, width: int) -> Dict:
     ``max_seq_len`` gather per insert), but the extension program keeps ONE
     compiled geometry — full width — so padding happens here, once per
     extension, instead of a compile per stored prefix length."""
-    W = row["k"].shape[2]
+    W = row["pos"].shape[1]
     if W >= width:
         return row
     out = dict(row)
     pad5 = [(0, 0), (0, 0), (0, width - W), (0, 0), (0, 0)]
-    out["k"] = jnp.pad(row["k"], pad5)
-    out["v"] = jnp.pad(row["v"], pad5)
-    if "k_scale" in row:
-        out["k_scale"] = jnp.pad(row["k_scale"], pad5[:-1])
-        out["v_scale"] = jnp.pad(row["v_scale"], pad5[:-1])
+    for key in kv_leaf_keys(row):
+        out[key] = jnp.pad(row[key], pad5[:row[key].ndim])
     out["pos"] = jnp.pad(row["pos"], [(0, 0), (0, width - W)],
                          constant_values=POS_SENTINEL)
     return out
@@ -375,6 +373,9 @@ def load_checkpoint_state(checkpoint_path: str) -> dict:
 # executables once the owning engines are gone.
 _PROGRAM_MEMO: "collections.OrderedDict" = collections.OrderedDict()
 _PROGRAM_MEMO_MAX = 8
+
+# cache["moe_stats"] columns (ops/moe.py N_STATS)
+MOE_STAT_NAMES = ("local_rows", "experts_hit", "max_rows", "layer_steps")
 
 
 def _program_memo_key(cfg, max_seq_len: int, kv_quant,
@@ -464,15 +465,13 @@ class _Programs:
                      slot, row_cache, row_logits, plen, n_prompt, max_new,
                      temp, top_p, stop_row, adapter, seed):
         cache = dict(cache)
-        cache["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], row_cache["k"], (0, slot, 0, 0, 0))
-        cache["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], row_cache["v"], (0, slot, 0, 0, 0))
-        if "k_scale" in cache:
-            cache["k_scale"] = jax.lax.dynamic_update_slice(
-                cache["k_scale"], row_cache["k_scale"], (0, slot, 0, 0))
-            cache["v_scale"] = jax.lax.dynamic_update_slice(
-                cache["v_scale"], row_cache["v_scale"], (0, slot, 0, 0))
+        for key in kv_leaf_keys(cache):
+            cache[key] = jax.lax.dynamic_update_slice(
+                cache[key], row_cache[key],
+                (0, slot) + (0,) * (cache[key].ndim - 2))
+        if "moe_stats" in cache and "moe_stats" in row_cache:
+            # what the row's own prefill counted
+            cache["moe_stats"] = cache["moe_stats"] + row_cache["moe_stats"]
         cache["pos"] = jax.lax.dynamic_update_slice(
             cache["pos"], row_cache["pos"], (slot, 0))
         cache["len"] = cache["len"].at[slot].set(plen)
@@ -498,6 +497,9 @@ class _Programs:
         into the slot's allocated blocks (installing its block table) and arm
         the slot's decode state."""
         cache = paged_insert_row(cache, slot, table_row, row_cache)
+        if "moe_stats" in cache and "moe_stats" in row_cache:
+            # what the row's own prefill counted
+            cache["moe_stats"] = cache["moe_stats"] + row_cache["moe_stats"]
         cache["len"] = jax.lax.dynamic_update_slice(
             cache["len"], cursor[None], (slot,))
         return (
@@ -550,9 +552,8 @@ class _Programs:
             compute_dtype=jnp.bfloat16,
         )
         out = dict(cache)
-        for key in ("k", "v", "k_scale", "v_scale"):
-            if key in out:
-                out[key] = new[key]
+        for key in kv_leaf_keys(out) + ["moe_stats"] * ("moe_stats" in out):
+            out[key] = new[key]
         out["pos"] = new["pos"]
         out["len"] = jax.lax.dynamic_update_slice(
             cache["len"], new["len"], (slot,))
@@ -651,6 +652,18 @@ class BatchedEngine:
         self.max_seq_len = min(max_seq_len, self.cfg.max_seq_len)
         self.slots = slots
         self.chunk = max(1, decode_chunk)
+        if self.cfg.hybrid:
+            # layers of several kinds (models/hybrid.py): a window layer reads
+            # a window-wide view placed by the slot's linear cursor, which
+            # holds while a row's pads lie at its left; a prefix-cache
+            # extension puts pads mid-row, and the draft/verify programs
+            # carve windows of their own
+            for flag, on in (("prefix_cache", prefix_cache > 0),
+                             ("spec_draft", bool(spec_draft))):
+                if on:
+                    raise NotImplementedError(
+                        f"model {self.cfg.name!r} has layers of several "
+                        f"kinds: --{flag} does not handle it yet")
 
         # ---- adapters: checkpoint_path becomes adapter "default" (unmerged);
         # full-param checkpoints swap the base instead
@@ -792,6 +805,16 @@ class BatchedEngine:
         # that ran a fused-epilogue program vs the legacy sampler; written
         # by the scheduler thread only, like spec_stats
         self.sampling_stats = {"fused_steps": 0, "legacy_steps": 0}
+        # what the expert layers counted (dtx_serving_moe_*), decode steps
+        # and prefill steps apart: rows routed to the experts held here, held
+        # experts that got a row, most rows on one expert, expert-layer steps.
+        # The programs accumulate them on the device (cache["moe_stats"],
+        # wrapping); _note_moe adds up differences at the decode tick's sync.
+        self.moe_stats = {f"{phase}_{name}": 0
+                          for phase in ("decode", "prefill")
+                          for name in MOE_STAT_NAMES}
+        self._moe_seen = 0  # device counters at the last read
+        self._slot_cursor = None  # each slot's linear cursor then
         # tokens handed to finished requests (dtx_serving_generated_tokens_
         # total): added once per request in _complete, never per token
         self.generated_tokens = 0
@@ -1116,6 +1139,47 @@ class BatchedEngine:
         candidates. Host-side list length; safe from any thread."""
         return len(self._preempted)
 
+    def _note_moe(self):
+        """Add up what the expert layers counted since the last read, and keep
+        every slot's linear cursor: two small arrays cross to the host at the
+        decode tick's designed sync point."""
+        stats, lens = jax.device_get(  # dtxlint: disable=DTX001
+            (self._cache["moe_stats"], self._cache["len"]))
+        stats = stats.astype(np.int64)
+        delta = (stats - self._moe_seen) % (1 << 32)  # the device's int32 wraps
+        for phase, row in zip(("decode", "prefill"), delta):
+            for name, v in zip(MOE_STAT_NAMES, row):
+                self.moe_stats[f"{phase}_{name}"] += int(v)  # dtxlint: disable=DTX001 — host numpy
+        self._moe_seen = stats
+        self._slot_cursor = lens
+
+    def kv_window_stats(self) -> Optional[dict]:
+        """Bytes of the window layers' pool that live slots hold
+        (``live_bytes``) and the part of them in blocks that lie wholly behind
+        every later query's window (``behind_bytes``): blocks stay allocated
+        until their request ends. None where no layer has a window, the
+        cache is not paged, or no decode tick has read the cursors yet."""
+        from datatunerx_tpu.models.config import attention_kinds, kind_layers
+
+        kind = attention_kinds(self.cfg).get("window")
+        if (kind is None or not self.paged or self._slot_cursor is None
+                or "k_window" not in self._cache):
+            return None
+        per_block = (kind_layers(self.cfg)["window"] * self.block_size
+                     * kind.num_kv_heads * (kind.head_dim + kind.v_head_dim)
+                     * self._cache["k_window"].dtype.itemsize)
+        live = behind = 0
+        for slot in range(self.slots):
+            if self._slot_req[slot] is None:
+                continue
+            held = len(self._slot_blocks[slot])
+            cursor = int(self._slot_cursor[slot])  # dtxlint: disable=DTX001 — host numpy
+            live += held
+            behind += min(held, max(0, cursor - kind.window + 1)
+                          // self.block_size)
+        return {"live_bytes": live * per_block,
+                "behind_bytes": behind * per_block}
+
     def _free_prefix_entry(self, ent: dict):
         """Prefix-cache eviction hook: return a COW block entry's refs to
         the allocator (dense-row entries hold no pool resources). Runs on
@@ -1134,7 +1198,11 @@ class BatchedEngine:
         the all-zero base adapter). Mixed ranks are padded to the max rank
         (zero cols/rows leave the delta unchanged); mixed target sets take
         the union with zeros where an adapter lacks a target."""
-        from datatunerx_tpu.models.lora import target_dims
+        from datatunerx_tpu.models.lora import (
+            adapter_leaves,
+            group_tree,
+            lora_groups,
+        )
 
         loaded: List[Tuple[str, dict, float]] = []
         for name, path in named.items():
@@ -1143,34 +1211,36 @@ class BatchedEngine:
             if not lora:
                 raise ValueError(f"adapter {name!r}: no lora tree in {path}")
             layers = lora["layers"]
-            rank = next(iter(layers.values()))["a"].shape[-1]
+            rank = adapter_leaves(layers)[0]["a"].shape[-1]
             scaling = state.get("_scaling")
             if scaling is None:
                 scaling = lora_scaling(32.0, rank)
             loaded.append((name, layers, float(scaling)))
 
-        targets = sorted({t for _, layers, _ in loaded for t in layers}
-                         & set(LORA_TARGETS))
-        max_rank = max(
-            layers[t]["a"].shape[-1]
-            for _, layers, _ in loaded for t in layers
-        )
-        L = self.cfg.num_layers
+        max_rank = max(leaf["a"].shape[-1] for _, layers, _ in loaded
+                       for leaf in adapter_leaves(layers))
         E = len(loaded) + 1  # + base zero adapter
         stack: Dict[str, dict] = {}
-        for t in targets:
-            d_in, d_out = target_dims(self.cfg, t)
-            a = np.zeros((L, E, d_in, max_rank), np.float32)
-            b = np.zeros((L, E, max_rank, d_out), np.float32)
-            for e, (_, layers, _) in enumerate(loaded, start=1):
-                if t not in layers:
-                    continue
-                ar = np.asarray(layers[t]["a"], np.float32)  # [L, d_in, r]
-                br = np.asarray(layers[t]["b"], np.float32)
-                r = ar.shape[-1]
-                a[:, e, :, :r] = ar
-                b[:, e, :r, :] = br
-            stack[t] = {"a": jnp.asarray(a), "b": jnp.asarray(b)}
+        # one group of like layers at a time (a model whose layers are all of
+        # one kind is the single group None: the flat tree there always was)
+        for gkey, L, dims in lora_groups(self.cfg):
+            trees = [group_tree(layers, gkey) for _, layers, _ in loaded]
+            targets = sorted({t for tree in trees for t in tree}
+                             & set(LORA_TARGETS) & set(dims))
+            group = stack if gkey is None else stack.setdefault(gkey, {})
+            for t in targets:
+                d_in, d_out = dims[t]
+                a = np.zeros((L, E, d_in, max_rank), np.float32)
+                b = np.zeros((L, E, max_rank, d_out), np.float32)
+                for e, tree in enumerate(trees, start=1):
+                    if t not in tree:
+                        continue
+                    ar = np.asarray(tree[t]["a"], np.float32)  # [L, d_in, r]
+                    br = np.asarray(tree[t]["b"], np.float32)
+                    r = ar.shape[-1]
+                    a[:, e, :, :r] = ar
+                    b[:, e, :r, :] = br
+                group[t] = {"a": jnp.asarray(a), "b": jnp.asarray(b)}
         scales = jnp.asarray([0.0] + [s for _, _, s in loaded], jnp.float32)
         self.lora_stack = ({"layers": stack}, scales)
         for e, (name, _, _) in enumerate(loaded, start=1):
@@ -2318,13 +2388,10 @@ class BatchedEngine:
             row = self._extract(self._cache, jnp.asarray(slot, jnp.int32),
                                 jnp.asarray(cursor, jnp.int32), width=w)
         else:
-            row = {"k": self._cache["k"][:, slot:slot + 1],
-                   "v": self._cache["v"][:, slot:slot + 1],
-                   "pos": self._cache["pos"][slot:slot + 1],
+            row = {"pos": self._cache["pos"][slot:slot + 1],
                    "len": jnp.asarray(cursor, jnp.int32)}
-            if "k_scale" in self._cache:
-                row["k_scale"] = self._cache["k_scale"][:, slot:slot + 1]
-                row["v_scale"] = self._cache["v_scale"][:, slot:slot + 1]
+            for key in kv_leaf_keys(self._cache):
+                row[key] = self._cache[key][:, slot:slot + 1]
         payload = mig.build_payload(
             self.cfg, self.kv_quant,
             request={"trace_id": req.trace_id,
@@ -3432,6 +3499,8 @@ class BatchedEngine:
                 with span("dtx_engine_decode_sync"):
                     emitted_np = np.asarray(emitted)  # [K, S]  # dtxlint: disable=DTX001
                     active_np = np.asarray(self._active)  # [S]  # dtxlint: disable=DTX001
+                    if "moe_stats" in self._cache:
+                        self._note_moe()
         except Exception as e:  # noqa: BLE001 — device fault: fail all in-flight
             for slot, req in enumerate(self._slot_req):
                 if req is not None:

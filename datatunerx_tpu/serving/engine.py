@@ -187,9 +187,13 @@ class InferenceEngine:
         state = restored if isinstance(restored, dict) else dict(restored)
         lora = state.get("lora")
         if lora:
-            from datatunerx_tpu.models.lora import lora_scaling, merge_lora
+            from datatunerx_tpu.models.lora import (
+                adapter_leaves,
+                lora_scaling,
+                merge_lora,
+            )
 
-            rank = next(iter(lora["layers"].values()))["a"].shape[-1]
+            rank = adapter_leaves(lora["layers"])[0]["a"].shape[-1]
             scaling = self._manifest_lora_scaling(root)
             if scaling is None:
                 # manifest absent (ad-hoc checkpoint dir): fall back to the

@@ -39,7 +39,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from datatunerx_tpu.ops.attention import kv_quantize
-from datatunerx_tpu.ops.paged_attention import POS_SENTINEL, row_trim
+from datatunerx_tpu.ops.paged_attention import (
+    POS_SENTINEL,
+    kv_leaf_keys,
+    row_trim,
+)
 
 PAYLOAD_KIND = "dtx-kv-session"
 # fleet prefix tier (datatunerx_tpu/fleet/prefix_tier.py): a prefilled
@@ -96,15 +100,35 @@ def encode_payload(payload: dict) -> dict:
         for key in ("k", "v", "pos", "k_scale", "v_scale"):
             if isinstance(kv.get(key), np.ndarray):
                 kv[key] = _b64(kv[key])
+        if isinstance(kv.get("pools"), dict):
+            kv["pools"] = {
+                name: dict(leaf, body=(_b64(leaf["body"])
+                                       if isinstance(leaf["body"], np.ndarray)
+                                       else leaf["body"]))
+                for name, leaf in kv["pools"].items()}
         out["kv"] = kv
     return out
 
 
 def model_signature(cfg, kv_quant: Optional[str]) -> dict:
     """What must match (or be convertible) for an import to be correct."""
-    return {"layers": cfg.num_layers, "kv_heads": cfg.num_kv_heads,
-            "head_dim": cfg.head_dim, "vocab": cfg.vocab_size,
-            "kv_quant": kv_quant or ""}
+    sig = {"layers": cfg.num_layers, "kv_heads": cfg.num_kv_heads,
+           "head_dim": cfg.head_dim, "vocab": cfg.vocab_size,
+           "kv_quant": kv_quant or ""}
+    if cfg.hybrid:
+        sig["pools"] = _pool_signature(cfg)
+    return sig
+
+
+def _pool_signature(cfg) -> dict:
+    """{kind: [layers, kv_heads, k width, v width]} of a model with a KV pool
+    per attention kind."""
+    from datatunerx_tpu.models.config import attention_kinds, kind_layers
+
+    layers = kind_layers(cfg)
+    return {name: [layers[name], kind.num_kv_heads, kind.head_dim,
+                   kind.v_head_dim]
+            for name, kind in attention_kinds(cfg).items()}
 
 
 def _check_model_sig(payload: dict, cfg) -> None:
@@ -117,6 +141,11 @@ def _check_model_sig(payload: dict, cfg) -> None:
             raise ValueError(
                 f"session payload is from an incompatible model: "
                 f"{key}={sig.get(key)} here {want}")
+    pools = _pool_signature(cfg) if cfg.hybrid else None
+    if sig.get("pools") != pools:
+        raise ValueError(
+            f"session payload is from an incompatible model: "
+            f"pools={sig.get('pools')} here {pools}")
 
 
 def check_signature(payload: dict, cfg) -> None:
@@ -160,6 +189,8 @@ def pack_kv_row(row: Dict, cursor: int, wire: str, b64: bool = True) -> dict:
     payloads: engine preemption parking); ``encode_payload`` upgrades
     them to base64 if they ever need the wire."""
     row = row_trim(row, max(1, cursor))
+    if "k" not in row:
+        return _pack_pools(row, wire, b64)
     k, v = row["k"], row["v"]
     quantized_cache = "k_scale" in row
     if wire == "int8" and not quantized_cache:
@@ -191,11 +222,47 @@ def pack_kv_row(row: Dict, cursor: int, wire: str, b64: bool = True) -> dict:
     return doc
 
 
+def _pack_pools(row: Dict, wire: str, b64: bool) -> dict:
+    """The wire doc of a row with one k/v pool per attention kind
+    (models/hybrid.py): each pool's own shape beside its bf16 bytes."""
+    if wire != "bf16":
+        raise ValueError(
+            "a row with a KV pool per attention kind travels as bf16 only "
+            f"(asked for {wire!r})")
+    pos_np = np.asarray(row["pos"], np.int32)  # dtxlint: disable=DTX001 — migration serialization point
+    pools = {}
+    for key in kv_leaf_keys(row):
+        host = np.asarray(row[key].astype(jnp.bfloat16))  # dtxlint: disable=DTX001 — migration serialization point
+        pools[key] = {"shape": [int(n) for n in host.shape],
+                      "body": _b64(host) if b64 else host}
+    return {"wire": "bf16", "width": int(pos_np.shape[1]), "pools": pools,
+            "pos": _b64(pos_np) if b64 else pos_np}
+
+
+def _unpack_pools(doc: dict, full_width: int) -> Dict:
+    W = int(doc["width"])
+    if W > full_width:
+        raise ValueError(
+            f"session KV depth {W} exceeds this replica's context "
+            f"{full_width}")
+    pos = _unb64(doc["pos"], np.int32, (1, W))
+    row: Dict = {"pos": jnp.asarray(np.pad(
+        pos, [(0, 0), (0, full_width - W)], constant_values=POS_SENTINEL))}
+    for key, leaf in doc["pools"].items():
+        a = _unb64(leaf["body"], jnp.bfloat16, tuple(leaf["shape"]))
+        widths = [(0, 0)] * a.ndim
+        widths[2] = (0, full_width - W)
+        row[key] = jnp.asarray(np.pad(a, widths))
+    return row
+
+
 def unpack_kv_row(doc: dict, full_width: int,
                   quantize: Optional[str]) -> Dict:
     """Wire doc → a dense row cache dict shaped for this engine's cache
     (``[L, 1, full_width, KV, d]`` + sentinel-padded positions), converting
     between int8 and bf16 encodings as the target's ``quantize`` demands."""
+    if "pools" in doc:
+        return _unpack_pools(doc, full_width)
     L, W = int(doc["layers"]), int(doc["width"])
     KV, d = int(doc["kv_heads"]), int(doc["head_dim"])
     if W > full_width:

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
-from datatunerx_tpu.models.config import ModelConfig
+from datatunerx_tpu.models.config import ModelConfig, refuse_hybrid
 from datatunerx_tpu.models.llama import forward
 from datatunerx_tpu.models.lora import (
     DEFAULT_TARGETS,
@@ -122,6 +122,7 @@ class Trainer:
         train_cfg: TrainConfig,
         mesh=None,
     ):
+        refuse_hybrid(model_cfg, "the trainer")
         self.model_cfg = model_cfg
         self.cfg = train_cfg
         self.mesh = mesh

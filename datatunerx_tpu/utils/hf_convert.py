@@ -15,7 +15,7 @@ from typing import Mapping
 
 import numpy as np
 
-from datatunerx_tpu.models.config import ModelConfig
+from datatunerx_tpu.models.config import ModelConfig, refuse_hybrid
 
 _LAYER_KERNELS = [
     ("self_attn.q_proj", "q_proj"),
@@ -42,6 +42,7 @@ def convert_hf_state_dict(
     sd: Mapping[str, "np.ndarray"], cfg: ModelConfig, dtype=np.float32
 ):
     """Convert an HF llama/mistral/qwen2 state_dict to our stacked param tree."""
+    refuse_hybrid(cfg, "HF weight conversion")
     L = cfg.num_layers
     prefix = "model." if any(k.startswith("model.") for k in sd) else ""
 
@@ -78,6 +79,7 @@ def convert_hf_state_dict(
 
 def export_hf_state_dict(params, cfg: ModelConfig) -> dict:
     """Inverse of convert_hf_state_dict (numpy arrays, HF key names)."""
+    refuse_hybrid(cfg, "HF weight export")
     out = {}
     out["model.embed_tokens.weight"] = np.asarray(
         params["embed_tokens"]["embedding"], np.float32
@@ -103,6 +105,14 @@ def export_hf_state_dict(params, cfg: ModelConfig) -> dict:
 
 def config_from_hf(hf_cfg) -> ModelConfig:
     """Build a ModelConfig from an HF PretrainedConfig (llama/mistral/qwen2)."""
+    mixed = [k for k in ("hybrid_layer_pattern", "n_routed_experts")
+             if getattr(hf_cfg, k, None)]
+    if mixed:
+        raise NotImplementedError(
+            f"HF config of type {getattr(hf_cfg, 'model_type', '?')!r} names "
+            f"layers of several kinds ({mixed}): weight conversion handles "
+            "the single-kind llama-family decoder only; such a model is "
+            "served from a preset with drawn weights")
     return ModelConfig(
         name=getattr(hf_cfg, "model_type", "llama"),
         vocab_size=hf_cfg.vocab_size,

@@ -58,6 +58,8 @@ def load_model_and_tokenizer(
                 raw[k] = None
         raw.update(overrides)
         cfg = ModelConfig(**raw)
+        # convert_hf_state_dict refuses a model of several layer kinds by
+        # name: such a model loads from a preset (drawn weights) only
         sd = dict(np.load(npz))
         params = convert_hf_state_dict(sd, cfg, dtype=dtype)
         tok = _load_hf_tokenizer(path_or_preset) or SimpleTokenizer()

@@ -148,6 +148,53 @@ def test_serving_kernels_lower_through_mosaic_at_engine_geometry():
         assert kernels == [want], (case, kernels)
 
 
+# One expert layer at the published widths of the benchmark's sparse-expert
+# configuration (16 held experts of 4096 x 2048, 64 slots x top-8 = 512 rows a
+# decode step, 2,048 rows a prefill chunk): XLA's TPU compiler must turn each
+# ``jax.lax.ragged_dot`` into a Mosaic grouped-matmul kernel (a ``ragged-dot``
+# custom call), which ``benchmarks/moe_readers.py`` finds by that name.
+_EXPERTS_PROBE = r"""
+import json, os
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
+jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_enable_compilation_cache", False)
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+from datatunerx_tpu.ops import moe
+
+sh = SingleDeviceSharding(topologies.get_topology_desc(
+    platform="tpu", topology_name="v5e:2x2").devices[0])
+sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+D, F, E, Eh, k = 4096, 2048, 256, 16, 8
+p = {"router": {"kernel": sds((D, E), jnp.bfloat16)},
+     "e_score_correction_bias": sds((E,), jnp.bfloat16),
+     "experts": {"gate_proj": sds((Eh, D, F), jnp.bfloat16),
+                 "up_proj": sds((Eh, D, F), jnp.bfloat16),
+                 "down_proj": sds((Eh, F, D), jnp.bfloat16)}}
+out = {}
+for rows in (64, 256):
+    fn = lambda x, valid, p: moe.expert_layer(
+        x, valid, p, experts_total=E, experts_held=Eh, first_held=0, top_k=k,
+        normalize=True, scaling=1.0)
+    text = jax.jit(fn).lower(sds((rows, D), jnp.bfloat16), sds((rows,), jnp.bool_),
+                             p).compile().as_text()
+    out[str(rows)] = {"ragged": text.count("%ragged-dot-none"),
+                      "mosaic": text.count('custom_call_target="tpu_custom_call"')}
+print(json.dumps(out))
+"""
+
+
+def test_expert_layer_compiles_to_grouped_matmul_kernels_for_v5e():
+    pytest.importorskip("libtpu")  # the TPU compiler; absent from jax[cpu]
+    doc = _run_probe(_EXPERTS_PROBE, timeout=600)
+    for rows, seen in doc.items():
+        # gate, up and down: three grouped matmuls, each a Mosaic kernel
+        assert seen["ragged"] >= 3 and seen["mosaic"] >= 3, (rows, seen)
+
+
 @pytest.mark.slow
 def test_aot_pipeline_compiles_for_v5e_target():
     assert _run_probe(_PROBE, timeout=900)["ok"] is True
