@@ -792,12 +792,30 @@ def child_kernels(rehearsal: bool) -> int:
                            POS_SENTINEL).reshape(B, -1)
         return xla_attention(q, k_all, v_all, make_causal_bias(q_pos, kv_pos))
 
+    # the kernels read one layer of the stacked pool the layer scan carries
+    # ([L, NB, bs, KV * d]): the oracle's pool is the LAST of two layers, the
+    # first holds the same blocks in reverse order, so a wrong layer offset
+    # reads plausible wrong numbers
+    def stacked(x):
+        if x is None:
+            return None
+        if x.ndim == 4:
+            x = x.reshape(x.shape[:2] + (-1,))
+        return jnp.stack([x[::-1], x])
+
+    def decode(q, q_pos, k_pool, v_pool, ks, vs, tables, pos):
+        return paged_decode_attention(
+            q, *(stacked(x) for x in (k_pool, v_pool, ks, vs)),
+            jnp.asarray(1, jnp.int32), tables, pos, q_pos)
+
     def multitoken(q, q_pos, k_pool, v_pool, ks, vs, tables, pos):
         tbl = jnp.where(tables >= 0, tables, 0)
         kv_pos = jnp.where((tables >= 0)[:, :, None], pos[tbl],
                            POS_SENTINEL).reshape(tables.shape[0], -1)
         return paged_multitoken_attention(
-            q, k_pool, v_pool, ks, vs, tables, attention_allow(q_pos, kv_pos))
+            q, *(stacked(x) for x in (k_pool, v_pool, ks, vs)),
+            jnp.asarray(1, jnp.int32), tables,
+            attention_allow(q_pos, kv_pos))
 
     def paged(gname, H, KV, d):
         W = SERVE_SEQ if not rehearsal else 128
@@ -810,7 +828,7 @@ def child_kernels(rehearsal: bool) -> int:
             qpos = jnp.asarray([n - 1 for n in lens], jnp.int32)
             check(f"paged_decode_{kvtag} [{gname} B{B} bs{SERVE_BLOCK} "
                   f"W{W}]",
-                  jax.jit(paged_decode_attention)(q, *pool, qpos),
+                  jax.jit(decode)(q, qpos, *pool),
                   jax.jit(gather_attention)(q[:, None], qpos[:, None],
                                             *pool)[:, 0],
                   atol=2e-2, rtol=2e-2)

@@ -62,17 +62,12 @@ from datatunerx_tpu.models.config import (
 )
 from datatunerx_tpu.ops import moe
 from datatunerx_tpu.ops.attention import (
+    KVStep,
     cache_positions_update,
     make_causal_bias,
     xla_attention,
 )
-from datatunerx_tpu.ops.paged_attention import (
-    POS_SENTINEL,
-    _gather_tables,
-    _write_targets,
-    gathered_positions,
-    window_tables,
-)
+from datatunerx_tpu.ops.paged_attention import POS_SENTINEL, gathered_positions
 from datatunerx_tpu.ops.rope import apply_rope, rope_cos_sin
 
 
@@ -204,30 +199,15 @@ def init_paged_cache(cfg: ModelConfig, slots: int, num_blocks: int,
 
 class _View:
     """How one step writes its tokens into a kind's pool and what its
-    attention reads back: built once a forward, shared by the kind's layers."""
+    attention reads back: built once a forward, shared by the kind's layers.
+    The targets and the view are ops/attention.py's ``KVStep``, the one the
+    single-kind decoder uses; a kind adds its window and its bias."""
 
     def __init__(self, cache, kind, positions, kv_pos_full, cache_pos, T):
-        self.kind = kind
-        self.paged = "block_tables" in cache
-        self.lens = cache["len"]
-        if self.paged:
-            num_blocks, block_size = cache_pos.shape
-            tables = cache["block_tables"]
-            self.phys, self.off = _write_targets(
-                tables, self.lens, T, block_size, num_blocks)
-            if kind.window is not None:
-                tables = window_tables(tables, self.lens, T, kind.window, block_size)
-                kv_pos = (kv_pos_full if tables is cache["block_tables"]
-                          else gathered_positions(cache_pos, tables))
-            else:
-                kv_pos = kv_pos_full
-            self.tables = _gather_tables(tables)
-        else:
-            kv_pos = kv_pos_full
-            if self.lens.ndim:
-                B = positions.shape[0]
-                self.rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-                self.idx = self.lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        self.step = KVStep(cache, T, window=kind.window)
+        kv_pos = kv_pos_full
+        if self.step.paged and self.step.view_tables is not cache["block_tables"]:
+            kv_pos = gathered_positions(cache_pos, self.step.view_tables)
         self.bias = make_causal_bias(positions, kv_pos, None,
                                      sliding_window=kind.window)
 
@@ -235,18 +215,9 @@ class _View:
         """Write ``new`` [B, T, KV, w] into layer ``li`` of ``pool`` and
         return (pool, what attention reads [B, S, KV, w])."""
         B, T, KV, w = new.shape
-        new = new.astype(pool.dtype).reshape(B, T, KV * w)
-        if self.paged:
-            pool = pool.at[li, self.phys, self.off].set(new)
-            read = pool[li, self.tables]  # [B, n, bs, KV * w]
-        elif self.lens.ndim == 0:
-            pool = jax.lax.dynamic_update_slice(
-                pool, new[None], (li, 0, self.lens, 0))
-            read = pool[li]
-        else:
-            pool = pool.at[li, self.rows, self.idx].set(new)
-            read = pool[li]
-        return pool, read.reshape(B, -1, KV, w)
+        pool = self.step.write(
+            pool, li, new.astype(pool.dtype).reshape(B, T, KV * w))
+        return pool, self.step.read(pool, li).reshape(B, -1, KV, w)
 
 
 # ------------------------------------------------------------------ forward

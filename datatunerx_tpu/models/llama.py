@@ -34,15 +34,16 @@ import jax.numpy as jnp
 
 from datatunerx_tpu.models.config import ModelConfig
 from datatunerx_tpu.ops.attention import (
+    KVStep,
     attention,
     attention_allow,
     cache_positions_update,
     kv_cache_update,
     kv_cache_width,
-    kv_cache_write_paged,
+    kv_cache_write,
     make_causal_bias,
 )
-from datatunerx_tpu.ops.paged_attention import POS_SENTINEL
+from datatunerx_tpu.ops.paged_attention import POS_SENTINEL, kv_leaf_keys
 from datatunerx_tpu.ops.rope import apply_rope, rope_cos_sin
 
 Params = Any  # nested dict pytree
@@ -164,7 +165,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
         return hybrid.init_cache(cfg, batch, max_len, dtype=dtype,
                                  per_slot=per_slot, quantize=quantize)
     L = cfg.num_layers
-    shape = (L, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+    # heads and width are one axis, as in the paged pool (ops/paged_attention.py)
+    shape = (L, batch, max_len, cfg.num_kv_heads * cfg.head_dim)
+    scales = shape[:-1] + (cfg.num_kv_heads,)
     cache = {
         "len": (jnp.zeros((batch,), jnp.int32) if per_slot
                 else jnp.zeros((), jnp.int32)),
@@ -175,8 +178,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
     if quantize == "int8":
         cache["k"] = jnp.zeros(shape, jnp.int8)
         cache["v"] = jnp.zeros(shape, jnp.int8)
-        cache["k_scale"] = jnp.zeros(shape[:-1], jnp.float32)
-        cache["v_scale"] = jnp.zeros(shape[:-1], jnp.float32)
+        cache["k_scale"] = jnp.zeros(scales, jnp.float32)
+        cache["v_scale"] = jnp.zeros(scales, jnp.float32)
     elif quantize:
         raise ValueError(f"unsupported cache quantization {quantize!r}")
     else:
@@ -379,8 +382,16 @@ def forward(
         _log_attention_once(cfg.attention_impl, att_impl, T,
                             cfg.sliding_window, segment_ids is not None)
 
-    def block(x, scanned):
-        lp, ll, ck, cv, cks, cvs, layer_idx = scanned
+    # where this step's tokens land in the cache leaves and what attention
+    # reads back: the same for every layer but for the layer's index
+    kv_step = KVStep(cache, T) if cache is not None else None
+
+    def block(carry, scanned):
+        # the cache leaves travel in the CARRY beside x and each layer writes
+        # and reads them at its own index, so the scan moves nothing of them;
+        # without a cache (training) the carry holds x alone
+        x, pools = carry
+        lp, ll, layer_idx = scanned
         lget = (lambda name: ll.get(name)) if ll else (lambda name: None)
         if drop > 0.0:
             lkey = jax.random.fold_in(dropout_rng, layer_idx)
@@ -393,8 +404,8 @@ def forward(
 
         # one named scope per region, so that every op of a layer is in
         # exactly one of them (benchmarks/scope_readers.py reads them from
-        # the device trace); what the scan itself moves carries dtx.layers
-        # and no inner scope
+        # the device trace); what the scan itself moves (the carry, a
+        # layer's parameters) carries dtx.layers and no inner scope
         with jax.named_scope("dtx.qkv"):
             h = rms_norm(x, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
             q = _proj(h, lp["q_proj"], lget("q_proj"), lora_scale, kget(0),
@@ -409,7 +420,7 @@ def forward(
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
 
-        if ck is not None and paged_kernel:
+        if pools is not None and paged_kernel:
             # in-place decode: scatter the token's K/V into its blocks, then
             # the Pallas kernel reads them back through the block table —
             # the [B, W, KV, d] gathered view never materializes
@@ -418,12 +429,11 @@ def forward(
             )
 
             with jax.named_scope("dtx.kv_write"):
-                ck, cv, cks, cvs = kv_cache_write_paged(
-                    cache, ck, cv, cks, cvs, k, v)
+                pools = kv_cache_write(kv_step, pools, layer_idx, k, v)
             with jax.named_scope("dtx.attn"):
                 attn = paged_attention_decode_step(
-                    q, ck, cv, cks, cvs, cache, cache_pos, positions)
-        elif ck is not None and paged_kernel_mt:
+                    q, pools, layer_idx, cache, cache_pos, positions)
+        elif pools is not None and paged_kernel_mt:
             # multi-token in-place: same scatter-then-read-through-the-table
             # scheme with the precomputed attendability operand standing in
             # for the oracle's bias
@@ -432,19 +442,18 @@ def forward(
             )
 
             with jax.named_scope("dtx.kv_write"):
-                ck, cv, cks, cvs = kv_cache_write_paged(
-                    cache, ck, cv, cks, cvs, k, v)
+                pools = kv_cache_write(kv_step, pools, layer_idx, k, v)
             with jax.named_scope("dtx.attn"):
                 attn = paged_attention_multitoken_step(
-                    q, ck, cv, cks, cvs, cache, allow)
+                    q, pools, layer_idx, cache, allow)
         else:
-            if ck is not None:
+            if pools is not None:
                 # dense (scalar/per-slot cursor) or paged (block-table)
                 # write + full-width read via the shared cache interface
                 # (the gather counts as pool traffic)
                 with jax.named_scope("dtx.kv_write"):
-                    ck, cv, cks, cvs, k_att, v_att = kv_cache_update(
-                        cache, ck, cv, cks, cvs, k, v)
+                    pools, k_att, v_att = kv_cache_update(
+                        kv_step, pools, layer_idx, k, v)
             else:
                 k_att, v_att = k, v
 
@@ -470,7 +479,7 @@ def forward(
                 lora_scale, kget(6), drop, qm, (F, D), qp, lora_adapter_idx,
             )
             x = x + mlp
-        return x, (ck, cv, cks, cvs)
+        return (x, pools), None
 
     if cfg.remat == "full":
         block = jax.checkpoint(block)
@@ -479,14 +488,11 @@ def forward(
             block, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
         )
 
-    quant_kv = cache is not None and "k_scale" in cache
+    pools = (None if cache is None
+             else {key: cache[key] for key in kv_leaf_keys(cache)})
     xs = (
         params["layers"],
         lora_layers,
-        cache["k"] if cache is not None else None,
-        cache["v"] if cache is not None else None,
-        cache["k_scale"] if quant_kv else None,
-        cache["v_scale"] if quant_kv else None,
         jnp.arange(cfg.num_layers, dtype=jnp.int32),
     )
     # DTX_SCAN_UNROLL: cost-analysis instrumentation (scripts/aot_certify.py).
@@ -496,8 +502,7 @@ def forward(
     # per-layer cost. Default 1 = production behavior, byte-identical program.
     _unroll = int(os.environ.get("DTX_SCAN_UNROLL", "1"))
     with jax.named_scope("dtx.layers"):
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(block, x, xs,
-                                                         unroll=_unroll)
+        (x, pools), _ = jax.lax.scan(block, (x, pools), xs, unroll=_unroll)
 
     with jax.named_scope("dtx.unembed"):
         x = rms_norm(x, params["norm"]["scale"], cfg.rms_norm_eps)
@@ -505,11 +510,7 @@ def forward(
 
     new_cache = None
     if cache is not None:
-        new_cache = {"k": new_k, "v": new_v, "len": cache["len"] + T,
-                     "pos": cache_pos}
-        if quant_kv:
-            new_cache["k_scale"] = new_ks
-            new_cache["v_scale"] = new_vs
+        new_cache = dict(pools, len=cache["len"] + T, pos=cache_pos)
         if "block_tables" in cache:
             new_cache["block_tables"] = cache["block_tables"]
     if return_hidden:

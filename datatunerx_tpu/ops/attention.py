@@ -10,11 +10,12 @@ Shapes: q [B, T, H, d]; k, v [B, S, KV, d] with H = KV * G (GQA).
 Bias is additive, broadcastable to [B, 1|H, T, S]; softmax runs in f32.
 
 This module also owns the serving KV-cache interface the model writes and
-reads through (``cache_positions_update`` / ``kv_cache_update``): a cache
-dict with ``block_tables`` takes the paged block-pool path
+reads through (``cache_positions_update`` / ``KVStep`` / ``kv_cache_update``):
+a cache dict with ``block_tables`` takes the paged block-pool path
 (ops/paged_attention.py); otherwise the dense contiguous layouts
 (scalar-cursor prefill rows, per-slot-cursor continuous batching). The int8
-``kv_quant`` representation is shared by both.
+``kv_quant`` representation is shared by both. The layer scan carries the
+stacked leaves and a layer writes and reads them at its own index.
 """
 
 from __future__ import annotations
@@ -24,11 +25,12 @@ import jax.numpy as jnp
 
 from datatunerx_tpu.ops.paged_attention import (
     POS_SENTINEL,
-    paged_kv_update,
-    paged_kv_write,
+    _gather_tables,
+    _write_targets,
     paged_linear_targets,
     paged_record_positions,
     paged_view_width,
+    window_tables,
 )
 
 
@@ -188,65 +190,100 @@ def cache_positions_update(cache: dict, positions: jnp.ndarray,
     return cache_pos, cache_pos
 
 
-def kv_cache_write_paged(cache: dict, ck, cv, cks, cvs, k, v):
-    """Paged write WITHOUT the gathered read — the Pallas kernel decode
-    path: quantize the new tokens exactly as ``kv_cache_update`` would,
-    scatter them through the block tables, and return only the updated
-    pool leaves; the kernel then reads the blocks in place."""
-    if cks is not None:
-        k_w, ks_w = kv_quantize(k)
-        v_w, vs_w = kv_quantize(v)
-    else:
-        k_w, v_w = k.astype(ck.dtype), v.astype(cv.dtype)
-        ks_w = vs_w = None
-    return paged_kv_write(ck, cv, cks, cvs, cache["block_tables"],
-                          cache["len"], k_w, v_w, ks_w, vs_w)
+class KVStep:
+    """Where one step's ``T`` tokens land in a stacked KV leaf ``[layers,
+    blocks | rows, offset | lane, ...]`` and what attention reads back:
+    built once a forward, shared by its layers. The layer scan CARRIES the
+    leaves and every write and read names its layer, so no layer of a leaf is
+    sliced out or stacked back and XLA updates the leaf in place. One class
+    for the three cache kinds (paged block pool, dense rows with a scalar
+    cursor, dense rows with a cursor per slot) and for both forwards
+    (models/llama.py, models/hybrid.py).
+
+    ``window`` (a sliding-window layer over a paged cache) narrows the view
+    to the table columns a query of this step can see
+    (ops/paged_attention.py:window_tables); ``view_tables`` are those
+    columns, the whole table otherwise."""
+
+    def __init__(self, cache: dict, T: int, window: int | None = None):
+        self.lens = cache["len"]
+        self.paged = "block_tables" in cache
+        if self.paged:
+            num_blocks, block_size = cache["pos"].shape
+            tables = cache["block_tables"]
+            self.phys, self.off = _write_targets(
+                tables, self.lens, T, block_size, num_blocks)
+            if window is not None:
+                tables = window_tables(tables, self.lens, T, window,
+                                       block_size)
+            self.view_tables = tables
+            self._gather = _gather_tables(tables)
+        elif self.lens.ndim:
+            # per-slot cursors: scatter each row at its own depth (OOB
+            # writes for exhausted slots are dropped by the default mode)
+            B = cache["pos"].shape[0]
+            self.rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+            self.idx = (self.lens[:, None]
+                        + jnp.arange(T, dtype=jnp.int32)[None, :])
+
+    def write(self, leaf, li, new):
+        """``new [B, T, ...]`` (the leaf's dtype and trailing dims) into
+        layer ``li`` of ``leaf``."""
+        if self.paged:
+            return leaf.at[li, self.phys, self.off].set(new)
+        if self.lens.ndim == 0:
+            return jax.lax.dynamic_update_slice(
+                leaf, new[None],
+                (li, 0, self.lens) + (0,) * (leaf.ndim - 3))
+        return leaf.at[li, self.rows, self.idx].set(new)
+
+    def read(self, leaf, li):
+        """What attention reads of layer ``li`` AFTER the write: ``[B, S,
+        ...]``, the gathered per-slot linear view of a paged leaf
+        (element-identical to a dense row for every written lane,
+        sentinel-masked elsewhere) or the layer's dense rows."""
+        if not self.paged:
+            return leaf[li]
+        view = leaf[li, self._gather]  # [B, n, bs, ...]
+        return view.reshape((view.shape[0], -1) + view.shape[3:])
 
 
-def kv_cache_update(cache: dict, ck, cv, cks, cvs, k, v):
-    """One layer's cache write + full-width read.
+def kv_cache_write(step: KVStep, leaves: dict, li, k, v) -> dict:
+    """One layer's cache write. ``leaves`` are the carried stacked cache
+    leaves (``k``/``v`` ``[L, rows, lanes, KV * d]`` and, for the int8 cache,
+    ``k_scale``/``v_scale`` ``[L, rows, lanes, KV]``); ``k``/``v`` the new
+    tokens' projections ``[B, T, KV, d]``, quantized on write for the int8
+    cache. Alone it is the Pallas kernel path's write: the kernel then reads
+    the blocks in place."""
+    B, T = k.shape[:2]
+    out = dict(leaves)
+    for name, new in (("k", k), ("v", v)):
+        if "k_scale" in leaves:
+            new, scale = kv_quantize(new)
+            out[name + "_scale"] = step.write(
+                leaves[name + "_scale"], li, scale)
+        out[name] = step.write(
+            leaves[name], li,
+            new.astype(leaves[name].dtype).reshape(B, T, -1))
+    return out
 
-    ``ck``/``cv`` (and int8 scale pools ``cks``/``cvs``) are the layer-peeled
-    cache leaves the scan threads; ``k``/``v`` the new tokens' projections
-    [B, T, KV, d]. Returns the updated leaves plus ``k_att``/``v_att`` — the
-    [B, W, KV, d] views attention reads, dequantized when quantized."""
-    if cks is not None:  # int8 cache: quantize new k/v on write
-        k_w, ks_w = kv_quantize(k)
-        v_w, vs_w = kv_quantize(v)
-    else:
-        k_w, v_w = k.astype(ck.dtype), v.astype(cv.dtype)
-        ks_w = vs_w = None
-    if "block_tables" in cache:
-        ck, cv, cks, cvs, k_all, v_all, ks_all, vs_all = paged_kv_update(
-            ck, cv, cks, cvs, cache["block_tables"], cache["len"],
-            k_w, v_w, ks_w, vs_w)
-        if cks is not None:
-            return ck, cv, cks, cvs, \
-                kv_dequantize(k_all, ks_all, k.dtype), \
-                kv_dequantize(v_all, vs_all, v.dtype)
-        return ck, cv, cks, cvs, k_all.astype(k.dtype), v_all.astype(v.dtype)
-    B, T = k.shape[0], k.shape[1]
-    start = cache["len"]
-    if start.ndim == 0:
-        ck = jax.lax.dynamic_update_slice(ck, k_w, (0, start, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v_w, (0, start, 0, 0))
-        if cks is not None:
-            cks = jax.lax.dynamic_update_slice(cks, ks_w, (0, start, 0))
-            cvs = jax.lax.dynamic_update_slice(cvs, vs_w, (0, start, 0))
-    else:
-        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-        idx = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-        ck = ck.at[rows, idx].set(k_w)
-        cv = cv.at[rows, idx].set(v_w)
-        if cks is not None:
-            cks = cks.at[rows, idx].set(ks_w)
-            cvs = cvs.at[rows, idx].set(vs_w)
-    if cks is not None:
-        k_att = kv_dequantize(ck, cks, k.dtype)
-        v_att = kv_dequantize(cv, cvs, v.dtype)
-    else:
-        k_att, v_att = ck.astype(k.dtype), cv.astype(v.dtype)
-    return ck, cv, cks, cvs, k_att, v_att
+
+def kv_cache_update(step: KVStep, leaves: dict, li, k, v):
+    """One layer's cache write + full-width read: the updated leaves plus
+    ``k_att``/``v_att``, the ``[B, W, KV, d]`` views attention reads,
+    dequantized when quantized."""
+    leaves = kv_cache_write(step, leaves, li, k, v)
+    KV, d = k.shape[2:]
+
+    def view(name, like):
+        x = step.read(leaves[name], li)
+        x = x.reshape(x.shape[:2] + (KV, d))
+        if "k_scale" in leaves:
+            return kv_dequantize(
+                x, step.read(leaves[name + "_scale"], li), like.dtype)
+        return x.astype(like.dtype)
+
+    return leaves, view("k", k), view("v", v)
 
 
 def compact_window(cache: dict, participate: jnp.ndarray, len0: jnp.ndarray,

@@ -4,9 +4,10 @@ Sarathi chunked prefill — PAPERS.md).
 The dense serving cache reserves ``slots × max_seq_len`` KV rows up front, so
 a 40-token chat strands the other 984 positions of its slot in HBM for its
 whole lifetime. Here the cache is a POOL of fixed-size blocks
-(``block_size`` tokens each, shaped ``[L, num_blocks, block_size, KV, d]``)
-plus a per-slot block table mapping linear cache positions to physical
-blocks. Admission reserves ``ceil((prompt + max_new) / block_size)`` blocks
+(``block_size`` tokens each, shaped ``[L, num_blocks, block_size, KV * d]``:
+heads and width are one axis, the layout the Pallas kernels read a block in,
+so a program hands them the leaf as it is stored) plus a per-slot block table
+mapping linear cache positions to physical blocks. Admission reserves ``ceil((prompt + max_new) / block_size)`` blocks
 from a host-side free list instead of a full-width row, so short requests
 release most of the HBM a dense slot would strand and the same pool admits
 more concurrent work (or the same work in less HBM).
@@ -26,8 +27,12 @@ The int8 ``kv_quant`` path is preserved: scale pools are paged alongside the
 value pools with the same tables.
 
 This module is wired into the model through ``ops/attention.py``'s cache
-interface (``cache_positions_update`` / ``kv_cache_update``): a cache dict
-carrying ``block_tables`` takes the paged path, anything else the dense one.
+interface (``cache_positions_update`` / ``KVStep`` / ``kv_cache_update``): a
+cache dict carrying ``block_tables`` takes the paged path, anything else the
+dense one. The layer scan CARRIES the stacked leaves: a layer scatters its
+tokens at ``[layer, block, offset]`` and gathers ``leaf[layer, tables]``, so
+no program slices a layer out of a leaf or stacks one back, and a program
+that returns the cache is given it to consume (donated) and writes in place.
 """
 
 from __future__ import annotations
@@ -198,7 +203,8 @@ def init_paged_cache(cfg, slots: int, num_blocks: int, block_size: int,
         return init(cfg, slots, num_blocks, block_size, blocks_per_slot,
                     dtype=dtype, quantize=quantize)
     L = cfg.num_layers
-    shape = (L, num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
+    shape = (L, num_blocks, block_size, cfg.num_kv_heads * cfg.head_dim)
+    scales = shape[:-1] + (cfg.num_kv_heads,)
     cache: Dict = {
         "len": jnp.zeros((slots,), jnp.int32),
         "pos": jnp.full((num_blocks, block_size), POS_SENTINEL, jnp.int32),
@@ -207,8 +213,8 @@ def init_paged_cache(cfg, slots: int, num_blocks: int, block_size: int,
     if quantize == "int8":
         cache["k"] = jnp.zeros(shape, jnp.int8)
         cache["v"] = jnp.zeros(shape, jnp.int8)
-        cache["k_scale"] = jnp.zeros(shape[:-1], jnp.float32)
-        cache["v_scale"] = jnp.zeros(shape[:-1], jnp.float32)
+        cache["k_scale"] = jnp.zeros(scales, jnp.float32)
+        cache["v_scale"] = jnp.zeros(scales, jnp.float32)
     elif quantize:
         raise ValueError(f"unsupported cache quantization {quantize!r}")
     else:
@@ -307,41 +313,6 @@ def paged_record_positions(cache: Dict, pos_update: jnp.ndarray,
     return new_pool, gathered.reshape(tables.shape[0], -1)
 
 
-def paged_kv_write(ck, cv, cks, cvs, tables, lens, k_w, v_w, ks_w, vs_w):
-    """Per-layer paged write WITHOUT the gathered read-back — the Pallas
-    kernel decode path's half of ``paged_kv_update``: scatter the new
-    tokens' K/V (and int8 scales) through the block tables and return the
-    updated pools; attention then reads the blocks in place."""
-    num_blocks, block_size = ck.shape[0], ck.shape[1]
-    phys, off = _write_targets(tables, lens, k_w.shape[1],
-                               block_size, num_blocks)
-    ck = ck.at[phys, off].set(k_w)
-    cv = cv.at[phys, off].set(v_w)
-    if cks is not None:
-        cks = cks.at[phys, off].set(ks_w)
-        cvs = cvs.at[phys, off].set(vs_w)
-    return ck, cv, cks, cvs
-
-
-def paged_kv_update(ck, cv, cks, cvs, tables, lens, k_w, v_w, ks_w, vs_w):
-    """Per-layer paged write + gathered read.
-
-    ``ck``/``cv`` are one layer's pools ``[NB, bs, KV, d]`` (the layer scan
-    peels the leading L axis); ``k_w``/``v_w`` the new tokens ``[B, T, KV,
-    d]``. Returns updated pools plus the gathered ``[B, W, KV, d]`` views
-    attention reads — element-identical to a dense row for every written
-    lane, sentinel-masked elsewhere."""
-    B = k_w.shape[0]
-    ck, cv, cks, cvs = paged_kv_write(ck, cv, cks, cvs, tables, lens,
-                                      k_w, v_w, ks_w, vs_w)
-    tbl = _gather_tables(tables)
-    k_all = ck[tbl].reshape(B, -1, ck.shape[-2], ck.shape[-1])
-    v_all = cv[tbl].reshape(B, -1, cv.shape[-2], cv.shape[-1])
-    ks_all = cks[tbl].reshape(B, -1, cks.shape[-1]) if cks is not None else None
-    vs_all = cvs[tbl].reshape(B, -1, cvs.shape[-1]) if cvs is not None else None
-    return ck, cv, cks, cvs, k_all, v_all, ks_all, vs_all
-
-
 # --------------------------------------------------------- row import/export
 def _row_targets(table_row: jnp.ndarray, width: int, block_size: int,
                  num_blocks: int):
@@ -356,7 +327,7 @@ def _row_targets(table_row: jnp.ndarray, width: int, block_size: int,
 def paged_insert_row(cache: Dict, slot, table_row: jnp.ndarray,
                      row_cache: Dict) -> Dict:
     """Scatter a dense single-row cache (a prefill/prefix-cache product,
-    ``k [L, 1, W, KV, d]``) into the slot's blocks and install its table.
+    ``k [L, 1, W, KV * d]``) into the slot's blocks and install its table.
     Positions beyond the row's cursor are POS_SENTINEL in the row already,
     so writing the full width doubles as the block scrub. Linear positions
     past the slot's allocation are dropped (no block — nothing to strand)."""

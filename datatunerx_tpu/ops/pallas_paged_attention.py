@@ -1,7 +1,7 @@
 """Pallas in-place paged-attention decode kernel (vLLM PagedAttention done
 natively — PAPERS.md; the ROADMAP "Decode fast path" arc).
 
-The XLA gather path (ops/paged_attention.py ``paged_kv_update``) is
+The XLA gather path (ops/attention.py ``kv_cache_update``) is
 token-exact but materializes a dense-equivalent ``[B, W, KV, d]`` linear
 view of every slot's blocks per layer per decode step: the block pool saves
 HBM *capacity* while decode still pays dense HBM *bandwidth* — a full-width
@@ -26,6 +26,13 @@ That drives the kernel's two-phase shape:
   single-pass accumulator cannot (it would normalize after the cast).
   Differences vs the oracle reduce to f32 summation order (~1e-7
   relative), which greedy/sampled token streams don't see.
+
+The kernels take the STACKED pool the layer scan carries (``[L, NB, bs,
+KV·d]``, int8 scales ``[L, NB, bs, KV]``) and the layer as a scalar-prefetch
+operand: layer ``l``'s block ``n`` is row ``l·NB + n`` of the pool viewed as
+``[L·NB, bs, KV·d]`` (merging leading dims moves nothing), so no layer is
+sliced out of a leaf for them; the pos pool is shared by the layers and is
+addressed by the plain block id.
 
 Masking needs no bias tensor: a table entry < 0 skips its block outright
 (``pl.when``), and within a block the pos pool — POS_SENTINEL on every
@@ -62,8 +69,8 @@ def _interpret() -> bool:
     return interpret_default()
 
 
-def _decode_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                   pos_ref, o_ref, acc_ref, m_ref, l_ref,
+def _decode_kernel(tables_ref, qpos_ref, layer_ref, q_ref, k_ref, v_ref,
+                   ks_ref, vs_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref,
                    *, nbps: int, kv_heads: int, group: int, scale: float,
                    quant: bool):
     """One (slot, table-entry, phase) grid step.
@@ -71,7 +78,8 @@ def _decode_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     Grid is ``(B, 2 * nbps)``: the trailing dim walks the slot's table twice
     — ``j < nbps`` is the stats phase, ``j >= nbps`` the weighted-sum phase.
     Block j's K/V/pos land in VMEM via the scalar-prefetched table (invalid
-    entries clamp to physical block 0 and are skipped by ``pl.when``)."""
+    entries clamp to physical block 0 and are skipped by ``pl.when``);
+    ``layer_ref`` is read by the index maps alone."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     jj = j - (j // nbps) * nbps  # table column this step covers
@@ -89,8 +97,7 @@ def _decode_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     def _heads(ref, scale_ref):
         """The block's per-head [bs, d] tiles, dequantized when quantized.
 
-        Pools arrive with the (KV, d) trailing dims MERGED ([1, bs, KV·d]
-        blocks): Mosaic cannot slice the middle dim of an int8 tile (and
+        A pool's last axis is (KV, d) MERGED ([1, bs, KV·d] blocks): Mosaic cannot slice the middle dim of an int8 tile (and
         per-head (…, 1, d) trailing block dims are illegal tilings), so the
         whole tile is loaded/converted 2D and each head is a static
         lane-dim slice — the nf4 kernel's planar-unpack idiom."""
@@ -155,12 +162,21 @@ def _decode_kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         o_ref[0] = acc_ref[:].astype(o_ref.dtype)
 
 
+def _stacked(pool: jnp.ndarray) -> jnp.ndarray:
+    """A stacked leaf ``[L, NB, bs, w]`` as the kernels address it, ``[L * NB,
+    bs, w]``: layer ``l``'s block ``n`` is row ``l * NB + n``. Merging the
+    leading dims moves nothing, so the kernel reads the cache leaf the layer
+    scan carries, in place, and no layer of it is sliced out."""
+    return pool.reshape((-1,) + pool.shape[2:])
+
+
 def paged_decode_attention(
     q: jnp.ndarray,          # [B, H, d] — the decode step's single token
-    k_pool: jnp.ndarray,     # [NB, bs, KV, d] one layer's block pool
+    k_pool: jnp.ndarray,     # [L, NB, bs, KV * d] the stacked block pool
     v_pool: jnp.ndarray,
-    k_scale: Optional[jnp.ndarray],  # [NB, bs, KV] f32 (int8 pools) | None
+    k_scale: Optional[jnp.ndarray],  # [L, NB, bs, KV] f32 (int8) | None
     v_scale: Optional[jnp.ndarray],
+    layer,                   # int32 scalar: the layer whose blocks are read
     tables: jnp.ndarray,     # [B, nbps] int32, -1 = unallocated
     pos_pool: jnp.ndarray,   # [NB, bs] int32 — POST-write (this token's rope
                              # position already scattered in)
@@ -175,7 +191,8 @@ def paged_decode_attention(
     mirroring the garbage the oracle's sentinel-masked uniform softmax
     yields for such rows."""
     B, H, d = q.shape
-    NB, bs, KV, _ = k_pool.shape
+    _, NB, bs, width = k_pool.shape
+    KV = width // d
     nbps = tables.shape[1]
     G = H // KV
     quant = k_scale is not None
@@ -189,34 +206,41 @@ def paged_decode_attention(
         _decode_kernel, nbps=nbps, kv_heads=KV, group=G,
         scale=scale, quant=quant)
 
-    def kv_index(b, j, tables_ref, qpos_ref):
+    def pos_index(b, j, tables_ref, qpos_ref, layer_ref):
         # clamp -1 → block 0: the DMA must stay in bounds; pl.when skips
         # the compute, so the fetched garbage is never read
         return (jnp.maximum(tables_ref[b, j - (j // nbps) * nbps], 0), 0, 0)
 
-    pos_index = scale_index = kv_index
+    def kv_index(b, j, tables_ref, qpos_ref, layer_ref):
+        # the layer's blocks start at row layer * NB of the stacked pool;
+        # the pos pool is shared by the layers and keeps the plain id
+        blk, _, _ = pos_index(b, j, tables_ref, qpos_ref, layer_ref)
+        return (layer_ref[0] * NB + blk, 0, 0)
 
-    def v_index(b, j, tables_ref, qpos_ref):
-        # V is consumed in phase 1 only; parking the index on block 0
-        # during phase 0 keeps Mosaic's same-block revisit from re-DMAing
-        # anything useless (interpret mode is indifferent)
+    scale_index = kv_index
+
+    def v_index(b, j, tables_ref, qpos_ref, layer_ref):
+        # V is consumed in phase 1 only; parking the index on the layer's
+        # block 0 during phase 0 keeps Mosaic's same-block revisit from
+        # re-DMAing anything useless (interpret mode is indifferent)
         jj = j - (j // nbps) * nbps
-        return (jnp.maximum(tables_ref[b, jj], 0) * (j >= nbps), 0, 0)
+        return (layer_ref[0] * NB
+                + jnp.maximum(tables_ref[b, jj], 0) * (j >= nbps), 0, 0)
 
-    # pools enter the kernel with (KV, d) merged — [NB, bs, KV·d] — a free
-    # trailing-dims reshape that makes every per-head extraction a static
-    # LANE slice (Mosaic cannot slice the middle dim of an int8 tile)
+    # a pool's last axis is (KV, d) merged, which makes every per-head
+    # extraction a static LANE slice (Mosaic cannot slice the middle dim of
+    # an int8 tile); the cache stores its leaves that way
+    # (ops/paged_attention.py), so nothing is reshaped on the way in
     in_specs = [
-        pl.BlockSpec((1, H, d), lambda b, j, t, p: (b, 0, 0)),
+        pl.BlockSpec((1, H, d), lambda b, j, t, p, l: (b, 0, 0)),
         pl.BlockSpec((1, bs, KV * d), kv_index),
         pl.BlockSpec((1, bs, KV * d), v_index),
     ]
-    args = [q, k_pool.reshape(NB, bs, KV * d),
-            v_pool.reshape(NB, bs, KV * d)]
+    args = [q, _stacked(k_pool), _stacked(v_pool)]
     if quant:
         in_specs += [pl.BlockSpec((1, bs, KV), scale_index),
                      pl.BlockSpec((1, bs, KV), scale_index)]
-        args += [k_scale, v_scale]
+        args += [_stacked(k_scale), _stacked(v_scale)]
     in_specs.append(pl.BlockSpec((1, 1, bs), pos_index))
     args.append(pos_pool[:, None])  # [NB, 1, bs]: Mosaic-legal tiling
 
@@ -225,10 +249,11 @@ def paged_decode_attention(
     out = pl.pallas_call(
         kernel_args,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, 2 * nbps),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, H, d), lambda b, j, t, p: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, d),
+                                   lambda b, j, t, p, l: (b, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((H, d), jnp.float32),
                 pltpu.VMEM((H, _LANES), jnp.float32),
@@ -238,27 +263,36 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((B, H, d), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
         name="dtx_paged_decode",
-    )(tables.astype(jnp.int32), q_positions.astype(jnp.int32), *args)
+    )(tables.astype(jnp.int32), q_positions.astype(jnp.int32),
+      _layer_operand(layer), *args)
     return out
 
 
-def _no_scale_kernel(kernel, tables_ref, qpos_ref, q_ref, k_ref, v_ref,
-                     pos_ref, o_ref, acc_ref, m_ref, l_ref):
+def _layer_operand(layer) -> jnp.ndarray:
+    """The layer index as a scalar-prefetch operand: int32 ``[1]``."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _no_scale_kernel(kernel, tables_ref, qpos_ref, layer_ref, q_ref, k_ref,
+                     v_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref):
     """Arity shim for the unquantized pools: no scale refs in the call."""
-    kernel(tables_ref, qpos_ref, q_ref, k_ref, v_ref, None, None,
+    kernel(tables_ref, qpos_ref, layer_ref, q_ref, k_ref, v_ref, None, None,
            pos_ref, o_ref, acc_ref, m_ref, l_ref)
 
 
-def paged_attention_decode_step(q, ck, cv, cks, cvs, cache: dict,
+def paged_attention_decode_step(q, leaves: dict, layer, cache: dict,
                                 pos_pool, positions, *, interpret=None):
     """Model-facing wrapper: q ``[B, 1, H, d]`` (one decode token), the
-    layer-peeled pools, the live cache dict (block tables), the POST-write
-    pos pool, and the step's ``positions [B, 1]``. Returns ``[B, 1, H, d]``
-    in q.dtype — drop-in for the gather + ``xla_attention`` pair."""
+    stacked cache leaves the layer scan carries (``k``/``v`` and, for the
+    int8 cache, ``k_scale``/``v_scale``), the layer's index, the live cache
+    dict (block tables), the POST-write pos pool, and the step's
+    ``positions [B, 1]``. Returns ``[B, 1, H, d]`` in q.dtype — drop-in for
+    the gather + ``xla_attention`` pair."""
     B, T, H, d = q.shape
     assert T == 1, f"paged decode kernel is single-token (T=1), got T={T}"
     out = paged_decode_attention(
-        q[:, 0], ck, cv, cks, cvs, cache["block_tables"], pos_pool,
+        q[:, 0], leaves["k"], leaves["v"], leaves.get("k_scale"),
+        leaves.get("v_scale"), layer, cache["block_tables"], pos_pool,
         positions[:, 0], interpret=interpret)
     return out[:, None]
 
@@ -316,8 +350,8 @@ def _mt_tiling(q_len: int, heads: int) -> tuple[int, int]:
     return tp, tq
 
 
-def _multitoken_kernel(tables_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                       allow_ref, o_ref, acc_ref, m_ref, l_ref,
+def _multitoken_kernel(tables_ref, layer_ref, q_ref, k_ref, v_ref, ks_ref,
+                       vs_ref, allow_ref, o_ref, acc_ref, m_ref, l_ref,
                        *, nbps: int, kv_heads: int, group: int, q_len: int,
                        scale: float, quant: bool):
     """One (slot, query tile, table-entry × phase) grid step for ``q_len``
@@ -396,10 +430,11 @@ def _multitoken_kernel(tables_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
 def paged_multitoken_attention(
     q: jnp.ndarray,          # [B, T, H, d] — the step's query columns
-    k_pool: jnp.ndarray,     # [NB, bs, KV, d] one layer's block pool
+    k_pool: jnp.ndarray,     # [L, NB, bs, KV * d] the stacked block pool
     v_pool: jnp.ndarray,
-    k_scale: Optional[jnp.ndarray],  # [NB, bs, KV] f32 (int8 pools) | None
+    k_scale: Optional[jnp.ndarray],  # [L, NB, bs, KV] f32 (int8) | None
     v_scale: Optional[jnp.ndarray],
+    layer,                   # int32 scalar: the layer whose blocks are read
     tables: jnp.ndarray,     # [B, nbps] int32, -1 = unallocated
     allow: jnp.ndarray,      # [B, T, nbps·bs] bool/int — attendability per
                              # (query row, linear cache lane), POST-write
@@ -411,7 +446,8 @@ def paged_multitoken_attention(
     ``allow`` must be ``attention_allow(...)`` over the POST-write gathered
     kv positions — the one tensor the gather oracle biases with."""
     B, T, H, d = q.shape
-    NB, bs, KV, _ = k_pool.shape
+    _, NB, bs, width = k_pool.shape
+    KV = width // d
     nbps = tables.shape[1]
     G = H // KV
     quant = k_scale is not None
@@ -425,19 +461,22 @@ def paged_multitoken_attention(
         _multitoken_kernel, nbps=nbps, kv_heads=KV, group=G, q_len=tq,
         scale=scale, quant=quant)
 
-    def kv_index(b, i, j, tables_ref):
-        return (jnp.maximum(tables_ref[b, j - (j // nbps) * nbps], 0), 0, 0)
+    def kv_index(b, i, j, tables_ref, layer_ref):
+        # as in the decode kernel: row layer * NB + block of the stacked pool
+        return (layer_ref[0] * NB
+                + jnp.maximum(tables_ref[b, j - (j // nbps) * nbps], 0), 0, 0)
 
     scale_index = kv_index
 
-    def v_index(b, i, j, tables_ref):
+    def v_index(b, i, j, tables_ref, layer_ref):
         jj = j - (j // nbps) * nbps
-        return (jnp.maximum(tables_ref[b, jj], 0) * (j >= nbps), 0, 0)
+        return (layer_ref[0] * NB
+                + jnp.maximum(tables_ref[b, jj], 0) * (j >= nbps), 0, 0)
 
-    def allow_index(b, i, j, tables_ref):
+    def allow_index(b, i, j, tables_ref, layer_ref):
         return (b, i, j - (j // nbps) * nbps, 0, 0)
 
-    def q_index(b, i, j, tables_ref):
+    def q_index(b, i, j, tables_ref, layer_ref):
         return (b, i, 0, 0)
 
     # pad rows are never attendable (allow 0) and are sliced off the output
@@ -453,12 +492,11 @@ def paged_multitoken_attention(
         pl.BlockSpec((1, bs, KV * d), kv_index),
         pl.BlockSpec((1, bs, KV * d), v_index),
     ]
-    args = [q_km, k_pool.reshape(NB, bs, KV * d),
-            v_pool.reshape(NB, bs, KV * d)]
+    args = [q_km, _stacked(k_pool), _stacked(v_pool)]
     if quant:
         in_specs += [pl.BlockSpec((1, bs, KV), scale_index),
                      pl.BlockSpec((1, bs, KV), scale_index)]
-        args += [k_scale, v_scale]
+        args += [_stacked(k_scale), _stacked(v_scale)]
     in_specs.append(pl.BlockSpec((1, 1, 1, tq, bs), allow_index))
     args.append(allow_t)
 
@@ -467,7 +505,7 @@ def paged_multitoken_attention(
     out = pl.pallas_call(
         kernel_args,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(B, nT, 2 * nbps),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, 1, H * tq, d), q_index),
@@ -480,24 +518,26 @@ def paged_multitoken_attention(
         out_shape=jax.ShapeDtypeStruct((B, nT, H * tq, d), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
         name="dtx_paged_multitoken",
-    )(tables.astype(jnp.int32), *args)
+    )(tables.astype(jnp.int32), _layer_operand(layer), *args)
     out = out.reshape(B, nT, H, tq, d).transpose(0, 1, 3, 2, 4)
     return out.reshape(B, Tp, H, d)[:, :T]
 
 
-def _no_scale_mt_kernel(kernel, tables_ref, q_ref, k_ref, v_ref, allow_ref,
-                        o_ref, acc_ref, m_ref, l_ref):
+def _no_scale_mt_kernel(kernel, tables_ref, layer_ref, q_ref, k_ref, v_ref,
+                        allow_ref, o_ref, acc_ref, m_ref, l_ref):
     """Arity shim for the unquantized pools: no scale refs in the call."""
-    kernel(tables_ref, q_ref, k_ref, v_ref, None, None, allow_ref,
+    kernel(tables_ref, layer_ref, q_ref, k_ref, v_ref, None, None, allow_ref,
            o_ref, acc_ref, m_ref, l_ref)
 
 
-def paged_attention_multitoken_step(q, ck, cv, cks, cvs, cache: dict,
+def paged_attention_multitoken_step(q, leaves: dict, layer, cache: dict,
                                     allow, *, interpret=None):
     """Model-facing wrapper: q ``[B, T, H, d]`` (chunk / verify columns),
-    the layer-peeled pools, the live cache dict, and the POST-write
-    ``allow [B, T, S]`` attendability tensor. Returns ``[B, T, H, d]`` in
-    q.dtype — drop-in for the gather + ``xla_attention`` pair."""
+    the stacked cache leaves the layer scan carries, the layer's index, the
+    live cache dict, and the POST-write ``allow [B, T, S]`` attendability
+    tensor. Returns ``[B, T, H, d]`` in q.dtype — drop-in for the gather +
+    ``xla_attention`` pair."""
     return paged_multitoken_attention(
-        q, ck, cv, cks, cvs, cache["block_tables"], allow,
+        q, leaves["k"], leaves["v"], leaves.get("k_scale"),
+        leaves.get("v_scale"), layer, cache["block_tables"], allow,
         interpret=interpret)

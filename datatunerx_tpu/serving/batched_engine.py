@@ -404,6 +404,16 @@ class _Programs:
     argument, which is what makes the programs shareable across engines in
     the first place.
 
+    The KV cache is CARRIED and CONSUMED: inside a program the layer scan
+    carries the stacked leaves and each layer writes its tokens at its own
+    index (models/llama.py, models/hybrid.py), and ``decode``,
+    ``prefill_chunk``, ``insert``, ``insert_paged`` and ``copy_block`` donate
+    the cache they are given (``decode``, ``insert*`` and ``activate`` the
+    per-slot state arrays they return as well), so from the engine's handle
+    down to a layer's scatter nothing copies a pool, a leaf or a layer of
+    one. ``extract``, ``prefill`` and ``extend`` take no cache of the
+    engine's or only read it.
+
     ``lora`` is ``None`` (base-only engine) or ``(tree, scales)`` with
     stacked ``[L, E, …]`` leaves; None-vs-tuple is pytree STRUCTURE, so jax
     compiles the two cases separately and, within the adapter case, per
@@ -422,16 +432,27 @@ class _Programs:
                                static_argnames=("prompt_len",))
         self.extend = jax.jit(self._extend_impl,
                               static_argnames=("suffix_len",))
-        self.insert = jax.jit(self._insert_impl)
-        self.insert_paged = jax.jit(self._insert_paged_impl)
-        self.activate = jax.jit(self._activate_impl)
+        # a program that RETURNS the engine's cache (or its per-slot decode
+        # state) CONSUMES the one it is given: the argument is donated, so
+        # the pool is written in place, one copy of it is alive, and the
+        # caller's handle is dead after the call (the engine rebinds
+        # ``self._cache`` / the state arrays from the result at every call)
+        self.insert = jax.jit(self._insert_impl,
+                              donate_argnums=tuple(range(10)))
+        self.insert_paged = jax.jit(self._insert_paged_impl,
+                                    donate_argnums=tuple(range(10)))
+        self.activate = jax.jit(self._activate_impl,
+                                donate_argnums=tuple(range(9)))
         self.prefill_chunk = jax.jit(self._prefill_chunk_impl,
-                                     static_argnames=("chunk_len",))
+                                     static_argnames=("chunk_len",),
+                                     donate_argnums=(2,))
+        # extract only reads: the slot's row is a copy, the cache stays
         self.extract = jax.jit(paged_extract_row,
                                static_argnames=("width",))
-        self.copy_block = jax.jit(paged_copy_block)
+        self.copy_block = jax.jit(paged_copy_block, donate_argnums=(0,))
         self.decode = jax.jit(self._decode_impl,
-                              static_argnames=("K", "mode"))
+                              static_argnames=("K", "mode"),
+                              donate_argnums=(2, 3, 4, 5, 6, 7))
 
     def _prefill_impl(self, params, lora, tokens, mask, positions,
                       adapter_idx, *, prompt_len: int):
@@ -2001,6 +2022,16 @@ class BatchedEngine:
                             chunk_len=c,
                         )
                 except Exception as e:  # noqa: BLE001 — fail request, not loop
+                    # the chunk program consumes the cache it is given. An
+                    # error raised while it is traced or compiled (a shape,
+                    # the compiler's refusal) comes before the call takes
+                    # the buffers: self._cache is intact, this request
+                    # fails and the engine serves on. A fault of the
+                    # RUNNING program leaves the donated leaves deleted:
+                    # the scheduler then stops serving (_stop_serving)
+                    # rather than go on with a pool it no longer has.
+                    if self._cache_consumed():
+                        raise
                     self._release_slot(slot)
                     self._complete(req, error=str(e))
                     break
@@ -2801,7 +2832,8 @@ class BatchedEngine:
                     "no_reuse": bool(ent.get("no_reuse", False)),
                     "logits": (None if ent.get("logits") is None
                                else mig.pack_logits(ent["logits"])),
-                    "kv": mig.pack_kv_row(row, cursor, wire),
+                    "kv": mig.pack_kv_row(
+                        row, cursor, wire, kv_heads=self.cfg.num_kv_heads),
                     "model_sig": mig.model_signature(self.cfg,
                                                      self.kv_quant),
                 })
@@ -3447,7 +3479,36 @@ class BatchedEngine:
         # session open a TraceAnnotation is a flag test
         while not self._shutdown.is_set():
             with jax.profiler.TraceAnnotation("dtx_engine_tick"):
-                self._tick()
+                try:
+                    self._tick()
+                except Exception as e:  # noqa: BLE001 — judged just below
+                    if not self._cache_consumed():
+                        raise
+                    self._stop_serving(e)
+
+    def _cache_consumed(self) -> bool:
+        """True after a program that was given the cache to consume (every
+        program that returns it: ``_Programs``) failed WHILE IT RAN: its
+        donated leaves are deleted and nothing was returned in their place.
+        An error raised while a program is traced or compiled comes before
+        the call takes the buffers and leaves the cache intact."""
+        return any(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(self._cache))
+
+    def _stop_serving(self, error: Exception):
+        """The pool went with a failed program: every live session's KV is
+        lost and the slot handlers themselves (``_release_slot`` clears a
+        row of the block table) raise on the deleted leaves. Fail what is in
+        flight or waiting and stop the scheduler: ``submit`` refuses from
+        here on, as it does after ``close``."""
+        msg = f"engine stopped, KV cache lost to a failed program: {error}"
+        self._shutdown.set()
+        live = [r for r in self._slot_req if r is not None]
+        self._slot_req = [None] * self.slots
+        while (req := self._take_waiting()) is not None:
+            live.append(req)
+        for req in live:
+            self._complete(req, error=msg)
 
     def _tick(self):
         """One pass of the scheduler: admissions, at most a budget of
@@ -3502,6 +3563,8 @@ class BatchedEngine:
                     if "moe_stats" in self._cache:
                         self._note_moe()
         except Exception as e:  # noqa: BLE001 — device fault: fail all in-flight
+            if self._cache_consumed():
+                raise  # the pool went with the program: _stop_serving
             for slot, req in enumerate(self._slot_req):
                 if req is not None:
                     self._release_slot(slot)
@@ -3580,6 +3643,8 @@ class BatchedEngine:
                       trace_id=trace_id or f"dtx-{uuid.uuid4().hex[:16]}",
                       tenant=tenant_name or (tenant or ""),
                       tenant_tier=tier)
+        if self._shutdown.is_set():
+            raise RuntimeError("engine is shut down")
         self._waiting.put(req)
         self._wake.set()
         return req

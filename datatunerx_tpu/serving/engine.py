@@ -49,8 +49,12 @@ class _EnginePrograms:
 
     def __init__(self, cfg):
         self.cfg = cfg
+        # prefill returns the cache it is given and consumes it (donated),
+        # as every such program of batched_engine._Programs does; the decode
+        # loop returns no cache, so there is nothing for a donation to alias
         self.prefill = jax.jit(self._prefill_impl,
-                               static_argnames=("prompt_len",))
+                               static_argnames=("prompt_len",),
+                               donate_argnums=(4,))
         # whole decode loop in ONE device program (lax.while_loop): no
         # per-token Python dispatch
         self.decode_loop = jax.jit(self._decode_loop_impl,

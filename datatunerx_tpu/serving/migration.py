@@ -178,9 +178,13 @@ def prefix_fingerprint(adapter: str, prompt_ids: Sequence[int]) -> str:
     return h.hexdigest()
 
 
-def pack_kv_row(row: Dict, cursor: int, wire: str, b64: bool = True) -> dict:
+def pack_kv_row(row: Dict, cursor: int, wire: str, b64: bool = True,
+                kv_heads: Optional[int] = None) -> dict:
     """A dense row cache (``paged_extract_row`` output or a dense-cache
     slot slice) → JSON-safe wire doc, trimmed to the live ``cursor``.
+    ``kv_heads`` splits the row's last axis (heads and width are one axis in
+    the cache, two on the wire; the bytes are the same); an int8 row's
+    scales say it themselves.
 
     ``wire`` is "int8" or "bf16"; int8 input rows (kv_quant caches) are
     shipped as-is under "int8" (exact), and a bf16 row asked for "int8"
@@ -191,8 +195,12 @@ def pack_kv_row(row: Dict, cursor: int, wire: str, b64: bool = True) -> dict:
     row = row_trim(row, max(1, cursor))
     if "k" not in row:
         return _pack_pools(row, wire, b64)
-    k, v = row["k"], row["v"]
     quantized_cache = "k_scale" in row
+    KV = row["k_scale"].shape[-1] if quantized_cache else kv_heads
+    if not KV:
+        raise ValueError("pack_kv_row: a bf16 row needs kv_heads")
+    k, v = (row[key].reshape(row[key].shape[:3] + (KV, -1))
+            for key in ("k", "v"))
     if wire == "int8" and not quantized_cache:
         # host transfer happens inside kv_quantize's consumers; do the
         # quantization on device, then pull the small int8 bodies
@@ -259,7 +267,7 @@ def _unpack_pools(doc: dict, full_width: int) -> Dict:
 def unpack_kv_row(doc: dict, full_width: int,
                   quantize: Optional[str]) -> Dict:
     """Wire doc → a dense row cache dict shaped for this engine's cache
-    (``[L, 1, full_width, KV, d]`` + sentinel-padded positions), converting
+    (``[L, 1, full_width, KV * d]`` + sentinel-padded positions), converting
     between int8 and bf16 encodings as the target's ``quantize`` demands."""
     if "pools" in doc:
         return _unpack_pools(doc, full_width)
@@ -306,6 +314,8 @@ def unpack_kv_row(doc: dict, full_width: int,
             v = (v.astype(np.float32) * vs[..., None])
         row["k"] = _pad(k.astype(jnp.bfloat16))
         row["v"] = _pad(v.astype(jnp.bfloat16))
+    for key in ("k", "v"):  # heads and width are one axis in the cache
+        row[key] = row[key].reshape(row[key].shape[:3] + (KV * d,))
     return row
 
 
@@ -337,7 +347,8 @@ def build_payload(cfg, kv_quant: Optional[str], request: dict, row: Dict,
         "pos": int(pos), "remaining": int(remaining), "cursor": cursor,
         "rng": [int(x) for x in np.asarray(rng, np.uint32)],
         "logits": pack_logits(logits, b64=b64),
-        "kv": pack_kv_row(row, cursor, wire or default_wire, b64=b64),
+        "kv": pack_kv_row(row, cursor, wire or default_wire, b64=b64,
+                          kv_heads=cfg.num_kv_heads),
         "model_sig": model_signature(cfg, kv_quant),
     }
 

@@ -759,14 +759,19 @@ class SpecPrograms:
         # epilogue with that implementation (ops/pallas_sampling.py)
         self.epilogue = epilogue
         self.enter = jax.jit(self._enter_impl, static_argnames=("mode",))
-        self.prime = jax.jit(self._prime_impl)
-        self.step = jax.jit(self._step_impl, static_argnames=("k", "mode"))
+        # as in batched_engine._Programs: a program that returns a cache
+        # (the target's ``tcache``, the draft's ``dcache``) consumes the one
+        # it is given, so both pools are written in place
+        self.prime = jax.jit(self._prime_impl, donate_argnums=(1,))
+        self.step = jax.jit(self._step_impl, static_argnames=("k", "mode"),
+                            donate_argnums=(3, 4))
         self.tree_step = jax.jit(
             self._tree_step_impl,
-            static_argnames=("widths", "mode"))
+            static_argnames=("widths", "mode"), donate_argnums=(3, 4))
         self.decode = jax.jit(self._decode_pending_impl,
-                              static_argnames=("K", "mode"))
-        self.settle = jax.jit(self._settle_impl)
+                              static_argnames=("K", "mode"),
+                              donate_argnums=(2,))
+        self.settle = jax.jit(self._settle_impl, donate_argnums=(2,))
 
     # ---- one batched token draw, epilogue-aware
     def _draw(self, logits, temps, top_ps, rng, mode: str):
@@ -829,9 +834,9 @@ class SpecPrograms:
         )
         out = dict(dcache)
         out["k"] = jax.lax.dynamic_update_slice(
-            dcache["k"], row["k"], (0, slot, 0, 0, 0))
+            dcache["k"], row["k"], (0, slot, 0, 0))
         out["v"] = jax.lax.dynamic_update_slice(
-            dcache["v"], row["v"], (0, slot, 0, 0, 0))
+            dcache["v"], row["v"], (0, slot, 0, 0))
         out["pos"] = jax.lax.dynamic_update_slice(
             dcache["pos"], row["pos"], (slot, 0))
         out["len"] = dcache["len"].at[slot].set(prime_len)

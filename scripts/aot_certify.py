@@ -264,19 +264,24 @@ def kernel_artifacts(cert: Certifier, dev):
     tables = jax.ShapeDtypeStruct((Bd, nbps), jnp.int32, sharding=sh)
     pos = jax.ShapeDtypeStruct((NBd, bsd), jnp.int32, sharding=sh)
     qpos = jax.ShapeDtypeStruct((Bd,), jnp.int32, sharding=sh)
-    pool_bf16 = jax.ShapeDtypeStruct((NBd, bsd, KVd, dd), jnp.bfloat16,
+    # the kernels read one layer of the stacked pool the layer scan carries
+    # ([L, NB, bs, KV * d]); the layer is a traced scalar
+    Ld = ENGINE_LAYERS
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=sh)
+    pool_bf16 = jax.ShapeDtypeStruct((Ld, NBd, bsd, KVd * dd), jnp.bfloat16,
                                      sharding=sh)
-    pool_i8 = jax.ShapeDtypeStruct((NBd, bsd, KVd, dd), jnp.int8,
+    pool_i8 = jax.ShapeDtypeStruct((Ld, NBd, bsd, KVd * dd), jnp.int8,
                                    sharding=sh)
-    pool_sc = jax.ShapeDtypeStruct((NBd, bsd, KVd), jnp.float32, sharding=sh)
+    pool_sc = jax.ShapeDtypeStruct((Ld, NBd, bsd, KVd), jnp.float32,
+                                   sharding=sh)
     cert.run("kernel/paged_decode_bf16", lambda: _lower(
-        lambda q, k, v, t, p, qp: paged_decode_attention(
-            q, k, v, None, None, t, p, qp),
-        qd, pool_bf16, pool_bf16, tables, pos, qpos))
+        lambda q, k, v, li, t, p, qp: paged_decode_attention(
+            q, k, v, None, None, li, t, p, qp),
+        qd, pool_bf16, pool_bf16, layer, tables, pos, qpos))
     cert.run("kernel/paged_decode_int8_kv", lambda: _lower(
-        lambda q, k, v, ks, vs, t, p, qp: paged_decode_attention(
-            q, k, v, ks, vs, t, p, qp),
-        qd, pool_i8, pool_i8, pool_sc, pool_sc, tables, pos, qpos))
+        lambda q, k, v, ks, vs, li, t, p, qp: paged_decode_attention(
+            q, k, v, ks, vs, li, t, p, qp),
+        qd, pool_i8, pool_i8, pool_sc, pool_sc, layer, tables, pos, qpos))
 
     for name, fn, args in serving_kernel_cases(sh):
         cert.run(name, lambda fn=fn, args=args: _lower(fn, *args))
@@ -288,6 +293,7 @@ def kernel_artifacts(cert: Certifier, dev):
 # DECODE_BUCKET=64 up to --prefill_chunk 256, chain verify k+1 for
 # k <= --spec_k 4, a 4x3 tree step (1 + 12 columns).
 ENGINE_SLOTS, ENGINE_BLOCK, ENGINE_SEQ = 4, 16, 1024
+ENGINE_LAYERS = 2  # layers of the stacked pool a kernel is handed
 ENGINE_QLENS = (64, 128, 192, 256) + (2, 3, 4, 5, 13)
 ENGINE_HEADS = {"tinyllama": (32, 4, 64), "llama2_7b": (32, 32, 128)}
 ENGINE_VOCABS = (32000, 151936)
@@ -318,18 +324,19 @@ def serving_kernel_cases(sh):
             q = sds((B, T, H, d), jnp.bfloat16)
             tables = sds((B, nbps), jnp.int32)
             allow = sds((B, T, nbps * bs), jnp.bool_)
+            layer = sds((), jnp.int32)
             for kv_dtype in (jnp.bfloat16, jnp.int8):
-                pool = sds((NB, bs, KV, d), kv_dtype)
+                pool = sds((ENGINE_LAYERS, NB, bs, KV * d), kv_dtype)
                 if kv_dtype == jnp.int8:
-                    sc = sds((NB, bs, KV), jnp.float32)
+                    sc = sds((ENGINE_LAYERS, NB, bs, KV), jnp.float32)
                     fn = paged_multitoken_attention
-                    args = (q, pool, pool, sc, sc, tables, allow)
+                    args = (q, pool, pool, sc, sc, layer, tables, allow)
                     tag = "int8_kv"
                 else:
-                    def fn(q, k, v, t, a):
+                    def fn(q, k, v, li, t, a):
                         return paged_multitoken_attention(
-                            q, k, v, None, None, t, a)
-                    args = (q, pool, pool, tables, allow)
+                            q, k, v, None, None, li, t, a)
+                    args = (q, pool, pool, layer, tables, allow)
                     tag = "bf16"
                 cases.append(
                     (f"kernel/paged_multitoken_{tag}_{gname}_T{T}", fn, args))

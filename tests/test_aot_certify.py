@@ -65,8 +65,8 @@ _SERVING_PROBE = r"""
 import json, os, sys
 sys.path.insert(0, os.environ["DTX_REPO"])
 from scripts.aot_certify import (
-    ENGINE_BLOCK, ENGINE_HEADS, ENGINE_SEQ, ENGINE_SLOTS, TOPOLOGY_1CHIP,
-    _topo, lower_mosaic, serving_kernel_cases)
+    ENGINE_BLOCK, ENGINE_HEADS, ENGINE_LAYERS, ENGINE_SEQ, ENGINE_SLOTS,
+    TOPOLOGY_1CHIP, _topo, lower_mosaic, serving_kernel_cases)
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
@@ -83,12 +83,13 @@ def sds(shape, dtype):
 # the multi-token kernel and the sampler)
 H, KV, d = ENGINE_HEADS["tinyllama"]
 nbps = ENGINE_SEQ // ENGINE_BLOCK
-pool = sds((ENGINE_SLOTS * nbps, ENGINE_BLOCK, KV, d), jnp.bfloat16)
+pool = sds((ENGINE_LAYERS, ENGINE_SLOTS * nbps, ENGINE_BLOCK, KV * d),
+           jnp.bfloat16)
 cases = list(serving_kernel_cases(sh)) + [(
     "kernel/paged_decode_bf16_tinyllama",
-    lambda q, k, v, t, p, qp: paged_decode_attention(
-        q, k, v, None, None, t, p, qp),
-    (sds((ENGINE_SLOTS, H, d), jnp.bfloat16), pool, pool,
+    lambda q, k, v, li, t, p, qp: paged_decode_attention(
+        q, k, v, None, None, li, t, p, qp),
+    (sds((ENGINE_SLOTS, H, d), jnp.bfloat16), pool, pool, sds((), jnp.int32),
      sds((ENGINE_SLOTS, nbps), jnp.int32),
      sds((ENGINE_SLOTS * nbps, ENGINE_BLOCK), jnp.int32),
      sds((ENGINE_SLOTS,), jnp.int32)))]
@@ -146,6 +147,50 @@ def test_serving_kernels_lower_through_mosaic_at_engine_geometry():
                 "dtx_paged_decode" if "paged_decode" in case else
                 "dtx_paged_multitoken")
         assert kernels == [want], (case, kernels)
+
+
+# The engine's decode and prefill-chunk programs over a paged bf16 cache, at
+# debug size, through the CHIP's compiler with the Mosaic paged kernels: the
+# KV leaves of the cache argument are the result's buffers and nothing moves
+# a whole leaf or a whole layer of one (tests/test_kv_in_place.py checks the
+# same on this process's backend, where a kernel is an emulation).
+_IN_PLACE_PROBE = r"""
+import json, os, sys
+sys.path.insert(0, os.environ["DTX_REPO"])
+sys.path.insert(0, os.path.join(os.environ["DTX_REPO"], "tests"))
+from scripts.aot_certify import TOPOLOGY_1CHIP, _topo
+from jax.sharding import SingleDeviceSharding
+import test_kv_in_place as kv
+
+sh = SingleDeviceSharding(_topo(TOPOLOGY_1CHIP).devices[0])
+out = {}
+for program in ("decode", "prefill_chunk"):
+    for kernels in (True, False):
+        cache, hlo = kv._compiled("paged-bf16", program, kernels, sharding=sh)
+        try:
+            kv._assert_in_place(cache, hlo, layer_is_read_whole=False)
+            verdict = "ok"
+        except AssertionError as e:
+            verdict = str(e)[:400]
+        out[f"{program}/{'kernels' if kernels else 'gather'}"] = {
+            "verdict": verdict, "mosaic_calls": hlo.count("tpu_custom_call")}
+print(json.dumps(out))
+"""
+
+
+@pytest.mark.parametrize("case", ["decode/kernels", "decode/gather",
+                                  "prefill_chunk/kernels",
+                                  "prefill_chunk/gather"])
+def test_engine_programs_write_the_kv_pool_in_place_for_v5e(case, in_place_doc):
+    got = in_place_doc[case]
+    assert got["verdict"] == "ok", got
+    assert (got["mosaic_calls"] > 0) == case.endswith("kernels"), got
+
+
+@pytest.fixture(scope="module")
+def in_place_doc():
+    pytest.importorskip("libtpu")  # the TPU compiler; absent from jax[cpu]
+    return _run_probe(_IN_PLACE_PROBE, timeout=600)
 
 
 # One expert layer at the published widths of the benchmark's sparse-expert

@@ -183,7 +183,8 @@ def test_int8_kv_cache_close_to_bf16_cache():
     q_cache = init_cache(cfg, B, P + 4, dtype=jnp.float32, quantize="int8")
     q_logits, q_cache = forward(params, toks, cfg, cache=q_cache)
     assert q_cache["k"].dtype == jnp.int8
-    assert q_cache["k_scale"].shape == q_cache["k"].shape[:-1]
+    assert q_cache["k_scale"].shape == q_cache["k"].shape[:-1] + (
+        cfg.num_kv_heads,)
     np.testing.assert_allclose(np.asarray(q_logits), np.asarray(ref_logits),
                                rtol=0.1, atol=0.15)
 

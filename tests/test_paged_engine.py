@@ -156,7 +156,7 @@ def test_paged_int8_cache_matches_dense_int8():
     qp["block_tables"] = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
     lp, qp = forward(params, toks, cfg, cache=qp)
     assert qp["k"].dtype == jnp.int8
-    assert qp["k_scale"].shape == qp["k"].shape[:-1]
+    assert qp["k_scale"].shape == qp["k"].shape[:-1] + (cfg.num_kv_heads,)
     np.testing.assert_array_equal(np.asarray(ld), np.asarray(lp))
 
 

@@ -22,6 +22,30 @@ from datatunerx_tpu.ops.paged_attention import POS_SENTINEL
 from datatunerx_tpu.ops.pallas_paged_attention import paged_decode_attention
 
 BS = 8  # block size (tokens per block)
+LAYERS, LAYER = 3, 1  # the kernels read one layer of a stacked pool
+
+
+def _stacked(pool, layer=LAYER):
+    """One layer's pool ``[NB, BS, KV(, d)]`` as layer ``layer`` of the
+    stacked leaf the kernels take (``[L, NB, BS, KV * d]``; scales keep their
+    ``KV`` axis), the other layers holding the same values in another order:
+    a kernel that reads a wrong layer offset reads plausible wrong numbers."""
+    if pool is None:
+        return None
+    if pool.ndim == 4:
+        pool = pool.reshape(pool.shape[:2] + (-1,))
+    decoys = [pool[::-1], jnp.roll(pool, 1, axis=1)]
+    layers = [decoys[i % 2] for i in range(LAYERS)]
+    layers[layer] = pool
+    return jnp.stack(layers)
+
+
+def _leaves(kp, vp, ks, vs, layer=LAYER):
+    out = {"k": _stacked(kp, layer), "v": _stacked(vp, layer)}
+    if ks is not None:
+        out["k_scale"] = _stacked(ks, layer)
+        out["v_scale"] = _stacked(vs, layer)
+    return out
 
 
 def _make_pool(key, B, NB, KV, d, lens, tables, dtype=jnp.float32,
@@ -76,7 +100,7 @@ def _oracle(q, k_pool, v_pool, ks, vs, tables, pos, q_positions, dtype):
 
 
 def _run(B=2, NB=8, nbps=3, KV=2, G=2, d=16, lens=(17, 5), dtype=jnp.float32,
-         quant=False, tables=None, seed=0):
+         quant=False, tables=None, seed=0, layer=LAYER):
     H = KV * G
     key = jax.random.PRNGKey(seed)
     if tables is None:
@@ -93,7 +117,9 @@ def _run(B=2, NB=8, nbps=3, KV=2, G=2, d=16, lens=(17, 5), dtype=jnp.float32,
     q = jax.random.normal(jax.random.fold_in(key, 99),
                           (B, H, d)).astype(dtype)
     q_positions = jnp.asarray([int(x) - 1 for x in lens], jnp.int32)
-    got = paged_decode_attention(q, kp, vp, ks, vs, tables, pos, q_positions)
+    got = paged_decode_attention(
+        q, *(_stacked(p, layer) for p in (kp, vp, ks, vs)), layer, tables,
+        pos, q_positions)
     want = _oracle(q, kp, vp, ks, vs, tables, pos, q_positions, dtype)
     assert got.dtype == q.dtype
     return np.asarray(got, np.float32), np.asarray(want, np.float32)
@@ -101,6 +127,14 @@ def _run(B=2, NB=8, nbps=3, KV=2, G=2, d=16, lens=(17, 5), dtype=jnp.float32,
 
 def test_block_table_walk_matches_gather_f32():
     got, want = _run()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+def test_reads_the_layer_it_is_given(layer):
+    """First and last layer of the stacked pool, as a traced scalar (the
+    layer scan's index): the blocks of layer ``l`` start at row ``l * NB``."""
+    got, want = _run(layer=jnp.asarray(layer, jnp.int32), quant=layer > 0)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
@@ -196,12 +230,13 @@ def test_decode_step_wrapper_shape():
     kp, vp, ks, vs, pos, _, _ = _make_pool(key, B, NB, KV, d, (9, 4), tables)
     q = jax.random.normal(kq, (B, 1, H, d))
     cache = {"block_tables": tables}
+    leaves = _leaves(kp, vp, None, None)
     out = paged_attention_decode_step(
-        q, kp, vp, None, None, cache, pos, jnp.asarray([[8], [3]], jnp.int32))
+        q, leaves, LAYER, cache, pos, jnp.asarray([[8], [3]], jnp.int32))
     assert out.shape == (B, 1, H, d)
     with pytest.raises(AssertionError):
         paged_attention_decode_step(
-            jax.random.normal(kq2, (B, 2, H, d)), kp, vp, None, None, cache,
+            jax.random.normal(kq2, (B, 2, H, d)), leaves, LAYER, cache,
             pos, jnp.asarray([[8, 9], [3, 4]], jnp.int32))
 
 
@@ -234,7 +269,7 @@ def _gathered_view(kp, vp, ks, vs, tables, pos, dtype):
 
 def _run_mt(B=2, NB=8, nbps=3, KV=2, G=2, d=16, lens=(17, 5), T=3,
             dtype=jnp.float32, quant=False, tables=None, seed=0,
-            window=None):
+            window=None, layer=LAYER):
     """Multi-token kernel vs the gather oracle. Queries sit on the last T
     written lanes per slot (the post-write verify/chunk shape), so every
     row has a DIFFERENT causal offset on a ragged batch. ``window=WN``
@@ -269,7 +304,9 @@ def _run_mt(B=2, NB=8, nbps=3, KV=2, G=2, d=16, lens=(17, 5), T=3,
             [int(x) - window for x in lens], jnp.int32)
     allow = attention_allow(q_positions, kv_pos, window_mask=window_mask,
                             window_start=window_start)
-    got = paged_multitoken_attention(q, kp, vp, ks, vs, tables, allow)
+    got = paged_multitoken_attention(
+        q, *(_stacked(p, layer) for p in (kp, vp, ks, vs)), layer, tables,
+        allow)
     bias = make_causal_bias(q_positions, kv_pos, window_mask=window_mask,
                             window_start=window_start)
     want = xla_attention(q.astype(dtype), k_all, v_all, bias)
@@ -279,6 +316,13 @@ def _run_mt(B=2, NB=8, nbps=3, KV=2, G=2, d=16, lens=(17, 5), T=3,
 
 def test_multitoken_matches_gather_f32():
     got, want = _run_mt()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+def test_multitoken_reads_the_layer_it_is_given(layer):
+    got, want = _run_mt(layer=jnp.asarray(layer, jnp.int32),
+                        quant=layer > 0)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
@@ -372,10 +416,9 @@ def test_multitoken_step_wrapper_shape_and_allow_contract():
     q = jax.random.normal(key, (B, T, H, d))
     allow = jnp.ones((B, T, nbps * BS), bool)
     cache = {"block_tables": tables}
-    out = paged_attention_multitoken_step(q, kp, vp, None, None, cache,
-                                          allow)
+    leaves = _leaves(kp, vp, None, None)
+    out = paged_attention_multitoken_step(q, leaves, LAYER, cache, allow)
     assert out.shape == (B, T, H, d)
     with pytest.raises(AssertionError, match="allow"):
         paged_attention_multitoken_step(
-            q, kp, vp, None, None, cache,
-            jnp.ones((B, T + 1, nbps * BS), bool))
+            q, leaves, LAYER, cache, jnp.ones((B, T + 1, nbps * BS), bool))
