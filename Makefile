@@ -4,25 +4,22 @@
 IMG_OPERATOR ?= datatunerx-tpu/operator:latest
 IMG_TRAINER  ?= datatunerx-tpu/trainer:latest
 
-.PHONY: test test-fast native bench chip-smoke graft-check aot-certify docker-build deploy undeploy fmt lint lint-fix
+.PHONY: test test-fast native chip-smoke graft-check aot-certify docker-build deploy undeploy fmt lint lint-fix
 
 test:            ## full test suite (8-device virtual CPU mesh)
 	python -m pytest tests/ -q
 
 lint:            ## dtxlint: program-level JAX-aware static analysis (the tier-1 CI gate)
-	python -m datatunerx_tpu.analysis datatunerx_tpu/ scripts/ bench.py __graft_entry__.py
+	python -m datatunerx_tpu.analysis datatunerx_tpu/ scripts/ __graft_entry__.py
 
 lint-fix:        ## apply dtxlint's mechanical autofixes (DTX002/DTX008), then re-lint
-	python -m datatunerx_tpu.analysis datatunerx_tpu/ scripts/ bench.py __graft_entry__.py --fix
+	python -m datatunerx_tpu.analysis datatunerx_tpu/ scripts/ __graft_entry__.py --fix
 
 test-fast:       ## skip the slow live-pipeline e2e
 	python -m pytest tests/ -q -m "not slow"
 
 native:          ## build the C++ data-path extension
 	python -c "from datatunerx_tpu import native; assert native.available(); print('native OK')"
-
-bench:           ## headline benchmark (one JSON line; fails without a TPU)
-	python bench.py
 
 chip-smoke:      ## trainer + server + every Pallas kernel, on the chip (fails without one)
 	python chip_smoke.py

@@ -72,7 +72,7 @@ TRAIN_STEPS = 10
 TRAIN_BATCH = 8       # per device
 TRAIN_BLOCK = 1024
 SERVE_SLOTS = 4       # --slots default; also the sampler check's S
-SERVE_BLOCK = 16      # --kv_block_size in the README, CI and bench.py
+SERVE_BLOCK = 16      # --kv_block_size of the README and the benchmark cells
 SERVE_BUDGET = 256    # = default --prefill_chunk: one chunk per tick
 SERVE_SEQ = 1024
 

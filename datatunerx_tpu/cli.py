@@ -50,6 +50,8 @@ import sys
 import urllib.error
 import urllib.request
 
+from datatunerx_tpu.serving import options
+
 _GROUP_BY_KIND = {
     "Finetune": "finetune.datatunerx.io",
     "FinetuneJob": "finetune.datatunerx.io",
@@ -213,30 +215,11 @@ def cmd_serve(args):
     """Launch serving directly (no operator): a single serving.server, or —
     with --replicas N / --gateway — the inference gateway fronting N replica
     subprocesses (routing, admission control, failover; gateway/server.py)."""
+    argv = options.argv(args) + ["--port", str(args.port)]
     if args.replicas > 1 or args.gateway:
         from datatunerx_tpu.gateway.server import main as gateway_main
 
-        argv = [
-            "--model_path", args.model_path,
-            "--checkpoint_path", args.checkpoint_path,
-            "--template", args.template,
-            "--max_seq_len", str(args.max_seq_len),
-            "--port", str(args.port),
-            "--quantization", args.quantization,
-            "--slots", str(args.slots),
-            "--adapters", args.adapters,
-            "--adapter_pool", str(args.adapter_pool),
-            "--adapter_rank_max", str(args.adapter_rank_max),
-            "--kv_block_size", str(args.kv_block_size),
-            "--kv_blocks", str(args.kv_blocks),
-            "--kv_overcommit", args.kv_overcommit,
-            "--paged_kernel", args.paged_kernel,
-            "--spec_draft_config", args.spec_draft_config,
-            "--spec_k", str(args.spec_k),
-            "--spec_mode", args.spec_mode,
-            "--spec_tree", args.spec_tree,
-            "--sampling_epilogue", args.sampling_epilogue,
-            "--prefill_token_budget", str(args.prefill_token_budget),
+        argv += [
             "--replicas", str(max(args.replicas, 1)),
             "--policy", args.policy,
             "--max_queue", str(args.max_queue),
@@ -246,38 +229,12 @@ def cmd_serve(args):
             "--fleet_prefix_mb", str(args.fleet_prefix_mb),
             "--fleet_handoff", str(int(args.fleet_handoff)),
             "--fleet_spill", str(int(args.fleet_spill)),
-            "--tenants_config", args.tenants_config,
-            "--host_adapter_cache_mb", str(args.host_adapter_cache_mb),
         ]
         if args.workdir:
             argv += ["--workdir", args.workdir]
         return gateway_main(argv)
     from datatunerx_tpu.serving.server import main as serving_main
 
-    argv = [
-        "--model_path", args.model_path,
-        "--checkpoint_path", args.checkpoint_path,
-        "--template", args.template,
-        "--max_seq_len", str(args.max_seq_len),
-        "--port", str(args.port),
-        "--quantization", args.quantization,
-        "--slots", str(args.slots),
-        "--adapters", args.adapters,
-        "--adapter_pool", str(args.adapter_pool),
-        "--adapter_rank_max", str(args.adapter_rank_max),
-        "--kv_block_size", str(args.kv_block_size),
-        "--kv_blocks", str(args.kv_blocks),
-        "--kv_overcommit", args.kv_overcommit,
-        "--paged_kernel", args.paged_kernel,
-        "--spec_draft_config", args.spec_draft_config,
-        "--spec_k", str(args.spec_k),
-        "--spec_mode", args.spec_mode,
-        "--spec_tree", args.spec_tree,
-        "--sampling_epilogue", args.sampling_epilogue,
-        "--prefill_token_budget", str(args.prefill_token_budget),
-        "--tenants_config", args.tenants_config,
-        "--host_adapter_cache_mb", str(args.host_adapter_cache_mb),
-    ]
     if args.role:
         # single server: one role, not a cycle (serving.server validates)
         argv += ["--role", args.role]
@@ -396,6 +353,12 @@ def main(argv=None):
         from datatunerx_tpu.analysis.sanitizers.cli import main as san_main
 
         return san_main(san_tail)
+    args = build_parser().parse_args(argv)
+    rc = args.fn(args)
+    return int(rc) if isinstance(rc, int) else 0
+
+
+def build_parser():
     p = argparse.ArgumentParser(prog="dtx")
     p.add_argument("--server", default=os.environ.get("DTX_SERVER",
                                                       "http://127.0.0.1:8080"))
@@ -432,62 +395,8 @@ def main(argv=None):
         "serve",
         help="serve a model directly: single server, or --replicas N / "
              "--gateway for the multi-replica inference gateway")
-    vp.add_argument("--model_path", required=True)
-    vp.add_argument("--checkpoint_path", default="")
-    vp.add_argument("--template", default="llama2")
-    vp.add_argument("--max_seq_len", type=int, default=1024)
+    options.add_arguments(vp)
     vp.add_argument("--port", type=int, default=8000)
-    vp.add_argument("--quantization", default="",
-                    choices=["", "int8", "int4", "nf4"])
-    vp.add_argument("--slots", type=int, default=4)
-    vp.add_argument("--adapters", default="",
-                    help="named LoRA adapters: name=ckpt[,name=ckpt…]")
-    vp.add_argument("--adapter_pool", type=int, default=0,
-                    help="dynamic multi-adapter pool: N HBM slots adapters "
-                         "load into at runtime (load-on-miss + LRU evict "
-                         "via POST/DELETE /admin/adapters; 0 = static "
-                         "--adapters stack)")
-    vp.add_argument("--adapter_rank_max", type=int, default=8,
-                    help="pool rank ceiling; lower ranks are zero-padded, "
-                         "higher ranks rejected")
-    vp.add_argument("--kv_block_size", type=int, default=0,
-                    help="paged KV cache block size in tokens (0 = dense)")
-    vp.add_argument("--kv_blocks", type=int, default=0,
-                    help="paged pool size in blocks (default: dense parity)")
-    vp.add_argument("--kv_overcommit", default="off",
-                    choices=["off", "on"],
-                    help="on: lazy block reserve + on-demand growth + COW "
-                         "prefix blocks + youngest-first preemption (more "
-                         "concurrent sessions per chip); off = eager "
-                         "reserve, byte-identical to the classic engine")
-    vp.add_argument("--paged_kernel", default="auto",
-                    choices=["auto", "on", "off"],
-                    help="Pallas in-place paged decode kernel: auto = "
-                         "kernel on TPU / gather elsewhere, on = force "
-                         "(interpret-mode on CPU), off = gather oracle")
-    vp.add_argument("--spec_draft_config", default="",
-                    help="speculative decoding draft: model path, "
-                         "preset:<name>, or take:N (target's first N "
-                         "layers); empty = off")
-    vp.add_argument("--spec_k", type=int, default=4,
-                    help="draft proposals per verify step")
-    vp.add_argument("--spec_mode", default="auto",
-                    choices=["auto", "on", "off"],
-                    help="speculative decoding: auto = adaptive, on = "
-                         "pinned, off = plain decode")
-    vp.add_argument("--spec_tree", default="",
-                    help="tree drafts 'WxD' (width x depth, e.g. 4x3): one "
-                         "batched verify over W branches, accept the "
-                         "longest surviving path; needs "
-                         "--spec_draft_config; empty = chain drafts")
-    vp.add_argument("--sampling_epilogue", default="auto",
-                    choices=["auto", "on", "off"],
-                    help="fused on-chip sampling epilogue: auto = on for "
-                         "TPU backends, on = force anywhere (exact XLA "
-                         "oracle off-TPU), off = legacy host sampler")
-    vp.add_argument("--prefill_token_budget", type=int, default=0,
-                    help="prefill tokens per scheduler tick between decode "
-                         "chunks (0 = unbounded)")
     vp.add_argument("--role", default="",
                     help="disaggregation role(s): a single role for one "
                          "server (prefill/decode/mixed), or a comma-"
@@ -504,16 +413,6 @@ def main(argv=None):
     vp.add_argument("--fleet_spill", type=int, default=0,
                     help="gateway: 1 = spill preemption-parked sessions "
                          "to peers with free KV blocks")
-    vp.add_argument("--tenants_config", default="",
-                    help="tenant directory (JSON file path or inline JSON "
-                         "object): enables the multi-tenant QoS plane — "
-                         "pinned/standard/bulk tiers, weighted-fair "
-                         "admission shares, per-tenant KV block quotas "
-                         "(empty = plane off, byte-identical serving)")
-    vp.add_argument("--host_adapter_cache_mb", type=float, default=0.0,
-                    help="host-RAM adapter tier budget in MB: evicted "
-                         "pool adapters reload from host arrays instead "
-                         "of orbax (0 = off)")
     vp.add_argument("--replicas", type=int, default=1,
                     help="replica count; > 1 puts the gateway in front")
     vp.add_argument("--gateway", action="store_true",
@@ -581,10 +480,7 @@ def main(argv=None):
     ip.add_argument("--kube-url", default=os.environ.get("DTX_KUBE_URL"),
                     help="apiserver base URL (default: in-cluster config)")
     ip.set_defaults(fn=cmd_install)
-
-    args = p.parse_args(argv)
-    rc = args.fn(args)
-    return int(rc) if isinstance(rc, int) else 0
+    return p
 
 
 if __name__ == "__main__":

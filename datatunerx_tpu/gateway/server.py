@@ -55,6 +55,7 @@ from datatunerx_tpu.obs.metrics import (
 )
 from datatunerx_tpu.obs.slo import SLOEvaluator, default_slos, load_slos
 from datatunerx_tpu.obs.trace import Span, Tracer, TraceStore
+from datatunerx_tpu.serving import options
 from datatunerx_tpu.serving.local_backend import _free_port
 from datatunerx_tpu.tenancy import load_tenants
 
@@ -1975,7 +1976,7 @@ def serve(gw: Gateway, port: int = 0,
     return srv
 
 
-def main(argv=None):
+def build_parser():
     p = argparse.ArgumentParser(prog="datatunerx-tpu-gateway")
     p.add_argument("--port", type=int, default=8000)
     p.add_argument("--policy", default="least_busy",
@@ -1990,12 +1991,6 @@ def main(argv=None):
                    help="model dir or preset:NAME for token-accurate "
                         "admission estimates (defaults to --model_path)")
     p.add_argument("--health_interval", type=float, default=2.0)
-    p.add_argument("--trace_ring", type=int, default=256,
-                   help="completed request traces kept for "
-                        "GET /debug/trace/<id>")
-    p.add_argument("--trace_log", default="",
-                   help="append every completed gateway span as one JSON "
-                        "line to this file (offline trace forensics)")
     p.add_argument("--slo_config", default="",
                    help="JSON file of SLO specs (obs/slo.py format) judged "
                         "at GET /debug/slo; default: the built-in gateway "
@@ -2031,19 +2026,6 @@ def main(argv=None):
                    help="comma-separated role cycle for spawned replicas "
                         "(e.g. 'prefill,decode' alternates; entries from "
                         "prefill/decode/mixed); empty = all mixed")
-    p.add_argument("--tenants_config", default="",
-                   help="tenant directory: a JSON file path or inline "
-                        "JSON object mapping tenant -> {tier, adapters, "
-                        "share, kv_block_quota, ttft_p95_ms}. Enables "
-                        "the multi-tenant QoS plane (weighted-fair "
-                        "admission, per-tenant KV quotas, pinned adapter "
-                        "tiers); empty (default) leaves the gateway "
-                        "byte-identical to a tenant-less build")
-    p.add_argument("--host_adapter_cache_mb", type=float, default=0.0,
-                   help="per-replica host-RAM adapter tier budget in MB "
-                        "(spawn mode pass-through): evicted adapters "
-                        "re-load from host arrays instead of orbax. "
-                        "0 (default) disables the tier")
     p.add_argument("--session_handoff", type=int, default=1,
                    help="1 (default): drain exports every in-flight KV "
                         "session from the leaving replica and imports it "
@@ -2056,35 +2038,15 @@ def main(argv=None):
                    help="spawn N serving.server subprocesses to front")
     p.add_argument("--workdir", default="",
                    help="replica log directory (spawn mode)")
-    # pass-through model flags for spawn mode (mirror serving.server)
-    p.add_argument("--model_path", default="")
-    p.add_argument("--checkpoint_path", default="")
-    p.add_argument("--template", default="llama2")
-    p.add_argument("--max_seq_len", type=int, default=1024)
-    p.add_argument("--quantization", default="")
-    p.add_argument("--slots", type=int, default=4)
-    p.add_argument("--decode_chunk", type=int, default=8)
-    p.add_argument("--adapters", default="")
-    p.add_argument("--adapter_pool", type=int, default=0)
-    p.add_argument("--adapter_rank_max", type=int, default=8)
-    p.add_argument("--adapter_targets", default="")
-    p.add_argument("--kv_quant", default="")
-    p.add_argument("--prefix_cache", type=int, default=0)
-    p.add_argument("--kv_block_size", type=int, default=0)
-    p.add_argument("--kv_blocks", type=int, default=0)
-    p.add_argument("--kv_overcommit", default="off",
-                   choices=["off", "on"])
-    p.add_argument("--spec_draft_config", default="")
-    p.add_argument("--spec_k", type=int, default=4)
-    p.add_argument("--spec_mode", default="auto",
-                   choices=["auto", "on", "off"])
-    p.add_argument("--spec_tree", default="")
-    p.add_argument("--sampling_epilogue", default="auto",
-                   choices=["auto", "on", "off"])
-    p.add_argument("--paged_kernel", default="auto",
-                   choices=["auto", "on", "off"])
-    p.add_argument("--prefill_chunk", type=int, default=256)
-    p.add_argument("--prefill_token_budget", type=int, default=0)
+    # what a spawned replica takes (serving/options.py). tenants_config also
+    # switches on the gateway's own QoS plane; trace_ring and trace_log are
+    # the gateway's own, replicas keep their defaults
+    options.add_arguments(p, model_required=False)
+    return p
+
+
+def main(argv=None):
+    p = build_parser()
     args = p.parse_args(argv)
 
     if not args.replica_url and args.replicas <= 0:
@@ -2133,36 +2095,7 @@ def main(argv=None):
     for i, url in enumerate(args.replica_url):
         pool.add(HTTPReplica(f"replica-{i}", url))
     if args.replicas > 0:
-        server_args = ["--model_path", args.model_path,
-                       "--checkpoint_path", args.checkpoint_path,
-                       "--template", args.template,
-                       "--max_seq_len", str(args.max_seq_len),
-                       "--quantization", args.quantization,
-                       "--slots", str(args.slots),
-                       "--decode_chunk", str(args.decode_chunk),
-                       "--adapters", args.adapters,
-                       "--adapter_pool", str(args.adapter_pool),
-                       "--adapter_rank_max", str(args.adapter_rank_max),
-                       "--adapter_targets", args.adapter_targets,
-                       "--kv_quant", args.kv_quant,
-                       "--prefix_cache", str(args.prefix_cache),
-                       "--kv_block_size", str(args.kv_block_size),
-                       "--kv_blocks", str(args.kv_blocks),
-                       "--kv_overcommit", args.kv_overcommit,
-                       "--paged_kernel", args.paged_kernel,
-                       "--spec_draft_config", args.spec_draft_config,
-                       "--spec_k", str(args.spec_k),
-                       "--spec_mode", args.spec_mode,
-                       "--spec_tree", args.spec_tree,
-                       "--sampling_epilogue", args.sampling_epilogue,
-                       "--prefill_chunk", str(args.prefill_chunk),
-                       "--prefill_token_budget",
-                       str(args.prefill_token_budget)]
-        if args.tenants_config:
-            server_args += ["--tenants_config", args.tenants_config]
-        if args.host_adapter_cache_mb > 0:
-            server_args += ["--host_adapter_cache_mb",
-                            str(args.host_adapter_cache_mb)]
+        server_args = options.argv(args, skip=options.PER_PROCESS)
         gw.replica_set = ManagedReplicaSet(
             pool, server_args, workdir=args.workdir or "gateway-replicas",
             roles=roles)

@@ -14,8 +14,7 @@ an SLO epilogue — the same ``obs/slo.py`` evaluator the gateway's
   loadgen.replay   — the runner, clients, SLO epilogue, and the
                      ``dtx replay`` CLI
 
-Entry points: ``dtx replay``, ``python -m datatunerx_tpu.loadgen.replay``,
-and bench.py's ``DTX_BENCH_REPLAY`` mode.
+Entry points: ``dtx replay``, ``python -m datatunerx_tpu.loadgen.replay``.
 """
 
 from datatunerx_tpu.loadgen.workload import (  # noqa: F401

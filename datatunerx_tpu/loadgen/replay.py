@@ -15,7 +15,7 @@ Two clients:
 
   HTTPClient   — a real gateway URL (SSE streaming, trace-id header).
   LocalClient  — an in-process ``Gateway`` object: the test/CI/bench path
-                 (``--selftest``, DTX_BENCH_REPLAY), where chaos can also
+                 (``--selftest``), where chaos can also
                  reach surfaces that have no wire form (replica kill,
                  slice-pool shrink) via injected actions.
 

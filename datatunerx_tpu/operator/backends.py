@@ -27,6 +27,8 @@ import sys
 import threading
 from typing import Dict, List, Optional, Protocol
 
+from datatunerx_tpu.serving import options as serving_options
+
 
 class TrainingBackend(Protocol):
     def submit(self, name: str, spec: dict) -> None: ...
@@ -471,14 +473,10 @@ class ManifestBackend:
                             "name": "server",
                             "image": spec.get("image", "datatunerx-tpu/serving:latest"),
                             "command": ["python", "-m", "datatunerx_tpu.serving.server"],
-                            "args": [
-                                "--model_path", spec["model_path"],
-                                "--checkpoint_path", spec.get("checkpoint_path", ""),
-                                "--port", "8000",
-                                "--quantization", spec.get("quantization", ""),
-                                *(["--slots", str(spec["slots"])]
-                                  if spec.get("slots") is not None else []),
-                            ],
+                            # every engine option generate_serving_spec
+                            # rendered from the job's serveConfig
+                            "args": ["--port", "8000",
+                                     *serving_options.argv(spec)],
                             "ports": [{"containerPort": 8000}],
                             "readinessProbe": {
                                 "httpGet": {"path": "/healthz", "port": 8000},

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 from datatunerx_tpu.operator.api import ALL_KINDS
 from datatunerx_tpu.operator.webhooks import OPTIMIZERS, SCHEDULERS
+from datatunerx_tpu.serving import options as serving_options
 
 ANY = {"x-kubernetes-preserve-unknown-fields": True}
 STR = {"type": "string"}
@@ -79,15 +80,10 @@ SPECS = {
         "scoringPluginConfig": obj({"name": STR, "parameters": STR}),
         "serveConfig": obj({
             "nodeSelector": ANY, "tolerations": arr(ANY),
-            # TPU additions (generate.py generate_serving_spec)
-            "quantization": {"type": "string",
-                             "enum": ["", "int8", "int4", "nf4"]},
-            "slots": INT,
-            # dynamic multi-adapter plane (serving --adapter_pool /
-            # --adapter_rank_max): N HBM pool slots tenant adapters load
-            # into at runtime via /admin/adapters, rank-padded to the max
-            "adapterPool": INT,
-            "adapterRankMax": INT,
+            # what a serving replica takes (serving/options.py): slots,
+            # quantization, the adapter pool, KV overcommit, speculative
+            # decoding, tenantsConfig (a file path mounted into the pod) …
+            **serving_options.crd_properties(),
             # gateway tier (gateway/server.py): N replicas behind one
             # endpoint with routing/admission/failover; min/max bound the
             # autoscale hint the controller applies
@@ -97,23 +93,6 @@ SPECS = {
                        "enum": ["least_busy", "round_robin"]},
             "minReplicas": INT,
             "maxReplicas": INT,
-            # paged-KV overcommit (serving --kv_overcommit): admission by
-            # prompt-need + headroom, on-demand growth, preempt-and-park
-            "kvOvercommit": {"type": "string", "enum": ["", "off", "on"]},
-            # speculative decoding (serving --spec_draft_config/--spec_k/
-            # --spec_mode): draft-propose / verify-k decode
-            "specDraft": STR,
-            "specK": INT,
-            "specMode": {"type": "string",
-                         "enum": ["", "auto", "on", "off"]},
-            # tree-draft verification (serving --spec_tree): 'WxD' flattens
-            # a W-wide, D-deep token tree into one batched verify forward
-            "specTree": STR,
-            # fused on-chip sampling epilogue (serving --sampling_epilogue):
-            # decode programs sample in the traced computation instead of
-            # materializing full-vocab logits for the host sampler
-            "samplingEpilogue": {"type": "string",
-                                 "enum": ["", "auto", "on", "off"]},
             # disaggregated fleet plane (gateway/server.py): role is a
             # single role for one server or a comma cycle the gateway
             # assigns across spawned replicas; prompts >= the threshold
@@ -124,14 +103,10 @@ SPECS = {
             "fleetPrefixMb": {"type": "number"},
             "fleetHandoff": BOOL,
             "fleetSpill": BOOL,
-            # multi-tenant QoS plane (datatunerx_tpu/tenancy/): tenants is
-            # an inline tenant -> {tier, adapters, share, kvBlockQuota,
-            # ttftP95Ms} map (webhook-validated) or tenantsConfig a file
-            # path mounted into the pod; hostAdapterCacheMb bounds the
-            # host-RAM adapter tier evicted pool adapters fall back to
+            # multi-tenant QoS plane (datatunerx_tpu/tenancy/): an inline
+            # tenant -> {tier, adapters, share, kvBlockQuota, ttftP95Ms}
+            # map (webhook-validated), the alternative to tenantsConfig
             "tenants": ANY,
-            "tenantsConfig": STR,
-            "hostAdapterCacheMb": {"type": "number"},
         }),
     }, required=["finetune"]),
     "FinetuneExperiment": obj({

@@ -32,6 +32,7 @@ from datatunerx_tpu.operator.labels import (
     generate_instance_label,
 )
 from datatunerx_tpu.operator.store import set_owner
+from datatunerx_tpu.serving import options as serving_options
 
 # Hyperparameter CR parameter keys (SURVEY.md §2.3; merge at
 # finetune_controller.go:682-758). Values arrive as strings (reference quirk).
@@ -216,6 +217,9 @@ def generate_serving_spec(job: FinetuneJob, checkpoint: dict) -> dict:
     server gets the base model path + checkpoint URI directly."""
     serve_cfg = job.spec.get("serveConfig", {}) or {}
     return {
+        # the replica's own options (serving/options.py): quantization,
+        # slots, the adapter pool, KV overcommit, speculative decoding …
+        **serving_options.from_serve_config(serve_cfg),
         "model_path": checkpoint.get("llmPath")
         or checkpoint.get("image", {}).get("path")
         or config.get_default_model_path(),
@@ -223,16 +227,6 @@ def generate_serving_spec(job: FinetuneJob, checkpoint: dict) -> dict:
         "labels": generate_instance_label(job.metadata.name),
         "node_selector": serve_cfg.get("nodeSelector", {}),
         "tolerations": serve_cfg.get("tolerations", []),
-        # serve-time base quantization (serving/engine.py): fit big models on
-        # one chip's HBM; TPU addition to ServeConfig
-        "quantization": serve_cfg.get("quantization", ""),
-        # continuous-batching slot count (serving/server.py --slots; 1 =
-        # single-request engine); TPU addition to ServeConfig
-        "slots": serve_cfg.get("slots"),
-        # dynamic multi-adapter pool (serving --adapter_pool /
-        # --adapter_rank_max + /admin/adapters): adapters as runtime data
-        "adapter_pool": serve_cfg.get("adapterPool"),
-        "adapter_rank_max": serve_cfg.get("adapterRankMax"),
         # multi-replica serving behind the inference gateway
         # (gateway/server.py, replaces the reference's Ray Serve tier):
         # replicas > 1 or gateway=true puts the gateway in front
@@ -242,14 +236,6 @@ def generate_serving_spec(job: FinetuneJob, checkpoint: dict) -> dict:
         "min_replicas": int(serve_cfg.get("minReplicas") or 1),
         "max_replicas": int(serve_cfg.get("maxReplicas")
                             or serve_cfg.get("replicas") or 1),
-        # paged-KV overcommit + speculative decoding (serving/server.py
-        # --kv_overcommit / --spec_draft_config / --spec_k / --spec_mode)
-        "kv_overcommit": serve_cfg.get("kvOvercommit") or "",
-        "spec_draft_config": serve_cfg.get("specDraft") or "",
-        "spec_k": serve_cfg.get("specK"),
-        "spec_mode": serve_cfg.get("specMode") or "",
-        "spec_tree": serve_cfg.get("specTree") or "",
-        "sampling_epilogue": serve_cfg.get("samplingEpilogue") or "",
         # disaggregated fleet plane (gateway/server.py --role /
         # --prefill_threshold / --fleet_*): replica roles, the shared
         # prefix tier, prefill→decode handoff, peer KV spill
@@ -259,26 +245,23 @@ def generate_serving_spec(job: FinetuneJob, checkpoint: dict) -> dict:
         "fleet_handoff": bool(serve_cfg.get("fleetHandoff")),
         "fleet_spill": bool(serve_cfg.get("fleetSpill")),
         # multi-tenant QoS plane (datatunerx_tpu/tenancy/): the inline map
-        # renders to one --tenants_config JSON argument (camelCase keys
-        # mapped onto the directory schema); tenantsConfig is a mounted
-        # file path passed through verbatim
-        "tenants_config": _tenants_config_from(serve_cfg),
-        "host_adapter_cache_mb": serve_cfg.get("hostAdapterCacheMb"),
+        # renders to one --tenants_config JSON argument; tenantsConfig, a
+        # mounted file path, came through the table above
+        **_inline_tenants(serve_cfg),
     }
 
 
-def _tenants_config_from(serve_cfg: dict) -> str:
-    """serveConfig.tenants (inline map) or .tenantsConfig (file path) →
-    the one --tenants_config string both servers load."""
+def _inline_tenants(serve_cfg: dict) -> dict:
+    """serveConfig.tenants (camelCase entries) as the --tenants_config JSON
+    both servers load, mapped onto the directory schema."""
     inline = serve_cfg.get("tenants")
-    if isinstance(inline, dict) and inline:
-        from datatunerx_tpu.tenancy import tenant_entry_from_crd
+    if not (isinstance(inline, dict) and inline):
+        return {}
+    from datatunerx_tpu.tenancy import tenant_entry_from_crd
 
-        return json.dumps({str(n): tenant_entry_from_crd(e)
-                           if isinstance(e, dict) else e
-                           for n, e in inline.items()},
-                          sort_keys=True)
-    return serve_cfg.get("tenantsConfig") or ""
+    return {"tenants_config": json.dumps(
+        {str(n): tenant_entry_from_crd(e) if isinstance(e, dict) else e
+         for n, e in inline.items()}, sort_keys=True)}
 
 
 def generate_builtin_scoring(job: FinetuneJob, inference_url: str) -> Scoring:

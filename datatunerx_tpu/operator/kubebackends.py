@@ -74,16 +74,15 @@ class KubeServingBackend(ManifestBackend):
         self.namespace = namespace
 
     def deploy(self, name: str, spec: dict) -> None:
+        # the spec generate_serving_spec rendered, whole: render_serving
+        # turns its engine options into the pod's args
         deployment, service = self.render_serving(name, {
+            **spec,
             "model_path": spec.get("llmPath") or spec.get("model_path") or "",
             "checkpoint_path": spec.get("checkpointPath")
             or spec.get("checkpoint_path") or "",
-            "labels": spec.get("labels", {}),
-            "node_selector": spec.get("nodeSelector", {}),
-            "tolerations": spec.get("tolerations", []),
-            "quantization": spec.get("quantization", ""),
-            "slots": spec.get("slots"),
-            "replicas": spec.get("replicas"),
+            "node_selector": spec.get("nodeSelector")
+            or spec.get("node_selector") or {},
         })
         for group, version, plural, body in (
             ("apps", "v1", "deployments", deployment),

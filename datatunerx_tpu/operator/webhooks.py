@@ -19,6 +19,7 @@ from datatunerx_tpu.operator.api import (
     LLM,
     Scoring,
 )
+from datatunerx_tpu.serving import options as serving_options
 
 SCHEDULERS = ("cosine", "linear", "constant", "constant_with_warmup",
               "cosine_with_restarts", "polynomial")
@@ -122,16 +123,16 @@ def validate_finetunejob(obj: CustomResource):
 
 def _validate_serve_config(cfg: dict):
     _require(isinstance(cfg, dict), "serveConfig must be an object")
-    for key in ("replicas", "minReplicas", "maxReplicas", "slots",
-                "adapterPool", "adapterRankMax"):
+    try:
+        # the replica's own options: enums, integers, specTree's format
+        serving_options.validate_serve_config(cfg)
+    except ValueError as e:
+        raise AdmissionError(str(e)) from None
+    for key in ("replicas", "minReplicas", "maxReplicas", "prefillThreshold"):
         if cfg.get(key) is not None:
             v = _num(cfg[key], f"serveConfig.{key}")
             _require(v >= 1 and float(v).is_integer(),
                      f"serveConfig.{key} must be a positive integer")
-    if cfg.get("adapterRankMax") is not None:
-        _require(cfg.get("adapterPool") is not None,
-                 "serveConfig.adapterRankMax requires adapterPool (the "
-                 "rank ceiling only shapes a dynamic pool)")
     lo = int(float(cfg.get("minReplicas", 1) or 1))
     hi = cfg.get("maxReplicas")
     if hi is not None:
@@ -140,37 +141,6 @@ def _validate_serve_config(cfg: dict):
     if cfg.get("policy") is not None:
         _require(str(cfg["policy"]) in ("least_busy", "round_robin"),
                  "serveConfig.policy must be least_busy or round_robin")
-    if cfg.get("kvOvercommit") not in (None, ""):
-        _require(str(cfg["kvOvercommit"]) in ("off", "on"),
-                 "serveConfig.kvOvercommit must be off or on")
-    if cfg.get("specMode") not in (None, ""):
-        _require(str(cfg["specMode"]) in ("auto", "on", "off"),
-                 "serveConfig.specMode must be auto, on, or off")
-    if cfg.get("samplingEpilogue") not in (None, ""):
-        _require(str(cfg["samplingEpilogue"]) in ("auto", "on", "off"),
-                 "serveConfig.samplingEpilogue must be auto, on, or off")
-    if cfg.get("specTree") not in (None, ""):
-        # validated here (not just at engine start) so a bad tree spec is
-        # refused at admission instead of crash-looping replicas. Format
-        # mirrors serving.speculative.parse_spec_tree — kept dependency-
-        # free because the webhook must not import jax.
-        _require(cfg.get("specDraft") not in (None, ""),
-                 "serveConfig.specTree requires specDraft (tree drafts "
-                 "are proposed by the draft model)")
-        parts = str(cfg["specTree"]).lower().split("x")
-        ok = (len(parts) == 2 and parts[0].strip().isdigit()
-              and parts[1].strip().isdigit())
-        _require(ok, "serveConfig.specTree must be 'WxD' (branch width x "
-                     "draft depth, e.g. '4x3')")
-        w, d = int(parts[0]), int(parts[1])
-        _require(1 <= w <= 64 and 1 <= d <= 16,
-                 "serveConfig.specTree width must be 1..64 and depth "
-                 "1..16")
-    for key in ("specK", "prefillThreshold"):
-        if cfg.get(key) is not None:
-            v = _num(cfg[key], f"serveConfig.{key}")
-            _require(v >= 1 and float(v).is_integer(),
-                     f"serveConfig.{key} must be a positive integer")
     if cfg.get("fleetPrefixMb") is not None:
         _require(_num(cfg["fleetPrefixMb"],
                       "serveConfig.fleetPrefixMb") > 0,
@@ -207,10 +177,6 @@ def _validate_serve_config(cfg: dict):
                 validate_tenant_entry(str(name), entry)
             except ValueError as e:
                 _require(False, f"serveConfig.tenants: {e}")
-    if cfg.get("hostAdapterCacheMb") is not None:
-        _require(_num(cfg["hostAdapterCacheMb"],
-                      "serveConfig.hostAdapterCacheMb") >= 0,
-                 "serveConfig.hostAdapterCacheMb must be >= 0")
 
 
 def validate_finetuneexperiment(obj: CustomResource):

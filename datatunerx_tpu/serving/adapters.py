@@ -4,10 +4,9 @@
 random weights — the shape/layout of a real training run's Orbax state
 (``{"lora": {"layers": ...}}``, loadable by
 ``batched_engine.load_checkpoint_state``) without paying for a training
-run. Used by the side-by-side serving bench
-(``scripts/bench_serving.py::bench_multi_adapter``, BASELINE row 6) and its
-test (``tests/test_sidebyside_serving.py``); numerics are meaningless by
-design — only routing, throughput, and isolation are measured.
+run. Used by the side-by-side serving test
+(``tests/test_sidebyside_serving.py``, BASELINE row 6); numerics are
+meaningless by design — only routing and isolation are checked.
 """
 
 from __future__ import annotations

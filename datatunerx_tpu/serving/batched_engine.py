@@ -644,20 +644,22 @@ class BatchedEngine:
         kv_block_size: int = 0,  # >0: paged block-pool cache (elastic HBM)
         kv_blocks: Optional[int] = None,  # pool size; default = dense parity
         kv_overcommit: str = "off",  # on: lazy block growth + COW + preempt
-        paged_kernel: str = "auto",  # Pallas in-place decode: auto|on|off
+        # for tests and the parity oracle; deployments get "auto" (the
+        # kernel on a TPU backend, the XLA gather elsewhere): auto|on|off
+        paged_kernel: str = "auto",
         spec_draft: Optional[str] = None,  # draft model: path|preset:|take:N
         spec_k: int = 4,  # proposals per verify step (adaptive ceiling)
         spec_mode: str = "auto",  # auto (adaptive) | on (pinned) | off
         spec_tree: Optional[str] = None,  # "WxD" tree drafts (None = chain)
-        spec_tree_learned: bool = True,  # learned per-depth widths + early exit
-        sampling_epilogue: str = "auto",  # fused on-chip sampling: auto|on|off
+        # for tests and the parity oracle; deployments get "auto" (fused
+        # on-chip sampling on a TPU backend, the host sampler elsewhere)
+        sampling_epilogue: str = "auto",
         prefill_chunk: int = 256,  # chunked-prefill program length (paged)
         prefill_token_budget: int = 0,  # prefill tokens per tick (0 = all)
         registry: Optional[Registry] = None,  # shared /metrics registry
         tracing: bool = True,  # per-request span timelines + trace ring
         trace_ring: int = 256,  # completed traces kept for /debug/trace
         trace_log_path: Optional[str] = None,  # optional JSONL span log
-        prefix_keep_warm: bool = False,  # publish prompt blocks on preempt
         tenants=None,  # TenantDirectory / dict / path / inline JSON
         host_adapter_cache_mb: float = 0.0,  # host-RAM adapter tier budget
     ):
@@ -908,8 +910,6 @@ class BatchedEngine:
                     f"spec_tree {self.spec_tree} writes "
                     f"{self.spec_tree.step_tokens} tokens per step — does "
                     f"not fit max_seq_len {self.max_seq_len}")
-        self.spec_tree_learned = bool(spec_tree_learned) and \
-            self.spec_tree is not None
         # one verify step writes up to step-token-count tokens past a row's
         # cursor (chain: pending + k proposals; tree: pending + W*D nodes);
         # paged admission reserves that overshoot so every verify write
@@ -940,9 +940,8 @@ class BatchedEngine:
             }
             # learned tree shapes (AdaptiveTree): per-depth width selection
             # from acceptance EMAs + draft-side early exit on a decisive
-            # root margin. spec_tree_learned=False pins the fixed WxD
-            # rectangle controller — the bench's learned-vs-fixed twin.
-            ctrl_cls = (spec_mod.AdaptiveTree if self.spec_tree_learned
+            # root margin; chain drafts keep the adaptive-k controller
+            ctrl_cls = (spec_mod.AdaptiveTree if self.spec_tree is not None
                         else spec_mod.AdaptiveK)
             self.spec_ctrl = ctrl_cls(self.spec_k, mode=smode,
                                       tree=self.spec_tree)
@@ -979,7 +978,7 @@ class BatchedEngine:
         # dtx_serving_preemptions_total{outcome} source (scheduler-only
         # writes; scraped racily like every other stats dict)
         self.preempt_stats: Dict[str, int] = {}
-        # capacity observability for DTX_BENCH_SERVE_CAPACITY: the high-water
+        # capacity observability: the high-water
         # mark of concurrently admitted sessions and each finished session's
         # physical block footprint (== its peak: tables only ever grow)
         self.kv_stats = {"peak_sessions": 0,
@@ -1070,15 +1069,6 @@ class BatchedEngine:
         # refcounted BLOCK entries — hits map shared physical blocks into
         # the new slot's table instead of the dense-row copy + re-insert
         self.cow = self.overcommit and self._prefix is not None
-        # keep-warm (fleet plane, off by default = byte-identical engine):
-        # a preempted/drained slot publishes its prompt blocks as a
-        # no_reuse prefix entry before freeing, so the prompt survives the
-        # park as a COW-extendable prefix instead of dying with the slot.
-        # Requires COW entries (the publish is a block incref + tail copy).
-        self.prefix_keep_warm = bool(prefix_keep_warm) and self.cow
-        # slot → (prefix-cache key, prompt cursor) of the prompt the slot
-        # holds — what keep-warm publishes at preemption time
-        self._slot_key: List[Optional[tuple]] = [None] * slots
         # observability: how admissions were served (tests + /metrics)
         self.prefill_stats = {"full": 0, "reuse": 0, "extend": 0}
         # Shared-registry latency histograms. Recording is BUFFERED off the
@@ -1442,9 +1432,9 @@ class BatchedEngine:
         # the effective budget below min(requested, cold)
         need = min(budget_needed, self.max_seq_len - plen)
         ent = self._prefix.get(key)
-        # no_reuse entries (keep-warm publishes, logits-free tier imports)
-        # carry no activation logits — they serve strict-prefix extension
-        # only, never the exact-hit fast path
+        # no_reuse entries (logits-free tier imports) carry no activation
+        # logits — they serve strict-prefix extension only, never the
+        # exact-hit fast path
         if (ent is not None and not ent.get("no_reuse")
                 and self.max_seq_len - ent["cursor"] >= need):
             self.prefill_stats["reuse"] += 1
@@ -1811,7 +1801,6 @@ class BatchedEngine:
         self._slot_blocks[slot] = blocks
         self._slot_req[slot] = req
         self._slot_demand[slot] = self._eager_demand(final, max_new)
-        self._slot_key[slot] = (key, final)
         if suffix is None:
             self._decode_ready[slot] = True
         else:
@@ -1849,45 +1838,6 @@ class BatchedEngine:
         self._prefix.put(key, {"blocks": ent_blocks, "full": full,
                                "rem": rem, "cursor": cursor,
                                "logits": row_logits})
-
-    def _keep_warm(self, slot: int):
-        """Publish the slot's PROMPT prefix into the prefix cache as a
-        no-reuse COW block entry right before the slot is released
-        (preemption / drain export), so a resume — here or on a peer —
-        admits via a COW strict-prefix hit instead of re-paying the
-        prefix prefill. No logits are stored: exact-hit arming needs the
-        prompt's last-token logits, which a slot that has decoded past
-        its prompt no longer has, hence ``no_reuse``. Best-effort — a
-        missing key, an existing entry, or a pool too tight for the tail
-        copy all skip silently (serving beats caching)."""
-        sk = self._slot_key[slot]
-        if sk is None:
-            return
-        key, pcursor = sk
-        if self._prefix.get(key) is not None:
-            return
-        full, rem = divmod(pcursor, self.block_size)
-        blocks = self._slot_blocks[slot]
-        if len(blocks) < full + (1 if rem else 0):
-            return
-        shared = list(blocks[:full])
-        ent_blocks = list(shared)
-        if rem:
-            tail = self._allocator.alloc(1)
-            if tail is None:
-                return
-            # decode lanes past the prompt cursor live at offsets >= rem
-            # of the tail block — the COW copy scrubs them in the copy
-            self._cache = self._copy_block(
-                self._cache, jnp.asarray(blocks[full], jnp.int32),
-                jnp.asarray(tail[0], jnp.int32),
-                jnp.asarray(rem, jnp.int32))
-            ent_blocks = shared + tail
-        self._allocator.incref(shared)
-        self._prefix.put(key, {"blocks": ent_blocks, "full": full,
-                               "rem": rem, "cursor": pcursor,
-                               "logits": None, "no_reuse": True})
-        self._trace("keep_warm", slot, pcursor)
 
     def _alloc_blocks(self, depth: int) -> Optional[List[int]]:
         from datatunerx_tpu.ops.paged_attention import blocks_for_depth
@@ -2069,8 +2019,6 @@ class BatchedEngine:
             # suffix extensions already counted as "extend" at admission;
             # imported mid-prefill tails (key None) are not cold prefills
             self.prefill_stats["full"] += 1
-        if st.get("key") is not None:
-            self._slot_key[slot] = (st["key"], cursor)
         if self._prefix is not None and st.get("key") is not None:
             if self.cow:
                 # publish refcounted blocks — no dense-row materialisation
@@ -2200,9 +2148,17 @@ class BatchedEngine:
         """Continuation deltas of an imported session: text BEYOND the
         migrated tail, streamed as decode produces it (the tail itself was
         already emitted to the client by the source replica)."""
-        acc = list(req.tokens[: getattr(req, "resume_base", 0)])
-        sent = (self.tokenizer.decode(acc, skip_special_tokens=True)
-                if acc else "")
+        yield from self._stream_text(
+            req, list(req.tokens[: getattr(req, "resume_base", 0)]))
+
+    def _stream_text(self, req: Request, acc: List[int]):
+        """Text deltas of ``req`` beyond the tokens already in ``acc``, as
+        decode produces them. Text that ends in U+FFFD is held back (the
+        next token may complete the byte sequence) until the stream ends:
+        a reply whose last bytes never complete is what ``chat`` returns,
+        so the stream sends it too."""
+        sent = text = (self.tokenizer.decode(acc, skip_special_tokens=True)
+                       if acc else "")
         while True:
             t = req.stream.get()
             if t is None:
@@ -2213,7 +2169,10 @@ class BatchedEngine:
                 yield text[len(sent):]
                 sent = text
         if req.error:
+            # a migrated session's held tail belongs to its continuation
             raise RuntimeError(req.error)
+        if len(text) > len(sent):
+            yield text[len(sent):]
 
     def adapter_catalog(self) -> Dict[str, str]:
         """Registered adapter name → checkpoint path (dynamic pools only)
@@ -2354,11 +2313,6 @@ class BatchedEngine:
             self._trace("export", slot)
             if self.tracing:
                 req.mark("export", slot=slot, cursor=payload["cursor"])
-            if self.prefix_keep_warm:
-                # keep the session's prompt rows warm across the drain so
-                # a later resume-on-peer (or a sibling tenant) gets a COW
-                # hit instead of a cold prefill
-                self._keep_warm(slot)
             self._release_slot(slot)
             # the slot is still ACTIVE on device — every other release
             # happens after the decode kernel deactivated it. Clear the
@@ -2923,7 +2877,6 @@ class BatchedEngine:
         self._pending.pop(slot, None)
         self._decode_ready[slot] = False
         self._slot_demand[slot] = 0
-        self._slot_key[slot] = None
         if self.spec is not None:
             self._spec_form[slot] = False
             self._spec_primed[slot] = False
@@ -3072,11 +3025,6 @@ class BatchedEngine:
         if self.spec is not None and self._spec_form[slot]:
             self._spec_settle_slot(slot)
         payload = self._export_slot(slot, req, None, b64=False)
-        if self.prefix_keep_warm:
-            # publish the session's prompt rows before freeing them: a
-            # resume (here or on a peer) admits via a COW hit instead of
-            # re-paying the prefix prefill
-            self._keep_warm(slot)
         self._release_slot(slot, note_session=False)
         # the slot is still ACTIVE on device (only the decode kernel
         # deactivates slots itself) — clear the mask and budget NOW, or an
@@ -3385,7 +3333,7 @@ class BatchedEngine:
                 alpha = self.spec_ctrl.alpha
                 self._spec_adapter_ema[name] = (
                     rate if ema is None else ema + alpha * (rate - ema))
-            if plan[0] == "tree" and self.spec_tree_learned and obs:
+            if plan[0] == "tree" and obs:
                 # learned-shape inputs, from data already on host: the
                 # fraction of drafting rows whose accepted path reached
                 # depth ≥ j+1, and the fraction whose root top-2 logit
@@ -3457,7 +3405,6 @@ class BatchedEngine:
                 "spec": str(self.spec_tree),
                 "width": self.spec_tree.width,
                 "depth": self.spec_tree.depth,
-                "learned": self.spec_tree_learned,
                 # per-depth plan widths (dtx_serving_spec_tree_width{depth})
                 "widths": widths,
                 "plan_width": widths[0] if widths else self.spec_tree.width,
@@ -3756,19 +3703,7 @@ class BatchedEngine:
                           temperature=temperature, top_p=top_p, seed=seed,
                           stop_ids=stop_ids, adapter=adapter,
                           trace_id=trace_id, tenant=tenant)
-        sent = ""
-        acc: List[int] = []
-        while True:
-            t = req.stream.get()
-            if t is None:
-                break
-            acc.append(t)
-            text = self.tokenizer.decode(acc, skip_special_tokens=True)
-            if len(text) > len(sent) and not text.endswith("�"):
-                yield text[len(sent):]
-                sent = text
-        if req.error:
-            raise RuntimeError(req.error)
+        yield from self._stream_text(req, [])
 
     def close(self):
         self._shutdown.set()

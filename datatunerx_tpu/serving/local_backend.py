@@ -18,6 +18,8 @@ import urllib.error
 import urllib.request
 from typing import Dict, Optional
 
+from datatunerx_tpu.serving import options
+
 
 def _free_port() -> int:
     with socket.socket() as s:
@@ -52,11 +54,6 @@ class LocalServingBackend:
                 # status() and the scoring POST work unchanged
                 argv = [
                     sys.executable, "-m", "datatunerx_tpu.gateway.server",
-                    "--model_path", spec["model_path"],
-                    "--checkpoint_path", spec.get("checkpoint_path") or "",
-                    "--template", spec.get("template", self.template),
-                    "--port", str(port),
-                    "--quantization", spec.get("quantization") or "",
                     "--replicas", str(replicas),
                     "--policy", spec.get("policy") or "least_busy",
                     "--workdir", appdir,
@@ -72,36 +69,15 @@ class LocalServingBackend:
                             val = int(val)  # the gateway flags are ints
                         argv += [f"--{key}", str(val)]
             else:
-                argv = [
-                    sys.executable, "-m", "datatunerx_tpu.serving.server",
-                    "--model_path", spec["model_path"],
-                    "--checkpoint_path", spec.get("checkpoint_path") or "",
-                    "--template", spec.get("template", self.template),
-                    "--port", str(port),
-                    "--quantization", spec.get("quantization") or "",
-                ]
+                argv = [sys.executable, "-m", "datatunerx_tpu.serving.server"]
                 if spec.get("role"):
                     # single server: one role (the webhook rejects cycles
                     # when there is no gateway to distribute them)
                     argv += ["--role", str(spec["role"])]
-            if spec.get("slots"):
-                argv += ["--slots", str(spec["slots"])]
-            # paged-cache + adapter-pool tuning flows through the
-            # serveConfig untouched (serving.server and gateway.server
-            # both accept these); paged_kernel rides along so an operator
-            # can pin the decode path per deployment ("auto" is default
-            # and needs no spec entry)
-            for key in ("kv_block_size", "kv_blocks", "kv_overcommit",
-                        "prefill_chunk",
-                        "prefill_token_budget", "adapter_pool",
-                        "adapter_rank_max", "paged_kernel",
-                        "spec_draft_config", "spec_k", "spec_mode",
-                        "spec_tree", "sampling_epilogue",
-                        # multi-tenant QoS plane: both servers accept these
-                        # (the gateway forwards them to spawned replicas)
-                        "tenants_config", "host_adapter_cache_mb"):
-                if spec.get(key):
-                    argv += [f"--{key}", str(spec[key])]
+            # every engine option of the spec (serving/options.py); both
+            # servers accept them, the gateway hands them to its replicas
+            argv += ["--port", str(port), *options.argv(
+                {"template": self.template, **spec})]
             from datatunerx_tpu.operator.backends import _pkg_root
 
             env = dict(os.environ)

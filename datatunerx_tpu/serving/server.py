@@ -32,6 +32,7 @@ from datatunerx_tpu.obs.metrics import (
     set_uptime,
 )
 from datatunerx_tpu.obs.slo import SLOEvaluator, default_slos
+from datatunerx_tpu.serving import options
 
 
 class ServingState:
@@ -1049,88 +1050,40 @@ class Handler(BaseHTTPRequestHandler):
         pass
 
 
-def load_engine_async(model_path, checkpoint_path, template, max_seq_len,
-                      quantization=None, slots=4, decode_chunk=8,
-                      adapters=None, adapter_pool=0, adapter_rank_max=8,
-                      adapter_targets=None, kv_quant=None, prefix_cache=0,
-                      kv_block_size=0, kv_blocks=0, kv_overcommit="off",
-                      prefill_chunk=256,
-                      prefill_token_budget=0, paged_kernel="auto",
-                      spec_draft=None, spec_k=4, spec_mode="auto",
-                      spec_tree=None, sampling_epilogue="auto",
-                      trace_ring=256, trace_log_path=None,
-                      tenants_config=None, host_adapter_cache_mb=0.0,
-                      on_failure=None):
-    """Build the engine on a background thread. A failure is recorded in
+def load_engine_async(args, on_failure=None):
+    """Build the engine ``args`` (a namespace of serving/options.py) asks for
+    on a background thread. A failure is recorded in
     ``STATE.error`` (``/healthz`` → 500 FAILED), its traceback printed, and
     ``on_failure()`` called — ``main`` passes the HTTP server's shutdown so
     the process EXITS non-zero instead of answering 500 for ever: a replica
     that could not open its chip must not look like one still loading."""
     def _load():
         try:
-            STATE.model_path = model_path
-            batched = slots > 1 and not quantization
-            # refusing beats silently serving the base model under a tenant's
-            # adapter name / running a full-size cache the operator budgeted
-            # HBM against
-            for flag, val in (("--adapters", adapters),
-                              ("--adapter_pool", adapter_pool),
-                              ("--prefix_cache", prefix_cache),
-                              ("--kv_quant", kv_quant),
-                              ("--kv_block_size", kv_block_size),
-                              ("--kv_overcommit", kv_overcommit == "on"),
-                              # only "on" demands the batched paged engine;
-                              # "off"/"auto" are no-ops everywhere else
-                              ("--paged_kernel", paged_kernel == "on"),
-                              ("--spec_draft_config", spec_draft),
-                              ("--spec_tree", spec_tree),
-                              # only "on" demands the batched engine; the
-                              # single-slot path has no fused epilogue
-                              ("--sampling_epilogue",
-                               sampling_epilogue == "on"),
-                              ("--tenants_config", tenants_config),
-                              ("--host_adapter_cache_mb",
-                               host_adapter_cache_mb)):
-                if val and not batched:
-                    raise ValueError(
-                        f"{flag} requires the batched engine "
-                        "(--slots > 1, no --quantization)"
-                    )
-            if batched:
+            STATE.model_path = args.model_path
+            if args.slots > 1 and not args.quantization:
                 from datatunerx_tpu.serving.batched_engine import BatchedEngine
 
-                STATE.engine = BatchedEngine(
-                    model_path, checkpoint_path or None, adapters=adapters,
-                    adapter_pool=adapter_pool,
-                    adapter_rank_max=adapter_rank_max,
-                    adapter_targets=adapter_targets or None,
-                    template=template, max_seq_len=max_seq_len,
-                    slots=slots, decode_chunk=decode_chunk,
-                    kv_quant=kv_quant or None, prefix_cache=prefix_cache,
-                    kv_block_size=kv_block_size, kv_blocks=kv_blocks or None,
-                    kv_overcommit=kv_overcommit or "off",
-                    paged_kernel=paged_kernel or "auto",
-                    spec_draft=spec_draft or None,
-                    spec_k=spec_k, spec_mode=spec_mode or "auto",
-                    spec_tree=spec_tree or None,
-                    sampling_epilogue=sampling_epilogue or "auto",
-                    prefill_chunk=prefill_chunk,
-                    prefill_token_budget=prefill_token_budget,
-                    # the server's registry: engine TTFT/TPOT/prefill-chunk
-                    # histograms land in the same /metrics exposition
-                    registry=STATE.registry,
-                    trace_ring=trace_ring,
-                    trace_log_path=trace_log_path or None,
-                    tenants=tenants_config or None,
-                    host_adapter_cache_mb=host_adapter_cache_mb or 0.0,
-                )
+                # the server's registry: engine TTFT/TPOT/prefill-chunk
+                # histograms land in the same /metrics exposition
+                STATE.engine = BatchedEngine(registry=STATE.registry,
+                                             **options.engine_kwargs(args))
             else:
+                # refusing beats silently serving the base model under a
+                # tenant's adapter name / running a full-size cache the
+                # operator budgeted HBM against
+                refused = options.requires_batched(args)
+                if refused:
+                    raise ValueError(
+                        f"{refused[0]} requires the batched engine "
+                        "(--slots > 1, no --quantization)"
+                    )
                 # single-slot path also carries serve-time quantization
                 from datatunerx_tpu.serving.engine import InferenceEngine
 
                 STATE.engine = InferenceEngine(
-                    model_path, checkpoint_path or None, template=template,
-                    max_seq_len=max_seq_len, quantization=quantization or None,
+                    args.model_path, args.checkpoint_path or None,
+                    template=args.template, max_seq_len=args.max_seq_len,
+                    quantization=args.quantization or None,
                 )
         except Exception as e:  # noqa: BLE001 — reported, then fatal in main
             STATE.error = str(e) or type(e).__name__
@@ -1143,124 +1096,10 @@ def load_engine_async(model_path, checkpoint_path, template, max_seq_len,
     return t
 
 
-def parse_adapters(spec: str) -> dict:
-    """--adapters name=ckpt_path[,name=path…]"""
-    out = {}
-    for part in (spec or "").split(","):
-        part = part.strip()
-        if not part:
-            continue
-        name, _, path = part.partition("=")
-        if not name or not path:
-            raise ValueError(f"bad adapter spec {part!r}; want name=path")
-        out[name] = path
-    return out
-
-
-def main(argv=None):
+def build_parser():
     p = argparse.ArgumentParser(prog="datatunerx-tpu-serving")
-    p.add_argument("--model_path", required=True)
-    p.add_argument("--checkpoint_path", default="")
-    p.add_argument("--template", default="llama2")
-    p.add_argument("--max_seq_len", type=int, default=1024)
+    options.add_arguments(p)
     p.add_argument("--port", type=int, default=8000)
-    p.add_argument("--quantization", default="",
-                   choices=["", "int8", "int4", "nf4"],
-                   help="serve-time base-weight quantization")
-    p.add_argument("--slots", type=int, default=4,
-                   help="continuous-batching cache slots (1 = single-request engine)")
-    p.add_argument("--decode_chunk", type=int, default=8,
-                   help="tokens per decode program (admission latency bound)")
-    p.add_argument("--adapters", default="",
-                   help="named LoRA adapters: name=ckpt[,name=ckpt…]; "
-                        "requests select one via the 'model' field")
-    p.add_argument("--adapter_pool", type=int, default=0,
-                   help="dynamic multi-adapter pool: N HBM slots adapters "
-                        "load into at runtime (load-on-miss, LRU evict, "
-                        "POST/DELETE /admin/adapters); 0 = static "
-                        "--adapters stack baked at startup")
-    p.add_argument("--adapter_rank_max", type=int, default=8,
-                   help="pool rank ceiling; lower ranks are zero-padded "
-                        "(numerically invisible), higher ranks rejected")
-    p.add_argument("--adapter_targets", default="",
-                   help="pool LoRA target set, comma-separated (default "
-                        "q_proj,v_proj); adapters training other targets "
-                        "are rejected")
-    p.add_argument("--kv_quant", default="", choices=["", "int8"],
-                   help="int8-quantized KV cache: half the cache HBM, double "
-                        "the slots×context budget (batched engine only)")
-    p.add_argument("--prefix_cache", type=int, default=0,
-                   help="LRU entries of reusable prefilled prompt prefixes "
-                        "(shared system prompts / repeated probes skip "
-                        "prefill; batched engine only; costs one cache row "
-                        "of HBM per entry)")
-    p.add_argument("--kv_block_size", type=int, default=0,
-                   help="paged KV cache block size in tokens (0 = dense "
-                        "slots×max_seq_len cache); admission reserves "
-                        "blocks, not full-width rows — see README "
-                        "'Serving performance' for the HBM math")
-    p.add_argument("--kv_blocks", type=int, default=0,
-                   help="total blocks in the paged pool (default "
-                        "slots × max_seq_len / kv_block_size; set lower to "
-                        "serve the same slots in less HBM)")
-    p.add_argument("--kv_overcommit", default="off",
-                   choices=["off", "on"],
-                   help="on: KV overcommit — admission reserves only the "
-                        "prompt's blocks plus a small headroom, the "
-                        "scheduler grows tables at each cursor, prefix-"
-                        "cache hits share refcounted blocks copy-on-write, "
-                        "and exhaustion preempts youngest-first (sessions "
-                        "park host-side and resume token-exactly). off "
-                        "(default) = eager ceil((prompt+max_new)/bs) "
-                        "reserve, byte-identical to the pre-overcommit "
-                        "engine")
-    p.add_argument("--paged_kernel", default="auto",
-                   choices=["auto", "on", "off"],
-                   help="Pallas in-place paged-attention decode kernel: "
-                        "auto = kernel on TPU / XLA gather elsewhere, "
-                        "on = force the kernel (interpret-mode on CPU), "
-                        "off = always the gather oracle; needs "
-                        "--kv_block_size > 0 to engage")
-    p.add_argument("--spec_draft_config", default="",
-                   help="speculative decoding draft model: a model path, "
-                        "preset:<name> (same vocab as the target), or "
-                        "take:N (self-speculative — the target's first N "
-                        "layers). Empty = speculative decoding off")
-    p.add_argument("--spec_k", type=int, default=4,
-                   help="draft proposals per verify step (the adaptive "
-                        "controller's ceiling)")
-    p.add_argument("--spec_mode", default="auto",
-                   choices=["auto", "on", "off"],
-                   help="speculative decoding: auto = adaptive (shrink k / "
-                        "fall back to plain decode when acceptance "
-                        "collapses), on = always draft, off = exactly "
-                        "today's decode path")
-    p.add_argument("--spec_tree", default="",
-                   help="tree-draft speculative verification: 'WxD' (branch "
-                        "width x draft depth, e.g. 4x3) flattens a per-slot "
-                        "token tree into one batched verify forward and "
-                        "accepts the longest surviving root-to-leaf path. "
-                        "Requires --spec_draft_config. Empty (default) = "
-                        "chain drafts, byte-identical to before")
-    p.add_argument("--sampling_epilogue", default="auto",
-                   choices=["auto", "on", "off"],
-                   help="fused on-chip sampling epilogue "
-                        "(ops/pallas_sampling.py): decode/spec programs "
-                        "sample inside the traced computation instead of "
-                        "materializing [slots, vocab] logits for the host "
-                        "sampler. auto = on for TPU backends, off "
-                        "elsewhere; on = force anywhere (non-TPU runs use "
-                        "the exact XLA oracle); off = legacy sampler, "
-                        "programs byte-identical to before")
-    p.add_argument("--prefill_chunk", type=int, default=256,
-                   help="chunked-prefill program length in tokens (paged "
-                        "engine); long prompts prefill in chunks "
-                        "interleaved with decode")
-    p.add_argument("--prefill_token_budget", type=int, default=0,
-                   help="max prefill tokens the scheduler spends between "
-                        "decode chunks (0 = unbounded); bounds the TPOT "
-                        "hit a long admission can inflict on in-flight "
-                        "requests")
     p.add_argument("--role", default="mixed",
                    choices=["prefill", "decode", "mixed"],
                    help="disaggregation role declared to the fleet: "
@@ -1270,23 +1109,6 @@ def main(argv=None):
                         "for decode), decode = token production, mixed "
                         "(default) = role-less, routing byte-identical "
                         "to older fleets")
-    p.add_argument("--tenants_config", default="",
-                   help="multi-tenant QoS directory: a JSON file path or "
-                        "inline JSON object mapping tenant → {tier: "
-                        "pinned|standard|bulk, adapters: [...], share, "
-                        "kv_block_quota, ttft_p95_ms}. Empty (default) = "
-                        "tenancy plane off, scheduling byte-identical")
-    p.add_argument("--host_adapter_cache_mb", type=float, default=0.0,
-                   help="host-RAM adapter tier budget in MB: evicted "
-                        "adapters' host arrays stay cached so "
-                        "evict→reload skips the orbax read; 0 (default) "
-                        "= tier off")
-    p.add_argument("--trace_ring", type=int, default=256,
-                   help="completed request traces kept for "
-                        "GET /debug/trace/<id>")
-    p.add_argument("--trace_log", default="",
-                   help="append every completed request span as one JSON "
-                        "line to this file (offline trace forensics)")
     p.add_argument("--slo_config", default="",
                    help="JSON file of SLO specs (obs/slo.py format) judged "
                         "at GET /debug/slo; default: built-in serving "
@@ -1294,7 +1116,11 @@ def main(argv=None):
     p.add_argument("--slo_sample_s", type=float, default=15.0,
                    help="background SLO sampling interval (0 = sample only "
                         "on /debug/slo)")
-    args = p.parse_args(argv)
+    return p
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     from datatunerx_tpu.utils import runtime
 
@@ -1312,31 +1138,7 @@ def main(argv=None):
         slo_evaluator().start(args.slo_sample_s)
 
     srv = ThreadingHTTPServer(("0.0.0.0", args.port), Handler)
-    load_engine_async(args.model_path, args.checkpoint_path, args.template,
-                      args.max_seq_len, quantization=args.quantization,
-                      slots=args.slots, decode_chunk=args.decode_chunk,
-                      adapters=parse_adapters(args.adapters),
-                      adapter_pool=args.adapter_pool,
-                      adapter_rank_max=args.adapter_rank_max,
-                      adapter_targets=[t.strip() for t in
-                                       args.adapter_targets.split(",")
-                                       if t.strip()] or None,
-                      kv_quant=args.kv_quant, prefix_cache=args.prefix_cache,
-                      kv_block_size=args.kv_block_size,
-                      kv_blocks=args.kv_blocks,
-                      kv_overcommit=args.kv_overcommit,
-                      prefill_chunk=args.prefill_chunk,
-                      prefill_token_budget=args.prefill_token_budget,
-                      paged_kernel=args.paged_kernel,
-                      spec_draft=args.spec_draft_config,
-                      spec_k=args.spec_k, spec_mode=args.spec_mode,
-                      spec_tree=args.spec_tree,
-                      sampling_epilogue=args.sampling_epilogue,
-                      trace_ring=args.trace_ring,
-                      trace_log_path=args.trace_log,
-                      tenants_config=args.tenants_config,
-                      host_adapter_cache_mb=args.host_adapter_cache_mb,
-                      on_failure=srv.shutdown)
+    load_engine_async(args, on_failure=srv.shutdown)
     print(f"[serving] listening on :{args.port} (model loading async)", flush=True)
     try:
         srv.serve_forever()

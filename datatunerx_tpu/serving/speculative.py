@@ -41,10 +41,8 @@ import numpy as np
 from datatunerx_tpu.models.llama import forward, init_cache
 from datatunerx_tpu.ops.attention import compact_window
 from datatunerx_tpu.ops.pallas_sampling import fused_sample, sample_rows
+from datatunerx_tpu.serving import options
 from datatunerx_tpu.serving.engine import _sample_jit
-
-SPEC_MODES = ("auto", "on", "off")
-SAMPLING_EPILOGUES = ("auto", "on", "off")
 
 
 # ------------------------------------------------------------- tree topology
@@ -72,20 +70,7 @@ class TreeSpec:
 
 def parse_spec_tree(spec: str) -> TreeSpec:
     """Parse ``--spec_tree`` / ``serveConfig.specTree`` ``"WxD"`` strings."""
-    err = (f"spec_tree must be 'WxD' (branch width x draft depth, e.g. "
-           f"'4x3'), got {spec!r}")
-    parts = str(spec).strip().lower().split("x")
-    if len(parts) != 2:
-        raise ValueError(err)
-    try:
-        width, depth = int(parts[0]), int(parts[1])
-    except ValueError:
-        raise ValueError(err) from None
-    if not 1 <= width <= 64 or not 1 <= depth <= 16:
-        raise ValueError(
-            f"spec_tree {spec!r} out of range: width must be in [1, 64] "
-            "and depth in [1, 16]")
-    return TreeSpec(width, depth)
+    return TreeSpec(*options.parse_spec_tree(spec))
 
 
 def _tree_col(j: int, b: int, width: int) -> int:
@@ -517,28 +502,14 @@ class AdaptiveK:
         return streak >= self.probe_every  # probe: one spec step, re-measure
 
     def current_plan(self) -> tuple:
-        """The step shape this tick runs: ``("chain", k)`` or ``("tree",
-        widths)`` where ``widths`` is the per-depth width tuple. The fixed
-        tree controller degrades along WIDTH as global acceptance collapses
-        (full W while it holds, half on mediocre, a width-1
-        chain-of-depth-D near the floor) — same thresholds, same
-        bounded-program-set property as ``current_k``. No tree configured
-        = degenerate chain = byte-identical PR 14 behavior. ``AdaptiveTree``
-        overrides the tree branch with LEARNED per-depth widths."""
+        """The step shape this tick runs: ``("chain", k)``, or from
+        ``AdaptiveTree`` ``("tree", widths)`` where ``widths`` is the
+        learned per-depth width tuple."""
         with self._lock:
             return self.current_plan_locked()
 
     def current_plan_locked(self) -> tuple:
-        if self.tree is None:
-            return ("chain", self.current_k_locked())
-        g = self.global_ema
-        if g is None or g >= 0.6:
-            w = self.tree.width
-        elif g >= 0.3:
-            w = max(1, self.tree.width // 2)
-        else:
-            w = 1
-        return ("tree", (w,) * self.tree.depth)
+        return ("chain", self.current_k_locked())
 
     # ---- observability
     def snapshot(self) -> dict:

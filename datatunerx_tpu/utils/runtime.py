@@ -3,7 +3,7 @@ backend it may use, where compiled programs are kept, and one line that
 says what it got.
 
 Called once at each entry point (``tuning/train.py run``, ``serving/server.py
-main``, ``bench.py``, ``chip_smoke.py``'s kernel child) before anything is
+main``, ``benchmarks/``, ``chip_smoke.py``'s kernel child) before anything is
 jitted:
 
 - ``configure_compile_cache()`` — the persistent XLA compilation cache. When

@@ -145,6 +145,33 @@ def test_streaming_deltas_concatenate_to_full_output(batched):
         assert n_events >= 1
 
 
+def test_stream_sends_a_reply_that_ends_mid_utf8_sequence(batched):
+    """Text ending in U+FFFD is held back while the next token may complete
+    the byte sequence; when the reply ENDS there, chat returns it, so the
+    stream must too (it used to drop the tail: '' against chat's '��')."""
+    import queue
+    import types
+
+    tok = batched.tokenizer
+    ids = tok.encode("aé")[:-1]  # 'a' + the first byte of a two-byte é
+    req = types.SimpleNamespace(stream=queue.Queue(), error=None)
+    for t in ids + [None]:
+        req.stream.put(t)
+    deltas = list(batched._stream_text(req, []))
+    assert deltas == ["a", "�"]
+    assert "".join(deltas) == tok.decode(ids, skip_special_tokens=True)
+    # a migrated stream's held tail is its continuation's to send
+    req = types.SimpleNamespace(stream=queue.Queue(), error="session migrated")
+    for t in ids + [None]:
+        req.stream.put(t)
+    with pytest.raises(RuntimeError, match="migrated"):
+        list(batched._stream_text(req, []))
+    # end to end, on a reply the debug weights end in an incomplete sequence
+    msgs = [{"role": "user", "content": "tell me a long story about foxes"}]
+    full = batched.chat(msgs, max_new_tokens=40)
+    assert "".join(batched.chat_stream(msgs, max_new_tokens=40)) == full
+
+
 def test_unknown_adapter_rejected(batched):
     with pytest.raises(KeyError, match="unknown adapter"):
         batched.submit([1, 2, 3], adapter="nope")

@@ -1373,7 +1373,7 @@ def test_repo_lints_clean_at_head():
     cross-module program pass ON, over the same surface CI lints."""
     cfg = dataclasses.replace(load_config("."), cache="")
     res, _ = lint_program(
-        ["datatunerx_tpu", "scripts", "bench.py", "__graft_entry__.py"],
+        ["datatunerx_tpu", "scripts", "__graft_entry__.py"],
         config=cfg)
     baseline = load_baseline(cfg.resolve(cfg.baseline))
     new, _ = partition(res.findings, baseline)

@@ -118,15 +118,41 @@ def test_kube_serving_backend_renders_slots(cluster):
     srv, client, workdir = cluster
     backend = KubeServingBackend(client, out_dir=os.path.join(workdir, "s2"))
     backend.deploy("s2", {"llmPath": "/models/m", "checkpointPath": "/ckpt",
-                          "slots": 4})
+                          "slots": 2})
     dep = client.get("apps", "v1", "deployments", "default", "s2")
     args = dep["spec"]["template"]["spec"]["containers"][0]["args"]
     i = args.index("--slots")
-    assert args[i + 1] == "4"
-    # absent slots -> flag omitted (server default applies)
+    assert args[i + 1] == "2"
+    # absent slots (or the server's own default) -> flag omitted
     backend.deploy("s3", {"llmPath": "/models/m"})
     dep = client.get("apps", "v1", "deployments", "default", "s3")
     assert "--slots" not in dep["spec"]["template"]["spec"]["containers"][0]["args"]
+
+
+def test_kube_serving_backend_renders_every_serve_config_option(cluster):
+    """What generate_serving_spec rendered from serveConfig reaches the pod:
+    the kube backend used to pass five flags and drop the rest, so
+    serveConfig.adapterPool was admitted and then ignored on a cluster."""
+    from datatunerx_tpu.operator.api import FinetuneJob
+    from datatunerx_tpu.operator.generate import generate_serving_spec
+
+    srv, client, workdir = cluster
+    job = FinetuneJob(metadata=ObjectMeta(name="j4"), spec={
+        "serveConfig": {"adapterPool": 16, "kvOvercommit": "on",
+                        "specDraft": "take:1", "nodeSelector": {"pool": "a"}}})
+    spec = generate_serving_spec(job, {"llmPath": "/models/m",
+                                       "checkpointPath": "/ckpt"})
+    backend = KubeServingBackend(client, out_dir=os.path.join(workdir, "s4"))
+    backend.deploy("s4", spec)
+    pod = client.get("apps", "v1", "deployments", "default",
+                     "s4")["spec"]["template"]["spec"]
+    args = pod["containers"][0]["args"]
+    for flag, value in (("--model_path", "/models/m"),
+                        ("--checkpoint_path", "/ckpt"),
+                        ("--adapter_pool", "16"), ("--kv_overcommit", "on"),
+                        ("--spec_draft_config", "take:1"), ("--port", "8000")):
+        assert args[args.index(flag) + 1] == value, (flag, args)
+    assert pod["nodeSelector"]["pool"] == "a"
 
 
 # ------------------------------------- full manifest-mode Finetune lifecycle

@@ -265,7 +265,7 @@ def test_four_concurrent_jobs_through_slice_placement(tmp_path):
             },
             # single-slot serving: 4 concurrent batched engines compiling
             # at once starves a CPU box; slot scaling is covered by
-            # scripts/bench_serving.py + test_batched_engine
+            # test_batched_engine
             "serveConfig": {"slots": 1},
         }}
 
