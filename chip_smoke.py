@@ -804,9 +804,11 @@ def child_kernels(rehearsal: bool) -> int:
         return jnp.stack([x[::-1], x])
 
     def decode(q, q_pos, k_pool, v_pool, ks, vs, tables, pos):
+        # the query sits on the last written lane (no pads): its lane cursor
+        # is its position
         return paged_decode_attention(
             q, *(stacked(x) for x in (k_pool, v_pool, ks, vs)),
-            jnp.asarray(1, jnp.int32), tables, pos, q_pos)
+            jnp.asarray(1, jnp.int32), tables, pos, q_pos, q_pos)
 
     def multitoken(q, q_pos, k_pool, v_pool, ks, vs, tables, pos):
         tbl = jnp.where(tables >= 0, tables, 0)
@@ -853,6 +855,44 @@ def child_kernels(rehearsal: bool) -> int:
                       jax.jit(multitoken)(qm, qp, *mpool),
                       jax.jit(gather_attention)(qm, qp, *mpool),
                       atol=2e-2, rtol=2e-2)
+
+    # ---- the decode kernel at the shape and fill of the benchmark's cell
+    # qwen-serve-steady (16 slots, a table of 128 columns of 16, 32 KV heads
+    # of 128, 384 blocks): three live slots whose tables hold a reserved tail
+    # past the cursor, thirteen released ones (table -1, a stale cursor)
+    def paged_cell():
+        B, H, KV, d, bs = 16, 32, 32, 128, SERVE_BLOCK
+        nbps, NB = (128, 384) if not rehearsal else (16, 48)
+        live = [40, 500, 1100] if not rehearsal else [5, 40, 100]
+        lens = np.asarray(live + [300 if not rehearsal else 30]
+                          * (B - len(live)))
+        k_pool, v_pool = normal((NB, bs, KV, d)), normal((NB, bs, KV, d))
+        perm = rng.permutation(NB)
+        tables = np.full((B, nbps), -1, np.int32)
+        pos = np.full((NB, bs), POS_SENTINEL, np.int32)
+        lane = np.arange(nbps * bs).reshape(nbps, bs)
+        at = 0
+        for b, n in enumerate(live):
+            held = -(-n // bs) + 8  # admission reserves prompt + max_new
+            tables[b, :held] = perm[at:at + held]
+            at += held
+            for j in range(-(-n // bs)):
+                pos[tables[b, j]] = np.where(lane[j] < n, lane[j],
+                                             POS_SENTINEL)
+        pool = (k_pool, v_pool, None, None, jnp.asarray(tables),
+                jnp.asarray(pos))
+        q = normal((B, H, d))
+        qpos = jnp.asarray(lens - 1, jnp.int32)
+        got = jax.jit(decode)(q, qpos, *pool)
+        want = jax.jit(gather_attention)(q[:, None], qpos[:, None],
+                                         *pool)[:, 0]
+        n_live = len(live)
+        check(f"paged_decode_bf16 [cell B{B} bs{bs} W{nbps * bs} "
+              f"live {live}]", got[:n_live], want[:n_live],
+              atol=2e-2, rtol=2e-2)
+        check("paged_decode_bf16 [cell: released slots read zero]",
+              got[n_live:], jnp.zeros_like(got[n_live:]), atol=0,
+              exact=True)
 
     # ---- fused sampler at S = slots and at the benchmark cells' 16: greedy
     # bitwise, simple exact by seed
@@ -911,6 +951,7 @@ def child_kernels(rehearsal: bool) -> int:
         guarded(f"quant [{gname}]", lambda: quant(gname, D, F))
         guarded(f"lora [{gname}]", lambda: lora(gname, D))
         guarded(f"paged [{gname}]", lambda: paged(gname, H, KV, d))
+    guarded("paged_cell", paged_cell)
     # both models' vocab 32000 and Qwen's 151936 (several tiles, the last
     # one ragged)
     for V in ((32000, 151936) if not rehearsal else (512,)):

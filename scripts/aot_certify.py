@@ -253,8 +253,9 @@ def kernel_artifacts(cert: Certifier, dev):
         x, w, a, b))
 
     # paged-decode attention (ops/pallas_paged_attention.py): the serving
-    # fast path — scalar-prefetched block-table walk, bf16 and int8 pools
-    # at tinyllama serving geometry (GQA 32q/4kv, bs=16, 64 blocks/slot)
+    # fast path — one grid step a slot, a loop over the slot's written
+    # blocks with hand-issued copies; bf16 and int8 pools at tinyllama
+    # serving geometry (GQA 32q/4kv, bs=16, 64 blocks/slot)
     from datatunerx_tpu.ops.pallas_paged_attention import (
         paged_decode_attention,
     )
@@ -274,14 +275,15 @@ def kernel_artifacts(cert: Certifier, dev):
                                    sharding=sh)
     pool_sc = jax.ShapeDtypeStruct((Ld, NBd, bsd, KVd), jnp.float32,
                                    sharding=sh)
+    # qpos twice: the query's rope position and its lane cursor
     cert.run("kernel/paged_decode_bf16", lambda: _lower(
-        lambda q, k, v, li, t, p, qp: paged_decode_attention(
-            q, k, v, None, None, li, t, p, qp),
-        qd, pool_bf16, pool_bf16, layer, tables, pos, qpos))
+        lambda q, k, v, *rest: paged_decode_attention(
+            q, k, v, None, None, *rest),
+        qd, pool_bf16, pool_bf16, layer, tables, pos, qpos, qpos))
     cert.run("kernel/paged_decode_int8_kv", lambda: _lower(
-        lambda q, k, v, ks, vs, li, t, p, qp: paged_decode_attention(
-            q, k, v, ks, vs, li, t, p, qp),
-        qd, pool_i8, pool_i8, pool_sc, pool_sc, layer, tables, pos, qpos))
+        paged_decode_attention,
+        qd, pool_i8, pool_i8, pool_sc, pool_sc, layer, tables, pos, qpos,
+        qpos))
 
     for name, fn, args in serving_kernel_cases(sh):
         cert.run(name, lambda fn=fn, args=args: _lower(fn, *args))

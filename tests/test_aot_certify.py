@@ -79,20 +79,33 @@ def sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
 
-# the single-token decode kernel at the same geometry (the shared list holds
-# the multi-token kernel and the sampler)
-H, KV, d = ENGINE_HEADS["tinyllama"]
+# the single-token decode kernel at the same geometry, and at the shape the
+# benchmark's cell qwen-serve-steady runs it at (16 slots, 32 KV heads of 128,
+# a table of 128 columns, 384 blocks, 16 stacked layers), bf16 and int8 pools
+# (the shared list holds the multi-token kernel and the sampler)
+def decode_cases(tag, geometry, slots, nbps, blocks, layers):
+    H, KV, d = ENGINE_HEADS[geometry]
+    # layer, tables, pos pool, q positions, lane cursors
+    rest = (sds((), jnp.int32), sds((slots, nbps), jnp.int32),
+            sds((blocks, ENGINE_BLOCK), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots,), jnp.int32))
+    q = sds((slots, H, d), jnp.bfloat16)
+    shape = (layers, blocks, ENGINE_BLOCK, KV * d)
+    pool, pool_i8 = sds(shape, jnp.bfloat16), sds(shape, jnp.int8)
+    scale = sds(shape[:3] + (KV,), jnp.float32)
+    return [
+        (f"kernel/paged_decode_bf16_{tag}",
+         lambda q, k, v, *r: paged_decode_attention(q, k, v, None, None, *r),
+         (q, pool, pool) + rest),
+        (f"kernel/paged_decode_int8_kv_{tag}", paged_decode_attention,
+         (q, pool_i8, pool_i8, scale, scale) + rest)]
+
+
 nbps = ENGINE_SEQ // ENGINE_BLOCK
-pool = sds((ENGINE_LAYERS, ENGINE_SLOTS * nbps, ENGINE_BLOCK, KV * d),
-           jnp.bfloat16)
-cases = list(serving_kernel_cases(sh)) + [(
-    "kernel/paged_decode_bf16_tinyllama",
-    lambda q, k, v, li, t, p, qp: paged_decode_attention(
-        q, k, v, None, None, li, t, p, qp),
-    (sds((ENGINE_SLOTS, H, d), jnp.bfloat16), pool, pool, sds((), jnp.int32),
-     sds((ENGINE_SLOTS, nbps), jnp.int32),
-     sds((ENGINE_SLOTS * nbps, ENGINE_BLOCK), jnp.int32),
-     sds((ENGINE_SLOTS,), jnp.int32)))]
+cases = (list(serving_kernel_cases(sh))
+         + decode_cases("tinyllama", "tinyllama", ENGINE_SLOTS, nbps,
+                        ENGINE_SLOTS * nbps, ENGINE_LAYERS)
+         + decode_cases("cell", "llama2_7b", 16, 128, 384, 16))
 KERNEL_NAMES = ("dtx_paged_decode", "dtx_paged_multitoken", "dtx_fused_sample")
 done, failed, named = [], {}, {}
 for name, fn, args in cases:
@@ -141,7 +154,11 @@ def test_serving_kernels_lower_through_mosaic_at_engine_geometry():
         assert want in names, (want, sorted(names))
     # each kernel keeps the name its pallas_call was given through Mosaic:
     # the device trace's readers (benchmarks/scope_readers.py) find it by that
-    assert "kernel/paged_decode_bf16_tinyllama" in names
+    for want in ("kernel/paged_decode_bf16_tinyllama",
+                 "kernel/paged_decode_int8_kv_tinyllama",
+                 "kernel/paged_decode_bf16_cell",
+                 "kernel/paged_decode_int8_kv_cell"):
+        assert want in names, (want, sorted(names))
     for case, kernels in doc["named"].items():
         want = ("dtx_fused_sample" if "fused_sample" in case else
                 "dtx_paged_decode" if "paged_decode" in case else

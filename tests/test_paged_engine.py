@@ -310,6 +310,118 @@ def test_kernel_pooled_adapter_parity(tmp_path):
         kern.close()
 
 
+# the paths that move a slot's cursor or its table without a plain decode
+# step, kernel on against gather off, everything else equal: (engine keywords,
+# scenario). A scenario drives one engine and returns its token streams,
+# greedy and fixed-seed sampled; it asserts that the path it is named for ran.
+_SAMPLED = {"temperature": 0.8, "top_p": 0.9, "seed": 7}
+
+
+def _off_bucket_prompts(eng):
+    """Prompt lengths off the 64-token bucket (left padding inside the
+    chunk: the query's lane runs ahead of its rope position), on it, and
+    past it by less than a block, alone and in flight together."""
+    prompts = [list(range(3, 3 + n)) for n in (5, 64, 70)]
+    out = [eng.generate(p, max_new_tokens=10, **kw)
+           for p in prompts for kw in ({}, _SAMPLED)]
+    reqs = [eng.submit(p, max_new_tokens=6 + 5 * i)
+            for i, p in enumerate(prompts[:2])]
+    for r in reqs:
+        assert r.done.wait(300) and r.error is None, r.error
+    return out + [r.tokens for r in reqs]
+
+
+def _cow_prefix_hit(eng):
+    """Slots whose tables map the SAME blocks for a shared prefix: an exact
+    hit, then a strict-prefix hit that extends it."""
+    tok = eng.tokenizer
+    p1 = tok.encode("shared system prompt for every request here")
+    p2 = tok.encode("shared system prompt for every request here plus")
+    out = [eng.generate(p1, max_new_tokens=10) for _ in range(2)]
+    out.append(eng.generate(p2, max_new_tokens=10))
+    out.append(eng.generate(p1, max_new_tokens=10, **_SAMPLED))
+    modes = {e[3] for e in eng.sched_trace if e[0] == "admit"}
+    assert "cow" in modes and "cow_extend" in modes, modes
+    return out
+
+
+def _preempt_and_resume(eng):
+    """Four sessions growing toward 9 blocks each on a 20-block pool: tables
+    grow a block at a time, and a preempted session's blocks and cursor are
+    exported and written back into other blocks."""
+    prompts = [eng.tokenizer.encode(f"request number {i} probing growth")
+               for i in range(4)]
+    reqs = [eng.submit(p, max_new_tokens=80, **kw)
+            for p, kw in zip(prompts, ({}, _SAMPLED, {}, _SAMPLED))]
+    for i, r in enumerate(reqs):
+        assert r.done.wait(300), f"request {i} stalled"
+        assert r.error is None, (i, r.error)
+    assert eng.preempt_stats.get("exported", 0) >= 1, eng.preempt_stats
+    assert eng.free_kv_blocks == eng.total_kv_blocks
+    return [r.tokens for r in reqs]
+
+
+def _spec_rejections(eng):
+    """A weak draft (one layer of two): most proposals are rejected and the
+    cursor rolls back over lanes that stay written."""
+    tok = eng.tokenizer
+    out = [eng.generate(tok.encode(text), max_new_tokens=16, **kw)
+           for text in ("hello world this is serving", "short")
+           for kw in ({}, _SAMPLED)]
+    info = eng.spec_info()
+    assert info["accepted"] < info["proposed"], info
+    return out
+
+
+def _migrated_resume(eng):
+    """A session exported mid-decode and imported again: its rows land in
+    fresh blocks and decode goes on from the cursor it carried."""
+    from test_session_handoff import _export_mid_decode, _import_and_wait
+
+    prompt = eng.tokenizer.encode("the quick brown fox jumps over")
+    out = []
+    for kw in ({}, _SAMPLED):
+        payload = _export_mid_decode(eng, prompt, max_new_tokens=24, **kw)
+        handle, _ = _import_and_wait(eng, payload)
+        out.append(handle.tokens)
+    assert eng.session_stats["import"].get("ok", 0) >= 2
+    return out
+
+
+_CURSOR_PATHS = {
+    "off_bucket_prompt": ({}, _off_bucket_prompts),
+    "cow_prefix_hit": (dict(kv_overcommit="on", prefix_cache=4),
+                       _cow_prefix_hit),
+    "preempt_and_resume": (dict(slots=4, kv_blocks=20, kv_overcommit="on"),
+                           _preempt_and_resume),
+    "spec_rejections": (dict(slots=3, spec_draft="take:1", spec_k=3,
+                             spec_mode="on"), _spec_rejections),
+    "migrated_resume": ({}, _migrated_resume),
+}
+
+
+@pytest.mark.parametrize("path", sorted(_CURSOR_PATHS))
+def test_kernel_matches_gather_where_the_cursor_or_table_moves(path):
+    """The decode kernel walks a slot's table as far as its cursor, so every
+    path that sets a cursor or a table has to leave them telling the truth.
+    (int8 pools: test_kernel_int8_kv_parity; pooled adapters:
+    test_kernel_pooled_adapter_parity; chunked prefill's handoff:
+    test_kernel_chunked_prefill_handoff.)"""
+    extra, scenario = _CURSOR_PATHS[path]
+    kw = dict(dict(template="vanilla", max_seq_len=256, slots=2,
+                   decode_chunk=4, kv_block_size=16), **extra)
+    streams = {}
+    for mode in ("off", "on"):
+        eng = BatchedEngine(MODEL, paged_kernel=mode, **kw)
+        try:
+            assert eng.decode_path == ("pallas" if mode == "on" else "gather")
+            streams[mode] = scenario(eng)
+        finally:
+            eng.close()
+    assert all(streams["off"]), streams["off"]
+    assert streams["on"] == streams["off"]
+
+
 def test_kernel_flag_validation():
     with pytest.raises(ValueError, match="kv_block_size"):
         BatchedEngine(MODEL, template="vanilla", max_seq_len=256, slots=2,

@@ -117,9 +117,10 @@ def _run(B=2, NB=8, nbps=3, KV=2, G=2, d=16, lens=(17, 5), dtype=jnp.float32,
     q = jax.random.normal(jax.random.fold_in(key, 99),
                           (B, H, d)).astype(dtype)
     q_positions = jnp.asarray([int(x) - 1 for x in lens], jnp.int32)
+    # the query is the last written token: its lane is the cursor
     got = paged_decode_attention(
         q, *(_stacked(p, layer) for p in (kp, vp, ks, vs)), layer, tables,
-        pos, q_positions)
+        pos, q_positions, jnp.maximum(q_positions, 0))
     want = _oracle(q, kp, vp, ks, vs, tables, pos, q_positions, dtype)
     assert got.dtype == q.dtype
     return np.asarray(got, np.float32), np.asarray(want, np.float32)
@@ -218,6 +219,129 @@ def test_empty_slot_yields_finite_output():
     np.testing.assert_array_equal(got[1], 0.0)
 
 
+# ------------------------------------------------- the walk's bound (cursor)
+
+# one compile a (shape, dtype) for the cases below, which differ in values
+_jitted_decode = jax.jit(paged_decode_attention)
+
+
+def _cursor_run(cursors, pads=None, nbps=5, KV=2, G=2, d=16,
+                dtype=jnp.float32, quant=False, past=None, seed=0):
+    """Slots whose lane cursors are ``cursors``: slot ``b`` has lanes
+    ``0 .. cursors[b]`` written, the query its last one. Its first ``pads[b]``
+    lanes are a prompt's left padding (sentinel position, junk K/V), the rope
+    positions count from the lane after them. ``past`` fills every table
+    column past the cursor with a VALID block of sentinel positions holding
+    ``"finite"`` large values or ``"nan"`` (NaN scales for the int8 pools,
+    which hold none): the oracle reads the table cut at the cursor."""
+    B, H = len(cursors), KV * G
+    pads = pads or (0,) * B
+    NB = B * nbps
+    rng = np.random.default_rng(seed)
+    junk = {None: 0.0, "finite": 3e4, "nan": np.nan}[past]
+    k_pool = np.full((NB, BS, KV, d), junk, np.float32)
+    v_pool = np.full((NB, BS, KV, d), junk, np.float32)
+    pos = np.full((NB, BS), POS_SENTINEL, np.int32)
+    tables = np.full((B, nbps), -1, np.int32)
+    cut = tables.copy()
+    for b, (c, pad) in enumerate(zip(cursors, pads)):
+        assert pad <= c < nbps * BS
+        held = c // BS + 1
+        tables[b, :held if past is None else nbps] = np.arange(
+            b * nbps, b * nbps + (held if past is None else nbps))
+        cut[b, :held] = tables[b, :held]
+        for lane in range(c + 1):
+            blk, off = tables[b, lane // BS], lane % BS
+            k_pool[blk, off] = rng.standard_normal((KV, d))
+            v_pool[blk, off] = rng.standard_normal((KV, d))
+            if lane >= pad:
+                pos[blk, off] = lane - pad
+        # the unwritten lanes of the cursor's own block are a scrubbed block's
+        k_pool[tables[b, c // BS], c % BS + 1:] = 0.0
+        v_pool[tables[b, c // BS], c % BS + 1:] = 0.0
+    # the oracle's pools hold what the cursors reach and zeros elsewhere
+    reached = np.unique(cut[cut >= 0])
+    beyond = np.setdiff1d(np.arange(NB), reached)
+    keep = lambda a: jnp.zeros_like(a).at[reached].set(a[reached])  # noqa: E731
+    kp, vp = jnp.asarray(k_pool).astype(dtype), jnp.asarray(v_pool).astype(dtype)
+    clean = pools = [keep(kp), keep(vp), None, None]
+    if quant:
+        kq, ks = kv_quantize(keep(jnp.asarray(k_pool)))
+        vq, vs = kv_quantize(keep(jnp.asarray(v_pool)))
+        clean = [kq, vq, ks, vs]
+        # an int8 block holds no NaN: its scales do, and dequantize to one
+        pools = [kq.at[beyond].set(127), vq.at[beyond].set(127),
+                 ks.at[beyond].set(junk), vs.at[beyond].set(junk)]
+    elif past is not None:
+        pools = [kp, vp, None, None]
+    q = jnp.asarray(rng.standard_normal((B, H, d))).astype(dtype)
+    cursor = jnp.asarray(cursors, jnp.int32)
+    q_positions = cursor - jnp.asarray(pads, jnp.int32)
+    tables, cut, pos = jnp.asarray(tables), jnp.asarray(cut), jnp.asarray(pos)
+    got = _jitted_decode(
+        q, *(_stacked(a) for a in pools), LAYER, tables, pos, q_positions,
+        cursor)
+    want = _oracle(q, *clean, cut, pos, q_positions, dtype)
+    return np.asarray(got, np.float32), np.asarray(want, np.float32)
+
+
+# around a block's edge is where a bound off by one drops or adds a column
+_CURSORS = (0, 1, BS - 1, BS, BS + 1, 3 * BS + 5, 5 * BS - 1)
+
+
+@pytest.mark.parametrize("past", [None, "finite", "nan"],
+                         ids=["table_ends_at_cursor", "finite_past_cursor",
+                              "nan_past_cursor"])
+@pytest.mark.parametrize("i", range(len(_CURSORS)),
+                         ids=[f"cursor{c}" for c in _CURSORS])
+def test_walk_covers_the_cursor_and_nothing_past_it(i, past):
+    """Slots of different cursors in one batch equal the oracle over the
+    table cut at each cursor: f32 to rounding, bf16 and int8-under-bf16
+    bitwise. With valid blocks in the columns past the cursor (what admission
+    reserves), their contents change nothing: they are never read."""
+    cursors = tuple(_CURSORS[(i + k) % len(_CURSORS)] for k in (0, 1, 3))
+    got, want = _cursor_run(cursors, past=past)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    for quant in (False, True):
+        got, want = _cursor_run(cursors, past=past, dtype=jnp.bfloat16,
+                                quant=quant)
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("pad", [1, BS - 1, BS + 3])
+def test_left_padded_rows_are_bounded_by_the_cursor(pad, dtype):
+    """A prompt left-padded inside its chunk: ``pad`` sentinel lanes, then
+    rope positions 0 .. n. The query's lane is ``pad + n``, past where its
+    rope position points, so a bound taken from ``q_positions`` would drop
+    the newest columns."""
+    n = 2 * BS
+    got, want = _cursor_run((pad + n, pad + 3, pad), pads=(pad,) * 3,
+                            dtype=dtype, past="nan")
+    if dtype == jnp.bfloat16:
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_grid_steps_follow_the_slots_not_the_table():
+    """The walk over the table is a loop inside the kernel: one pallas_call
+    whose grid has at most two steps a slot, whatever the table's width."""
+    B, KV, G, d, nbps, NB = 4, 2, 2, 16, 64, 8
+    pool = jnp.zeros((LAYERS, NB, BS, KV * d), jnp.bfloat16)
+    ints = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    jaxpr = jax.make_jaxpr(
+        lambda q: paged_decode_attention(
+            q, pool, pool, None, None, LAYER, ints(B, nbps), ints(NB, BS),
+            ints(B), ints(B)))(jnp.zeros((B, KV * G, d), jnp.bfloat16))
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert calls[0].params["name"] == "dtx_paged_decode"
+    assert int(np.prod(calls[0].params["grid_mapping"].grid)) <= 2 * B
+    assert calls[0].outvars[0].aval.shape == (B, KV * G, d)
+
+
 def test_decode_step_wrapper_shape():
     from datatunerx_tpu.ops.pallas_paged_attention import (
         paged_attention_decode_step,
@@ -229,7 +353,7 @@ def test_decode_step_wrapper_shape():
     tables = jnp.asarray([[0, 1], [2, -1]], jnp.int32)
     kp, vp, ks, vs, pos, _, _ = _make_pool(key, B, NB, KV, d, (9, 4), tables)
     q = jax.random.normal(kq, (B, 1, H, d))
-    cache = {"block_tables": tables}
+    cache = {"block_tables": tables, "len": jnp.asarray([8, 3], jnp.int32)}
     leaves = _leaves(kp, vp, None, None)
     out = paged_attention_decode_step(
         q, leaves, LAYER, cache, pos, jnp.asarray([[8], [3]], jnp.int32))
