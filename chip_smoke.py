@@ -665,36 +665,49 @@ def child_kernels(rehearsal: bool) -> int:
         return jnp.asarray(
             rng.standard_normal(shape, np.float32) * scale, dtype)
 
-    # ---- flash attention fwd/bwd: causal GQA, and packed segments
+    # ---- flash attention fwd/bwd: causal GQA, and packed segments; both
+    # again under a sliding window of a quarter tile over rows of four tiles,
+    # so that tiles wholly behind the window are skipped (no cell reaches
+    # that: a FinetuneJob's rows are shorter than the window of every model
+    # it trains)
     def flash(gname, H, KV, d):
-        B, T = (2, 1024) if not rehearsal else (2, 128)
-        q, k, v = (normal((B, T, n, d)) for n in (H, KV, KV))
-        pos = jnp.asarray(np.broadcast_to(np.arange(T, dtype=np.int32),
-                                          (B, T)))
-        seg = jnp.asarray(np.broadcast_to(
-            np.where(np.arange(T) < T // 2, 1, 2).astype(np.int32), (B, T)))
-        for label, s in (("causal_gqa", None), ("segmented", seg)):
-            def f_kernel(q, k, v, s=s):
-                return flash_attention(q, k, v, segment_ids=s)
+        B, T0 = (2, 1024) if not rehearsal else (2, 128)
+        for T, w in ((T0, None), (2 * T0, T0 // 4)):
+            q, k, v = (normal((B, T, n, d)) for n in (H, KV, KV))
+            pos = jnp.asarray(np.broadcast_to(np.arange(T, dtype=np.int32),
+                                              (B, T)))
+            seg = jnp.asarray(np.broadcast_to(
+                np.where(np.arange(T) < T // 2, 1, 2).astype(np.int32),
+                (B, T)))
+            for label, s in (("causal_gqa", None), ("segmented", seg)):
+                if w is not None:
+                    label = f"window{w}_{label}"
 
-            def f_oracle(q, k, v, s=s):
-                return xla_attention(q, k, v, make_causal_bias(
-                    pos, pos, q_segment_ids=s, kv_segment_ids=s))
+                def f_kernel(q, k, v, s=s, w=w):
+                    return flash_attention(q, k, v, segment_ids=s,
+                                           sliding_window=w)
 
-            check(f"flash_fwd_{label} [{gname} B{B} T{T}]",
-                  jax.jit(f_kernel)(q, k, v), jax.jit(f_oracle)(q, k, v),
-                  atol=3e-2)
+                def f_oracle(q, k, v, s=s, w=w, pos=pos):
+                    return xla_attention(q, k, v, make_causal_bias(
+                        pos, pos, sliding_window=w, q_segment_ids=s,
+                        kv_segment_ids=s))
 
-            def loss(f):
-                return lambda q, k, v: (
-                    f(q, k, v).astype(jnp.float32) ** 2).sum()
+                check(f"flash_fwd_{label} [{gname} B{B} T{T}]",
+                      jax.jit(f_kernel)(q, k, v), jax.jit(f_oracle)(q, k, v),
+                      atol=3e-2)
 
-            gk = jax.jit(jax.grad(loss(f_kernel), argnums=(0, 1, 2)))(q, k, v)
-            go = jax.jit(jax.grad(loss(f_oracle), argnums=(0, 1, 2)))(q, k, v)
-            for nm, a, b in zip(("dq", "dk", "dv"), gk, go):
-                scale = float(np.abs(np.asarray(b, np.float32)).max())
-                check(f"flash_bwd_{label}_{nm} [{gname} B{B} T{T}]", a, b,
-                      atol=3e-2 * max(scale, 1.0))
+                def loss(f):
+                    return lambda q, k, v: (
+                        f(q, k, v).astype(jnp.float32) ** 2).sum()
+
+                gk = jax.jit(jax.grad(loss(f_kernel), argnums=(0, 1, 2)))(
+                    q, k, v)
+                go = jax.jit(jax.grad(loss(f_oracle), argnums=(0, 1, 2)))(
+                    q, k, v)
+                for nm, a, b in zip(("dq", "dk", "dv"), gk, go):
+                    scale = float(np.abs(np.asarray(b, np.float32)).max())
+                    check(f"flash_bwd_{label}_{nm} [{gname} B{B} T{T}]", a, b,
+                          atol=3e-2 * max(scale, 1.0))
 
     # ---- quantized matmuls at the model's projection shapes
     def quant(gname, D, F):

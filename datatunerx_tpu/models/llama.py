@@ -198,8 +198,7 @@ def _log_attention_once(requested: str, traced: str, seq_len: int,
     note = ""
     if traced != requested:
         note = (" — DOWNGRADED: flash needs T % 128 == 0 or T < 128, ring "
-                "takes no packed segments, and neither takes a sliding "
-                "window")
+                "takes no packed segments and no sliding window")
     print(f"[attention] requested={requested} traced={traced} T={seq_len} "
           f"sliding_window={sliding_window} packed={packed}{note}",
           file=sys.stderr, flush=True)
@@ -331,14 +330,14 @@ def forward(
         kv_valid = None  # sentinel positions handle both unwritten and pads
         kv_seg = None
     # flash/ring kernels skip the [B, T, S] bias entirely (building it would
-    # defeat their O(T) memory win). Flash handles causal + packed segments
-    # in-kernel; ring is causal-only. Cache decode and sliding window need the
+    # defeat their O(T) memory win). Flash handles causal + packed segments +
+    # sliding window in-kernel; ring is causal-only. Cache decode needs the
     # biased path.
     _flash_ok = (
         cfg.attention_impl in ("flash", "ring")
         and cache is None
-        and cfg.sliding_window is None
-        and (cfg.attention_impl != "ring" or segment_ids is None)
+        and (cfg.attention_impl != "ring"
+             or (segment_ids is None and cfg.sliding_window is None))
         and (cfg.attention_impl != "flash" or T % 128 == 0 or T < 128)
     )
     allow = None
@@ -374,7 +373,8 @@ def forward(
 
     drop = lora_dropout if (dropout_rng is not None and lora is not None) else 0.0
 
-    # packed segments, sliding window, and cache decode need the biased path
+    # a forward with a cache, and a shape or config the requested kernel
+    # cannot take, go the biased path
     att_impl = cfg.attention_impl if _flash_ok else (
         "xla" if cfg.attention_impl in ("flash", "ring") else cfg.attention_impl
     )
@@ -460,7 +460,8 @@ def forward(
             with jax.named_scope("dtx.attn"):
                 attn = attention(
                     q, k_att, v_att, bias, impl=att_impl,
-                    segment_ids=segment_ids if att_impl == "flash" else None)
+                    segment_ids=segment_ids if att_impl == "flash" else None,
+                    sliding_window=cfg.sliding_window)
         with jax.named_scope("dtx.attn_out"):
             attn = attn.reshape(B, T, cfg.q_dim)
             x = x + _proj(attn, lp["o_proj"], lget("o_proj"), lora_scale,

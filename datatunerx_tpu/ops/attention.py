@@ -358,13 +358,17 @@ def attention(
     *,
     impl: str = "xla",
     segment_ids: jnp.ndarray | None = None,
+    sliding_window: int | None = None,
 ) -> jnp.ndarray:
+    """``segment_ids`` and ``sliding_window`` reach the flash kernels, which
+    mask in-kernel; the other paths read both from ``bias``."""
     if impl == "xla":
         return xla_attention(q, k, v, bias)
     if impl == "flash":
         from datatunerx_tpu.ops.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, bias, segment_ids=segment_ids)
+        return flash_attention(q, k, v, bias, segment_ids=segment_ids,
+                               sliding_window=sliding_window)
     if impl == "ring":
         from datatunerx_tpu.ops.ring_attention import (
             get_ring_context,

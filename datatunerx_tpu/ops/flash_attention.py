@@ -10,8 +10,16 @@ tiles, with D = rowsum(dO ∘ O) precomputed).
 Masking is handled in-kernel: causal by row index, plus packed-segment
 isolation via per-row segment ids (all-equal ids degenerate to plain causal,
 so unpacked right-padded batches are exact — pads sit at the tail where no
-valid query can attend them). Sliding window and cache decode fall back to the
-biased XLA path (models/llama.py).
+valid query can attend them), plus a sliding window by row index: inside a
+packed segment the difference of row indices is the difference of rope
+positions, and the segment test isolates the rest. A window no query can
+feel (``window >= S``) is dropped at trace time. Cache decode falls back to
+the biased XLA path (models/llama.py).
+
+Products take their operands in the arrays' own dtype and accumulate in f32,
+as the einsum path does (ops/attention.py:xla_attention): logits, running
+max / sum / logsumexp and the accumulators stay f32, and the probabilities
+and ``ds`` are cast to the operand dtype only where they enter a product.
 """
 
 from __future__ import annotations
@@ -80,12 +88,39 @@ def _flash_shard_mesh():
     return mesh, batch_axes, tp
 
 
+def _tile_runs(i, j, block_q: int, block_k: int, causal: bool, window):
+    """Whether q tile ``i`` and k tile ``j`` hold a pair the row-index masks
+    let through: not wholly in the future (causal), not wholly behind every
+    row's window. causal=False (ring-of-flash past chunks): every block
+    contributes — the in/visible split is decided OUTSIDE the kernel per ring
+    step (full vs none), so the kernel stays static."""
+    if not causal:
+        return j >= 0
+    run = j * block_k <= i * block_q + block_q - 1
+    if window is not None:
+        run &= j * block_k + block_k - 1 > i * block_q - window
+    return run
+
+
+def _tile_mask(i, j, block_q: int, block_k: int, causal: bool, window,
+               qseg_ref, kseg_ref):
+    """[block_q, block_k] bool: causal and window by row index, AND
+    packed-segment isolation (all-equal ids = plain causal)."""
+    shape = (block_q, block_k)
+    q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mask = (k_pos <= q_pos) if causal else (k_pos >= 0)
+    if causal and window is not None:
+        mask &= k_pos > q_pos - window
+    return mask & (qseg_ref[0][:, 0:1] == kseg_ref[0][0:1, :])
+
+
 # ------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
                 *, block_q: int, block_k: int, scale: float,
-                causal: bool = True):
+                causal: bool = True, window=None):
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -96,24 +131,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # causal=False (ring-of-flash past chunks): every block contributes and
-    # no triangular mask applies — the in/visible split is decided OUTSIDE
-    # the kernel per ring step (full vs none), so the kernel stays static
-    run = (j * block_k <= i * block_q + block_q - 1) if causal else (j >= 0)
-
-    @pl.when(run)  # causal: skip fully-future blocks
+    @pl.when(_tile_runs(i, j, block_q, block_k, causal, window))
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
         ) * scale
-
-        q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (k_pos <= q_pos) if causal else (k_pos >= 0)
-        # packed-segment isolation (all-equal ids = plain causal)
-        mask &= qseg_ref[0][:, 0:1] == kseg_ref[0][0:1, :]
+        mask = _tile_mask(i, j, block_q, block_k, causal, window,
+                          qseg_ref, kseg_ref)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[:, 0:1]
@@ -123,7 +149,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, o_ref, lse_ref,
 
         l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -148,13 +174,13 @@ def _kv_index(H: int, G: int):
 
 
 def _fwd(q, k, v, q_seg, kv_seg, *, block_q, block_k, interpret, H, G,
-         causal: bool = True):
+         causal: bool = True, window=None):
     BH, T, d = q.shape
     S = k.shape[1]
     scale = 1.0 / (d ** 0.5)
     kernel = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, scale=scale,
-        causal=causal,
+        causal=causal, window=window,
     )
     kv_idx = _kv_index(H, G)
     q_seg3, kv_seg3 = _seg3d(q_seg, kv_seg)
@@ -189,10 +215,23 @@ def _fwd(q, k, v, q_seg, kv_seg, *, block_q, block_k, interpret, H, G,
 
 # ------------------------------------------------------------- backward
 
+def _tile_p_ds(q, k, v, do, lse_ref, dsum_ref, mask, scale: float):
+    """One tile's probabilities and logit gradients, both f32 [bq, bk],
+    recomputed from the saved logsumexp and ``dsum = rowsum(dO * O)``."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, 0:1]), 0.0)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return p, p * (dp - dsum_ref[0][:, 0:1]) * scale
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
                    qseg_ref, kseg_ref, dq_ref,
                    acc_ref, *, block_q: int, block_k: int, scale: float,
-                   causal: bool = True):
+                   causal: bool = True, window=None):
     i = pl.program_id(1)
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -201,29 +240,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    run = (j * block_k <= i * block_q + block_q - 1) if causal else (j >= 0)
-
-    @pl.when(run)
+    @pl.when(_tile_runs(i, j, block_q, block_k, causal, window))
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = ((k_pos <= q_pos) if causal else (k_pos >= 0)) \
-            & (qseg_ref[0][:, 0:1] == kseg_ref[0][0:1, :])
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, 0:1]), 0.0)
-
-        do = do_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - dsum_ref[0][:, 0:1]) * scale
+        k = k_ref[0]
+        mask = _tile_mask(i, j, block_q, block_k, causal, window,
+                          qseg_ref, kseg_ref)
+        _, ds = _tile_p_ds(q_ref[0], k, v_ref[0], do_ref[0], lse_ref,
+                           dsum_ref, mask, scale)
         acc_ref[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
 
     @pl.when(j == nk - 1)
@@ -235,7 +261,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
                     qseg_ref, kseg_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
                     *, block_q: int, block_k: int, scale: float,
-                    causal: bool = True):
+                    causal: bool = True, window=None):
     j = pl.program_id(1)  # k tile
     i = pl.program_id(2)  # q tile (sequential)
     nq = pl.num_programs(2)
@@ -245,32 +271,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = (i * block_q + block_q - 1 >= j * block_k) if causal else (i >= 0)
-
-    @pl.when(run)  # causal: skip q tiles fully in the past
+    # the same tile test, read from the k tile's side: q tiles wholly in the
+    # past of the k tile, or wholly beyond its window, are skipped
+    @pl.when(_tile_runs(i, j, block_q, block_k, causal, window))
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = ((k_pos <= q_pos) if causal else (k_pos >= 0)) \
-            & (qseg_ref[0][:, 0:1] == kseg_ref[0][0:1, :])
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, 0:1]), 0.0)  # [bq, bk]
-
-        do = do_ref[0].astype(jnp.float32)  # [bq, d]
+        q = q_ref[0]
+        do = do_ref[0]  # [bq, d]
+        mask = _tile_mask(i, j, block_q, block_k, causal, window,
+                          qseg_ref, kseg_ref)
+        p, ds = _tile_p_ds(q, k_ref[0], v_ref[0], do, lse_ref, dsum_ref,
+                           mask, scale)  # [bq, bk]
         dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [bq, bk]
-        ds = p * (dp - dsum_ref[0][:, 0:1]) * scale
+        )  # [bk, d]
         dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )  # [bk, d]
 
     @pl.when(i == nq - 1)
@@ -279,21 +296,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(block_q, block_k, interpret, G, res, do, causal: bool = True):
-    """K/V arrive un-expanded [B*KV, S, d]; expand here (backward only) and
-    group-sum dk/dv at the end — forward never materializes the repeat."""
+def _bwd(block_q, block_k, interpret, G, res, do, causal: bool = True,
+         window=None):
+    """K/V arrive un-expanded [B*KV, S, d] and stay so: as in the forward,
+    the index map routes each q head to its KV group's rows. dk/dv come out
+    per q head and are group-summed at the end."""
     q, k, v, q_seg, kv_seg, out, lse = res
     BH, T, d = q.shape
-    if G > 1:
-        BKV = k.shape[0]
-        k = jnp.repeat(k, G, axis=0)
-        v = jnp.repeat(v, G, axis=0)
-    S = k.shape[1]
+    BKV, S = k.shape[:2]
     scale = 1.0 / (d ** 0.5)
     if interpret is None:
         interpret = _interpret()
 
     H_ = BH // q_seg.shape[0]  # q heads per batch row (segment index maps)
+    kv_idx = _kv_index(H_, G)
     dsum = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     lse_b = jnp.broadcast_to(lse[:, :, None], (BH, T, _LANES))
     dsum_b = jnp.broadcast_to(dsum[:, :, None], (BH, T, _LANES))
@@ -301,12 +317,12 @@ def _bwd(block_q, block_k, interpret, G, res, do, causal: bool = True):
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                          scale=scale, causal=causal),
+                          scale=scale, causal=causal, window=window),
         grid=(BH, T // block_q, S // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_idx),
+            pl.BlockSpec((1, block_k, d), kv_idx),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
@@ -322,12 +338,12 @@ def _bwd(block_q, block_k, interpret, G, res, do, causal: bool = True):
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                          scale=scale, causal=causal),
+                          scale=scale, causal=causal, window=window),
         grid=(BH, S // block_k, T // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, i: kv_idx(b, i, j)),
+            pl.BlockSpec((1, block_k, d), lambda b, j, i: kv_idx(b, i, j)),
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0)),
@@ -357,27 +373,29 @@ def _bwd(block_q, block_k, interpret, G, res, do, causal: bool = True):
 
 # --------------------------------------------------------------- public
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def flash_attention_causal(q, k, v, q_seg, kv_seg, block_q: int = 512,
                            block_k: int = 512, interpret=None, H: int = 1,
-                           G: int = 1):
+                           G: int = 1, window=None):
     """q: [B*H, T, d]; k, v: [B*KV, S, d] (un-expanded GQA);
-    q_seg/kv_seg: [B, T]/[B, S] int32 segment ids (all-equal = plain causal)."""
+    q_seg/kv_seg: [B, T]/[B, S] int32 segment ids (all-equal = plain causal);
+    window: a static sliding window that binds (< S), or None."""
     out, _ = _fwd(q, k, v, q_seg, kv_seg, block_q=block_q, block_k=block_k,
                   interpret=_interpret() if interpret is None else interpret,
-                  H=H, G=G)
+                  H=H, G=G, window=window)
     return out
 
 
-def _vjp_fwd(q, k, v, q_seg, kv_seg, block_q, block_k, interpret, H, G):
+def _vjp_fwd(q, k, v, q_seg, kv_seg, block_q, block_k, interpret, H, G,
+             window):
     out, lse = _fwd(q, k, v, q_seg, kv_seg, block_q=block_q, block_k=block_k,
                     interpret=_interpret() if interpret is None else interpret,
-                    H=H, G=G)
+                    H=H, G=G, window=window)
     return out, (q, k, v, q_seg, kv_seg, out, lse)
 
 
-def _vjp_bwd(block_q, block_k, interpret, H, G, res, do):
-    dq, dk, dv = _bwd(block_q, block_k, interpret, G, res, do)
+def _vjp_bwd(block_q, block_k, interpret, H, G, window, res, do):
+    dq, dk, dv = _bwd(block_q, block_k, interpret, G, res, do, window=window)
     return dq, dk, dv, None, None
 
 
@@ -385,7 +403,13 @@ flash_attention_causal.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 def _pick_block(n: int, cap: int = 512) -> int:
-    """Largest power-of-two divisor of n, capped (TPU-friendly tile sizes)."""
+    """Largest power-of-two divisor of n, capped (TPU-friendly tile sizes).
+
+    The cap is measured (v5e, B 8, H 32, T 1024, d 128, bf16; PERF.md §6,
+    PR 33): 512 x 512 beats every mix of 128, 256 and 512 in all three
+    kernels (forward 1.91 ms against 3.41 at 256 x 256 and 8.04 at 128 x
+    128), though the causal skip then runs 3 tiles of 4 and not 10 of 16: a
+    grid step has a fixed cost, and skipped tiles are still fetched."""
     b = 1
     while b < cap and n % (b * 2) == 0:
         b *= 2
@@ -399,6 +423,7 @@ def flash_attention(
     bias=None,  # accepted for dispatch parity; causal handled in-kernel
     *,
     segment_ids: jnp.ndarray | None = None,  # [B, T] packed-segment ids
+    sliding_window: int | None = None,
     block_q: int = 512,
     block_k: int = 512,
     interpret=None,
@@ -406,7 +431,9 @@ def flash_attention(
     """GQA wrapper: fold (B, H) into the grid dim; KV stays un-expanded and the
     kernel's index_map routes each q head to its KV group. With segment_ids,
     attention is additionally confined within packed segments (self-attention:
-    T == S, ids shared between q and kv).
+    T == S, ids shared between q and kv). With ``sliding_window``, a query
+    sees the keys less than that many rows behind it; one that reaches past
+    the first key of every row (``>= S``) emits the window-less kernels.
 
     Under an active mesh (set_flash_context) the call is wrapped in
     shard_map over the batch (+tp head) axes — Mosaic custom calls cannot
@@ -427,27 +454,31 @@ def flash_attention(
 
         if segment_ids is None:
             def local3(q, k, v):
-                return _flash_local(q, k, v, None, block_q, block_k,
-                                    interpret)
+                return _flash_local(q, k, v, None, sliding_window, block_q,
+                                    block_k, interpret)
 
             return jax.shard_map(local3, mesh=mesh, in_specs=(qkv_spec,) * 3,
                                  out_specs=qkv_spec, check_vma=False)(q, k, v)
 
         def local(q, k, v, seg):
-            return _flash_local(q, k, v, seg, block_q, block_k, interpret)
+            return _flash_local(q, k, v, seg, sliding_window, block_q,
+                                block_k, interpret)
 
         return jax.shard_map(local, mesh=mesh,
                              in_specs=(qkv_spec, qkv_spec, qkv_spec,
                                        seg_spec),
                              out_specs=qkv_spec, check_vma=False)(
             q, k, v, segment_ids)
-    return _flash_local(q, k, v, segment_ids, block_q, block_k, interpret)
+    return _flash_local(q, k, v, segment_ids, sliding_window, block_q,
+                        block_k, interpret)
 
 
-def _flash_local(q, k, v, segment_ids, block_q, block_k, interpret):
+def _flash_local(q, k, v, segment_ids, window, block_q, block_k, interpret):
     B, T, H, d = q.shape
     S, KV = k.shape[1], k.shape[2]
     G = H // KV
+    if window is not None and window >= S:
+        window = None  # removes no key from any query
     block_q = min(block_q, _pick_block(T))
     block_k = min(block_k, _pick_block(S))
     if segment_ids is None:
@@ -462,5 +493,5 @@ def _flash_local(q, k, v, segment_ids, block_q, block_k, interpret):
     kf = k.transpose(0, 2, 1, 3).reshape(B * KV, S, d)
     vf = v.transpose(0, 2, 1, 3).reshape(B * KV, S, d)
     out = flash_attention_causal(qf, kf, vf, q_seg, kv_seg, block_q, block_k,
-                                 interpret, H, G)
+                                 interpret, H, G, window)
     return out.reshape(B, H, T, d).transpose(0, 2, 1, 3)

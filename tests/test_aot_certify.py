@@ -257,6 +257,53 @@ def test_expert_layer_compiles_to_grouped_matmul_kernels_for_v5e():
         assert seen["ragged"] >= 3 and seen["mosaic"] >= 3, (rows, seen)
 
 
+# The training kernels at the shape the benchmark's cell mistral-train-lora
+# runs them at (8 rows of 1,024, 32 heads over 8 KV heads of 128, bf16, packed
+# segments): forward and both backward kernels, window-less as the cell emits
+# them (rows shorter than Mistral's window) and under a window that binds and
+# skips tiles (rows of 2,048, window 256), which no cell reaches.
+_FLASH_PROBE = r"""
+import json, os
+os.environ["DTX_PALLAS_INTERPRET"] = "0"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
+jax.config.update("jax_platforms", "cpu")
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+from datatunerx_tpu.ops.flash_attention import flash_attention
+
+sh = SingleDeviceSharding(topologies.get_topology_desc(
+    platform="tpu", topology_name="v5e:2x2").devices[0])
+sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+out = {}
+for T, window in ((1024, 4096), (2048, 256)):
+    def loss(q, k, v, seg):
+        return flash_attention(q, k, v, segment_ids=seg,
+                               sliding_window=window).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds((8, T, 32, 128), jnp.bfloat16), sds((8, T, 8, 128), jnp.bfloat16),
+        sds((8, T, 8, 128), jnp.bfloat16), sds((8, T), jnp.int32)
+    ).compile().as_text()
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    out[f"T{T}_w{window}"] = [k for k in (
+        "dtx_flash_fwd", "dtx_flash_bwd_dq", "dtx_flash_bwd_dkv")
+        if sum(k + ")" in l for l in calls) == 1]
+print(json.dumps(out))
+"""
+
+
+def test_flash_kernels_compile_for_v5e_at_the_training_cell_shape():
+    pytest.importorskip("libtpu")  # the TPU compiler; absent from jax[cpu]
+    doc = _run_probe(_FLASH_PROBE, timeout=600)
+    assert sorted(doc) == ["T1024_w4096", "T2048_w256"]
+    for case, kernels in doc.items():
+        # one Mosaic custom call each, its pallas_call's name in its op_name:
+        # the device trace of the cell shows the three under train.attn_share
+        assert kernels == ["dtx_flash_fwd", "dtx_flash_bwd_dq",
+                           "dtx_flash_bwd_dkv"], (case, kernels)
+
+
 @pytest.mark.slow
 def test_aot_pipeline_compiles_for_v5e_target():
     assert _run_probe(_PROBE, timeout=900)["ok"] is True
