@@ -516,17 +516,44 @@ def phase_kernels(rehearsal: bool, budget_s: float, name: str = "kernels") -> di
     return info
 
 
+def _served_gaps(eng, reference, work_items, verdict, tag):
+    """How far each served greedy token's logit lies below the plain float32
+    reference's best, over each request's own full forward; ``work_items`` is
+    [(prompt, adapter name, request)]."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    mc = dataclasses.asdict(eng.cfg)
+    gaps = []
+    for prompt, name, req in work_items:
+        if not req.done.wait(900) or req.error:
+            verdict(f"{tag}/serve[{name or 'base'}]", False, str(req.error))
+            continue
+        tokens = list(prompt) + list(req.tokens)
+        rows = list(range(len(prompt) - 1, len(tokens) - 1))
+        lora = None
+        if name:
+            e = eng.adapter_ids[name]
+            lora = jax.tree_util.tree_map(lambda a: a[:, e], eng.lora_stack[0]["layers"])
+        ref = reference.sequence_logits(
+            eng.params, mc, tokens, rows, lora,
+            float(eng.lora_stack[1][eng.adapter_ids[name]]) if name else 0.0)
+        got = jnp.take_along_axis(ref, jnp.asarray(req.tokens)[:, None], axis=-1)[:, 0]
+        gaps.append(np.asarray(jnp.max(ref, axis=-1) - got))
+    return np.concatenate(gaps) if gaps else np.asarray([np.inf])
+
+
 def child_hybrid(rehearsal: bool) -> int:
     """Runs IN the chip-holding child: the batched engine on the debug preset
     of a model with window and global attention layers and sparse experts
     (``preset:debug-hybrid``), two adapters, paged pool. Served greedy tokens
     are held against the plain float32 reference (benchmarks/reference/
     mimo_v2.py, which imports nothing of the program) as logits."""
-    import dataclasses
     import tempfile
 
-    import jax
-    import jax.numpy as jnp
     import numpy as np
 
     sys.path.insert(0, os.path.join(REPO, "benchmarks"))
@@ -556,24 +583,7 @@ def child_hybrid(rehearsal: bool) -> int:
         for name in ("", "ad0", "ad1", "ad0"):
             prompt = rng.integers(10, 3000, size=int(rng.integers(40, 160))).tolist()
             work_items.append((prompt, name, eng.submit(prompt, max_new_tokens=24, adapter=name)))
-        mc = dataclasses.asdict(eng.cfg)
-        gaps = []
-        for prompt, name, req in work_items:
-            if not req.done.wait(600) or req.error:
-                verdict(f"hybrid/serve[{name or 'base'}]", False, str(req.error))
-                continue
-            tokens = list(prompt) + list(req.tokens)
-            rows = list(range(len(prompt) - 1, len(tokens) - 1))
-            lora = None
-            if name:
-                e = eng.adapter_ids[name]
-                lora = jax.tree_util.tree_map(lambda a: a[:, e], eng.lora_stack[0]["layers"])
-            ref = reference.sequence_logits(
-                eng.params, mc, tokens, rows, lora,
-                float(eng.lora_stack[1][eng.adapter_ids[name]]) if name else 0.0)
-            got = jnp.take_along_axis(ref, jnp.asarray(req.tokens)[:, None], axis=-1)[:, 0]
-            gaps.append(np.asarray(jnp.max(ref, axis=-1) - got))
-        gaps = np.concatenate(gaps) if gaps else np.asarray([np.inf])
+        gaps = _served_gaps(eng, reference, work_items, verdict, "hybrid")
         # bf16 program against the float32 reference at debug widths: a served
         # token may trail the reference's best by rounding, never by a logit
         verdict("hybrid/served_vs_reference", float(gaps.max()) <= 0.05,
@@ -586,6 +596,87 @@ def child_hybrid(rehearsal: bool) -> int:
     finally:
         eng.close()
         shutil.rmtree(work, ignore_errors=True)
+    return 0 if ok else 1
+
+
+def child_ling(rehearsal: bool) -> int:
+    """Runs IN the chip-holding child: a model whose mixers are linear
+    attention (a recurrent state per slot) and latent attention (one pool of
+    latent rows), with group-limited routing and a shared expert. First
+    ``preset:debug-ling`` with two adapters on ``q_proj`` / ``o_proj``, six
+    requests over three slots so that every slot is used twice; then (not in
+    the CPU rehearsal) the benchmark's configuration at its cell's engine
+    settings for ONE request of two prefill chunks. Served greedy tokens are
+    held against benchmarks/reference/ling_v3.py as logits."""
+    import tempfile
+
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    import spec
+    from reference import ling_v3 as reference
+
+    from datatunerx_tpu.serving.adapters import make_adapter_checkpoint
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+    from datatunerx_tpu.utils import runtime
+
+    runtime.startup("ling")
+    ok = True
+
+    def verdict(name, passed, detail):
+        nonlocal ok
+        ok &= bool(passed)
+        print(f"{'PASS' if passed else 'FAIL'} {name} {detail}", flush=True)
+
+    work = tempfile.mkdtemp(prefix="smoke_ling_")
+    adapters = {f"ad{i}": make_adapter_checkpoint(
+        f"{work}/ad{i}", "preset:debug-ling", seed=20 + i, rank=4,
+        targets=("q_proj", "o_proj")) for i in range(2)}
+    eng = BatchedEngine("preset:debug-ling", adapters=adapters, slots=3, decode_chunk=4,
+                        kv_block_size=8, kv_blocks=96, max_seq_len=256, prefill_chunk=64)
+    try:
+        rng = np.random.default_rng(1)
+        work_items = []
+        for n, name in ((5, ""), (70, "ad0"), (130, "ad1"), (33, "ad0"), (90, ""), (64, "ad1")):
+            prompt = rng.integers(10, 500, size=n).tolist()
+            work_items.append((prompt, name, eng.submit(prompt, max_new_tokens=24, adapter=name)))
+        gaps = _served_gaps(eng, reference, work_items, verdict, "ling")
+        verdict("ling/served_vs_reference", float(gaps.max()) <= 0.05,
+                f"gap_max {gaps.max():.4f} gap_mean {gaps.mean():.5f} tokens {gaps.size}")
+        stats = eng.moe_stats
+        verdict("ling/counters",
+                0 < stats["decode_rows_here"] <= stats["decode_rows"]
+                and stats["decode_local_rows"] >= stats["decode_rows_here"]
+                and eng.state_bytes() == 4 * 3 * (4 * 16 * 16 * 4 + 3 * 192 * 2),
+                json.dumps(dict(stats, state_bytes=eng.state_bytes())))
+    finally:
+        eng.close()
+        shutil.rmtree(work, ignore_errors=True)
+    if rehearsal or not ok:
+        return 0 if ok else 1
+
+    import jax
+
+    cell = spec.load_cell("ling-serve-decode")
+    spec.register_preset(cell)
+    t0 = time.monotonic()
+    eng = BatchedEngine(f"preset:{cell.config_name}", **cell.workload["engine"])
+    try:
+        built = time.monotonic() - t0
+        prompt = rng.integers(10, eng.cfg.vocab_size, size=300).tolist()
+        req = eng.submit(prompt, max_new_tokens=40)
+        gaps = _served_gaps(eng, reference, [(prompt, "", req)], verdict, "ling/cell")
+        peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use", 0)
+        # the cell's own limits (benchmarks/workloads/ling-serve-decode.json)
+        limits = cell.workload["check"]["limits"]
+        verdict("ling/cell/served_vs_reference",
+                float(gaps.max()) <= limits["gap_max"] and float(gaps.mean()) <= limits["gap_mean"],
+                f"gap_max {gaps.max():.4f} gap_mean {gaps.mean():.5f} tokens {gaps.size} "
+                f"engine built in {built:.0f}s, request and reference in "
+                f"{time.monotonic() - t0 - built:.0f}s, state_bytes {eng.state_bytes()} "
+                f"peak_bytes_in_use {peak}")
+    finally:
+        eng.close()
     return 0 if ok else 1
 
 
@@ -987,8 +1078,8 @@ def main(argv=None) -> int:
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="debug-size run on the CPU to debug THIS SCRIPT; "
                          "proves nothing about the chip")
-    ap.add_argument("--phases", default="trainer,server,kernels,hybrid",
-                    help="comma list out of trainer,server,kernels,hybrid")
+    ap.add_argument("--phases", default="trainer,server,kernels,hybrid,ling",
+                    help="comma list out of trainer,server,kernels,hybrid,ling")
     ap.add_argument("--mesh", action="append", default=None,
                     help="trainer --mesh (e.g. dp=1,fsdp=4,tp=1); repeat to "
                          "run the trainer once per mesh. 'auto' (the "
@@ -997,6 +1088,8 @@ def main(argv=None) -> int:
     ap.add_argument("--child-kernels", action="store_true",
                     help=argparse.SUPPRESS)
     ap.add_argument("--child-hybrid", action="store_true",
+                    help=argparse.SUPPRESS)
+    ap.add_argument("--child-ling", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
@@ -1010,12 +1103,14 @@ def main(argv=None) -> int:
         return child_kernels(args.cpu_rehearsal)
     if args.child_hybrid:
         return child_hybrid(args.cpu_rehearsal)
+    if args.child_ling:
+        return child_ling(args.cpu_rehearsal)
     if not os.path.isdir(os.path.join(REPO, "datatunerx_tpu")):
         print("chip_smoke: no datatunerx_tpu package beside this script",
               file=sys.stderr)
         return 2
     phases = [p.strip() for p in args.phases.split(",") if p.strip()]
-    unknown = set(phases) - {"trainer", "server", "kernels", "hybrid"}
+    unknown = set(phases) - {"trainer", "server", "kernels", "hybrid", "ling"}
     if unknown:
         ap.error(f"unknown phases {sorted(unknown)}")
 
@@ -1047,9 +1142,9 @@ def main(argv=None) -> int:
         runs.append(("server", lambda left: phase_server(rehearsal, left)))
     if "kernels" in phases:
         runs.append(("kernels", lambda left: phase_kernels(rehearsal, left)))
-    if "hybrid" in phases:
-        runs.append(("hybrid",
-                     lambda left: phase_kernels(rehearsal, left, "hybrid")))
+    for name in ("hybrid", "ling"):
+        if name in phases:
+            runs.append((name, lambda left, name=name: phase_kernels(rehearsal, left, name)))
 
     devices, failed = [], []
     try:

@@ -54,11 +54,12 @@ class ModelConfig:
     # ---- layers of different kinds in one model (models/hybrid.py). Every
     # default is today's single kind: all layers attend alike (the fields
     # above) and feed forward through one dense SwiGLU. ``layer_types`` names
-    # each layer's attention ("global" | "window") and ``ffn_types`` its
-    # feed-forward ("dense" | "experts"); a model that sets either is run by
-    # runs of like layers (``layer_runs``), each stacked and scanned. The
-    # fields above keep their published meaning for such a model:
-    # num_kv_heads/rope_theta are the global layers', sliding_window the
+    # each layer's mixer ("global" | "window" softmax attention, "mla" latent
+    # attention, "kda" linear attention) and ``ffn_types`` its feed-forward
+    # ("dense" | "experts"); a model that sets either is run by runs of like
+    # layers (``layer_runs``), each stacked and scanned. The fields above keep
+    # their published meaning for such a model: num_kv_heads/rope_theta are
+    # the global layers' (rope_theta the MLA layers' too), sliding_window the
     # window layers' window.
     layer_types: Optional[tuple] = None
     ffn_types: Optional[tuple] = None
@@ -77,6 +78,24 @@ class ModelConfig:
     expert_intermediate_size: int = 0
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # group-limited routing: the experts lie in ``n_group`` groups of equal
+    # size, of which the ``topk_group`` with the best two-expert score are kept
+    n_group: int = 1
+    topk_group: int = 1
+    # one shared expert of this width beside the routed ones (0: none)
+    shared_expert_intermediate_size: int = 0
+    # the published per-layer clamp of an expert's SwiGLU is not implemented:
+    # a model whose held layers carry a non-zero limit is refused, not served
+    # without it (``expert_swiglu_limits``: one number per held layer)
+    expert_swiglu_limits: Optional[tuple] = None
+    # "mla" layers: q uncompressed [nope | rope], k and v from one latent row
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    # "kda" layers: heads of head_dim (keys) x v_head_dim (values), a short
+    # causal convolution before them, a decay gate bounded below
+    kda_conv_kernel: int = 4
+    kda_lower_bound: float = -5.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -84,7 +103,7 @@ class ModelConfig:
         assert self.num_heads % self.num_kv_heads == 0
         if self.rope_scaling_type is not None:
             assert self.rope_scaling_type in ("linear", "dynamic"), self.rope_scaling_type
-        for name in ("layer_types", "ffn_types"):
+        for name in ("layer_types", "ffn_types", "expert_swiglu_limits"):
             value = getattr(self, name)
             if value is not None:  # a list from JSON: keep the config hashable
                 assert len(value) == self.num_layers, (name, len(value), self.num_layers)
@@ -94,6 +113,13 @@ class ModelConfig:
             assert 0 <= self.first_held <= self.experts_total - self.experts_held
             assert 0 < self.experts_per_token <= self.experts_total
             assert self.expert_intermediate_size > 0
+            assert self.experts_total % self.n_group == 0
+            assert 0 < self.topk_group <= self.n_group
+        if self.expert_swiglu_limits and any(self.expert_swiglu_limits):
+            raise NotImplementedError(
+                f"model {self.name!r}: a held layer carries a non-zero SwiGLU "
+                f"limit {self.expert_swiglu_limits}; the clamp is not "
+                "implemented, and serving without it would be another model")
 
     @property
     def hybrid(self) -> bool:
@@ -111,7 +137,7 @@ class ModelConfig:
 
 @dataclasses.dataclass(frozen=True)
 class AttentionKind:
-    """One kind of attention layer: what it projects, rotates, caches, sees."""
+    """One kind of softmax-attention layer: what it projects, rotates, caches, sees."""
     name: str  # "global" | "window": also names its KV pool (k_<name>, v_<name>)
     num_kv_heads: int
     head_dim: int  # q and k
@@ -122,20 +148,76 @@ class AttentionKind:
     sink: bool
     value_scale: float
 
+    def pools(self) -> dict:
+        """{cache leaf: row width} of the rows this kind caches per token."""
+        return {f"k_{self.name}": self.num_kv_heads * self.head_dim,
+                f"v_{self.name}": self.num_kv_heads * self.v_head_dim}
+
+    def states(self, cfg) -> dict:
+        return {}
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaKind:
+    """Latent attention: one cached row per token, ``[c kv_lora_rank | kR
+    rope_dim]``, shared by every head; no v pool (ops/mla.py)."""
+    kv_lora_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_head_dim: int
+    rope_theta: float
+    name: str = "mla"
+    window = None
+
+    @property
+    def rotary_dim(self) -> int:
+        return self.rope_dim
+
+    def pools(self) -> dict:
+        return {"k_mla": self.kv_lora_rank + self.rope_dim}
+
+    def states(self, cfg) -> dict:
+        return {}
+
+
+@dataclasses.dataclass(frozen=True)
+class KdaKind:
+    """Linear attention: no rows, a state of constant size per slot (ops/kda.py)."""
+    head_dim: int  # q, k and the decay, per head
+    v_head_dim: int
+    conv_kernel: int
+    lower_bound: float
+    name: str = "kda"
+    window = None
+
+    def pools(self) -> dict:
+        return {}
+
+    def states(self, cfg) -> dict:
+        """{cache leaf: (shape per slot, dtype name | None for the pools'
+        dtype)}: the float32 memory matrix per head, and the last
+        pre-convolution rows of q, k and v in the type they are computed in."""
+        H = cfg.num_heads
+        return {"state_kda": ((H, self.head_dim, self.v_head_dim), "float32"),
+                "state_kda_conv": ((self.conv_kernel - 1,
+                                    H * (2 * self.head_dim + self.v_head_dim)),
+                                   None)}
+
 
 @dataclasses.dataclass(frozen=True)
 class LayerRun:
     """``count`` consecutive layers of one kind, stacked and scanned together.
-    ``kind_start`` is where they lie among the layers of their attention kind
-    (the layer axis of that kind's KV pool)."""
-    attn: AttentionKind
+    ``mixer`` is what mixes tokens in them (an ``AttentionKind``, ``MlaKind``
+    or ``KdaKind``); ``kind_start`` is where they lie among the layers of
+    their mixer kind (the layer axis of that kind's cache leaves)."""
+    mixer: object
     ffn: str  # "dense" | "experts"
     count: int
     kind_start: int
 
 
-def attention_kinds(cfg: ModelConfig) -> dict:
-    """{name: AttentionKind} of the kinds this model has, global first."""
+def mixer_kinds(cfg: ModelConfig) -> dict:
+    """{name: kind} of the mixers this model has, softmax attention first."""
     types = cfg.layer_types or (
         ("window" if cfg.sliding_window else "global",) * cfg.num_layers)
     rotary = int(cfg.head_dim * cfg.partial_rotary_factor)  # dtxlint: disable=DTX001 — config scalars, host only
@@ -151,13 +233,24 @@ def attention_kinds(cfg: ModelConfig) -> dict:
             name="window", num_kv_heads=cfg.window_num_kv_heads or cfg.num_kv_heads,
             rope_theta=cfg.window_rope_theta or cfg.rope_theta,
             window=cfg.sliding_window, sink=cfg.window_sink, **common)
+    if "mla" in types:
+        assert cfg.kv_lora_rank > 0 and cfg.qk_nope_head_dim > 0
+        assert cfg.qk_rope_head_dim > 0 and cfg.qk_rope_head_dim % 2 == 0
+        kinds["mla"] = MlaKind(
+            kv_lora_rank=cfg.kv_lora_rank, nope_dim=cfg.qk_nope_head_dim,
+            rope_dim=cfg.qk_rope_head_dim, rope_theta=cfg.rope_theta,
+            v_head_dim=cfg.v_head_dim or cfg.head_dim)
+    if "kda" in types:
+        kinds["kda"] = KdaKind(
+            head_dim=cfg.head_dim, v_head_dim=cfg.v_head_dim or cfg.head_dim,
+            conv_kernel=cfg.kda_conv_kernel, lower_bound=cfg.kda_lower_bound)
     return kinds
 
 
 def layer_runs(cfg: ModelConfig) -> tuple:
     """The model as runs of like layers, in order: the one description that
     ``forward``, the cache constructors, the adapters and the estimators read."""
-    kinds = attention_kinds(cfg)
+    kinds = mixer_kinds(cfg)
     types = cfg.layer_types or (next(iter(kinds)),) * cfg.num_layers
     ffns = cfg.ffn_types or ("dense",) * cfg.num_layers
     runs, seen = [], {name: 0 for name in kinds}
@@ -165,21 +258,26 @@ def layer_runs(cfg: ModelConfig) -> tuple:
         if t not in kinds or f not in ("dense", "experts"):
             raise ValueError(f"layer {i}: unknown kind {t!r}/{f!r}")
         last = runs[-1] if runs else None
-        if last is not None and last.attn.name == t and last.ffn == f:
+        if last is not None and last.mixer.name == t and last.ffn == f:
             runs[-1] = dataclasses.replace(last, count=last.count + 1)
         else:
-            runs.append(LayerRun(attn=kinds[t], ffn=f, count=1,
+            runs.append(LayerRun(mixer=kinds[t], ffn=f, count=1,
                                  kind_start=seen[t]))
         seen[t] += 1
     return tuple(runs)
 
 
 def kind_layers(cfg: ModelConfig) -> dict:
-    """{attention kind name: how many layers are of it} (a KV pool's layer axis)."""
+    """{mixer kind name: how many layers are of it} (its cache leaves' layer axis)."""
     out = {}
     for run in layer_runs(cfg):
-        out[run.attn.name] = out.get(run.attn.name, 0) + run.count
+        out[run.mixer.name] = out.get(run.mixer.name, 0) + run.count
     return out
+
+
+def has_recurrent_state(cfg: ModelConfig) -> bool:
+    """Some layer keeps state of constant size per slot instead of rows."""
+    return any(kind.states(cfg) for kind in mixer_kinds(cfg).values())
 
 
 def refuse_hybrid(cfg: ModelConfig, what: str) -> None:
@@ -190,6 +288,16 @@ def refuse_hybrid(cfg: ModelConfig, what: str) -> None:
             f"model {cfg.name!r} has layers of several kinds (window and global "
             f"attention, sparse experts): it is served by the batched engine, "
             f"and {what} does not handle it yet")
+
+
+def refuse_recurrent_state(cfg: ModelConfig, what: str) -> None:
+    """One clear message from every entry that would have to SNAPSHOT a slot's
+    recurrent state (rows can be trimmed at a cursor; a state cannot)."""
+    if has_recurrent_state(cfg):
+        raise NotImplementedError(
+            f"model {cfg.name!r} has linear-attention layers whose per-slot "
+            f"recurrent state cannot be rewound to an earlier cursor: {what} "
+            f"needs snapshots of that state and does not handle it yet")
 
 
 PRESETS = {
@@ -241,6 +349,20 @@ PRESETS = {
         window_num_kv_heads=2, window_rope_theta=1e4, window_sink=True,
         experts_total=8, experts_held=4, first_held=0, experts_per_token=2,
         expert_intermediate_size=32,
+    ),
+    # Debug size of a model whose mixers are linear attention (KDA: a
+    # recurrent state per slot, no rows) and latent attention (MLA: one pool,
+    # no v pool), with group-limited routing and a shared expert.
+    "debug-ling": ModelConfig(
+        name="debug-ling", vocab_size=512, hidden_size=64, intermediate_size=128,
+        num_layers=5, num_heads=4, num_kv_heads=4, head_dim=16, v_head_dim=16,
+        max_seq_len=512, rope_theta=6e6, rms_norm_eps=1e-6,
+        layer_types=("kda", "kda", "kda", "mla", "kda"),
+        ffn_types=("dense", "experts", "experts", "experts", "experts"),
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        experts_total=16, experts_held=4, first_held=0, experts_per_token=2,
+        expert_intermediate_size=32, n_group=4, topk_group=2,
+        shared_expert_intermediate_size=32, routed_scaling_factor=2.5,
     ),
     "qwen1.5-7b": ModelConfig(
         name="qwen1.5-7b", vocab_size=151936, hidden_size=4096,

@@ -1,6 +1,6 @@
-"""A decoder whose layers are of several kinds: window and global attention
-layers with KV geometry of their own in one stack, dense and sparse-expert
-feed-forward layers.
+"""A decoder whose layers are of several kinds: window and global softmax
+attention with KV geometry of their own, latent attention (MLA) and linear
+attention (KDA) in one stack, dense and sparse-expert feed-forward layers.
 
 ``models/config.py:layer_runs`` describes the model as runs of like layers;
 this module stacks each run's parameters ``[n, ...]`` and scans it, so the
@@ -19,21 +19,43 @@ Every layer is a pre-norm residual block (RMSNorm, no bias anywhere):
   window`` (ops/attention.py:attention_allow), has its own number of KV heads
   and its own RoPE theta, and a learned sink logit per head
   (``attention_sink_bias``).
-- dense feed-forward: SwiGLU; expert feed-forward: ops/moe.py.
+- latent attention (``mla``, ops/mla.py): q heads ``[nope | rope]`` straight
+  from x; ``kv_a_proj`` gives a latent row ``c`` (RMS-normed) and one rotated
+  key ``kR`` for all heads (interleaved RoPE on it and on q's rope lanes);
+  ``[c | kR]`` is the cached row; attention runs absorbed over those rows,
+  scaled by ``(nope + rope) ** -0.5``; a sigmoid gate per head on the output.
+- linear attention (``kda``, ops/kda.py): q, k, v through a short causal
+  convolution and SiLU, q and k L2-normalised per head, no RoPE; a decay per
+  key channel from ``f_proj`` and a write strength per head from ``b_proj``
+  drive the gated delta rule on a float32 state per head; the read-out is
+  RMS-normed per head and gated channel-wise (``g_proj``).
+- dense feed-forward: SwiGLU; expert feed-forward: ops/moe.py, plus one
+  shared expert (SwiGLU over every row) where the model has one.
 
 Param tree (HF leaf names):
   embed_tokens.embedding [V, D];  norm.scale [D];  lm_head.kernel [D, V]
   layers.run<i>.{input_layernorm,post_attention_layernorm}.scale [n, D]
   layers.run<i>.{q,k,v,o}_proj.kernel [n, in, out]
   layers.run<i>.attention_sink_bias [n, H]                (kinds with a sink)
+  layers.run<i>.{q,kv_a,kv_b,g,o}_proj.kernel, kv_a_layernorm.scale   (mla runs)
+  layers.run<i>.{q,k,v,f,b,g,o}_proj.kernel, conv.kernel [n, C, K],
+      A_log [n, H], dt_bias [n, H*d], o_norm.scale [n, d_v]            (kda runs)
   layers.run<i>.{gate,up,down}_proj.kernel                (dense runs)
   layers.run<i>.router.kernel [n, D, E_total]             (expert runs)
   layers.run<i>.e_score_correction_bias [n, E_total]
   layers.run<i>.experts.{gate,up,down}_proj [n, E_held, in, out]
+  layers.run<i>.shared_expert.{gate,up,down}_proj.kernel  (models with one)
 A LoRA tree mirrors it: ``layers.run<i>.<target>.{a,b}``.
 
-Cache: one ``k_<kind>``/``v_<kind>`` pool per attention kind, with that
-kind's head count and the two widths, ``[layers of the kind, blocks | rows,
+Cache, two kinds of leaf in one dict (ops/paged_attention.py tells them apart:
+``kv_leaf_keys`` / ``state_leaf_keys``). POOLS hold rows and are moved by
+block table: one ``k_<kind>``/``v_<kind>`` pair per softmax-attention kind,
+one ``k_mla`` (no v pool) for latent attention. STATE leaves hold what a
+linear-attention layer remembers, of constant size per slot, ``[layers of the
+kind, slots, ...]``, moved by slot: ``state_kda`` (float32 ``[.., H, d_k,
+d_v]``) and ``state_kda_conv`` (the last pre-convolution rows). A slot whose
+cursor is 0 reads its state as zero, so admission resets nothing. A pool has
+its kind's head count and the two widths, ``[layers of the kind, blocks | rows,
 offset | lane, KV * width]``: laid out as the single-kind cache is but for the
 last axis, where heads and width are one (a 192-wide head pads to 256 lanes on
 the TPU, whose runtime then stores the pool in a layout of its own choosing
@@ -49,6 +71,7 @@ differences.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -56,11 +79,11 @@ import jax.numpy as jnp
 
 from datatunerx_tpu.models.config import (
     ModelConfig,
-    attention_kinds,
     kind_layers,
     layer_runs,
+    mixer_kinds,
 )
-from datatunerx_tpu.ops import moe
+from datatunerx_tpu.ops import kda, mla, moe
 from datatunerx_tpu.ops.attention import (
     KVStep,
     cache_positions_update,
@@ -76,12 +99,45 @@ def run_key(i: int) -> str:
 
 
 def attn_dims(cfg: ModelConfig, kind) -> dict:
-    """in/out widths of one attention kind's projections."""
-    D = cfg.hidden_size
-    return {"q_proj": (D, cfg.num_heads * kind.head_dim),
+    """in/out widths of the adaptable projections of one mixer kind (a LoRA
+    target exists in a run iff its name is here)."""
+    D, H = cfg.hidden_size, cfg.num_heads
+    if kind.name == "mla":
+        return {"q_proj": (D, H * (kind.nope_dim + kind.rope_dim)),
+                "o_proj": (H * kind.v_head_dim, D)}
+    if kind.name == "kda":
+        return {"q_proj": (D, H * kind.head_dim),
+                "k_proj": (D, H * kind.head_dim),
+                "v_proj": (D, H * kind.v_head_dim),
+                "o_proj": (H * kind.v_head_dim, D)}
+    return {"q_proj": (D, H * kind.head_dim),
             "k_proj": (D, kind.num_kv_heads * kind.head_dim),
             "v_proj": (D, kind.num_kv_heads * kind.v_head_dim),
-            "o_proj": (cfg.num_heads * kind.v_head_dim, D)}
+            "o_proj": (H * kind.v_head_dim, D)}
+
+
+def mixer_shapes(cfg: ModelConfig, kind) -> dict:
+    """{leaf path: shape of one layer} of a mixer's parameters."""
+    D, H = cfg.hidden_size, cfg.num_heads
+    out = {(name, "kernel"): dims for name, dims in attn_dims(cfg, kind).items()}
+    if kind.name == "mla":
+        out[("kv_a_proj", "kernel")] = (D, kind.kv_lora_rank + kind.rope_dim)
+        out[("kv_a_layernorm", "scale")] = (kind.kv_lora_rank,)
+        out[("kv_b_proj", "kernel")] = (
+            kind.kv_lora_rank, H * (kind.nope_dim + kind.v_head_dim))
+        out[("g_proj", "kernel")] = (D, H)
+    elif kind.name == "kda":
+        C = H * (2 * kind.head_dim + kind.v_head_dim)
+        out[("conv", "kernel")] = (C, kind.conv_kernel)
+        out[("f_proj", "kernel")] = (D, H * kind.head_dim)
+        out[("b_proj", "kernel")] = (D, H)
+        out[("g_proj", "kernel")] = (D, H * kind.v_head_dim)
+        out[("A_log",)] = (H,)
+        out[("dt_bias",)] = (H * kind.head_dim,)
+        out[("o_norm", "scale")] = (kind.v_head_dim,)
+    elif kind.sink:
+        out[("attention_sink_bias",)] = (H,)
+    return out
 
 
 def run_shapes(cfg: ModelConfig, run) -> dict:
@@ -89,10 +145,8 @@ def run_shapes(cfg: ModelConfig, run) -> dict:
     D, n = cfg.hidden_size, run.count
     out = {("input_layernorm", "scale"): (n, D),
            ("post_attention_layernorm", "scale"): (n, D)}
-    for name, (d_in, d_out) in attn_dims(cfg, run.attn).items():
-        out[(name, "kernel")] = (n, d_in, d_out)
-    if run.attn.sink:
-        out[("attention_sink_bias",)] = (n, cfg.num_heads)
+    for path, shape in mixer_shapes(cfg, run.mixer).items():
+        out[path] = (n,) + shape
     if run.ffn == "dense":
         F = cfg.intermediate_size
         out[("gate_proj", "kernel")] = (n, D, F)
@@ -105,6 +159,11 @@ def run_shapes(cfg: ModelConfig, run) -> dict:
         out[("experts", "gate_proj")] = (n, Eh, D, F)
         out[("experts", "up_proj")] = (n, Eh, D, F)
         out[("experts", "down_proj")] = (n, Eh, F, D)
+        Fs = cfg.shared_expert_intermediate_size
+        if Fs:
+            out[("shared_expert", "gate_proj", "kernel")] = (n, D, Fs)
+            out[("shared_expert", "up_proj", "kernel")] = (n, D, Fs)
+            out[("shared_expert", "down_proj", "kernel")] = (n, Fs, D)
     return out
 
 
@@ -114,54 +173,100 @@ def _set(tree: dict, path: tuple, value) -> None:
     tree[path[-1]] = value
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+_DRAW = 1 << 19  # elements a single draw makes
+_TOGETHER = 1 << 26  # a layer's leaves up to this size share one draw
+_CONSTANT = {"scale": 1.0, "A_log": 0.0, "dt_bias": -3.0}  # dt_bias: a decay near exp(-0.24) a token
+
+
+def _scale_of(path) -> float:
+    if path[-1] == "e_score_correction_bias":
+        return 0.1  # a preset's init; benchmark cells draw their own
+    return 1.0 if path[-1] == "attention_sink_bias" else 0.02
+
+
 def _normal(key, shape, scale, dtype):
-    # one fused program a leaf: no float32 copy of a stacked leaf stays alive
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+    """A large leaf is drawn in slices of at most ``_DRAW`` elements under a
+    loop: no float32 copy of a stacked leaf is ever alive, and the TPU's
+    compiler takes ten seconds over one draw of gigabytes where a slice's
+    takes half of one."""
+    rows, last = math.prod(shape[:-1]), shape[-1]
+    r = max(1, min(rows, _DRAW // last))
+    while rows % r:
+        r -= 1
+
+    def one(k):
+        return (jax.random.normal(k, (r, last), jnp.float32) * scale).astype(dtype)
+
+    if r == rows:
+        return one(key).reshape(shape)
+    return jax.lax.map(one, jax.random.split(key, rows // r)).reshape(shape)
+
+
+def _draw_together(key, shapes: dict, dtype) -> dict:
+    """{path: normal(0, _scale_of(path)) of shape} for the smaller leaves of
+    ONE layer, cut from one flat float32 draw: a generator costs the TPU's
+    compiler about a second wherever it stands, and a layer has twenty leaves."""
+    total = sum(math.prod(shape) for shape in shapes.values())
+    z = _normal(key, (-(-total // _DRAW), _DRAW), 1.0, jnp.float32).reshape(-1)
+    out, at = {}, 0
+    for path, shape in shapes.items():
+        n = math.prod(shape)
+        # the barrier keeps XLA from reshaping the WHOLE vector to a leaf's
+        # last dimension first (lanes pad a width of 4 thirty-two times over)
+        cut = jax.lax.optimization_barrier(z[at:at + n])
+        out[path] = (cut.reshape(shape) * _scale_of(path)).astype(dtype)
+        at += n
+    return out
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32):
+    return _init_params(cfg, key, jnp.dtype(dtype))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _init_params(cfg: ModelConfig, key: jax.Array, dtype):
+    # ONE program for the whole tree, and in it a dozen generators: an engine
+    # on a preset compiled a program a leaf, some forty, before
     D, V = cfg.hidden_size, cfg.vocab_size
-    dtype = jnp.dtype(dtype)
-
-    def dense(k, shape, scale=0.02):
-        return _normal(k, tuple(shape), scale, dtype)
-
     k_emb, k_head, k_layers = jax.random.split(key, 3)
     layers = {}
     for i, run in enumerate(layer_runs(cfg)):
+        k_run = jax.random.fold_in(k_layers, i)
+        shapes = dict(sorted(run_shapes(cfg, run).items()))
+        small = {path: shape[1:] for path, shape in shapes.items()
+                 if path[-1] not in _CONSTANT and math.prod(shape[1:]) <= _TOGETHER}
+        together = jax.lax.map(lambda k, small=small: _draw_together(k, small, dtype),
+                               jax.random.split(k_run, run.count))
         tree: dict = {}
-        for j, (path, shape) in enumerate(sorted(run_shapes(cfg, run).items())):
-            k = jax.random.fold_in(jax.random.fold_in(k_layers, i), j)
-            if path[-1] == "scale":
-                leaf = jnp.ones(shape, dtype)
-            elif path[-1] == "e_score_correction_bias":
-                leaf = dense(k, shape, 0.1)  # a preset's init; benchmark cells draw their own
-            elif path[-1] == "attention_sink_bias":
-                leaf = dense(k, shape, 1.0)
-            else:
-                leaf = dense(k, shape)
+        for j, (path, shape) in enumerate(shapes.items()):
+            if path in together:
+                leaf = together[path]
+            elif path[-1] in _CONSTANT:
+                leaf = jnp.full(shape, _CONSTANT[path[-1]], dtype)
+            else:  # a layer's experts: gigabytes, drawn on their own
+                leaf = _normal(jax.random.fold_in(k_run, j), shape, _scale_of(path), dtype)
             _set(tree, path, leaf)
         layers[run_key(i)] = tree
-    params = {"embed_tokens": {"embedding": dense(k_emb, (V, D))},
+    params = {"embed_tokens": {"embedding": _normal(k_emb, (V, D), 0.02, dtype)},
               "layers": layers, "norm": {"scale": jnp.ones((D,), dtype)}}
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = {"kernel": dense(k_head, (D, V))}
+        params["lm_head"] = {"kernel": _normal(k_head, (D, V), 0.02, dtype)}
     return params
 
 
 # ------------------------------------------------------------------- caches
 
-def _pools(cfg: ModelConfig, lead: tuple, dtype) -> dict:
-    """One k/v pool per attention kind: ``[layers of the kind, *lead, KV * width]``."""
+def _leaves(cfg: ModelConfig, lead: tuple, slots: int, dtype) -> dict:
+    """The cache's leaves: per mixer kind its pools ``[layers of the kind,
+    *lead, row width]`` and its state leaves ``[layers of the kind, slots,
+    ...]``; the experts' counters."""
     out = {}
     layers = kind_layers(cfg)
-    for name, kind in attention_kinds(cfg).items():
-        shape = (layers[name],) + lead
-        out[f"k_{name}"] = jnp.zeros(
-            shape + (kind.num_kv_heads * kind.head_dim,), dtype)
-        out[f"v_{name}"] = jnp.zeros(
-            shape + (kind.num_kv_heads * kind.v_head_dim,), dtype)
+    for name, kind in mixer_kinds(cfg).items():
+        for key, width in kind.pools().items():
+            out[key] = jnp.zeros((layers[name],) + lead + (width,), dtype)
+        for key, (shape, leaf_dtype) in kind.states(cfg).items():
+            out[key] = jnp.zeros((layers[name], slots) + shape, leaf_dtype or dtype)
     if cfg.ffn_types is not None and "experts" in cfg.ffn_types:
         out["moe_stats"] = jnp.zeros((2, moe.N_STATS), jnp.int32)
     return out
@@ -170,7 +275,7 @@ def _pools(cfg: ModelConfig, lead: tuple, dtype) -> dict:
 def _no_quant(cfg: ModelConfig, quantize) -> None:
     if quantize:
         raise NotImplementedError(
-            f"model {cfg.name!r} has a KV pool per attention kind; the int8 "
+            f"model {cfg.name!r} has a cache leaf per mixer kind; the int8 "
             "cache (kv_quant) does not handle that yet")
 
 
@@ -180,7 +285,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
     cache = {"len": (jnp.zeros((batch,), jnp.int32) if per_slot
                      else jnp.zeros((), jnp.int32)),
              "pos": jnp.full((batch, max_len), POS_SENTINEL, jnp.int32)}
-    cache.update(_pools(cfg, (batch, max_len), dtype))
+    cache.update(_leaves(cfg, (batch, max_len), batch, dtype))
     return cache
 
 
@@ -191,7 +296,7 @@ def init_paged_cache(cfg: ModelConfig, slots: int, num_blocks: int,
     cache = {"len": jnp.zeros((slots,), jnp.int32),
              "pos": jnp.full((num_blocks, block_size), POS_SENTINEL, jnp.int32),
              "block_tables": jnp.full((slots, blocks_per_slot), -1, jnp.int32)}
-    cache.update(_pools(cfg, (num_blocks, block_size), dtype))
+    cache.update(_leaves(cfg, (num_blocks, block_size), slots, dtype))
     return cache
 
 
@@ -241,21 +346,25 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
     if compute_dtype is not None:
         x = x.astype(compute_dtype)
 
-    kinds = attention_kinds(cfg)
+    kinds = mixer_kinds(cfg)
+    attending = {name: kind for name, kind in kinds.items() if kind.pools()}
     rope = {name: rope_cos_sin(positions, kind.rotary_dim, theta=kind.rope_theta)
-            for name, kind in kinds.items()}
+            for name, kind in attending.items()}
     valid = attention_mask.astype(bool) if attention_mask is not None else None
     views, bias, cache_pos = {}, {}, None
     if cache is None:
-        for name, kind in kinds.items():
+        for name, kind in attending.items():
             bias[name] = make_causal_bias(positions, positions, valid,
                                           sliding_window=kind.window)
     else:
         cache_pos, kv_pos_full = cache_positions_update(
             cache, positions, attention_mask)
-        for name, kind in kinds.items():
+        for name, kind in attending.items():
             views[name] = _View(cache, kind, positions, kv_pos_full, cache_pos, T)
             bias[name] = views[name].bias
+    # a slot at cursor 0 starts from nothing, whatever its state leaves hold
+    fresh = (jnp.broadcast_to(cache["len"] == 0, (B,))
+             if cache is not None and "kda" in kinds else None)
 
     lora_layers, lora_scale = (None, 0.0)
     if lora is not None:
@@ -264,13 +373,114 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
     D, H = cfg.hidden_size, cfg.num_heads
     rows_valid = valid.reshape(B * T) if valid is not None else None
 
-    def make_block(run, experts):
-        kind = run.attn
+    def attention_mixer(kind, h, lp, proj, leaves, li):
         cos, sin = rope[kind.name]
         view = views.get(kind.name)
+        pool_k, pool_v = leaves
+        with jax.named_scope("dtx.qkv"):
+            q = proj(h, "q_proj").reshape(B, T, H, kind.head_dim)
+            k = proj(h, "k_proj").reshape(B, T, kind.num_kv_heads, kind.head_dim)
+            v = proj(h, "v_proj").reshape(B, T, kind.num_kv_heads, kind.v_head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            if kind.value_scale != 1.0:
+                v = v * jnp.asarray(kind.value_scale, v.dtype)
+        if view is not None:
+            with jax.named_scope("dtx.kv_write"):
+                pool_k, k_att = view.update(pool_k, li, k)
+                pool_v, v_att = view.update(pool_v, li, v)
+                k_att, v_att = k_att.astype(k.dtype), v_att.astype(v.dtype)
+        else:
+            k_att, v_att = k, v
+        with jax.named_scope("dtx.attn"):
+            attn = xla_attention(
+                q, k_att, v_att, bias[kind.name],
+                sink=lp["attention_sink_bias"] if kind.sink else None)
+        return attn.reshape(B, T, H * kind.v_head_dim), (pool_k, pool_v)
+
+    def mla_mixer(kind, h, lp, proj, leaves, li):
+        cos, sin = rope["mla"]
+        view = views.get("mla")
+        rank = kind.kv_lora_rank
+        with jax.named_scope("dtx.qkv"):
+            q = proj(h, "q_proj").reshape(B, T, H, kind.nope_dim + kind.rope_dim)
+            q_nope = q[..., :kind.nope_dim]
+            q_rope = mla.rope_interleaved(q[..., kind.nope_dim:], cos, sin)
+            row = _proj(h, lp["kv_a_proj"], None, 0.0)
+            c = rms_norm(row[..., :rank], lp["kv_a_layernorm"]["scale"],
+                         cfg.rms_norm_eps)
+            k_rope = mla.rope_interleaved(row[..., None, rank:], cos, sin)[:, :, 0]
+            row = jnp.concatenate([c, k_rope], axis=-1)
+            gate = jax.nn.sigmoid(
+                _proj(h, lp["g_proj"], None, 0.0).astype(jnp.float32))
+        if view is not None:
+            with jax.named_scope("dtx.kv_write"):
+                pool, rows = view.update(leaves[0], li, row[:, :, None, :])
+                rows, leaves = rows.astype(row.dtype), (pool,)
+        else:
+            rows = row[:, :, None, :]
+        wkb, wvb = mla.split_kv_b(lp["kv_b_proj"]["kernel"], H, kind.nope_dim)
+        with jax.named_scope("dtx.mla_absorb"):
+            q_lat = jnp.concatenate([mla.absorb_query(q_nope, wkb), q_rope], axis=-1)
+        with jax.named_scope("dtx.attn"):
+            o_lat = xla_attention(
+                q_lat, rows, rows[..., :rank], bias["mla"],
+                scale=(kind.nope_dim + kind.rope_dim) ** -0.5)
+        with jax.named_scope("dtx.mla_absorb"):
+            o = mla.expand_value(o_lat, wvb)
+            o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
+        return o.reshape(B, T, H * kind.v_head_dim), leaves
+
+    def kda_mixer(kind, h, lp, proj, leaves, li):
+        d, dv = kind.head_dim, kind.v_head_dim
+        with jax.named_scope("dtx.qkv"):
+            qkv = jnp.concatenate(
+                [proj(h, name) for name in ("q_proj", "k_proj", "v_proj")], axis=-1)
+            f = _proj(h, lp["f_proj"], None, 0.0).reshape(B, T, H, d)
+            beta = jax.nn.sigmoid(
+                _proj(h, lp["b_proj"], None, 0.0).astype(jnp.float32))
+            out_gate = jax.nn.sigmoid(
+                _proj(h, lp["g_proj"], None, 0.0).astype(jnp.float32))
+        with jax.named_scope("dtx.kda_conv"):
+            conv_state = None
+            if leaves:
+                conv_state = jnp.where(fresh[:, None, None], 0, leaves[1][li])
+            y, conv_state = kda.short_conv(qkv, lp["conv"]["kernel"], conv_state, valid)
+            q = kda.l2_normalize(y[..., :H * d].reshape(B, T, H, d)) * d ** -0.5
+            k = kda.l2_normalize(y[..., H * d:2 * H * d].reshape(B, T, H, d))
+            v = y[..., 2 * H * d:].reshape(B, T, H, dv)
+            g = kda.gate(f, lp["A_log"], lp["dt_bias"].reshape(H, d), kind.lower_bound)
+            if valid is not None:  # a pad moves nothing
+                g = jnp.where(valid[:, :, None, None], g, 0.0)
+                beta = jnp.where(valid[:, :, None], beta, 0.0)
+        with jax.named_scope("dtx.kda_state"):
+            if leaves:
+                state = jnp.where(fresh[:, None, None, None], 0.0, leaves[0][li])
+            else:
+                state = jnp.zeros((B, H, d, dv), jnp.float32)
+            if T == 1:
+                o, state = kda.state_step(
+                    state, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                o = o[:, None]
+            else:
+                o, state = kda.chunk_states(state, q, k, v, g, beta)
+            if leaves:
+                leaves = (leaves[0].at[li].set(state),
+                          leaves[1].at[li].set(conv_state.astype(leaves[1].dtype)))
+        with jax.named_scope("dtx.kda_out"):
+            o = rms_norm(o, lp["o_norm"]["scale"], cfg.rms_norm_eps)
+            o = (o.astype(jnp.float32)
+                 * out_gate.reshape(B, T, H, dv)).astype(h.dtype)
+        return o.reshape(B, T, H * dv), leaves
+
+    mixers = {"mla": mla_mixer, "kda": kda_mixer}
+
+    def make_block(run, experts):
+        kind = run.mixer
+        mixer = mixers.get(kind.name, attention_mixer)
 
         def block(carry, scanned):
-            x, pool_k, pool_v, stats = carry
+            x, leaves, stats = carry
             lp, ll, li, lr = scanned
             lget = (lambda name: ll.get(name)) if ll else (lambda name: None)
 
@@ -280,26 +490,9 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
 
             with jax.named_scope("dtx.qkv"):
                 h = rms_norm(x, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
-                q = proj(h, "q_proj").reshape(B, T, H, kind.head_dim)
-                k = proj(h, "k_proj").reshape(B, T, kind.num_kv_heads, kind.head_dim)
-                v = proj(h, "v_proj").reshape(B, T, kind.num_kv_heads, kind.v_head_dim)
-                q = apply_rope(q, cos, sin)
-                k = apply_rope(k, cos, sin)
-                if kind.value_scale != 1.0:
-                    v = v * jnp.asarray(kind.value_scale, v.dtype)
-            if view is not None:
-                with jax.named_scope("dtx.kv_write"):
-                    pool_k, k_att = view.update(pool_k, li, k)
-                    pool_v, v_att = view.update(pool_v, li, v)
-                    k_att, v_att = k_att.astype(k.dtype), v_att.astype(v.dtype)
-            else:
-                k_att, v_att = k, v
-            with jax.named_scope("dtx.attn"):
-                attn = xla_attention(
-                    q, k_att, v_att, bias[kind.name],
-                    sink=lp["attention_sink_bias"] if kind.sink else None)
+            mixed, leaves = mixer(kind, h, lp, proj, leaves, li)
             with jax.named_scope("dtx.attn_out"):
-                x = x + proj(attn.reshape(B, T, H * kind.v_head_dim), "o_proj")
+                x = x + proj(mixed, "o_proj")
             if run.ffn == "dense":
                 with jax.named_scope("dtx.mlp"):
                     h = rms_norm(x, lp["post_attention_layernorm"]["scale"],
@@ -315,11 +508,18 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
                     experts_total=cfg.experts_total,
                     experts_held=cfg.experts_held, first_held=cfg.first_held,
                     top_k=cfg.experts_per_token, normalize=cfg.norm_topk_prob,
-                    scaling=cfg.routed_scaling_factor, layer=lr)
+                    scaling=cfg.routed_scaling_factor, layer=lr,
+                    n_group=cfg.n_group, topk_group=cfg.topk_group)
+                if "shared_expert" in lp:
+                    with jax.named_scope("dtx.moe_shared"):
+                        sp = lp["shared_expert"]
+                        dot = lambda a, name: _proj(a, sp[name], None, 0.0)  # noqa: E731
+                        x = x + dot(jax.nn.silu(dot(h, "gate_proj"))
+                                    * dot(h, "up_proj"), "down_proj")
                 with jax.named_scope("dtx.moe_combine"):
                     x = x + y.reshape(B, T, D)
                     stats = stats + counts
-            return (x, pool_k, pool_v, stats), None
+            return (x, leaves, stats), None
 
         return block
 
@@ -327,9 +527,10 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
     stats = jnp.zeros((moe.N_STATS,), jnp.int32)
     with jax.named_scope("dtx.layers"):
         for i, run in enumerate(layer_runs(cfg)):
-            name = run.attn.name
-            pools = ((new_cache[f"k_{name}"], new_cache[f"v_{name}"])
-                     if cache is not None else (None, None))
+            kind = run.mixer
+            keys = tuple(kind.pools()) + tuple(kind.states(cfg))
+            leaves = (tuple(new_cache[key] for key in keys) if cache is not None
+                      else (None,) * len(kind.pools()))
             # the run's experts go to every layer whole, not a slice a
             # layer (ops/moe.py:grouped_swiglu); everything else is scanned
             scanned = dict(params["layers"][run_key(i)])
@@ -337,10 +538,10 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
             steps = jnp.arange(run.count, dtype=jnp.int32)
             xs = (scanned, lora_layers.get(run_key(i)) if lora_layers else None,
                   run.kind_start + steps, steps)
-            (x, pool_k, pool_v, stats), _ = jax.lax.scan(
-                make_block(run, experts), (x,) + pools + (stats,), xs)
+            (x, leaves, stats), _ = jax.lax.scan(
+                make_block(run, experts), (x, leaves, stats), xs)
             if cache is not None:
-                new_cache[f"k_{name}"], new_cache[f"v_{name}"] = pool_k, pool_v
+                new_cache.update(zip(keys, leaves))
 
     with jax.named_scope("dtx.unembed"):
         x = rms_norm(x, params["norm"]["scale"], cfg.rms_norm_eps)

@@ -54,7 +54,8 @@ def lora_groups(cfg: ModelConfig) -> list:
     of one kind is one group with key ``None`` (tree ``layers.<target>``, the
     only layout there was); a model of several kinds has one group per run of
     like layers (tree ``layers.<run>.<target>``), each with its own geometry
-    (``v_proj`` is as wide as that run's KV heads). Experts take no adapter."""
+    (``v_proj`` is as wide as that run's KV heads; a latent-attention run has
+    ``q_proj`` and ``o_proj`` only). Experts take no adapter."""
     if not cfg.hybrid:
         return [(None, cfg.num_layers,
                  {t: target_dims(cfg, t) for t in LORA_TARGETS})]
@@ -64,7 +65,7 @@ def lora_groups(cfg: ModelConfig) -> list:
     D, F = cfg.hidden_size, cfg.intermediate_size
     groups = []
     for i, run in enumerate(layer_runs(cfg)):
-        dims = dict(attn_dims(cfg, run.attn))
+        dims = dict(attn_dims(cfg, run.mixer))
         if run.ffn == "dense":
             dims.update(gate_proj=(D, F), up_proj=(D, F), down_proj=(F, D))
         groups.append((run_key(i), run.count, dims))

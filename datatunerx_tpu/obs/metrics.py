@@ -373,11 +373,23 @@ def export_moe_stats(registry: Registry, engine) -> None:
     steps = registry.gauge(
         "dtx_serving_moe_layer_steps",
         "Expert-layer executions (steps times expert layers), by phase.")
+    here = registry.gauge(
+        "dtx_serving_moe_rows_here",
+        "Rows of which at least one chosen expert is held on this chip, "
+        "summed over expert layers and steps, by phase.")
+    seen = registry.gauge(
+        "dtx_serving_moe_rows",
+        "Real rows the expert layers routed (pads and idle slots left out), "
+        "summed over expert layers and steps, by phase.")
     behind = registry.gauge(
         "dtx_serving_kv_behind_window_bytes",
         "Bytes of the window layers' KV pool held by blocks that no later "
         "query can see (they stay allocated until the request ends).")
-    for m in (rows, hit, most, steps, behind):
+    state = registry.gauge(
+        "dtx_serving_state_bytes",
+        "Bytes of recurrent state resident for the linear-attention layers "
+        "(constant per slot, whatever the slots' contexts).")
+    for m in (rows, hit, most, steps, here, seen, behind, state):
         m.clear()
     stats = getattr(engine, "moe_stats", None) or {}
     for phase in ("decode", "prefill"):
@@ -387,9 +399,13 @@ def export_moe_stats(registry: Registry, engine) -> None:
             hit.set(stats[f"{phase}_experts_hit"], label)
             most.set(stats[f"{phase}_max_rows"], label)
             steps.set(stats[f"{phase}_layer_steps"], label)
+            here.set(stats.get(f"{phase}_rows_here", 0), label)
+            seen.set(stats.get(f"{phase}_rows", 0), label)
     window_fn = getattr(engine, "kv_window_stats", None)
     window = window_fn() if callable(window_fn) else None
     behind.set(window["behind_bytes"] if window else 0)
+    state_fn = getattr(engine, "state_bytes", None)
+    state.set(state_fn() if callable(state_fn) else 0)
 
 
 # ------------------------------------------------------------ process plumbing

@@ -108,6 +108,7 @@ def xla_attention(
     v: jnp.ndarray,
     bias: jnp.ndarray,
     sink: jnp.ndarray | None = None,  # [H] one learned logit per head
+    scale: float | None = None,  # ``d ** -0.5`` of q's width unless given
 ) -> jnp.ndarray:
     """Reference attention: f32 softmax, GQA via reshape. q and k share a
     head width, v may have another: returns [B, T, H, v's width].
@@ -119,7 +120,8 @@ def xla_attention(
     S, KV = k.shape[1], k.shape[2]
     G = H // KV
     q = q.reshape(B, T, KV, G, d)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
     logits = jnp.einsum("btkgd,bskd->bkgts", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
     bias4 = bias.astype(jnp.float32)  # [B, 1|H, T, S]

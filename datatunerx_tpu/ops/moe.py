@@ -20,22 +20,41 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# what one expert layer counts of one step: rows routed to held experts,
-# held experts that got a row, most rows on one expert, and 1 (layer-steps)
-N_STATS = 4
+# what one expert layer counts of one step: (token, expert) pairs routed to
+# held experts, held experts that got a row, most rows on one expert, 1
+# (layer-steps), rows with at least one held pair, real rows
+N_STATS = 6
+
+
+def keep_groups(c: jnp.ndarray, n_group: int, topk_group: int) -> jnp.ndarray:
+    """Group-limited selection: the experts lie in ``n_group`` groups of
+    consecutive experts; a group's score is the sum of its two largest
+    ``c``; every expert outside the ``topk_group`` best groups gets ``-inf``.
+    c [N, E] -> [N, E]."""
+    N, E = c.shape
+    grouped = c.reshape(N, n_group, E // n_group)
+    score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [N, n_group]
+    _, best = jax.lax.top_k(score, topk_group)
+    kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+    return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(N, E)
 
 
 def route(x: jnp.ndarray, router: jnp.ndarray, correction: jnp.ndarray, *,
-          top_k: int, normalize: bool, scaling: float):
-    """Sigmoid router with a selection-only correction bias (``noaux_tc``, one
-    group). x [N, D]; router [D, E]; correction [E]. Returns the chosen
-    experts [N, k] int32 and their weights [N, k] float32: the ``k`` largest
-    ``sigmoid(g) + correction`` are chosen, and weighted by ``sigmoid(g)``
-    WITHOUT the correction, divided by their sum, times ``scaling``."""
+          top_k: int, normalize: bool, scaling: float, n_group: int = 1,
+          topk_group: int = 1):
+    """Sigmoid router with a selection-only correction bias (``noaux_tc``).
+    x [N, D]; router [D, E]; correction [E]. Returns the chosen experts [N, k]
+    int32 and their weights [N, k] float32: the ``k`` largest ``sigmoid(g) +
+    correction`` are chosen (among the kept groups' experts where the model
+    has groups: ``keep_groups``), and weighted by ``sigmoid(g)`` WITHOUT the
+    correction, divided by their sum, times ``scaling``."""
     g = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(g)
-    _, idx = jax.lax.top_k(s + correction.astype(jnp.float32), top_k)
+    c = s + correction.astype(jnp.float32)
+    if n_group > 1:
+        c = keep_groups(c, n_group, topk_group)
+    _, idx = jax.lax.top_k(c, top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if normalize:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -82,7 +101,8 @@ def grouped_swiglu(xs: jnp.ndarray, sizes: jnp.ndarray, gate: jnp.ndarray,
 
 def expert_layer(x: jnp.ndarray, valid: jnp.ndarray | None, p: dict, *,
                  experts_total: int, experts_held: int, first_held: int,
-                 top_k: int, normalize: bool, scaling: float, layer=None):
+                 top_k: int, normalize: bool, scaling: float, layer=None,
+                 n_group: int = 1, topk_group: int = 1):
     """This chip's part of one expert layer. x [N, D]; ``valid`` [N] marks
     real rows (pads and idle slots are routed nowhere). ``p`` holds
     ``router.kernel`` [D, E_total], ``e_score_correction_bias`` [E_total] and
@@ -93,7 +113,8 @@ def expert_layer(x: jnp.ndarray, valid: jnp.ndarray | None, p: dict, *,
     assert p["router"]["kernel"].shape[-1] == experts_total
     with jax.named_scope("dtx.moe_route"):
         idx, w = route(x, p["router"]["kernel"], p["e_score_correction_bias"],
-                       top_k=top_k, normalize=normalize, scaling=scaling)
+                       top_k=top_k, normalize=normalize, scaling=scaling,
+                       n_group=n_group, topk_group=topk_group)
         here, order, sizes = sort_pairs(idx, valid, first_held=first_held,
                                         held=experts_held)
         xs = x[order // top_k]
@@ -108,6 +129,8 @@ def expert_layer(x: jnp.ndarray, valid: jnp.ndarray | None, p: dict, *,
         pairs = out[place].reshape(N, top_k, D)
         pairs = jnp.where(here[:, :, None], pairs, 0.0)
         y = jnp.einsum("nk,nkd->nd", jnp.where(here, w, 0.0), pairs)
+        rows = jnp.sum(valid) if valid is not None else jnp.asarray(N)
         stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
-                           jnp.max(sizes), jnp.ones((), jnp.int32)])
+                           jnp.max(sizes), jnp.ones((), jnp.int32),
+                           jnp.sum(jnp.any(here, axis=1)), rows])
     return y.astype(x.dtype), stats.astype(jnp.int32)

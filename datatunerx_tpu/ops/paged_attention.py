@@ -191,6 +191,28 @@ def kv_leaf_keys(cache: Dict) -> List[str]:
     return [key for key in cache if key[:1] in ("k", "v")]
 
 
+def state_leaf_keys(cache: Dict) -> List[str]:
+    """The cache's recurrent-state leaves (a linear-attention layer's memory,
+    models/hybrid.py): ``[layers of the kind, slots, ...]``, of constant size
+    per slot. They are NOT rows: nothing trims them at a cursor or finds them
+    through a block table; whatever moves a slot's row moves the slot's
+    entry of each whole."""
+    return [key for key in cache if key.startswith("state_")]
+
+
+def state_slot(leaf: jnp.ndarray, slot) -> jnp.ndarray:
+    """One slot's entry ``[layers, 1, ...]`` of a state leaf."""
+    return jax.lax.dynamic_slice(
+        leaf, (0, slot) + (0,) * (leaf.ndim - 2),
+        (leaf.shape[0], 1) + leaf.shape[2:])
+
+
+def state_insert(leaf: jnp.ndarray, slot, entry: jnp.ndarray) -> jnp.ndarray:
+    """Put one slot's entry ``[layers, 1, ...]`` back into a state leaf."""
+    return jax.lax.dynamic_update_slice(
+        leaf, entry.astype(leaf.dtype), (0, slot) + (0,) * (leaf.ndim - 2))
+
+
 def init_paged_cache(cfg, slots: int, num_blocks: int, block_size: int,
                      blocks_per_slot: int, dtype=jnp.bfloat16,
                      quantize: Optional[str] = None) -> Dict:
@@ -339,6 +361,8 @@ def paged_insert_row(cache: Dict, slot, table_row: jnp.ndarray,
         cache["block_tables"], table_row[None], (slot, 0))
     for key in kv_leaf_keys(cache):
         out[key] = cache[key].at[:, phys, off].set(row_cache[key][:, 0])
+    for key in state_leaf_keys(cache):
+        out[key] = state_insert(cache[key], slot, row_cache[key])
     out["pos"] = cache["pos"].at[phys, off].set(row_cache["pos"][0])
     return out
 
@@ -354,7 +378,27 @@ def row_trim(row: Dict, width: int) -> Dict:
     out: Dict = {"len": row.get("len")}
     for key in kv_leaf_keys(row):
         out[key] = row[key][:, :, :width]
+    for key in state_leaf_keys(row):
+        out[key] = row[key]
     out["pos"] = row["pos"][:, :width]
+    return out
+
+
+def paged_install_table(cache: Dict, slot, table_row: jnp.ndarray) -> Dict:
+    """Hand ``slot`` the blocks of ``table_row`` ([blocks_per_slot], -1 past
+    the last one) for a prompt that is prefilled in place: install the row,
+    scrub the blocks' recycled positions to the sentinel (chunked prefill
+    shows the whole table to attention before every lane is written) and
+    rewind the slot's cursor. ONE program whatever the number of blocks: done
+    eagerly, each of the three updates is a handful of small programs and the
+    scrub compiles them anew for every block count a workload has."""
+    out = dict(cache)
+    out["block_tables"] = jax.lax.dynamic_update_slice(
+        cache["block_tables"], table_row[None], (slot, 0))
+    # an unused column points past the pool and is dropped
+    blocks = jnp.where(table_row >= 0, table_row, cache["pos"].shape[0])
+    out["pos"] = cache["pos"].at[blocks].set(POS_SENTINEL, mode="drop")
+    out["len"] = cache["len"].at[slot].set(0)
     return out
 
 
@@ -401,6 +445,8 @@ def paged_extract_row(cache: Dict, slot, cursor, *,
         leaf = cache[key]  # [L, NB, bs, ...] -> [L, 1, W, ...]
         row[key] = leaf[:, tbl].reshape(
             (leaf.shape[0], 1, W) + leaf.shape[3:])
+    for key in state_leaf_keys(cache):
+        row[key] = state_slot(cache[key], slot)
     pos = cache["pos"][tbl]  # [nbps, bs]
     pos = jnp.where((table_row >= 0)[:, None], pos, POS_SENTINEL)
     row["pos"] = pos.reshape(1, W)

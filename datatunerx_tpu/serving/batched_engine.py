@@ -36,6 +36,7 @@ from __future__ import annotations
 import collections
 import itertools
 import json
+import math
 import queue
 import sys
 import threading
@@ -66,6 +67,10 @@ from datatunerx_tpu.ops.paged_attention import (
     paged_copy_block,
     paged_extract_row,
     paged_insert_row,
+    paged_install_table,
+    state_insert,
+    state_leaf_keys,
+    state_slot,
 )
 from datatunerx_tpu.ops.pallas_sampling import (
     default_impl as sampling_default_impl,
@@ -375,7 +380,8 @@ _PROGRAM_MEMO: "collections.OrderedDict" = collections.OrderedDict()
 _PROGRAM_MEMO_MAX = 8
 
 # cache["moe_stats"] columns (ops/moe.py N_STATS)
-MOE_STAT_NAMES = ("local_rows", "experts_hit", "max_rows", "layer_steps")
+MOE_STAT_NAMES = ("local_rows", "experts_hit", "max_rows", "layer_steps",
+                  "rows_here", "rows")
 
 
 def _program_memo_key(cfg, max_seq_len: int, kv_quant,
@@ -407,11 +413,11 @@ class _Programs:
     The KV cache is CARRIED and CONSUMED: inside a program the layer scan
     carries the stacked leaves and each layer writes its tokens at its own
     index (models/llama.py, models/hybrid.py), and ``decode``,
-    ``prefill_chunk``, ``insert``, ``insert_paged`` and ``copy_block`` donate
-    the cache they are given (``decode``, ``insert*`` and ``activate`` the
-    per-slot state arrays they return as well), so from the engine's handle
-    down to a layer's scatter nothing copies a pool, a leaf or a layer of
-    one. ``extract``, ``prefill`` and ``extend`` take no cache of the
+    ``prefill_chunk``, ``insert``, ``insert_paged``, ``install_table`` and
+    ``copy_block`` donate the cache they are given (``decode``, ``insert*``
+    and ``activate`` the per-slot state arrays they return as well), so from
+    the engine's handle down to a layer's scatter nothing copies a pool, a
+    leaf or a layer of one. ``extract``, ``prefill`` and ``extend`` take no cache of the
     engine's or only read it.
 
     ``lora`` is ``None`` (base-only engine) or ``(tree, scales)`` with
@@ -450,6 +456,7 @@ class _Programs:
         self.extract = jax.jit(paged_extract_row,
                                static_argnames=("width",))
         self.copy_block = jax.jit(paged_copy_block, donate_argnums=(0,))
+        self.install_table = jax.jit(paged_install_table, donate_argnums=(0,))
         self.decode = jax.jit(self._decode_impl,
                               static_argnames=("K", "mode"),
                               donate_argnums=(2, 3, 4, 5, 6, 7))
@@ -486,7 +493,8 @@ class _Programs:
                      slot, row_cache, row_logits, plen, n_prompt, max_new,
                      temp, top_p, stop_row, adapter, seed):
         cache = dict(cache)
-        for key in kv_leaf_keys(cache):
+        # a dense row and a slot's recurrent state both lie at [:, slot]
+        for key in kv_leaf_keys(cache) + state_leaf_keys(cache):
             cache[key] = jax.lax.dynamic_update_slice(
                 cache[key], row_cache[key],
                 (0, slot) + (0,) * (cache[key].ndim - 2))
@@ -565,6 +573,10 @@ class _Programs:
         view["len"] = jax.lax.dynamic_slice(cache["len"], (slot,), (1,))
         view["block_tables"] = jax.lax.dynamic_slice(
             cache["block_tables"], (slot, 0), (1, nbps))
+        # pools are shared and found through the slot's table; recurrent
+        # state is the slot's own entry, sliced as ``len`` is
+        for key in state_leaf_keys(cache):
+            view[key] = state_slot(cache[key], slot)
         logits, new = forward(
             params, tokens, self.cfg, positions=positions,
             attention_mask=mask, cache=view, lora=lora,
@@ -575,6 +587,8 @@ class _Programs:
         out = dict(cache)
         for key in kv_leaf_keys(out) + ["moe_stats"] * ("moe_stats" in out):
             out[key] = new[key]
+        for key in state_leaf_keys(out):
+            out[key] = state_insert(cache[key], slot, new[key])
         out["pos"] = new["pos"]
         out["len"] = jax.lax.dynamic_update_slice(
             cache["len"], new["len"], (slot,))
@@ -687,6 +701,16 @@ class BatchedEngine:
                     raise NotImplementedError(
                         f"model {self.cfg.name!r} has layers of several "
                         f"kinds: --{flag} does not handle it yet")
+        # a prefix hit, a rejected draft, a preempted or a migrating session
+        # all restart a slot at a cursor it has passed: rows are trimmed
+        # there, a linear-attention layer's state cannot be
+        from datatunerx_tpu.models.config import refuse_recurrent_state
+
+        for flag, on in (("prefix_cache", prefix_cache > 0),
+                         ("spec_draft", bool(spec_draft)),
+                         ("kv_overcommit", str(kv_overcommit).lower() == "on")):
+            if on:
+                refuse_recurrent_state(self.cfg, f"--{flag}")
 
         # ---- adapters: checkpoint_path becomes adapter "default" (unmerged);
         # full-param checkpoints swap the base instead
@@ -1060,6 +1084,7 @@ class BatchedEngine:
         self._prefill_chunk_fn = progs.prefill_chunk
         self._extract = progs.extract
         self._copy_block = progs.copy_block
+        self._install_table = progs.install_table
         self._decode = progs.decode
 
         self._prefix = _PrefixCache(
@@ -1164,15 +1189,23 @@ class BatchedEngine:
         self._moe_seen = stats
         self._slot_cursor = lens
 
+    def state_bytes(self) -> int:
+        """Bytes of recurrent state the cache holds (``state_*`` leaves): what
+        the linear-attention layers keep per slot, resident whether a slot is
+        live or idle."""
+        cache = self._cache  # shapes only: a donated leaf still has its shape
+        return sum(math.prod(cache[key].shape) * cache[key].dtype.itemsize
+                   for key in state_leaf_keys(cache))
+
     def kv_window_stats(self) -> Optional[dict]:
         """Bytes of the window layers' pool that live slots hold
         (``live_bytes``) and the part of them in blocks that lie wholly behind
         every later query's window (``behind_bytes``): blocks stay allocated
         until their request ends. None where no layer has a window, the
         cache is not paged, or no decode tick has read the cursors yet."""
-        from datatunerx_tpu.models.config import attention_kinds, kind_layers
+        from datatunerx_tpu.models.config import kind_layers, mixer_kinds
 
-        kind = attention_kinds(self.cfg).get("window")
+        kind = mixer_kinds(self.cfg).get("window")
         if (kind is None or not self.paged or self._slot_cursor is None
                 or "k_window" not in self._cache):
             return None
@@ -1658,11 +1691,9 @@ class BatchedEngine:
             # install the table, scrub the blocks' recycled positions to the
             # sentinel (chunked prefill reveals the whole table to attention
             # before every lane is written), and rewind the slot cursor
-            self._cache["block_tables"] = \
-                self._cache["block_tables"].at[slot].set(self._table_row(blocks))
-            self._cache["pos"] = self._cache["pos"].at[
-                jnp.asarray(blocks, jnp.int32)].set(POS_SENTINEL)
-            self._cache["len"] = self._cache["len"].at[slot].set(0)
+            self._cache = self._install_table(
+                self._cache, jnp.asarray(slot, jnp.int32),
+                self._table_row(blocks))
         except Exception:
             self._allocator.free(blocks)
             raise
@@ -2060,6 +2091,9 @@ class BatchedEngine:
         (disaggregated handoff): the payload carries the blocks written so
         far plus a ``pending`` document with the remaining prompt tail, and
         the importer resumes chunked prefill where the source stopped."""
+        from datatunerx_tpu.models.config import refuse_recurrent_state
+
+        refuse_recurrent_state(self.cfg, "session migration")
         return self._mig_call({"kind": "export",
                                "slots": (None if slots is None
                                          else [int(s) for s in slots]),
@@ -2083,6 +2117,9 @@ class BatchedEngine:
         The returned meta carries ``"_request"`` (the live Request handle
         for ``resume_stream``) and ``text_so_far`` (the detokenized
         migrated tail)."""
+        from datatunerx_tpu.models.config import refuse_recurrent_state
+
+        refuse_recurrent_state(self.cfg, "session migration")
         return self._mig_call(
             {"kind": "import", "payload": payload,
              "deadline": time.monotonic() + wait_s}, timeout_s)

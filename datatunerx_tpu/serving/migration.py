@@ -123,12 +123,13 @@ def model_signature(cfg, kv_quant: Optional[str]) -> dict:
 def _pool_signature(cfg) -> dict:
     """{kind: [layers, kv_heads, k width, v width]} of a model with a KV pool
     per attention kind."""
-    from datatunerx_tpu.models.config import attention_kinds, kind_layers
+    from datatunerx_tpu.models.config import kind_layers, mixer_kinds
 
     layers = kind_layers(cfg)
     return {name: [layers[name], kind.num_kv_heads, kind.head_dim,
                    kind.v_head_dim]
-            for name, kind in attention_kinds(cfg).items()}
+            for name, kind in mixer_kinds(cfg).items()
+            if name in ("global", "window")}
 
 
 def _check_model_sig(payload: dict, cfg) -> None:
@@ -149,6 +150,9 @@ def _check_model_sig(payload: dict, cfg) -> None:
 
 
 def check_signature(payload: dict, cfg) -> None:
+    from datatunerx_tpu.models.config import refuse_recurrent_state
+
+    refuse_recurrent_state(cfg, "session migration")
     _check_model_sig(payload, cfg)
     if payload.get("kind") != PAYLOAD_KIND:
         raise ValueError(
@@ -339,6 +343,9 @@ def build_payload(cfg, kv_quant: Optional[str], request: dict, row: Dict,
     row this function trims, encodes, and pulls to host. ``b64=False``
     keeps array bodies as raw numpy for payloads that stay in-process
     (engine preemption parking); ``encode_payload`` makes them wire-safe."""
+    from datatunerx_tpu.models.config import refuse_recurrent_state
+
+    refuse_recurrent_state(cfg, "session migration")
     cursor = int(cursor)
     default_wire = "int8" if kv_quant == "int8" else "bf16"
     return {

@@ -377,6 +377,10 @@ def build_draft(spec_draft: str, target_cfg, target_params,
       model loader; its vocab must match the target's (the acceptance rule
       compares distributions over one vocabulary).
     """
+    from datatunerx_tpu.models.config import refuse_recurrent_state
+
+    # a rejected proposal rewinds the cursor; a recurrent state cannot follow
+    refuse_recurrent_state(target_cfg, "speculative decoding (--spec_draft)")
     if spec_draft.startswith("take:"):
         n = int(spec_draft.split(":", 1)[1])
         if not 1 <= n <= target_cfg.num_layers:
@@ -683,6 +687,10 @@ _SPEC_MEMO_MAX = 8
 
 def spec_programs(tcfg, dcfg, max_seq_len: int, kv_quant,
                   epilogue: str = "off") -> "SpecPrograms":
+    from datatunerx_tpu.models.config import refuse_recurrent_state
+
+    for cfg in (tcfg, dcfg):
+        refuse_recurrent_state(cfg, "speculative decoding (--spec_draft)")
     try:
         key = (repr(tcfg), repr(dcfg), int(max_seq_len), kv_quant, epilogue)
     except Exception:  # noqa: BLE001 — memoization is best-effort

@@ -304,6 +304,88 @@ def test_flash_kernels_compile_for_v5e_at_the_training_cell_shape():
                            "dtx_flash_bwd_dkv"], (case, kernels)
 
 
+# The decode program (128 slots, 8 token steps) and the 256-token prefill-chunk
+# program of the benchmark's cell ling-serve-decode at its published widths and
+# its engine settings, adapters on q_proj and o_proj: three scanned blocks (KDA
+# + dense, KDA + experts, MLA + experts), the recurrent state [6, 128, 32, 128,
+# 128] float32 donated with the cache. The chip's compiler must take them, fit
+# them in one chip's 16 GB beside their arguments, alias the state leaves to
+# the result (nothing copies 1.6 GB of state), and keep the expert layers'
+# grouped matmuls as ``ragged-dot`` Mosaic kernels.
+_LING_PROBE = r"""
+import json, os, sys
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.join(os.environ["DTX_REPO"], "benchmarks"))
+import jax
+jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_enable_compilation_cache", False)
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+import spec
+from datatunerx_tpu.models import init_params
+from datatunerx_tpu.models.lora import lora_groups
+from datatunerx_tpu.ops.paged_attention import init_paged_cache, state_leaf_keys
+from datatunerx_tpu.serving.batched_engine import MAX_STOP, _Programs
+
+cell = spec.load_cell("ling-serve-decode")
+cfg = spec.register_preset(cell)
+eng = cell.workload["engine"]
+S, bs, NB, L = eng["slots"], eng["kv_block_size"], eng["kv_blocks"], eng["max_seq_len"]
+sh = SingleDeviceSharding(topologies.get_topology_desc(
+    platform="tpu", topology_name="v5e:2x2").devices[0])
+z = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+sds = lambda tree: jax.tree_util.tree_map(lambda x: z(x.shape, x.dtype), tree)
+params = sds(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)))
+cache = sds(jax.eval_shape(lambda: init_paged_cache(cfg, S, NB, bs, L // bs, dtype=jnp.bfloat16)))
+E, r = 3, cell.workload["adapters"]["rank"]  # base + two adapters
+lora = ({"layers": {key: {t: {"a": z((n, E, dims[t][0], r), jnp.bfloat16),
+                              "b": z((n, E, r, dims[t][1]), jnp.bfloat16)}
+                          for t in cell.workload["adapters"]["targets"]}
+                    for key, n, dims in lora_groups(cfg)}}, z((E,), jnp.float32))
+progs = _Programs(cfg, L, None, epilogue="kernel")
+state = sum(cache[k].size * cache[k].dtype.itemsize for k in state_leaf_keys(cache))
+row = z((1, 256), jnp.int32)
+cases = {
+    "decode": lambda: progs.decode.lower(
+        params, lora, cache, z((S, cfg.vocab_size), jnp.float32), z((S,), jnp.int32),
+        z((S,), jnp.int32), z((S,), jnp.bool_), z((S, 2), jnp.uint32), z((S,), jnp.float32),
+        z((S,), jnp.float32), z((S, MAX_STOP), jnp.int32), z((S,), jnp.int32),
+        K=eng["decode_chunk"], mode="greedy"),
+    "prefill_chunk_256": lambda: progs.prefill_chunk.lower(
+        params, lora, cache, z((), jnp.int32), row, row, row, z((), jnp.int32), chunk_len=256),
+}
+out = {"state_bytes": state}
+for name, lower in cases.items():
+    c = lower().compile()
+    m, text = c.memory_analysis(), c.as_text()
+    out[name] = {"live": m.argument_size_in_bytes + m.temp_size_in_bytes
+                 + m.output_size_in_bytes - m.alias_size_in_bytes,
+                 "alias": m.alias_size_in_bytes, "ragged": text.count("%ragged-dot"),
+                 "scopes": [s for s in ("dtx.kda_conv", "dtx.kda_state", "dtx.kda_out",
+                                        "dtx.mla_absorb", "dtx.moe_shared") if s in text]}
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def ling_doc():
+    pytest.importorskip("libtpu")  # the TPU compiler; absent from jax[cpu]
+    return _run_probe(_LING_PROBE, timeout=900)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk_256"])
+def test_ling_cell_programs_compile_for_v5e_at_published_widths(program, ling_doc):
+    got = ling_doc[program]
+    assert ling_doc["state_bytes"] == 6 * 128 * (32 * 128 * 128 * 4 + 3 * 12288 * 2)
+    assert got["live"] < 13e9, got  # one chip holds 16 GB; the engine keeps logits and adapters beside it
+    assert got["alias"] >= ling_doc["state_bytes"], got  # the donated state is written in place
+    assert got["ragged"] >= 6, got  # gate, up and down in two runs of expert layers
+    assert got["scopes"] == ["dtx.kda_conv", "dtx.kda_state", "dtx.kda_out",
+                             "dtx.mla_absorb", "dtx.moe_shared"], got
+
+
 @pytest.mark.slow
 def test_aot_pipeline_compiles_for_v5e_target():
     assert _run_probe(_PROBE, timeout=900)["ok"] is True

@@ -71,11 +71,11 @@ def _step(params, tokens, cache, positions, mask=None):
 def test_runs_of_like_layers(model):
     cfg = model[0]
     runs = layer_runs(cfg)
-    assert [(r.attn.name, r.ffn, r.count, r.kind_start) for r in runs] == [
+    assert [(r.mixer.name, r.ffn, r.count, r.kind_start) for r in runs] == [
         ("global", "dense", 1, 0), ("window", "experts", 3, 0), ("global", "experts", 1, 1)]
-    assert runs[0].attn.num_kv_heads == 1 and runs[1].attn.num_kv_heads == 2
-    assert runs[1].attn.rotary_dim == 8 and runs[1].attn.window == 24
-    assert ref.runs_of(model[1]) == [(r.attn.name, r.ffn, r.count) for r in runs]
+    assert runs[0].mixer.num_kv_heads == 1 and runs[1].mixer.num_kv_heads == 2
+    assert runs[1].mixer.rotary_dim == 8 and runs[1].mixer.window == 24
+    assert ref.runs_of(model[1]) == [(r.mixer.name, r.ffn, r.count) for r in runs]
 
 
 def test_full_forward_equals_reference(model, want):
@@ -424,4 +424,4 @@ def test_entries_that_handle_one_kind_refuse_by_name(model, entry):
             BatchedEngine("preset:debug-hybrid", prefix_cache=4)
         else:
             forward(params, tokens, cfg, segment_ids=jnp.zeros_like(tokens))
-    assert "several" in str(err.value) or "per attention kind" in str(err.value)
+    assert "several" in str(err.value) or "per mixer kind" in str(err.value)

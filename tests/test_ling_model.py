@@ -1,0 +1,526 @@
+"""A model whose mixers are linear attention (KDA: a recurrent state per slot,
+no rows) and latent attention (MLA: one pool of latent rows, absorbed decode),
+with group-limited routing and a shared expert (models/hybrid.py, ops/kda.py,
+ops/mla.py, ops/moe.py). The chunk form of the gated delta rule is tested
+first, in float64 NumPy, against the three-line recurrence: everything else
+in the KDA path rests on it. Every model-level test is against the plain
+reference ``benchmarks/reference/ling_v3.py`` (float32, recurrence token by
+token, expanded heads, no cache), at the ``debug-ling`` size on seeded weights."""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+
+from reference import ling_v3 as ref  # noqa: E402
+
+from datatunerx_tpu.models import forward, get_config, init_params  # noqa: E402
+from datatunerx_tpu.models.config import layer_runs, mixer_kinds  # noqa: E402
+from datatunerx_tpu.models.llama import init_cache  # noqa: E402
+from datatunerx_tpu.ops import kda, mla, moe  # noqa: E402
+from datatunerx_tpu.ops.paged_attention import (  # noqa: E402
+    init_paged_cache,
+    kv_leaf_keys,
+    paged_extract_row,
+    paged_insert_row,
+    state_leaf_keys,
+)
+
+# float32 program against float32 reference: rounding order only. The chunk
+# form solves a 64-row triangular system where the recurrence adds 64 rank-one
+# updates, so it is a few ulp of the state's largest entries wider than the
+# softmax layers' 2e-5.
+TOL = 5e-5
+T = 150
+
+
+# ------------------------------------------------- the equations, float64
+
+def np_recurrence(S, q, k, v, g, beta):
+    o = np.zeros(v.shape)
+    for t in range(q.shape[0]):
+        S = np.exp(g[t])[:, None] * S
+        S = S + beta[t] * np.outer(k[t], v[t] - S.T @ k[t])
+        o[t] = S.T @ q[t]
+    return o, S
+
+
+def np_chunk(S0, Q, K, V, g, beta):
+    C = Q.shape[0]
+    G = np.cumsum(g, axis=0)
+    D = np.exp(np.minimum(G[:, None, :] - G[None, :, :], 0))  # differences <= 0 only
+    A = np.tril(beta[:, None] * np.einsum("tc,sc,tsc->ts", K, K, D), -1)
+    Tm = np.linalg.inv(np.eye(C) + A) @ np.diag(beta)
+    U = Tm @ V - Tm @ (K * np.exp(G)) @ S0
+    O = (Q * np.exp(G)) @ S0 + np.tril(np.einsum("tc,sc,tsc->ts", Q, K, D)) @ U
+    return O, np.exp(G[-1])[:, None] * S0 + (K * np.exp(G[-1] - G)).T @ U
+
+
+def _draw(rng, *lead, dk=16, dv=8, left_pad=0):
+    """q, k (unit), v, g in (-5, 0), beta in (0, 1), S0; pads at the left carry g 0, beta 0."""
+    q, k = rng.normal(size=lead + (dk,)), rng.normal(size=lead + (dk,))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.normal(size=lead + (dv,))
+    g = -5 * rng.uniform(size=lead + (dk,)) ** 3
+    beta = rng.uniform(size=lead)
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("C", [1, 16, 64])
+def test_chunk_form_equals_the_recurrence_in_float64(C):
+    rng = np.random.default_rng(C)
+    q, k, v, g, beta = _draw(rng, C, dk=8, dv=6)
+    g = -5 * rng.uniform(size=g.shape)  # the whole range: exp(5 * 64) overflows float32, not this
+    S0 = rng.normal(size=(8, 6))
+    o1, s1 = np_recurrence(S0, q, k, v, g, beta)
+    o2, s2 = np_chunk(S0, q, k, v, g, beta)
+    np.testing.assert_allclose(o2, o1, atol=1e-12)
+    np.testing.assert_allclose(s2, s1, atol=1e-12)
+
+
+def _bthd(rng, B, T_, H, pads):
+    q, k, v, g, beta = _draw(rng, B, T_, H)
+    valid = np.arange(T_)[None, :] >= np.asarray(pads)[:, None]
+    g, beta = g * valid[:, :, None, None], beta * valid[:, :, None]
+    S0 = rng.normal(size=(B, H, 16, 8))
+    return tuple(jnp.asarray(x, jnp.float32) for x in (S0, q, k, v, g, beta))
+
+
+def _against_numpy(o, S, args):
+    S0, q, k, v, g, beta = (np.asarray(x, np.float64) for x in args)
+    for b in range(q.shape[0]):
+        for h in range(q.shape[2]):
+            on, sn = np_recurrence(S0[b, h], q[b, :, h], k[b, :, h], v[b, :, h], g[b, :, h], beta[b, :, h])
+            np.testing.assert_allclose(np.asarray(o[b, :, h]), on, atol=2e-5)
+            np.testing.assert_allclose(np.asarray(S[b, h]), sn, atol=2e-5)
+
+
+def test_one_token_step_equals_the_recurrence():
+    """``state_step`` reads S once for two products and writes it once; the
+    read-out is rebuilt from the first pass. Twelve steps against NumPy."""
+    args = _bthd(np.random.default_rng(1), 2, 12, 3, (0, 0))
+    S = args[0]
+    outs = []
+    for t in range(12):
+        o, S = kda.state_step(S, *(x[:, t] for x in args[1:]))
+        outs.append(o)
+    _against_numpy(jnp.stack(outs, axis=1), S, args)
+    # a row with g 0 and beta 0 (a pad, an idle slot) moves nothing, bit for bit
+    _, same = kda.state_step(S, args[1][:, 0], args[2][:, 0], args[3][:, 0],
+                             jnp.zeros_like(args[4][:, 0]), jnp.zeros_like(args[5][:, 0]))
+    np.testing.assert_array_equal(same, S)
+
+
+@pytest.mark.parametrize("T_,pads", [(64, (0, 5)), (128, (37, 0)), (192, (0, 130)), (256, (63, 200)),
+                                     (200, (0, 11))])
+def test_chunk_states_equal_the_recurrence(T_, pads):
+    """Sub-chunks of 64 rows, a non-zero incoming state, left pads that span
+    a whole sub-chunk and part of one; 200 is no multiple of the sub-chunk."""
+    args = _bthd(np.random.default_rng(T_), 2, T_, 3, pads)
+    o, S = jax.jit(kda.chunk_states)(*args)
+    _against_numpy(o, S, args)
+
+
+@pytest.mark.parametrize("cuts,pad", [((150,), 0), ((64, 150), 0), ((7, 8, 9, 150), 0),
+                                      ((64, 150), 21), ((1, 2, 3, 150), 2)])
+def test_conv_state_carries_across_chunk_boundaries(cuts, pad):
+    """The last three pre-convolution rows are the state: any split of a row
+    gives the whole row's convolution, with the first chunk left-padded."""
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.normal(size=(2, 150, 12)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(12, 4)), jnp.float32)
+    want, _ = kda.short_conv(x, w, None, None)
+    np.testing.assert_allclose(want[:, 3], jax.nn.silu(jnp.einsum("bkc,ck->bc", x[:, :4], w)), atol=1e-6)
+    state, lo, outs = None, 0, []
+    for i, hi in enumerate(cuts):
+        part, valid = x[:, lo:hi], None
+        if i == 0 and pad:
+            part = jnp.concatenate([jnp.full((2, pad, 12), 9.0), part], axis=1)  # junk under the pads
+            valid = jnp.arange(part.shape[1])[None, :] >= jnp.asarray([pad, pad])[:, None]
+        y, state = kda.short_conv(part, w, state, valid)
+        outs.append(y[:, pad if i == 0 else 0:])
+        lo = hi
+    np.testing.assert_allclose(jnp.concatenate(outs, axis=1), want, atol=1e-6)
+    # an idle row of a decode step shifts nothing
+    _, kept = kda.short_conv(x[:, :1], w, state, jnp.asarray([[True], [False]]))
+    np.testing.assert_array_equal(kept[1], state[1])
+    assert float(jnp.abs(kept[0] - state[0]).max()) > 0
+
+
+def test_decay_gate_is_bounded_below():
+    f = jnp.asarray(np.random.default_rng(0).normal(size=(50, 4, 16)) * 30, jnp.float32)
+    g = kda.gate(f, jnp.zeros((4,)), jnp.zeros((4, 16)), -5.0)
+    assert float(g.min()) >= -5.0 and float(g.max()) <= 0.0 and float(g.min()) < -4.9
+
+
+def test_absorbed_latent_attention_equals_expanded_heads():
+    """q^ = q_nope Wkb^T against the cached latent and o = (sum p c) Wvb are
+    the expanded heads' scores and values (ops/mla.py); RoPE pairs lanes (2i, 2i+1)."""
+    rng = np.random.default_rng(3)
+    H, nope, rot, rank, dv, S = 4, 16, 8, 32, 12, 20
+    q_nope, q_rope = rng.normal(size=(1, 1, H, nope)), rng.normal(size=(1, 1, H, rot))
+    c, kr = rng.normal(size=(S, rank)), rng.normal(size=(S, rot))
+    kv_b = rng.normal(size=(rank, H * (nope + dv))) * 0.2
+    wkb, wvb = mla.split_kv_b(jnp.asarray(kv_b), H, nope)
+    k_nope = np.einsum("sc,chn->shn", c, np.asarray(wkb))
+    v = np.einsum("sc,chv->shv", c, np.asarray(wvb))
+    scores = (np.einsum("hn,shn->hs", q_nope[0, 0], k_nope) + q_rope[0, 0] @ kr.T) / np.sqrt(nope + rot)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    want = np.einsum("hs,shv->hv", p, v)
+    q_lat = np.concatenate([np.asarray(mla.absorb_query(jnp.asarray(q_nope), wkb)), q_rope], axis=-1)
+    rows = np.concatenate([c, kr], axis=-1)
+    s2 = np.einsum("hc,sc->hs", q_lat[0, 0], rows) / np.sqrt(nope + rot)
+    np.testing.assert_allclose(s2, scores, atol=1e-5)  # the absorbed product is float32
+    o_lat = np.einsum("hs,sc->hc", p, c)[None, None]
+    np.testing.assert_allclose(mla.expand_value(jnp.asarray(o_lat), wvb)[0, 0], want, atol=1e-5)
+    x = jnp.asarray(rng.normal(size=(1, 3, 2, rot)), jnp.float32)
+    ang = jnp.asarray(rng.uniform(size=(1, 3, rot // 2)), jnp.float32)
+    got = mla.rope_interleaved(x, jnp.cos(ang), jnp.sin(ang))
+    np.testing.assert_allclose(got[..., 0::2], x[..., 0::2] * jnp.cos(ang)[:, :, None]
+                               - x[..., 1::2] * jnp.sin(ang)[:, :, None], atol=1e-6)
+
+
+# ---------------------------------------------------------------- routing
+
+@pytest.mark.parametrize("n_group,topk_group,top_k", [(1, 1, 2), (4, 2, 2), (8, 4, 4), (8, 1, 2), (2, 2, 3)])
+def test_group_limited_route_equals_a_plain_loop(n_group, topk_group, top_k):
+    rng = np.random.default_rng(n_group * 10 + topk_group)
+    x = jnp.asarray(rng.normal(size=(60, 32)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(32, 16)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=(16,)) * 0.1, jnp.float32)
+    idx, w = moe.route(x, router, bias, top_k=top_k, normalize=True, scaling=2.5,
+                       n_group=n_group, topk_group=topk_group)
+    with jax.default_matmul_precision("highest"):
+        s = jax.nn.sigmoid(x @ router)
+    want = ref.choose(s, bias, {"n_group": n_group, "topk_group": topk_group, "experts_per_token": top_k})
+    assert np.array_equal(np.sort(np.asarray(idx), -1), np.sort(np.asarray(want), -1))
+    np.testing.assert_allclose(jnp.sum(w, -1), 2.5, atol=1e-5)
+    if n_group > 1:  # every chosen expert lies in one of at most topk_group groups
+        groups = np.asarray(idx) // (16 // n_group)
+        assert max(len(set(row)) for row in groups) <= topk_group
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = get_config("debug-ling")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, T), 0, cfg.vocab_size)
+    return cfg, dataclasses.asdict(cfg), params, tokens
+
+
+def test_eight_shares_and_one_shared_expert_add_up_to_the_uncut_layer(model):
+    """16 experts in 8 groups of 2, one group a share (``first_held`` 0, 2, ...,
+    14): the parts the eight shares give, with the shared expert counted once,
+    are the uncut reference's layer."""
+    cfg, mc, _, _ = model
+    cfg = dataclasses.replace(cfg, n_group=8, topk_group=4, experts_per_token=4, experts_held=2)
+    mc = dict(mc, n_group=8, topk_group=4, experts_per_token=4, experts_held=2)
+    whole = dataclasses.replace(cfg, experts_held=cfg.experts_total)
+    lp = jax.tree_util.tree_map(lambda a: a[1], init_params(whole, jax.random.PRNGKey(5))["layers"]["run1"])
+    h = jax.random.normal(jax.random.PRNGKey(6), (40, cfg.hidden_size), jnp.float32)
+    uncut = ref.expert_ffn(h, lp, dict(mc, experts_held=cfg.experts_total), "f32") - h
+    normed = ref.rms_norm(h, lp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
+    total, pairs, rows_here = ref.swiglu(normed, lp["shared_expert"], "f32"), 0, 0
+    for first in range(0, cfg.experts_total, cfg.experts_held):
+        held = dict(lp, experts=jax.tree_util.tree_map(lambda a: a[first:first + 2], lp["experts"]))
+        part, stats = moe.expert_layer(
+            normed, None, held, experts_total=cfg.experts_total, experts_held=2, first_held=first,
+            top_k=4, normalize=True, scaling=cfg.routed_scaling_factor, n_group=8, topk_group=4)
+        total, pairs, rows_here = total + part, pairs + int(stats[0]), rows_here + int(stats[4])
+        assert int(stats[5]) == 40
+        one = ref.expert_ffn(h, held, dict(mc, first_held=first, no_shared_expert=True), "f32") - h
+        np.testing.assert_allclose(part, one, atol=TOL)
+    assert pairs == 40 * 4  # every pair is some share's
+    assert 40 * 2 <= rows_here <= 40 * 4  # a row reaches 2 to 4 of the 8 shares
+    np.testing.assert_allclose(total, uncut, atol=TOL)
+
+
+# ------------------------------------------------------ the whole forward
+
+def _ref_logits(mc, params, tokens, **kw):
+    return jnp.stack([ref.sequence_logits(params, mc, [int(t) for t in row],
+                                          list(range(len(row))), **kw)
+                      for row in np.asarray(tokens)])
+
+
+@pytest.fixture(scope="module")
+def want(model):
+    _, mc, params, tokens = model
+    return _ref_logits(mc, params, tokens)
+
+
+def _positions(lo, hi, batch=2):
+    return jnp.broadcast_to(jnp.arange(lo, hi, dtype=jnp.int32)[None], (batch, hi - lo))
+
+
+@jax.jit
+def _step(params, tokens, cache, positions, mask=None):
+    return forward(params, tokens, get_config("debug-ling"), cache=cache,
+                   positions=positions, attention_mask=mask)
+
+
+def test_runs_name_their_mixers(model):
+    cfg = model[0]
+    runs = layer_runs(cfg)
+    assert [(r.mixer.name, r.ffn, r.count, r.kind_start) for r in runs] == [
+        ("kda", "dense", 1, 0), ("kda", "experts", 2, 1), ("mla", "experts", 1, 0),
+        ("kda", "experts", 1, 3)]
+    assert ref.runs_of(model[1]) == [(r.mixer.name, r.ffn, r.count) for r in runs]
+    kinds = mixer_kinds(cfg)
+    assert kinds["mla"].pools() == {"k_mla": 32 + 8} and kinds["kda"].pools() == {}
+    assert kinds["kda"].states(cfg)["state_kda"] == ((4, 16, 16), "float32")
+
+
+def test_full_forward_equals_reference(model, want):
+    cfg, _, params, tokens = model
+    got, cache = forward(params, tokens, cfg)
+    assert cache is None
+    np.testing.assert_allclose(got, want, atol=TOL)
+
+
+def test_dense_cache_prefill_then_decode_equals_reference(model, want):
+    cfg, _, params, tokens = model
+    cache = init_cache(cfg, 2, 192, dtype=jnp.float32, per_slot=True)
+    assert cache["k_mla"].shape == (1, 2, 192, 40) and "v_mla" not in cache
+    assert cache["state_kda"].shape == (4, 2, 4, 16, 16) and cache["state_kda"].dtype == jnp.float32
+    assert cache["state_kda_conv"].shape == (4, 2, 3, 3 * 4 * 16)
+    assert kv_leaf_keys(cache) == ["k_mla"]
+    assert state_leaf_keys(cache) == ["state_kda", "state_kda_conv"]
+    out, cache = _step(params, tokens[:, :130], cache, _positions(0, 130))
+    outs = [out]
+    for t in range(130, T):
+        out, cache = _step(params, tokens[:, t:t + 1], cache, _positions(t, t + 1))
+        outs.append(out)
+    np.testing.assert_allclose(jnp.concatenate(outs, axis=1), want, atol=TOL)
+
+
+@pytest.mark.parametrize("block_size,chunks", [
+    (8, ((0, 64), (64, 130))),          # a sub-chunk whole, then 66 rows
+    (16, ((0, 130),)),                  # three sub-chunks in one program
+    (4, ((0, 3), (3, 70), (70, 130))),  # a chunk shorter than the convolution
+])
+def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, block_size, chunks):
+    cfg, _, params, tokens = model
+    nbps = 192 // block_size
+    cache = init_paged_cache(cfg, 2, 2 * nbps + 3, block_size, nbps, dtype=jnp.float32)
+    cache["block_tables"] = jnp.asarray(
+        np.stack([np.arange(nbps) + nbps, np.arange(nbps)]), jnp.int32)
+    # what an earlier request left in the slots: a cursor at 0 reads it as zero
+    cache["state_kda"] = cache["state_kda"] + 3.0
+    cache["state_kda_conv"] = cache["state_kda_conv"] - 2.0
+    outs = []
+    for lo, hi in chunks:
+        out, cache = _step(params, tokens[:, lo:hi], cache, _positions(lo, hi))
+        outs.append(out)
+    for t in range(130, T):
+        out, cache = _step(params, tokens[:, t:t + 1], cache, _positions(t, t + 1))
+        outs.append(out)
+    np.testing.assert_allclose(jnp.concatenate(outs, axis=1), want, atol=TOL)
+
+
+def test_left_pads_and_idle_rows_leave_the_state_alone(model, want):
+    """Pads lie at a row's left and move neither state; a decode step whose
+    row is idle (mask 0) leaves that slot's state bit for bit."""
+    cfg, _, params, tokens = model
+    pad = 14
+    cache = init_paged_cache(cfg, 2, 60, 8, 24, dtype=jnp.float32)
+    cache["block_tables"] = jnp.asarray(np.arange(48).reshape(2, 24), jnp.int32)
+    ids = jnp.concatenate([jnp.full((2, pad), 7, tokens.dtype), tokens[:, :130]], axis=1)
+    mask = jnp.concatenate([jnp.zeros((2, pad), jnp.int32), jnp.ones((2, 130), jnp.int32)], axis=1)
+    pos = jnp.concatenate([jnp.zeros((2, pad), jnp.int32), _positions(0, 130)], axis=1)
+    out, cache = _step(params, ids, cache, pos, mask)
+    np.testing.assert_allclose(out[:, pad:], want[:, :130], atol=TOL)
+    before = {k: np.asarray(cache[k]) for k in state_leaf_keys(cache)}
+    idle = jnp.asarray([[1], [0]], jnp.int32)
+    out, cache = _step(params, tokens[:, 130:131], cache, _positions(130, 131), idle)
+    np.testing.assert_allclose(out[0], want[0, 130:131], atol=TOL)
+    for key, was in before.items():
+        np.testing.assert_array_equal(np.asarray(cache[key])[:, 1], was[:, 1])
+        assert np.abs(np.asarray(cache[key])[:, 0] - was[:, 0]).max() > 0
+
+
+def test_extract_insert_moves_a_slots_state_with_its_rows(model):
+    cfg, _, params, tokens = model
+    cache = init_paged_cache(cfg, 2, 60, 8, 24, dtype=jnp.float32)
+    cache["block_tables"] = jnp.asarray(np.arange(48).reshape(2, 24), jnp.int32)
+    _, cache = _step(params, tokens[:, :50], cache, _positions(0, 50))
+    row = paged_extract_row(cache, 1, 50, width=56)
+    assert row["state_kda"].shape == (4, 1, 4, 16, 16) and row["k_mla"].shape == (1, 1, 56, 40)
+    fresh = init_paged_cache(cfg, 2, 60, 8, 24, dtype=jnp.float32)
+    table = jnp.asarray(list(range(20, 27)) + [-1] * 17, jnp.int32)
+    fresh = paged_insert_row(fresh, 0, table, row)
+    fresh["len"] = fresh["len"].at[0].set(50)
+    np.testing.assert_array_equal(fresh["state_kda"][:, 0], cache["state_kda"][:, 1])
+    def one(c, s):  # the cache as one slot sees it: pools whole, its own cursor, table and state
+        return {k: (v[s:s + 1] if k in ("len", "block_tables") else
+                    v[:, s:s + 1] if k.startswith("state_") else v) for k, v in c.items()}
+
+    tok = tokens[1:2, 50:51]
+    a, _ = forward(params, tok, cfg, positions=_positions(50, 51, 1), cache=one(cache, 1))
+    b, _ = forward(params, tok, cfg, positions=_positions(50, 51, 1), cache=one(fresh, 0))
+    np.testing.assert_allclose(a, b, atol=1e-6)
+
+
+@pytest.mark.parametrize("name,change", [
+    ("one group", dict(n_group=1, topk_group=1)),
+    ("no shared expert", None),  # the program adds one where the weights have one
+    ("no lower bound", dict(kda_lower_bound=-1.0)),
+])
+def test_each_mechanism_matters(model, want, name, change):
+    cfg, _, params, tokens = model
+    if change is None:
+        params = dict(params, layers={
+            k: {n: v for n, v in run.items() if n != "shared_expert"}
+            for k, run in params["layers"].items()})
+    got, _ = forward(params, tokens[:1, :64], dataclasses.replace(cfg, **(change or {})))
+    assert float(jnp.abs(got - want[:1, :64]).max()) > 1e-3, name
+
+
+# ------------------------------------------------------------ the engine
+
+ENGINE = dict(slots=3, decode_chunk=4, kv_block_size=8, kv_blocks=96, max_seq_len=256, prefill_chunk=64)
+
+
+@pytest.fixture(scope="module")
+def engine(tmp_path_factory):
+    from datatunerx_tpu.serving.adapters import make_adapter_checkpoint
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+
+    d = tmp_path_factory.mktemp("ling_adapters")
+    adapters = {"ad0": make_adapter_checkpoint(
+        str(d / "ad0"), "preset:debug-ling", seed=10, rank=4, targets=("q_proj", "o_proj"))}
+    eng = BatchedEngine("preset:debug-ling", adapters=adapters, **ENGINE)
+    yield eng
+    eng.close()
+
+
+def _gaps(engine, prompt, req, name=""):
+    """How far each served token's logit lies below the reference's best, over
+    the request's own full forward (the benchmark's comparison)."""
+    mc = dataclasses.asdict(engine.cfg)
+    tokens = list(prompt) + list(req.tokens)
+    rows = list(range(len(prompt) - 1, len(tokens) - 1))
+    lora, scale = None, 0.0
+    if name:
+        stack, scales = engine.lora_stack
+        i = engine.adapter_ids[name]
+        lora = jax.tree_util.tree_map(lambda a: a[:, i], stack["layers"])
+        scale = float(scales[i])
+    logits = ref.sequence_logits(engine.params, mc, tokens, rows, lora, scale)
+    got = jnp.take_along_axis(logits, jnp.asarray(req.tokens)[:, None], axis=-1)[:, 0]
+    return np.asarray(jnp.max(logits, axis=-1) - got)
+
+
+def test_engine_serves_what_the_reference_puts_first(engine):
+    """Prefill in chunks of 64 (left-padded to a bucket), then decode through
+    the cache in steps of 4, six requests over three slots so that every slot
+    is used twice. The engine computes in bf16 and the reference in float32:
+    a served token may differ from the reference's first where two logits lie
+    within bf16's rounding of each other, so what is held is the GAP, as the
+    benchmark holds it: 0.05 at this width (the rehearsal cell's limit; the
+    sound engine reads under 0.03, a wrong state or a stale slot reads 0.2 and more)."""
+    assert engine.decode_path == "gather"
+    stack = engine.lora_stack[0]["layers"]
+    assert stack["run0"]["q_proj"]["b"].shape[-1] == 4 * 16           # a KDA run: H * d
+    assert stack["run2"]["q_proj"]["b"].shape[-1] == 4 * (16 + 8)     # the MLA run: H * (nope + rope)
+    assert "v_proj" not in stack["run2"] and "o_proj" in stack["run2"]
+    rng = np.random.default_rng(0)
+    work = []
+    for n, name in ((5, ""), (70, "ad0"), (130, ""), (33, "ad0"), (90, ""), (64, "ad0")):
+        prompt = rng.integers(10, 500, size=n).tolist()
+        work.append((prompt, name, engine.submit(prompt, max_new_tokens=12, adapter=name)))
+    for prompt, name, req in work:
+        assert req.done.wait(600) and req.error is None, req.error
+        gaps = _gaps(engine, prompt, req, name)
+        assert len(req.tokens) == 12 and gaps.max() < 0.05, (len(prompt), name, gaps)
+    stats = engine.moe_stats
+    assert stats["decode_layer_steps"] % 4 == 0 and stats["decode_layer_steps"] > 0
+    assert 0 < stats["decode_rows_here"] <= stats["decode_rows"]
+    assert stats["decode_local_rows"] >= stats["decode_rows_here"]
+    # four KDA layers x three slots x (4 heads x 16 x 16 float32 + 3 rows x 192 bf16)
+    assert engine.state_bytes() == 4 * 3 * (4 * 16 * 16 * 4 + 3 * 192 * 2)
+
+
+def test_a_used_slot_serves_a_new_request_as_a_fresh_engine_does(engine):
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+
+    prompt = list(range(100, 177))
+    first = engine.submit(prompt, max_new_tokens=9)
+    assert first.done.wait(600) and first.error is None
+    again = engine.submit(prompt, max_new_tokens=9)  # every slot has been used by now
+    assert again.done.wait(600) and again.error is None
+    fresh = BatchedEngine("preset:debug-ling", **dict(ENGINE, slots=1))
+    try:
+        new = fresh.submit(prompt, max_new_tokens=9)
+        assert new.done.wait(600) and new.error is None
+    finally:
+        fresh.close()
+    assert first.tokens == again.tokens == new.tokens
+
+
+def test_an_idle_slots_state_does_not_move(engine):
+    """One request decodes in one slot: the other slots' state leaves are, bit
+    for bit, what they were."""
+    for _ in range(100):
+        if not any(r is not None for r in engine._slot_req):
+            break
+        import time
+        time.sleep(0.05)
+    before = {k: np.asarray(engine._cache[k]) for k in state_leaf_keys(engine._cache)}
+    req = engine.submit(list(range(50, 90)), max_new_tokens=10)
+    assert req.done.wait(600) and req.error is None
+    after = {k: np.asarray(engine._cache[k]) for k in state_leaf_keys(engine._cache)}
+    moved = [s for s in range(3)
+             if any(not np.array_equal(after[k][:, s], before[k][:, s]) for k in before)]
+    assert len(moved) == 1, moved
+
+
+def test_metrics_name_the_state_and_the_new_counters(engine):
+    from datatunerx_tpu.obs.metrics import Registry, export_moe_stats
+
+    reg = Registry()
+    export_moe_stats(reg, engine)
+    text = reg.expose()
+    assert f"dtx_serving_state_bytes {float(engine.state_bytes())}" in text or \
+        f"dtx_serving_state_bytes {engine.state_bytes()}" in text
+    assert 'dtx_serving_moe_rows_here{phase="decode"}' in text
+    assert 'dtx_serving_moe_rows{phase="decode"}' in text
+
+
+@pytest.mark.parametrize("entry", ["prefix_cache", "spec_draft", "kv_overcommit", "export", "import",
+                                   "migration_wire", "kv_quant", "trainer", "swiglu_limit"])
+def test_what_needs_a_snapshot_of_state_refuses_by_name(model, engine, entry):
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+
+    cfg = model[0]
+    with pytest.raises(NotImplementedError, match="debug-ling"):
+        if entry == "prefix_cache":
+            BatchedEngine("preset:debug-ling", prefix_cache=4, **ENGINE)
+        elif entry == "spec_draft":
+            BatchedEngine("preset:debug-ling", spec_draft="take:2", **ENGINE)
+        elif entry == "kv_overcommit":
+            BatchedEngine("preset:debug-ling", kv_overcommit="on", **ENGINE)
+        elif entry == "export":
+            engine.export_sessions()
+        elif entry == "import":
+            engine.import_session({})
+        elif entry == "migration_wire":
+            from datatunerx_tpu.serving import migration as mig
+
+            mig.check_signature({}, cfg)
+        elif entry == "kv_quant":
+            init_cache(cfg, 1, 64, quantize="int8")
+        elif entry == "trainer":
+            from datatunerx_tpu.training.train_lib import TrainConfig, Trainer
+
+            Trainer(cfg, TrainConfig())
+        elif entry == "swiglu_limit":
+            dataclasses.replace(cfg, expert_swiglu_limits=(0, 0, 0, 0, 4))

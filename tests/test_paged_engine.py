@@ -17,7 +17,9 @@ from datatunerx_tpu.models.llama import forward, init_cache
 from datatunerx_tpu.ops.paged_attention import (
     BlockAllocator,
     BlockAllocatorError,
+    POS_SENTINEL,
     init_paged_cache,
+    paged_install_table,
 )
 from datatunerx_tpu.serving.batched_engine import BatchedEngine
 
@@ -103,6 +105,30 @@ def test_block_allocator_free_rejects_corruption():
         a.free(held)  # ...and replaying it is a double-free
     assert a.free_count == 4  # rejected frees changed nothing
     assert isinstance(BlockAllocatorError("x"), ValueError)
+
+
+@pytest.mark.parametrize("blocks", [[5], [7, 2, 9], [0, 1, 2, 3]])
+def test_install_table_is_the_three_eager_updates_in_one_program(blocks):
+    """Admission hands a slot its blocks through ONE jitted program whatever
+    their number (an eager scatter compiled a handful of small programs per
+    block count): the table row, the scrub of the blocks' recycled positions
+    and the rewound cursor are what the three eager updates gave; a block the
+    row does not name keeps its positions, as does the pool's last block,
+    which an unused column's -1 must not reach."""
+    from datatunerx_tpu.models import get_config
+
+    cache = init_paged_cache(get_config("debug"), 3, 12, 4, 4)
+    cache["pos"] = jnp.arange(48, dtype=jnp.int32).reshape(12, 4)
+    cache["len"] = jnp.asarray([9, 8, 7], jnp.int32)
+    row = np.full((4,), -1, np.int32)
+    row[: len(blocks)] = blocks
+    want_pos = cache["pos"].at[jnp.asarray(blocks)].set(POS_SENTINEL)
+    want_tables = cache["block_tables"].at[1].set(jnp.asarray(row))
+    out = jax.jit(paged_install_table)(cache, jnp.asarray(1, jnp.int32), jnp.asarray(row))
+    np.testing.assert_array_equal(out["pos"], want_pos)
+    np.testing.assert_array_equal(out["block_tables"], want_tables)
+    np.testing.assert_array_equal(out["len"], [9, 0, 7])
+    assert int(out["pos"][11, 0]) == 44 and set(out) == set(cache)
 
 
 # ------------------------------------------------------- model primitive
