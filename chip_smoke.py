@@ -675,6 +675,11 @@ def child_ling(rehearsal: bool) -> int:
                 f"engine built in {built:.0f}s, request and reference in "
                 f"{time.monotonic() - t0 - built:.0f}s, state_bytes {eng.state_bytes()} "
                 f"peak_bytes_in_use {peak}")
+        # what the expert layers of the cell's two programs run (ops/moe.py)
+        verdict("ling/cell/moe_kernel",
+                eng.moe_kernel == {"decode": ("dtx_moe_gmm", 16),
+                                   "prefill": ("dtx_moe_gmm", 32)},
+                json.dumps(eng.moe_kernel))
     finally:
         eng.close()
     return 0 if ok else 1
@@ -1013,6 +1018,37 @@ def child_kernels(rehearsal: bool) -> int:
             check(f"fused_sample_{mode} [S{S} V{V}]", run("kernel"),
                   run("xla"), atol=0, exact=True)
 
+    # ---- the expert layer's grouped matmul at the two sparse-expert cells'
+    # widths, rows of a decode step, a run's stacked weights and a traced
+    # layer: dtx_moe_gmm at the row tile the shapes give against ragged_dot
+    def moe_gmm():
+        from datatunerx_tpu.ops import moe
+
+        shapes = ({"mimo": (64, 8, 256, 16, 4096, 2048),
+                   "ling": (128, 8, 512, 64, 2560, 768)} if not rehearsal
+                  else {"debug": (16, 4, 32, 8, 256, 128)})
+        got, want = [], []
+        for rows, k, total, held, D, F in shapes.values():
+            name, tm = moe.grouped_matmul(rows, top_k=k, experts_total=total,
+                                          d=D, f=F)
+            assert name == "dtx_moe_gmm", (name, tm)
+            sizes = np.minimum(rng.poisson(rows * k / total, held), 3 * tm)
+            sizes[:: 5] = 0  # experts no row chose
+            real = int(sizes.sum())
+            args = (normal((rows * k, D)), jnp.asarray(sizes, jnp.int32),
+                    normal((2, held, D, F), scale=0.02),
+                    normal((2, held, D, F), scale=0.02),
+                    normal((2, held, F, D), scale=0.02), jnp.asarray(1, jnp.int32))
+            for out, row_tile in ((got, tm), (want, None)):
+                out.append(np.asarray(jax.jit(
+                    lambda xs, sizes, g, u, d, layer, row_tile=row_tile:
+                    moe.grouped_swiglu(xs, sizes, g, u, d, layer, row_tile)
+                )(*args)[:real], np.float32).ravel())
+        check("moe_gmm_vs_ragged_dot [" + " ".join(
+            f"{n}:{r * k}x{D}x{F}/E{held}" for n, (r, k, _, held, D, F)
+            in shapes.items()) + "]",
+            np.concatenate(got), np.concatenate(want), atol=2e-2, rtol=2e-2)
+
     # ---- one QLoRA train step, --quant_impl pallas against xla: the fused
     # kernels forward AND backward inside the real step program with remat
     def qlora_step():
@@ -1061,6 +1097,7 @@ def child_kernels(rehearsal: bool) -> int:
     for V in ((32000, 151936) if not rehearsal else (512,)):
         for S in sorted({SERVE_SLOTS, 16}):
             guarded(f"fused_sample [S{S} V{V}]", lambda: sampler(S, V))
+    guarded("moe_gmm", moe_gmm)
     if not rehearsal:  # interpret-mode QLoRA steps are slow and tier-1's job
         guarded("qlora_step", qlora_step)
 

@@ -381,6 +381,11 @@ def export_moe_stats(registry: Registry, engine) -> None:
         "dtx_serving_moe_rows",
         "Real rows the expert layers routed (pads and idle slots left out), "
         "summed over expert layers and steps, by phase.")
+    tile = registry.gauge(
+        "dtx_serving_moe_row_tile",
+        "Row tile of the grouped matmul the expert layers run, by phase and "
+        "kernel (dtx_moe_gmm: ours, from the rows a group expects; ragged_dot: "
+        "XLA's own tiling, stated as 0).")
     behind = registry.gauge(
         "dtx_serving_kv_behind_window_bytes",
         "Bytes of the window layers' KV pool held by blocks that no later "
@@ -389,7 +394,7 @@ def export_moe_stats(registry: Registry, engine) -> None:
         "dtx_serving_state_bytes",
         "Bytes of recurrent state resident for the linear-attention layers "
         "(constant per slot, whatever the slots' contexts).")
-    for m in (rows, hit, most, steps, here, seen, behind, state):
+    for m in (rows, hit, most, steps, here, seen, tile, behind, state):
         m.clear()
     stats = getattr(engine, "moe_stats", None) or {}
     for phase in ("decode", "prefill"):
@@ -401,6 +406,8 @@ def export_moe_stats(registry: Registry, engine) -> None:
             steps.set(stats[f"{phase}_layer_steps"], label)
             here.set(stats.get(f"{phase}_rows_here", 0), label)
             seen.set(stats.get(f"{phase}_rows", 0), label)
+    for phase, (kernel, tm) in (getattr(engine, "moe_kernel", None) or {}).items():
+        tile.set(tm or 0, {"phase": phase, "kernel": kernel})
     window_fn = getattr(engine, "kv_window_stats", None)
     window = window_fn() if callable(window_fn) else None
     behind.set(window["behind_bytes"] if window else 0)

@@ -4,12 +4,19 @@ An expert layer is told how many experts the model has, how many of them are
 held here and which (``first_held`` on). It routes every token over ALL of
 them, as every chip of the deployment does, and computes the part of the
 result that its own experts give: the (token, expert) pairs whose expert is
-held are sorted by expert and multiplied group by group
-(``jax.lax.ragged_dot``: XLA lowers it to a Mosaic grouped-matmul kernel on
-the TPU, which visits only the row tiles a group really has, and to a plain
-loop on the CPU); pairs of absent experts add nothing. With every expert held
-it is the whole layer. Nothing here stands in for the absent chips or for the
-exchange between them.
+held are sorted by expert and multiplied group by group; pairs of absent
+experts add nothing. With every expert held it is the whole layer. Nothing
+here stands in for the absent chips or for the exchange between them.
+
+The grouped matmul is chosen from static shapes (``grouped_matmul``). Where a
+group expects a few rows (serving: a held expert has a row or two a decode
+step, a handful in a prefill chunk) it is ``ops/pallas_moe.py``'s kernel
+``dtx_moe_gmm`` at a row tile of 16 to 256 rows, which streams each hit
+expert's weights once. Otherwise it is ``jax.lax.ragged_dot``, the only path
+with a VJP: XLA lowers it to a Mosaic kernel on the TPU (a plain loop on the
+CPU) whose row tile is 512, so every group with a row in a tile multiplies
+its weights by all 512 rows and masks the others away: right where groups are
+long, MXU-bound on masked rows where they are not.
 
 Shapes are static: the sorted buffer has the worst case ``N * k`` rows, there
 is no capacity factor and no token is ever dropped.
@@ -19,6 +26,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from datatunerx_tpu.ops import pallas_moe
 
 # what one expert layer counts of one step: (token, expert) pairs routed to
 # held experts, held experts that got a row, most rows on one expert, 1
@@ -76,18 +85,31 @@ def sort_pairs(idx: jnp.ndarray, valid: jnp.ndarray | None, *,
     return here, order, sizes
 
 
+def grouped_matmul(rows: int, *, top_k: int, experts_total: int, d: int,
+                   f: int) -> tuple:
+    """(name, row tile) of the grouped matmul an expert layer runs on ``rows``
+    rows: ``pallas_moe.row_tile`` of the ``rows * top_k`` sorted pairs decides,
+    from shapes alone. ``ragged_dot``'s row tile is XLA's to choose: None."""
+    tm = pallas_moe.row_tile(rows * top_k, experts_total, d, f)
+    return (pallas_moe.KERNEL, tm) if tm else ("ragged_dot", None)
+
+
 def grouped_swiglu(xs: jnp.ndarray, sizes: jnp.ndarray, gate: jnp.ndarray,
-                   up: jnp.ndarray, down: jnp.ndarray, layer=None) -> jnp.ndarray:
+                   up: jnp.ndarray, down: jnp.ndarray, layer=None,
+                   row_tile: int | None = None) -> jnp.ndarray:
     """SwiGLU of each group's rows with its own expert. xs [M, D] sorted by
     group; gate/up [E, D, F]; down [E, F, D]. Rows past ``sum(sizes)`` belong
     to no group; what comes out for them is not used.
 
     With ``layer`` the weights are a whole run's, stacked ``[n, E, ...]``, and
-    ``layer`` says whose turn it is: the stack is read as ``n * E`` groups of
-    which only this layer's have rows. The grouped matmul is a kernel of its
+    ``layer`` says whose turn it is. Either grouped matmul is a kernel of its
     own on the TPU and takes its operands whole, so a layer's experts sliced
     out of the stack first would be copied (1.2 GB a layer at 16 experts of
-    4096 x 2048, every step); an empty group costs nothing."""
+    4096 x 2048, every step). The Pallas kernel (``row_tile`` rows a tile)
+    indexes the stack by ``layer``; ``ragged_dot`` reads it as ``n * E``
+    groups of which only this layer's have rows."""
+    if row_tile is not None:
+        return pallas_moe.grouped_swiglu(xs, sizes, gate, up, down, layer, row_tile)
     if layer is not None:
         n, held = gate.shape[0], gate.shape[1]
         gate, up, down = (w.reshape((n * held,) + w.shape[2:]) for w in (gate, up, down))
@@ -120,8 +142,10 @@ def expert_layer(x: jnp.ndarray, valid: jnp.ndarray | None, p: dict, *,
         xs = x[order // top_k]
     with jax.named_scope("dtx.moe_experts"):
         ex = p["experts"]
+        _, tm = grouped_matmul(N, top_k=top_k, experts_total=experts_total, d=D,
+                               f=ex["gate_proj"].shape[-1])
         out = grouped_swiglu(xs, sizes, ex["gate_proj"], ex["up_proj"],
-                             ex["down_proj"], layer)
+                             ex["down_proj"], layer, tm)
     with jax.named_scope("dtx.moe_combine"):
         # back to pair order, then each token's held pairs weighted and added
         place = jnp.zeros((N * top_k,), jnp.int32).at[order].set(

@@ -891,6 +891,20 @@ class BatchedEngine:
         # count stays bounded (chunk lengths ∈ multiples of DECODE_BUCKET)
         self.prefill_chunk = max(
             DECODE_BUCKET, -(-int(prefill_chunk) // DECODE_BUCKET) * DECODE_BUCKET)
+        # which grouped matmul the expert layers of the two serving programs
+        # run and at what row tile (dtx_serving_moe_row_tile): static per
+        # program, from shapes alone (prefill: a whole chunk's; a prompt's
+        # shorter last chunk may get a smaller tile); empty without experts
+        self.moe_kernel = {}
+        if "experts" in (self.cfg.ffn_types or ()):
+            from datatunerx_tpu.ops.moe import grouped_matmul
+
+            self.moe_kernel = {
+                phase: grouped_matmul(
+                    rows, top_k=self.cfg.experts_per_token,
+                    experts_total=self.cfg.experts_total, d=self.cfg.hidden_size,
+                    f=self.cfg.expert_intermediate_size)
+                for phase, rows in (("decode", slots), ("prefill", self.prefill_chunk))}
         # the budget is a HARD bound (prefill chunks are clamped to the
         # remaining budget each tick), so round it up to the bucket quantum —
         # a sub-bucket budget could never admit a chunk and would starve
@@ -1125,6 +1139,7 @@ class BatchedEngine:
             "slots": slots,
             "kv_block_size": self.block_size,
             "prefill_chunk": self.prefill_chunk,
+            "moe_kernel": self.moe_kernel,
         }, sort_keys=True), file=sys.stderr, flush=True)
 
         self._thread = threading.Thread(target=self._scheduler, daemon=True)

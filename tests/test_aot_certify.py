@@ -210,13 +210,17 @@ def in_place_doc():
     return _run_probe(_IN_PLACE_PROBE, timeout=600)
 
 
-# One expert layer at the published widths of the benchmark's sparse-expert
-# configuration (16 held experts of 4096 x 2048, 64 slots x top-8 = 512 rows a
-# decode step, 2,048 rows a prefill chunk): XLA's TPU compiler must turn each
-# ``jax.lax.ragged_dot`` into a Mosaic grouped-matmul kernel (a ``ragged-dot``
-# custom call), which ``benchmarks/moe_readers.py`` finds by that name.
+# One expert layer at the published widths of the benchmark's two sparse-expert
+# configurations, at the rows of their decode steps and of a 256-token prefill
+# chunk, with a run's stacked weights and ``layer=`` as ``models/hybrid.py``
+# passes them: the grouped matmuls must be the ``dtx_moe_gmm`` Mosaic kernels
+# (gate, up and the activation in one, down in the other: the device trace
+# books them by their scope and shows them by that name), nothing may copy an
+# expert leaf, and a shape the rule leaves to ``jax.lax.ragged_dot`` (every
+# expert held, long rows) must still become XLA's own Mosaic kernels.
 _EXPERTS_PROBE = r"""
-import json, os
+import json, os, re
+os.environ["DTX_PALLAS_INTERPRET"] = "0"
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
@@ -230,31 +234,60 @@ from datatunerx_tpu.ops import moe
 sh = SingleDeviceSharding(topologies.get_topology_desc(
     platform="tpu", topology_name="v5e:2x2").devices[0])
 sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
-D, F, E, Eh, k = 4096, 2048, 256, 16, 8
-p = {"router": {"kernel": sds((D, E), jnp.bfloat16)},
-     "e_score_correction_bias": sds((E,), jnp.bfloat16),
-     "experts": {"gate_proj": sds((Eh, D, F), jnp.bfloat16),
-                 "up_proj": sds((Eh, D, F), jnp.bfloat16),
-                 "down_proj": sds((Eh, F, D), jnp.bfloat16)}}
+k = 8
+# name: rows, D, F, experts in all, held, layers of the run, groups, groups kept
+CASES = {"mimo/decode": (64, 4096, 2048, 256, 16, 5, 1, 1),
+         "mimo/chunk": (256, 4096, 2048, 256, 16, 5, 1, 1),
+         "ling/decode": (128, 2560, 768, 512, 64, 5, 8, 4),
+         "ling/chunk": (256, 2560, 768, 512, 64, 5, 8, 4),
+         "all_held/long": (1024, 4096, 2048, 8, 8, 2, 1, 1)}
 out = {}
-for rows in (64, 256):
-    fn = lambda x, valid, p: moe.expert_layer(
+for name, (rows, D, F, E, Eh, n, n_group, topk_group) in CASES.items():
+    p = {"router": {"kernel": sds((D, E), jnp.bfloat16)},
+         "e_score_correction_bias": sds((E,), jnp.bfloat16),
+         "experts": {"gate_proj": sds((n, Eh, D, F), jnp.bfloat16),
+                     "up_proj": sds((n, Eh, D, F), jnp.bfloat16),
+                     "down_proj": sds((n, Eh, F, D), jnp.bfloat16)}}
+    fn = lambda x, valid, p, layer: moe.expert_layer(
         x, valid, p, experts_total=E, experts_held=Eh, first_held=0, top_k=k,
-        normalize=True, scaling=1.0)
+        normalize=True, scaling=1.0, layer=layer, n_group=n_group,
+        topk_group=topk_group)
     text = jax.jit(fn).lower(sds((rows, D), jnp.bfloat16), sds((rows,), jnp.bool_),
-                             p).compile().as_text()
-    out[str(rows)] = {"ragged": text.count("%ragged-dot-none"),
-                      "mosaic": text.count('custom_call_target="tpu_custom_call"')}
+                             p, sds((), jnp.int32)).compile().as_text()
+    leaf = r"bf16\[(%d,)?%d,(%d,%d|%d,%d)\]" % (n, Eh, D, F, F, D)
+    out[name] = {"chosen": list(moe.grouped_matmul(rows, top_k=k, experts_total=E, d=D, f=F)),
+                 "gmm": len(re.findall(r"%dtx_moe_gmm[.\w]* = ", text)),
+                 "in_scope": len(re.findall(r"%dtx_moe_gmm[.\w]* = .*dtx\.moe_experts", text)),
+                 "ragged": text.count("%ragged-dot-none"),
+                 "ragged_tiling": sorted(set(re.findall(r'ragged_dot_tiling="([\d,]+)"', text))),
+                 "mosaic": text.count('custom_call_target="tpu_custom_call"'),
+                 "leaf_copies": len(re.findall(r" = " + leaf + r"[^ ]* copy\(", text))}
 print(json.dumps(out))
 """
 
 
-def test_expert_layer_compiles_to_grouped_matmul_kernels_for_v5e():
+@pytest.fixture(scope="module")
+def experts_doc():
     pytest.importorskip("libtpu")  # the TPU compiler; absent from jax[cpu]
-    doc = _run_probe(_EXPERTS_PROBE, timeout=600)
-    for rows, seen in doc.items():
-        # gate, up and down: three grouped matmuls, each a Mosaic kernel
-        assert seen["ragged"] >= 3 and seen["mosaic"] >= 3, (rows, seen)
+    return _run_probe(_EXPERTS_PROBE, timeout=600)
+
+
+@pytest.mark.parametrize("case,tm", [("mimo/decode", 16), ("mimo/chunk", 64),
+                                     ("ling/decode", 16), ("ling/chunk", 32)])
+def test_expert_layer_compiles_to_grouped_matmul_kernels_for_v5e(case, tm, experts_doc):
+    seen = experts_doc[case]
+    assert seen["chosen"] == ["dtx_moe_gmm", tm], seen
+    # two named kernels under the experts' scope, and no ragged-dot beside them
+    assert seen["gmm"] == seen["in_scope"] == seen["mosaic"] == 2, seen
+    assert seen["ragged"] == 0 and seen["leaf_copies"] == 0, seen
+
+
+def test_long_groups_stay_on_xlas_grouped_matmul_for_v5e(experts_doc):
+    seen = experts_doc["all_held/long"]
+    assert seen["chosen"] == ["ragged_dot", None], seen
+    # gate, up and down: three ragged-dot Mosaic kernels at XLA's own tiling
+    assert seen["gmm"] == 0 and seen["ragged"] >= 3 and seen["mosaic"] >= 3, seen
+    assert seen["ragged_tiling"] and seen["leaf_copies"] == 0, seen
 
 
 # The training kernels at the shape the benchmark's cell mistral-train-lora
@@ -310,10 +343,11 @@ def test_flash_kernels_compile_for_v5e_at_the_training_cell_shape():
 # + dense, KDA + experts, MLA + experts), the recurrent state [6, 128, 32, 128,
 # 128] float32 donated with the cache. The chip's compiler must take them, fit
 # them in one chip's 16 GB beside their arguments, alias the state leaves to
-# the result (nothing copies 1.6 GB of state), and keep the expert layers'
-# grouped matmuls as ``ragged-dot`` Mosaic kernels.
+# the result (nothing copies 1.6 GB of state), and run the expert layers'
+# grouped matmuls as ``dtx_moe_gmm`` Mosaic kernels.
 _LING_PROBE = r"""
 import json, os, sys
+os.environ["DTX_PALLAS_INTERPRET"] = "0"
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.environ["DTX_REPO"], "benchmarks"))
@@ -363,6 +397,7 @@ for name, lower in cases.items():
     out[name] = {"live": m.argument_size_in_bytes + m.temp_size_in_bytes
                  + m.output_size_in_bytes - m.alias_size_in_bytes,
                  "alias": m.alias_size_in_bytes, "ragged": text.count("%ragged-dot"),
+                 "gmm": text.count("%dtx_moe_gmm"),
                  "scopes": [s for s in ("dtx.kda_conv", "dtx.kda_state", "dtx.kda_out",
                                         "dtx.mla_absorb", "dtx.moe_shared") if s in text]}
 print(json.dumps(out))
@@ -381,7 +416,8 @@ def test_ling_cell_programs_compile_for_v5e_at_published_widths(program, ling_do
     assert ling_doc["state_bytes"] == 6 * 128 * (32 * 128 * 128 * 4 + 3 * 12288 * 2)
     assert got["live"] < 13e9, got  # one chip holds 16 GB; the engine keeps logits and adapters beside it
     assert got["alias"] >= ling_doc["state_bytes"], got  # the donated state is written in place
-    assert got["ragged"] >= 6, got  # gate, up and down in two runs of expert layers
+    # gate with up, and down, in two runs of expert layers; no ragged-dot left
+    assert got["gmm"] >= 4 and got["ragged"] == 0, got
     assert got["scopes"] == ["dtx.kda_conv", "dtx.kda_state", "dtx.kda_out",
                              "dtx.mla_absorb", "dtx.moe_shared"], got
 
