@@ -415,6 +415,30 @@ def export_moe_stats(registry: Registry, engine) -> None:
     state.set(state_fn() if callable(state_fn) else 0)
 
 
+def export_sched_stats(registry: Registry, engine) -> None:
+    """The scheduler's phase table (``BatchedEngine.sched_stats``: host
+    seconds and a count per ``dtx_engine_*`` span, kept with no profiler
+    open), restated at scrape time. ``wait_empty`` over ``tick`` is the
+    share of its time the replica had nothing asked of it; ``wait_blocked``
+    is time it had work it could not run; the rest of a tick's seconds is
+    the host at work or waiting on the device (``decode_sync``)."""
+    seconds = registry.counter(
+        "dtx_serving_sched_seconds_total",
+        "Host seconds the engine's scheduler spent in each phase of its "
+        "tick (phase = the dtx_engine_* profiler span of the same name; "
+        "tick is the whole pass, nested phases are inside their parent's).")
+    count = registry.counter(
+        "dtx_serving_sched_phases_total",
+        "Times the engine's scheduler entered each phase of its tick.")
+    seconds.clear()
+    count.clear()
+    for name, (secs, n) in sorted(
+            dict(getattr(engine, "sched_stats", None) or {}).items()):
+        label = {"phase": name.removeprefix("dtx_engine_")}
+        seconds.set(secs, label)
+        count.set(n, label)
+
+
 # ------------------------------------------------------------ process plumbing
 
 _PROCESS_START = time.monotonic()

@@ -305,6 +305,11 @@ class Request:
         self.timeline: List[tuple] = []  # (perf stamp, event, detail dict)
         self.first_token_ts: Optional[float] = None
         self.last_token_ts: Optional[float] = None
+        # the scheduler tick ``submit`` saw, and what an admission attempt
+        # of this request's own last failed for ("blocks" / "adapter"):
+        # the admit mark's waited_ticks / waited_for
+        self.tick_submit = 0
+        self.waited_for: Optional[str] = None
 
     def mark(self, event: str, **detail):
         self.timeline.append((time.perf_counter(), event, detail))
@@ -323,6 +328,31 @@ class Request:
         self.error = error
         self.stream.put(None)
         self.done.set()
+
+
+class _Phase:
+    """One phase of the scheduler, recorded twice from one place: the
+    ``jax.profiler.TraceAnnotation`` of that name (a flag test with no
+    profiler session open) and the phase's host seconds and a count in the
+    engine's always-on table ``sched_stats[name] = [seconds, count]``
+    (``dtx_serving_sched_seconds_total`` / ``_phases_total``). Both readers
+    get the same boundaries, so the trace and the counters cannot disagree."""
+
+    __slots__ = ("_row", "_span", "_t0")
+
+    def __init__(self, stats: Dict[str, list], name: str, detail: dict):
+        self._row = stats.get(name) or stats.setdefault(name, [0.0, 0])
+        self._span = jax.profiler.TraceAnnotation(name, **detail)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._row[0] += time.perf_counter() - self._t0
+        self._row[1] += 1
+        return self._span.__exit__(*exc)
 
 
 def _pad_row(row: Dict, width: int) -> Dict:
@@ -1045,6 +1075,15 @@ class BatchedEngine:
         self._waiting_front: "collections.deque[Request]" = collections.deque()
         self._last_adapter_wait: Optional[str] = None  # wait-trace dedupe
         self._admit_wait_reason = ""  # why the last _admit returned False
+        # the scheduler's pass counter (dtx_engine_tick's ``tick=``, every
+        # Request.mark's ``tick``) and the last pass that ended on a head
+        # the FIFO order holds everyone behind, with what the head lacked
+        self._tick_no = 0
+        self._blocked_tick = 0
+        self._blocked_cause = "blocks"
+        # always-on phase table, {span name: [host seconds, count]}; written
+        # by the scheduler thread alone (see _Phase)
+        self.sched_stats: Dict[str, list] = {}
         self._wake = threading.Event()
         self._shutdown = threading.Event()
         # KV migration fabric (serving/migration.py): export/import commands
@@ -1060,7 +1099,8 @@ class BatchedEngine:
             "export": {}, "import": {}}
         # scheduler-tick trace, for tests and TTFT/TPOT forensics:
         # ("admit", slot, plen, mode) / ("prefill", slot, ntokens) /
-        # ("activate", slot) / ("decode", K) / ("finish", slot)
+        # ("activate", slot) / ("decode", K) / ("finish", slot); fed by
+        # _event, the one recording call, beside Request.timeline
         self.sched_trace: "collections.deque[tuple]" = \
             collections.deque(maxlen=4096)
 
@@ -1567,7 +1607,7 @@ class BatchedEngine:
             # non-blocking: a miss kicks an ASYNC load and returns None —
             # decode keeps ticking while the checkpoint reads; the request
             # parks at its FIFO position until the load resolves
-            with jax.profiler.TraceAnnotation("dtx_engine_adapter_acquire"):
+            with self._phase("dtx_engine_adapter_acquire"):
                 idx = self.adapter_registry.acquire(
                     req.adapter_name,
                     count_hit=not req.adapter_stats_counted)
@@ -1585,15 +1625,14 @@ class BatchedEngine:
                 if self._last_adapter_wait != req.adapter_name:
                     # dedupe: one trace entry per wait episode, not one
                     # per scheduler retry tick (would flood the ring)
-                    self._trace("adapter_wait", req.adapter_name)
+                    self._event("adapter_wait", req.adapter_name)
                     self._last_adapter_wait = req.adapter_name
                 return False
             self._last_adapter_wait = None
             pinned = True
             req.adapter = idx
-            if self.tracing:
-                req.mark("adapter", name=req.adapter_name, slot=idx,
-                         loaded=not req.adapter_was_resident)
+            self._mark(req, "adapter", name=req.adapter_name, slot=idx,
+                       loaded=not req.adapter_was_resident)
         try:
             ok = self._admit_slot(req, slot)
         except Exception:
@@ -1643,9 +1682,7 @@ class BatchedEngine:
             self._slot_req[slot] = req
             self._decode_ready[slot] = True
             self._note_admitted(slot)
-            self._trace("admit", slot, plen, "dense")
-            if self.tracing:
-                req.mark("admit", slot=slot, plen=plen, mode="dense")
+            self._admitted(req, slot, plen, "dense")
             return True
 
         if self.cow:
@@ -1694,9 +1731,7 @@ class BatchedEngine:
                 self._decode_ready[slot] = True
                 self._slot_demand[slot] = self._eager_demand(cursor, max_new)
                 self._note_admitted(slot)
-                self._trace("admit", slot, plen, "cache")
-                if self.tracing:
-                    req.mark("admit", slot=slot, plen=plen, mode="cache")
+                self._admitted(req, slot, plen, "cache")
                 return True
 
         blocks = self._alloc_blocks(self._reserve_depth(plen, max_new))
@@ -1723,9 +1758,7 @@ class BatchedEngine:
             "key": self._prefix_key(ids, plen, n_prompt, akey),
         }
         self._note_admitted(slot)
-        self._trace("admit", slot, plen, "chunked")
-        if self.tracing:
-            req.mark("admit", slot=slot, plen=plen, mode="chunked")
+        self._admitted(req, slot, plen, "chunked")
         return True
 
     def _reserve_depth(self, cursor: int, max_new: int) -> int:
@@ -1772,9 +1805,7 @@ class BatchedEngine:
                                suffix=None, key=key)
             if ok:
                 self.prefill_stats["reuse"] += 1
-                self._trace("admit", slot, plen, "cow")
-                if self.tracing:
-                    req.mark("admit", slot=slot, plen=plen, mode="cow")
+                self._admitted(req, slot, plen, "cow")
             return ok
         pkey, pent = self._prefix.longest_prefix(used, akey)
         if pent is not None and pent.get("blocks") is not None:
@@ -1791,10 +1822,7 @@ class BatchedEngine:
                                    suffix=sfx, key=key)
                 if ok:
                     self.prefill_stats["extend"] += 1
-                    self._trace("admit", slot, plen, "cow_extend")
-                    if self.tracing:
-                        req.mark("admit", slot=slot, plen=plen,
-                                 mode="cow_extend")
+                    self._admitted(req, slot, plen, "cow_extend")
                 return ok
         return None
 
@@ -1900,38 +1928,91 @@ class BatchedEngine:
         row[: len(blocks)] = blocks
         return jnp.asarray(row)
 
-    def _trace(self, *event):
-        self.sched_trace.append(event)
+    def _phase(self, name: str, **detail) -> _Phase:
+        """``with self._phase("dtx_engine_<what>", ...)``: every span of the
+        scheduler opens through here (see ``_Phase``)."""
+        return _Phase(self.sched_stats, name, detail)
+
+    def _mark(self, req: Request, event: str, **detail):
+        """Stamp ``event`` on the request's own timeline, with the pass of
+        the scheduler it happened in."""
+        if self.tracing:
+            req.mark(event, tick=self._tick_no, **detail)
+
+    def _event(self, name: str, *ring, req: Optional[Request] = None,
+               mark: Optional[str] = None, **detail):
+        """Record one scheduler event, once: ``(name, *ring)`` into the
+        ``sched_trace`` ring and, where it is a request's, ``detail`` onto
+        that request's timeline (as ``mark`` where the timeline's word for
+        the event differs from the ring's)."""
+        self.sched_trace.append((name, *ring))
+        if req is not None:
+            self._mark(req, mark or name, **detail)
+
+    def _admitted(self, req: Request, slot: int, plen: int, mode: str):
+        """The admit event, with how long the request queued and for what:
+        ``tick`` (no pass of the scheduler saw it and left it: it was
+        admitted within a tick of its submission), what its own last failed
+        attempt lacked (``blocks``, ``adapter``), what the head the FIFO
+        order held it behind lacked, or ``slot`` (every slot was taken)."""
+        ticks = self._tick_no - req.tick_submit
+        cause = req.waited_for
+        if cause is None:
+            if ticks <= 1:
+                cause = "tick"
+            elif self._blocked_tick > req.tick_submit:
+                cause = self._blocked_cause
+            else:
+                cause = "slot"
+        self._event("admit", slot, plen, mode, req=req, slot=slot, plen=plen,
+                    mode=mode, waited_ticks=ticks, waited_for=cause)
 
     def _complete(self, req: Request, error: Optional[str] = None):
         """Finish a request AND flush its buffered observability: one
         TTFT/TPOT observe pair per request (never per token) and, with
         tracing on, the request's span timeline into the trace ring."""
-        n = len(req.tokens)
-        self.generated_tokens += n
-        if req.first_token_ts is not None:
-            # exemplar only when tracing: the trace id is then resolvable at
-            # GET /debug/trace/<id>, and the tracing-off observe stays the
-            # bare-arithmetic path (token-parity test's no-overhead contract)
-            tid = req.trace_id if self.tracing else None
-            self._h_ttft.observe((req.first_token_ts - req.t_submit) * 1e3,
-                                 trace_id=tid)
-            if req.last_token_ts is not None and n > 1:
-                self._h_tpot.observe(
-                    (req.last_token_ts - req.first_token_ts) / (n - 1) * 1e3,
-                    trace_id=tid)
-        if self.tenants is not None and getattr(req, "tenant", ""):
-            self._tenant_count(req.tenant, "tokens_out", n)
-        if self.tracing:
-            span = build_request_span(
-                req.trace_id, req.t_submit, req.timeline,
-                req.first_token_ts, req.last_token_ts, n,
-                req.wall_submit_ms, error=error,
-                attrs={"adapter": req.adapter_name or req.adapter,
-                       "prompt_len": len(req.prompt_ids)},
-            )
-            self.trace_store.add(span)
-        req.finish(error=error)
+        with self._phase("dtx_engine_complete"):
+            n = len(req.tokens)
+            self.generated_tokens += n
+            if req.first_token_ts is not None:
+                # exemplar only when tracing: the trace id is then resolvable
+                # at GET /debug/trace/<id>, and the tracing-off observe stays
+                # the bare-arithmetic path (token-parity test's no-overhead
+                # contract)
+                tid = req.trace_id if self.tracing else None
+                self._h_ttft.observe(
+                    (req.first_token_ts - req.t_submit) * 1e3, trace_id=tid)
+                if req.last_token_ts is not None and n > 1:
+                    self._h_tpot.observe(
+                        (req.last_token_ts - req.first_token_ts)
+                        / (n - 1) * 1e3, trace_id=tid)
+            if self.tenants is not None and getattr(req, "tenant", ""):
+                self._tenant_count(req.tenant, "tokens_out", n)
+            if self.tracing:
+                span = build_request_span(
+                    req.trace_id, req.t_submit, req.timeline,
+                    req.first_token_ts, req.last_token_ts, n,
+                    req.wall_submit_ms, error=error,
+                    attrs={"adapter": req.adapter_name or req.adapter,
+                           "prompt_len": len(req.prompt_ids)},
+                )
+                self.trace_store.add(span)
+            req.finish(error=error)
+
+    def _wait_cause(self) -> str:
+        """Why the scheduler is about to sleep with work it cannot run:
+        ``preempted`` (a parked session cannot resume yet), what the queue's
+        head lacks (``blocks``, ``adapter``, ``adapter_pool``), ``import`` (a
+        migration command waits for room), or "" when nothing has been asked
+        of the engine. A request that arrived during this pass has set
+        ``_wake``: the sleep it meets has no length."""
+        if self._preempted:
+            return "preempted"
+        if self._waiting_front:
+            return self._admit_wait_reason or "blocks"
+        if self._mig_retry:
+            return "import"
+        return ""
 
     def _take_waiting(self) -> Optional[Request]:
         if self._waiting_front:
@@ -1967,6 +2048,8 @@ class BatchedEngine:
                     # session older than this cold request resumes first —
                     # admitting the younger one would hand it the very
                     # blocks the parked head is waiting for
+                    self._blocked_tick = self._tick_no
+                    self._blocked_cause = "blocks"
                     self._requeue_front(parked + [req])
                     return
                 try:
@@ -1976,12 +2059,16 @@ class BatchedEngine:
                     continue  # try the next request for this slot
                 if ok:
                     break
+                req.waited_for = ("blocks" if self._admit_wait_reason
+                                  == "blocks" else "adapter")
                 if self._admit_wait_reason == "adapter":
                     parked.append(req)
                     continue
                 # KV blocks exhausted: the FIFO head waits for freed blocks
                 # (younger requests must not starve it by sneaking in —
                 # they'd consume the very blocks it needs)
+                self._blocked_tick = self._tick_no
+                self._blocked_cause = req.waited_for
                 self._requeue_front(parked + [req])
                 return
         self._requeue_front(parked)
@@ -2006,8 +2093,8 @@ class BatchedEngine:
                         budget - spent)
                 lo = st["done"]
                 try:
-                    with jax.profiler.TraceAnnotation(
-                            "dtx_engine_prefill_chunk", tokens=c, slot=slot):
+                    with self._phase("dtx_engine_prefill_chunk",
+                                     tokens=c, slot=slot):
                         logits, self._cache = self._prefill_chunk_fn(
                             self.params, self._lora_arg(), self._cache,
                             jnp.asarray(slot, jnp.int32),
@@ -2033,12 +2120,9 @@ class BatchedEngine:
                     break
                 st["done"] += c
                 spent += c
-                self._trace("prefill", slot, c)
-                if self.tracing:
-                    req.mark("prefill", slot=slot, tokens=c)
+                self._event("prefill", slot, c, req=req, slot=slot, tokens=c)
                 if st["done"] >= st["plen"]:
-                    with jax.profiler.TraceAnnotation("dtx_engine_activate",
-                                                      slot=slot):
+                    with self._phase("dtx_engine_activate", slot=slot):
                         self._finish_prefill(slot, st, logits)
                     break
             if spent >= budget:
@@ -2082,9 +2166,7 @@ class BatchedEngine:
                 self._prefix.put(st["key"], {"cache": row,
                                              "logits": row_logits,
                                              "cursor": cursor})
-        self._trace("activate", slot)
-        if self.tracing:
-            req.mark("activate", slot=slot)
+        self._event("activate", slot, req=req, slot=slot)
 
     # ------------------------------------------------- KV migration fabric
     def export_sessions(self, slots: Optional[Sequence[int]] = None,
@@ -2328,10 +2410,9 @@ class BatchedEngine:
                         continue
                     sessions.append(payload)
                     self._count_mig("export", "ok_prefill")
-                    self._trace("export_prefill", slot)
-                    if self.tracing:
-                        req.mark("export", slot=slot, prefill=True,
-                                 done=st["done"])
+                    self._event("export_prefill", slot, req=req,
+                                mark="export", slot=slot, prefill=True,
+                                done=st["done"])
                     self._release_slot(slot)
                     self._active = self._active.at[slot].set(False)
                     self._remaining = self._remaining.at[slot].set(0)
@@ -2362,9 +2443,8 @@ class BatchedEngine:
                 continue
             sessions.append(payload)
             self._count_mig("export", "ok")
-            self._trace("export", slot)
-            if self.tracing:
-                req.mark("export", slot=slot, cursor=payload["cursor"])
+            self._event("export", slot, req=req, slot=slot,
+                        cursor=payload["cursor"])
             self._release_slot(slot)
             # the slot is still ACTIVE on device — every other release
             # happens after the decode kernel deactivated it. Clear the
@@ -2400,9 +2480,8 @@ class BatchedEngine:
                 req = entry["req"]
                 sessions.append(encode_payload(entry["payload"]))
                 self._count_mig("export", "ok")
-                self._trace("export_parked", req.seq)
-                if self.tracing:
-                    req.mark("export", parked=True)
+                self._event("export_parked", req.seq, req=req,
+                            mark="export", parked=True)
                 self._complete(
                     req, error=f"{MIGRATED_SESSION}: parked session exported")
         return {"sessions": sessions, "skipped": skipped}
@@ -2627,10 +2706,8 @@ class BatchedEngine:
             self._slot_demand[slot] = self._eager_demand(cursor, remaining)
         self._note_admitted(slot)
         self._count_mig("import", "ok")
-        self._trace("import", slot, cursor)
-        if self.tracing:
-            req.mark("import", slot=slot, cursor=cursor, adapter=name,
-                     tail_tokens=req.resume_base)
+        self._event("import", slot, cursor, req=req, slot=slot,
+                    cursor=cursor, adapter=name, tail_tokens=req.resume_base)
         text = (self.tokenizer.decode(req.tokens, skip_special_tokens=True)
                 if req.tokens else "")
         return {"session": req.trace_id, "slot": slot,
@@ -2708,10 +2785,9 @@ class BatchedEngine:
         }
         self._note_admitted(slot)
         self._count_mig("import", "ok_prefill")
-        self._trace("import_prefill", slot, cursor)
-        if self.tracing:
-            req.mark("import", slot=slot, cursor=cursor, adapter=name,
-                     prefill=True, tail=len(ids))
+        self._event("import_prefill", slot, cursor, req=req, mark="import",
+                    slot=slot, cursor=cursor, adapter=name, prefill=True,
+                    tail=len(ids))
         text = (self.tokenizer.decode(req.tokens, skip_special_tokens=True)
                 if req.tokens else "")
         return {"session": req.trace_id, "slot": slot,
@@ -2751,9 +2827,7 @@ class BatchedEngine:
             if req.trace_id in want:
                 dropped += 1
                 self._count_preempt("spilled")
-                self._trace("spill", req.seq)
-                if self.tracing:
-                    req.mark("spill")
+                self._event("spill", req.seq, req=req)
                 self._complete(
                     req, error=f"{MIGRATED_SESSION}: parked session spilled")
             else:
@@ -2920,37 +2994,42 @@ class BatchedEngine:
             ent["no_reuse"] = True
         self._prefix.put(key, ent)
         self._count_mig("import_prefix", "ok")
-        self._trace("import_prefix", cursor)
+        self._event("import_prefix", cursor)
         return {"imported": True, "cursor": cursor,
                 "fingerprint": payload.get("fingerprint")}
 
     def _release_slot(self, slot: int, note_session: bool = True):
-        self._slot_req[slot] = None
-        self._pending.pop(slot, None)
-        self._decode_ready[slot] = False
-        self._slot_demand[slot] = 0
-        if self.spec is not None:
-            self._spec_form[slot] = False
-            self._spec_primed[slot] = False
-            self.spec_ctrl.reset_slot(slot)
-            # prune-on-release, like the slot acceptance EMAs: per-slot
-            # tree-path series never outlive the tenant that produced them
-            self._spec_tree_slot_path.pop(slot, None)
-        name, self._slot_adapter[slot] = self._slot_adapter[slot], None
-        if name is not None and self.adapter_registry is not None:
-            self.adapter_registry.release(name)
-        blocks, self._slot_blocks[slot] = self._slot_blocks[slot], []
-        if blocks:
-            if note_session:
-                # tables only grow, so the count at release IS the
-                # session's peak physical footprint (bench p50/p95 source);
-                # preemptions pass False — the session isn't over
-                self.kv_stats["session_blocks"].append(len(blocks))
-            # clear the table FIRST: a masked decode write from this slot
-            # must never land in a block the allocator has already re-issued
-            self._cache["block_tables"] = \
-                self._cache["block_tables"].at[slot].set(-1)
-            self._allocator.free(blocks)
+        with self._phase("dtx_engine_release",
+                         blocks=len(self._slot_blocks[slot])):
+            self._slot_req[slot] = None
+            self._pending.pop(slot, None)
+            self._decode_ready[slot] = False
+            self._slot_demand[slot] = 0
+            if self.spec is not None:
+                self._spec_form[slot] = False
+                self._spec_primed[slot] = False
+                self.spec_ctrl.reset_slot(slot)
+                # prune-on-release, like the slot acceptance EMAs: per-slot
+                # tree-path series never outlive the tenant that produced
+                # them
+                self._spec_tree_slot_path.pop(slot, None)
+            name, self._slot_adapter[slot] = self._slot_adapter[slot], None
+            if name is not None and self.adapter_registry is not None:
+                self.adapter_registry.release(name)
+            blocks, self._slot_blocks[slot] = self._slot_blocks[slot], []
+            if blocks:
+                if note_session:
+                    # tables only grow, so the count at release IS the
+                    # session's peak physical footprint (bench p50/p95
+                    # source); preemptions pass False — the session isn't
+                    # over
+                    self.kv_stats["session_blocks"].append(len(blocks))
+                # clear the table FIRST: a masked decode write from this
+                # slot must never land in a block the allocator has already
+                # re-issued
+                self._cache["block_tables"] = \
+                    self._cache["block_tables"].at[slot].set(-1)
+                self._allocator.free(blocks)
 
     # --------------------------------------------- overcommit: grow/preempt
     def _grow_tick(self):
@@ -3063,7 +3142,7 @@ class BatchedEngine:
         self._cache["pos"] = self._cache["pos"].at[arr].set(POS_SENTINEL)
         self._cache["block_tables"] = self._cache["block_tables"].at[
             slot].set(self._table_row(blocks))
-        self._trace("grow", slot, len(new_blocks))
+        self._event("grow", slot, len(new_blocks))
 
     def _preempt_slot(self, slot: int):
         """Park a decode session host-side: settle (spec), export its
@@ -3087,9 +3166,7 @@ class BatchedEngine:
         self._preempted.append({"payload": payload, "req": req})
         self._preempted.sort(key=lambda e: e["req"].seq)
         self._count_preempt("exported")
-        self._trace("preempt", slot, req.seq)
-        if self.tracing:
-            req.mark("preempt", slot=slot)
+        self._event("preempt", slot, req.seq, req=req, slot=slot)
 
     def _unadmit_pending(self, slot: int):
         """Roll a chunk-prefilling admission back to the cold queue: its
@@ -3103,9 +3180,8 @@ class BatchedEngine:
         self._waiting_front = collections.deque(
             sorted([*self._waiting_front, req], key=lambda r: r.seq))
         self._count_preempt("requeued_prefill")
-        self._trace("preempt_prefill", slot, req.seq)
-        if self.tracing:
-            req.mark("preempt", slot=slot, kind="prefill")
+        self._event("preempt_prefill", slot, req.seq, req=req,
+                    mark="preempt", slot=slot, kind="prefill")
 
     def _resume_preempted_tick(self):
         """Re-admit preemption-parked sessions, oldest first, ahead of the
@@ -3201,9 +3277,8 @@ class BatchedEngine:
         self._slot_demand[slot] = self._eager_demand(cursor, remaining)
         self._note_admitted(slot)
         self._count_preempt("resumed")
-        self._trace("resume", slot, cursor)
-        if self.tracing:
-            req.mark("resume", slot=slot, cursor=cursor)
+        self._event("resume", slot, cursor, req=req, slot=slot,
+                    cursor=cursor)
         return True
 
     # ------------------------------------------------ speculative decoding
@@ -3237,7 +3312,7 @@ class BatchedEngine:
             jnp.asarray([[0] * pad + list(range(n))], jnp.int32),
             jnp.asarray(padded, jnp.int32))
         self._spec_primed[slot] = True
-        self._trace("spec_prime", slot, n)
+        self._event("spec_prime", slot, n)
 
     def _spec_settle_slot(self, slot: int):
         """Write the slot's pending token through the target (one masked
@@ -3255,7 +3330,7 @@ class BatchedEngine:
         self._logits = jnp.where(jnp.asarray(onehot)[:, None], row_logits,
                                  self._logits)
         self._spec_form[slot] = False
-        self._trace("spec_settle", slot)
+        self._event("spec_settle", slot)
 
     def _batch_sample_mode(self) -> str:
         """Static per-batch sampling mode (bounded compiled variants):
@@ -3335,7 +3410,7 @@ class BatchedEngine:
             if plan[0] == "tree":
                 widths = plan[1]  # learned (or rectangular) per-depth widths
                 k = len(widths)  # accepted path depth plays the chain k role
-                with jax.profiler.TraceAnnotation("dtx_engine_spec_tree"):
+                with self._phase("dtx_engine_spec_tree"):
                     (emitted, acc, self._cache, sp["dcache"],
                      self._spec_pending, self._pos, self._remaining,
                      self._active, self._rng, margin) = progs.tree_step(
@@ -3348,7 +3423,7 @@ class BatchedEngine:
                 self.spec_stats["tree_steps"] += 1
             else:
                 k = plan[1]
-                with jax.profiler.TraceAnnotation("dtx_engine_spec_step"):
+                with self._phase("dtx_engine_spec_step"):
                     (emitted, acc, self._cache, sp["dcache"],
                      self._spec_pending, self._pos, self._remaining,
                      self._active, self._rng) = progs.step(
@@ -3404,11 +3479,11 @@ class BatchedEngine:
                 self.sampling_stats["fused_steps"] += 1
             else:
                 self.sampling_stats["legacy_steps"] += 1
-            self._trace("spec", k, len(obs))
+            self._event("spec", k, len(obs))
         else:
             emode = self._epilogue_mode()
-            with jax.profiler.TraceAnnotation("dtx_engine_decode",
-                                              live=sum(self._decode_ready)):
+            with self._phase("dtx_engine_decode",
+                             live=sum(self._decode_ready)):
                 (emitted, self._cache, self._spec_pending, self._pos,
                  self._remaining, self._active, self._rng) = progs.decode(
                     self.params, self._lora_arg(), self._cache,
@@ -3416,15 +3491,15 @@ class BatchedEngine:
                     self._active, self._rng, self._temps, self._top_ps,
                     self._stops, self._adapter_idx, K=self.chunk,
                     mode=emode)
-            with jax.profiler.TraceAnnotation("dtx_engine_decode_sync"):
+            with self._phase("dtx_engine_decode_sync"):
                 out_rows.append(np.asarray(emitted))  # [K, S]  # dtxlint: disable=DTX001
             self.spec_stats["plain_steps"] += 1
             self.sampling_stats["fused_steps" if emode != "off"
                                 else "legacy_steps"] += 1
             self.spec_ctrl.note_plain_step()
-            self._trace("decode", self.chunk)
+            self._event("decode", self.chunk)
 
-        with jax.profiler.TraceAnnotation("dtx_engine_decode_sync"):
+        with self._phase("dtx_engine_decode_sync"):
             active_np = np.asarray(self._active)  # dtxlint: disable=DTX001
         return np.concatenate(out_rows, axis=0), active_np
 
@@ -3474,10 +3549,13 @@ class BatchedEngine:
 
     def _scheduler(self):
         # every pass and every phase in it is a host span in the profiler's
-        # own trace (one clock with the device ops): with no profiler
-        # session open a TraceAnnotation is a flag test
+        # own trace (one clock with the device ops) and a row of
+        # sched_stats; the pass carries its number and the host clock at its
+        # start, which ties Request.timeline's stamps to the trace's clock
         while not self._shutdown.is_set():
-            with jax.profiler.TraceAnnotation("dtx_engine_tick"):
+            self._tick_no += 1
+            with self._phase("dtx_engine_tick", tick=self._tick_no,
+                             t_perf=time.perf_counter()):
                 try:
                     self._tick()
                 except Exception as e:  # noqa: BLE001 — judged just below
@@ -3512,7 +3590,7 @@ class BatchedEngine:
     def _tick(self):
         """One pass of the scheduler: admissions, at most a budget of
         prefill, then one decode chunk and the delivery of its tokens."""
-        span = jax.profiler.TraceAnnotation
+        span = self._phase
         # migrations first: an imported session is already mid-decode
         # (its prefill budget was spent on the source replica), so it
         # outranks cold admissions for free slots
@@ -3530,8 +3608,14 @@ class BatchedEngine:
             if self._pending:
                 return  # keep prefilling; nothing to decode yet
             with span("dtx_engine_wait"):
-                self._wake.wait(timeout=0.1)
-                self._wake.clear()
+                # starved (nothing has been asked of the engine) or held up
+                # (something has, and cannot run): the two read alike from
+                # outside, an idle chip
+                cause = self._wait_cause()
+                with (span("dtx_engine_wait_blocked", reason=cause) if cause
+                      else span("dtx_engine_wait_empty")):
+                    self._wake.wait(timeout=0.1)
+                    self._wake.clear()
             return
 
         try:
@@ -3553,7 +3637,7 @@ class BatchedEngine:
                         )
                 self.sampling_stats["fused_steps" if emode != "off"
                                     else "legacy_steps"] += 1
-                self._trace("decode", self.chunk)
+                self._event("decode", self.chunk)
                 # the decode loop's ONE designed sync point: K tokens per
                 # chunk cross to host here so req.push can stream them
                 with span("dtx_engine_decode_sync"):
@@ -3571,13 +3655,18 @@ class BatchedEngine:
             return
 
         with span("dtx_engine_emit"):
-            for k in range(emitted_np.shape[0]):
-                for slot in range(self.slots):
-                    # emitted_np is host-side numpy already — no device sync
-                    t = int(emitted_np[k, slot])  # dtxlint: disable=DTX001
-                    req = self._slot_req[slot]
-                    if t >= 0 and req is not None:
-                        req.push(t)
+            # emission's two costs apart: the push loop goes with the tokens
+            # of the chunk, release and complete with the requests that end
+            pushed = int(np.count_nonzero(emitted_np >= 0))  # dtxlint: disable=DTX001 — host numpy since the sync above
+            with span("dtx_engine_emit_push", tokens=pushed):
+                for k in range(emitted_np.shape[0]):
+                    for slot in range(self.slots):
+                        # emitted_np is host-side numpy already — no device
+                        # sync
+                        t = int(emitted_np[k, slot])  # dtxlint: disable=DTX001
+                        req = self._slot_req[slot]
+                        if t >= 0 and req is not None:
+                            req.push(t)
             for slot in range(self.slots):
                 req = self._slot_req[slot]
                 # pending-prefill slots are inactive by design — only slots
@@ -3585,10 +3674,8 @@ class BatchedEngine:
                 if (req is not None and self._decode_ready[slot]
                         and not bool(active_np[slot])):
                     self._release_slot(slot)
-                    if self.tracing:
-                        req.mark("finish", slot=slot)
+                    self._event("finish", slot, req=req, slot=slot)
                     self._complete(req)
-                    self._trace("finish", slot)
 
     # ---------------------------------------------------------------- API
     def submit(
@@ -3644,6 +3731,7 @@ class BatchedEngine:
                       tenant_tier=tier)
         if self._shutdown.is_set():
             raise RuntimeError("engine is shut down")
+        req.tick_submit = self._tick_no
         self._waiting.put(req)
         self._wake.set()
         return req

@@ -26,6 +26,7 @@ from datatunerx_tpu.obs.metrics import (
     adapter_load_histogram,
     exemplars_requested,
     export_moe_stats,
+    export_sched_stats,
     serving_latency_histograms,
     spec_accept_len_histogram,
     set_build_info,
@@ -356,6 +357,7 @@ def _metrics_text_locked(with_exemplars: bool = True) -> str:
         sp_fused.set(samp_stats.get("fused_steps", 0), {"path": "fused"})
         sp_fused.set(samp_stats.get("legacy_steps", 0), {"path": "legacy"})
     export_moe_stats(reg, eng)
+    export_sched_stats(reg, eng)
     gen_toks = reg.counter("dtx_serving_generated_tokens_total",
                            "Tokens emitted to finished requests.")
     path_g = reg.gauge("dtx_serving_decode_path",
