@@ -47,8 +47,9 @@ class ModelConfig:
     # paged-decode attention kernel (ops/pallas_paged_attention.py): True
     # routes single-token decode over a block-table cache through the Pallas
     # in-place kernel instead of the XLA gather; engages only when the cache
-    # is paged, T == 1, and sliding_window is None (everything else keeps
-    # the gather oracle). Resolved by the serving engine from its
+    # is paged and T == 1 (sliding_window goes to the kernel; multi-token
+    # steps take their kernel only where it is None, and everything else
+    # keeps the gather oracle). Resolved by the serving engine from its
     # --paged_kernel auto|on|off flag; training never sets it.
     paged_kernel: bool = False
     # ---- layers of different kinds in one model (models/hybrid.py). Every

@@ -296,21 +296,23 @@ def forward(
 
     # Pallas in-place decode: single-token steps over a paged cache read the
     # K/V blocks through the block table inside the kernel — no gathered
-    # [B, W, KV, d] view, no [B, 1, T, W] bias tensor. Multi-token steps
-    # over a paged cache (chunked-prefill chunks, spec verify-k columns,
-    # tree-verify windows) ride the multi-token variant, which consumes the
-    # oracle's own attendability tensor as a mask operand. Everything else
-    # (prefill into dense caches, sliding window, packed segments) keeps
-    # the gather path, which doubles as the kernels' parity oracle.
+    # [B, W, KV, d] view, no [B, 1, T, W] bias tensor; the kernel takes the
+    # model's sliding window (and drops one the cache is not wider than).
+    # Multi-token steps over a paged cache (chunked-prefill chunks, spec
+    # verify-k columns, tree-verify windows) of a model with no window ride
+    # the multi-token variant, which consumes the oracle's own attendability
+    # tensor as a mask operand. Everything else (prefill into dense caches,
+    # a windowed model's multi-token steps, packed segments) keeps the
+    # gather path, which doubles as the kernels' parity oracle.
     _paged_cfg = (
         cache is not None
         and "block_tables" in cache
         and getattr(cfg, "paged_kernel", False)
-        and cfg.sliding_window is None
     )
     paged_kernel = _paged_cfg and T == 1 and window_mask is None
     paged_kernel_mt = (_paged_cfg and not paged_kernel
-                       and segment_ids is None)
+                       and segment_ids is None
+                       and cfg.sliding_window is None)
     if window_mask is not None and window_start is None:
         if cache is None:
             raise ValueError("window_mask without a cache needs window_start")
@@ -432,7 +434,8 @@ def forward(
                 pools = kv_cache_write(kv_step, pools, layer_idx, k, v)
             with jax.named_scope("dtx.attn"):
                 attn = paged_attention_decode_step(
-                    q, pools, layer_idx, cache, cache_pos, positions)
+                    q, pools, layer_idx, cache, cache_pos, positions,
+                    window=cfg.sliding_window)
         elif pools is not None and paged_kernel_mt:
             # multi-token in-place: same scatter-then-read-through-the-table
             # scheme with the precomputed attendability operand standing in

@@ -19,7 +19,11 @@ blocks admission reserves for tokens to come among them — cost neither a
 step nor a byte, so per decode token the kernel streams only the slot's LIVE
 blocks (K twice, V once — see below) and both its bytes and its time follow
 ``len(session)`` instead of ``blocks_per_slot × block_size``. A slot with
-nothing written costs a grid step that stores zeros.
+nothing written costs a grid step that stores zeros. A model's sliding
+``window`` is a static operand: one the cache's width cannot exceed is dropped
+at trace time (no query has a key behind it, and the kernel emitted is the
+window-less one), a narrower one gives the walk a FIRST trip as the cursor
+gives it a last, so bytes and time follow ``min(len, window)``.
 
 Correctness contract — the gather path stays alive as the parity ORACLE,
 and the PR 5 bit-parity suite asserts kernel-vs-gather token-exactness.
@@ -97,7 +101,8 @@ def _blocks_per_trip(bs: int, width: int, itemsize: int, nbps: int) -> int:
 
 def _decode_kernel(tables_ref, qpos_ref, bound_ref, layer_ref, q_ref,
                    pos_ref, k_hbm, v_hbm, *refs, pool_blocks: int, trip: int,
-                   kv_heads: int, group: int, scale: float, quant: bool):
+                   kv_heads: int, group: int, scale: float, quant: bool,
+                   window: Optional[int] = None, first_ref=None):
     """One slot a grid step; the walk over its block table is a loop in here.
 
     The pools stay in HBM. A trip covers ``trip`` consecutive table columns
@@ -108,6 +113,11 @@ def _decode_kernel(tables_ref, qpos_ref, bound_ref, layer_ref, q_ref,
     and a slot with nothing written runs none. The table is walked twice, in
     ascending order both times: the stats phase (K) carries the running row
     max and normalizer, the weighted-sum phase reads K again and V.
+
+    With a ``window`` the walk also has a first trip (``first_ref``): the
+    trips before it hold no lane the window admits and are not copied
+    either, the loops count trips from it (``at``), and the mask gains the
+    oracle's ``pos > q_pos - window``, which decides inside the first trip.
 
     All heads share one MXU pass a trip: the query rows are laid out
     block-diagonally (``qbd[h, kv(h)·d:(kv(h)+1)·d] = q[h]``, zero elsewhere)
@@ -126,6 +136,17 @@ def _decode_kernel(tables_ref, qpos_ref, bound_ref, layer_ref, q_ref,
     trips = (nb + trip - 1) // trip
     row0 = layer_ref[0] * pool_blocks  # the layer's first row of the pools
     q_pos = qpos_ref[b]
+    if window is None:
+        def at(r):
+            return r
+    else:
+        # the loops below count the walk's trips from its first one; ``at``
+        # gives a trip's place in the table
+        first = jnp.minimum(first_ref[b], trips)
+        trips = trips - first
+
+        def at(r):
+            return first + r
 
     def copies(t, slot, which):
         """The copies of trip ``t`` into buffer ``slot``, each under the
@@ -181,8 +202,8 @@ def _decode_kernel(tables_ref, qpos_ref, bound_ref, layer_ref, q_ref,
 
     @pl.when(trips > 0)
     def _walk():
-        start(0, 0, "k")
-        start(0, 0, "v")  # V's first trip lands while K is walked
+        start(at(0), 0, "k")
+        start(at(0), 0, "v")  # V's first trip lands while K is walked
         for kv in range(kv_heads):
             rows = slice(kv * group, (kv + 1) * group)
             qbd_ref[rows, kv * d:(kv + 1) * d] = q_ref[0, rows, :]
@@ -196,15 +217,17 @@ def _decode_kernel(tables_ref, qpos_ref, bound_ref, layer_ref, q_ref,
             # sentinel + causal in one compare, as the oracle's bias; the
             # index bound drops the lanes of the columns not copied
             mask = (pos_ref[0, t] <= q_pos) & (t * lanes + lane < nb * bs)
+            if window is not None:  # attention_allow's compare
+                mask &= pos_ref[0, t] > q_pos - window
             return jnp.where(mask, s, NEG_INF)
 
-        def stats(t, carry):
+        def stats(r, carry):
             m_prev, l_prev = carry
             # K's copies run on through both phases: after the last trip of
             # this one comes the first of the next, again
-            nxt = jnp.where(t + 1 < trips, t + 1, 0)
-            start(nxt, (t + 1) % 2, "k")
-            s = scores(t, t % 2)
+            nxt = jnp.where(r + 1 < trips, r + 1, 0)
+            start(at(nxt), (r + 1) % 2, "k")
+            s = scores(at(r), r % 2)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             l_new = (l_prev * jnp.exp(m_prev - m_new)
                      + jnp.sum(jnp.exp(s - m_new), axis=1, keepdims=True))
@@ -218,20 +241,20 @@ def _decode_kernel(tables_ref, qpos_ref, bound_ref, layer_ref, q_ref,
         l_row = jnp.maximum(l, 1e-30)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        def weighted_sum(t, carry):
-            k_slot = (trips + t) % 2
+        def weighted_sum(r, carry):
+            k_slot = (trips + r) % 2
 
-            @pl.when(t + 1 < trips)
+            @pl.when(r + 1 < trips)
             def _():
-                start(t + 1, 1 - k_slot, "k")
-                start(t + 1, (t + 1) % 2, "v")
+                start(at(r + 1), 1 - k_slot, "k")
+                start(at(r + 1), (r + 1) % 2, "v")
 
-            s = scores(t, k_slot)
+            s = scores(at(r), k_slot)
             # the oracle's probs: normalized THEN quantized to the compute
             # dtype before the PV product (xla_attention rounds the same way)
             p = (jnp.exp(s - m) / l_row).astype(o_ref.dtype)
             acc_ref[...] += jax.lax.dot_general(
-                p, landed(t, t % 2, "v"), (((1,), (0,)), ((), ())),
+                p, landed(at(r), r % 2, "v"), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             return carry
 
@@ -264,6 +287,7 @@ def paged_decode_attention(
     cursor: jnp.ndarray,     # [B] int32 the linear lane this token was
                              # written at (the cache's ``len`` before the step)
     *,
+    window: Optional[int] = None,  # the model's sliding window, static
     interpret=None,
 ) -> jnp.ndarray:
     """In-place paged decode attention over the block pool: out [B, H, d].
@@ -271,15 +295,26 @@ def paged_decode_attention(
     A slot's walk ends at the column its cursor lies in (left pads make the
     rope position undercount the lanes, so the cursor and not ``q_positions``
     is the bound), or at its last column that holds a block if that comes
-    first. Slots with nothing to walk (released: a table of -1, whatever
-    cursor they kept) produce zeros — the engine's emit mask already discards
-    their tokens, mirroring the garbage the oracle's sentinel-masked uniform
-    softmax yields for such rows."""
+    first. Under a ``window`` narrower than the cache it starts at the trip
+    that holds the first lane the window admits, read off the slot's position
+    view and not reckoned from the cursor: while a row's pads all lie at its
+    left they shift its lanes against its rope positions by a constant
+    (``lane = position + pads``, keys and query alike) and that lane is
+    ``cursor + 1 - window`` whatever the pads, but a prefix-cache extension
+    leaves pads mid-row, which put it that many lanes earlier. A ``window``
+    of the cache's width (``table columns × block size``) or more has no lane
+    behind it: it is dropped here, from the shapes, and the kernel emitted
+    is the window-less one. Slots with nothing to walk (released: a table of
+    -1, whatever cursor they kept) produce zeros — the engine's emit mask
+    already discards their tokens, mirroring the garbage the oracle's
+    sentinel-masked uniform softmax yields for such rows."""
     B, H, d = q.shape
     _, NB, bs, width = k_pool.shape
     KV = width // d
     nbps = tables.shape[1]
     quant = k_scale is not None
+    if window is not None and window >= nbps * bs:
+        window = None
     trip = _blocks_per_trip(bs, width, k_pool.dtype.itemsize, nbps)
     trips = -(-nbps // trip)
     lanes = trip * bs
@@ -291,7 +326,7 @@ def paged_decode_attention(
     scale = float(np.float32(1.0) / np.sqrt(np.float32(d)))  # dtxlint: disable=DTX001 — host numpy scalar (d is a static shape), no device sync
     kernel = functools.partial(
         _decode_kernel, pool_blocks=NB, trip=trip, kv_heads=KV,
-        group=H // KV, scale=scale, quant=quant)
+        group=H // KV, scale=scale, quant=quant, window=window)
 
     tables = tables.astype(jnp.int32)
     held = jnp.max(jnp.where(tables >= 0,
@@ -303,7 +338,18 @@ def paged_decode_attention(
     # lane backed by no block, and the pad past the table, read as sentinel
     pos = jnp.pad(gathered_positions(pos_pool, tables),
                   ((0, 0), (0, trips * lanes - nbps * bs)),
-                  constant_values=POS_SENTINEL).reshape(B, trips, 1, lanes)
+                  constant_values=POS_SENTINEL)
+    q_positions = q_positions.astype(jnp.int32)
+    prefetch = [tables, q_positions, bound, _layer_operand(layer)]
+    if window is not None:
+        # the trip of the first lane the mask will admit (``trips``: none)
+        q_pos = q_positions[:, None]
+        admits = jnp.pad((pos <= q_pos) & (pos > q_pos - window),
+                         ((0, 0), (0, 1)), constant_values=True)
+        prefetch.append(
+            (jnp.argmax(admits, axis=1) // lanes).astype(jnp.int32))
+        kernel = functools.partial(_windowed_decode_kernel, kernel)
+    pos = pos.reshape(B, trips, 1, lanes)
 
     # a pool's last axis is (KV, d) merged and the cache stores its leaves
     # that way (ops/paged_attention.py), so nothing is reshaped on the way in
@@ -342,7 +388,7 @@ def paged_decode_attention(
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(prefetch),
             grid=(B,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, H, d), lambda b, *_: (b, 0, 0)),
@@ -354,8 +400,15 @@ def paged_decode_attention(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret() if interpret is None else interpret,
         name="dtx_paged_decode",
-    )(tables, q_positions.astype(jnp.int32), bound, _layer_operand(layer),
-      *args)
+    )(*prefetch, *args)
+
+
+def _windowed_decode_kernel(kernel, tables_ref, qpos_ref, bound_ref,
+                            layer_ref, first_ref, *refs):
+    """Arity shim for a ``window`` narrower than the cache: the walk's first
+    trip is the last scalar-prefetch operand of the call."""
+    kernel(tables_ref, qpos_ref, bound_ref, layer_ref, *refs,
+           first_ref=first_ref)
 
 
 def _layer_operand(layer) -> jnp.ndarray:
@@ -364,14 +417,16 @@ def _layer_operand(layer) -> jnp.ndarray:
 
 
 def paged_attention_decode_step(q, leaves: dict, layer, cache: dict,
-                                pos_pool, positions, *, interpret=None):
+                                pos_pool, positions, *, window=None,
+                                interpret=None):
     """Model-facing wrapper: q ``[B, 1, H, d]`` (one decode token), the
     stacked cache leaves the layer scan carries (``k``/``v`` and, for the
     int8 cache, ``k_scale``/``v_scale``), the layer's index, the cache dict
     the step was handed (block tables, and ``len``: the lane this step's
     token was written at, which bounds the walk), the POST-write pos pool,
-    and the step's ``positions [B, 1]``. Returns ``[B, 1, H, d]`` in q.dtype
-    — drop-in for the gather + ``xla_attention`` pair."""
+    the step's ``positions [B, 1]`` and the model's sliding ``window`` (static;
+    None: every earlier key is seen). Returns ``[B, 1, H, d]`` in q.dtype —
+    drop-in for the gather + ``xla_attention`` pair."""
     B, T, H, d = q.shape
     assert T == 1, f"paged decode kernel is single-token (T=1), got T={T}"
     interpret = _interpret() if interpret is None else interpret
@@ -383,12 +438,13 @@ def paged_attention_decode_step(q, leaves: dict, layer, cache: dict,
         # kernel at q_len 1, whose blocks arrive through BlockSpecs
         kv_pos = gathered_positions(pos_pool, cache["block_tables"])
         return paged_attention_multitoken_step(
-            q, leaves, layer, cache, attention_allow(positions, kv_pos),
+            q, leaves, layer, cache,
+            attention_allow(positions, kv_pos, sliding_window=window),
             interpret=interpret)
     out = paged_decode_attention(
         q[:, 0], leaves["k"], leaves["v"], leaves.get("k_scale"),
         leaves.get("v_scale"), layer, cache["block_tables"], pos_pool,
-        positions[:, 0], cache["len"], interpret=interpret)
+        positions[:, 0], cache["len"], window=window, interpret=interpret)
     return out[:, None]
 
 

@@ -1173,6 +1173,7 @@ class BatchedEngine:
         # process can read them (chip_smoke.py asserts on this line)
         print("[engine] " + json.dumps({
             "decode_path": self.decode_path,
+            "decode_window": self.decode_window,
             "sampling_epilogue": self.sampling_epilogue,
             "epilogue_impl": self._epilogue_impl,
             "pallas_interpret": interpret_default(),
@@ -1181,6 +1182,14 @@ class BatchedEngine:
             "prefill_chunk": self.prefill_chunk,
             "moe_kernel": self.moe_kernel,
         }, sort_keys=True), file=sys.stderr, flush=True)
+        if self.decode_path == "pallas" and self.cfg.sliding_window:
+            window, width = self.cfg.sliding_window, self.max_seq_len
+            print(f"[engine] sliding_window={window} "
+                  + (f">= cache width {width}: dropped"
+                     if self.decode_window is None else
+                     f"< cache width {width}: the decode kernel walks a "
+                     "slot's table from the window's first trip"),
+                  file=sys.stderr, flush=True)
 
         self._thread = threading.Thread(target=self._scheduler, daemon=True)
         self._thread.start()
@@ -1192,10 +1201,21 @@ class BatchedEngine:
         block-table kernel), ``gather`` (paged XLA oracle), or ``dense``."""
         if not self.paged:
             return "dense"
-        # the path forward() takes, not the flag: a sliding window keeps
-        # the model on the gather whatever paged_kernel asked for
-        takes_kernel = self.paged_kernel and self.cfg.sliding_window is None
+        # the path forward() takes, not the flag: models/hybrid.py reads a
+        # gathered view whatever paged_kernel asked for (a windowed model's
+        # prefill chunks and verify columns do too; its token step does not)
+        takes_kernel = self.paged_kernel and not self.cfg.hybrid
         return "pallas" if takes_kernel else "gather"
+
+    @property
+    def decode_window(self) -> Optional[int]:
+        """The sliding window the decode kernel was built with, in lanes;
+        None where it has none: no kernel, no window, or one the cache (as
+        wide as ``max_seq_len``) cannot exceed, which the kernel drops."""
+        window = self.cfg.sliding_window
+        if self.decode_path != "pallas" or not window:
+            return None
+        return window if window < self.max_seq_len else None
 
     @property
     def total_kv_blocks(self) -> Optional[int]:

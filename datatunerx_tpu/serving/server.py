@@ -364,12 +364,20 @@ def _metrics_text_locked(with_exemplars: bool = True) -> str:
                        "How decode attention reads the KV cache, one-hot by "
                        "label (pallas = in-place block-table kernel, gather "
                        "= paged XLA oracle, dense).")
+    window_g = reg.gauge("dtx_serving_decode_window",
+                         "Lanes of sliding window the paged decode kernel "
+                         "was built with; absent where it has none (no "
+                         "kernel, no window, or one the cache cannot "
+                         "exceed, which is dropped).")
     gen_toks.clear()
     path_g.clear()
+    window_g.clear()
     if getattr(eng, "generated_tokens", None) is not None:
         gen_toks.set(eng.generated_tokens)
     if getattr(eng, "decode_path", None):
         path_g.set(1, {"path": eng.decode_path})
+    if getattr(eng, "decode_window", None):
+        window_g.set(eng.decode_window)
     # persistent compile cache (utils/runtime.py): a replica that started
     # against a warm cache shows hits == requests
     cc_req = reg.counter("dtx_serving_compile_cache_requests_total",

@@ -82,9 +82,11 @@ def sds(shape, dtype):
 # the single-token decode kernel at the same geometry, and at the shape the
 # benchmark's cell qwen-serve-steady runs it at (16 slots, 32 KV heads of 128,
 # a table of 128 columns, 384 blocks, 16 stacked layers), bf16 and int8 pools
-# (the shared list holds the multi-token kernel and the sampler)
-def decode_cases(tag, geometry, slots, nbps, blocks, layers):
-    H, KV, d = ENGINE_HEADS[geometry]
+# (the shared list holds the multi-token kernel and the sampler); and at
+# Mistral-7B's (8 KV heads of 128, a window of 4,096) with the cache its preset
+# allows, 8,192 lanes: the walk that starts at the window's first trip
+def decode_cases(tag, heads, slots, nbps, blocks, layers, window=None):
+    H, KV, d = heads
     # layer, tables, pos pool, q positions, lane cursors
     rest = (sds((), jnp.int32), sds((slots, nbps), jnp.int32),
             sds((blocks, ENGINE_BLOCK), jnp.int32), sds((slots,), jnp.int32),
@@ -95,17 +97,21 @@ def decode_cases(tag, geometry, slots, nbps, blocks, layers):
     scale = sds(shape[:3] + (KV,), jnp.float32)
     return [
         (f"kernel/paged_decode_bf16_{tag}",
-         lambda q, k, v, *r: paged_decode_attention(q, k, v, None, None, *r),
+         lambda q, k, v, *r: paged_decode_attention(
+             q, k, v, None, None, *r, window=window),
          (q, pool, pool) + rest),
-        (f"kernel/paged_decode_int8_kv_{tag}", paged_decode_attention,
+        (f"kernel/paged_decode_int8_kv_{tag}",
+         lambda *a: paged_decode_attention(*a, window=window),
          (q, pool_i8, pool_i8, scale, scale) + rest)]
 
 
 nbps = ENGINE_SEQ // ENGINE_BLOCK
 cases = (list(serving_kernel_cases(sh))
-         + decode_cases("tinyllama", "tinyllama", ENGINE_SLOTS, nbps,
-                        ENGINE_SLOTS * nbps, ENGINE_LAYERS)
-         + decode_cases("cell", "llama2_7b", 16, 128, 384, 16))
+         + decode_cases("tinyllama", ENGINE_HEADS["tinyllama"], ENGINE_SLOTS,
+                        nbps, ENGINE_SLOTS * nbps, ENGINE_LAYERS)
+         + decode_cases("cell", ENGINE_HEADS["llama2_7b"], 16, 128, 384, 16)
+         + decode_cases("windowed", (32, 8, 128), 16, 512, 2048, 16,
+                        window=4096))
 KERNEL_NAMES = ("dtx_paged_decode", "dtx_paged_multitoken", "dtx_fused_sample")
 done, failed, named = [], {}, {}
 for name, fn, args in cases:
@@ -157,7 +163,9 @@ def test_serving_kernels_lower_through_mosaic_at_engine_geometry():
     for want in ("kernel/paged_decode_bf16_tinyllama",
                  "kernel/paged_decode_int8_kv_tinyllama",
                  "kernel/paged_decode_bf16_cell",
-                 "kernel/paged_decode_int8_kv_cell"):
+                 "kernel/paged_decode_int8_kv_cell",
+                 "kernel/paged_decode_bf16_windowed",
+                 "kernel/paged_decode_int8_kv_windowed"):
         assert want in names, (want, sorted(names))
     for case, kernels in doc["named"].items():
         want = ("dtx_fused_sample" if "fused_sample" in case else

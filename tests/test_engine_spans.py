@@ -11,6 +11,7 @@ HLO's ``op_name`` metadata, every ``pallas_call``'s ``name`` in the jaxpr.
 
 import dataclasses
 import glob
+import json
 import os
 import re
 import time
@@ -279,16 +280,34 @@ def test_every_pallas_call_is_given_a_name(module, names):
     assert len(calls) == len(names) and sorted(given) == sorted(names)
 
 
-def test_decode_path_reports_the_path_a_windowed_model_takes(monkeypatch):
+@pytest.mark.parametrize("window,built_with", [(32, 32), (512, None)])
+def test_decode_path_reports_the_path_a_windowed_model_takes(
+        monkeypatch, capfd, window, built_with):
     windowed = dataclasses.replace(PRESETS["debug"], name="debug-window",
-                                   sliding_window=32)
+                                   sliding_window=window)
     monkeypatch.setitem(PRESETS, "debug-window", windowed)
     eng = BatchedEngine("preset:debug-window", template="vanilla",
                         max_seq_len=256, slots=2, decode_chunk=4,
                         kv_block_size=16, paged_kernel="on")
     try:
-        # the flag is on, and forward() still drops to the gather
-        assert eng.paged_kernel and eng.decode_path == "gather"
+        # forward() hands the token step to the kernel, window and all; one
+        # the cache of 256 lanes cannot exceed is dropped there
+        assert eng.paged_kernel and eng.decode_path == "pallas"
+        assert eng.decode_window == built_with
+        lines = [ln for ln in capfd.readouterr().err.splitlines()
+                 if ln.startswith("[engine] ")]
+        assert json.loads(lines[0][len("[engine] "):])[
+            "decode_window"] == built_with
+        assert f"sliding_window={window}" in lines[1]
+        assert ("dropped" in lines[1]) == (built_with is None)
+        from datatunerx_tpu.serving import server as serving
+
+        monkeypatch.setattr(serving.STATE, "engine", eng)
+        text = serving.metrics_text()
+        assert 'dtx_serving_decode_path{path="pallas"} 1' in text
+        assert [ln for ln in text.splitlines()
+                if ln.startswith("dtx_serving_decode_window")] == (
+            ["dtx_serving_decode_window 32"] if built_with else [])
         assert eng.generate(eng.tokenizer.encode("a b c"), max_new_tokens=3)
     finally:
         eng.close()

@@ -414,6 +414,37 @@ def _migrated_resume(eng):
     return out
 
 
+# a model with a sliding window: its token step takes the decode kernel too,
+# which drops a window the cache (256 lanes here, two trips of 128) cannot
+# exceed and starts its walk at the first trip of a narrower one
+def _windowed_traffic(eng):
+    """Prompts shorter than a window of 32, longer than it, and longer than a
+    trip of the kernel's walk, off the 64-token bucket, alone and in flight
+    together."""
+    prompts = [list(range(3, 3 + n)) for n in (5, 70, 150)]
+    out = [eng.generate(p, max_new_tokens=12, **kw)
+           for p in prompts for kw in ({}, _SAMPLED)]
+    reqs = [eng.submit(p, max_new_tokens=8 + 6 * i)
+            for i, p in enumerate(prompts[1:])]
+    for r in reqs:
+        assert r.done.wait(300) and r.error is None, r.error
+    return out + [r.tokens for r in reqs]
+
+
+def _windowed_prefix_extension(eng):
+    """A strict-prefix hit extends a row of 128 lanes by a suffix of 4 tokens
+    behind 60 pads: a window of 32 then reaches over the pads into the
+    prefix, a trip before the one ``cursor + 1 - window`` lies in."""
+    p1 = list(range(3, 123))
+    p2 = p1 + [7, 8, 9, 10]
+    out = [eng.generate(p1, max_new_tokens=6),
+           eng.generate(p2, max_new_tokens=24),
+           eng.generate(p2, max_new_tokens=24, **_SAMPLED)]
+    modes = {e[3] for e in eng.sched_trace if e[0] == "admit"}
+    assert "cow_extend" in modes, modes
+    return out
+
+
 _CURSOR_PATHS = {
     "off_bucket_prompt": ({}, _off_bucket_prompts),
     "cow_prefix_hit": (dict(kv_overcommit="on", prefix_cache=4),
@@ -423,24 +454,42 @@ _CURSOR_PATHS = {
     "spec_rejections": (dict(slots=3, spec_draft="take:1", spec_k=3,
                              spec_mode="on"), _spec_rejections),
     "migrated_resume": ({}, _migrated_resume),
+    "window_32": (dict(window=32), _windowed_traffic),
+    "window_32_prefix_extension": (
+        dict(window=32, kv_overcommit="on", prefix_cache=4),
+        _windowed_prefix_extension),
+    "window_512_dropped": (dict(window=512), _windowed_traffic),
 }
 
 
 @pytest.mark.parametrize("path", sorted(_CURSOR_PATHS))
-def test_kernel_matches_gather_where_the_cursor_or_table_moves(path):
+def test_kernel_matches_gather_where_the_cursor_or_table_moves(
+        path, monkeypatch):
     """The decode kernel walks a slot's table as far as its cursor, so every
     path that sets a cursor or a table has to leave them telling the truth.
     (int8 pools: test_kernel_int8_kv_parity; pooled adapters:
     test_kernel_pooled_adapter_parity; chunked prefill's handoff:
-    test_kernel_chunked_prefill_handoff.)"""
+    test_kernel_chunked_prefill_handoff.) ``window`` gives the model a
+    sliding window."""
     extra, scenario = _CURSOR_PATHS[path]
     kw = dict(dict(template="vanilla", max_seq_len=256, slots=2,
                    decode_chunk=4, kv_block_size=16), **extra)
+    model, window = MODEL, kw.pop("window", None)
+    if window:
+        import dataclasses
+
+        from datatunerx_tpu.models.config import PRESETS
+
+        model = "preset:debug-window"
+        monkeypatch.setitem(PRESETS, "debug-window", dataclasses.replace(
+            PRESETS["debug"], name="debug-window", sliding_window=window))
     streams = {}
     for mode in ("off", "on"):
-        eng = BatchedEngine(MODEL, paged_kernel=mode, **kw)
+        eng = BatchedEngine(model, paged_kernel=mode, **kw)
         try:
             assert eng.decode_path == ("pallas" if mode == "on" else "gather")
+            assert eng.decode_window == (32 if mode == "on" and window == 32
+                                         else None)
             streams[mode] = scenario(eng)
         finally:
             eng.close()

@@ -78,9 +78,11 @@ def _make_pool(key, B, NB, KV, d, lens, tables, dtype=jnp.float32,
     return kq_pool, vq_pool, ks_pool, vs_pool, pos, k_rows, v_rows
 
 
-def _oracle(q, k_pool, v_pool, ks, vs, tables, pos, q_positions, dtype):
+def _oracle(q, k_pool, v_pool, ks, vs, tables, pos, q_positions, dtype,
+            window=None):
     """The gather path, element for element: clamp the table, gather the
-    linear view, sentinel-mask the positions, bias, xla_attention."""
+    linear view, sentinel-mask the positions, bias (the model's sliding
+    ``window`` in it), xla_attention."""
     B = q.shape[0]
     tbl = jnp.where(tables >= 0, tables, 0)
     k_all = k_pool[tbl].reshape(B, -1, k_pool.shape[-2], k_pool.shape[-1])
@@ -95,7 +97,8 @@ def _oracle(q, k_pool, v_pool, ks, vs, tables, pos, q_positions, dtype):
     kv_pos = pos[tbl]  # [B, nbps, BS]
     kv_pos = jnp.where((tables >= 0)[:, :, None], kv_pos, POS_SENTINEL)
     kv_pos = kv_pos.reshape(B, -1)
-    bias = make_causal_bias(q_positions[:, None], kv_pos)
+    bias = make_causal_bias(q_positions[:, None], kv_pos,
+                            sliding_window=window)
     return xla_attention(q[:, None].astype(dtype), k_all, v_all, bias)[:, 0]
 
 
@@ -222,20 +225,30 @@ def test_empty_slot_yields_finite_output():
 # ------------------------------------------------- the walk's bound (cursor)
 
 # one compile a (shape, dtype) for the cases below, which differ in values
-_jitted_decode = jax.jit(paged_decode_attention)
+_jitted_decode = jax.jit(paged_decode_attention, static_argnames=("window",))
 
 
 def _cursor_run(cursors, pads=None, nbps=5, KV=2, G=2, d=16,
-                dtype=jnp.float32, quant=False, past=None, seed=0):
+                dtype=jnp.float32, quant=False, past=None, seed=0,
+                window=None, gaps=None, holes=(), poison_before=None):
     """Slots whose lane cursors are ``cursors``: slot ``b`` has lanes
     ``0 .. cursors[b]`` written, the query its last one. Its first ``pads[b]``
     lanes are a prompt's left padding (sentinel position, junk K/V), the rope
     positions count from the lane after them. ``past`` fills every table
     column past the cursor with a VALID block of sentinel positions holding
     ``"finite"`` large values or ``"nan"`` (NaN scales for the int8 pools,
-    which hold none): the oracle reads the table cut at the cursor."""
+    which hold none): the oracle reads the table cut at the cursor.
+
+    ``window`` is the model's sliding window, given to kernel and oracle
+    alike. ``gaps[b] = (lane, n)`` puts ``n`` more pad lanes mid-row, from
+    ``lane`` on (what a prefix-cache extension leaves). ``holes`` lists
+    ``(slot, column)`` table entries set to -1 after the writes, for both.
+    ``poison_before[b]`` columns at the head of slot ``b``'s table hold NaN
+    in the kernel's pools (NaN scales for int8) and zeros in the oracle's,
+    whose mask drops them: the kernel must not have copied them."""
     B, H = len(cursors), KV * G
     pads = pads or (0,) * B
+    gaps = gaps or ((0, 0),) * B
     NB = B * nbps
     rng = np.random.default_rng(seed)
     junk = {None: 0.0, "finite": 3e4, "nan": np.nan}[past]
@@ -246,6 +259,7 @@ def _cursor_run(cursors, pads=None, nbps=5, KV=2, G=2, d=16,
     cut = tables.copy()
     for b, (c, pad) in enumerate(zip(cursors, pads)):
         assert pad <= c < nbps * BS
+        gap_at, gap_n = gaps[b]
         held = c // BS + 1
         tables[b, :held if past is None else nbps] = np.arange(
             b * nbps, b * nbps + (held if past is None else nbps))
@@ -254,8 +268,8 @@ def _cursor_run(cursors, pads=None, nbps=5, KV=2, G=2, d=16,
             blk, off = tables[b, lane // BS], lane % BS
             k_pool[blk, off] = rng.standard_normal((KV, d))
             v_pool[blk, off] = rng.standard_normal((KV, d))
-            if lane >= pad:
-                pos[blk, off] = lane - pad
+            if lane >= pad and not gap_at <= lane < gap_at + gap_n:
+                pos[blk, off] = lane - pad - gap_n * (lane >= gap_at)
         # the unwritten lanes of the cursor's own block are a scrubbed block's
         k_pool[tables[b, c // BS], c % BS + 1:] = 0.0
         v_pool[tables[b, c // BS], c % BS + 1:] = 0.0
@@ -274,14 +288,24 @@ def _cursor_run(cursors, pads=None, nbps=5, KV=2, G=2, d=16,
                  ks.at[beyond].set(junk), vs.at[beyond].set(junk)]
     elif past is not None:
         pools = [kp, vp, None, None]
+    if poison_before:
+        bad = np.concatenate([tables[b, :n] for b, n in
+                              enumerate(poison_before)])
+        clean = [a if a is None or a.dtype == jnp.int8
+                 else a.at[bad].set(0) for a in clean]
+        pools = [a if a is None or a.dtype == jnp.int8
+                 else a.at[bad].set(np.nan) for a in pools]
+    for b, col in holes:
+        tables[b, col] = cut[b, col] = -1
     q = jnp.asarray(rng.standard_normal((B, H, d))).astype(dtype)
     cursor = jnp.asarray(cursors, jnp.int32)
-    q_positions = cursor - jnp.asarray(pads, jnp.int32)
+    q_positions = cursor - jnp.asarray(
+        [pad + n for pad, (_, n) in zip(pads, gaps)], jnp.int32)
     tables, cut, pos = jnp.asarray(tables), jnp.asarray(cut), jnp.asarray(pos)
     got = _jitted_decode(
         q, *(_stacked(a) for a in pools), LAYER, tables, pos, q_positions,
-        cursor)
-    want = _oracle(q, *clean, cut, pos, q_positions, dtype)
+        cursor, window=window)
+    want = _oracle(q, *clean, cut, pos, q_positions, dtype, window=window)
     return np.asarray(got, np.float32), np.asarray(want, np.float32)
 
 
@@ -323,6 +347,164 @@ def test_left_padded_rows_are_bounded_by_the_cursor(pad, dtype):
         np.testing.assert_array_equal(got, want)
     else:
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+# ------------------------------------------- the walk's first trip (window)
+
+# a table of 40 columns is 320 lanes: two whole trips of 128 and half a one
+_W_NBPS = 40
+_W_LANES = 128
+_W_HEADS = {"gqa4": dict(KV=2, G=4), "mha": dict(KV=4, G=1)}
+
+
+def _window_of(name, n_tokens):
+    """None; behind the longest slot's query; that slot's token count, which
+    admits every key by one; wider than the cache, which is dropped."""
+    return {"none": None, "short": 100, "len": n_tokens,
+            "wide": _W_NBPS * BS + 80}[name]
+
+
+@pytest.mark.parametrize("pad", [0, 1, BS - 1, BS + 3])
+@pytest.mark.parametrize("pools", ["bf16", "int8"])
+@pytest.mark.parametrize("heads", sorted(_W_HEADS))
+@pytest.mark.parametrize("window", ["none", "short", "len", "wide"])
+def test_windowed_walk_matches_the_windowed_oracle_bitwise(window, heads,
+                                                           pools, pad):
+    """Three slots of 250, 141 and 31 tokens behind ``pad`` left pads: under
+    the short window the first starts its walk inside the second trip, the
+    second inside the first, and the third sees all it has. The oracle is
+    ``xla_attention`` over the gathered view under ``sliding_window``."""
+    cursors = (pad + 249, pad + 140, pad + 30)
+    got, want = _cursor_run(
+        cursors, pads=(pad,) * 3, nbps=_W_NBPS, dtype=jnp.bfloat16,
+        quant=pools == "int8", past="finite",
+        window=_window_of(window, 250), **_W_HEADS[heads])
+    np.testing.assert_array_equal(got, want)
+
+
+_WINDOW_CASES = {
+    # first admitted lane 229 of [128, 256)
+    "starts_mid_trip": dict(cursors=(300, 60)),
+    # first admitted lanes 128 and (under a window of 45) 256: a trip's first
+    "starts_at_a_trips_first_lane": dict(cursors=(199, 300), also={1: 45}),
+    # column 30 of a window over columns 28 .. 37 holds no block
+    "hole_inside_the_window": dict(cursors=(300, 150),
+                                   holes=((0, 30), (1, 10))),
+    # pads mid-row put the first admitted lane 9 lanes before
+    # cursor + 1 - window = 132, inside the trip before
+    "pads_mid_row": dict(cursors=(203, 90), pads=(3, 0),
+                         gaps=((150, 9), (40, BS))),
+    # no column of slot 0's window, 28 .. 37, holds a block
+    "window_holds_no_block": dict(cursors=(300, 40),
+                                  holes=tuple((0, c) for c in range(28, 38))),
+}
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("case", sorted(_WINDOW_CASES))
+def test_windowed_walk_at_the_edges(case, dtype):
+    """A window of 72 lanes over tables of 320, NaN past every cursor."""
+    kw = dict(_WINDOW_CASES[case])
+    also = kw.pop("also", {})
+    for window, rows in [(72, slice(None))] + [(w, b) for b, w in also.items()]:
+        got, want = _cursor_run(
+            nbps=_W_NBPS, window=window, past="nan",
+            dtype=jnp.float32 if dtype == "f32" else jnp.bfloat16,
+            quant=dtype == "int8", **kw)
+        if case == "window_holds_no_block":
+            # no lane is admitted: zeros, as for a slot with nothing to walk
+            # (the oracle's row is uniform garbage)
+            np.testing.assert_array_equal(got[0], 0.0)
+            rows = 1
+        if dtype == "f32":
+            np.testing.assert_allclose(got[rows], want[rows], rtol=1e-5,
+                                       atol=1e-6)
+        else:
+            np.testing.assert_array_equal(got[rows], want[rows])
+
+
+@pytest.mark.parametrize("tables", ["all_unallocated", "held_and_scrubbed"])
+def test_windowed_slot_with_nothing_written_yields_zeros(tables):
+    """A released slot (a table of -1) and one admitted but not yet written
+    (blocks held, every position the sentinel) beside a live one: no lane is
+    admitted, no trip is walked, and zeros are stored."""
+    B, KV, G, d, nbps, NB = 2, 2, 2, 16, _W_NBPS, 2 * _W_NBPS
+    rng = np.random.default_rng(0)
+    pool = jnp.asarray(rng.standard_normal((LAYERS, NB, BS, KV * d)),
+                       jnp.bfloat16)
+    table = np.full((B, nbps), -1, np.int32)
+    table[0] = np.arange(nbps)
+    if tables == "held_and_scrubbed":
+        table[1] = nbps + np.arange(nbps)
+    pos = np.full((NB, BS), POS_SENTINEL, np.int32)
+    pos[:nbps] = np.arange(nbps * BS).reshape(nbps, BS)
+    got = _jitted_decode(
+        jnp.asarray(rng.standard_normal((B, KV * G, d)), jnp.bfloat16),
+        pool, pool, None, None, LAYER, jnp.asarray(table), jnp.asarray(pos),
+        jnp.asarray([200, 0], jnp.int32), jnp.asarray([200, 0], jnp.int32),
+        window=64)
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all() and np.abs(got[0]).max() > 0
+    np.testing.assert_array_equal(got[1], 0.0)
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("cursor", [2 * _W_LANES - 1, 2 * _W_LANES,
+                                    _W_NBPS * BS - 1])
+def test_columns_before_the_windows_first_trip_are_never_copied(cursor,
+                                                                dtype):
+    """NaN in every column of the trips before the one the window starts in
+    (NaN scales for the int8 pools): a copy of one of them would put NaN in
+    a buffer and ``0 * NaN`` in the sum. The columns of the first trip that
+    lie before the window ARE copied and masked."""
+    window, pad = 72, 3
+    cursors = (cursor, cursor - _W_LANES)
+    first_trip = [(c + 1 - window) // _W_LANES for c in cursors]
+    assert first_trip[0] >= 1
+    got, want = _cursor_run(
+        cursors, pads=(pad,) * 2, nbps=_W_NBPS, window=window, past="nan",
+        dtype=jnp.float32 if dtype == "f32" else jnp.bfloat16,
+        quant=dtype == "int8",
+        poison_before=[t * _W_LANES // BS for t in first_trip])
+    assert np.isfinite(got).all()
+    if dtype == "f32":
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    else:
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_a_window_the_cache_cannot_exceed_lowers_to_the_windowless_call(quant):
+    """``window=None`` and ``window >= table columns x block size`` are the
+    program a call with no ``window`` argument lowers to (the parent's
+    call); a narrower one takes one more scalar-prefetch operand."""
+    B, KV, G, d, nbps, NB = 4, 2, 4, 16, _W_NBPS, 8
+    pool = jnp.zeros((LAYERS, NB, BS, KV * d),
+                     jnp.int8 if quant else jnp.bfloat16)
+    scale = jnp.zeros((LAYERS, NB, BS, KV), jnp.float32) if quant else None
+    ints = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    q = jnp.zeros((B, KV * G, d), jnp.bfloat16)
+
+    def lowered(**kw):
+        return jax.jit(lambda q: paged_decode_attention(
+            q, pool, pool, scale, scale, LAYER, ints(B, nbps), ints(NB, BS),
+            ints(B), ints(B), **kw)).lower(q).as_text()
+
+    base = lowered()
+    assert lowered(window=None) == base
+    assert lowered(window=nbps * BS) == base
+    assert lowered(window=nbps * BS + 4096) == base
+    narrower = lowered(window=nbps * BS - 1)
+    assert narrower != base
+
+    def prefetched(**kw):
+        jaxpr = jax.make_jaxpr(lambda q: paged_decode_attention(
+            q, pool, pool, scale, scale, LAYER, ints(B, nbps), ints(NB, BS),
+            ints(B), ints(B), **kw))(q)
+        call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        return call.params["grid_mapping"].num_index_operands
+
+    assert prefetched() == 4 and prefetched(window=nbps * BS - 1) == 5
 
 
 def test_grid_steps_follow_the_slots_not_the_table():
