@@ -56,7 +56,8 @@ class ModelConfig:
     # default is today's single kind: all layers attend alike (the fields
     # above) and feed forward through one dense SwiGLU. ``layer_types`` names
     # each layer's mixer ("global" | "window" softmax attention, "mla" latent
-    # attention, "kda" linear attention) and ``ffn_types`` its feed-forward
+    # attention, "kda" linear attention, "ssm" state space) and ``ffn_types``
+    # its feed-forward
     # ("dense" | "experts"); a model that sets either is run by runs of like
     # layers (``layer_runs``), each stacked and scanned. The fields above keep
     # their published meaning for such a model: num_kv_heads/rope_theta are
@@ -65,7 +66,9 @@ class ModelConfig:
     layer_types: Optional[tuple] = None
     ffn_types: Optional[tuple] = None
     v_head_dim: Optional[int] = None  # value/output head width; head_dim if None
-    partial_rotary_factor: float = 1.0  # RoPE on the first int(head_dim*f) dims
+    # RoPE on the first int(head_dim*f) dims; 0: no rotation at all (a model
+    # whose order comes from its recurrent layers)
+    partial_rotary_factor: float = 1.0
     attention_value_scale: float = 1.0  # v <- scale * v before cache and product
     window_num_kv_heads: Optional[int] = None  # KV heads of window layers
     window_rope_theta: Optional[float] = None
@@ -97,6 +100,24 @@ class ModelConfig:
     # causal convolution before them, a decay gate bounded below
     kda_conv_kernel: int = 4
     kda_lower_bound: float = -5.0
+    # "ssm" layers (Mamba-2): ``ssm_heads`` heads of ``ssm_head_dim`` channels
+    # (together ``ssm_expand * hidden_size``), a state of ``ssm_state`` per
+    # channel, B and C shared by the heads of each of ``ssm_groups`` groups, a
+    # short causal convolution with bias before them
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_expand: int = 2
+    # four scalars on the residual stream (Granite): the embedding is
+    # multiplied by the first, attention scores by the second (None:
+    # ``head_dim ** -0.5``), each branch by the third before it is added, and
+    # the logits are divided by the fourth
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -148,6 +169,7 @@ class AttentionKind:
     window: Optional[int]
     sink: bool
     value_scale: float
+    scale: Optional[float] = None  # of the scores; ``head_dim ** -0.5`` if None
 
     def pools(self) -> dict:
         """{cache leaf: row width} of the rows this kind caches per token."""
@@ -206,10 +228,42 @@ class KdaKind:
 
 
 @dataclasses.dataclass(frozen=True)
+class SsmKind:
+    """State space (Mamba-2): no rows, a state of constant size per slot with
+    one scalar decay a head (ops/ssm.py)."""
+    heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv_kernel: int
+    expand: int
+    name: str = "ssm"
+    window = None
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution sees: ``[x | B | C]``."""
+        return self.inner + 2 * self.groups * self.state
+
+    def pools(self) -> dict:
+        return {}
+
+    def states(self, cfg) -> dict:
+        """As ``KdaKind.states``: the float32 state per head, and the last
+        pre-convolution rows of ``[x | B | C]``."""
+        return {"state_ssm": ((self.heads, self.head_dim, self.state), "float32"),
+                "state_ssm_conv": ((self.conv_kernel - 1, self.conv_dim), None)}
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerRun:
     """``count`` consecutive layers of one kind, stacked and scanned together.
-    ``mixer`` is what mixes tokens in them (an ``AttentionKind``, ``MlaKind``
-    or ``KdaKind``); ``kind_start`` is where they lie among the layers of
+    ``mixer`` is what mixes tokens in them (an ``AttentionKind``, ``MlaKind``,
+    ``KdaKind`` or ``SsmKind``); ``kind_start`` is where they lie among the layers of
     their mixer kind (the layer axis of that kind's cache leaves)."""
     mixer: object
     ffn: str  # "dense" | "experts"
@@ -223,7 +277,8 @@ def mixer_kinds(cfg: ModelConfig) -> dict:
         ("window" if cfg.sliding_window else "global",) * cfg.num_layers)
     rotary = int(cfg.head_dim * cfg.partial_rotary_factor)  # dtxlint: disable=DTX001 — config scalars, host only
     common = dict(head_dim=cfg.head_dim, v_head_dim=cfg.v_head_dim or cfg.head_dim,
-                  rotary_dim=rotary - rotary % 2, value_scale=cfg.attention_value_scale)
+                  rotary_dim=rotary - rotary % 2, value_scale=cfg.attention_value_scale,
+                  scale=cfg.attention_multiplier)
     kinds = {}
     if "global" in types:
         kinds["global"] = AttentionKind(
@@ -245,6 +300,13 @@ def mixer_kinds(cfg: ModelConfig) -> dict:
         kinds["kda"] = KdaKind(
             head_dim=cfg.head_dim, v_head_dim=cfg.v_head_dim or cfg.head_dim,
             conv_kernel=cfg.kda_conv_kernel, lower_bound=cfg.kda_lower_bound)
+    if "ssm" in types:
+        assert cfg.ssm_heads * cfg.ssm_head_dim == cfg.ssm_expand * cfg.hidden_size
+        assert cfg.ssm_state > 0 and cfg.ssm_heads % cfg.ssm_groups == 0
+        kinds["ssm"] = SsmKind(
+            heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim, state=cfg.ssm_state,
+            groups=cfg.ssm_groups, conv_kernel=cfg.ssm_conv_kernel,
+            expand=cfg.ssm_expand)
     return kinds
 
 
@@ -285,20 +347,24 @@ def refuse_hybrid(cfg: ModelConfig, what: str) -> None:
     """One clear message from every entry that handles only the single-kind
     decoder: a model with layers of several kinds is served, not ``what``."""
     if cfg.hybrid:
+        named = sorted(set(cfg.layer_types or ()) | set(cfg.ffn_types or ()))
         raise NotImplementedError(
-            f"model {cfg.name!r} has layers of several kinds (window and global "
-            f"attention, sparse experts): it is served by the batched engine, "
-            f"and {what} does not handle it yet")
+            f"model {cfg.name!r} has layers of several kinds ({', '.join(named)}): "
+            f"it is served by the batched engine, and {what} does not handle "
+            f"it yet")
 
 
 def refuse_recurrent_state(cfg: ModelConfig, what: str) -> None:
     """One clear message from every entry that would have to SNAPSHOT a slot's
     recurrent state (rows can be trimmed at a cursor; a state cannot)."""
     if has_recurrent_state(cfg):
+        named = sorted(name for name, kind in mixer_kinds(cfg).items()
+                       if kind.states(cfg))
         raise NotImplementedError(
-            f"model {cfg.name!r} has linear-attention layers whose per-slot "
-            f"recurrent state cannot be rewound to an earlier cursor: {what} "
-            f"needs snapshots of that state and does not handle it yet")
+            f"model {cfg.name!r} has layers ({', '.join(named)}) that keep a "
+            f"recurrent state per slot, which cannot be rewound to an earlier "
+            f"cursor: {what} needs snapshots of that state and does not handle "
+            f"it yet")
 
 
 PRESETS = {
@@ -364,6 +430,18 @@ PRESETS = {
         experts_total=16, experts_held=4, first_held=0, experts_per_token=2,
         expert_intermediate_size=32, n_group=4, topk_group=2,
         shared_expert_intermediate_size=32, routed_scaling_factor=2.5,
+    ),
+    # Debug size of a model whose mixers are state-space layers (Mamba-2: a
+    # recurrent state per slot, no rows) and softmax attention without
+    # positions, with Granite's four multipliers, dense feed-forward, tied head.
+    "debug-granite": ModelConfig(
+        name="debug-granite", vocab_size=512, hidden_size=64, intermediate_size=128,
+        num_layers=6, num_heads=4, num_kv_heads=2, head_dim=16, max_seq_len=512,
+        tie_word_embeddings=True, partial_rotary_factor=0.0,
+        layer_types=("ssm", "ssm", "global", "ssm", "ssm", "ssm"),
+        ssm_heads=8, ssm_head_dim=16, ssm_state=32, ssm_groups=1,
+        embedding_multiplier=12.0, attention_multiplier=0.125,
+        residual_multiplier=0.22, logits_scaling=8.0,
     ),
     "qwen1.5-7b": ModelConfig(
         name="qwen1.5-7b", vocab_size=151936, hidden_size=4096,

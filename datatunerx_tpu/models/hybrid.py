@@ -1,6 +1,7 @@
 """A decoder whose layers are of several kinds: window and global softmax
-attention with KV geometry of their own, latent attention (MLA) and linear
-attention (KDA) in one stack, dense and sparse-expert feed-forward layers.
+attention with KV geometry of their own, latent attention (MLA), linear
+attention (KDA) and state-space layers (Mamba-2) in one stack, dense and
+sparse-expert feed-forward layers.
 
 ``models/config.py:layer_runs`` describes the model as runs of like layers;
 this module stacks each run's parameters ``[n, ...]`` and scans it, so the
@@ -14,8 +15,9 @@ Every layer is a pre-norm residual block (RMSNorm, no bias anywhere):
 - attention, both kinds: q and k heads of width ``head_dim``, v heads of
   width ``v_head_dim``; RoPE on the first ``rotary_dim`` dims of each q and k
   head (half-split), the rest pass through; ``v <- value_scale * v`` before
-  the cache and the product; scores scaled by ``head_dim ** -0.5``, softmax
-  in float32. A window layer sees key j from query i iff ``0 <= i - j <
+  the cache and the product; scores scaled by ``head_dim ** -0.5`` (or the
+  model's ``attention_multiplier``), softmax in float32; ``rotary_dim`` 0 is
+  no rotation at all. A window layer sees key j from query i iff ``0 <= i - j <
   window`` (ops/attention.py:attention_allow), has its own number of KV heads
   and its own RoPE theta, and a learned sink logit per head
   (``attention_sink_bias``).
@@ -29,8 +31,17 @@ Every layer is a pre-norm residual block (RMSNorm, no bias anywhere):
   key channel from ``f_proj`` and a write strength per head from ``b_proj``
   drive the gated delta rule on a float32 state per head; the read-out is
   RMS-normed per head and gated channel-wise (``g_proj``).
+- state space (``ssm``, ops/ssm.py): one ``in_proj`` gives ``[z | x B C |
+  dt]``; ``[x | B | C]`` pass a short causal convolution with bias and SiLU;
+  ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)`` per head drive ``S <-
+  exp(dt A) S + dt x (x) B`` on a float32 state per head, read out as ``S C + D
+  x``; the read-out is gated by ``SiLU(z)`` and THEN RMS-normed, per group.
 - dense feed-forward: SwiGLU; expert feed-forward: ops/moe.py, plus one
   shared expert (SwiGLU over every row) where the model has one.
+- Granite's scalars, each 1 (None) unless the config says otherwise: the
+  embedding times ``embedding_multiplier``, every branch times
+  ``residual_multiplier`` before it joins the stream, the logits divided by
+  ``logits_scaling``; a tied head reads the embedding.
 
 Param tree (HF leaf names):
   embed_tokens.embedding [V, D];  norm.scale [D];  lm_head.kernel [D, V]
@@ -40,6 +51,8 @@ Param tree (HF leaf names):
   layers.run<i>.{q,kv_a,kv_b,g,o}_proj.kernel, kv_a_layernorm.scale   (mla runs)
   layers.run<i>.{q,k,v,f,b,g,o}_proj.kernel, conv.kernel [n, C, K],
       A_log [n, H], dt_bias [n, H*d], o_norm.scale [n, d_v]            (kda runs)
+  layers.run<i>.{in,o}_proj.kernel, conv.{kernel [n, C, K], bias [n, C]},
+      A_log, D, dt_bias [n, H], ssm_norm.scale [n, H*P]                (ssm runs)
   layers.run<i>.{gate,up,down}_proj.kernel                (dense runs)
   layers.run<i>.router.kernel [n, D, E_total]             (expert runs)
   layers.run<i>.e_score_correction_bias [n, E_total]
@@ -51,9 +64,10 @@ Cache, two kinds of leaf in one dict (ops/paged_attention.py tells them apart:
 ``kv_leaf_keys`` / ``state_leaf_keys``). POOLS hold rows and are moved by
 block table: one ``k_<kind>``/``v_<kind>`` pair per softmax-attention kind,
 one ``k_mla`` (no v pool) for latent attention. STATE leaves hold what a
-linear-attention layer remembers, of constant size per slot, ``[layers of the
-kind, slots, ...]``, moved by slot: ``state_kda`` (float32 ``[.., H, d_k,
-d_v]``) and ``state_kda_conv`` (the last pre-convolution rows). A slot whose
+linear-attention or state-space layer remembers, of constant size per slot,
+``[layers of the kind, slots, ...]``, moved by slot: ``state_kda`` (float32
+``[.., H, d_k, d_v]``) and ``state_kda_conv`` (the last pre-convolution rows);
+``state_ssm`` (float32 ``[.., H, P, N]``) and ``state_ssm_conv``. A slot whose
 cursor is 0 reads its state as zero, so admission resets nothing. A pool has
 its kind's head count and the two widths, ``[layers of the kind, blocks | rows,
 offset | lane, KV * width]``: laid out as the single-kind cache is but for the
@@ -79,11 +93,12 @@ import jax.numpy as jnp
 
 from datatunerx_tpu.models.config import (
     ModelConfig,
+    has_recurrent_state,
     kind_layers,
     layer_runs,
     mixer_kinds,
 )
-from datatunerx_tpu.ops import kda, mla, moe
+from datatunerx_tpu.ops import kda, mla, moe, ssm
 from datatunerx_tpu.ops.attention import (
     KVStep,
     cache_positions_update,
@@ -110,6 +125,9 @@ def attn_dims(cfg: ModelConfig, kind) -> dict:
                 "k_proj": (D, H * kind.head_dim),
                 "v_proj": (D, H * kind.v_head_dim),
                 "o_proj": (H * kind.v_head_dim, D)}
+    if kind.name == "ssm":  # [z | x B C | dt]
+        return {"in_proj": (D, kind.inner + kind.conv_dim + kind.heads),
+                "o_proj": (kind.inner, D)}
     return {"q_proj": (D, H * kind.head_dim),
             "k_proj": (D, kind.num_kv_heads * kind.head_dim),
             "v_proj": (D, kind.num_kv_heads * kind.v_head_dim),
@@ -135,6 +153,11 @@ def mixer_shapes(cfg: ModelConfig, kind) -> dict:
         out[("A_log",)] = (H,)
         out[("dt_bias",)] = (H * kind.head_dim,)
         out[("o_norm", "scale")] = (kind.v_head_dim,)
+    elif kind.name == "ssm":
+        out[("conv", "kernel")] = (kind.conv_dim, kind.conv_kernel)
+        out[("conv", "bias")] = (kind.conv_dim,)
+        out[("A_log",)] = out[("D",)] = out[("dt_bias",)] = (kind.heads,)
+        out[("ssm_norm", "scale")] = (kind.inner,)
     elif kind.sink:
         out[("attention_sink_bias",)] = (H,)
     return out
@@ -175,7 +198,7 @@ def _set(tree: dict, path: tuple, value) -> None:
 
 _DRAW = 1 << 19  # elements a single draw makes
 _TOGETHER = 1 << 26  # a layer's leaves up to this size share one draw
-_CONSTANT = {"scale": 1.0, "A_log": 0.0, "dt_bias": -3.0}  # dt_bias: a decay near exp(-0.24) a token
+_CONSTANT = {"scale": 1.0, "A_log": 0.0, "dt_bias": -3.0, "D": 1.0}  # dt_bias: a decay near exp(-0.24) a token (kda), exp(-0.05) (ssm)
 
 
 def _scale_of(path) -> float:
@@ -345,11 +368,13 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
     x = params["embed_tokens"]["embedding"][tokens]
     if compute_dtype is not None:
         x = x.astype(compute_dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
 
     kinds = mixer_kinds(cfg)
     attending = {name: kind for name, kind in kinds.items() if kind.pools()}
     rope = {name: rope_cos_sin(positions, kind.rotary_dim, theta=kind.rope_theta)
-            for name, kind in attending.items()}
+            for name, kind in attending.items() if kind.rotary_dim}
     valid = attention_mask.astype(bool) if attention_mask is not None else None
     views, bias, cache_pos = {}, {}, None
     if cache is None:
@@ -364,7 +389,7 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
             bias[name] = views[name].bias
     # a slot at cursor 0 starts from nothing, whatever its state leaves hold
     fresh = (jnp.broadcast_to(cache["len"] == 0, (B,))
-             if cache is not None and "kda" in kinds else None)
+             if cache is not None and has_recurrent_state(cfg) else None)
 
     lora_layers, lora_scale = (None, 0.0)
     if lora is not None:
@@ -374,15 +399,15 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
     rows_valid = valid.reshape(B * T) if valid is not None else None
 
     def attention_mixer(kind, h, lp, proj, leaves, li):
-        cos, sin = rope[kind.name]
         view = views.get(kind.name)
         pool_k, pool_v = leaves
         with jax.named_scope("dtx.qkv"):
             q = proj(h, "q_proj").reshape(B, T, H, kind.head_dim)
             k = proj(h, "k_proj").reshape(B, T, kind.num_kv_heads, kind.head_dim)
             v = proj(h, "v_proj").reshape(B, T, kind.num_kv_heads, kind.v_head_dim)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            if kind.rotary_dim:
+                q = apply_rope(q, *rope[kind.name])
+                k = apply_rope(k, *rope[kind.name])
             if kind.value_scale != 1.0:
                 v = v * jnp.asarray(kind.value_scale, v.dtype)
         if view is not None:
@@ -395,7 +420,8 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
         with jax.named_scope("dtx.attn"):
             attn = xla_attention(
                 q, k_att, v_att, bias[kind.name],
-                sink=lp["attention_sink_bias"] if kind.sink else None)
+                sink=lp["attention_sink_bias"] if kind.sink else None,
+                scale=kind.scale)
         return attn.reshape(B, T, H * kind.v_head_dim), (pool_k, pool_v)
 
     def mla_mixer(kind, h, lp, proj, leaves, li):
@@ -473,7 +499,54 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
                  * out_gate.reshape(B, T, H, dv)).astype(h.dtype)
         return o.reshape(B, T, H * dv), leaves
 
-    mixers = {"mla": mla_mixer, "kda": kda_mixer}
+    def ssm_mixer(kind, h, lp, proj, leaves, li):
+        Hs, P, N, G = kind.heads, kind.head_dim, kind.state, kind.groups
+        inner = kind.inner
+        with jax.named_scope("dtx.qkv"):
+            zxbcdt = proj(h, "in_proj")
+        with jax.named_scope("dtx.ssm_conv"):
+            z = zxbcdt[..., :inner]
+            conv_state = None
+            if leaves:
+                conv_state = jnp.where(fresh[:, None, None], 0, leaves[1][li])
+            y, conv_state = kda.short_conv(
+                zxbcdt[..., inner:inner + kind.conv_dim], lp["conv"]["kernel"],
+                conv_state, valid, bias=lp["conv"]["bias"])
+            xs = y[..., :inner].reshape(B, T, Hs, P)
+            Bm = y[..., inner:inner + G * N].reshape(B, T, G, N)
+            Cm = y[..., inner + G * N:].reshape(B, T, G, N)
+            dt, dA = ssm.discretize(zxbcdt[..., -Hs:], lp["dt_bias"], lp["A_log"])
+            if valid is not None:  # a pad moves nothing
+                dt = jnp.where(valid[:, :, None], dt, 0.0)
+                dA = jnp.where(valid[:, :, None], dA, 0.0)
+        with jax.named_scope("dtx.ssm_state"):
+            if leaves:
+                state = jnp.where(fresh[:, None, None, None], 0.0, leaves[0][li])
+            else:
+                state = jnp.zeros((B, Hs, P, N), jnp.float32)
+            if T == 1:
+                o, state = ssm.state_step(
+                    state, xs[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], dA[:, 0], lp["D"])
+                o = o[:, None]
+            else:
+                o, state = ssm.chunk_states(state, xs, Bm, Cm, dt, dA, lp["D"])
+            if leaves:
+                leaves = (leaves[0].at[li].set(state),
+                          leaves[1].at[li].set(conv_state.astype(leaves[1].dtype)))
+        with jax.named_scope("dtx.ssm_out"):
+            # the gate BEFORE the norm, which runs over each group's channels
+            o = o.reshape(B, T, inner) * jax.nn.silu(z.astype(jnp.float32))
+            o = rms_norm(o.reshape(B, T, G, inner // G),
+                         lp["ssm_norm"]["scale"].reshape(G, inner // G),
+                         cfg.rms_norm_eps)
+        return o.reshape(B, T, inner).astype(h.dtype), leaves
+
+    mixers = {"mla": mla_mixer, "kda": kda_mixer, "ssm": ssm_mixer}
+    # a branch joins the stream times the model's residual multiplier
+    if cfg.residual_multiplier == 1.0:
+        branch = lambda y: y  # noqa: E731
+    else:
+        branch = lambda y: y * jnp.asarray(cfg.residual_multiplier, y.dtype)  # noqa: E731
 
     def make_block(run, experts):
         kind = run.mixer
@@ -492,13 +565,13 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
                 h = rms_norm(x, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
             mixed, leaves = mixer(kind, h, lp, proj, leaves, li)
             with jax.named_scope("dtx.attn_out"):
-                x = x + proj(mixed, "o_proj")
+                x = x + branch(proj(mixed, "o_proj"))
             if run.ffn == "dense":
                 with jax.named_scope("dtx.mlp"):
                     h = rms_norm(x, lp["post_attention_layernorm"]["scale"],
                                  cfg.rms_norm_eps)
-                    x = x + proj(jax.nn.silu(proj(h, "gate_proj"))
-                                 * proj(h, "up_proj"), "down_proj")
+                    x = x + branch(proj(jax.nn.silu(proj(h, "gate_proj"))
+                                        * proj(h, "up_proj"), "down_proj"))
             else:
                 with jax.named_scope("dtx.moe_route"):
                     h = rms_norm(x, lp["post_attention_layernorm"]["scale"],
@@ -514,10 +587,10 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
                     with jax.named_scope("dtx.moe_shared"):
                         sp = lp["shared_expert"]
                         dot = lambda a, name: _proj(a, sp[name], None, 0.0)  # noqa: E731
-                        x = x + dot(jax.nn.silu(dot(h, "gate_proj"))
-                                    * dot(h, "up_proj"), "down_proj")
+                        x = x + branch(dot(jax.nn.silu(dot(h, "gate_proj"))
+                                           * dot(h, "up_proj"), "down_proj"))
                 with jax.named_scope("dtx.moe_combine"):
-                    x = x + y.reshape(B, T, D)
+                    x = x + branch(y.reshape(B, T, D))
                     stats = stats + counts
             return (x, leaves, stats), None
 
@@ -546,6 +619,8 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
     with jax.named_scope("dtx.unembed"):
         x = rms_norm(x, params["norm"]["scale"], cfg.rms_norm_eps)
         logits = None if skip_logits else lm_logits(params, x, cfg)
+        if logits is not None and cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
 
     if cache is not None:
         new_cache["len"] = cache["len"] + T

@@ -29,9 +29,11 @@ import jax.numpy as jnp
 from datatunerx_tpu.models.config import ModelConfig
 
 # Valid llama-family targets (reference cmd/tuning/parser.py:150-160).
-LORA_TARGETS = (
+LLAMA_TARGETS = (
     "q_proj", "k_proj", "v_proj", "o_proj", "gate_proj", "up_proj", "down_proj",
 )
+# ``in_proj``: a state-space mixer's one input projection (models/hybrid.py)
+LORA_TARGETS = LLAMA_TARGETS + ("in_proj",)
 DEFAULT_TARGETS = ("q_proj", "v_proj")
 
 
@@ -58,7 +60,7 @@ def lora_groups(cfg: ModelConfig) -> list:
     ``q_proj`` and ``o_proj`` only). Experts take no adapter."""
     if not cfg.hybrid:
         return [(None, cfg.num_layers,
-                 {t: target_dims(cfg, t) for t in LORA_TARGETS})]
+                 {t: target_dims(cfg, t) for t in LLAMA_TARGETS})]
     from datatunerx_tpu.models.config import layer_runs
     from datatunerx_tpu.models.hybrid import attn_dims, run_key
 

@@ -392,8 +392,8 @@ def export_moe_stats(registry: Registry, engine) -> None:
         "query can see (they stay allocated until the request ends).")
     state = registry.gauge(
         "dtx_serving_state_bytes",
-        "Bytes of recurrent state resident for the linear-attention layers "
-        "(constant per slot, whatever the slots' contexts).")
+        "Bytes of recurrent state resident for the linear-attention and "
+        "state-space layers (constant per slot, whatever the slots' contexts).")
     for m in (rows, hit, most, steps, here, seen, tile, behind, state):
         m.clear()
     stats = getattr(engine, "moe_stats", None) or {}

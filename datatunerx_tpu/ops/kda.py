@@ -46,12 +46,12 @@ HIGHEST = jax.lax.Precision.HIGHEST
 CHUNK = 64
 
 
-def short_conv(x, w, state, valid):
+def short_conv(x, w, state, valid, bias=None):
     """Depthwise causal convolution then SiLU. x [B, T, C] pre-convolution
     rows; w [C, K]; state [B, K-1, C] or None (no history); valid [B, T] bool
-    or None. Returns (y [B, T, C] float32, new state [B, K-1, C] in x's dtype):
-    ``y_t = silu(sum_i w[:, i] z_{t-(K-1)+i})`` over the history followed by
-    the real rows."""
+    or None; bias [C] or None. Returns (y [B, T, C] float32, new state [B,
+    K-1, C] in x's dtype): ``y_t = silu(bias + sum_i w[:, i] z_{t-(K-1)+i})``
+    over the history followed by the real rows."""
     B, T, C = x.shape
     K = w.shape[-1]
     if valid is not None:
@@ -78,6 +78,8 @@ def short_conv(x, w, state, valid):
     wf = w.astype(jnp.float32)
     y = sum(ext[:, i:i + T].astype(jnp.float32) * wf[None, None, :, i]
             for i in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y), new_state
 
 

@@ -733,7 +733,7 @@ class BatchedEngine:
                         f"kinds: --{flag} does not handle it yet")
         # a prefix hit, a rejected draft, a preempted or a migrating session
         # all restart a slot at a cursor it has passed: rows are trimmed
-        # there, a linear-attention layer's state cannot be
+        # there, a layer's recurrent state cannot be
         from datatunerx_tpu.models.config import refuse_recurrent_state
 
         for flag, on in (("prefix_cache", prefix_cache > 0),
@@ -1171,7 +1171,7 @@ class BatchedEngine:
 
         # the choices "auto" resolved to, once, where a caller outside the
         # process can read them (chip_smoke.py asserts on this line)
-        print("[engine] " + json.dumps({
+        self.engine_line = {
             "decode_path": self.decode_path,
             "decode_window": self.decode_window,
             "sampling_epilogue": self.sampling_epilogue,
@@ -1181,7 +1181,10 @@ class BatchedEngine:
             "kv_block_size": self.block_size,
             "prefill_chunk": self.prefill_chunk,
             "moe_kernel": self.moe_kernel,
-        }, sort_keys=True), file=sys.stderr, flush=True)
+            "state_bytes": self.state_bytes(),
+        }
+        print("[engine] " + json.dumps(self.engine_line, sort_keys=True),
+              file=sys.stderr, flush=True)
         if self.decode_path == "pallas" and self.cfg.sliding_window:
             window, width = self.cfg.sliding_window, self.max_seq_len
             print(f"[engine] sliding_window={window} "
@@ -1266,8 +1269,8 @@ class BatchedEngine:
 
     def state_bytes(self) -> int:
         """Bytes of recurrent state the cache holds (``state_*`` leaves): what
-        the linear-attention layers keep per slot, resident whether a slot is
-        live or idle."""
+        the linear-attention and state-space layers keep per slot, resident
+        whether a slot is live or idle."""
         cache = self._cache  # shapes only: a donated leaf still has its shape
         return sum(math.prod(cache[key].shape) * cache[key].dtype.itemsize
                    for key in state_leaf_keys(cache))

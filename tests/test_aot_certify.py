@@ -132,9 +132,9 @@ print(json.dumps({"done": done, "failed": failed, "named": named}))
 """
 
 
-def _run_probe(code: str, timeout: int) -> dict:
+def _run_probe(code: str, timeout: int, **more) -> dict:
     env = dict(os.environ, DTX_REPO=REPO,
-               PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))
+               PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""), **more)
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=timeout)
     assert out.returncode == 0, out.stderr[-2000:]
@@ -353,7 +353,11 @@ def test_flash_kernels_compile_for_v5e_at_the_training_cell_shape():
 # them in one chip's 16 GB beside their arguments, alias the state leaves to
 # the result (nothing copies 1.6 GB of state), and run the expert layers'
 # grouped matmuls as ``dtx_moe_gmm`` Mosaic kernels.
-_LING_PROBE = r"""
+# The same probe takes the cell granite-serve-chat (``DTX_CELL``): nine scanned
+# blocks (36 Mamba-2 layers in five runs around 4 attention layers), the
+# recurrent state [36, 64, 64, 64, 128] float32 donated with the cache, adapters
+# on q_proj, in_proj and o_proj, each in the runs that have the projection.
+_CELL_PROBE = r"""
 import json, os, sys
 os.environ["DTX_PALLAS_INTERPRET"] = "0"
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -371,7 +375,7 @@ from datatunerx_tpu.models.lora import lora_groups
 from datatunerx_tpu.ops.paged_attention import init_paged_cache, state_leaf_keys
 from datatunerx_tpu.serving.batched_engine import MAX_STOP, _Programs
 
-cell = spec.load_cell("ling-serve-decode")
+cell = spec.load_cell(os.environ["DTX_CELL"])
 cfg = spec.register_preset(cell)
 eng = cell.workload["engine"]
 S, bs, NB, L = eng["slots"], eng["kv_block_size"], eng["kv_blocks"], eng["max_seq_len"]
@@ -384,7 +388,7 @@ cache = sds(jax.eval_shape(lambda: init_paged_cache(cfg, S, NB, bs, L // bs, dty
 E, r = 3, cell.workload["adapters"]["rank"]  # base + two adapters
 lora = ({"layers": {key: {t: {"a": z((n, E, dims[t][0], r), jnp.bfloat16),
                               "b": z((n, E, r, dims[t][1]), jnp.bfloat16)}
-                          for t in cell.workload["adapters"]["targets"]}
+                          for t in cell.workload["adapters"]["targets"] if t in dims}
                     for key, n, dims in lora_groups(cfg)}}, z((E,), jnp.float32))
 progs = _Programs(cfg, L, None, epilogue="kernel")
 state = sum(cache[k].size * cache[k].dtype.itemsize for k in state_leaf_keys(cache))
@@ -404,10 +408,12 @@ for name, lower in cases.items():
     m, text = c.memory_analysis(), c.as_text()
     out[name] = {"live": m.argument_size_in_bytes + m.temp_size_in_bytes
                  + m.output_size_in_bytes - m.alias_size_in_bytes,
-                 "alias": m.alias_size_in_bytes, "ragged": text.count("%ragged-dot"),
+                 "alias": m.alias_size_in_bytes, "arguments": m.argument_size_in_bytes,
+                 "temporaries": m.temp_size_in_bytes, "ragged": text.count("%ragged-dot"),
                  "gmm": text.count("%dtx_moe_gmm"),
                  "scopes": [s for s in ("dtx.kda_conv", "dtx.kda_state", "dtx.kda_out",
-                                        "dtx.mla_absorb", "dtx.moe_shared") if s in text]}
+                                        "dtx.mla_absorb", "dtx.moe_shared", "dtx.ssm_conv",
+                                        "dtx.ssm_state", "dtx.ssm_out") if s in text]}
 print(json.dumps(out))
 """
 
@@ -415,7 +421,7 @@ print(json.dumps(out))
 @pytest.fixture(scope="module")
 def ling_doc():
     pytest.importorskip("libtpu")  # the TPU compiler; absent from jax[cpu]
-    return _run_probe(_LING_PROBE, timeout=900)
+    return _run_probe(_CELL_PROBE, timeout=900, DTX_CELL="ling-serve-decode")
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk_256"])
@@ -428,6 +434,27 @@ def test_ling_cell_programs_compile_for_v5e_at_published_widths(program, ling_do
     assert got["gmm"] >= 4 and got["ragged"] == 0, got
     assert got["scopes"] == ["dtx.kda_conv", "dtx.kda_state", "dtx.kda_out",
                              "dtx.mla_absorb", "dtx.moe_shared"], got
+
+
+@pytest.fixture(scope="module")
+def granite_doc():
+    pytest.importorskip("libtpu")  # the TPU compiler; absent from jax[cpu]
+    return _run_probe(_CELL_PROBE, timeout=900, DTX_CELL="granite-serve-chat")
+
+
+@pytest.mark.parametrize("program,arguments,temporaries", [
+    ("decode", 11.9e9, 1.6e9), ("prefill_chunk_256", 11.9e9, 0.15e9)])  # read: 11.869 + 1.531, 11.843 + 0.116 GB
+def test_granite_cell_programs_compile_for_v5e_at_full_depth(program, arguments, temporaries, granite_doc):
+    """All 40 layers at the published widths, 64 slots: the numbers quoted in
+    ``benchmarks/workloads/granite-serve-chat.json``'s ``engine_notes``."""
+    got = granite_doc[program]
+    # 36 Mamba-2 layers x 64 slots x (64 x 64 x 128 float32 + 3 x 4,352 bf16): 76.4 MB a slot
+    assert granite_doc["state_bytes"] == 36 * 64 * (2097152 + 26112) == 64 * 76437504
+    assert got["arguments"] < arguments and got["temporaries"] < temporaries, got
+    assert got["live"] < 13.5e9, got  # one chip holds 16 GB
+    assert got["alias"] >= granite_doc["state_bytes"], got  # the donated state is written in place
+    assert got["gmm"] == 0 and got["ragged"] == 0, got  # no routed experts at all
+    assert got["scopes"] == ["dtx.ssm_conv", "dtx.ssm_state", "dtx.ssm_out"], got
 
 
 @pytest.mark.slow
