@@ -1143,6 +1143,94 @@ def child_kernels(rehearsal: bool) -> int:
             in shapes.items()) + "]",
             np.concatenate(got), np.concatenate(want), atol=2e-2, rtol=2e-2)
 
+    # ---- the Mamba-2 token step at the cell granite-serve-chat's shapes (64
+    # slots, one layer of a 36-layer float32 leaf carried and donated, as the
+    # decode program has it): dtx_ssm_step against ssm.state_step, the leaf's
+    # other layers untouched, and both timings over the head tiles
+    def ssm_step():
+        from datatunerx_tpu.ops import pallas_ssm, ssm
+
+        L, B, H, P, N, G = ((36, 64, 64, 64, 128, 1) if not rehearsal
+                            else (3, 4, 8, 8, 128, 1))
+        name, th0 = pallas_ssm.step_kernel(
+            jax.ShapeDtypeStruct((L, B, H, P, N), jnp.float32), 1)
+        assert name == "dtx_ssm_step", (name, th0)
+        li = jnp.asarray(L - 2, jnp.int32)
+
+        @jax.jit
+        def draw():  # on the device, a layer a program step: 4.8 GB
+            return jax.lax.map(
+                lambda k: jax.random.normal(k, (B, H, P, N), jnp.float32),
+                jax.random.split(jax.random.PRNGKey(SEED), L))
+
+        dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), (B, 1, H)))
+        dt[1] = 0.0  # an idle row: its state must come back bit for bit
+        ops = (normal((B, 1, H, P), jnp.float32), normal((B, 1, G, N), jnp.float32),
+               normal((B, 1, G, N), jnp.float32), jnp.asarray(dt, jnp.float32),
+               jnp.asarray(-dt * rng.uniform(1, 16, (H,)), jnp.float32),
+               normal((H,), jnp.float32))
+        fresh = jnp.asarray(np.arange(B) % 8 == 3)
+
+        def xla(leaf, li):
+            state = jnp.where(fresh[:, None, None, None], 0.0, leaf[li])
+            y, state = ssm.state_step(state, *(a[:, 0] for a in ops[:5]), ops[5])
+            return y[:, None], leaf.at[li].set(state)
+
+        def kernel(th):
+            return lambda leaf, li: pallas_ssm.ssm_step(
+                leaf, li, fresh, *ops, th=th)
+
+        def run(fn):
+            return jax.jit(fn, donate_argnums=0)
+
+        before = draw()
+        edge = np.asarray(before[L - 3:, :2, :2])  # the layer stepped and its neighbours
+        want_y, want = run(xla)(before, li)
+        want_y, want_state = np.asarray(want_y), np.asarray(want[li])
+        del want
+        got_y, got = run(kernel(th0))(draw(), li)
+        shape = f"[{L}x{B}x{H}x{P}x{N} th{th0}]"
+        check(f"ssm_step_y_vs_state_step {shape}", got_y, want_y, atol=5e-5, rtol=1e-6)
+        check(f"ssm_step_state_vs_state_step {shape}", got[li], want_state,
+              atol=1e-6, rtol=1e-6)
+        after = np.asarray(got[L - 3:, :2, :2])
+        check(f"ssm_step_other_layers_and_idle_row_untouched {shape}",
+              np.concatenate([after[0].ravel(), after[2].ravel(), after[1, 1].ravel()]),
+              np.concatenate([edge[0].ravel(), edge[2].ravel(), edge[1, 1].ravel()]),
+              atol=0, exact=True)
+
+        def seconds(fn, leaf):
+            # twenty calls in a row and one wait: a call alone is mostly the
+            # host's dispatch and the wait's round trip (0.8 ms on v5e)
+            fn = run(fn)
+            _, leaf = fn(leaf, li)
+            took = []
+            for _ in range(5):
+                jax.block_until_ready(leaf)
+                t0 = time.perf_counter()
+                for _ in range(20):
+                    y, leaf = fn(leaf, li)
+                jax.block_until_ready((y, leaf))
+                took.append((time.perf_counter() - t0) / 20)
+            return float(np.median(took)), leaf
+
+        moved = 2 * B * H * P * N * 4  # the layer's state read once and written once
+        t_xla, leaf = seconds(xla, got)
+        sweep = []
+        for th in [t for t in (8, 16, 32, 64) if H % t == 0] or [th0]:
+            t, leaf = seconds(kernel(th), leaf)
+            sweep.append((th, t))
+        t_kernel = dict(sweep).get(th0) or seconds(kernel(th0), leaf)[0]
+        # a CPU's time for the emulation says nothing: reported, not judged
+        ok = rehearsal or t_kernel < t_xla
+        results.append(ok)
+        print(f"{'PASS' if ok else 'FAIL'} kernel/ssm_step {shape} [dtx_ssm_step "
+              f"{t_kernel * 1e6:.0f} us a layer ({moved / t_kernel / 1e9:.0f} GB/s of "
+              f"state) under ssm.state_step {t_xla * 1e6:.0f} us "
+              f"({moved / t_xla / 1e9:.0f} GB/s); by head tile: "
+              + ", ".join(f"th{th} {t * 1e6:.0f} us" for th, t in sweep) + "]",
+              flush=True)
+
     # ---- one QLoRA train step, --quant_impl pallas against xla: the fused
     # kernels forward AND backward inside the real step program with remat
     def qlora_step():
@@ -1194,6 +1282,7 @@ def child_kernels(rehearsal: bool) -> int:
         for S in sorted({SERVE_SLOTS, 16}):
             guarded(f"fused_sample [S{S} V{V}]", lambda: sampler(S, V))
     guarded("moe_gmm", moe_gmm)
+    guarded("ssm_step", ssm_step)
     if not rehearsal:  # interpret-mode QLoRA steps are slow and tier-1's job
         guarded("qlora_step", qlora_step)
 

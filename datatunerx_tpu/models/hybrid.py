@@ -98,7 +98,7 @@ from datatunerx_tpu.models.config import (
     layer_runs,
     mixer_kinds,
 )
-from datatunerx_tpu.ops import kda, mla, moe, ssm
+from datatunerx_tpu.ops import kda, mla, moe, pallas_ssm, ssm
 from datatunerx_tpu.ops.attention import (
     KVStep,
     cache_positions_update,
@@ -520,18 +520,24 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
                 dt = jnp.where(valid[:, :, None], dt, 0.0)
                 dA = jnp.where(valid[:, :, None], dA, 0.0)
         with jax.named_scope("dtx.ssm_state"):
-            if leaves:
-                state = jnp.where(fresh[:, None, None, None], 0.0, leaves[0][li])
+            _, th = pallas_ssm.step_kernel(leaves[0] if leaves else None, T)
+            if th:  # the leaf whole, stepped in place: no layer sliced out and set back
+                o, stepped = pallas_ssm.ssm_step(
+                    leaves[0], li, fresh, xs, Bm, Cm, dt, dA, lp["D"], th=th)
             else:
-                state = jnp.zeros((B, Hs, P, N), jnp.float32)
-            if T == 1:
-                o, state = ssm.state_step(
-                    state, xs[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], dA[:, 0], lp["D"])
-                o = o[:, None]
-            else:
-                o, state = ssm.chunk_states(state, xs, Bm, Cm, dt, dA, lp["D"])
+                if leaves:
+                    state = jnp.where(fresh[:, None, None, None], 0.0, leaves[0][li])
+                else:
+                    state = jnp.zeros((B, Hs, P, N), jnp.float32)
+                if T == 1:
+                    o, state = ssm.state_step(
+                        state, xs[:, 0], Bm[:, 0], Cm[:, 0], dt[:, 0], dA[:, 0], lp["D"])
+                    o = o[:, None]
+                else:
+                    o, state = ssm.chunk_states(state, xs, Bm, Cm, dt, dA, lp["D"])
+                stepped = leaves[0].at[li].set(state) if leaves else None
             if leaves:
-                leaves = (leaves[0].at[li].set(state),
+                leaves = (stepped,
                           leaves[1].at[li].set(conv_state.astype(leaves[1].dtype)))
         with jax.named_scope("dtx.ssm_out"):
             # the gate BEFORE the norm, which runs over each group's channels

@@ -386,6 +386,11 @@ def export_moe_stats(registry: Registry, engine) -> None:
         "Row tile of the grouped matmul the expert layers run, by phase and "
         "kernel (dtx_moe_gmm: ours, from the rows a group expects; ragged_dot: "
         "XLA's own tiling, stated as 0).")
+    head_tile = registry.gauge(
+        "dtx_serving_state_head_tile",
+        "Heads a block of the state-space layers' token step holds, by phase "
+        "and kernel (dtx_ssm_step: the Pallas kernel that steps the state leaf "
+        "in place; xla: ops/ssm.py's step, stated as 0).")
     behind = registry.gauge(
         "dtx_serving_kv_behind_window_bytes",
         "Bytes of the window layers' KV pool held by blocks that no later "
@@ -394,7 +399,7 @@ def export_moe_stats(registry: Registry, engine) -> None:
         "dtx_serving_state_bytes",
         "Bytes of recurrent state resident for the linear-attention and "
         "state-space layers (constant per slot, whatever the slots' contexts).")
-    for m in (rows, hit, most, steps, here, seen, tile, behind, state):
+    for m in (rows, hit, most, steps, here, seen, tile, head_tile, behind, state):
         m.clear()
     stats = getattr(engine, "moe_stats", None) or {}
     for phase in ("decode", "prefill"):
@@ -408,6 +413,8 @@ def export_moe_stats(registry: Registry, engine) -> None:
             seen.set(stats.get(f"{phase}_rows", 0), label)
     for phase, (kernel, tm) in (getattr(engine, "moe_kernel", None) or {}).items():
         tile.set(tm or 0, {"phase": phase, "kernel": kernel})
+    for phase, (kernel, th) in (getattr(engine, "state_kernel", None) or {}).items():
+        head_tile.set(th or 0, {"phase": phase, "kernel": kernel})
     window_fn = getattr(engine, "kv_window_stats", None)
     window = window_fn() if callable(window_fn) else None
     behind.set(window["behind_bytes"] if window else 0)

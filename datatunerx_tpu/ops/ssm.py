@@ -15,7 +15,12 @@ pre-convolution rows).
 
 Two forms of the same recurrence:
 
-- ``state_step``: one token (decode). S is read once and written once.
+- ``state_step``: one token (decode). The recurrence needs S read once and
+  written once; XLA compiles this form to three passes (one fusion updates S
+  in place, a second reads it again for the read-out). Over a float32 cache
+  leaf ``ops/pallas_ssm.py``'s kernel does the step in the two passes, and
+  this form is what it must equal and what runs wherever the kernel's tiles
+  do not exist.
 - ``chunk_states``: ``T`` tokens in sub-chunks of ``chunk`` rows (prefill).
   Inside a sub-chunk with incoming state ``S0`` and ``L_t = sum_{s<=t} dt_s A``:
 

@@ -935,6 +935,15 @@ class BatchedEngine:
                     experts_total=self.cfg.experts_total, d=self.cfg.hidden_size,
                     f=self.cfg.expert_intermediate_size)
                 for phase, rows in (("decode", slots), ("prefill", self.prefill_chunk))}
+        # what steps the state-space layers' state a decode token
+        # (dtx_serving_state_head_tile): the Pallas kernel at its head tile
+        # where the leaf's shapes give it tiles, else ops/ssm.py's XLA step;
+        # static per program, from shapes alone; empty without such layers
+        self.state_kernel = {}
+        if "state_ssm" in self._cache:
+            from datatunerx_tpu.ops.pallas_ssm import step_kernel
+
+            self.state_kernel = {"decode": step_kernel(self._cache["state_ssm"], 1)}
         # the budget is a HARD bound (prefill chunks are clamped to the
         # remaining budget each tick), so round it up to the bucket quantum —
         # a sub-bucket budget could never admit a chunk and would starve
@@ -1181,6 +1190,7 @@ class BatchedEngine:
             "kv_block_size": self.block_size,
             "prefill_chunk": self.prefill_chunk,
             "moe_kernel": self.moe_kernel,
+            "state_kernel": self.state_kernel,
             "state_bytes": self.state_bytes(),
         }
         print("[engine] " + json.dumps(self.engine_line, sort_keys=True),

@@ -358,7 +358,7 @@ def test_flash_kernels_compile_for_v5e_at_the_training_cell_shape():
 # recurrent state [36, 64, 64, 64, 128] float32 donated with the cache, adapters
 # on q_proj, in_proj and o_proj, each in the runs that have the projection.
 _CELL_PROBE = r"""
-import json, os, sys
+import json, os, re, sys
 os.environ["DTX_PALLAS_INTERPRET"] = "0"
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -403,6 +403,7 @@ cases = {
         params, lora, cache, z((), jnp.int32), row, row, row, z((), jnp.int32), chunk_len=256),
 }
 out = {"state_bytes": state}
+ssm_leaf = r" = f32\[%s\]" % ",".join(map(str, cache["state_ssm"].shape)) if "state_ssm" in cache else "no such leaf"
 for name, lower in cases.items():
     c = lower().compile()
     m, text = c.memory_analysis(), c.as_text()
@@ -411,6 +412,12 @@ for name, lower in cases.items():
                  "alias": m.alias_size_in_bytes, "arguments": m.argument_size_in_bytes,
                  "temporaries": m.temp_size_in_bytes, "ragged": text.count("%ragged-dot"),
                  "gmm": text.count("%dtx_moe_gmm"),
+                 # the state-space token step: its kernel's call sites (under their scope), and
+                 # what else still produces or copies the whole state leaf
+                 "ssm_step": len(re.findall(r"%dtx_ssm_step[.\w]* = ", text)),
+                 "ssm_step_in_scope": len(re.findall(r"%dtx_ssm_step[.\w]* = .*dtx\.ssm_state", text)),
+                 "ssm_leaf_fusions": len(re.findall(ssm_leaf + r"[^ ]* fusion\(", text)),
+                 "ssm_leaf_copies": len(re.findall(ssm_leaf + r"[^ ]* copy\(", text)),
                  "scopes": [s for s in ("dtx.kda_conv", "dtx.kda_state", "dtx.kda_out",
                                         "dtx.mla_absorb", "dtx.moe_shared", "dtx.ssm_conv",
                                         "dtx.ssm_state", "dtx.ssm_out") if s in text]}
@@ -451,10 +458,23 @@ def test_granite_cell_programs_compile_for_v5e_at_full_depth(program, arguments,
     # 36 Mamba-2 layers x 64 slots x (64 x 64 x 128 float32 + 3 x 4,352 bf16): 76.4 MB a slot
     assert granite_doc["state_bytes"] == 36 * 64 * (2097152 + 26112) == 64 * 76437504
     assert got["arguments"] < arguments and got["temporaries"] < temporaries, got
-    assert got["live"] < 13.5e9, got  # one chip holds 16 GB
+    # one chip holds 16 GB. The decode program reads 13.400 GB, as before its token step was a Mosaic
+    # call: without the barrier in front of the call (ops/pallas_ssm.py) XLA kept the adapters'
+    # stacks in its default layout inside the loops and copied them there (13.570 GB, 1.701 of
+    # temporaries)
+    assert got["live"] < 13.5e9, got
     assert got["alias"] >= granite_doc["state_bytes"], got  # the donated state is written in place
     assert got["gmm"] == 0 and got["ragged"] == 0, got  # no routed experts at all
     assert got["scopes"] == ["dtx.ssm_conv", "dtx.ssm_state", "dtx.ssm_out"], got
+    if program == "decode":
+        # the token step is ``dtx_ssm_step``, one call site a run of Mamba-2 layers (5, 9, 9, 9, 4),
+        # under its scope, and it steps the leaf in place inside the layer scan and the 8-step loop:
+        # no fusion still produces the leaf (XLA's step was a select_dynamic-update-slice fusion a
+        # run, and a second fusion read the old state again) and nothing copies it
+        assert got["ssm_step"] == got["ssm_step_in_scope"] == 5, got
+        assert got["ssm_leaf_fusions"] == 0 and got["ssm_leaf_copies"] == 0, got
+    else:  # a chunk of prompt tokens takes ``ssm.chunk_states``, as before
+        assert got["ssm_step"] == 0 and got["ssm_leaf_copies"] == 0, got
 
 
 @pytest.mark.slow

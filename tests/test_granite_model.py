@@ -470,3 +470,107 @@ def test_what_needs_a_snapshot_of_state_refuses_by_name(model, engine, entry):
         assert "several kinds" in said or "per mixer kind" in said
     else:  # the one message, and it names the kind that keeps the state
         assert "recurrent state per slot" in said and "(ssm)" in said and "linear" not in said
+
+
+# ------------------------------------------- the token step as a Pallas kernel
+
+@pytest.fixture(scope="module")
+def wide():
+    """``debug-granite`` with a state of 128: the width at which the token
+    step's kernel has its tiles (ops/pallas_ssm.py; ``debug-granite``'s 32 and
+    every test above take ``ssm.state_step``)."""
+    from datatunerx_tpu.models.config import PRESETS
+
+    cfg = dataclasses.replace(get_config("debug-granite"), name="debug-granite-n128", ssm_state=128)
+    PRESETS[cfg.name] = cfg
+    params = _gained(draw.draw_params(dataclasses.asdict(cfg), 13, dtype=jnp.float32))
+    yield cfg, params
+    del PRESETS[cfg.name]
+
+
+def test_decode_through_the_kernel_equals_the_xla_step_and_the_reference(wide, monkeypatch):
+    """Two slots of a paged cache admitted at different times: slot 0 is
+    prefilled and decodes three tokens while slot 1 is idle at cursor 0 over
+    what an earlier request left; slot 1 is prefilled; both decode five more.
+    The kernel (interpret) against ``ssm.state_step`` forced, logits and state
+    leaves, and both against the plain reference."""
+    from datatunerx_tpu.ops import pallas_ssm
+
+    cfg, params = wide
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (2, 48), 10, cfg.vocab_size)
+    n0, n1 = 40, 24  # the prompts' lengths
+
+    def serve():
+        step = jax.jit(lambda tokens, cache, positions, mask: forward(
+            params, tokens, cfg, cache=cache, positions=positions, attention_mask=mask))
+        cache = init_paged_cache(cfg, 2, 40, 8, 16, dtype=jnp.float32)
+        cache["block_tables"] = jnp.asarray(np.arange(32).reshape(2, 16), jnp.int32)
+        cache["state_ssm"] = cache["state_ssm"].at[:, 1].add(3.0)
+        only = lambda s, T_: jnp.asarray(np.arange(2)[:, None] == s, jnp.int32) * jnp.ones((2, T_), jnp.int32)  # noqa: E731
+        rows = [[], []]
+        out, cache = step(tokens[:, :n0], cache, _positions(0, n0), only(0, n0))
+        rows[0].append(out[0])
+        for t in range(n0, n0 + 3):  # slot 1 stays where it was: idle, cursor 0
+            cache["len"] = cache["len"].at[1].set(0)
+            out, cache = step(tokens[:, t:t + 1], cache, _positions(t, t + 1), only(0, 1))
+            rows[0].append(out[0])
+        cache["len"] = cache["len"].at[1].set(0)
+        out, cache = step(tokens[:, :n1], cache, _positions(0, n1), only(1, n1))
+        rows[1].append(out[1])
+        for k in range(5):
+            ids = jnp.stack([tokens[0, n0 + 3 + k], tokens[1, n1 + k]])[:, None]
+            pos = jnp.asarray([[n0 + 3 + k], [n1 + k]], jnp.int32)
+            out, cache = step(ids, cache, pos, jnp.ones((2, 1), jnp.int32))
+            rows[0].append(out[0])
+            rows[1].append(out[1])
+        return [jnp.concatenate(r) for r in rows], cache
+
+    assert pallas_ssm.step_kernel(jax.ShapeDtypeStruct((5, 2, 8, 16, 128), jnp.float32), 1) == ("dtx_ssm_step", 8)
+    got, got_cache = serve()
+    monkeypatch.setattr(pallas_ssm, "step_kernel", lambda *a: ("xla", None))
+    want, want_cache = serve()
+    mc = dataclasses.asdict(cfg)
+    for s, n in ((0, n0 + 8), (1, n1 + 5)):
+        # rounding order of one 128-term sum a layer a token
+        np.testing.assert_allclose(got[s], want[s], atol=TOL)
+        ref_logits = ref.sequence_logits(params, mc, np.asarray(tokens[s, :n]).tolist(), list(range(n)))
+        np.testing.assert_allclose(got[s], ref_logits, atol=2 * TOL)
+        assert float(jnp.abs(ref_logits).max()) > 0.05
+    for key in state_leaf_keys(got_cache):
+        np.testing.assert_allclose(got_cache[key], want_cache[key], rtol=1e-5, atol=2e-5)
+    assert float(jnp.abs(got_cache["state_ssm"]).max()) > 0
+
+
+def test_the_engine_says_which_state_step_it_runs(wide, engine, capfd):
+    """``engine.state_kernel``, the ``[engine]`` line and the gauge: the kernel
+    at its head tile where the leaf's shapes give it tiles, the XLA step at
+    ``debug-granite``'s state of 32; and the kernel's engine serves what the
+    reference puts first, two requests admitted at different times."""
+    from datatunerx_tpu.obs.metrics import Registry, export_moe_stats
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+
+    cfg, _ = wide
+    assert engine.state_kernel == engine.engine_line["state_kernel"] == {"decode": ("xla", None)}
+    eng = BatchedEngine("preset:" + cfg.name, **dict(ENGINE, slots=2))
+    try:
+        assert eng.state_kernel == {"decode": ("dtx_ssm_step", 8)}
+        line = [ln for ln in capfd.readouterr().err.splitlines() if ln.startswith("[engine] {")][-1]
+        assert '"state_kernel": {"decode": ["dtx_ssm_step", 8]}' in line
+        reg = Registry()
+        export_moe_stats(reg, eng)
+        assert 'dtx_serving_state_head_tile{kernel="dtx_ssm_step",phase="decode"} 8' in reg.expose()
+        # the benchmark's draw at the engine's own vocabulary (the tokenizer's)
+        eng.params = jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16),
+            _gained(draw.draw_params(dataclasses.asdict(eng.cfg), 13, dtype=jnp.float32)))
+        first_prompt, second_prompt = list(range(100, 170)), list(range(300, 333))
+        first = eng.submit(first_prompt, max_new_tokens=16)
+        while not first.tokens and not first.done.is_set():  # the second joins a decoding engine
+            time.sleep(0.01)
+        second = eng.submit(second_prompt, max_new_tokens=12)
+        for prompt, req, n in ((first_prompt, first, 16), (second_prompt, second, 12)):
+            assert req.done.wait(600) and req.error is None, req.error
+            gaps = _gaps(eng, prompt, req)
+            assert len(req.tokens) == n and gaps.max() < 0.004, gaps
+    finally:
+        eng.close()
