@@ -686,6 +686,193 @@ def child_ling(rehearsal: bool) -> int:
     return 0 if ok else 1
 
 
+def child_glm(rehearsal: bool) -> int:
+    """Runs IN the chip-holding child: a model whose queries SELECT the cached
+    tokens they read (latent attention with a learned indexer, ops/dsa.py).
+    First ``preset:debug-glm`` with two adapters on ``q_b_proj`` / ``o_proj``,
+    seven requests over three slots; then (not in the CPU rehearsal), at the
+    published widths: the selection's own ops on scores with ties at the cell's
+    shapes against the reference's stable sort, EXACTLY; and the benchmark's
+    configuration cut to its first two layers, one slot through a paged cache
+    (prefill in chunks of 256 to 3,072 tokens, then eight token steps), the
+    sets each position selects against benchmarks/reference/glm_5.py's: equal
+    where the context is within ``index_topk`` (every visible token), and
+    shared but for the picks bf16 and float32 order otherwise at the cut."""
+    import dataclasses
+    import tempfile
+
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    import spec
+    from reference import glm_5 as reference
+
+    from datatunerx_tpu.serving.adapters import make_adapter_checkpoint
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+    from datatunerx_tpu.utils import runtime
+
+    runtime.startup("glm")
+    ok = True
+
+    def verdict(name, passed, detail):
+        nonlocal ok
+        ok &= bool(passed)
+        print(f"{'PASS' if passed else 'FAIL'} {name} {detail}", flush=True)
+
+    work = tempfile.mkdtemp(prefix="smoke_glm_")
+    adapters = {f"ad{i}": make_adapter_checkpoint(
+        f"{work}/ad{i}", "preset:debug-glm", seed=20 + i, rank=4,
+        targets=("q_b_proj", "o_proj")) for i in range(2)}
+    eng = BatchedEngine("preset:debug-glm", adapters=adapters, slots=3, decode_chunk=4,
+                        kv_block_size=8, kv_blocks=96, max_seq_len=256, prefill_chunk=64)
+    try:
+        rng = np.random.default_rng(1)
+        work_items = []
+        for n, name in ((5, ""), (70, "ad0"), (130, "ad1"), (33, "ad0"), (90, ""), (64, "ad1"), (160, "")):
+            prompt = rng.integers(10, 500, size=n).tolist()
+            work_items.append((prompt, name, eng.submit(prompt, max_new_tokens=24, adapter=name)))
+        gaps = _served_gaps(eng, reference, work_items, verdict, "glm")
+        # 32 of up to 184 tokens selected: one pick that bf16 orders otherwise is a
+        # thirtieth of a row's attention (the CPU reads up to 0.067 on a whole forward)
+        verdict("glm/served_vs_reference", float(gaps.max()) <= 0.1,
+                f"gap_max {gaps.max():.4f} gap_mean {gaps.mean():.5f} tokens {gaps.size}")
+        stats = eng.dsa_stats
+        verdict("glm/counters",
+                stats["decode_rows"] == 7 * 24 and stats["decode_selected"] <= 32 * stats["decode_rows"]
+                and stats["decode_context"] > stats["decode_selected"] > 0
+                and eng.index_pool_bytes() == 5 * 96 * 8 * 16 * 2,
+                json.dumps(dict(stats, index_pool_bytes=eng.index_pool_bytes())))
+    finally:
+        eng.close()
+        shutil.rmtree(work, ignore_errors=True)
+    if rehearsal or not ok:
+        return 0 if ok else 1
+    _glm_cell_check(verdict, "glm-serve-docs")
+    return 0 if ok else 1
+
+
+def _glm_cell_check(verdict, cell_name: str) -> None:
+    """The second half of ``child_glm``, at the widths of ``cell_name``'s
+    configuration (``K`` its ``index_topk``: 2,048 in the benchmark's cell)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import spec
+    from reference import glm_5 as reference
+
+    from datatunerx_tpu.models import forward
+    from datatunerx_tpu.ops import dsa
+    from datatunerx_tpu.ops.paged_attention import init_paged_cache
+
+    cell = spec.load_cell(cell_name)
+    cfg = dataclasses.replace(spec.register_preset(cell), num_layers=2, layer_types=("mla",) * 2,
+                              ffn_types=("dense", "experts"))
+    K = cfg.index_topk
+    # the selection's ops at the cell's shapes (a view 4.25 times the selection), scores of few
+    # distinct values: ties at every cut
+    S = 17 * K // 4
+    for tokens, slots, seen in ((256, 1, 11 * K // 4), (1, 16, 23 * K // 8), (1, 16, 3 * K // 4)):
+        scores = jnp.round(jax.random.normal(jax.random.PRNGKey(tokens), (slots, tokens, S)) * 2) / 2
+        visible = jnp.broadcast_to(jnp.arange(S)[None, None, :] < seen, scores.shape)
+        lanes, real = jax.jit(lambda a, b: dsa.top_lanes(a, b, K))(scores, visible)
+        mask = jax.jit(lambda a, b: dsa.top_mask(a, b, K))(scores, visible)
+        want = jnp.stack([reference.select(scores[i], visible[i], K) for i in range(slots)])
+        picked = np.zeros(scores.shape, bool)
+        np.put_along_axis(picked, np.asarray(lanes), np.asarray(real), axis=-1)
+        verdict(f"glm/select[{tokens}x{slots}, {seen} of {S} visible]",
+                bool(jnp.all(mask == want)) and bool((picked == np.asarray(want)).all())
+                and int(want.sum()) == slots * tokens * min(K, seen),
+                f"selected {int(mask.sum())} = {slots * tokens} rows x {min(K, seen)}")
+
+    # the published widths, the configuration's first two layers, through a paged cache
+    mc = dict(cell.model_fields, num_layers=2, layer_types=["mla"] * 2, ffn_types=["dense", "experts"])
+    weights = spec.load_module(cell.config["weights_module"])
+    params = weights.draw_params(mc, 4100000001)
+    T, steps, bs = 3 * K // 2, 8, 16
+    blocks = -(-(T + 256) // bs)
+    toks = np.random.default_rng(2).integers(10, cfg.vocab_size, size=T + steps).tolist()
+    seen = []  # every selection as a mask [1, T, lanes], whichever form took it
+    real_lanes, real_mask = dsa.top_lanes, dsa.top_mask
+
+    def note_lanes(lanes, real, width):
+        mask = np.zeros(lanes.shape[:2] + (int(width),), bool)
+        np.put_along_axis(mask, np.asarray(lanes), np.asarray(real), axis=-1)
+        seen.append(mask)
+
+    def spy_lanes(scores, visible, k):
+        lanes, real = real_lanes(scores, visible, k)
+        jax.debug.callback(note_lanes, lanes, real, scores.shape[-1])
+        return lanes, real
+
+    def spy_mask(scores, visible, k):
+        mask = real_mask(scores, visible, k)
+        jax.debug.callback(lambda m: seen.append(np.asarray(m)), mask)
+        return mask
+
+    dsa.top_lanes, dsa.top_mask = spy_lanes, spy_mask
+    step = jax.jit(lambda p, ids, cache, pos: forward(p, ids, cfg, cache=cache, positions=pos,
+                                                     compute_dtype=jnp.bfloat16), donate_argnums=(2,))
+    cache = init_paged_cache(cfg, 1, blocks + 48, bs, blocks, dtype=jnp.bfloat16)
+    table = np.random.default_rng(3).permutation(blocks + 48)[:blocks]
+    cache["block_tables"] = jnp.asarray(table[None], jnp.int32)
+    sets, last = {}, []
+    spans = [(lo, min(lo + 256, T)) for lo in range(0, T, 256)] + [(T + i, T + i + 1) for i in range(steps)]
+    for lo, hi in spans:
+        out, cache = step(params, jnp.asarray([toks[lo:hi]], jnp.int32), cache,
+                          jnp.arange(lo, hi, dtype=jnp.int32)[None])
+        jax.effects_barrier()
+        assert len(seen) == 2, len(seen)
+        for j, t in enumerate(range(lo, hi)):
+            sets[t] = {frozenset(np.flatnonzero(mask[0, j])) for mask in seen}
+        seen.clear()
+        if hi - lo == 1:
+            last.append(out[0, 0])
+    dsa.top_lanes, dsa.top_mask = real_lanes, real_mask
+    chosen = np.asarray(reference.sequence_selected(params, mc, toks))
+    under = all(sets[t] == {frozenset(np.flatnonzero(chosen[layer, t])) for layer in range(2)}
+                for t in range(K))
+    verdict(f"glm/cell/selected_sets[context <= {K}]", under,
+            f"every visible token, in both layers, {K} rows")
+    shared, total = 0, 0
+    for t in range(K, T + steps):
+        want = sorted((frozenset(np.flatnonzero(chosen[layer, t])) for layer in range(2)), key=sorted)
+        got = sorted(sets[t], key=sorted)
+        # layer order is not promised: pair the sets the way that shares most
+        shared += max(sum(len(a & b) for a, b in zip(want, perm)) for perm in (got, got[::-1]))
+        total += 2 * K
+    verdict(f"glm/cell/selected_sets[context > {K}]", shared >= 0.97 * total,
+            f"{shared} of {total} picks shared with the float32 reference ({100 * shared / total:.2f} %), "
+            f"{T + steps - K} rows x 2 layers")
+    ref = reference.sequence_logits(params, mc, toks, list(range(T, T + steps)))
+    served = jnp.stack(last).astype(jnp.float32)
+    # the same steps WITHOUT the selection (every visible token read: plain latent attention),
+    # program and reference alike: what bf16 costs apart from the picks it orders otherwise
+    plain = dataclasses.replace(cfg, index_topk=0)
+    step = jax.jit(lambda p, ids, cache, pos: forward(p, ids, plain, cache=cache, positions=pos,
+                                                     compute_dtype=jnp.bfloat16), donate_argnums=(2,))
+    cache = init_paged_cache(plain, 1, blocks + 48, bs, blocks, dtype=jnp.bfloat16)
+    cache["block_tables"] = jnp.asarray(table[None], jnp.int32)
+    last = []
+    for lo, hi in spans:
+        out, cache = step(params, jnp.asarray([toks[lo:hi]], jnp.int32), cache,
+                          jnp.arange(lo, hi, dtype=jnp.int32)[None])
+        if hi - lo == 1:
+            last.append(out[0, 0])
+    ref_plain = reference.sequence_logits(params, dict(mc, index_topk=10 ** 9), toks,
+                                          list(range(T, T + steps)))
+    rms = lambda a: float(jnp.sqrt(jnp.mean(a * a)))  # noqa: E731
+    noise = rms(served - ref) / rms(ref - jnp.mean(ref))
+    noise_plain = rms(jnp.stack(last).astype(jnp.float32) - ref_plain) / rms(ref_plain - jnp.mean(ref_plain))
+    # swapping m of N near-equally weighted rows moves a mean of N by sqrt(2 m / N) of itself: the
+    # 0.5 % of picks that bf16 orders otherwise are a tenth of an attention output, in every layer
+    verdict("glm/cell/decode_logits", noise_plain <= 0.03 and noise <= 0.25,
+            f"rms (logit - reference) over the logits' spread: {noise:.4f} with the selection, "
+            f"{noise_plain:.4f} without it ({steps} steps, {cfg.vocab_size} logits each)")
+
+
 def child_kernels(rehearsal: bool) -> int:
     """Runs IN the chip-holding child: compile every Pallas kernel with
     Mosaic and compare it with its oracle. On the CPU rehearsal the same
@@ -1300,8 +1487,8 @@ def main(argv=None) -> int:
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="debug-size run on the CPU to debug THIS SCRIPT; "
                          "proves nothing about the chip")
-    ap.add_argument("--phases", default="trainer,server,kernels,hybrid,ling",
-                    help="comma list out of trainer,server,kernels,hybrid,ling")
+    ap.add_argument("--phases", default="trainer,server,kernels,hybrid,ling,glm",
+                    help="comma list out of trainer,server,kernels,hybrid,ling,glm")
     ap.add_argument("--mesh", action="append", default=None,
                     help="trainer --mesh (e.g. dp=1,fsdp=4,tp=1); repeat to "
                          "run the trainer once per mesh. 'auto' (the "
@@ -1312,6 +1499,8 @@ def main(argv=None) -> int:
     ap.add_argument("--child-hybrid", action="store_true",
                     help=argparse.SUPPRESS)
     ap.add_argument("--child-ling", action="store_true",
+                    help=argparse.SUPPRESS)
+    ap.add_argument("--child-glm", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
@@ -1327,12 +1516,14 @@ def main(argv=None) -> int:
         return child_hybrid(args.cpu_rehearsal)
     if args.child_ling:
         return child_ling(args.cpu_rehearsal)
+    if args.child_glm:
+        return child_glm(args.cpu_rehearsal)
     if not os.path.isdir(os.path.join(REPO, "datatunerx_tpu")):
         print("chip_smoke: no datatunerx_tpu package beside this script",
               file=sys.stderr)
         return 2
     phases = [p.strip() for p in args.phases.split(",") if p.strip()]
-    unknown = set(phases) - {"trainer", "server", "kernels", "hybrid", "ling"}
+    unknown = set(phases) - {"trainer", "server", "kernels", "hybrid", "ling", "glm"}
     if unknown:
         ap.error(f"unknown phases {sorted(unknown)}")
 
@@ -1364,7 +1555,7 @@ def main(argv=None) -> int:
         runs.append(("server", lambda left: phase_server(rehearsal, left)))
     if "kernels" in phases:
         runs.append(("kernels", lambda left: phase_kernels(rehearsal, left)))
-    for name in ("hybrid", "ling"):
+    for name in ("hybrid", "ling", "glm"):
         if name in phases:
             runs.append((name, lambda left, name=name: phase_kernels(rehearsal, left, name)))
 

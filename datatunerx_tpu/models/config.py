@@ -92,10 +92,20 @@ class ModelConfig:
     # a model whose held layers carry a non-zero limit is refused, not served
     # without it (``expert_swiglu_limits``: one number per held layer)
     expert_swiglu_limits: Optional[tuple] = None
-    # "mla" layers: q uncompressed [nope | rope], k and v from one latent row
+    # "mla" layers: q heads [nope | rope], straight from x or (``q_lora_rank``)
+    # through a low-rank bottleneck with a norm; k and v from one latent row; a
+    # sigmoid gate a head on the output unless ``mla_head_gate`` is off
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
+    q_lora_rank: int = 0
+    mla_head_gate: bool = True
+    # learned selection of cached tokens (ops/dsa.py): ``index_heads`` index
+    # queries of ``index_head_dim`` score one cached index key a token, and a
+    # query attends to its ``index_topk`` best tokens only (0: no indexer)
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # "kda" layers: heads of head_dim (keys) x v_head_dim (values), a short
     # causal convolution before them, a decay gate bounded below
     kda_conv_kernel: int = 4
@@ -183,12 +193,20 @@ class AttentionKind:
 @dataclasses.dataclass(frozen=True)
 class MlaKind:
     """Latent attention: one cached row per token, ``[c kv_lora_rank | kR
-    rope_dim]``, shared by every head; no v pool (ops/mla.py)."""
+    rope_dim]``, shared by every head; no v pool (ops/mla.py). With an
+    indexer (``index_topk``) a second row per token, the index key, in a pool
+    of its own, and every query reads its ``index_topk`` best tokens
+    (ops/dsa.py)."""
     kv_lora_rank: int
     nope_dim: int
     rope_dim: int
     v_head_dim: int
     rope_theta: float
+    q_lora_rank: int = 0  # 0: ``q_proj`` straight from x
+    head_gate: bool = True
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0  # 0: no indexer
     name: str = "mla"
     window = None
 
@@ -196,8 +214,19 @@ class MlaKind:
     def rotary_dim(self) -> int:
         return self.rope_dim
 
+    @property
+    def index_rope_dim(self) -> int:
+        """The FIRST lanes of an index query and key are rotated, as many as
+        q's rope lanes and by the same table."""
+        return self.rope_dim
+
     def pools(self) -> dict:
-        return {"k_mla": self.kv_lora_rank + self.rope_dim}
+        row = self.kv_lora_rank + self.rope_dim
+        if not self.index_topk:
+            return {"k_mla": row}
+        # a selecting kind gathers single rows: each starts on a lane tile
+        # (128 lanes; the row's tail is zeros)
+        return {"k_mla": -(-row // 128) * 128, "k_idx": self.index_dim}
 
     def states(self, cfg) -> dict:
         return {}
@@ -292,10 +321,16 @@ def mixer_kinds(cfg: ModelConfig) -> dict:
     if "mla" in types:
         assert cfg.kv_lora_rank > 0 and cfg.qk_nope_head_dim > 0
         assert cfg.qk_rope_head_dim > 0 and cfg.qk_rope_head_dim % 2 == 0
+        if cfg.index_topk:  # the index query comes from the compressed query
+            assert cfg.q_lora_rank > 0 and cfg.index_heads > 0
+            assert cfg.index_head_dim >= cfg.qk_rope_head_dim
         kinds["mla"] = MlaKind(
             kv_lora_rank=cfg.kv_lora_rank, nope_dim=cfg.qk_nope_head_dim,
             rope_dim=cfg.qk_rope_head_dim, rope_theta=cfg.rope_theta,
-            v_head_dim=cfg.v_head_dim or cfg.head_dim)
+            v_head_dim=cfg.v_head_dim or cfg.head_dim,
+            q_lora_rank=cfg.q_lora_rank, head_gate=cfg.mla_head_gate,
+            index_heads=cfg.index_heads, index_dim=cfg.index_head_dim,
+            index_topk=cfg.index_topk)
     if "kda" in types:
         kinds["kda"] = KdaKind(
             head_dim=cfg.head_dim, v_head_dim=cfg.v_head_dim or cfg.head_dim,
@@ -442,6 +477,23 @@ PRESETS = {
         ssm_heads=8, ssm_head_dim=16, ssm_state=32, ssm_groups=1,
         embedding_multiplier=12.0, attention_multiplier=0.125,
         residual_multiplier=0.22, logits_scaling=8.0,
+    ),
+    # Debug size of a model whose every mixer is latent attention with a
+    # low-rank query and a learned indexer: a query reads its ``index_topk``
+    # best cached tokens (an index-key pool beside the latent pool), no head
+    # gate; one dense layer, then sigmoid-routed experts with a shared one.
+    "debug-glm": ModelConfig(
+        name="debug-glm", vocab_size=512, hidden_size=64, intermediate_size=128,
+        num_layers=5, num_heads=4, num_kv_heads=4, head_dim=16, v_head_dim=24,
+        max_seq_len=512, rope_theta=1e6,
+        layer_types=("mla",) * 5,
+        ffn_types=("dense", "experts", "experts", "experts", "experts"),
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        q_lora_rank=48, mla_head_gate=False,
+        index_heads=3, index_head_dim=16, index_topk=32,
+        experts_total=16, experts_held=4, first_held=0, experts_per_token=2,
+        expert_intermediate_size=32, shared_expert_intermediate_size=32,
+        routed_scaling_factor=2.5,
     ),
     "qwen1.5-7b": ModelConfig(
         name="qwen1.5-7b", vocab_size=151936, hidden_size=4096,

@@ -22,10 +22,18 @@ Every layer is a pre-norm residual block (RMSNorm, no bias anywhere):
   and its own RoPE theta, and a learned sink logit per head
   (``attention_sink_bias``).
 - latent attention (``mla``, ops/mla.py): q heads ``[nope | rope]`` straight
-  from x; ``kv_a_proj`` gives a latent row ``c`` (RMS-normed) and one rotated
-  key ``kR`` for all heads (interleaved RoPE on it and on q's rope lanes);
-  ``[c | kR]`` is the cached row; attention runs absorbed over those rows,
-  scaled by ``(nope + rope) ** -0.5``; a sigmoid gate per head on the output.
+  from x (``q_proj``) or through a low-rank bottleneck (``q_lora_rank``:
+  ``q_b_proj(RMSNorm(q_a_proj(x)))``); ``kv_a_proj`` gives a latent row ``c``
+  (RMS-normed) and one rotated key ``kR`` for all heads (interleaved RoPE on
+  it and on q's rope lanes); ``[c | kR]`` is the cached row; attention runs
+  absorbed over those rows, scaled by ``(nope + rope) ** -0.5``; a sigmoid
+  gate per head on the output where the model has one (``head_gate``).
+- learned selection (an ``mla`` kind with ``index_topk``, ops/dsa.py): an
+  indexer beside the mixer (index queries ``wq_b`` from the compressed query,
+  one index key a token ``k_norm(wk(x))`` with a LayerNorm's scale and bias,
+  a weight a head ``weights_proj(x)``; the first ``rope_dim`` lanes of both
+  rotated, interleaved) scores every cached token, and a query attends to its
+  ``index_topk`` best only. The index key is cached in a pool of its own.
 - linear attention (``kda``, ops/kda.py): q, k, v through a short causal
   convolution and SiLU, q and k L2-normalised per head, no RoPE; a decay per
   key channel from ``f_proj`` and a write strength per head from ``b_proj``
@@ -49,6 +57,10 @@ Param tree (HF leaf names):
   layers.run<i>.{q,k,v,o}_proj.kernel [n, in, out]
   layers.run<i>.attention_sink_bias [n, H]                (kinds with a sink)
   layers.run<i>.{q,kv_a,kv_b,g,o}_proj.kernel, kv_a_layernorm.scale   (mla runs)
+  layers.run<i>.{q_a,q_b}_proj.kernel, q_a_layernorm.scale in place of q_proj
+      (a low-rank query); no g_proj without a head gate
+  layers.run<i>.indexer.{wq_b,wk,weights_proj}.kernel, indexer.k_norm.{scale,
+      bias}                                                (mla runs that select)
   layers.run<i>.{q,k,v,f,b,g,o}_proj.kernel, conv.kernel [n, C, K],
       A_log [n, H], dt_bias [n, H*d], o_norm.scale [n, d_v]            (kda runs)
   layers.run<i>.{in,o}_proj.kernel, conv.{kernel [n, C, K], bias [n, C]},
@@ -63,7 +75,8 @@ A LoRA tree mirrors it: ``layers.run<i>.<target>.{a,b}``.
 Cache, two kinds of leaf in one dict (ops/paged_attention.py tells them apart:
 ``kv_leaf_keys`` / ``state_leaf_keys``). POOLS hold rows and are moved by
 block table: one ``k_<kind>``/``v_<kind>`` pair per softmax-attention kind,
-one ``k_mla`` (no v pool) for latent attention. STATE leaves hold what a
+one ``k_mla`` (no v pool) for latent attention and beside it ``k_idx`` (the
+index keys) where it selects. STATE leaves hold what a
 linear-attention or state-space layer remembers, of constant size per slot,
 ``[layers of the kind, slots, ...]``, moved by slot: ``state_kda`` (float32
 ``[.., H, d_k, d_v]``) and ``state_kda_conv`` (the last pre-convolution rows);
@@ -78,7 +91,8 @@ and every program converts the whole pool on its way in and out; 8 x 192 =
 Over a paged cache a window layer reads a window-wide view of each slot's
 table (ops/paged_attention.py:window_tables), a global layer the full width.
 ``moe_stats`` (int32 [2, N_STATS], decode steps and prefill steps apart)
-accumulates what the expert layers count; it wraps, and the engine adds up
+accumulates what the expert layers count, ``dsa_stats`` (the same form,
+ops/dsa.py) what the selecting steps do; they wrap, and the engine adds up
 differences.
 """
 
@@ -98,7 +112,7 @@ from datatunerx_tpu.models.config import (
     layer_runs,
     mixer_kinds,
 )
-from datatunerx_tpu.ops import kda, mla, moe, pallas_ssm, ssm
+from datatunerx_tpu.ops import dsa, kda, mla, moe, pallas_ssm, ssm
 from datatunerx_tpu.ops.attention import (
     KVStep,
     cache_positions_update,
@@ -118,8 +132,10 @@ def attn_dims(cfg: ModelConfig, kind) -> dict:
     target exists in a run iff its name is here)."""
     D, H = cfg.hidden_size, cfg.num_heads
     if kind.name == "mla":
-        return {"q_proj": (D, H * (kind.nope_dim + kind.rope_dim)),
-                "o_proj": (H * kind.v_head_dim, D)}
+        q = H * (kind.nope_dim + kind.rope_dim)
+        query = ({"q_b_proj": (kind.q_lora_rank, q)} if kind.q_lora_rank
+                 else {"q_proj": (D, q)})
+        return {**query, "o_proj": (H * kind.v_head_dim, D)}
     if kind.name == "kda":
         return {"q_proj": (D, H * kind.head_dim),
                 "k_proj": (D, H * kind.head_dim),
@@ -143,7 +159,18 @@ def mixer_shapes(cfg: ModelConfig, kind) -> dict:
         out[("kv_a_layernorm", "scale")] = (kind.kv_lora_rank,)
         out[("kv_b_proj", "kernel")] = (
             kind.kv_lora_rank, H * (kind.nope_dim + kind.v_head_dim))
-        out[("g_proj", "kernel")] = (D, H)
+        if kind.head_gate:
+            out[("g_proj", "kernel")] = (D, H)
+        if kind.q_lora_rank:
+            out[("q_a_proj", "kernel")] = (D, kind.q_lora_rank)
+            out[("q_a_layernorm", "scale")] = (kind.q_lora_rank,)
+        if kind.index_topk:
+            Hi, di = kind.index_heads, kind.index_dim
+            out[("indexer", "wq_b", "kernel")] = (kind.q_lora_rank, Hi * di)
+            out[("indexer", "wk", "kernel")] = (D, di)
+            out[("indexer", "k_norm", "scale")] = (di,)
+            out[("indexer", "k_norm", "bias")] = (di,)
+            out[("indexer", "weights_proj", "kernel")] = (D, Hi)
     elif kind.name == "kda":
         C = H * (2 * kind.head_dim + kind.v_head_dim)
         out[("conv", "kernel")] = (C, kind.conv_kernel)
@@ -292,6 +319,8 @@ def _leaves(cfg: ModelConfig, lead: tuple, slots: int, dtype) -> dict:
             out[key] = jnp.zeros((layers[name], slots) + shape, leaf_dtype or dtype)
     if cfg.ffn_types is not None and "experts" in cfg.ffn_types:
         out["moe_stats"] = jnp.zeros((2, moe.N_STATS), jnp.int32)
+    if "k_idx" in out:
+        out["dsa_stats"] = jnp.zeros((2, dsa.N_STATS), jnp.int32)
     return out
 
 
@@ -429,32 +458,90 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
         view = views.get("mla")
         rank = kind.kv_lora_rank
         with jax.named_scope("dtx.qkv"):
-            q = proj(h, "q_proj").reshape(B, T, H, kind.nope_dim + kind.rope_dim)
+            if kind.q_lora_rank:
+                c_q = rms_norm(_proj(h, lp["q_a_proj"], None, 0.0),
+                               lp["q_a_layernorm"]["scale"], cfg.rms_norm_eps)
+                q = proj(c_q, "q_b_proj")
+            else:
+                q = proj(h, "q_proj")
+            q = q.reshape(B, T, H, kind.nope_dim + kind.rope_dim)
             q_nope = q[..., :kind.nope_dim]
             q_rope = mla.rope_interleaved(q[..., kind.nope_dim:], cos, sin)
             row = _proj(h, lp["kv_a_proj"], None, 0.0)
             c = rms_norm(row[..., :rank], lp["kv_a_layernorm"]["scale"],
                          cfg.rms_norm_eps)
             k_rope = mla.rope_interleaved(row[..., None, rank:], cos, sin)[:, :, 0]
-            row = jnp.concatenate([c, k_rope], axis=-1)
-            gate = jax.nn.sigmoid(
-                _proj(h, lp["g_proj"], None, 0.0).astype(jnp.float32))
+            # as wide as the pool's rows: lanes past [c | kR] are zeros
+            tail = kind.pools()["k_mla"] - rank - kind.rope_dim
+
+            def widen(a):
+                return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, tail)]) if tail else a
+
+            row = widen(jnp.concatenate([c, k_rope], axis=-1))
+            if kind.head_gate:
+                gate = jax.nn.sigmoid(
+                    _proj(h, lp["g_proj"], None, 0.0).astype(jnp.float32))
+        # which tokens the step's queries read: every one the causal bias
+        # lets through, or (a selecting kind over a view wider than its
+        # selection) the indexer's picks among them
+        path = dsa.selection_path(T, bias["mla"].shape[-1], kind.index_topk)
+        if kind.index_topk:
+            ip, rot = lp["indexer"], kind.index_rope_dim
+
+            def lead_rotated(a):  # [B, T, heads, d]: the first ``rot`` lanes rotated
+                return jnp.concatenate(
+                    [mla.rope_interleaved(a[..., :rot], cos, sin), a[..., rot:]], axis=-1)
+
+            with jax.named_scope("dtx.dsa_index"):
+                k_idx = dsa.key_norm(_proj(h, ip["wk"], None, 0.0),
+                                     ip["k_norm"]["scale"], ip["k_norm"]["bias"])
+                k_idx = lead_rotated(k_idx[:, :, None])[:, :, 0]
         if view is not None:
             with jax.named_scope("dtx.kv_write"):
-                pool, rows = view.update(leaves[0], li, row[:, :, None, :])
-                rows, leaves = rows.astype(row.dtype), (pool,)
+                pool = view.step.write(leaves[0], li, row.astype(leaves[0].dtype))
+                leaves = (pool,) + leaves[1:]
+                if kind.index_topk:
+                    leaves = (pool, view.step.write(
+                        leaves[1], li, k_idx.astype(leaves[1].dtype)))
+        mask = bias["mla"]
+        if path != "all":
+            with jax.named_scope("dtx.dsa_index"):
+                q_idx = lead_rotated(_proj(c_q, ip["wq_b"], None, 0.0).reshape(
+                    B, T, kind.index_heads, kind.index_dim))
+                w_idx = _proj(h, ip["weights_proj"], None, 0.0).astype(jnp.float32) * (
+                    kind.index_heads * kind.index_dim) ** -0.5
+                keys = (view.step.read(leaves[1], li).astype(k_idx.dtype)
+                        if view is not None else k_idx)
+                scores = dsa.index_scores(q_idx, w_idx, keys)
+            with jax.named_scope("dtx.dsa_select"):
+                visible = mask[:, 0] == 0
+                if path == "mask":  # the set over the view: no lanes, no sort
+                    real = dsa.top_mask(scores, visible, kind.index_topk)
+                else:
+                    lanes, real = dsa.top_lanes(scores, visible, kind.index_topk)
+                mask = jnp.where(real, 0.0, jnp.finfo(mask.dtype).min)[:, None]
+        if path == "gather":  # one token a slot: its chosen rows and no others
+            with jax.named_scope("dtx.dsa_gather"):
+                rows = dsa.gather_rows(
+                    leaves[0], li, lanes[:, 0],
+                    view.step.view_tables if view.step.paged else None)
+                rows = rows.astype(row.dtype)[:, :, None, :]
+        elif view is not None:
+            with jax.named_scope("dtx.kv_write"):
+                rows = view.step.read(leaves[0], li).astype(row.dtype)[:, :, None, :]
         else:
             rows = row[:, :, None, :]
         wkb, wvb = mla.split_kv_b(lp["kv_b_proj"]["kernel"], H, kind.nope_dim)
         with jax.named_scope("dtx.mla_absorb"):
-            q_lat = jnp.concatenate([mla.absorb_query(q_nope, wkb), q_rope], axis=-1)
+            q_lat = widen(jnp.concatenate([mla.absorb_query(q_nope, wkb), q_rope], axis=-1))
         with jax.named_scope("dtx.attn"):
             o_lat = xla_attention(
-                q_lat, rows, rows[..., :rank], bias["mla"],
+                q_lat, rows, rows[..., :rank], mask,
                 scale=(kind.nope_dim + kind.rope_dim) ** -0.5)
         with jax.named_scope("dtx.mla_absorb"):
             o = mla.expand_value(o_lat, wvb)
-            o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
+            if kind.head_gate:
+                o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
         return o.reshape(B, T, H * kind.v_head_dim), leaves
 
     def kda_mixer(kind, h, lp, proj, leaves, li):
@@ -633,6 +720,9 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
         new_cache["pos"] = cache_pos
         if "moe_stats" in cache:
             new_cache["moe_stats"] = cache["moe_stats"].at[0 if T == 1 else 1].add(stats)
+        if "dsa_stats" in cache:
+            new_cache["dsa_stats"] = cache["dsa_stats"].at[0 if T == 1 else 1].add(
+                dsa.step_stats(positions, valid, kinds["mla"].index_topk))
     if return_hidden:
         return logits, new_cache, x
     return logits, new_cache

@@ -32,8 +32,9 @@ from datatunerx_tpu.models.config import ModelConfig
 LLAMA_TARGETS = (
     "q_proj", "k_proj", "v_proj", "o_proj", "gate_proj", "up_proj", "down_proj",
 )
-# ``in_proj``: a state-space mixer's one input projection (models/hybrid.py)
-LORA_TARGETS = LLAMA_TARGETS + ("in_proj",)
+# ``in_proj``: a state-space mixer's one input projection; ``q_b_proj``: the
+# up-projection of a latent-attention mixer's low-rank query (models/hybrid.py)
+LORA_TARGETS = LLAMA_TARGETS + ("in_proj", "q_b_proj")
 DEFAULT_TARGETS = ("q_proj", "v_proj")
 
 
@@ -57,7 +58,8 @@ def lora_groups(cfg: ModelConfig) -> list:
     only layout there was); a model of several kinds has one group per run of
     like layers (tree ``layers.<run>.<target>``), each with its own geometry
     (``v_proj`` is as wide as that run's KV heads; a latent-attention run has
-    ``q_proj`` and ``o_proj`` only). Experts take no adapter."""
+    ``q_proj``, or ``q_b_proj`` where its query is low-rank, and ``o_proj``
+    only). Experts take no adapter."""
     if not cfg.hybrid:
         return [(None, cfg.num_layers,
                  {t: target_dims(cfg, t) for t in LLAMA_TARGETS})]

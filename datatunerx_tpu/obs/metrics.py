@@ -399,7 +399,21 @@ def export_moe_stats(registry: Registry, engine) -> None:
         "dtx_serving_state_bytes",
         "Bytes of recurrent state resident for the linear-attention and "
         "state-space layers (constant per slot, whatever the slots' contexts).")
-    for m in (rows, hit, most, steps, here, seen, tile, head_tile, behind, state):
+    dsa = {name: registry.gauge(f"dtx_serving_dsa_{name}", text) for name, text in (
+        ("steps", "Steps of a model whose queries select the cached tokens "
+                  "they read (learned sparse attention), by phase."),
+        ("rows", "Live rows (a decode slot, a prompt token) of those steps, "
+                 "by phase."),
+        ("context", "Cached tokens visible to those rows, summed, by phase."),
+        ("selected", "Cached tokens those rows selected and read (at most "
+                     "index_topk a row, in every selecting layer), summed, "
+                     "by phase."))}
+    index_pool = registry.gauge(
+        "dtx_serving_index_pool_bytes",
+        "Bytes of the index-key pool a selecting model keeps beside its "
+        "latent rows (one key a token a layer).")
+    for m in (rows, hit, most, steps, here, seen, tile, head_tile, behind, state,
+              index_pool, *dsa.values()):
         m.clear()
     stats = getattr(engine, "moe_stats", None) or {}
     for phase in ("decode", "prefill"):
@@ -411,6 +425,12 @@ def export_moe_stats(registry: Registry, engine) -> None:
             steps.set(stats[f"{phase}_layer_steps"], label)
             here.set(stats.get(f"{phase}_rows_here", 0), label)
             seen.set(stats.get(f"{phase}_rows", 0), label)
+    pool_fn = getattr(engine, "index_pool_bytes", None)
+    if callable(pool_fn) and pool_fn():
+        index_pool.set(pool_fn())
+        for phase in ("decode", "prefill"):
+            for name, gauge in dsa.items():
+                gauge.set(engine.dsa_stats[f"{phase}_{name}"], {"phase": phase})
     for phase, (kernel, tm) in (getattr(engine, "moe_kernel", None) or {}).items():
         tile.set(tm or 0, {"phase": phase, "kernel": kernel})
     for phase, (kernel, th) in (getattr(engine, "state_kernel", None) or {}).items():

@@ -412,6 +412,8 @@ _PROGRAM_MEMO_MAX = 8
 # cache["moe_stats"] columns (ops/moe.py N_STATS)
 MOE_STAT_NAMES = ("local_rows", "experts_hit", "max_rows", "layer_steps",
                   "rows_here", "rows")
+# cache["dsa_stats"] columns (ops/dsa.py N_STATS)
+DSA_STAT_NAMES = ("steps", "rows", "context", "selected")
 
 
 def _program_memo_key(cfg, max_seq_len: int, kv_quant,
@@ -528,9 +530,10 @@ class _Programs:
             cache[key] = jax.lax.dynamic_update_slice(
                 cache[key], row_cache[key],
                 (0, slot) + (0,) * (cache[key].ndim - 2))
-        if "moe_stats" in cache and "moe_stats" in row_cache:
-            # what the row's own prefill counted
-            cache["moe_stats"] = cache["moe_stats"] + row_cache["moe_stats"]
+        for key in ("moe_stats", "dsa_stats"):
+            if key in cache and key in row_cache:
+                # what the row's own prefill counted
+                cache[key] = cache[key] + row_cache[key]
         cache["pos"] = jax.lax.dynamic_update_slice(
             cache["pos"], row_cache["pos"], (slot, 0))
         cache["len"] = cache["len"].at[slot].set(plen)
@@ -556,9 +559,10 @@ class _Programs:
         into the slot's allocated blocks (installing its block table) and arm
         the slot's decode state."""
         cache = paged_insert_row(cache, slot, table_row, row_cache)
-        if "moe_stats" in cache and "moe_stats" in row_cache:
-            # what the row's own prefill counted
-            cache["moe_stats"] = cache["moe_stats"] + row_cache["moe_stats"]
+        for key in ("moe_stats", "dsa_stats"):
+            if key in cache and key in row_cache:
+                # what the row's own prefill counted
+                cache[key] = cache[key] + row_cache[key]
         cache["len"] = jax.lax.dynamic_update_slice(
             cache["len"], cursor[None], (slot,))
         return (
@@ -615,7 +619,8 @@ class _Programs:
             compute_dtype=jnp.bfloat16,
         )
         out = dict(cache)
-        for key in kv_leaf_keys(out) + ["moe_stats"] * ("moe_stats" in out):
+        for key in kv_leaf_keys(out) + [
+                key for key in ("moe_stats", "dsa_stats") if key in out]:
             out[key] = new[key]
         for key in state_leaf_keys(out):
             out[key] = state_insert(cache[key], slot, new[key])
@@ -886,11 +891,17 @@ class BatchedEngine:
         # and prefill steps apart: rows routed to the experts held here, held
         # experts that got a row, most rows on one expert, expert-layer steps.
         # The programs accumulate them on the device (cache["moe_stats"],
-        # wrapping); _note_moe adds up differences at the decode tick's sync.
+        # wrapping); _note_counters adds up differences at the decode tick's
+        # sync. dsa_stats (dtx_serving_dsa_*) likewise, of a model whose
+        # queries select the cached tokens they read: steps, live rows, the
+        # sum of their contexts, the sum of the tokens they selected.
         self.moe_stats = {f"{phase}_{name}": 0
                           for phase in ("decode", "prefill")
                           for name in MOE_STAT_NAMES}
-        self._moe_seen = 0  # device counters at the last read
+        self.dsa_stats = {f"{phase}_{name}": 0
+                          for phase in ("decode", "prefill")
+                          for name in DSA_STAT_NAMES}
+        self._counters_seen = {}  # device counters at the last read, by leaf
         self._slot_cursor = None  # each slot's linear cursor then
         # tokens handed to finished requests (dtx_serving_generated_tokens_
         # total): added once per request in _complete, never per token
@@ -1192,6 +1203,8 @@ class BatchedEngine:
             "moe_kernel": self.moe_kernel,
             "state_kernel": self.state_kernel,
             "state_bytes": self.state_bytes(),
+            "index_topk": self.cfg.index_topk,
+            "index_pool_bytes": self.index_pool_bytes(),
         }
         print("[engine] " + json.dumps(self.engine_line, sort_keys=True),
               file=sys.stderr, flush=True)
@@ -1263,19 +1276,41 @@ class BatchedEngine:
         candidates. Host-side list length; safe from any thread."""
         return len(self._preempted)
 
-    def _note_moe(self):
-        """Add up what the expert layers counted since the last read, and keep
-        every slot's linear cursor: two small arrays cross to the host at the
-        decode tick's designed sync point."""
-        stats, lens = jax.device_get(  # dtxlint: disable=DTX001
-            (self._cache["moe_stats"], self._cache["len"]))
-        stats = stats.astype(np.int64)
-        delta = (stats - self._moe_seen) % (1 << 32)  # the device's int32 wraps
-        for phase, row in zip(("decode", "prefill"), delta):
-            for name, v in zip(MOE_STAT_NAMES, row):
-                self.moe_stats[f"{phase}_{name}"] += int(v)  # dtxlint: disable=DTX001 — host numpy
-        self._moe_seen = stats
+    def _note_counters(self):
+        """Add up what the expert layers and the selecting steps counted since
+        the last read, and keep every slot's linear cursor: a few small arrays
+        cross to the host at the decode tick's designed sync point."""
+        tables = {"moe_stats": (self.moe_stats, MOE_STAT_NAMES),
+                  "dsa_stats": (self.dsa_stats, DSA_STAT_NAMES)}
+        keys = [key for key in tables if key in self._cache]
+        *read, lens = jax.device_get(  # dtxlint: disable=DTX001
+            [self._cache[key] for key in keys] + [self._cache["len"]])
+        for key, stats in zip(keys, read):
+            totals, names = tables[key]
+            stats = stats.astype(np.int64)
+            # the device's int32 wraps
+            delta = (stats - self._counters_seen.get(key, 0)) % (1 << 32)
+            for phase, row in zip(("decode", "prefill"), delta):
+                for name, v in zip(names, row):
+                    totals[f"{phase}_{name}"] += int(v)  # dtxlint: disable=DTX001 — host numpy
+            self._counters_seen[key] = stats
         self._slot_cursor = lens
+
+    def index_pool_bytes(self) -> int:
+        """Bytes of the index-key pool (``k_idx``): what the indexer of a
+        model that selects its cached tokens keeps beside the latent rows."""
+        leaf = self._cache.get("k_idx")  # shape only: a donated leaf still has it
+        return 0 if leaf is None else math.prod(leaf.shape) * leaf.dtype.itemsize
+
+    def _dsa_marks(self) -> dict:
+        """Keywords of the decode span of a model that selects: the running
+        sums of its decode rows' contexts and of the tokens they selected, so
+        that a profiler trace carries the counters (two spans' difference is
+        what the steps between them did)."""
+        if "dsa_stats" not in self._cache:
+            return {}
+        return {"dsa_context": self.dsa_stats["decode_context"],
+                "dsa_selected": self.dsa_stats["decode_selected"]}
 
     def state_bytes(self) -> int:
         """Bytes of recurrent state the cache holds (``state_*`` leaves): what
@@ -3657,7 +3692,7 @@ class BatchedEngine:
             else:
                 emode = self._epilogue_mode()
                 with span("dtx_engine_decode",
-                          live=sum(self._decode_ready)):
+                          live=sum(self._decode_ready), **self._dsa_marks()):
                     (emitted, self._logits, self._cache, self._pos,
                      self._remaining, self._active, self._rng) = \
                         self._decode(
@@ -3676,8 +3711,8 @@ class BatchedEngine:
                 with span("dtx_engine_decode_sync"):
                     emitted_np = np.asarray(emitted)  # [K, S]  # dtxlint: disable=DTX001
                     active_np = np.asarray(self._active)  # [S]  # dtxlint: disable=DTX001
-                    if "moe_stats" in self._cache:
-                        self._note_moe()
+                    if "moe_stats" in self._cache or "dsa_stats" in self._cache:
+                        self._note_counters()
         except Exception as e:  # noqa: BLE001 — device fault: fail all in-flight
             if self._cache_consumed():
                 raise  # the pool went with the program: _stop_serving

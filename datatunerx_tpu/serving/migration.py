@@ -122,14 +122,19 @@ def model_signature(cfg, kv_quant: Optional[str]) -> dict:
 
 def _pool_signature(cfg) -> dict:
     """{kind: [layers, kv_heads, k width, v width]} of a model with a KV pool
-    per attention kind."""
+    per attention kind; a latent-attention kind signs its layers and the row
+    width of each of its pools (the latent rows, the index keys)."""
     from datatunerx_tpu.models.config import kind_layers, mixer_kinds
 
     layers = kind_layers(cfg)
-    return {name: [layers[name], kind.num_kv_heads, kind.head_dim,
-                   kind.v_head_dim]
-            for name, kind in mixer_kinds(cfg).items()
-            if name in ("global", "window")}
+    sig = {}
+    for name, kind in mixer_kinds(cfg).items():
+        if name in ("global", "window"):
+            sig[name] = [layers[name], kind.num_kv_heads, kind.head_dim,
+                         kind.v_head_dim]
+        elif kind.pools():
+            sig[name] = [layers[name], *kind.pools().values()]
+    return sig
 
 
 def _check_model_sig(payload: dict, cfg) -> None:

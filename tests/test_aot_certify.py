@@ -357,6 +357,10 @@ def test_flash_kernels_compile_for_v5e_at_the_training_cell_shape():
 # blocks (36 Mamba-2 layers in five runs around 4 attention layers), the
 # recurrent state [36, 64, 64, 64, 128] float32 donated with the cache, adapters
 # on q_proj, in_proj and o_proj, each in the runs that have the projection.
+# And the cell glm-serve-docs: two scanned blocks (latent attention with the
+# indexer over a dense layer, then over four expert layers), 16 slots of up to
+# 8,704 tokens, the latent pool [5, 8704, 16, 640] and the index-key pool
+# [5, 8704, 16, 128] donated with the cache, adapters on q_b_proj and o_proj.
 _CELL_PROBE = r"""
 import json, os, re, sys
 os.environ["DTX_PALLAS_INTERPRET"] = "0"
@@ -404,6 +408,8 @@ cases = {
 }
 out = {"state_bytes": state}
 ssm_leaf = r" = f32\[%s\]" % ",".join(map(str, cache["state_ssm"].shape)) if "state_ssm" in cache else "no such leaf"
+pool_leaf = r" = bf16\[%s\]" % ",".join(map(str, cache["k_mla"].shape)) if "k_mla" in cache else "no such leaf"
+out["pool_bytes"] = sum(cache[k].size * cache[k].dtype.itemsize for k in cache if k[:2] == "k_")
 for name, lower in cases.items():
     c = lower().compile()
     m, text = c.memory_analysis(), c.as_text()
@@ -418,6 +424,12 @@ for name, lower in cases.items():
                  "ssm_step_in_scope": len(re.findall(r"%dtx_ssm_step[.\w]* = .*dtx\.ssm_state", text)),
                  "ssm_leaf_fusions": len(re.findall(ssm_leaf + r"[^ ]* fusion\(", text)),
                  "ssm_leaf_copies": len(re.findall(ssm_leaf + r"[^ ]* copy\(", text)),
+                 # the selection: the index scores' exact top-k is a stable sort of every lane of
+                 # a slot's view; the latent pool is never re-laid at the program's edge
+                 "dsa_sorts": len(re.findall(r" sort\(.*dtx\.dsa_select", text)),
+                 "dsa_scopes": [s for s in ("dtx.dsa_index", "dtx.dsa_select", "dtx.dsa_gather")
+                                if s in text],
+                 "pool_copies": len(re.findall(pool_leaf + r"[^ ]* copy\(", text)),
                  "scopes": [s for s in ("dtx.kda_conv", "dtx.kda_state", "dtx.kda_out",
                                         "dtx.mla_absorb", "dtx.moe_shared", "dtx.ssm_conv",
                                         "dtx.ssm_state", "dtx.ssm_out") if s in text]}
@@ -475,6 +487,36 @@ def test_granite_cell_programs_compile_for_v5e_at_full_depth(program, arguments,
         assert got["ssm_leaf_fusions"] == 0 and got["ssm_leaf_copies"] == 0, got
     else:  # a chunk of prompt tokens takes ``ssm.chunk_states``, as before
         assert got["ssm_step"] == 0 and got["ssm_leaf_copies"] == 0, got
+
+
+@pytest.fixture(scope="module")
+def glm_doc():
+    pytest.importorskip("libtpu")  # the TPU compiler; absent from jax[cpu]
+    return _run_probe(_CELL_PROBE, timeout=900, DTX_CELL="glm-serve-docs")
+
+
+@pytest.mark.parametrize("program,temporaries,paths,sorts", [
+    ("decode", 0.7e9, ["dtx.dsa_index", "dtx.dsa_select", "dtx.dsa_gather"], 2),  # read: 8.901 + 0.616 GB
+    ("prefill_chunk_256", 0.7e9, ["dtx.dsa_index", "dtx.dsa_select"], 0)])        # read: 8.899 + 0.607 GB
+def test_glm_cell_programs_compile_for_v5e_at_published_widths(program, temporaries, paths, sorts, glm_doc):
+    """Five layers at the published widths, 16 slots of 8,704 tokens: the
+    numbers quoted in ``benchmarks/workloads/glm-serve-docs.json``'s
+    ``engine_notes``."""
+    got = glm_doc[program]
+    # two pools: 8,704 blocks x 16 tokens x 5 layers x (640 + 128) bf16 values
+    assert glm_doc["pool_bytes"] == 8704 * 16 * 5 * (640 + 128) * 2 and glm_doc["state_bytes"] == 0
+    assert got["arguments"] < 9.0e9 and got["temporaries"] < temporaries, got
+    assert got["live"] < 9.7e9, got  # one chip holds 16 GB
+    assert got["alias"] >= glm_doc["pool_bytes"], got  # both donated pools are written in place
+    # the latent pool is never converted at the program's edge: with rows of 576 lanes (4.5 lane
+    # tiles) XLA kept it in a layout of its own inside the loops and copied all 0.8 GB in and
+    # out at every dispatch (2 copies, temporaries 1.505 GB); rows of whole lane tiles cure it
+    assert got["pool_copies"] == 0, got
+    assert got["gmm"] >= 2 and got["ragged"] == 0, got
+    # a token step sorts a slot's index scores (one call site a run of layers) and gathers its
+    # chosen rows; a chunk masks the view it already reads and finds its mask with no sort
+    assert got["dsa_scopes"] == paths and got["dsa_sorts"] == sorts, got
+    assert got["scopes"] == ["dtx.mla_absorb", "dtx.moe_shared"], got
 
 
 @pytest.mark.slow

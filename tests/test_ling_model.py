@@ -216,30 +216,40 @@ def model():
     return cfg, dataclasses.asdict(cfg), params, tokens
 
 
-def test_eight_shares_and_one_shared_expert_add_up_to_the_uncut_layer(model):
-    """16 experts in 8 groups of 2, one group a share (``first_held`` 0, 2, ...,
-    14): the parts the eight shares give, with the shared expert counted once,
-    are the uncut reference's layer."""
-    cfg, mc, _, _ = model
-    cfg = dataclasses.replace(cfg, n_group=8, topk_group=4, experts_per_token=4, experts_held=2)
-    mc = dict(mc, n_group=8, topk_group=4, experts_per_token=4, experts_held=2)
+@pytest.mark.parametrize("preset,reference,route", [
+    ("debug-ling", "ling_v3", dict(n_group=8, topk_group=4, experts_per_token=4, experts_held=2)),
+    ("debug-glm", "glm_5", dict(n_group=1, topk_group=1, experts_per_token=4, experts_held=1)),
+])
+def test_every_share_and_one_shared_expert_add_up_to_the_uncut_layer(preset, reference, route):
+    """16 experts. Ling's: 8 groups of 2, one group a share (``first_held`` 0, 2,
+    ..., 14), 4 groups kept; GLM-5's: no groups, one expert a share (16 shares,
+    as 16 chips share a layer of the benchmark's configuration). The parts all
+    the shares give, with the shared expert counted once, are the uncut
+    reference's layer; each share's part is the reference's share."""
+    import importlib
+
+    ref_ = importlib.import_module("reference." + reference)
+    cfg = dataclasses.replace(get_config(preset), **route)
+    mc = dataclasses.asdict(cfg)
+    held, top_k = cfg.experts_held, cfg.experts_per_token
     whole = dataclasses.replace(cfg, experts_held=cfg.experts_total)
     lp = jax.tree_util.tree_map(lambda a: a[1], init_params(whole, jax.random.PRNGKey(5))["layers"]["run1"])
     h = jax.random.normal(jax.random.PRNGKey(6), (40, cfg.hidden_size), jnp.float32)
-    uncut = ref.expert_ffn(h, lp, dict(mc, experts_held=cfg.experts_total), "f32") - h
-    normed = ref.rms_norm(h, lp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
-    total, pairs, rows_here = ref.swiglu(normed, lp["shared_expert"], "f32"), 0, 0
-    for first in range(0, cfg.experts_total, cfg.experts_held):
-        held = dict(lp, experts=jax.tree_util.tree_map(lambda a: a[first:first + 2], lp["experts"]))
+    uncut = ref_.expert_ffn(h, lp, dict(mc, experts_held=cfg.experts_total), "f32") - h
+    normed = ref_.rms_norm(h, lp["post_attention_layernorm"]["scale"], cfg.rms_norm_eps)
+    total, pairs, rows_here = ref_.swiglu(normed, lp["shared_expert"], "f32"), 0, 0
+    for first in range(0, cfg.experts_total, held):
+        mine = dict(lp, experts=jax.tree_util.tree_map(lambda a: a[first:first + held], lp["experts"]))
         part, stats = moe.expert_layer(
-            normed, None, held, experts_total=cfg.experts_total, experts_held=2, first_held=first,
-            top_k=4, normalize=True, scaling=cfg.routed_scaling_factor, n_group=8, topk_group=4)
+            normed, None, mine, experts_total=cfg.experts_total, experts_held=held, first_held=first,
+            top_k=top_k, normalize=True, scaling=cfg.routed_scaling_factor,
+            n_group=cfg.n_group, topk_group=cfg.topk_group)
         total, pairs, rows_here = total + part, pairs + int(stats[0]), rows_here + int(stats[4])
         assert int(stats[5]) == 40
-        one = ref.expert_ffn(h, held, dict(mc, first_held=first, no_shared_expert=True), "f32") - h
+        one = ref_.expert_ffn(h, mine, dict(mc, first_held=first, no_shared_expert=True), "f32") - h
         np.testing.assert_allclose(part, one, atol=TOL)
-    assert pairs == 40 * 4  # every pair is some share's
-    assert 40 * 2 <= rows_here <= 40 * 4  # a row reaches 2 to 4 of the 8 shares
+    assert pairs == 40 * top_k  # every pair is some share's
+    assert 40 * -(-top_k // held) <= rows_here <= 40 * top_k  # a row reaches top_k / held to top_k shares
     np.testing.assert_allclose(total, uncut, atol=TOL)
 
 
