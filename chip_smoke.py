@@ -690,7 +690,9 @@ def child_glm(rehearsal: bool) -> int:
     """Runs IN the chip-holding child: a model whose queries SELECT the cached
     tokens they read (latent attention with a learned indexer, ops/dsa.py).
     First ``preset:debug-glm`` with two adapters on ``q_b_proj`` / ``o_proj``,
-    seven requests over three slots; then (not in the CPU rehearsal), at the
+    eight requests over three slots, the last a prompt of 250 tokens whose
+    chunks cross every step of 32 lanes of its view (ops/dsa.py:view_steps) up
+    to 256 of the table's 288; then (not in the CPU rehearsal), at the
     published widths: the selection's own ops on scores with ties at the cell's
     shapes against the reference's stable sort, EXACTLY; and the benchmark's
     configuration cut to its first two layers, one slot through a paged cache
@@ -724,22 +726,27 @@ def child_glm(rehearsal: bool) -> int:
         f"{work}/ad{i}", "preset:debug-glm", seed=20 + i, rank=4,
         targets=("q_b_proj", "o_proj")) for i in range(2)}
     eng = BatchedEngine("preset:debug-glm", adapters=adapters, slots=3, decode_chunk=4,
-                        kv_block_size=8, kv_blocks=96, max_seq_len=256, prefill_chunk=64)
+                        kv_block_size=8, kv_blocks=96, max_seq_len=288, prefill_chunk=64)
     try:
         rng = np.random.default_rng(1)
         work_items = []
-        for n, name in ((5, ""), (70, "ad0"), (130, "ad1"), (33, "ad0"), (90, ""), (64, "ad1"), (160, "")):
+        for n, name in ((5, ""), (70, "ad0"), (130, "ad1"), (33, "ad0"), (90, ""), (64, "ad1"), (160, ""),
+                        (250, "ad1")):
             prompt = rng.integers(10, 500, size=n).tolist()
             work_items.append((prompt, name, eng.submit(prompt, max_new_tokens=24, adapter=name)))
         gaps = _served_gaps(eng, reference, work_items, verdict, "glm")
-        # 32 of up to 184 tokens selected: one pick that bf16 orders otherwise is a
+        # 32 of up to 274 tokens selected: one pick that bf16 orders otherwise is a
         # thirtieth of a row's attention (the CPU reads up to 0.067 on a whole forward)
         verdict("glm/served_vs_reference", float(gaps.max()) <= 0.1,
                 f"gap_max {gaps.max():.4f} gap_mean {gaps.mean():.5f} tokens {gaps.size}")
         stats = eng.dsa_stats
         verdict("glm/counters",
-                stats["decode_rows"] == 7 * 24 and stats["decode_selected"] <= 32 * stats["decode_rows"]
+                stats["decode_rows"] == 8 * 24 and stats["decode_selected"] <= 32 * stats["decode_rows"]
                 and stats["decode_context"] > stats["decode_selected"] > 0
+                # 17 chunks of 64 under a table of 288 lanes, each viewing as far as it reaches:
+                # 64 (eight first chunks), 128 (five), 192 (three), 256 (the long prompt's last)
+                and stats["prefill_table_lanes"] == 17 * 288
+                and stats["prefill_view_lanes"] == 8 * 64 + 5 * 128 + 3 * 192 + 256
                 and eng.index_pool_bytes() == 5 * 96 * 8 * 16 * 2,
                 json.dumps(dict(stats, index_pool_bytes=eng.index_pool_bytes())))
     finally:
@@ -764,6 +771,7 @@ def _glm_cell_check(verdict, cell_name: str) -> None:
     from reference import glm_5 as reference
 
     from datatunerx_tpu.models import forward
+    from datatunerx_tpu.models import hybrid
     from datatunerx_tpu.ops import dsa
     from datatunerx_tpu.ops.paged_attention import init_paged_cache
 
@@ -812,30 +820,42 @@ def _glm_cell_check(verdict, cell_name: str) -> None:
         jax.debug.callback(lambda m: seen.append(np.asarray(m)), mask)
         return mask
 
-    dsa.top_lanes, dsa.top_mask = spy_lanes, spy_mask
+    read, real_attention = [], hybrid.xla_attention  # the lanes each layer's chunk attention read
+
+    def spy_attention(q, k, v, bias, **kw):
+        if q.shape[1] > 1:  # of the branches of static width, the one taken speaks
+            jax.debug.callback(lambda _: read.append(k.shape[1]), q[0, 0, 0, 0])
+        return real_attention(q, k, v, bias, **kw)
+
+    dsa.top_lanes, dsa.top_mask, hybrid.xla_attention = spy_lanes, spy_mask, spy_attention
     step = jax.jit(lambda p, ids, cache, pos: forward(p, ids, cfg, cache=cache, positions=pos,
                                                      compute_dtype=jnp.bfloat16), donate_argnums=(2,))
     cache = init_paged_cache(cfg, 1, blocks + 48, bs, blocks, dtype=jnp.bfloat16)
     table = np.random.default_rng(3).permutation(blocks + 48)[:blocks]
     cache["block_tables"] = jnp.asarray(table[None], jnp.int32)
-    sets, last = {}, []
+    sets, last, widths = {}, [], []
     spans = [(lo, min(lo + 256, T)) for lo in range(0, T, 256)] + [(T + i, T + i + 1) for i in range(steps)]
     for lo, hi in spans:
         out, cache = step(params, jnp.asarray([toks[lo:hi]], jnp.int32), cache,
                           jnp.arange(lo, hi, dtype=jnp.int32)[None])
         jax.effects_barrier()
-        assert len(seen) == 2, len(seen)
+        # a chunk that reaches no further than the selection views one step, reads every
+        # visible token and never runs the indexer (ops/dsa.py:view_steps): nothing to spy
+        assert len(seen) == (2 if hi > K else 0), (lo, hi, len(seen))
         for j, t in enumerate(range(lo, hi)):
             sets[t] = {frozenset(np.flatnonzero(mask[0, j])) for mask in seen}
+        widths.append(sorted(set(read)))
         seen.clear()
+        read.clear()
         if hi - lo == 1:
             last.append(out[0, 0])
-    dsa.top_lanes, dsa.top_mask = real_lanes, real_mask
+    dsa.top_lanes, dsa.top_mask, hybrid.xla_attention = real_lanes, real_mask, real_attention
     chosen = np.asarray(reference.sequence_selected(params, mc, toks))
-    under = all(sets[t] == {frozenset(np.flatnonzero(chosen[layer, t])) for layer in range(2)}
-                for t in range(K))
-    verdict(f"glm/cell/selected_sets[context <= {K}]", under,
-            f"every visible token, in both layers, {K} rows")
+    under = all(not sets[t] and chosen[:, t, :t + 1].all() for t in range(K))
+    views = [[dsa.view_lanes(lo, hi - lo, K, bs, blocks)] if hi - lo > 1 else [] for lo, hi in spans]
+    verdict(f"glm/cell/selected_sets[context <= {K}]", under and widths == views,
+            f"{K} rows in chunks that reach no further than the selection: every visible token, no indexer "
+            f"run; lanes a chunk's attention read, of {blocks * bs}: {sorted({w for v in views for w in v})}")
     shared, total = 0, 0
     for t in range(K, T + steps):
         want = sorted((frozenset(np.flatnonzero(chosen[layer, t])) for layer in range(2)), key=sorted)

@@ -89,7 +89,9 @@ the TPU, whose runtime then stores the pool in a layout of its own choosing
 and every program converts the whole pool on its way in and out; 8 x 192 =
 1536 lanes pad nothing). ``len``, ``pos`` and ``block_tables`` are shared.
 Over a paged cache a window layer reads a window-wide view of each slot's
-table (ops/paged_attention.py:window_tables), a global layer the full width.
+table (ops/paged_attention.py:window_tables), a global layer the full width,
+a selecting latent layer's chunk as far as its longest row reaches, in whole
+steps of ``index_topk`` lanes (ops/dsa.py:view_steps).
 ``moe_stats`` (int32 [2, N_STATS], decode steps and prefill steps apart)
 accumulates what the expert layers count, ``dsa_stats`` (the same form,
 ops/dsa.py) what the selecting steps do; they wrap, and the engine adds up
@@ -416,6 +418,18 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
         for name, kind in attending.items():
             views[name] = _View(cache, kind, positions, kv_pos_full, cache_pos, T)
             bias[name] = views[name].bias
+    # a selecting kind's step of several tokens views a paged cache as far as
+    # its longest row reaches, in whole steps (ops/dsa.py:view_steps): the
+    # widths it may take, in table columns, and which of them this step takes
+    # (traced; a reach past the table takes the last); None where the view
+    # stays the table's
+    reach = None
+    if cache is not None and "block_tables" in cache and "k_idx" in cache:
+        columns, block_size = cache["block_tables"].shape[1], cache["pos"].shape[1]
+        widths = dsa.view_steps(T, columns, block_size, kinds["mla"].index_topk)
+        if widths:
+            reach = (widths, block_size,
+                     (jnp.max(cache["len"]) + T - 1) // (widths[0] * block_size))
     # a slot at cursor 0 starts from nothing, whatever its state leaves hold
     fresh = (jnp.broadcast_to(cache["len"] == 0, (B,))
              if cache is not None and has_recurrent_state(cfg) else None)
@@ -481,10 +495,6 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
             if kind.head_gate:
                 gate = jax.nn.sigmoid(
                     _proj(h, lp["g_proj"], None, 0.0).astype(jnp.float32))
-        # which tokens the step's queries read: every one the causal bias
-        # lets through, or (a selecting kind over a view wider than its
-        # selection) the indexer's picks among them
-        path = dsa.selection_path(T, bias["mla"].shape[-1], kind.index_topk)
         if kind.index_topk:
             ip, rot = lp["indexer"], kind.index_rope_dim
 
@@ -503,44 +513,67 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
                 if kind.index_topk:
                     leaves = (pool, view.step.write(
                         leaves[1], li, k_idx.astype(leaves[1].dtype)))
-        mask = bias["mla"]
-        if path != "all":
+        # a selecting chunk's view is as wide as its context reaches, a branch
+        # of static width a count of steps; every other step's is the table's
+        widths, block_size, taken = reach or ((), 0, None)
+        if widths or dsa.selection_path(T, bias["mla"].shape[-1], kind.index_topk) != "all":
             with jax.named_scope("dtx.dsa_index"):
                 q_idx = lead_rotated(_proj(c_q, ip["wq_b"], None, 0.0).reshape(
                     B, T, kind.index_heads, kind.index_dim))
                 w_idx = _proj(h, ip["weights_proj"], None, 0.0).astype(jnp.float32) * (
                     kind.index_heads * kind.index_dim) ** -0.5
-                keys = (view.step.read(leaves[1], li).astype(k_idx.dtype)
-                        if view is not None else k_idx)
-                scores = dsa.index_scores(q_idx, w_idx, keys)
-            with jax.named_scope("dtx.dsa_select"):
-                visible = mask[:, 0] == 0
-                if path == "mask":  # the set over the view: no lanes, no sort
-                    real = dsa.top_mask(scores, visible, kind.index_topk)
-                else:
-                    lanes, real = dsa.top_lanes(scores, visible, kind.index_topk)
-                mask = jnp.where(real, 0.0, jnp.finfo(mask.dtype).min)[:, None]
-        if path == "gather":  # one token a slot: its chosen rows and no others
-            with jax.named_scope("dtx.dsa_gather"):
-                rows = dsa.gather_rows(
-                    leaves[0], li, lanes[:, 0],
-                    view.step.view_tables if view.step.paged else None)
-                rows = rows.astype(row.dtype)[:, :, None, :]
-        elif view is not None:
-            with jax.named_scope("dtx.kv_write"):
-                rows = view.step.read(leaves[0], li).astype(row.dtype)[:, :, None, :]
-        else:
-            rows = row[:, :, None, :]
         wkb, wvb = mla.split_kv_b(lp["kv_b_proj"]["kernel"], H, kind.nope_dim)
         with jax.named_scope("dtx.mla_absorb"):
             q_lat = widen(jnp.concatenate([mla.absorb_query(q_nope, wkb), q_rope], axis=-1))
-        with jax.named_scope("dtx.attn"):
-            o_lat = xla_attention(
-                q_lat, rows, rows[..., :rank], mask,
-                scale=(kind.nope_dim + kind.rope_dim) ** -0.5)
-        with jax.named_scope("dtx.mla_absorb"):
-            o = mla.expand_value(o_lat, wvb)
-            if kind.head_gate:
+
+        def attend(leaves, columns=None):
+            """The step's attention over its view's first ``columns`` table
+            columns (None: all of the view). Which tokens its queries read:
+            every one the causal bias lets through, or (a selecting kind over
+            a view wider than its selection) the indexer's picks among them.
+            The pools come in and only the output leaves: a branch that
+            returned a pool would have it copied."""
+            mask = bias["mla"]
+            if columns is not None:
+                mask = mask[..., :columns * block_size]
+            path = dsa.selection_path(T, mask.shape[-1], kind.index_topk)
+            if path != "all":
+                with jax.named_scope("dtx.dsa_index"):
+                    keys = (view.step.read(leaves[1], li, columns).astype(k_idx.dtype)
+                            if view is not None else k_idx)
+                    scores = dsa.index_scores(q_idx, w_idx, keys)
+                with jax.named_scope("dtx.dsa_select"):
+                    visible = mask[:, 0] == 0
+                    if path == "mask":  # the set over the view: no lanes, no sort
+                        real = dsa.top_mask(scores, visible, kind.index_topk)
+                    else:
+                        lanes, real = dsa.top_lanes(scores, visible, kind.index_topk)
+                    mask = jnp.where(real, 0.0, jnp.finfo(mask.dtype).min)[:, None]
+            if path == "gather":  # one token a slot: its chosen rows and no others
+                with jax.named_scope("dtx.dsa_gather"):
+                    rows = dsa.gather_rows(
+                        leaves[0], li, lanes[:, 0],
+                        view.step.view_tables if view.step.paged else None)
+                    rows = rows.astype(row.dtype)[:, :, None, :]
+            elif view is not None:
+                with jax.named_scope("dtx.kv_write"):
+                    rows = view.step.read(leaves[0], li, columns).astype(row.dtype)[:, :, None, :]
+            else:
+                rows = row[:, :, None, :]
+            with jax.named_scope("dtx.attn"):
+                o_lat = xla_attention(
+                    q_lat, rows, rows[..., :rank], mask,
+                    scale=(kind.nope_dim + kind.rope_dim) ** -0.5)
+            with jax.named_scope("dtx.mla_absorb"):
+                return mla.expand_value(o_lat, wvb)
+
+        if widths:
+            o = jax.lax.switch(
+                taken, [functools.partial(attend, columns=n) for n in widths], leaves)
+        else:
+            o = attend(leaves)
+        if kind.head_gate:
+            with jax.named_scope("dtx.mla_absorb"):
                 o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
         return o.reshape(B, T, H * kind.v_head_dim), leaves
 

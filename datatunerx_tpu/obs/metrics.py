@@ -408,12 +408,20 @@ def export_moe_stats(registry: Registry, engine) -> None:
         ("selected", "Cached tokens those rows selected and read (at most "
                      "index_topk a row, in every selecting layer), summed, "
                      "by phase."))}
+    lanes = {name: registry.counter(f"dtx_serving_dsa_prefill_{name}_lanes_total", text)
+             for name, text in (
+        ("view", "Lanes the prefill chunks of a selecting model viewed: as far "
+                 "as the slot's context reached, in whole steps of index_topk "
+                 "lanes, summed over chunks."),
+        ("table", "Lanes of the slot's whole table, summed over the same "
+                  "chunks: view over table is the share of the table a chunk "
+                  "scored, ranked and attended over."))}
     index_pool = registry.gauge(
         "dtx_serving_index_pool_bytes",
         "Bytes of the index-key pool a selecting model keeps beside its "
         "latent rows (one key a token a layer).")
     for m in (rows, hit, most, steps, here, seen, tile, head_tile, behind, state,
-              index_pool, *dsa.values()):
+              index_pool, *dsa.values(), *lanes.values()):
         m.clear()
     stats = getattr(engine, "moe_stats", None) or {}
     for phase in ("decode", "prefill"):
@@ -431,6 +439,8 @@ def export_moe_stats(registry: Registry, engine) -> None:
         for phase in ("decode", "prefill"):
             for name, gauge in dsa.items():
                 gauge.set(engine.dsa_stats[f"{phase}_{name}"], {"phase": phase})
+        for name, counter in lanes.items():
+            counter.set(engine.dsa_stats[f"prefill_{name}_lanes"])
     for phase, (kernel, tm) in (getattr(engine, "moe_kernel", None) or {}).items():
         tile.set(tm or 0, {"phase": phase, "kernel": kernel})
     for phase, (kernel, th) in (getattr(engine, "state_kernel", None) or {}).items():

@@ -239,14 +239,16 @@ class KVStep:
                 (li, 0, self.lens) + (0,) * (leaf.ndim - 3))
         return leaf.at[li, self.rows, self.idx].set(new)
 
-    def read(self, leaf, li):
+    def read(self, leaf, li, columns: int | None = None):
         """What attention reads of layer ``li`` AFTER the write: ``[B, S,
         ...]``, the gathered per-slot linear view of a paged leaf
         (element-identical to a dense row for every written lane,
-        sentinel-masked elsewhere) or the layer's dense rows."""
+        sentinel-masked elsewhere) or the layer's dense rows. ``columns``
+        (paged) reads the view's first so many table columns and no more."""
         if not self.paged:
             return leaf[li]
-        view = leaf[li, self._gather]  # [B, n, bs, ...]
+        gather = self._gather if columns is None else self._gather[:, :columns]
+        view = leaf[li, gather]  # [B, n, bs, ...]
         return view.reshape((view.shape[0], -1) + view.shape[3:])
 
 

@@ -57,6 +57,7 @@ from datatunerx_tpu.obs.metrics import (
 from datatunerx_tpu.obs.trace import TraceStore, build_request_span
 from datatunerx_tpu.models.llama import forward, init_cache
 from datatunerx_tpu.models.lora import LORA_TARGETS, lora_scaling
+from datatunerx_tpu.ops import dsa
 from datatunerx_tpu.ops._pallas import interpret_default
 from datatunerx_tpu.ops.paged_attention import (
     POS_SENTINEL,
@@ -901,6 +902,10 @@ class BatchedEngine:
         self.dsa_stats = {f"{phase}_{name}": 0
                           for phase in ("decode", "prefill")
                           for name in DSA_STAT_NAMES}
+        # and, counted here at dispatch, the lanes a selecting model's prefill
+        # chunks viewed (as far as the slot's context reached, in whole steps)
+        # beside the lanes of the table they would have viewed
+        self.dsa_stats.update(prefill_view_lanes=0, prefill_table_lanes=0)
         self._counters_seen = {}  # device counters at the last read, by leaf
         self._slot_cursor = None  # each slot's linear cursor then
         # tokens handed to finished requests (dtx_serving_generated_tokens_
@@ -1205,6 +1210,9 @@ class BatchedEngine:
             "state_bytes": self.state_bytes(),
             "index_topk": self.cfg.index_topk,
             "index_pool_bytes": self.index_pool_bytes(),
+            # lanes a selecting model's prefill chunk adds to its view a
+            # step of its context (0: every chunk views its whole table)
+            "prefill_view_step": self.prefill_view_step(),
         }
         print("[engine] " + json.dumps(self.engine_line, sort_keys=True),
               file=sys.stderr, flush=True)
@@ -1302,6 +1310,15 @@ class BatchedEngine:
         leaf = self._cache.get("k_idx")  # shape only: a donated leaf still has it
         return 0 if leaf is None else math.prod(leaf.shape) * leaf.dtype.itemsize
 
+    def prefill_view_step(self) -> int:
+        """Lanes of one step of a prefill chunk's view (``ops/dsa.py:
+        view_steps``), 0 where a chunk views its whole table."""
+        if "k_idx" not in self._cache or not self.paged:
+            return 0
+        steps = dsa.view_steps(self.prefill_chunk, self._cache["block_tables"].shape[1],
+                               self.block_size, self.cfg.index_topk)
+        return steps[0] * self.block_size if steps else 0
+
     def _dsa_marks(self) -> dict:
         """Keywords of the decode span of a model that selects: the running
         sums of its decode rows' contexts and of the tokens they selected, so
@@ -1311,6 +1328,20 @@ class BatchedEngine:
             return {}
         return {"dsa_context": self.dsa_stats["decode_context"],
                 "dsa_selected": self.dsa_stats["decode_selected"]}
+
+    def _dsa_chunk_marks(self, cursor: int, tokens: int) -> dict:
+        """Keywords of the chunk span of a model that selects, counted as the
+        chunk is dispatched: the lanes its program views at linear cursor
+        ``cursor`` (``ops/dsa.py:view_lanes``, the program's own rule) and
+        the lanes of the slot's table."""
+        if "dsa_stats" not in self._cache:
+            return {}
+        columns = self._cache["block_tables"].shape[1]
+        view = dsa.view_lanes(cursor, tokens, self.cfg.index_topk,
+                              self.block_size, columns)
+        self.dsa_stats["prefill_view_lanes"] += view
+        self.dsa_stats["prefill_table_lanes"] += columns * self.block_size
+        return {"view": view, "table": columns * self.block_size}
 
     def state_bytes(self) -> int:
         """Bytes of recurrent state the cache holds (``state_*`` leaves): what
@@ -2161,8 +2192,8 @@ class BatchedEngine:
                         budget - spent)
                 lo = st["done"]
                 try:
-                    with self._phase("dtx_engine_prefill_chunk",
-                                     tokens=c, slot=slot):
+                    with self._phase("dtx_engine_prefill_chunk", tokens=c, slot=slot,
+                                     **self._dsa_chunk_marks(st.get("base", 0) + lo, c)):
                         logits, self._cache = self._prefill_chunk_fn(
                             self.params, self._lora_arg(), self._cache,
                             jnp.asarray(slot, jnp.int32),

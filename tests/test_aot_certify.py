@@ -409,6 +409,7 @@ cases = {
 out = {"state_bytes": state}
 ssm_leaf = r" = f32\[%s\]" % ",".join(map(str, cache["state_ssm"].shape)) if "state_ssm" in cache else "no such leaf"
 pool_leaf = r" = bf16\[%s\]" % ",".join(map(str, cache["k_mla"].shape)) if "k_mla" in cache else "no such leaf"
+idx_leaf = r" = bf16\[%s\]" % ",".join(map(str, cache["k_idx"].shape)) if "k_idx" in cache else "no such leaf"
 out["pool_bytes"] = sum(cache[k].size * cache[k].dtype.itemsize for k in cache if k[:2] == "k_")
 for name, lower in cases.items():
     c = lower().compile()
@@ -429,7 +430,14 @@ for name, lower in cases.items():
                  "dsa_sorts": len(re.findall(r" sort\(.*dtx\.dsa_select", text)),
                  "dsa_scopes": [s for s in ("dtx.dsa_index", "dtx.dsa_select", "dtx.dsa_gather")
                                 if s in text],
-                 "pool_copies": len(re.findall(pool_leaf + r"[^ ]* copy\(", text)),
+                 "pool_copies": len(re.findall(pool_leaf + r"[^ ]* copy\(", text))
+                 + len(re.findall(idx_leaf + r"[^ ]* copy\(", text)),
+                 # a selecting chunk: one conditional a run of layers, a branch of static width
+                 # a count of steps; the widths of its attention's float32 logits
+                 "select_conds": [len(m.split(",")) for m in re.findall(
+                     r" conditional\(.*branch_computations=\{([^}]*)\}.*dtx\.layers", text)],
+                 "logit_widths": sorted({int(w) for w in re.findall(
+                     r" = f32\[1,(?:1,)?%d,256,(\d+)\]" % cfg.num_heads, text)} - {cfg.kv_lora_rank}),
                  "scopes": [s for s in ("dtx.kda_conv", "dtx.kda_state", "dtx.kda_out",
                                         "dtx.mla_absorb", "dtx.moe_shared", "dtx.ssm_conv",
                                         "dtx.ssm_state", "dtx.ssm_out") if s in text]}
@@ -497,7 +505,8 @@ def glm_doc():
 
 @pytest.mark.parametrize("program,temporaries,paths,sorts", [
     ("decode", 0.7e9, ["dtx.dsa_index", "dtx.dsa_select", "dtx.dsa_gather"], 2),  # read: 8.901 + 0.616 GB
-    ("prefill_chunk_256", 0.7e9, ["dtx.dsa_index", "dtx.dsa_select"], 0)])        # read: 8.899 + 0.607 GB
+    # read: 8.899 + 0.596 GB; 0.607 before the chunk's view followed its reach (its widest branch)
+    ("prefill_chunk_256", 0.61e9, ["dtx.dsa_index", "dtx.dsa_select"], 0)])
 def test_glm_cell_programs_compile_for_v5e_at_published_widths(program, temporaries, paths, sorts, glm_doc):
     """Five layers at the published widths, 16 slots of 8,704 tokens: the
     numbers quoted in ``benchmarks/workloads/glm-serve-docs.json``'s
@@ -517,7 +526,16 @@ def test_glm_cell_programs_compile_for_v5e_at_published_widths(program, temporar
     # chosen rows; a chunk masks the view it already reads and finds its mask with no sort
     assert got["dsa_scopes"] == paths and got["dsa_sorts"] == sorts, got
     assert got["scopes"] == ["dtx.mla_absorb", "dtx.moe_shared"], got
-
+    # a chunk scores, ranks and attends over the lanes its context reaches: ONE conditional a run
+    # of layers, a branch of static width a count of steps (2,048 / 4,096 / 6,144 / 8,192 / 8,704
+    # lanes; the first picks all it sees); the branches read the pools as operands (no copy above)
+    # and return the attention's output, so the temporaries are the widest branch's: no more than
+    # the table-wide chunk's 0.607 GB. The token step: no conditional
+    if program == "decode":
+        assert got["select_conds"] == [], got
+    else:
+        assert got["select_conds"] == [5, 5], got
+        assert got["logit_widths"] == [2048, 4096, 6144, 8192, 8704], got
 
 @pytest.mark.slow
 def test_aot_pipeline_compiles_for_v5e_target():

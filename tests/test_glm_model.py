@@ -39,6 +39,17 @@ from datatunerx_tpu.ops.paged_attention import (  # noqa: E402
     state_leaf_keys,
 )
 
+@pytest.fixture(autouse=True)
+def _drop_compiled_programs():
+    """These tests run ``forward`` op by op, a program a primitive and shape:
+    some 50,000 memory maps of loaded executables by the file's end in ONE
+    worker, and the kernel allows a process 65,530 (``vm.max_map_count``):
+    past it XLA's loader segfaults inside jax's compilation cache. Dropped
+    after each test, they come back from the session's persistent cache."""
+    yield
+    jax.clear_caches()
+
+
 TOL = 2e-5  # float32 program against float32 reference: rounding order only
 T = 150  # over index_topk (32): every later row selects
 TOPK = 32
@@ -112,9 +123,59 @@ def test_the_mask_without_a_sort_is_the_sorts_set_on_any_float(seed):
 
 @pytest.mark.parametrize("tokens,width,topk,path", [
     (1, 8704, 2048, "gather"), (256, 8704, 2048, "mask"), (1, 2048, 2048, "all"),
-    (256, 1024, 2048, "all"), (1, 2064, 2048, "gather"), (150, 150, 32, "mask")])
+    (256, 1024, 2048, "all"), (1, 2064, 2048, "gather"), (150, 150, 32, "mask"),
+    # the widths a chunk of cell 7 reaches (dsa.view_steps): only the first is within the selection
+    (256, 2048, 2048, "all"), (256, 4096, 2048, "mask"), (64, 6144, 2048, "mask"), (192, 8192, 2048, "mask")])
 def test_a_steps_path_follows_from_its_shapes(tokens, width, topk, path):
     assert dsa.selection_path(tokens, width, topk) == path
+
+
+@pytest.mark.parametrize("tokens,columns,block_size,topk,steps", [
+    # cell 7: a chunk of a table of 8,704 lanes reads 2,048 / 4,096 / 6,144 / 8,192 / 8,704 of them
+    (256, 544, 16, 2048, (128, 256, 384, 512, 544)),
+    (64, 544, 16, 2048, (128, 256, 384, 512, 544)),
+    (256, 512, 16, 2048, (128, 256, 384, 512)),
+    (256, 129, 16, 2048, (128, 129)),     # one column over a step
+    (256, 128, 16, 2048, ()),             # a table of one step: as before
+    (256, 64, 16, 2048, ()),
+    (1, 544, 16, 2048, ()),               # a token step gathers its picks
+    (256, 544, 16, 0, ()),                # no indexer
+    (64, 24, 8, 32, (4, 8, 12, 16, 20, 24)),    # debug-glm, the tests' table
+    (8, 10, 16, 40, (2, 4, 6, 8, 10)),          # a step is whole blocks: 32 lanes of 40
+    (8, 5, 16, 8, (1, 2, 3, 4, 5))])            # and at least one
+def test_a_chunks_view_grows_in_steps_of_the_selection(tokens, columns, block_size, topk, steps):
+    """The widths, in table columns, a chunk's view may take."""
+    assert dsa.view_steps(tokens, columns, block_size, topk) == steps
+
+
+@pytest.mark.parametrize("cursor,tokens,lanes", [
+    (0, 256, 2048), (1792, 256, 2048), (1793, 256, 4096), (1792, 64, 2048), (2047, 2, 4096),
+    (3840, 256, 4096), (3841, 256, 6144), (5888, 256, 6144), (5889, 256, 8192), (7936, 256, 8192),
+    (7937, 256, 8704), (8448, 256, 8704), (9000, 256, 8704),   # a reach past the table: the table
+    (0, 1, 8704), (5000, 1, 8704)])                            # a token step views no less
+def test_the_views_width_is_its_reach_in_whole_steps(cursor, tokens, lanes):
+    """``len + T`` rounded up to whole steps of 2,048 lanes, cut at the
+    table: what the scheduler counts is the branch the program's switch
+    takes."""
+    assert dsa.view_lanes(cursor, tokens, 2048, 16, 544) == lanes
+    steps = dsa.view_steps(tokens, 544, 16, 2048)
+    if steps:  # the program's branch, from a traced reach (models/hybrid.py); lax.switch holds it to the last
+        taken = int(jnp.clip((jnp.asarray(cursor + tokens) - 1) // 2048, 0, len(steps) - 1))
+        assert steps[taken] * 16 == lanes
+    assert dsa.view_lanes(cursor, tokens, 0, 16, 544) == 8704  # a kind that does not select
+
+
+@pytest.mark.parametrize("prompt,chunk,share", [
+    (8192, 256, 0.5882), (6400, 256, 0.4894), (4096, 256, 0.3529), (4160, 256, 0.3737), (2048, 256, 0.2353)])
+def test_a_prompts_chunks_view_a_share_of_the_table_that_follows_from_its_length(prompt, chunk, share):
+    """A prompt walked chunk by chunk (the last one its remainder) under cell
+    7's table: the lanes its chunks view over the lanes of as many tables, as
+    the engine's counter adds them up; within the selection it is one step's."""
+    cuts = list(range(0, prompt, chunk)) + [prompt]
+    views = [dsa.view_lanes(lo, hi - lo, 2048, 16, 544) for lo, hi in zip(cuts, cuts[1:])]
+    assert all(v in (2048, 4096, 6144, 8192) for v in views) and views == sorted(views)
+    assert views[0] == 2048 and views[-1] == min(-(-prompt // 2048) * 2048, 8704)
+    assert round(sum(views) / (len(views) * 8704), 4) == share
 
 
 def test_rows_are_gathered_through_the_block_table():
@@ -291,7 +352,7 @@ def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, wa
     outs = []
     for lo, hi in chunks:
         out, cache = forward(params, tokens[:, lo:hi], cfg, cache=cache, positions=_positions(lo, hi))
-        if dsa.selection_path(hi - lo, 192, TOPK) != "all":
+        if hi > TOPK:  # a chunk that reaches no further than the selection runs no indexer
             _check_sets(picks, want_sets, lo, hi)
         outs.append(out)
     for t in range(130, T):
@@ -305,6 +366,72 @@ def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, wa
     assert list(stats[0]) == [20, 40, 2 * sum(range(131, 151)), 40 * TOPK]
     assert list(stats[1]) == [len(chunks), 260, 2 * sum(range(1, 131)),
                               2 * sum(min(TOPK, c) for c in range(1, 131))]
+
+
+@pytest.mark.parametrize("edge", [-1, 0, 1])
+def test_a_chunk_views_what_its_context_reaches(model, want, want_sets, picks, monkeypatch, edge):
+    """Chunks whose reach (``len + T``) sits just under, at and just over each
+    step of 32 lanes, the last one left-padded, in a table whose columns past
+    the prompt have no block: the lanes read follow the reach, a chunk within
+    the selection runs no indexer, and logits and selected sets are the
+    reference's and the table-wide path's."""
+    cfg, _, params, tokens = model
+    block_size, nbps, pad, end = 8, 24, 5, 140
+    cuts = [0] + [32 * k + edge for k in range(1, 5)] + [end]
+    table = np.full((2, nbps), -1, np.int32)
+    held = -(-(end + pad) // block_size)
+    table[:, :held] = np.random.default_rng(edge + 1).permutation(2 * nbps)[:2 * held].reshape(2, held)
+    read, real_attention = [], hybrid.xla_attention  # the lanes each layer's attention read
+
+    def spy_attention(q, k, v, bias, **kw):
+        jax.debug.callback(lambda _: read.append(k.shape[1]), q[0, 0, 0, 0])  # the branch taken speaks
+        return real_attention(q, k, v, bias, **kw)
+
+    monkeypatch.setattr(hybrid, "xla_attention", spy_attention)
+
+    def prefill(stepped):
+        cache = init_paged_cache(cfg, 2, 2 * nbps, block_size, nbps, dtype=jnp.float32)
+        cache["block_tables"] = jnp.asarray(table)
+        cache["k_idx"] = cache["k_idx"] + 50.0  # what earlier requests left in the pools
+        cache["k_mla"] = cache["k_mla"] - 7.0
+        outs, sets = [], []
+        for lo, hi in zip(cuts, cuts[1:]):
+            n_pad = pad if hi == end else 0
+            ids = jnp.concatenate([jnp.full((2, n_pad), 7, tokens.dtype), tokens[:, lo:hi]], axis=1)
+            mask = jnp.concatenate([jnp.zeros((2, n_pad), jnp.int32), jnp.ones((2, hi - lo), jnp.int32)], axis=1)
+            pos = jnp.concatenate([jnp.zeros((2, n_pad), jnp.int32), _positions(lo, hi)], axis=1)
+            out, cache = forward(params, ids, cfg, cache=cache, positions=pos, attention_mask=mask)
+            outs.append(out[:, n_pad:])
+            jax.effects_barrier()
+            reach = hi + n_pad
+            if stepped:  # each of the five layers read as far as the chunk reached, in whole steps
+                assert read == [dsa.view_lanes(lo, reach - lo, TOPK, block_size, nbps)] * 5 == [-(-reach // 32) * 32] * 5
+            else:
+                assert read == [nbps * block_size] * 5
+            # the indexer ran over as many lanes as attention read (the whole view on the
+            # table-wide path), or (a reach within the selection) not at all
+            width = -(-reach // 32) * 32 if stepped else nbps * block_size
+            assert [m.shape for m in picks] == ([(2, reach - lo, width)] * 5
+                                                if reach > TOPK or not stepped else []), (lo, hi)
+            for j, t in enumerate(range(lo, hi)):  # a lane past the pads is its position + pad
+                sets.append([{frozenset(lane - (n_pad if lane >= lo else 0) for lane in np.flatnonzero(m[b, n_pad + j]))
+                              for m in picks} for b in range(2)])
+            picks.clear()
+            read.clear()
+        assert int(cache["len"][0]) == end + pad
+        return jnp.concatenate(outs, axis=1), sets
+
+    got, got_sets = prefill(True)
+    np.testing.assert_allclose(got, want[:, :end], atol=TOL)
+    for t, per_row in enumerate(got_sets):
+        for b in range(2):  # no indexer: every visible token, nothing to compare
+            assert per_row[b] == (want_sets[b][t] if t >= cuts[1] or edge > 0 else set()), (b, t)
+    # the table-wide path (as the step was before its view followed its reach): the same
+    monkeypatch.setattr(dsa, "view_steps", lambda *a: ())
+    wide, wide_sets = prefill(False)
+    np.testing.assert_allclose(got, wide, atol=TOL)
+    assert all(a[b] in (set(), w[b]) for a, w in zip(got_sets, wide_sets) for b in range(2))
+    assert all(w[b] == want_sets[b][t] for t, w in enumerate(wide_sets) for b in range(2))
 
 
 def test_left_pads_and_idle_rows_select_nothing_of_theirs(model, want):
@@ -624,6 +751,7 @@ def test_the_engine_line_and_metrics_name_the_selection(engine, capfd):
 
     assert engine.engine_line["index_topk"] == TOPK
     assert engine.engine_line["index_pool_bytes"] == engine.index_pool_bytes() > 0
+    assert engine.engine_line["prefill_view_step"] == TOPK  # lanes a step: 4 blocks of 8
     reg = Registry()
     export_moe_stats(reg, engine)
     text = reg.expose()
@@ -641,6 +769,28 @@ def test_the_engine_line_and_metrics_name_the_selection(engine, capfd):
         assert req.done.wait(600) and req.error is None
     finally:
         engine._phase = real_phase
+    # a chunk's span says how many lanes its program views and how many its slot's table has,
+    # by the program's own rule; the engine adds them up and /metrics states the sums
+    before, chunks = dict(engine.dsa_stats), []
+    engine._phase = lambda name, **detail: (chunks.append((name, detail)), real_phase(name, **detail))[1]
+    try:
+        long = engine.submit(list(range(30, 160)), max_new_tokens=2)  # 130 tokens: chunks of 64 at 0, 64, 128
+        assert long.done.wait(600) and long.error is None
+    finally:
+        engine._phase = real_phase
+    chunks = [d for name, d in chunks if name == "dtx_engine_prefill_chunk"]
+    assert [(d["tokens"], d["view"], d["table"]) for d in chunks] == [(64, 64, 256), (64, 128, 256), (64, 192, 256)]
+    assert all(d["view"] == dsa.view_lanes(64 * i, 64, TOPK, 8, 32) for i, d in enumerate(chunks))
+    assert [d for name, d in spans if name == "dtx_engine_prefill_chunk"] == [
+        {"tokens": 64, "slot": chunks[0]["slot"], "view": 64, "table": 256}]
+    assert engine.dsa_stats["prefill_view_lanes"] - before["prefill_view_lanes"] == 64 + 128 + 192
+    assert engine.dsa_stats["prefill_table_lanes"] - before["prefill_table_lanes"] == 3 * 256
+    reg = Registry()
+    export_moe_stats(reg, engine)
+    text = reg.expose()
+    for name in ("view", "table"):
+        assert f"# TYPE dtx_serving_dsa_prefill_{name}_lanes_total counter" in text
+        assert f"dtx_serving_dsa_prefill_{name}_lanes_total {engine.dsa_stats[f'prefill_{name}_lanes']}\n" in text
     marks = [d for name, d in spans if name == "dtx_engine_decode"]
     assert len(marks) >= 2 and all(d.keys() == {"live", "dsa_context", "dsa_selected"} for d in marks)
     assert marks[-1]["dsa_context"] > marks[0]["dsa_context"]
@@ -649,10 +799,12 @@ def test_the_engine_line_and_metrics_name_the_selection(engine, capfd):
     other = BatchedEngine("preset:debug-ling", **ENGINE)
     try:
         assert other.engine_line["index_topk"] == 0 and other.engine_line["index_pool_bytes"] == 0
+        assert other.engine_line["prefill_view_step"] == 0 and other._dsa_chunk_marks(0, 64) == {}
         assert other._dsa_marks() == {} and engine._dsa_marks().keys() == {"dsa_context", "dsa_selected"}
         reg = Registry()
         export_moe_stats(reg, other)
         assert "dtx_serving_dsa_steps{" not in reg.expose()
+        assert "\ndtx_serving_dsa_prefill_view_lanes_total " not in reg.expose()
     finally:
         other.close()
     assert '"index_topk": 0' in capfd.readouterr().err
@@ -677,6 +829,8 @@ def test_every_option_works_with_two_pools_or_refuses_by_name(model, entry):
             b = dense.submit(prompt, max_new_tokens=10)
             assert a.done.wait(600) and b.done.wait(600) and a.error is None and b.error is None
             assert a.tokens == b.tokens
+            # and both what the float32 reference puts first, as the benchmark holds a served token
+            assert len(a.tokens) == 10 and _gaps(paged, prompt, a).max() < 0.05
         finally:
             paged.close()
             dense.close()

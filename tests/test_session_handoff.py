@@ -536,17 +536,24 @@ def test_admin_sessions_http_roundtrip(paged_pair):
 
 
 def test_serving_metrics_expose_session_series(paged_pair):
-    src, _ = paged_pair
+    """Hands a session over itself: the counters it reads are its own,
+    whatever other tests of the file ran on these engines, on whichever
+    worker."""
+    src, dst = paged_pair
     from datatunerx_tpu.serving import server as serving
 
+    prompt = src.tokenizer.encode("a session of this test's own")
+    _import_and_wait(dst, _export_mid_decode(src, prompt, max_new_tokens=16))
     old_engine = serving.STATE.engine
-    serving.STATE.engine = src
     try:
-        text = serving.metrics_text()
+        serving.STATE.engine = src
+        exported = serving.metrics_text()
+        serving.STATE.engine = dst
+        imported = serving.metrics_text()
     finally:
         serving.STATE.engine = old_engine
-    assert 'dtx_serving_session_export_total{outcome="ok"}' in text
-    assert 'dtx_serving_session_import_total{outcome="ok"}' in text
+    assert 'dtx_serving_session_export_total{outcome="ok"}' in exported
+    assert 'dtx_serving_session_import_total{outcome="ok"}' in imported
 
 
 # ----------------------------------------- selftest fleet (no model load)

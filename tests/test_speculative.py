@@ -380,6 +380,9 @@ def test_spec_metrics_and_replica_stats(paged_pair):
     _, on = paged_pair
     from datatunerx_tpu.gateway.replica_pool import InProcessReplica
 
+    # its own speculative steps: under ``--dist load`` the file's earlier tests
+    # (which used to leave them on the module's engine) may run on another worker
+    on.generate(on.tokenizer.encode("a request of this test's own"), max_new_tokens=8)
     st = InProcessReplica("r0", on).stats()
     assert st["spec_enabled"] is True
     assert st["spec_accept_rate"] is not None
