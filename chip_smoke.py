@@ -465,7 +465,7 @@ def phase_server(rehearsal: bool, budget_s: float) -> dict:
             if not fused > 0:
                 raise SmokeFailure("server: no decode tick took the fused "
                                    "sampler")
-            if path != ['dtx_serving_decode_path{path="pallas"} 1']:
+            if path != ['dtx_serving_decode_path{kind="global",path="pallas"} 1']:
                 raise SmokeFailure(f"server: decode path is {path}")
             if epilogue != 2:
                 raise SmokeFailure("server: sampling epilogue is not the "
@@ -552,7 +552,12 @@ def child_hybrid(rehearsal: bool) -> int:
     of a model with window and global attention layers and sparse experts
     (``preset:debug-hybrid``), two adapters, paged pool. Served greedy tokens
     are held against the plain float32 reference (benchmarks/reference/
-    mimo_v2.py, which imports nothing of the program) as logits."""
+    mimo_v2.py, which imports nothing of the program) as logits. Its heads
+    are widened to 2 x 128 (q/k) and 2 x 64 (v), whole lane tiles a pool row
+    as every published model's are: the chip's compiler can then cut blocks
+    out of the pools, and the global kind's token step takes the paged decode
+    kernel while the window kind, with its sink, reads its gathered view."""
+    import dataclasses
     import tempfile
 
     import numpy as np
@@ -560,11 +565,16 @@ def child_hybrid(rehearsal: bool) -> int:
     sys.path.insert(0, os.path.join(REPO, "benchmarks"))
     from reference import mimo_v2 as reference
 
+    from datatunerx_tpu.models.config import PRESETS
     from datatunerx_tpu.serving.adapters import make_adapter_checkpoint
     from datatunerx_tpu.serving.batched_engine import BatchedEngine
     from datatunerx_tpu.utils import runtime
 
     runtime.startup("hybrid")
+    preset = "debug-hybrid-tiles"
+    PRESETS[preset] = dataclasses.replace(
+        PRESETS["debug-hybrid"], name=preset, num_kv_heads=2, head_dim=128,
+        v_head_dim=64)
     ok = True
 
     def verdict(name, passed, detail):
@@ -574,11 +584,14 @@ def child_hybrid(rehearsal: bool) -> int:
 
     work = tempfile.mkdtemp(prefix="smoke_hybrid_")
     adapters = {f"ad{i}": make_adapter_checkpoint(
-        f"{work}/ad{i}", "preset:debug-hybrid", seed=20 + i, rank=4) for i in range(2)}
-    eng = BatchedEngine("preset:debug-hybrid", adapters=adapters, slots=4, decode_chunk=4,
-                        kv_block_size=8, kv_blocks=96, max_seq_len=256, prefill_chunk=64)
+        f"{work}/ad{i}", "preset:" + preset, seed=20 + i, rank=4) for i in range(2)}
+    eng = BatchedEngine("preset:" + preset, adapters=adapters, slots=4, decode_chunk=4,
+                        kv_block_size=8, kv_blocks=96, max_seq_len=256, prefill_chunk=64,
+                        paged_kernel="on" if rehearsal else "auto")
     try:
-        verdict("hybrid/decode_path", eng.decode_path == "gather", eng.decode_path)
+        verdict("hybrid/decode_paths",
+                eng.decode_paths == {"global": "pallas", "window": "gather"},
+                json.dumps(eng.decode_paths))
         rng = np.random.default_rng(1)
         work_items = []
         for name in ("", "ad0", "ad1", "ad0"):
@@ -1262,6 +1275,109 @@ def child_kernels(rehearsal: bool) -> int:
               f"{window} [walk {t_walk * 1e6:.0f} us a call under the "
               f"window-less {t_whole * 1e6:.0f} us]", flush=True)
 
+    # ---- the decode kernel at the shapes of the attending kinds whose token
+    # step models/hybrid.py hands it: MiMo's global kind in the cell
+    # mimo-serve-batch (64 slots, 64 heads over 4 KV heads, q/k 192 and v 128
+    # wide, tables of 160 columns over 2,560 blocks) and Granite's in
+    # granite-serve-chat (32 heads over 8 KV heads of 64, scores x 1/64,
+    # tables of 64 columns over 4,096 blocks), contexts as those cells hold
+    # them. Each against what the step took before, a gathered view of every
+    # slot's whole table and ``xla_attention`` over it, in values and in time.
+    # And the walk MiMo's WINDOW kind would take (8 KV heads, a window of 128;
+    # the kernel has no sink, so the values are held without one) against its
+    # window-wide view under the sink: reported, not judged. That kind stays
+    # on the view until the walk is the faster (ROADMAP B7)
+    def paged_kinds():
+        from datatunerx_tpu.ops.attention import KVStep
+        from datatunerx_tpu.ops.paged_attention import gathered_positions
+
+        bs = SERVE_BLOCK
+        #        B, H, KV, d, dv, nbps, NB, scale, window, contexts, judged
+        cases = {
+            "mimo_global": (64, 64, 4, 192, 128, 160, 2560, None, None,
+                            (150, 1000), True),
+            "granite_global": (64, 32, 8, 64, 64, 64, 4096, 0.015625, None,
+                               (60, 420), True),
+            "mimo_window": (64, 64, 8, 192, 128, 160, 2560, None, 128,
+                            (150, 1000), False),
+        } if not rehearsal else {
+            "debug_v_under_d": (4, 8, 2, 24, 16, 8, 32, None, None,
+                                (5, 100), True),
+            "debug_scaled": (4, 4, 2, 16, 16, 8, 32, 0.125, None,
+                             (5, 100), True),
+            "debug_window": (4, 4, 2, 24, 16, 8, 32, None, 24,
+                             (5, 100), False),
+        }
+        for name, (B, H, KV, d, dv, nbps, NB, scale, window, (lo, hi),
+                   judged) in cases.items():
+            lens = rng.integers(lo, hi, B)
+            lens[0], lens[1] = hi, 1
+            while sum(-(-int(n) // bs) for n in lens) > NB:  # the pool holds them
+                lens = np.maximum(1, lens * 9 // 10)
+            perm = rng.permutation(NB)
+            tables = np.full((B, nbps), -1, np.int32)
+            pos = np.full((NB, bs), POS_SENTINEL, np.int32)
+            lane = np.arange(nbps * bs).reshape(nbps, bs)
+            at = 0
+            for b, n in enumerate(lens):
+                held = -(-int(n) // bs)
+                tables[b, :held] = perm[at:at + held]
+                at += held
+                for j in range(held):
+                    pos[tables[b, j]] = np.where(lane[j] < n, lane[j],
+                                                 POS_SENTINEL)
+            assert at <= NB
+            # two stacked layers, the second one read
+            k, v = normal((2, NB, bs, KV * d)), normal((2, NB, bs, KV * dv))
+            q = normal((B, H, d))
+            sink = normal((H,), jnp.float32) if window else None
+            tables, pos = jnp.asarray(tables), jnp.asarray(pos)
+            cursor = jnp.asarray(lens - 1, jnp.int32)
+            li = jnp.asarray(1, jnp.int32)
+
+            def view(q, k, v, sink):
+                step = KVStep({"len": cursor, "pos": pos,
+                               "block_tables": tables}, 1, window=window)
+                bias = make_causal_bias(
+                    cursor[:, None], gathered_positions(pos, step.view_tables),
+                    None, sliding_window=window)
+                return xla_attention(
+                    q[:, None], step.read(k, li).reshape(B, -1, KV, d),
+                    step.read(v, li).reshape(B, -1, KV, dv), bias, sink=sink,
+                    scale=scale)[:, 0]
+
+            def walk(q, k, v, sink):
+                return paged_decode_attention(
+                    q, k, v, None, None, li, tables, pos, cursor, cursor,
+                    window=window, scale=scale)
+
+            shape = (f"[{name} B{B} H{H}/{KV} d{d}/{dv} bs{bs} W{nbps * bs} "
+                     f"contexts {lo}-{hi}]")
+            check(f"paged_decode_kind {shape}", jax.jit(walk)(q, k, v, None),
+                  jax.jit(view)(q, k, v, None), atol=2e-2, rtol=2e-2)
+
+            def seconds(fn):
+                # twenty calls in a row and one wait, as ssm_step times
+                fn = jax.jit(fn)
+                jax.block_until_ready(fn(q, k, v, sink))
+                took = []
+                for _ in range(5):
+                    t0 = time.perf_counter()
+                    for _ in range(20):
+                        out = fn(q, k, v, sink)
+                    jax.block_until_ready(out)
+                    took.append((time.perf_counter() - t0) / 20)
+                return float(np.median(took))
+
+            t_walk, t_view = seconds(walk), seconds(view)
+            # a CPU's time for the emulation says nothing: reported, not judged
+            ok = rehearsal or not judged or t_walk < t_view
+            results.append(ok)
+            print(f"{'PASS' if ok else 'FAIL'} kernel/paged_decode_kind {shape} "
+                  f"[dtx_paged_decode {t_walk * 1e6:.0f} us a call, the view "
+                  f"and xla_attention {t_view * 1e6:.0f} us"
+                  + ("" if judged else "; not judged") + "]", flush=True)
+
     # ---- a windowed model through the batched engine: the token step takes
     # the decode kernel (pool rows of 2 x 64 = one lane tile, so the kernel
     # itself and not the multi-token one at q_len 1) with the window it was
@@ -1482,6 +1598,7 @@ def child_kernels(rehearsal: bool) -> int:
         guarded(f"paged [{gname}]", lambda: paged(gname, H, KV, d))
     guarded("paged_cell", paged_cell)
     guarded("paged_window", paged_window)
+    guarded("paged_kinds", paged_kinds)
     guarded("windowed_engine", windowed_engine)
     # both models' vocab 32000 and Qwen's 151936 (several tiles, the last
     # one ragged)

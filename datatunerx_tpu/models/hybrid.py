@@ -91,7 +91,15 @@ and every program converts the whole pool on its way in and out; 8 x 192 =
 Over a paged cache a window layer reads a window-wide view of each slot's
 table (ops/paged_attention.py:window_tables), a global layer the full width,
 a selecting latent layer's chunk as far as its longest row reaches, in whole
-steps of ``index_topk`` lanes (ops/dsa.py:view_steps).
+steps of ``index_topk`` lanes (ops/dsa.py:view_steps). A TOKEN step of an
+engine that asked for the paged kernels gathers no view of a softmax-attention
+kind the decode kernel can express (``in_place_kinds``: no sink, pool rows of
+whole lane tiles): it scatters the token's row and the kernel reads the kind's
+blocks in place through the block table, as far as each slot's cursor
+(ops/pallas_paged_attention.py; the kind's v width, score scale and window go
+in as they are). The choice is per kind, from the step's shapes, the cache and
+the kind's own fields; every other step and kind reads its view, which is the
+kernel's parity oracle.
 ``moe_stats`` (int32 [2, N_STATS], decode steps and prefill steps apart)
 accumulates what the expert layers count, ``dsa_stats`` (the same form,
 ops/dsa.py) what the selecting steps do; they wrap, and the engine adds up
@@ -108,6 +116,7 @@ import jax
 import jax.numpy as jnp
 
 from datatunerx_tpu.models.config import (
+    AttentionKind,
     ModelConfig,
     has_recurrent_state,
     kind_layers,
@@ -122,6 +131,10 @@ from datatunerx_tpu.ops.attention import (
     xla_attention,
 )
 from datatunerx_tpu.ops.paged_attention import POS_SENTINEL, gathered_positions
+from datatunerx_tpu.ops.pallas_paged_attention import (
+    paged_attention_decode_step,
+    walks_in_place,
+)
 from datatunerx_tpu.ops.rope import apply_rope, rope_cos_sin
 
 
@@ -356,13 +369,45 @@ def init_paged_cache(cfg: ModelConfig, slots: int, num_blocks: int,
 
 # ------------------------------------------------------- cache write / read
 
+def in_place_kinds(cfg: ModelConfig, cache, T: int) -> tuple:
+    """Names of the attending kinds whose step reads its blocks IN PLACE
+    through the paged decode kernel (ops/pallas_paged_attention.py), no view
+    of them gathered: a token step over a paged cache of an engine that asked
+    for the kernels, and of its softmax-attention kinds those the kernel can
+    express: no sink (its softmax has no column but the keys'), pools the
+    chip's compiler can cut blocks out of. The kernel takes the kind's v
+    width, score scale and window as they are. Every other step and kind
+    reads a gathered view, which is the kernel's parity oracle."""
+    if not (T == 1 and cache is not None and "block_tables" in cache
+            and cfg.paged_kernel):
+        return ()
+    return tuple(
+        name for name, kind in mixer_kinds(cfg).items()
+        if isinstance(kind, AttentionKind) and not kind.sink
+        and walks_in_place(*(cache[key] for key in kind.pools())))
+
+
+# jitted so that a program traces and lowers the kernel's body once a kind,
+# not once a run of that kind's layers (a second or two each: Granite's four
+# attention layers are four runs); XLA inlines the call
+_decode_step = jax.jit(paged_attention_decode_step,
+                       static_argnames=("window", "scale", "interpret"))
+
+
 class _View:
     """How one step writes its tokens into a kind's pool and what its
     attention reads back: built once a forward, shared by the kind's layers.
     The targets and the view are ops/attention.py's ``KVStep``, the one the
-    single-kind decoder uses; a kind adds its window and its bias."""
+    single-kind decoder uses; a kind adds its window and its bias. A kind
+    whose step reads its blocks in place (``in_place``) writes through the
+    same targets and has neither view nor bias."""
 
-    def __init__(self, cache, kind, positions, kv_pos_full, cache_pos, T):
+    def __init__(self, cache, kind, positions, kv_pos_full, cache_pos, T,
+                 in_place=False):
+        self.in_place = in_place
+        if in_place:  # the kernel walks the whole table and masks by position
+            self.step, self.bias = KVStep(cache, T), None
+            return
         self.step = KVStep(cache, T, window=kind.window)
         kv_pos = kv_pos_full
         if self.step.paged and self.step.view_tables is not cache["block_tables"]:
@@ -370,12 +415,17 @@ class _View:
         self.bias = make_causal_bias(positions, kv_pos, None,
                                      sliding_window=kind.window)
 
+    def write(self, pool, li, new):
+        """``new`` [B, T, KV, w] into layer ``li`` of ``pool``."""
+        B, T, KV, w = new.shape
+        return self.step.write(
+            pool, li, new.astype(pool.dtype).reshape(B, T, KV * w))
+
     def update(self, pool, li, new):
         """Write ``new`` [B, T, KV, w] into layer ``li`` of ``pool`` and
         return (pool, what attention reads [B, S, KV, w])."""
-        B, T, KV, w = new.shape
-        pool = self.step.write(
-            pool, li, new.astype(pool.dtype).reshape(B, T, KV * w))
+        B, _, KV, w = new.shape
+        pool = self.write(pool, li, new)
         return pool, self.step.read(pool, li).reshape(B, -1, KV, w)
 
 
@@ -413,10 +463,15 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
             bias[name] = make_causal_bias(positions, positions, valid,
                                           sliding_window=kind.window)
     else:
+        # a token step's kinds that read their blocks in place; where every
+        # attending kind does, no position view is gathered either
+        in_place = in_place_kinds(cfg, cache, T)
         cache_pos, kv_pos_full = cache_positions_update(
-            cache, positions, attention_mask)
+            cache, positions, attention_mask,
+            gather=len(in_place) < len(attending))
         for name, kind in attending.items():
-            views[name] = _View(cache, kind, positions, kv_pos_full, cache_pos, T)
+            views[name] = _View(cache, kind, positions, kv_pos_full, cache_pos, T,
+                                in_place=name in in_place)
             bias[name] = views[name].bias
     # a selecting kind's step of several tokens views a paged cache as far as
     # its longest row reaches, in whole steps (ops/dsa.py:view_steps): the
@@ -453,6 +508,18 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
                 k = apply_rope(k, *rope[kind.name])
             if kind.value_scale != 1.0:
                 v = v * jnp.asarray(kind.value_scale, v.dtype)
+        if view is not None and view.in_place:
+            # scatter the token's row into its blocks, then the kernel reads
+            # them back through the block table: no view of this kind exists
+            with jax.named_scope("dtx.kv_write"):
+                pool_k = view.write(pool_k, li, k)
+                pool_v = view.write(pool_v, li, v)
+            with jax.named_scope("dtx.attn"):
+                attn = _decode_step(
+                    q, {"k": pool_k, "v": pool_v}, li,
+                    {key: cache[key] for key in ("block_tables", "len")},
+                    cache_pos, positions, window=kind.window, scale=kind.scale)
+            return attn.reshape(B, T, H * kind.v_head_dim), (pool_k, pool_v)
         if view is not None:
             with jax.named_scope("dtx.kv_write"):
                 pool_k, k_att = view.update(pool_k, li, k)

@@ -23,7 +23,12 @@ nothing written costs a grid step that stores zeros. A model's sliding
 ``window`` is a static operand: one the cache's width cannot exceed is dropped
 at trace time (no query has a key behind it, and the kernel emitted is the
 window-less one), a narrower one gives the walk a FIRST trip as the cursor
-gives it a last, so bytes and time follow ``min(len, window)``.
+gives it a last, so bytes and time follow ``min(len, window)``. The v pool's
+rows may have a head width of their own (``[L, NB, bs, KV·dv]``: a trip
+buffer, an accumulator and an output of that width) and the scores a static
+``scale`` other than ``d ** -0.5``: both are a layer KIND's in a model of
+several kinds (models/hybrid.py hands a sink-less kind's token step here).
+With one width and no scale the call lowers to the same text as before them.
 
 Correctness contract — the gather path stays alive as the parity ORACLE,
 and the PR 5 bit-parity suite asserts kernel-vs-gather token-exactness.
@@ -90,12 +95,13 @@ def _interpret() -> bool:
     return interpret_default()
 
 
-def _blocks_per_trip(bs: int, width: int, itemsize: int, nbps: int) -> int:
+def _blocks_per_trip(bs: int, widths: int, itemsize: int, nbps: int) -> int:
     """Table columns one trip of the decode kernel's loop covers: enough for
     a lane-dense score tile (``_LANES`` tokens), fewer where the table has
-    fewer or where K and V, each double-buffered at ``bs * width * itemsize``
-    bytes a block, would pass ``_TRIP_VMEM``."""
-    fit = _TRIP_VMEM // (4 * bs * width * itemsize)
+    fewer or where K and V (``widths``: their rows' widths added), each
+    double-buffered at ``bs * width * itemsize`` bytes a block, would pass
+    ``_TRIP_VMEM``."""
+    fit = _TRIP_VMEM // (2 * bs * widths * itemsize)
     return max(1, min(-(-_LANES // bs), fit, nbps))
 
 
@@ -123,13 +129,15 @@ def _decode_kernel(tables_ref, qpos_ref, bound_ref, layer_ref, q_ref,
     block-diagonally (``qbd[h, kv(h)·d:(kv(h)+1)·d] = q[h]``, zero elsewhere)
     against the pools' merged ``(KV, d)`` axis, so ``qbd · Kᵀ`` is every
     head's score row ``[H, tokens]`` (the added products are exact zeros)
-    and ``p · V`` is ``[H, KV·d]`` whose diagonal blocks are the output —
-    which is also how GQA maps a query-head group onto its KV head."""
+    and ``p · V`` is ``[H, KV·dv]`` whose diagonal blocks are the output —
+    which is also how GQA maps a query-head group onto its KV head. V's
+    heads may have a width of their own (``dv``, the output's): it is read
+    off the refs, K's ``d`` off the query's."""
     # the int8 pools' scale views come between the pools and the output
     ks_ref, vs_ref = refs[:2] if quant else (None, None)
     o_ref, k_buf, v_buf, qbd_ref, acc_ref, k_sem, v_sem = refs[-7:]
     b = pl.program_id(0)
-    d = o_ref.shape[-1]
+    d, dv = q_ref.shape[-1], o_ref.shape[-1]
     bs = k_buf.shape[2]
     lanes = trip * bs  # tokens a trip covers
     nb = bound_ref[b]
@@ -174,7 +182,8 @@ def _decode_kernel(tables_ref, qpos_ref, bound_ref, layer_ref, q_ref,
         (the f32 product rounded through the compute dtype)."""
         for live, c in copies(t, slot, which):
             pl.when(live)(c.wait)
-        buf, sc_ref = (k_buf, ks_ref) if which == "k" else (v_buf, vs_ref)
+        buf, sc_ref, w = ((k_buf, ks_ref, d) if which == "k"
+                          else (v_buf, vs_ref, dv))
         if not quant:
             return buf[slot].reshape(lanes, -1).astype(o_ref.dtype)
         full = buf[slot].astype(jnp.float32).reshape(lanes, -1)
@@ -183,7 +192,7 @@ def _decode_kernel(tables_ref, qpos_ref, bound_ref, layer_ref, q_ref,
         row = jax.lax.broadcasted_iota(jnp.int32, (lanes, 1), 0)
         sc = jnp.where(t * lanes + row < nb * bs, sc_ref[0, t], 0.0)
         return jnp.concatenate(
-            [full[:, kv * d:(kv + 1) * d] * sc[:, kv:kv + 1]
+            [full[:, kv * w:(kv + 1) * w] * sc[:, kv:kv + 1]
              for kv in range(kv_heads)], axis=1).astype(o_ref.dtype)
 
     @pl.when(b == 0)
@@ -261,7 +270,7 @@ def _decode_kernel(tables_ref, qpos_ref, bound_ref, layer_ref, q_ref,
         jax.lax.fori_loop(0, trips, weighted_sum, None)
         for kv in range(kv_heads):
             rows = slice(kv * group, (kv + 1) * group)
-            o_ref[0, rows, :] = acc_ref[rows, kv * d:(kv + 1) * d].astype(
+            o_ref[0, rows, :] = acc_ref[rows, kv * dv:(kv + 1) * dv].astype(
                 o_ref.dtype)
 
 
@@ -276,7 +285,7 @@ def _stacked(pool: jnp.ndarray) -> jnp.ndarray:
 def paged_decode_attention(
     q: jnp.ndarray,          # [B, H, d] — the decode step's single token
     k_pool: jnp.ndarray,     # [L, NB, bs, KV * d] the stacked block pool
-    v_pool: jnp.ndarray,
+    v_pool: jnp.ndarray,     # [L, NB, bs, KV * dv]: v heads of their own width
     k_scale: Optional[jnp.ndarray],  # [L, NB, bs, KV] f32 (int8) | None
     v_scale: Optional[jnp.ndarray],
     layer,                   # int32 scalar: the layer whose blocks are read
@@ -288,9 +297,11 @@ def paged_decode_attention(
                              # written at (the cache's ``len`` before the step)
     *,
     window: Optional[int] = None,  # the model's sliding window, static
+    scale: Optional[float] = None,  # of the scores, static; d ** -0.5 if None
     interpret=None,
 ) -> jnp.ndarray:
-    """In-place paged decode attention over the block pool: out [B, H, d].
+    """In-place paged decode attention over the block pool: out [B, H, dv],
+    ``dv`` the v pool's head width (the k pool's ``d`` in most models).
 
     A slot's walk ends at the column its cursor lies in (left pads make the
     rope position undercount the lanes, so the cursor and not ``q_positions``
@@ -311,11 +322,13 @@ def paged_decode_attention(
     B, H, d = q.shape
     _, NB, bs, width = k_pool.shape
     KV = width // d
+    v_width = v_pool.shape[-1]
+    dv = v_width // KV
     nbps = tables.shape[1]
     quant = k_scale is not None
     if window is not None and window >= nbps * bs:
         window = None
-    trip = _blocks_per_trip(bs, width, k_pool.dtype.itemsize, nbps)
+    trip = _blocks_per_trip(bs, width + v_width, k_pool.dtype.itemsize, nbps)
     trips = -(-nbps // trip)
     lanes = trip * bs
 
@@ -323,7 +336,8 @@ def paged_decode_attention(
     # 1/sqrt(f32(d)) in f32 — a python 1/d**0.5 double differs by 1 ulp for
     # head dims like 96/112, enough to flip a bf16-rounded probability and
     # break the token-parity contract on those models
-    scale = float(np.float32(1.0) / np.sqrt(np.float32(d)))  # dtxlint: disable=DTX001 — host numpy scalar (d is a static shape), no device sync
+    if scale is None:
+        scale = float(np.float32(1.0) / np.sqrt(np.float32(d)))  # dtxlint: disable=DTX001 — host numpy scalar (d is a static shape), no device sync
     kernel = functools.partial(
         _decode_kernel, pool_blocks=NB, trip=trip, kv_heads=KV,
         group=H // KV, scale=scale, quant=quant, window=window)
@@ -361,7 +375,7 @@ def paged_decode_attention(
     ]
     args = [q, pos, _stacked(k_pool), _stacked(v_pool)]
     scratch = [pltpu.VMEM((2, trip, bs, width), k_pool.dtype),
-               pltpu.VMEM((2, trip, bs, width), v_pool.dtype)]
+               pltpu.VMEM((2, trip, bs, v_width), v_pool.dtype)]
     if quant:
         # Mosaic cuts no copy out of an HBM array whose minor dim (the KV
         # heads) is under a lane tile, so the scales come as each slot's
@@ -380,7 +394,7 @@ def paged_decode_attention(
         args += [view(k_scale), view(v_scale)]
     scratch += [
         pltpu.VMEM((H, width), q.dtype),      # block-diagonal q
-        pltpu.VMEM((H, width), jnp.float32),  # p · V, all blocks
+        pltpu.VMEM((H, v_width), jnp.float32),  # p · V, all blocks
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.SemaphoreType.DMA((2,)),
     ]
@@ -391,10 +405,10 @@ def paged_decode_attention(
             num_scalar_prefetch=len(prefetch),
             grid=(B,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, H, d), lambda b, *_: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, dv), lambda b, *_: (b, 0, 0)),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
         # the scratch buffers are zeroed in the first step for all of them
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -416,26 +430,36 @@ def _layer_operand(layer) -> jnp.ndarray:
     return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
+def walks_in_place(k_pool, v_pool, interpret=None) -> bool:
+    """Whether the decode kernel can walk these pools: the chip's compiler
+    cuts a block out of an HBM array only along whole lane tiles, so a pool
+    whose rows are not a multiple of that (the debug presets; KV heads x head
+    width of every published model is one) cannot be read by hand-issued
+    copies. Interpret mode takes any width."""
+    interpret = _interpret() if interpret is None else interpret
+    return interpret or not (k_pool.shape[-1] % _LANES
+                             or v_pool.shape[-1] % _LANES)
+
+
 def paged_attention_decode_step(q, leaves: dict, layer, cache: dict,
                                 pos_pool, positions, *, window=None,
-                                interpret=None):
+                                scale=None, interpret=None):
     """Model-facing wrapper: q ``[B, 1, H, d]`` (one decode token), the
     stacked cache leaves the layer scan carries (``k``/``v`` and, for the
     int8 cache, ``k_scale``/``v_scale``), the layer's index, the cache dict
     the step was handed (block tables, and ``len``: the lane this step's
     token was written at, which bounds the walk), the POST-write pos pool,
-    the step's ``positions [B, 1]`` and the model's sliding ``window`` (static;
-    None: every earlier key is seen). Returns ``[B, 1, H, d]`` in q.dtype —
-    drop-in for the gather + ``xla_attention`` pair."""
+    the step's ``positions [B, 1]``, the model's sliding ``window`` (static;
+    None: every earlier key is seen) and its score ``scale`` (static; None:
+    ``d ** -0.5``). Returns ``[B, 1, H, dv]`` in q.dtype, ``dv`` the v
+    pool's head width — drop-in for the gather + ``xla_attention`` pair."""
     B, T, H, d = q.shape
     assert T == 1, f"paged decode kernel is single-token (T=1), got T={T}"
     interpret = _interpret() if interpret is None else interpret
-    if leaves["k"].shape[-1] % _LANES and not interpret:
-        # the chip's compiler cuts a block out of an HBM array only along
-        # whole lane tiles, so a pool narrower than that (the debug presets;
-        # KV heads x head width of every published model is a multiple)
-        # cannot be walked by hand-issued copies: it takes the multi-token
-        # kernel at q_len 1, whose blocks arrive through BlockSpecs
+    if not walks_in_place(leaves["k"], leaves["v"], interpret):
+        # such pools take the multi-token kernel at q_len 1, whose blocks
+        # arrive through BlockSpecs; it has one head width and one scale
+        assert scale is None and leaves["k"].shape == leaves["v"].shape
         kv_pos = gathered_positions(pos_pool, cache["block_tables"])
         return paged_attention_multitoken_step(
             q, leaves, layer, cache,
@@ -444,7 +468,8 @@ def paged_attention_decode_step(q, leaves: dict, layer, cache: dict,
     out = paged_decode_attention(
         q[:, 0], leaves["k"], leaves["v"], leaves.get("k_scale"),
         leaves.get("v_scale"), layer, cache["block_tables"], pos_pool,
-        positions[:, 0], cache["len"], window=window, interpret=interpret)
+        positions[:, 0], cache["len"], window=window, scale=scale,
+        interpret=interpret)
     return out[:, None]
 
 

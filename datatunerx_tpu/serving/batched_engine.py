@@ -1198,6 +1198,7 @@ class BatchedEngine:
         # process can read them (chip_smoke.py asserts on this line)
         self.engine_line = {
             "decode_path": self.decode_path,
+            "decode_paths": self.decode_paths,
             "decode_window": self.decode_window,
             "sampling_epilogue": self.sampling_epilogue,
             "epilogue_impl": self._epilogue_impl,
@@ -1230,16 +1231,32 @@ class BatchedEngine:
 
     # ------------------------------------------------------------ block pool
     @property
-    def decode_path(self) -> str:
-        """How decode attention reads the KV cache: ``pallas`` (in-place
-        block-table kernel), ``gather`` (paged XLA oracle), or ``dense``."""
+    def decode_paths(self) -> dict:
+        """{attending kind: how its token step reads the KV cache}:
+        ``pallas`` (in-place block-table kernel), ``gather`` (paged XLA
+        oracle) or ``dense``. The path forward() takes, not the flag: of a
+        model of several layer kinds, the kinds models/hybrid.py hands to the
+        kernel (a windowed model's prefill chunks and verify columns read a
+        gathered view too; its token step does not)."""
+        from datatunerx_tpu.models.config import mixer_kinds
+        from datatunerx_tpu.models.hybrid import in_place_kinds
+
+        kinds = [name for name, kind in mixer_kinds(self.cfg).items()
+                 if kind.pools()]
         if not self.paged:
-            return "dense"
-        # the path forward() takes, not the flag: models/hybrid.py reads a
-        # gathered view whatever paged_kernel asked for (a windowed model's
-        # prefill chunks and verify columns do too; its token step does not)
-        takes_kernel = self.paged_kernel and not self.cfg.hybrid
-        return "pallas" if takes_kernel else "gather"
+            return dict.fromkeys(kinds, "dense")
+        if self.cfg.hybrid:  # shapes only: a donated leaf still has its shape
+            in_place = in_place_kinds(self.cfg, self._cache, 1)
+        else:
+            in_place = kinds if self.paged_kernel else ()
+        return {name: "pallas" if name in in_place else "gather"
+                for name in kinds}
+
+    @property
+    def decode_path(self) -> str:
+        """``decode_paths`` in a word: ``pallas``, ``gather`` or ``dense``,
+        or ``gather+pallas`` where a model's kinds differ."""
+        return "+".join(sorted(set(self.decode_paths.values())))
 
     @property
     def decode_window(self) -> Optional[int]:

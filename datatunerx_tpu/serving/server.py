@@ -361,9 +361,10 @@ def _metrics_text_locked(with_exemplars: bool = True) -> str:
     gen_toks = reg.counter("dtx_serving_generated_tokens_total",
                            "Tokens emitted to finished requests.")
     path_g = reg.gauge("dtx_serving_decode_path",
-                       "How decode attention reads the KV cache, one-hot by "
-                       "label (pallas = in-place block-table kernel, gather "
-                       "= paged XLA oracle, dense).")
+                       "How each attending layer kind's token step reads "
+                       "the KV cache, one-hot by label (pallas = in-place "
+                       "block-table kernel, gather = paged XLA oracle, "
+                       "dense).")
     window_g = reg.gauge("dtx_serving_decode_window",
                          "Lanes of sliding window the paged decode kernel "
                          "was built with; absent where it has none (no "
@@ -374,8 +375,8 @@ def _metrics_text_locked(with_exemplars: bool = True) -> str:
     window_g.clear()
     if getattr(eng, "generated_tokens", None) is not None:
         gen_toks.set(eng.generated_tokens)
-    if getattr(eng, "decode_path", None):
-        path_g.set(1, {"path": eng.decode_path})
+    for kind, path in (getattr(eng, "decode_paths", None) or {}).items():
+        path_g.set(1, {"kind": kind, "path": path})
     if getattr(eng, "decode_window", None):
         window_g.set(eng.decode_window)
     # persistent compile cache (utils/runtime.py): a replica that started

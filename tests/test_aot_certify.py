@@ -84,8 +84,13 @@ def sds(shape, dtype):
 # a table of 128 columns, 384 blocks, 16 stacked layers), bf16 and int8 pools
 # (the shared list holds the multi-token kernel and the sampler); and at
 # Mistral-7B's (8 KV heads of 128, a window of 4,096) with the cache its preset
-# allows, 8,192 lanes: the walk that starts at the window's first trip
-def decode_cases(tag, heads, slots, nbps, blocks, layers, window=None):
+# allows, 8,192 lanes: the walk that starts at the window's first trip; and at
+# the attending kinds of two models of several layer kinds whose token step
+# takes it (models/hybrid.py), as the cells mimo-serve-batch and
+# granite-serve-chat shape them: 64 slots, 4 KV heads of 192 (q/k) and 128 (v)
+# over tables of 160 columns; 8 KV heads of 64 over 64 columns, scores x 1/64
+def decode_cases(tag, heads, slots, nbps, blocks, layers, window=None,
+                 dv=None, scale=None):
     H, KV, d = heads
     # layer, tables, pos pool, q positions, lane cursors
     rest = (sds((), jnp.int32), sds((slots, nbps), jnp.int32),
@@ -93,16 +98,17 @@ def decode_cases(tag, heads, slots, nbps, blocks, layers, window=None):
             sds((slots,), jnp.int32))
     q = sds((slots, H, d), jnp.bfloat16)
     shape = (layers, blocks, ENGINE_BLOCK, KV * d)
-    pool, pool_i8 = sds(shape, jnp.bfloat16), sds(shape, jnp.int8)
-    scale = sds(shape[:3] + (KV,), jnp.float32)
+    v_shape = shape[:3] + (KV * (dv or d),)
+    scales = sds(shape[:3] + (KV,), jnp.float32)
     return [
         (f"kernel/paged_decode_bf16_{tag}",
          lambda q, k, v, *r: paged_decode_attention(
-             q, k, v, None, None, *r, window=window),
-         (q, pool, pool) + rest),
+             q, k, v, None, None, *r, window=window, scale=scale),
+         (q, sds(shape, jnp.bfloat16), sds(v_shape, jnp.bfloat16)) + rest),
         (f"kernel/paged_decode_int8_kv_{tag}",
-         lambda *a: paged_decode_attention(*a, window=window),
-         (q, pool_i8, pool_i8, scale, scale) + rest)]
+         lambda *a: paged_decode_attention(*a, window=window, scale=scale),
+         (q, sds(shape, jnp.int8), sds(v_shape, jnp.int8), scales, scales)
+         + rest)]
 
 
 nbps = ENGINE_SEQ // ENGINE_BLOCK
@@ -111,7 +117,12 @@ cases = (list(serving_kernel_cases(sh))
                         nbps, ENGINE_SLOTS * nbps, ENGINE_LAYERS)
          + decode_cases("cell", ENGINE_HEADS["llama2_7b"], 16, 128, 384, 16)
          + decode_cases("windowed", (32, 8, 128), 16, 512, 2048, 16,
-                        window=4096))
+                        window=4096)
+         # a model of several layer kinds has no int8 cache
+         + decode_cases("mimo_global", (64, 4, 192), 64, 160, 2560, 2,
+                        dv=128)[:1]
+         + decode_cases("granite_global", (32, 8, 64), 64, 64, 4096, 4,
+                        scale=0.015625)[:1])
 KERNEL_NAMES = ("dtx_paged_decode", "dtx_paged_multitoken", "dtx_fused_sample")
 done, failed, named = [], {}, {}
 for name, fn, args in cases:
@@ -165,7 +176,9 @@ def test_serving_kernels_lower_through_mosaic_at_engine_geometry():
                  "kernel/paged_decode_bf16_cell",
                  "kernel/paged_decode_int8_kv_cell",
                  "kernel/paged_decode_bf16_windowed",
-                 "kernel/paged_decode_int8_kv_windowed"):
+                 "kernel/paged_decode_int8_kv_windowed",
+                 "kernel/paged_decode_bf16_mimo_global",
+                 "kernel/paged_decode_bf16_granite_global"):
         assert want in names, (want, sorted(names))
     for case, kernels in doc["named"].items():
         want = ("dtx_fused_sample" if "fused_sample" in case else
@@ -380,7 +393,9 @@ from datatunerx_tpu.ops.paged_attention import init_paged_cache, state_leaf_keys
 from datatunerx_tpu.serving.batched_engine import MAX_STOP, _Programs
 
 cell = spec.load_cell(os.environ["DTX_CELL"])
-cfg = spec.register_preset(cell)
+# the cells' engines ask for the paged kernels ("auto" on a TPU): a sink-less
+# softmax-attention kind's token step then takes the decode kernel
+cfg = spec.register_preset(cell, paged_kernel=True)
 eng = cell.workload["engine"]
 S, bs, NB, L = eng["slots"], eng["kv_block_size"], eng["kv_blocks"], eng["max_seq_len"]
 sh = SingleDeviceSharding(topologies.get_topology_desc(
@@ -419,6 +434,10 @@ for name, lower in cases.items():
                  "alias": m.alias_size_in_bytes, "arguments": m.argument_size_in_bytes,
                  "temporaries": m.temp_size_in_bytes, "ragged": text.count("%ragged-dot"),
                  "gmm": text.count("%dtx_moe_gmm"),
+                 # the attention layers' token step: the paged decode kernel's call sites (under
+                 # their scope), and what still copies a gathered view of every slot's table
+                 "paged_decode_in_scope": len(re.findall(r"%dtx_paged_decode[.\w]* = .*dtx\.attn", text)),
+                 "view_copies": len(re.findall(r" = bf16\[%d,%d,[^ ]* copy\(" % (S, L), text)),
                  # the state-space token step: its kernel's call sites (under their scope), and
                  # what else still produces or copies the whole state leaf
                  "ssm_step": len(re.findall(r"%dtx_ssm_step[.\w]* = ", text)),
@@ -493,8 +512,14 @@ def test_granite_cell_programs_compile_for_v5e_at_full_depth(program, arguments,
         # run, and a second fusion read the old state again) and nothing copies it
         assert got["ssm_step"] == got["ssm_step_in_scope"] == 5, got
         assert got["ssm_leaf_fusions"] == 0 and got["ssm_leaf_copies"] == 0, got
-    else:  # a chunk of prompt tokens takes ``ssm.chunk_states``, as before
+        # the four attention layers (each a run of its own) read their blocks in place through
+        # ``dtx_paged_decode``: no view [64, 1024, 8, 64] of a pool is gathered and re-tiled (the
+        # gather path had 8 such copies and 1.531 GB of temporaries; this reads 1.341)
+        assert got["paged_decode_in_scope"] == 4 and got["view_copies"] == 0, got
+        assert got["temporaries"] < 1.4e9, got
+    else:  # a chunk of prompt tokens takes ``ssm.chunk_states`` and the gathered view, as before
         assert got["ssm_step"] == 0 and got["ssm_leaf_copies"] == 0, got
+        assert got["paged_decode_in_scope"] == 0, got
 
 
 @pytest.fixture(scope="module")

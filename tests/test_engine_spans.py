@@ -293,6 +293,7 @@ def test_decode_path_reports_the_path_a_windowed_model_takes(
         # forward() hands the token step to the kernel, window and all; one
         # the cache of 256 lanes cannot exceed is dropped there
         assert eng.paged_kernel and eng.decode_path == "pallas"
+        assert eng.decode_paths == {"window": "pallas"}
         assert eng.decode_window == built_with
         lines = [ln for ln in capfd.readouterr().err.splitlines()
                  if ln.startswith("[engine] ")]
@@ -304,10 +305,46 @@ def test_decode_path_reports_the_path_a_windowed_model_takes(
 
         monkeypatch.setattr(serving.STATE, "engine", eng)
         text = serving.metrics_text()
-        assert 'dtx_serving_decode_path{path="pallas"} 1' in text
+        assert 'dtx_serving_decode_path{kind="window",path="pallas"} 1' in text
         assert [ln for ln in text.splitlines()
                 if ln.startswith("dtx_serving_decode_window")] == (
             ["dtx_serving_decode_window 32"] if built_with else [])
         assert eng.generate(eng.tokenizer.encode("a b c"), max_new_tokens=3)
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("preset,engine_kw,paths,word", [
+    # a sink-less global kind takes the kernel, a window kind with a sink its view
+    ("debug-hybrid", dict(paged_kernel="on"), {"global": "pallas", "window": "gather"},
+     "gather+pallas"),
+    ("debug-hybrid", dict(paged_kernel="off"), {"global": "gather", "window": "gather"}, "gather"),
+    # state-space layers attend to nothing; the attention layers take the kernel
+    ("debug-granite", dict(paged_kernel="on"), {"global": "pallas"}, "pallas"),
+    # a latent pool has no kernel: a gathered view or its chosen rows
+    ("debug-ling", dict(paged_kernel="on"), {"mla": "gather"}, "gather"),
+    ("debug-granite", dict(paged_kernel="auto", kv_block_size=0), {"global": "dense"}, "dense"),
+])
+def test_decode_path_reports_what_each_attending_kinds_token_step_takes(
+        monkeypatch, capfd, preset, engine_kw, paths, word):
+    """The ``[engine]`` line, ``decode_paths`` / ``decode_path`` and the gauge
+    say per attending kind what ``forward`` does with a token step, not what
+    the flag asked for."""
+    eng = BatchedEngine("preset:" + preset, **dict(dict(
+        max_seq_len=128, slots=2, decode_chunk=4, kv_block_size=8), **engine_kw))
+    try:
+        assert eng.decode_paths == paths and eng.decode_path == word
+        assert eng.decode_window is None
+        line = [ln for ln in capfd.readouterr().err.splitlines()
+                if ln.startswith("[engine] {")][0]
+        doc = json.loads(line[len("[engine] "):])
+        assert doc["decode_paths"] == paths and doc["decode_path"] == word
+        from datatunerx_tpu.serving import server as serving
+
+        monkeypatch.setattr(serving.STATE, "engine", eng)
+        assert sorted(ln for ln in serving.metrics_text().splitlines()
+                      if ln.startswith("dtx_serving_decode_path{")) == sorted(
+            'dtx_serving_decode_path{kind="%s",path="%s"} 1' % kv
+            for kv in paths.items())
     finally:
         eng.close()

@@ -160,6 +160,16 @@ def _step(params, tokens, cache, positions, mask=None):
                    positions=positions, attention_mask=mask)
 
 
+@jax.jit
+def _step_in_place(params, tokens, cache, positions, mask=None):
+    """``_step`` for an engine that asked for the paged kernels: a token step
+    over a paged cache reads the attention layer's blocks in place through the
+    decode kernel (interpreted here; scores x ``attention_multiplier``) and
+    gathers no view, not of the positions either."""
+    return forward(params, tokens, get_config("debug-granite", paged_kernel=True),
+                   cache=cache, positions=positions, attention_mask=mask)
+
+
 def test_runs_name_their_mixers_and_every_scalar_is_away_from_its_default(model):
     cfg = model[0]
     runs = layer_runs(cfg)
@@ -211,29 +221,52 @@ def _paged(cfg, block_size, dtype=jnp.float32):
     return cache
 
 
-def _through(params, tokens, cache, chunks):
+def _through(params, tokens, cache, chunks, step=_step):
     outs = []
     for lo, hi in chunks:
-        out, cache = _step(params, tokens[:, lo:hi], cache, _positions(lo, hi))
+        out, cache = step(params, tokens[:, lo:hi], cache, _positions(lo, hi))
         outs.append(out)
     for t in range(chunks[-1][1], T):
-        out, cache = _step(params, tokens[:, t:t + 1], cache, _positions(t, t + 1))
+        out, cache = step(params, tokens[:, t:t + 1], cache, _positions(t, t + 1))
         outs.append(out)
     return jnp.concatenate(outs, axis=1)
 
 
+@pytest.mark.parametrize("path", ["gather", "kernel"])
 @pytest.mark.parametrize("block_size,chunks", [
     (8, ((0, 64), (64, 130))),          # two chunk programs, the state handed over
     (16, ((0, 130),)),                  # one chunk
     (4, ((0, 3), (3, 70), (70, 130))),  # a chunk shorter than the convolution
 ])
-def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, block_size, chunks):
+def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, block_size, chunks, path):
     cfg, _, params, tokens = model
     cache = _paged(cfg, block_size)
     # what an earlier request left in the slots: a cursor at 0 reads it as zero
     cache["state_ssm"] = cache["state_ssm"] + 3.0
     cache["state_ssm_conv"] = cache["state_ssm_conv"] - 2.0
-    np.testing.assert_allclose(_through(params, tokens, cache, chunks), want, atol=TOL)
+    step = _step_in_place if path == "kernel" else _step
+    np.testing.assert_allclose(_through(params, tokens, cache, chunks, step), want, atol=TOL)
+
+
+@pytest.mark.parametrize("step,calls", [("paged_token", 1), ("paged_chunk", 0), ("dense_token", 0)])
+def test_only_a_token_step_over_a_paged_cache_takes_the_kernel(model, step, calls):
+    """Asked for the paged kernels, ``forward`` traces the program it traced
+    without them for a chunk and for a dense cache; a token step over a paged
+    cache calls the decode kernel in its one run of attention layers and
+    gathers no view of the pools ([2, 192, 2, 16])."""
+    cfg = model[0]
+    asked = dataclasses.replace(cfg, paged_kernel=True)
+    n = 1 if step.endswith("token") else 8
+    cache = (_paged(cfg, 8) if step.startswith("paged")
+             else init_cache(cfg, 2, 192, dtype=jnp.float32, per_slot=True))
+    plain, got = (str(jax.make_jaxpr(lambda p, ids, ch, c=c: forward(
+        p, ids, c, cache=ch, positions=_positions(40, 40 + n)))(
+            model[2], jnp.zeros((2, n), jnp.int32), cache)) for c in (cfg, asked))
+    assert got.count("dtx_paged_decode") == calls and "dtx_paged_decode" not in plain
+    if calls:
+        assert "f32[2,192,2,16]" in plain and "f32[2,192,2,16]" not in got
+    else:
+        assert got == plain
 
 
 def test_a_state_stored_in_bfloat16_fails_the_tolerance(model, want):
@@ -387,6 +420,52 @@ def test_engine_serves_what_the_reference_puts_first(engine):
         assert np.mean(np.asarray(req.tokens[1:]) == np.asarray(req.tokens[:-1])) < 0.5
     # five Mamba layers x three slots x (8 heads x 16 x 32 float32 + 3 rows x 192 bf16)
     assert engine.state_bytes() == 5 * 3 * (8 * 16 * 32 * 4 + 3 * 192 * 2)
+
+
+def test_engine_serves_the_same_tokens_with_the_attention_layer_on_the_kernel(engine, tmp_path):
+    """An engine that differs from the fixture's in ``paged_kernel`` alone (the
+    same weights, the adapter on ``in_proj`` / ``q_proj`` / ``o_proj``): seven
+    requests over three slots, so that every slot is released and taken again,
+    prompts on and off the chunk's bucket, serve the same greedy tokens (or
+    part at a tie of the reference's, each serving one of the two)."""
+    from datatunerx_tpu.serving.adapters import make_adapter_checkpoint
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+
+    adapters = {"ad0": make_adapter_checkpoint(
+        str(tmp_path / "ad0"), "preset:debug-granite", seed=10, rank=4, targets=TARGETS)}
+    kernel = BatchedEngine("preset:debug-granite", adapters=adapters, paged_kernel="on", **ENGINE)
+    try:
+        kernel.params = engine.params
+        assert kernel.decode_paths == {"global": "pallas"} and kernel.decode_path == "pallas"
+        assert engine.decode_paths == {"global": "gather"}
+        rng = np.random.default_rng(5)
+        work = [(rng.integers(10, 500, size=n).tolist(), ad) for n, ad in (
+            (5, ""), (64, "ad0"), (70, ""), (150, "ad0"), (33, ""), (129, "ad0"), (8, ""))]
+        served = {}
+        for name, eng in (("gather", engine), ("kernel", kernel)):
+            reqs = [eng.submit(prompt, max_new_tokens=8 + 2 * i, adapter=ad)
+                    for i, (prompt, ad) in enumerate(work)]
+            for r in reqs:
+                assert r.done.wait(600) and r.error is None, r.error
+            served[name] = [r.tokens for r in reqs]
+    finally:
+        kernel.close()
+    assert all(len(t) == 8 + 2 * i for i, t in enumerate(served["gather"]))
+    # equal, or parted where the reference's first two choices lie within bf16's
+    # rounding of each other (the kernel sums a softmax in another order than XLA)
+    mc = dataclasses.asdict(engine.cfg)
+    stack, scales = engine.lora_stack
+    for (prompt, ad), a, b in zip(work, served["gather"], served["kernel"]):
+        if a == b:
+            continue
+        at = next(i for i, (x, y) in enumerate(zip(a, b)) if x != y)
+        i = engine.adapter_ids[ad]
+        lora = jax.tree_util.tree_map(lambda w: w[:, i], stack["layers"]) if ad else None
+        logits = ref.sequence_logits(engine.params, mc, prompt + a[:at], [len(prompt) + at - 1],
+                                     lora, float(scales[i]) if ad else 0.0)[0]
+        first, second = (int(t) for t in jnp.argsort(logits)[-1:-3:-1])
+        assert {a[at], b[at]} == {first, second}, (len(prompt), ad, at)
+        assert float(logits[first] - logits[second]) < 0.004, (len(prompt), ad, at)
 
 
 def test_a_used_slot_serves_a_new_request_as_a_fresh_engine_does(engine):

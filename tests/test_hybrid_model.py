@@ -68,6 +68,19 @@ def _step(params, tokens, cache, positions, mask=None):
                    positions=positions, attention_mask=mask)
 
 
+@jax.jit
+def _step_in_place(params, tokens, cache, positions, mask=None):
+    """``_step`` for an engine that asked for the paged kernels: over a paged
+    cache a token step's global kind (no sink) reads its blocks in place
+    through the decode kernel, interpreted here; the window kind, with its
+    sink, and every other step read the gathered view."""
+    return forward(params, tokens, get_config("debug-hybrid", paged_kernel=True),
+                   cache=cache, positions=positions, attention_mask=mask)
+
+
+STEPS = {"gather": _step, "kernel": _step_in_place}
+
+
 def test_runs_of_like_layers(model):
     cfg = model[0]
     runs = layer_runs(cfg)
@@ -101,13 +114,15 @@ def test_dense_cache_prefill_then_decode_equals_reference(model, want):
     assert decode[3] == 4 * (T - 50) and prefill[3] == 4  # expert layers a step
 
 
+@pytest.mark.parametrize("path", sorted(STEPS))
 @pytest.mark.parametrize("block_size,chunks", [
     (8, ((0, 32), (32, 50))),   # a block boundary inside the window; chunks wider than it
     (16, ((0, 50),)),
     (4, ((0, 16), (16, 32), (32, 50))),
 ])
-def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, block_size, chunks):
+def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, block_size, chunks, path):
     cfg, _, params, tokens = model
+    _step = STEPS[path]
     nbps = 128 // block_size
     cache = init_paged_cache(cfg, 2, 2 * nbps + 3, block_size, nbps, dtype=jnp.float32)
     # slot 1's blocks first, so that tables are no identity
@@ -124,9 +139,12 @@ def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, bl
     np.testing.assert_allclose(jnp.concatenate(outs, axis=1), want, atol=TOL)
 
 
-def test_left_padded_rows_read_the_window_view(model, want):
-    """Pads lie at a row's left: linear index and position differ by a constant."""
+@pytest.mark.parametrize("path", sorted(STEPS))
+def test_left_padded_rows_read_the_window_view(model, want, path):
+    """Pads lie at a row's left: linear index and position differ by a constant
+    (and the kernel's walk is bounded by the lane cursor, not the position)."""
     cfg, _, params, tokens = model
+    _step = STEPS[path]
     pad = 6
     cache = init_paged_cache(cfg, 2, 40, 8, 16, dtype=jnp.float32)
     cache["block_tables"] = jnp.asarray(np.arange(32).reshape(2, 16), jnp.int32)
@@ -139,6 +157,37 @@ def test_left_padded_rows_read_the_window_view(model, want):
         out, cache = _step(params, tokens[:, t:t + 1], cache, _positions(t, t + 1))
         outs.append(out)
     np.testing.assert_allclose(jnp.concatenate(outs, axis=1), want, atol=TOL)
+
+
+def _traced(cfg, cache, n):
+    """The program ``forward`` traces for a step of ``n`` tokens, as text."""
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    return str(jax.make_jaxpr(lambda p, ids, c: forward(
+        p, ids, cfg, cache=c, positions=_positions(40, 40 + n)))(
+            params, jnp.zeros((2, n), jnp.int32), cache))
+
+
+@pytest.mark.parametrize("step,calls", [("paged_token", 2), ("paged_chunk", 0), ("dense_token", 0),
+                                        ("dense_chunk", 0)])
+def test_only_a_token_step_over_a_paged_cache_takes_the_kernel(model, step, calls):
+    """An engine that asked for the paged kernels traces the program it traced
+    without them for every step but one: a single token over a paged cache,
+    where each run of global layers (two: one with a dense feed-forward, one
+    with experts) calls the decode kernel and no view of that kind is gathered
+    ([2, 128, 1, 24]: every slot's table through k_global). The window kind's
+    view stays."""
+    cfg = model[0]
+    asked = dataclasses.replace(cfg, paged_kernel=True)
+    kind, n = step.split("_")
+    cache = (init_paged_cache(cfg, 2, 35, 8, 16, dtype=jnp.float32) if kind == "paged"
+             else init_cache(cfg, 2, 128, dtype=jnp.float32, per_slot=True))
+    plain, got = (_traced(c, cache, 1 if n == "token" else 8) for c in (cfg, asked))
+    assert got.count("dtx_paged_decode") == calls and "dtx_paged_decode" not in plain
+    if calls:
+        assert "f32[2,128,1,24]" in plain and "f32[2,128,1,24]" not in got
+        assert "f32[2,32,2,24]" in got  # the window kind's view: 4 columns of 8 a slot
+    else:
+        assert got == plain
 
 
 @pytest.mark.parametrize("name,change,least", [
@@ -303,6 +352,89 @@ def test_engine_serves_greedy_tokens_of_a_plain_loop(engine):
     assert stats["prefill_local_rows"] > 0
     assert 0 < stats["decode_experts_hit"] <= 4 * stats["decode_layer_steps"]
     assert stats["decode_max_rows"] <= stats["decode_local_rows"]
+
+
+def _slots_reused(eng):
+    """Seven requests over two slots, base and two adapters, prompts on and off
+    the chunk's bucket (left pads inside rows): every slot is released and
+    taken again."""
+    rng = np.random.default_rng(3)
+    work = [(rng.integers(10, 500, size=n).tolist(), name) for n, name in (
+        (5, ""), (64, "ad0"), (70, "ad1"), (150, ""), (33, "ad1"), (129, "ad0"), (8, ""))]
+    return [(p, name, eng.submit(p, max_new_tokens=6 + 3 * i, adapter=name))
+            for i, (p, name) in enumerate(work)]
+
+
+def _preempted(eng):
+    """Four sessions growing toward 7 blocks each on a pool of 20: a preempted
+    session's rows of BOTH kinds and its cursor are exported and written back
+    into other blocks."""
+    work = [(list(range(20 * i + 5, 20 * i + 35)), "") for i in range(4)]
+    work = [(p, name, eng.submit(p, max_new_tokens=80)) for p, name in work]
+    for _, _, r in work:
+        assert r.done.wait(600)
+    assert eng.preempt_stats.get("exported", 0) >= 1, eng.preempt_stats
+    assert eng.free_kv_blocks == eng.total_kv_blocks
+    return work
+
+
+_KERNEL_PATHS = {
+    "slots_reused": (dict(kv_block_size=8, kv_blocks=64), _slots_reused),
+    "preempted": (dict(slots=4, kv_block_size=16, kv_blocks=20, kv_overcommit="on"), _preempted),
+}
+
+
+@pytest.mark.parametrize("path", sorted(_KERNEL_PATHS))
+def test_engine_serves_the_same_tokens_with_the_global_kind_on_the_kernel(path, tmp_path, capfd):
+    """Two engines that differ in ``paged_kernel`` alone, adapters on ``q_proj``
+    and ``o_proj``, through the paths that set a cursor or a table (the engine
+    refuses a prefix cache for a model of several kinds): the greedy streams are equal, or part where the reference's first two choices
+    lie within bf16's rounding of each other, each engine serving one of them
+    (the kernel sums a softmax's terms in another order than XLA does, so one
+    probability in thousands rounds the other way; at this width the logits'
+    spread is 0.16 and a tie within 0.005 comes every few dozen tokens). And
+    the engine says which kind's token step took what."""
+    from datatunerx_tpu.serving.adapters import make_adapter_checkpoint
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+
+    extra, scenario = _KERNEL_PATHS[path]
+    adapters = {f"ad{i}": make_adapter_checkpoint(
+        str(tmp_path / f"ad{i}"), "preset:debug-hybrid", seed=30 + i, rank=4,
+        targets=("q_proj", "o_proj")) for i in range(2)}
+    served = {}
+    for mode in ("off", "on"):
+        capfd.readouterr()
+        eng = BatchedEngine("preset:debug-hybrid", adapters=adapters, paged_kernel=mode,
+                            **dict(dict(slots=2, decode_chunk=4, max_seq_len=256,
+                                        prefill_chunk=64), **extra))
+        try:
+            took = "pallas" if mode == "on" else "gather"
+            assert eng.decode_paths == {"global": took, "window": "gather"}
+            assert eng.decode_path == ("gather+pallas" if mode == "on" else "gather")
+            assert eng.decode_window is None  # the window kind's step reads its view
+            line = [ln for ln in capfd.readouterr().err.splitlines() if ln.startswith("[engine] {")]
+            assert json.loads(line[0][len("[engine] "):])["decode_paths"] == eng.decode_paths
+            work = scenario(eng)
+            for _, _, r in work:
+                assert r.done.wait(600) and r.error is None, r.error
+            served[mode] = [(p, name, list(r.tokens)) for p, name, r in work]
+            if mode == "on":
+                params, mc = eng.params, dataclasses.asdict(eng.cfg)
+                stack, scales = eng.lora_stack
+                ids = dict(eng.adapter_ids)
+        finally:
+            eng.close()
+    assert all(tokens for _, _, tokens in served["off"])
+    for (prompt, name, a), (_, _, b) in zip(served["off"], served["on"]):
+        if a == b:
+            continue
+        at = next(i for i, (x, y) in enumerate(zip(a, b)) if x != y)
+        lora = jax.tree_util.tree_map(lambda w: w[:, ids[name]], stack["layers"]) if name else None
+        logits = ref.sequence_logits(params, mc, prompt + a[:at], [len(prompt) + at - 1], lora,
+                                     float(scales[ids[name]]) if name else 0.0)[0]
+        first, second = (int(i) for i in jnp.argsort(logits)[-1:-3:-1])
+        assert {a[at], b[at]} == {first, second}, (len(prompt), name, at)
+        assert float(logits[first] - logits[second]) < 0.005, (len(prompt), name, at)
 
 
 def test_engine_counts_blocks_behind_the_window(engine):

@@ -229,6 +229,7 @@ def test_paged_long_prompt_chunked_prefill_matches_dense(dense, budgeted):
 
 def test_kernel_decode_matches_gather_and_dense(dense, paged, kernel_eng):
     assert kernel_eng.decode_path == "pallas"
+    assert kernel_eng.decode_paths == {"global": "pallas"}
     assert paged.decode_path == "gather" and dense.decode_path == "dense"
     prompt = dense.tokenizer.encode("the quick brown fox jumps over")
     want = dense.generate(prompt, max_new_tokens=12)

@@ -79,10 +79,11 @@ def _make_pool(key, B, NB, KV, d, lens, tables, dtype=jnp.float32,
 
 
 def _oracle(q, k_pool, v_pool, ks, vs, tables, pos, q_positions, dtype,
-            window=None):
+            window=None, scale=None):
     """The gather path, element for element: clamp the table, gather the
     linear view, sentinel-mask the positions, bias (the model's sliding
-    ``window`` in it), xla_attention."""
+    ``window`` in it), xla_attention (a kind's score ``scale`` in it; v heads
+    as wide as the v pool's are)."""
     B = q.shape[0]
     tbl = jnp.where(tables >= 0, tables, 0)
     k_all = k_pool[tbl].reshape(B, -1, k_pool.shape[-2], k_pool.shape[-1])
@@ -99,7 +100,8 @@ def _oracle(q, k_pool, v_pool, ks, vs, tables, pos, q_positions, dtype,
     kv_pos = kv_pos.reshape(B, -1)
     bias = make_causal_bias(q_positions[:, None], kv_pos,
                             sliding_window=window)
-    return xla_attention(q[:, None].astype(dtype), k_all, v_all, bias)[:, 0]
+    return xla_attention(q[:, None].astype(dtype), k_all, v_all, bias,
+                         scale=scale)[:, 0]
 
 
 def _run(B=2, NB=8, nbps=3, KV=2, G=2, d=16, lens=(17, 5), dtype=jnp.float32,
@@ -225,12 +227,14 @@ def test_empty_slot_yields_finite_output():
 # ------------------------------------------------- the walk's bound (cursor)
 
 # one compile a (shape, dtype) for the cases below, which differ in values
-_jitted_decode = jax.jit(paged_decode_attention, static_argnames=("window",))
+_jitted_decode = jax.jit(paged_decode_attention,
+                         static_argnames=("window", "scale"))
 
 
 def _cursor_run(cursors, pads=None, nbps=5, KV=2, G=2, d=16,
                 dtype=jnp.float32, quant=False, past=None, seed=0,
-                window=None, gaps=None, holes=(), poison_before=None):
+                window=None, gaps=None, holes=(), poison_before=None,
+                dv=None, scale=None):
     """Slots whose lane cursors are ``cursors``: slot ``b`` has lanes
     ``0 .. cursors[b]`` written, the query its last one. Its first ``pads[b]``
     lanes are a prompt's left padding (sentinel position, junk K/V), the rope
@@ -245,15 +249,19 @@ def _cursor_run(cursors, pads=None, nbps=5, KV=2, G=2, d=16,
     ``(slot, column)`` table entries set to -1 after the writes, for both.
     ``poison_before[b]`` columns at the head of slot ``b``'s table hold NaN
     in the kernel's pools (NaN scales for int8) and zeros in the oracle's,
-    whose mask drops them: the kernel must not have copied them."""
+    whose mask drops them: the kernel must not have copied them.
+
+    ``dv`` is the v heads' width where it is not ``d`` and ``scale`` the
+    scores' where it is not ``d ** -0.5``: kernel and oracle get both."""
     B, H = len(cursors), KV * G
+    dv = dv or d
     pads = pads or (0,) * B
     gaps = gaps or ((0, 0),) * B
     NB = B * nbps
     rng = np.random.default_rng(seed)
     junk = {None: 0.0, "finite": 3e4, "nan": np.nan}[past]
     k_pool = np.full((NB, BS, KV, d), junk, np.float32)
-    v_pool = np.full((NB, BS, KV, d), junk, np.float32)
+    v_pool = np.full((NB, BS, KV, dv), junk, np.float32)
     pos = np.full((NB, BS), POS_SENTINEL, np.int32)
     tables = np.full((B, nbps), -1, np.int32)
     cut = tables.copy()
@@ -267,7 +275,7 @@ def _cursor_run(cursors, pads=None, nbps=5, KV=2, G=2, d=16,
         for lane in range(c + 1):
             blk, off = tables[b, lane // BS], lane % BS
             k_pool[blk, off] = rng.standard_normal((KV, d))
-            v_pool[blk, off] = rng.standard_normal((KV, d))
+            v_pool[blk, off] = rng.standard_normal((KV, dv))
             if lane >= pad and not gap_at <= lane < gap_at + gap_n:
                 pos[blk, off] = lane - pad - gap_n * (lane >= gap_at)
         # the unwritten lanes of the cursor's own block are a scrubbed block's
@@ -304,8 +312,9 @@ def _cursor_run(cursors, pads=None, nbps=5, KV=2, G=2, d=16,
     tables, cut, pos = jnp.asarray(tables), jnp.asarray(cut), jnp.asarray(pos)
     got = _jitted_decode(
         q, *(_stacked(a) for a in pools), LAYER, tables, pos, q_positions,
-        cursor, window=window)
-    want = _oracle(q, *clean, cut, pos, q_positions, dtype, window=window)
+        cursor, window=window, scale=scale)
+    want = _oracle(q, *clean, cut, pos, q_positions, dtype, window=window,
+                   scale=scale)
     return np.asarray(got, np.float32), np.asarray(want, np.float32)
 
 
@@ -347,6 +356,96 @@ def test_left_padded_rows_are_bounded_by_the_cursor(pad, dtype):
         np.testing.assert_array_equal(got, want)
     else:
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+# ------------------------------ a kind's own v width and score scale
+
+_KIND_CASES = {
+    # v heads of 128 under q/k heads of 192, sixteen query heads a KV head
+    "v128_under_d192_g16": dict(KV=2, G=16, d=192, dv=128),
+    # heads of 64 whose scores are scaled by 1/64, where d ** -0.5 is 1/8
+    "scale_64th_d64": dict(KV=2, G=4, d=64, scale=0.015625),
+    "v24_over_d16_scaled": dict(KV=2, G=2, d=16, dv=24, scale=0.3),
+    "v_equals_d_no_scale": dict(KV=2, G=2, d=16),
+}
+
+
+@pytest.mark.parametrize("pad", [0, 1, BS - 1])
+@pytest.mark.parametrize("pools", ["bf16", "int8"])
+@pytest.mark.parametrize("case", sorted(_KIND_CASES))
+def test_a_kinds_v_width_and_scale_match_the_oracle_bitwise(case, pools, pad):
+    """Slots of 3 blocks and a bit (5 more pads mid-row, as a prefix-cache
+    extension leaves them), 4 tokens and 1 behind ``pad`` left pads, and one
+    released with the cursor it kept (a table of -1: zeros), finite junk past
+    every cursor. The oracle is ``xla_attention(..., scale=)`` over the
+    gathered views, v's of its own width."""
+    cursors = (pad + 3 * BS + 2, pad + 3, pad, pad + BS)
+    got, want = _cursor_run(
+        cursors, pads=(pad,) * 4, dtype=jnp.bfloat16, quant=pools == "int8",
+        past="finite", holes=tuple((3, c) for c in range(5)),
+        gaps=((pad + BS + 1, 5),) + ((0, 0),) * 3, **_KIND_CASES[case])
+    kw = _KIND_CASES[case]
+    assert got.shape == (4, kw["KV"] * kw["G"], kw.get("dv", kw["d"]))
+    np.testing.assert_array_equal(got[:3], want[:3])
+    np.testing.assert_array_equal(got[3], 0.0)
+
+
+@pytest.mark.parametrize("case", sorted(_KIND_CASES))
+def test_a_kinds_slot_with_nothing_written_stays_finite(case):
+    """A slot admitted and not yet written (blocks held, every position the
+    sentinel, NaN past the cursor's column) beside a live one: its row is
+    the oracle's uniform garbage, finite, and the live row is the oracle's."""
+    kw = _KIND_CASES[case]
+    KV, G, d, dv = kw["KV"], kw["G"], kw["d"], kw.get("dv", kw["d"])
+    nbps, NB = 5, 10
+    rng = np.random.default_rng(0)
+    draw = lambda w: jnp.asarray(  # noqa: E731
+        rng.standard_normal((NB, BS, KV, w)), jnp.bfloat16)
+    kp, vp = draw(d), draw(dv)
+    kp, vp = kp.at[nbps + 1:].set(jnp.nan), vp.at[nbps + 1:].set(jnp.nan)
+    tables = jnp.arange(2 * nbps, dtype=jnp.int32).reshape(2, nbps)
+    pos = np.full((NB, BS), POS_SENTINEL, np.int32)
+    pos[:nbps] = np.arange(nbps * BS).reshape(nbps, BS)
+    q = jnp.asarray(rng.standard_normal((2, KV * G, d)), jnp.bfloat16)
+    q_positions = jnp.asarray([nbps * BS - 1, 0], jnp.int32)
+    got = _jitted_decode(q, _stacked(kp), _stacked(vp), None, None, LAYER,
+                         tables, jnp.asarray(pos), q_positions, q_positions,
+                         scale=kw.get("scale"))
+    want = _oracle(q, kp, vp, None, None, tables, jnp.asarray(pos),
+                   q_positions, jnp.bfloat16, scale=kw.get("scale"))
+    assert got.shape == (2, KV * G, dv)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(got[0], np.float32),
+                                  np.asarray(want[0], np.float32))
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_one_width_and_no_scale_lower_to_the_call_without_them(quant):
+    """``scale=None`` over pools of one width is the program a call with no
+    ``scale`` argument lowers to (the parent's call, text for text:
+    scripts/hash_programs.py compares it with the parent commit's), and so
+    is the default spelled out; another scale or a v pool of another width
+    is another program."""
+    B, KV, G, d, nbps, NB = 4, 2, 4, 16, _W_NBPS, 8
+    dtype = jnp.int8 if quant else jnp.bfloat16
+    pool = jnp.zeros((LAYERS, NB, BS, KV * d), dtype)
+    wider = jnp.zeros((LAYERS, NB, BS, KV * (d + 8)), dtype)
+    scales = jnp.zeros((LAYERS, NB, BS, KV), jnp.float32) if quant else None
+    ints = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    q = jnp.zeros((B, KV * G, d), jnp.bfloat16)
+
+    def lowered(v_pool=pool, **kw):
+        return jax.jit(lambda q: paged_decode_attention(
+            q, pool, v_pool, scales, scales, LAYER, ints(B, nbps),
+            ints(NB, BS), ints(B), ints(B), **kw)).lower(q).as_text()
+
+    base = lowered()
+    assert lowered(scale=None) == base
+    assert lowered(scale=float(np.float32(1.0) / np.sqrt(np.float32(d)))) == base
+    assert lowered(scale=0.125) != base
+    assert lowered(v_pool=wider) != base
+    for window in (72, nbps * BS):
+        assert lowered(window=window, scale=None) == lowered(window=window)
 
 
 # ------------------------------------------- the walk's first trip (window)
