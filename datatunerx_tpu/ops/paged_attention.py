@@ -384,6 +384,24 @@ def row_trim(row: Dict, width: int) -> Dict:
     return out
 
 
+def row_pad(row: Dict, width: int) -> Dict:
+    """Sentinel-pad a cursor-trimmed dense row cache back to ``width``
+    (``row_trim``'s inverse). Stored prefix rows are trimmed to their live cursor (no full
+    ``max_seq_len`` gather per insert), but the extension program keeps ONE
+    compiled geometry — full width — so padding happens here, once per
+    extension, instead of a compile per stored prefix length."""
+    W = row["pos"].shape[1]
+    if W >= width:
+        return row
+    out = dict(row)
+    pad5 = [(0, 0), (0, 0), (0, width - W), (0, 0), (0, 0)]
+    for key in kv_leaf_keys(row):
+        out[key] = jnp.pad(row[key], pad5[:row[key].ndim])
+    out["pos"] = jnp.pad(row["pos"], [(0, 0), (0, width - W)],
+                         constant_values=POS_SENTINEL)
+    return out
+
+
 def paged_install_table(cache: Dict, slot, table_row: jnp.ndarray) -> Dict:
     """Hand ``slot`` the blocks of ``table_row`` ([blocks_per_slot], -1 past
     the last one) for a prompt that is prefilled in place: install the row,

@@ -34,6 +34,8 @@ generate.go:160-329 deploys LlamaDeployment replicas), rebuilt TPU-first:
 from __future__ import annotations
 
 import collections
+import contextlib
+import functools
 import itertools
 import json
 import math
@@ -42,6 +44,7 @@ import sys
 import threading
 import time
 import uuid
+import weakref
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -60,15 +63,14 @@ from datatunerx_tpu.models.lora import LORA_TARGETS, lora_scaling
 from datatunerx_tpu.ops import dsa
 from datatunerx_tpu.ops._pallas import interpret_default
 from datatunerx_tpu.ops.paged_attention import (
-    POS_SENTINEL,
-    BlockAllocator,
-    blocks_for_depth,
     init_paged_cache,
     kv_leaf_keys,
     paged_copy_block,
     paged_extract_row,
     paged_insert_row,
     paged_install_table,
+    row_pad,
+    row_trim,
     state_insert,
     state_leaf_keys,
     state_slot,
@@ -78,7 +80,8 @@ from datatunerx_tpu.ops.pallas_sampling import (
     sample_rows,
 )
 from datatunerx_tpu.serving.engine import _sample_jit
-from datatunerx_tpu.utils.decoding import DECODE_BUCKET
+from datatunerx_tpu.serving.kv_pool import KVPool
+from datatunerx_tpu.utils.decoding import DECODE_BUCKET, prepare_prompt
 from datatunerx_tpu.utils.model_loader import load_model_and_tokenizer
 
 MAX_STOP = 8  # static per-slot stop-token capacity
@@ -354,24 +357,6 @@ class _Phase:
         self._row[0] += time.perf_counter() - self._t0
         self._row[1] += 1
         return self._span.__exit__(*exc)
-
-
-def _pad_row(row: Dict, width: int) -> Dict:
-    """Sentinel-pad a cursor-trimmed dense row cache back to ``width``.
-    Stored prefix rows are trimmed to their live cursor (no full
-    ``max_seq_len`` gather per insert), but the extension program keeps ONE
-    compiled geometry — full width — so padding happens here, once per
-    extension, instead of a compile per stored prefix length."""
-    W = row["pos"].shape[1]
-    if W >= width:
-        return row
-    out = dict(row)
-    pad5 = [(0, 0), (0, 0), (0, width - W), (0, 0), (0, 0)]
-    for key in kv_leaf_keys(row):
-        out[key] = jnp.pad(row[key], pad5[:row[key].ndim])
-    out["pos"] = jnp.pad(row["pos"], [(0, 0), (0, width - W)],
-                         constant_values=POS_SENTINEL)
-    return out
 
 
 def load_checkpoint_state(checkpoint_path: str) -> dict:
@@ -911,23 +896,60 @@ class BatchedEngine:
         # tokens handed to finished requests (dtx_serving_generated_tokens_
         # total): added once per request in _complete, never per token
         self.generated_tokens = 0
-        self._allocator: Optional[BlockAllocator] = None
+        # ---- speculative decoding (serving/speculative.py): a draft model
+        # proposes k tokens, one verify-k target forward accepts a prefix.
+        # No draft configured → every spec structure stays None and the
+        # scheduler takes the exact pre-spec decode path (--spec_mode off
+        # is byte-identical to not having the feature).
+        smode = (spec_mode or "auto").strip().lower()
+        if smode not in ("auto", "on", "off"):
+            raise ValueError(f"spec_mode must be auto|on|off, got {spec_mode!r}")
+        if smode == "on" and not spec_draft:
+            raise ValueError("--spec_mode on requires --spec_draft_config")
+        self.spec_mode = smode
+        self.spec_k = max(1, int(spec_k))
+        self.spec = None
+        self.spec_tree = None
+        if spec_tree and smode != "off":
+            from datatunerx_tpu.serving import speculative as spec_mod
+
+            if not spec_draft:
+                raise ValueError("--spec_tree requires --spec_draft_config")
+            self.spec_tree = spec_mod.parse_spec_tree(spec_tree)
+            if self.spec_tree.step_tokens >= self.max_seq_len:
+                raise ValueError(
+                    f"spec_tree {self.spec_tree} writes "
+                    f"{self.spec_tree.step_tokens} tokens per step — does "
+                    f"not fit max_seq_len {self.max_seq_len}")
+        # one verify step writes up to step-token-count tokens past a row's
+        # cursor (chain: pending + k proposals; tree: pending + W*D nodes);
+        # paged admission reserves that overshoot so every verify write
+        # stays physical (ops.paged_attention.blocks_for_depth caps at the
+        # table width). Sizing it from the ACTUAL per-step token count —
+        # not a chain-shaped spec_k+1 — is what keeps tree mode from
+        # under-reserving blocks. 0 when spec is off — reserve math
+        # byte-identical to today.
+        self._spec_step_tokens = (self.spec_tree.step_tokens
+                                  if self.spec_tree else self.spec_k + 1)
+        self._spec_overshoot = (self._spec_step_tokens
+                                if spec_draft and smode != "off" else 0)
+        # the most cache lanes one scheduler tick can consume per slot (a
+        # plain decode chunk, or a verify step — chain or tree): with the
+        # overshoot, what the overcommit grower keeps ahead of every cursor
+        self._tick_advance = (max(self.chunk, self._spec_step_tokens)
+                              if self._spec_overshoot else self.chunk)
+        self._pool: Optional[KVPool] = None
         if self.paged:
-            if self.max_seq_len % self.block_size:
-                raise ValueError(
-                    f"kv_block_size {self.block_size} must divide "
-                    f"max_seq_len {self.max_seq_len}")
-            self.blocks_per_slot = self.max_seq_len // self.block_size
-            total_blocks = int(kv_blocks or slots * self.blocks_per_slot)
-            if total_blocks < self.blocks_per_slot:
-                raise ValueError(
-                    f"kv_blocks {total_blocks} cannot hold one full-length "
-                    f"request ({self.blocks_per_slot} blocks of "
-                    f"{self.block_size})")
-            self._allocator = BlockAllocator(total_blocks)
+            self._pool = KVPool(
+                slots, self.max_seq_len, self.block_size, kv_blocks,
+                overshoot=self._spec_overshoot,
+                advance=self._tick_advance if self.overcommit else None,
+                # weakly: a cycle would keep a dropped engine's cache in HBM
+                # until the collector runs
+                cache=functools.partial(getattr, weakref.proxy(self), "_cache"))
             self._cache = init_paged_cache(
-                self.cfg, slots, total_blocks, self.block_size,
-                self.blocks_per_slot, dtype=jnp.bfloat16,
+                self.cfg, slots, self._pool.total, self.block_size,
+                self._pool.blocks_per_slot, dtype=jnp.bfloat16,
                 quantize=self.kv_quant)
         else:
             self._cache = init_cache(self.cfg, slots, self.max_seq_len,
@@ -978,42 +1000,6 @@ class BatchedEngine:
         self._stops = jnp.full((slots, MAX_STOP), -1, jnp.int32)
         self._adapter_idx = jnp.zeros((slots,), jnp.int32)
 
-        # ---- speculative decoding (serving/speculative.py): a draft model
-        # proposes k tokens, one verify-k target forward accepts a prefix.
-        # No draft configured → every spec structure stays None and the
-        # scheduler takes the exact pre-spec decode path (--spec_mode off
-        # is byte-identical to not having the feature).
-        smode = (spec_mode or "auto").strip().lower()
-        if smode not in ("auto", "on", "off"):
-            raise ValueError(f"spec_mode must be auto|on|off, got {spec_mode!r}")
-        if smode == "on" and not spec_draft:
-            raise ValueError("--spec_mode on requires --spec_draft_config")
-        self.spec_mode = smode
-        self.spec_k = max(1, int(spec_k))
-        self.spec = None
-        self.spec_tree = None
-        if spec_tree and smode != "off":
-            from datatunerx_tpu.serving import speculative as spec_mod
-
-            if not spec_draft:
-                raise ValueError("--spec_tree requires --spec_draft_config")
-            self.spec_tree = spec_mod.parse_spec_tree(spec_tree)
-            if self.spec_tree.step_tokens >= self.max_seq_len:
-                raise ValueError(
-                    f"spec_tree {self.spec_tree} writes "
-                    f"{self.spec_tree.step_tokens} tokens per step — does "
-                    f"not fit max_seq_len {self.max_seq_len}")
-        # one verify step writes up to step-token-count tokens past a row's
-        # cursor (chain: pending + k proposals; tree: pending + W*D nodes);
-        # paged admission reserves that overshoot so every verify write
-        # stays physical (ops.paged_attention.blocks_for_depth caps at the
-        # table width). Sizing it from the ACTUAL per-step token count —
-        # not a chain-shaped spec_k+1 — is what keeps tree mode from
-        # under-reserving blocks. 0 when spec is off — reserve math
-        # byte-identical to today.
-        self._spec_step_tokens = (self.spec_tree.step_tokens
-                                  if self.spec_tree else self.spec_k + 1)
-        self._spec_overshoot = 0
         if spec_draft and smode != "off":
             from datatunerx_tpu.serving import speculative as spec_mod
 
@@ -1038,7 +1024,6 @@ class BatchedEngine:
                         else spec_mod.AdaptiveK)
             self.spec_ctrl = ctrl_cls(self.spec_k, mode=smode,
                                       tree=self.spec_tree)
-            self._spec_overshoot = self._spec_step_tokens
             self._spec_pending = jnp.zeros((slots,), jnp.int32)
             self._spec_form = [False] * slots   # slot is in pending form
             self._spec_primed = [False] * slots  # draft row holds the context
@@ -1055,36 +1040,23 @@ class BatchedEngine:
             self._spec_tree_slot_path: Dict[int, float] = {}
             self._h_accept_len = None  # bound after the registry exists
 
-        # ---- overcommit scheduler state. _tick_advance = the most cache
-        # lanes one scheduler tick can consume per slot (a plain decode
-        # chunk, or a verify step — chain or tree), and growth must
-        # additionally keep the spec write overshoot physical — together
-        # the per-tick capacity target the grower maintains ahead of every
-        # cursor.
-        self._tick_advance = self.chunk
-        if self.spec is not None:
-            self._tick_advance = max(self.chunk, self._spec_step_tokens)
-        # preempted sessions, parked host-side as dtx-kv-session payloads
-        # (raw-numpy bodies — no b64 for in-process parking), oldest first;
-        # owned by the scheduler thread
+        # ---- overcommit scheduler state: preempted sessions, parked
+        # host-side as dtx-kv-session payloads (raw-numpy bodies — no b64 for
+        # in-process parking), oldest first; owned by the scheduler thread
         self._preempted: List[dict] = []
         # dtx_serving_preemptions_total{outcome} source (scheduler-only
         # writes; scraped racily like every other stats dict)
         self.preempt_stats: Dict[str, int] = {}
-        # capacity observability: the high-water
-        # mark of concurrently admitted sessions and each finished session's
-        # physical block footprint (== its peak: tables only ever grow)
+        # capacity observability: the high-water mark of concurrently
+        # admitted sessions and the pool's record of finished sessions' blocks
         self.kv_stats = {"peak_sessions": 0,
-                         "session_blocks": collections.deque(maxlen=4096)}
-        # per-slot EAGER-equivalent reserve (what the overcommit-off engine
-        # would hold) — the dtx_serving_kv_overcommit_ratio numerator
-        self._slot_demand: List[int] = [0] * slots
+                         "session_blocks": self._pool.session_blocks
+                         if self._pool else collections.deque()}
         # chat-encode LRU (see _encode_chat): HTTP threads share it
         self._encode_memo: "collections.OrderedDict[str, tuple]" = \
             collections.OrderedDict()
         self._encode_memo_lock = threading.Lock()
         self._slot_req: List[Optional[Request]] = [None] * slots
-        self._slot_blocks: List[List[int]] = [[] for _ in range(slots)]
         # dynamic mode: the adapter NAME each slot pins (released with the
         # slot, so LRU eviction can never pull weights out from under an
         # in-flight decode)
@@ -1268,31 +1240,22 @@ class BatchedEngine:
             return None
         return window if window < self.max_seq_len else None
 
+    # the pool's gauges; None on a dense engine (no block signal)
     @property
     def total_kv_blocks(self) -> Optional[int]:
-        return self._allocator.num_blocks if self._allocator else None
+        return self._pool.total if self._pool else None
 
     @property
     def free_kv_blocks(self) -> Optional[int]:
-        return self._allocator.free_count if self._allocator else None
+        return self._pool.free if self._pool else None
 
     @property
     def kv_blocks_reserved(self) -> Optional[int]:
-        if self._allocator is None:
-            return None
-        return self._allocator.num_blocks - self._allocator.free_count
+        return self._pool.total - self._pool.free if self._pool else None
 
     @property
     def kv_overcommit_ratio(self) -> Optional[float]:
-        """Live sessions' EAGER-equivalent block demand over the physical
-        pool: > 1.0 means the engine has admitted more logical reserve than
-        HBM holds — the whole point of on-demand growth. None on dense
-        engines (no block signal)."""
-        if self._allocator is None:
-            return None
-        demand = sum(self._slot_demand[s] for s in range(self.slots)
-                     if self._slot_req[s] is not None)
-        return round(demand / max(1, self._allocator.num_blocks), 4)
+        return self._pool.overcommit_ratio if self._pool else None
 
     @property
     def parked_sessions(self) -> int:
@@ -1387,7 +1350,7 @@ class BatchedEngine:
         for slot in range(self.slots):
             if self._slot_req[slot] is None:
                 continue
-            held = len(self._slot_blocks[slot])
+            held = len(self._pool.held(slot))
             cursor = int(self._slot_cursor[slot])  # dtxlint: disable=DTX001 — host numpy
             live += held
             behind += min(held, max(0, cursor - kind.window + 1)
@@ -1400,9 +1363,8 @@ class BatchedEngine:
         the allocator (dense-row entries hold no pool resources). Runs on
         whichever thread evicted (scheduler put, admin drop_adapter) —
         the allocator's own lock covers it."""
-        blocks = ent.get("blocks")
-        if blocks and self._allocator is not None:
-            self._allocator.free(blocks)
+        if ent.get("blocks"):
+            self._pool.free_entry(ent["blocks"])
 
     def _count_preempt(self, outcome: str):
         self.preempt_stats[outcome] = self.preempt_stats.get(outcome, 0) + 1
@@ -1580,8 +1542,8 @@ class BatchedEngine:
             row = usage.setdefault(
                 req.tenant,
                 {"requests": 0, "tokens_in": 0, "tokens_out": 0})
-            row["kv_blocks"] = (row.get("kv_blocks", 0)
-                                + len(self._slot_blocks[s]))
+            row["kv_blocks"] = (row.get("kv_blocks", 0) + (
+                len(self._pool.held(s)) if self._pool else 0))
         # adapter residency per tenant (how many of the tenant's adapters
         # are pool-resident right now)
         resident = set(self.adapter_registry.resident()) \
@@ -1655,7 +1617,7 @@ class BatchedEngine:
             if self.max_seq_len - cursor >= need:
                 row_logits, row_cache = self._extend(
                     self.params, self._lora_arg(),
-                    _pad_row(pent["cache"], self.max_seq_len),
+                    row_pad(pent["cache"], self.max_seq_len),
                     jnp.asarray([stoks], jnp.int32),
                     jnp.asarray([smask], jnp.int32),
                     jnp.asarray([spos], jnp.int32),
@@ -1707,6 +1669,25 @@ class BatchedEngine:
             jnp.asarray(req.adapter, jnp.int32),
             jnp.asarray(req.seed, jnp.uint32),
         )
+
+    def _insert_row(self, slot: int, table, row: Dict, row_logits, cursor,
+                    arm_args: tuple, rng=None):
+        """Put a dense single-row cache into ``slot`` (through the row
+        ``table`` of its blocks where the cache is paged) and arm it to decode
+        from ``cursor``, the row's real KV depth. ``rng``: a migrated or
+        resumed session's LIVE stream, in place of the seed-derived key."""
+        program, at = ((self._insert_paged, (table,)) if self.paged
+                       else (self._insert, ()))
+        (self._cache, self._logits, self._pos, self._remaining,
+         self._active, self._temps, self._top_ps, self._stops,
+         self._adapter_idx, self._rng) = program(
+            self._cache, self._logits, self._pos, self._remaining,
+            self._active, self._temps, self._top_ps, self._stops,
+            self._adapter_idx, self._rng,
+            jnp.asarray(slot, jnp.int32), *at, row, row_logits,
+            jnp.asarray(cursor, jnp.int32), *arm_args)
+        if rng is not None:
+            self._rng = self._rng.at[slot].set(jnp.asarray(rng, jnp.uint32))
 
     def _admit(self, req: Request, slot: int) -> bool:
         """Occupy ``slot`` with ``req``, resolving (and PINNING) its
@@ -1768,8 +1749,6 @@ class BatchedEngine:
         pool exhausted; the request stays queued), serves prefix-cache hits
         by scattering the row into the blocks, and registers everything else
         for chunked prefill interleaved with decode."""
-        from datatunerx_tpu.utils.decoding import prepare_prompt
-
         ids, mask, positions, plen, n_prompt, max_new, _ = prepare_prompt(
             req.prompt_ids, self.tokenizer.eos_token_id,
             self.max_seq_len, req.max_new_tokens,
@@ -1783,21 +1762,11 @@ class BatchedEngine:
                 ids, mask, positions, plen, n_prompt, req.adapter, akey,
                 budget_needed=max_new)
             max_new = max(1, min(max_new, self.max_seq_len - cursor))
-            (self._cache, self._logits, self._pos, self._remaining,
-             self._active, self._temps, self._top_ps, self._stops,
-             self._adapter_idx, self._rng) = self._insert(
-                self._cache, self._logits, self._pos, self._remaining,
-                self._active, self._temps, self._top_ps, self._stops,
-                self._adapter_idx, self._rng,
-                jnp.asarray(slot, jnp.int32), row_cache, row_logits,
-                # the slot's write cursor continues from the row's real KV
-                # depth (prefix reuse can sit deeper than this request's plen)
-                jnp.asarray(cursor, jnp.int32),
-                *self._arm_args(req, n_prompt, max_new),
-            )
-            self._slot_req[slot] = req
-            self._decode_ready[slot] = True
-            self._note_admitted(slot)
+            # the slot's write cursor continues from the row's real KV
+            # depth (prefix reuse can sit deeper than this request's plen)
+            self._insert_row(slot, None, row_cache, row_logits, cursor,
+                             self._arm_args(req, n_prompt, max_new))
+            self._seat(slot, req)
             self._admitted(req, slot, plen, "dense")
             return True
 
@@ -1816,85 +1785,46 @@ class BatchedEngine:
             if hit is not None:
                 row_logits, row_cache, cursor = hit
                 max_new = max(1, min(max_new, self.max_seq_len - cursor))
-                blocks = self._alloc_blocks(
-                    self._reserve_depth(cursor, max_new))
+                blocks = self._pool.reserve(cursor, max_new)
                 if blocks is None:
                     return False
-                try:
+                with self._pool.occupy(slot, blocks,
+                                       cursor + max_new) as table:
                     # scrub first: stored rows are TRIMMED to their live
                     # cursor now, so the insert no longer doubles as the
                     # whole-table recycled-position scrub
-                    self._cache["pos"] = self._cache["pos"].at[
-                        jnp.asarray(blocks, jnp.int32)].set(POS_SENTINEL)
-                    (self._cache, self._logits, self._pos, self._remaining,
-                     self._active, self._temps, self._top_ps, self._stops,
-                     self._adapter_idx, self._rng) = self._insert_paged(
-                        self._cache, self._logits, self._pos,
-                        self._remaining, self._active, self._temps,
-                        self._top_ps, self._stops,
-                        self._adapter_idx, self._rng,
-                        jnp.asarray(slot, jnp.int32),
-                        self._table_row(blocks),
-                        row_cache, row_logits,
-                        jnp.asarray(cursor, jnp.int32),
-                        *self._arm_args(req, n_prompt, max_new),
-                    )
-                except Exception:
-                    self._allocator.free(blocks)
-                    raise
-                self._slot_blocks[slot] = blocks
-                self._slot_req[slot] = req
-                self._decode_ready[slot] = True
-                self._slot_demand[slot] = self._eager_demand(cursor, max_new)
-                self._note_admitted(slot)
+                    self._pool.scrub(blocks)
+                    self._insert_row(slot, table, row_cache, row_logits,
+                                     cursor,
+                                     self._arm_args(req, n_prompt, max_new))
+                self._seat(slot, req)
                 self._admitted(req, slot, plen, "cache")
                 return True
 
-        blocks = self._alloc_blocks(self._reserve_depth(plen, max_new))
+        blocks = self._pool.reserve(plen, max_new)
         if blocks is None:
             return False
-        try:
+        with self._pool.occupy(slot, blocks, plen + max_new) as table:
             # install the table, scrub the blocks' recycled positions to the
             # sentinel (chunked prefill reveals the whole table to attention
             # before every lane is written), and rewind the slot cursor
             self._cache = self._install_table(
-                self._cache, jnp.asarray(slot, jnp.int32),
-                self._table_row(blocks))
-        except Exception:
-            self._allocator.free(blocks)
-            raise
-        self._slot_blocks[slot] = blocks
-        self._slot_req[slot] = req
-        self._decode_ready[slot] = False
-        self._slot_demand[slot] = self._eager_demand(plen, max_new)
-        self._pending[slot] = {
-            "req": req, "ids": ids, "mask": mask, "positions": positions,
-            "plen": plen, "n_prompt": n_prompt, "max_new": max_new,
-            "adapter": req.adapter, "done": 0, "base": 0,
-            "key": self._prefix_key(ids, plen, n_prompt, akey),
-        }
-        self._note_admitted(slot)
+                self._cache, jnp.asarray(slot, jnp.int32), table)
+        self._seat(slot, req, {
+            "ids": ids, "mask": mask, "positions": positions, "plen": plen,
+            "n_prompt": n_prompt, "max_new": max_new, "base": 0,
+            "key": self._prefix_key(ids, plen, n_prompt, akey)})
         self._admitted(req, slot, plen, "chunked")
         return True
 
-    def _reserve_depth(self, cursor: int, max_new: int) -> int:
-        """Token depth admission reserves blocks for: the full decode
-        extent eagerly, or just the context plus one scheduler tick's
-        advance when overcommitted (the grower keeps the table ahead of
-        the cursor from there; the spec overshoot rides on top inside
-        ``_alloc_blocks``)."""
-        if self.overcommit:
-            return cursor + min(max_new, self._tick_advance)
-        return cursor + max_new
-
-    def _eager_demand(self, cursor: int, max_new: int) -> int:
-        """Blocks the overcommit-OFF engine would reserve for this session
-        — the dtx_serving_kv_overcommit_ratio numerator."""
-        return blocks_for_depth(cursor + max_new, self.block_size,
-                                overshoot=self._spec_overshoot,
-                                cap_depth=self.max_seq_len)
-
-    def _note_admitted(self, slot: int):
+    def _seat(self, slot: int, req: Request, pending: Optional[dict] = None):
+        """``req`` holds ``slot`` from here on: ready to decode, or with the
+        prompt tail ``pending`` describes left to chunk-prefill first."""
+        self._slot_req[slot] = req
+        self._decode_ready[slot] = pending is None
+        if pending is not None:
+            self._pending[slot] = {"req": req, "adapter": req.adapter,
+                                   "done": 0, **pending}
         live = sum(1 for r in self._slot_req if r is not None)
         if live > self.kv_stats["peak_sessions"]:
             self.kv_stats["peak_sessions"] = live
@@ -1953,27 +1883,22 @@ class BatchedEngine:
         blocks; nothing held."""
         base = ent["cursor"]  # host int: _cow_store stores python scalars
         full, rem = ent["full"], ent["rem"]
-        shared = list(ent["blocks"][:full])
         suffix_len = len(suffix["ids"]) if suffix else 0
         final = base + suffix_len
-        target = blocks_for_depth(
-            self._reserve_depth(final, max_new), self.block_size,
-            overshoot=self._spec_overshoot, cap_depth=self.max_seq_len)
-        own = self._allocator.alloc(target - full)  # >= 1: max_new >= 1
-        if own is None:
+        # >= 1 block of its own: max_new >= 1
+        blocks = self._pool.reserve(final, max_new,
+                                    shared=ent["blocks"][:full])
+        if blocks is None:
             return False
-        self._allocator.incref(shared)
-        blocks = shared + own
-        try:
-            self._cache["pos"] = self._cache["pos"].at[
-                jnp.asarray(own, jnp.int32)].set(POS_SENTINEL)
+        own = blocks[full:]
+        with self._pool.occupy(slot, blocks, final + max_new) as table:
+            self._pool.scrub(own)
             if rem:
                 self._cache = self._copy_block(
                     self._cache, jnp.asarray(ent["blocks"][full], jnp.int32),
                     jnp.asarray(own[0], jnp.int32),
                     jnp.asarray(rem, jnp.int32))
-            self._cache["block_tables"] = self._cache["block_tables"].at[
-                slot].set(self._table_row(blocks))
+            self._pool.set_row(slot, table)
             self._cache["len"] = self._cache["len"].at[slot].set(base)
             if suffix is None:
                 (self._logits, self._pos, self._remaining, self._active,
@@ -1985,24 +1910,11 @@ class BatchedEngine:
                     jnp.asarray(slot, jnp.int32), ent["logits"],
                     *self._arm_args(req, n_prompt, max_new),
                 )
-        except Exception:
-            self._allocator.free(blocks)
-            raise
-        self._slot_blocks[slot] = blocks
-        self._slot_req[slot] = req
-        self._slot_demand[slot] = self._eager_demand(final, max_new)
-        if suffix is None:
-            self._decode_ready[slot] = True
-        else:
-            self._decode_ready[slot] = False
-            self._pending[slot] = {
-                "req": req, "ids": suffix["ids"], "mask": suffix["mask"],
-                "positions": suffix["positions"],
-                "plen": len(suffix["ids"]), "n_prompt": n_prompt,
-                "max_new": max_new, "adapter": req.adapter, "done": 0,
-                "key": key, "base": base,
-            }
-        self._note_admitted(slot)
+        self._seat(slot, req, None if suffix is None else {
+            "ids": suffix["ids"], "mask": suffix["mask"],
+            "positions": suffix["positions"], "plen": suffix_len,
+            "n_prompt": n_prompt, "max_new": max_new, "key": key,
+            "base": base})
         return True
 
     def _cow_store(self, slot: int, key, cursor: int, row_logits):
@@ -2013,36 +1925,18 @@ class BatchedEngine:
         the donor's continued decode cannot leak into the entry. A pool
         too tight for the tail copy skips caching: serving beats caching."""
         full, rem = divmod(cursor, self.block_size)
-        blocks = self._slot_blocks[slot]
-        shared = list(blocks[:full])
-        ent_blocks = list(shared)
+        blocks = self._pool.held(slot)
+        ent_blocks = self._pool.take(1 if rem else 0, shared=blocks[:full])
+        if ent_blocks is None:
+            return
         if rem:
-            tail = self._allocator.alloc(1)
-            if tail is None:
-                return
             self._cache = self._copy_block(
                 self._cache, jnp.asarray(blocks[full], jnp.int32),
-                jnp.asarray(tail[0], jnp.int32), jnp.asarray(rem, jnp.int32))
-            ent_blocks = shared + tail
-        self._allocator.incref(shared)
+                jnp.asarray(ent_blocks[-1], jnp.int32),
+                jnp.asarray(rem, jnp.int32))
         self._prefix.put(key, {"blocks": ent_blocks, "full": full,
                                "rem": rem, "cursor": cursor,
                                "logits": row_logits})
-
-    def _alloc_blocks(self, depth: int) -> Optional[List[int]]:
-        from datatunerx_tpu.ops.paged_attention import blocks_for_depth
-
-        # spec engines reserve the verify-k write overshoot (spec_k + 1
-        # tokens) on top of the request's own depth, capped at the block
-        # table's width — see blocks_for_depth for the rationale
-        return self._allocator.alloc(blocks_for_depth(
-            depth, self.block_size, overshoot=self._spec_overshoot,
-            cap_depth=self.max_seq_len))
-
-    def _table_row(self, blocks: List[int]) -> jnp.ndarray:
-        row = np.full((self.blocks_per_slot,), -1, np.int32)
-        row[: len(blocks)] = blocks
-        return jnp.asarray(row)
 
     def _phase(self, name: str, **detail) -> _Phase:
         """``with self._phase("dtx_engine_<what>", ...)``: every span of the
@@ -2500,6 +2394,11 @@ class BatchedEngine:
             cmd["_done"].set()
 
     def _do_export(self, cmd: dict) -> dict:
+        from datatunerx_tpu.serving.migration import (
+            MIGRATED_SESSION,
+            encode_payload,
+        )
+
         want = cmd.get("slots")
         sessions: List[dict] = []
         skipped: List[dict] = []
@@ -2511,78 +2410,53 @@ class BatchedEngine:
                 if want is not None:
                     skipped.append({"slot": slot, "reason": "empty"})
                 continue
+            st = None  # the chunked-prefill state of a slot exported mid-prompt
             if not self._decode_ready[slot]:
                 st = self._pending.get(slot)
-                if cmd.get("prefill") and st is not None and self.paged:
+                if not (cmd.get("prefill") and st is not None and self.paged):
+                    skipped.append({"slot": slot,
+                                    "reason": "prefill_in_progress"})
+                    self._count_mig("export", "skipped_prefill")
+                    continue
+            try:
+                if st is not None:
                     # disaggregated handoff: ship the blocks written so
                     # far plus the remaining prompt tail — the importer
                     # resumes chunked prefill exactly where we stopped
-                    try:
-                        payload = self._export_prefill_slot(
-                            slot, st, cmd.get("wire"))
-                    except Exception as e:  # noqa: BLE001 — skip slot, keep rest
-                        skipped.append({"slot": slot, "reason": str(e)})
-                        self._count_mig("export", "error")
-                        continue
-                    sessions.append(payload)
-                    self._count_mig("export", "ok_prefill")
-                    self._event("export_prefill", slot, req=req,
-                                mark="export", slot=slot, prefill=True,
-                                done=st["done"])
-                    self._release_slot(slot)
-                    self._active = self._active.at[slot].set(False)
-                    self._remaining = self._remaining.at[slot].set(0)
-                    from datatunerx_tpu.serving.migration import (
-                        MIGRATED_SESSION,
-                    )
-
-                    self._complete(
-                        req,
-                        error=f"{MIGRATED_SESSION}: prefill slot exported")
-                    continue
-                skipped.append({"slot": slot,
-                                "reason": "prefill_in_progress"})
-                self._count_mig("export", "skipped_prefill")
-                continue
-            try:
-                # a spec-active slot first settles: its pending token's KV
-                # is written and next-token logits materialize, so the
-                # payload is the standard logits-form wire format any
-                # replica (spec or not) can import; the importer re-primes
-                # its own draft cache rather than shipping draft KV
-                if self.spec is not None and self._spec_form[slot]:
-                    self._spec_settle_slot(slot)
-                payload = self._export_slot(slot, req, cmd.get("wire"))
+                    payload = self._export_prefill_slot(
+                        slot, st, cmd.get("wire"))
+                else:
+                    # a spec-active slot first settles: its pending token's
+                    # KV is written and next-token logits materialize, so
+                    # the payload is the standard logits-form wire format
+                    # any replica (spec or not) can import; the importer
+                    # re-primes its own draft cache rather than shipping
+                    # draft KV
+                    if self.spec is not None and self._spec_form[slot]:
+                        self._spec_settle_slot(slot)
+                    payload = self._export_slot(slot, req, cmd.get("wire"))
             except Exception as e:  # noqa: BLE001 — skip the slot, keep the rest
                 skipped.append({"slot": slot, "reason": str(e)})
                 self._count_mig("export", "error")
                 continue
             sessions.append(payload)
-            self._count_mig("export", "ok")
-            self._event("export", slot, req=req, slot=slot,
-                        cursor=payload["cursor"])
-            self._release_slot(slot)
-            # the slot is still ACTIVE on device — every other release
-            # happens after the decode kernel deactivated it. Clear the
-            # mask (and the token budget) NOW: an interleaved decode chunk
-            # would otherwise keep sampling this slot and write a stale
-            # token through the NEXT tenant's freshly-installed block
-            # table while that tenant is still chunk-prefilling.
-            self._active = self._active.at[slot].set(False)
-            self._remaining = self._remaining.at[slot].set(0)
-            from datatunerx_tpu.serving.migration import MIGRATED_SESSION
-
-            self._complete(req, error=f"{MIGRATED_SESSION}: slot exported")
+            if st is not None:
+                self._count_mig("export", "ok_prefill")
+                self._event("export_prefill", slot, req=req, mark="export",
+                            slot=slot, prefill=True, done=st["done"])
+            else:
+                self._count_mig("export", "ok")
+                self._event("export", slot, req=req, slot=slot,
+                            cursor=payload["cursor"])
+            self._vacate_slot(slot)
+            self._complete(req, error=f"{MIGRATED_SESSION}: "
+                           + ("prefill " if st is not None else "")
+                           + "slot exported")
         if want is None and self._preempted:
             # preemption-parked sessions are in flight too — a drain that
             # missed them would strand their clients. Their payloads
             # already exist (raw numpy bodies): re-encode for the wire,
             # terminate with the migrated marker so the gateway splices.
-            from datatunerx_tpu.serving.migration import (
-                MIGRATED_SESSION,
-                encode_payload,
-            )
-
             # entries leased to the spill coordinator stay parked: the
             # coordinator (or lease expiry) is their single owner — a
             # drain exporting them too would fork the session onto two
@@ -2601,6 +2475,16 @@ class BatchedEngine:
                 self._complete(
                     req, error=f"{MIGRATED_SESSION}: parked session exported")
         return {"sessions": sessions, "skipped": skipped}
+
+    @staticmethod
+    def _request_doc(req: Request) -> dict:
+        return {"trace_id": req.trace_id,
+                "adapter": req.adapter_name,
+                "prompt_ids": list(req.prompt_ids),
+                "tokens": list(req.tokens),
+                "max_new_tokens": req.max_new_tokens,
+                "temperature": req.temperature, "top_p": req.top_p,
+                "seed": req.seed, "stop_ids": list(req.stop_ids)}
 
     def _export_slot(self, slot: int, req: Request,
                      wire: Optional[str], b64: bool = True) -> dict:
@@ -2626,13 +2510,7 @@ class BatchedEngine:
                 row[key] = self._cache[key][:, slot:slot + 1]
         payload = mig.build_payload(
             self.cfg, self.kv_quant,
-            request={"trace_id": req.trace_id,
-                     "adapter": req.adapter_name,
-                     "prompt_ids": list(req.prompt_ids),
-                     "tokens": list(req.tokens),
-                     "max_new_tokens": req.max_new_tokens,
-                     "temperature": req.temperature, "top_p": req.top_p,
-                     "seed": req.seed, "stop_ids": list(req.stop_ids)},
+            request=self._request_doc(req),
             row=row, cursor=cursor, pos=pos, remaining=remaining,
             rng=rng, logits=logits, wire=wire, b64=b64)
         # learned spec-controller state rides the payload as plain JSON
@@ -2662,13 +2540,7 @@ class BatchedEngine:
                             jnp.asarray(cursor, jnp.int32), width=w)
         payload = mig.build_payload(
             self.cfg, self.kv_quant,
-            request={"trace_id": req.trace_id,
-                     "adapter": req.adapter_name,
-                     "prompt_ids": list(req.prompt_ids),
-                     "tokens": list(req.tokens),
-                     "max_new_tokens": req.max_new_tokens,
-                     "temperature": req.temperature, "top_p": req.top_p,
-                     "seed": req.seed, "stop_ids": list(req.stop_ids)},
+            request=self._request_doc(req),
             row=row, cursor=cursor, pos=st["n_prompt"],
             remaining=st["max_new"], rng=np.zeros(2, np.uint32),
             logits=np.zeros((self.cfg.vocab_size,), np.float32),
@@ -2732,95 +2604,37 @@ class BatchedEngine:
             else:
                 raise ValueError(f"unknown adapter {name!r} on this replica")
         pending = payload.get("pending")
-        if pending is not None:
-            try:
+        try:
+            if pending is not None:
                 return self._import_prefill_tail(payload, pending, slot,
                                                  name, idx, pinned, cursor)
-            except Exception:
-                if pinned:
-                    self.adapter_registry.release(name)
-                raise
-        blocks: Optional[List[int]] = None
-        try:
+            occupy = contextlib.nullcontext()
             if self.paged:
                 # overcommit engines import lazily too: the grower extends
                 # the table as the resumed decode advances
-                depth = self._reserve_depth(cursor, remaining)
-                blocks = self._alloc_blocks(depth)
+                blocks = self._pool.reserve(cursor, remaining)
                 if blocks is None:
+                    depth = self._pool.reserve_depth(cursor, remaining)
                     raise _RetryLater(
                         "kv blocks exhausted "
                         f"(need {-(-depth // self.block_size)}"
-                        f", free {self._allocator.free_count})")
-            row = mig.unpack_kv_row(payload["kv"], full_width=W,
-                                    quantize=self.kv_quant)
-            row_logits = mig.unpack_logits(payload, self.cfg.vocab_size)
-            req = Request(
-                payload["prompt_ids"], payload["max_new_tokens"],
-                payload["temperature"], payload["top_p"],
-                payload["seed"], payload["stop_ids"],
-                idx, adapter_name=name,
-                trace_id=(payload["trace_id"]
-                          or f"dtx-{uuid.uuid4().hex[:16]}"))
-            req.tokens = payload["tokens"]
-            req.resume_base = len(req.tokens)
-            if self.spec is not None:
-                # re-prime contract: the wire carries no draft-cache state;
-                # the slot joins speculative decoding after its draft row
-                # is re-prefilled from the payload's prompt + tail (the
-                # scheduler does this before the slot's first spec step —
-                # priming affects acceptance only, never output exactness)
-                from datatunerx_tpu.utils.decoding import prepare_prompt
-
-                p_ids, _, _, p_plen, p_n, _, _ = prepare_prompt(
-                    payload["prompt_ids"], self.tokenizer.eos_token_id,
-                    self.max_seq_len, payload["max_new_tokens"])
-                req.spec_prime_ids = p_ids[p_plen - p_n:]
-                # warm the controller from the source's learned state
-                # (acceptance EMAs, learned per-depth widths): re-prime
-                # rebuilds the draft KV but must not reset what the source
-                # already learned about this session's acceptance
-                self.spec_ctrl.import_slot_state(slot, payload.get("spec"))
-            if self.paged:
-                (self._cache, self._logits, self._pos, self._remaining,
-                 self._active, self._temps, self._top_ps, self._stops,
-                 self._adapter_idx, self._rng) = self._insert_paged(
-                    self._cache, self._logits, self._pos, self._remaining,
-                    self._active, self._temps, self._top_ps, self._stops,
-                    self._adapter_idx, self._rng,
-                    jnp.asarray(slot, jnp.int32), self._table_row(blocks),
-                    row, row_logits, jnp.asarray(cursor, jnp.int32),
-                    *self._arm_args(req, pos_val, remaining),
-                )
-            else:
-                (self._cache, self._logits, self._pos, self._remaining,
-                 self._active, self._temps, self._top_ps, self._stops,
-                 self._adapter_idx, self._rng) = self._insert(
-                    self._cache, self._logits, self._pos, self._remaining,
-                    self._active, self._temps, self._top_ps, self._stops,
-                    self._adapter_idx, self._rng,
-                    jnp.asarray(slot, jnp.int32), row, row_logits,
-                    jnp.asarray(cursor, jnp.int32),
-                    *self._arm_args(req, pos_val, remaining),
-                )
-            # token-exact resume: replace the seed-derived key the insert
-            # armed with the SOURCE slot's live rng stream
-            self._rng = self._rng.at[slot].set(
-                jnp.asarray(payload["rng"], jnp.uint32))
+                        f", free {self._pool.free})")
+                occupy = self._pool.occupy(slot, blocks, cursor + remaining)
+            with occupy as table:
+                row = mig.unpack_kv_row(payload["kv"], full_width=W,
+                                        quantize=self.kv_quant)
+                row_logits = mig.unpack_logits(payload, self.cfg.vocab_size)
+                req = self._imported_request(payload, slot, idx, name)
+                self._insert_row(slot, table, row, row_logits, cursor,
+                                 self._arm_args(req, pos_val, remaining),
+                                 rng=payload["rng"])
         except Exception:
-            if blocks:
-                self._allocator.free(blocks)
             if pinned:
                 self.adapter_registry.release(name)
             raise
         if pinned:
             self._slot_adapter[slot] = name
-        self._slot_blocks[slot] = blocks or []
-        self._slot_req[slot] = req
-        self._decode_ready[slot] = True
-        if self.paged:
-            self._slot_demand[slot] = self._eager_demand(cursor, remaining)
-        self._note_admitted(slot)
+        self._seat(slot, req)
         self._count_mig("import", "ok")
         self._event("import", slot, cursor, req=req, slot=slot,
                     cursor=cursor, adapter=name, tail_tokens=req.resume_base)
@@ -2831,6 +2645,34 @@ class BatchedEngine:
                 "remaining": remaining, "adapter": name,
                 "text_so_far": text, "_request": req}
 
+    def _imported_request(self, payload: dict, slot: int, idx: int,
+                          name: str) -> Request:
+        """The request a migrated session goes on as in ``slot``."""
+        req = Request(
+            payload["prompt_ids"], payload["max_new_tokens"],
+            payload["temperature"], payload["top_p"],
+            payload["seed"], payload["stop_ids"],
+            idx, adapter_name=name,
+            trace_id=payload["trace_id"] or f"dtx-{uuid.uuid4().hex[:16]}")
+        req.tokens = payload["tokens"]
+        req.resume_base = len(req.tokens)
+        if self.spec is not None:
+            # re-prime contract: the wire carries no draft-cache state; the
+            # slot joins speculative decoding after its draft row is
+            # re-prefilled from the payload's prompt + tail (the scheduler
+            # does this before the slot's first spec step — priming affects
+            # acceptance only, never output exactness)
+            p_ids, _, _, p_plen, p_n, _, _ = prepare_prompt(
+                payload["prompt_ids"], self.tokenizer.eos_token_id,
+                self.max_seq_len, payload["max_new_tokens"])
+            req.spec_prime_ids = p_ids[p_plen - p_n:]
+            # warm the controller from the source's learned state
+            # (acceptance EMAs, learned per-depth widths): re-prime rebuilds
+            # the draft KV but must not reset what the source already
+            # learned about this session's acceptance
+            self.spec_ctrl.import_slot_state(slot, payload.get("spec"))
+        return req
+
     def _import_prefill_tail(self, payload: dict, pending: dict, slot: int,
                              name: str, idx: int, pinned: bool,
                              cursor: int) -> dict:
@@ -2840,7 +2682,6 @@ class BatchedEngine:
         tail is not a cold prefill and never publishes a prefix entry).
         ``_finish_prefill`` then arms decode from ``req.seed`` exactly as
         the source replica would have, so the handoff is token-exact."""
-        from datatunerx_tpu.ops.paged_attention import paged_insert_row
         from datatunerx_tpu.serving import migration as mig
 
         if not self.paged:
@@ -2854,52 +2695,25 @@ class BatchedEngine:
             raise ValueError(
                 f"prefill depth {final} exceeds this replica's context {W}")
         max_new = max(1, int(pending["max_new"]))  # dtxlint: disable=DTX001 — wire payloads carry host scalars
-        blocks = self._alloc_blocks(self._reserve_depth(final, max_new))
+        blocks = self._pool.reserve(final, max_new)
         if blocks is None:
             raise _RetryLater(
                 "kv blocks exhausted for mid-prefill import "
-                f"(free {self._allocator.free_count})")
-        try:
+                f"(free {self._pool.free})")
+        with self._pool.occupy(slot, blocks, final + max_new) as table:
             row = mig.unpack_kv_row(payload["kv"], full_width=W,
                                     quantize=self.kv_quant)
-            req = Request(
-                payload["prompt_ids"], payload["max_new_tokens"],
-                payload["temperature"], payload["top_p"],
-                payload["seed"], payload["stop_ids"],
-                idx, adapter_name=name,
-                trace_id=(payload["trace_id"]
-                          or f"dtx-{uuid.uuid4().hex[:16]}"))
-            req.tokens = payload["tokens"]
-            req.resume_base = len(req.tokens)
-            if self.spec is not None:
-                from datatunerx_tpu.utils.decoding import prepare_prompt
-
-                p_ids, _, _, p_plen, p_n, _, _ = prepare_prompt(
-                    payload["prompt_ids"], self.tokenizer.eos_token_id,
-                    self.max_seq_len, payload["max_new_tokens"])
-                req.spec_prime_ids = p_ids[p_plen - p_n:]
-                self.spec_ctrl.import_slot_state(slot, payload.get("spec"))
-            # the row's unwritten tail is POS_SENTINEL-padded to full
+            req = self._imported_request(payload, slot, idx, name)
+            # the row's unwritten tail is sentinel-padded to full
             # width, so the scatter doubles as the recycled-block scrub
-            self._cache = paged_insert_row(
-                self._cache, slot, self._table_row(blocks), row)
+            self._cache = paged_insert_row(self._cache, slot, table, row)
             self._cache["len"] = self._cache["len"].at[slot].set(cursor)
-        except Exception:
-            self._allocator.free(blocks)
-            raise
         if pinned:
             self._slot_adapter[slot] = name
-        self._slot_blocks[slot] = blocks
-        self._slot_req[slot] = req
-        self._decode_ready[slot] = False
-        self._slot_demand[slot] = self._eager_demand(final, max_new)
-        self._pending[slot] = {
-            "req": req, "ids": ids, "mask": mask, "positions": positions,
+        self._seat(slot, req, {
+            "ids": ids, "mask": mask, "positions": positions,
             "plen": len(ids), "n_prompt": int(pending["n_prompt"]),  # dtxlint: disable=DTX001 — wire payloads carry host scalars
-            "max_new": max_new, "adapter": req.adapter, "done": 0,
-            "base": cursor, "key": None,
-        }
-        self._note_admitted(slot)
+            "max_new": max_new, "base": cursor, "key": None})
         self._count_mig("import", "ok_prefill")
         self._event("import_prefill", slot, cursor, req=req, mark="import",
                     slot=slot, cursor=cursor, adapter=name, prefill=True,
@@ -2983,15 +2797,10 @@ class BatchedEngine:
             raise _RetryLater("no free slot to stage a prefix export")
         w = min(-(-max(1, cursor) // DECODE_BUCKET) * DECODE_BUCKET,
                 self.max_seq_len)
-        saved = self._cache["block_tables"][slot]
-        try:
-            self._cache["block_tables"] = self._cache["block_tables"].at[
-                slot].set(self._table_row(ent["blocks"]))
+        with self._pool.mounted(slot, ent["blocks"]) as table:
+            self._pool.set_row(slot, table)
             return self._extract(self._cache, jnp.asarray(slot, jnp.int32),
                                  jnp.asarray(cursor, jnp.int32), width=w)
-        finally:
-            self._cache["block_tables"] = \
-                self._cache["block_tables"].at[slot].set(saved)
 
     def _do_export_prefix(self, cmd: dict) -> dict:
         from datatunerx_tpu.serving import migration as mig
@@ -3040,10 +2849,6 @@ class BatchedEngine:
         return {"entries": entries}
 
     def _do_import_prefix(self, cmd: dict) -> dict:
-        from datatunerx_tpu.ops.paged_attention import (
-            paged_insert_row,
-            row_trim,
-        )
         from datatunerx_tpu.serving import migration as mig
 
         if self._prefix is None:
@@ -3078,26 +2883,24 @@ class BatchedEngine:
         if self.cow:
             full, rem = divmod(cursor, self.block_size)
             n_blocks = full + (1 if rem else 0)
-            blocks = self._allocator.alloc(n_blocks)
+            blocks = self._pool.take(n_blocks)
             if blocks is None:
                 raise _RetryLater(
                     f"kv blocks exhausted for prefix import "
-                    f"(need {n_blocks}, free {self._allocator.free_count})")
+                    f"(need {n_blocks}, free {self._pool.free})")
             slot = next((i for i in range(self.slots)
                          if self._slot_req[i] is None), None)
             if slot is None:
-                self._allocator.free(blocks)
+                self._pool.free_entry(blocks)
                 raise _RetryLater("no free slot to stage a prefix import")
             try:
-                # the scatter installs the table on the free slot; restore
-                # it right after — the ENTRY owns these blocks, not a slot
-                saved = self._cache["block_tables"][slot]
-                self._cache = paged_insert_row(
-                    self._cache, slot, self._table_row(blocks), row)
-                self._cache["block_tables"] = \
-                    self._cache["block_tables"].at[slot].set(saved)
+                # the scatter installs the table on the free slot, restored
+                # right after — the ENTRY owns these blocks, not a slot
+                with self._pool.mounted(slot, blocks) as table:
+                    self._cache = paged_insert_row(self._cache, slot, table,
+                                                   row)
             except Exception:
-                self._allocator.free(blocks)
+                self._pool.free_entry(blocks)
                 raise
             ent = {"blocks": blocks, "full": full, "rem": rem,
                    "cursor": cursor, "logits": logits}
@@ -3115,12 +2918,12 @@ class BatchedEngine:
                 "fingerprint": payload.get("fingerprint")}
 
     def _release_slot(self, slot: int, note_session: bool = True):
+        paged = self._pool is not None
         with self._phase("dtx_engine_release",
-                         blocks=len(self._slot_blocks[slot])):
+                         blocks=len(self._pool.held(slot)) if paged else 0):
             self._slot_req[slot] = None
             self._pending.pop(slot, None)
             self._decode_ready[slot] = False
-            self._slot_demand[slot] = 0
             if self.spec is not None:
                 self._spec_form[slot] = False
                 self._spec_primed[slot] = False
@@ -3132,20 +2935,18 @@ class BatchedEngine:
             name, self._slot_adapter[slot] = self._slot_adapter[slot], None
             if name is not None and self.adapter_registry is not None:
                 self.adapter_registry.release(name)
-            blocks, self._slot_blocks[slot] = self._slot_blocks[slot], []
-            if blocks:
-                if note_session:
-                    # tables only grow, so the count at release IS the
-                    # session's peak physical footprint (bench p50/p95
-                    # source); preemptions pass False — the session isn't
-                    # over
-                    self.kv_stats["session_blocks"].append(len(blocks))
-                # clear the table FIRST: a masked decode write from this
-                # slot must never land in a block the allocator has already
-                # re-issued
-                self._cache["block_tables"] = \
-                    self._cache["block_tables"].at[slot].set(-1)
-                self._allocator.free(blocks)
+            if paged:
+                self._pool.release(slot, note_session)
+
+    def _vacate_slot(self, slot: int, note_session: bool = True):
+        """Release a slot that is still ACTIVE on device (an export, a
+        preemption: every other release follows the decode program's own
+        deactivation) and clear its mask and token budget NOW: an interleaved
+        decode chunk would otherwise keep sampling it and write a stale token
+        through the NEXT tenant's table while that tenant still prefills."""
+        self._release_slot(slot, note_session)
+        self._active = self._active.at[slot].set(False)
+        self._remaining = self._remaining.at[slot].set(0)
 
     # --------------------------------------------- overcommit: grow/preempt
     def _grow_tick(self):
@@ -3172,20 +2973,16 @@ class BatchedEngine:
             if req is None:
                 continue  # preempted by an older slot's reclaim this pass
             advance = min(self._tick_advance, max(1, int(rem[slot])))  # dtxlint: disable=DTX001 — host numpy from this tick's sync point
-            depth = min(int(lens[slot]) + advance + self._spec_overshoot,  # dtxlint: disable=DTX001 — host numpy from this tick's sync point
-                        self.max_seq_len)
-            need = (blocks_for_depth(depth, self.block_size)
-                    - len(self._slot_blocks[slot]))
-            while need > 0:
-                got = self._allocator.alloc(need)
-                if got is not None:
-                    self._install_growth(slot, got)
-                    break
+            depth = int(lens[slot]) + advance  # dtxlint: disable=DTX001 — host numpy from this tick's sync point
+            # None: the pool cannot cover the blocks the slot lacks
+            while (grown := self._pool.grow(slot, depth)) is None:
                 if self._reclaim_for(req):
                     continue
                 if not self._is_oldest_live(req):
                     self._preempt_slot(slot)
                 break
+            if grown:
+                self._event("grow", slot, grown)
 
     def _is_oldest_live(self, req: Request) -> bool:
         seqs = [r.seq for r in self._slot_req if r is not None]
@@ -3202,7 +2999,7 @@ class BatchedEngine:
         if self._prefix is not None:
             ent = self._prefix.pop_lru_block_entry()
             if ent is not None:
-                self._allocator.free(ent["blocks"])
+                self._pool.free_entry(ent["blocks"])
                 return True
         victims = [s for s in range(self.slots)
                    if self._decode_ready[s]
@@ -3249,17 +3046,6 @@ class BatchedEngine:
             TIER_RANK.get(getattr(req_of[s], "tenant_tier", "standard"), 1),
             -req_of[s].seq))
 
-    def _install_growth(self, slot: int, new_blocks: List[int]):
-        blocks = self._slot_blocks[slot]
-        blocks.extend(new_blocks)
-        arr = jnp.asarray(new_blocks, jnp.int32)
-        # scrub the recycled blocks' positions BEFORE the table reveals
-        # them to attention (same contract as cold admission)
-        self._cache["pos"] = self._cache["pos"].at[arr].set(POS_SENTINEL)
-        self._cache["block_tables"] = self._cache["block_tables"].at[
-            slot].set(self._table_row(blocks))
-        self._event("grow", slot, len(new_blocks))
-
     def _preempt_slot(self, slot: int):
         """Park a decode session host-side: settle (spec), export its
         dtx-kv-session payload (raw numpy bodies — no base64 for
@@ -3272,13 +3058,7 @@ class BatchedEngine:
         if self.spec is not None and self._spec_form[slot]:
             self._spec_settle_slot(slot)
         payload = self._export_slot(slot, req, None, b64=False)
-        self._release_slot(slot, note_session=False)
-        # the slot is still ACTIVE on device (only the decode kernel
-        # deactivates slots itself) — clear the mask and budget NOW, or an
-        # interleaved chunk would keep sampling it and write a stale token
-        # through the next tenant's freshly-installed table
-        self._active = self._active.at[slot].set(False)
-        self._remaining = self._remaining.at[slot].set(0)
+        self._vacate_slot(slot, note_session=False)
         self._preempted.append({"payload": payload, "req": req})
         self._preempted.sort(key=lambda e: e["req"].seq)
         self._count_preempt("exported")
@@ -3345,53 +3125,35 @@ class BatchedEngine:
                 idx = self._static_adapter_ids.get(name, req.adapter)
         cursor = int(payload["cursor"])  # dtxlint: disable=DTX001 — parked payloads carry host scalars
         remaining = int(payload["remaining"])  # dtxlint: disable=DTX001 — parked payloads carry host scalars
-        blocks = None
         try:
-            blocks = self._alloc_blocks(
-                self._reserve_depth(cursor, remaining))
+            blocks = self._pool.reserve(cursor, remaining)
             if blocks is None and self._prefix is not None:
                 # prefix-cache entries are the cheapest reclaim here too
                 ent = self._prefix.pop_lru_block_entry()
                 if ent is not None:
-                    self._allocator.free(ent["blocks"])
-                    blocks = self._alloc_blocks(
-                        self._reserve_depth(cursor, remaining))
+                    self._pool.free_entry(ent["blocks"])
+                    blocks = self._pool.reserve(cursor, remaining)
             if blocks is None:
                 if pinned:
                     self.adapter_registry.release(name)
                 return False
-            row = mig.unpack_kv_row(payload["kv"],
-                                    full_width=self.max_seq_len,
-                                    quantize=self.kv_quant)
-            row_logits = mig.unpack_logits(payload, self.cfg.vocab_size)
-            (self._cache, self._logits, self._pos, self._remaining,
-             self._active, self._temps, self._top_ps, self._stops,
-             self._adapter_idx, self._rng) = self._insert_paged(
-                self._cache, self._logits, self._pos, self._remaining,
-                self._active, self._temps, self._top_ps, self._stops,
-                self._adapter_idx, self._rng,
-                jnp.asarray(slot, jnp.int32), self._table_row(blocks),
-                row, row_logits, jnp.asarray(cursor, jnp.int32),
-                *self._arm_args(req, int(payload["pos"]), remaining),  # dtxlint: disable=DTX001 — parked payloads carry host scalars
-            )
-            # token-exact resume: restore the slot's LIVE rng stream in
-            # place of the seed-derived key the insert armed
-            self._rng = self._rng.at[slot].set(
-                jnp.asarray(payload["rng"], jnp.uint32))
+            with self._pool.occupy(slot, blocks, cursor + remaining) as table:
+                row = mig.unpack_kv_row(payload["kv"],
+                                        full_width=self.max_seq_len,
+                                        quantize=self.kv_quant)
+                row_logits = mig.unpack_logits(payload, self.cfg.vocab_size)
+                self._insert_row(
+                    slot, table, row, row_logits, cursor,
+                    self._arm_args(req, int(payload["pos"]), remaining),  # dtxlint: disable=DTX001 — parked payloads carry host scalars
+                    rng=payload["rng"])
         except Exception:
-            if blocks:
-                self._allocator.free(blocks)
             if pinned:
                 self.adapter_registry.release(name)
             raise
         req.adapter = idx
         if pinned:
             self._slot_adapter[slot] = name
-        self._slot_blocks[slot] = blocks
-        self._slot_req[slot] = req
-        self._decode_ready[slot] = True
-        self._slot_demand[slot] = self._eager_demand(cursor, remaining)
-        self._note_admitted(slot)
+        self._seat(slot, req)
         self._count_preempt("resumed")
         self._event("resume", slot, cursor, req=req, slot=slot,
                     cursor=cursor)

@@ -7,9 +7,15 @@ this repo compile, and says SAME or DIFF a program.
 <commit> | tar -x -C .scratch/parent``); the other side is the checkout this
 file lies in. A case is a debug preset served by ``BatchedEngine`` on the CPU
 backend with ``paged_kernel`` on or off (a prompt of three chunks, six tokens
-out), each side in a process of its own. A program is the optimized HLO of
-one of the engine's jitted programs, less what an edit moves without changing
-the program: op metadata (scopes, source lines) and the stack-frame tables.
+out), each side in a process of its own; ``debug.kernels+prefix`` and
+``debug.kernels+overcommit`` serve a second session whose requests share
+prefixes and outgrow a small pool, so that the prefix-cache, copy-on-write,
+growth, preemption and resume paths are in it (``events``). A program is the
+optimized HLO of one of the engine's jitted programs, less what an edit moves
+without changing the program: op metadata (scopes, source lines) and the
+stack-frame tables. Every OTHER module the session compiled (the eager
+scatters and slices of the scheduler, the row programs, set-up) is on the
+``(other modules)`` line: their count and one digest over their sorted hashes.
 The Pallas kernels are emulated there, so the hash covers a kernel's body too.
 ``kernel/*`` cases hash the lowered text of the paged decode kernel's call
 alone. Nothing here is timed and nothing needs the chip: a program that
@@ -33,15 +39,22 @@ import tempfile
 from concurrent.futures import ThreadPoolExecutor
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# case -> (preset, paged_kernel)
+# case -> (preset, paged_kernel, further engine keywords)
 ENGINES = {
-    f"{preset}.{'kernels' if mode == 'on' else 'gather'}": (preset, mode)
+    f"{preset}.{'kernels' if mode == 'on' else 'gather'}": (preset, mode, {})
     for preset in ("debug", "debug-hybrid", "debug-ling", "debug-granite", "debug-glm")
     for mode in ("on", "off")
 }
+# 24 blocks under two slots of 16 columns: two sessions outgrow the pool. ONE
+# prefix entry: a cold admission never reclaims the blocks entries hold
+# (ROADMAP D20), so the next put has to
+ENGINES["debug.kernels+prefix"] = ("debug", "on", {"prefix_cache": 4})
+ENGINES["debug.kernels+overcommit"] = (
+    "debug", "on", {"prefix_cache": 1, "kv_overcommit": "on", "kv_blocks": 24})
 # case -> keywords of ``paged_decode_attention`` over bf16 pools of one width
 KERNELS = {"kernel/paged_decode": {}, "kernel/paged_decode_window": {"window": 100}}
 PROGRAMS = "decode_impl|prefill_chunk_impl|activate_impl|install_table"
+OTHER = "(other modules)"
 
 
 def _strip(text: str) -> str:
@@ -57,8 +70,7 @@ def _digest(text: str) -> str:
 
 def child(root: str, case: str) -> None:
     dump = tempfile.mkdtemp(prefix="hlo_")
-    os.environ["XLA_FLAGS"] = (f"--xla_dump_to={dump} --xla_dump_hlo_as_text "
-                               f"--xla_dump_hlo_module_re=.*({PROGRAMS}).*")
+    os.environ["XLA_FLAGS"] = f"--xla_dump_to={dump} --xla_dump_hlo_as_text"
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"
     os.chdir(root)
@@ -80,22 +92,48 @@ def child(root: str, case: str) -> None:
     else:
         from datatunerx_tpu.serving.batched_engine import BatchedEngine
 
-        preset, mode = ENGINES[case]
+        preset, mode, more = ENGINES[case]
         kw = dict(max_seq_len=256, slots=2, decode_chunk=4, kv_block_size=16,
-                  prefill_chunk=64, paged_kernel=mode)
+                  prefill_chunk=64, paged_kernel=mode, **more)
         if preset == "debug":
             kw["template"] = "vanilla"
         eng = BatchedEngine("preset:" + preset, **kw)
         try:
             out["tokens"] = eng.generate(list(range(3, 3 + 150)), max_new_tokens=6)
+            if more:
+                out["tokens"] += _shared_prefix_session(eng)
+                out["events"] = sorted({e[0] for e in eng.sched_trace}
+                                       | {"admit:" + e[3] for e in eng.sched_trace
+                                          if e[0] == "admit"})
             out["decode_path"] = eng.decode_path
         finally:
             eng.close()
+        other = []
         for f in sorted(glob.glob(os.path.join(dump, "*after_optimizations.txt"))):
             name = re.sub(r"^module_\d+\.", "", os.path.basename(f)).split(".")[0]
             with open(f) as fh:
-                out["programs"].setdefault(name, []).append(_digest(_strip(fh.read())))
+                digest = _digest(_strip(fh.read()))
+            if re.search(PROGRAMS, name):
+                out["programs"].setdefault(name, []).append(digest)
+            else:
+                other.append(digest)
+        out["programs"][OTHER] = [len(other), _digest(" ".join(sorted(other)))]
     print("RESULT " + json.dumps(out))
+
+
+def _shared_prefix_session(eng) -> list:
+    """An exact prefix hit, a strict-prefix hit, then two requests at once
+    whose decode outgrows the pool (the younger is parked and resumed)."""
+    base = list(range(3, 3 + 90))
+    tokens = eng.generate(base, max_new_tokens=4)
+    tokens += eng.generate(base, max_new_tokens=4)
+    tokens += eng.generate(base + list(range(200, 230)), max_new_tokens=4)
+    reqs = [eng.submit(list(range(lo, lo + 100)), max_new_tokens=100)
+            for lo in (40, 500)]
+    for req in reqs:
+        req.done.wait(600)
+        tokens += req.tokens
+    return tokens
 
 
 def _run(root: str, case: str):
@@ -132,7 +170,8 @@ def main() -> int:
         if "failed" in a or "failed" in b:
             continue
         print(f"== {case}: decode_path {a.get('decode_path')} -> {b.get('decode_path')}; "
-              f"tokens equal: {a.get('tokens') == b.get('tokens')}")
+              f"tokens equal: {a.get('tokens') == b.get('tokens')}"
+              + (f"; events {' '.join(b['events'])}" if "events" in b else ""))
         for name in sorted(set(a["programs"]) | set(b["programs"])):
             ha, hb = a["programs"].get(name), b["programs"].get(name)
             print(f"   {name:40s} {'SAME' if ha == hb else 'DIFF'} {ha} {hb}")

@@ -50,3 +50,21 @@ def test_a_checkout_hashes_as_itself():
     assert out.returncode == 0, out.stderr[-2000:]
     lines = [ln.split() for ln in out.stdout.splitlines() if ln.startswith("   ")]
     assert len(lines) == len(hash_programs.KERNELS) and all(ln[1] == "SAME" for ln in lines), out.stdout
+
+
+def test_a_session_hashes_as_itself_on_the_other_modules_line():
+    """Beside the four named programs, every other module a session compiled
+    (the scheduler's eager scatters and slices among them) is one line of
+    count and digest: the same tree reads SAME there too."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "scripts", "hash_programs.py"),
+         "--parent", ROOT, "--only", "debug.gather"],
+        capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = [ln for ln in out.stdout.splitlines() if ln.startswith("   ")]
+    assert len(lines) >= 4, out.stdout
+    assert all(" SAME " in ln for ln in lines), out.stdout
+    other = [ln for ln in lines if hash_programs.OTHER in ln]
+    assert len(other) == 1, out.stdout
+    count = int(other[0].split("[")[1].split(",")[0])
+    assert count > len(lines), other[0]  # dozens of eager modules, not four

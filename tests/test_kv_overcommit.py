@@ -129,7 +129,7 @@ def test_cow_block_accounting_shares_then_releases(over_cow):
     the pool to full."""
     ents = [e for e in over_cow._prefix._d.values() if e.get("blocks")]
     assert ents, "COW cache holds no block entries"
-    alloc = over_cow._allocator
+    alloc = over_cow._pool.allocator
     # entries SHARE physical blocks with each other (an extended prefix's
     # entry increfs its parent's full blocks): the reserved count is the
     # UNIQUE block set, and each block's refcount equals its owner count
@@ -321,7 +321,7 @@ def test_overcommit_off_reserves_eagerly_byte_identical():
                         kv_overcommit="off", prefix_cache=4)
     try:
         assert not eng.overcommit and not eng.cow
-        assert eng._reserve_depth(64, 100) == 164  # eager math
+        assert eng._pool.reserve_depth(64, 100) == 164  # eager math
         req = eng.submit(eng.tokenizer.encode("hi"), max_new_tokens=48)
         peak = 0
         deadline = time.time() + 300

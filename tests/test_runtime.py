@@ -8,7 +8,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 import pytest
 
@@ -136,15 +135,12 @@ def test_local_serving_backend_reports_failed_when_the_engine_cannot_load(
     backend.deploy("broken", {"model_path": str(tmp_path / "no-such-model"),
                               "template": "vanilla"})
     try:
-        deadline = time.time() + 180
-        status = backend.status("broken")
-        while status != "FAILED" and time.time() < deadline:
-            assert status == "PENDING", status
-            time.sleep(0.2)
-            status = backend.status("broken")
-        assert status == "FAILED"
-        proc = backend._procs["broken"]
-        assert proc.wait(timeout=60) == 1  # exited, non-zero
+        assert backend.status("broken") in ("PENDING", "FAILED")
+        # the replica's exit is the event to wait on (the timeout only
+        # guards a hang: a child that imports JAX beside six busy workers
+        # has missed a 180 s poll)
+        assert backend._procs["broken"].wait(timeout=900) == 1  # non-zero
+        assert backend.status("broken") == "FAILED"
     finally:
         backend.delete("broken")
 

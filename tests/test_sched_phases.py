@@ -197,7 +197,13 @@ def test_the_17th_of_17_on_16_slots_waited_for_a_slot():
     eng = _engine(slots=16, max_seq_len=128)
     try:
         ids = eng.tokenizer.encode("seventeen on sixteen")
+        # the scheduler's next pass waits for all seventeen: a submitting
+        # thread that stalls beside busy workers would else let the first
+        # sixteen finish before the last arrives
+        all_in, tick = threading.Event(), eng._tick
+        eng._tick = lambda: all_in.wait(600) and tick()
         reqs = [eng.submit(ids, max_new_tokens=24) for _ in range(17)]
+        all_in.set()
         for r in reqs:
             assert r.done.wait(600) and r.error is None
     finally:

@@ -13,7 +13,6 @@ cmd/controller-manager/app/controller_manager.go:72-111).
 import datetime
 import ssl
 import threading
-import time
 
 import pytest
 
@@ -177,14 +176,19 @@ def test_standby_rotation_loop_hot_reloads_tls(client, tmp_path):
                     return tls.getpeercert(binary_form=True)
 
         before = _served_cert()
+        # the rotation loop's own reload is the event to wait on: a poll
+        # against a 10 s deadline is one that six busy workers can miss
+        reloaded = threading.Event()
+        load = standby._ssl_ctx.load_cert_chain
+
+        def load_and_tell(*a, **kw):
+            load(*a, **kw)
+            reloaded.set()
+
+        standby._ssl_ctx.load_cert_chain = load_and_tell
         leader.refresh_margin = datetime.timedelta(days=9999)
         assert leader.ensure(as_leader=True) is True  # rotate the Secret
-        deadline = time.time() + 10
-        while time.time() < deadline:
-            if _read(standby_cm.ca_path) == _read(leader.ca_path) \
-                    and _served_cert() != before:
-                break
-            time.sleep(0.05)
+        assert reloaded.wait(300), "the standby never reloaded its TLS context"
         assert _read(standby_cm.ca_path) == _read(leader.ca_path)
         assert _served_cert() != before  # live TLS reload, no restart
     finally:
