@@ -906,6 +906,186 @@ def _glm_cell_check(verdict, cell_name: str) -> None:
             f"{noise_plain:.4f} without it ({steps} steps, {cfg.vocab_size} logits each)")
 
 
+def child_kimi(rehearsal: bool) -> int:
+    """Runs IN the chip-holding child: a model whose every mixer is DENSE
+    latent attention under YaRN, served under a prefix cache (copy-on-write
+    blocks of the latent pool). First ``preset:debug-kimi`` with two adapters on
+    ``q_b_proj`` / ``o_proj``: a session of three turns an adapter (a cold turn,
+    two that extend their history through shared blocks, pads mid-row) and the
+    first turn again (an exact hit), served tokens against
+    benchmarks/reference/kimi_k2.py as logits, and the admissions' paths. Then
+    (not in the CPU rehearsal), at the published widths of the benchmark's
+    configuration cut to its first two layers: YaRN's tables against the closed
+    form in float64; one slot through a paged cache to 12k tokens, the token
+    steps at contexts of 1k, 4k and 12k against the reference's full forward;
+    and a turn served through shared blocks against the same turn served cold."""
+    import tempfile
+
+    import numpy as np
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    from reference import kimi_k2 as reference
+
+    from datatunerx_tpu.serving.adapters import make_adapter_checkpoint
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+    from datatunerx_tpu.utils import runtime
+
+    runtime.startup("kimi")
+    ok = True
+
+    def verdict(name, passed, detail):
+        nonlocal ok
+        ok &= bool(passed)
+        print(f"{'PASS' if passed else 'FAIL'} {name} {detail}", flush=True)
+
+    work = tempfile.mkdtemp(prefix="smoke_kimi_")
+    adapters = {f"ad{i}": make_adapter_checkpoint(
+        f"{work}/ad{i}", "preset:debug-kimi", seed=20 + i, rank=4,
+        targets=("q_b_proj", "o_proj")) for i in range(2)}
+    eng = BatchedEngine("preset:debug-kimi", adapters=adapters, slots=3, decode_chunk=4,
+                        kv_block_size=8, kv_blocks=192, max_seq_len=512, prefill_chunk=64,
+                        kv_overcommit="on", prefix_cache=8)
+    try:
+        rng = np.random.default_rng(1)
+        items = []
+        for name, n in (("", 70), ("ad0", 130), ("ad1", 33)):
+            history = rng.integers(10, 500, size=n).tolist()
+            first = list(history)
+            for tool in (0, 37, 90):
+                history = history + rng.integers(10, 500, size=tool).tolist()
+                req = eng.submit(history, max_new_tokens=12, adapter=name)
+                items.append((list(history), name, req))
+                assert req.done.wait(900)
+                history = history + list(req.tokens)
+            items.append((first, name, eng.submit(first, max_new_tokens=12, adapter=name)))
+        gaps = _served_gaps(eng, reference, items, verdict, "kimi")
+        verdict("kimi/served_vs_reference", float(gaps.max()) <= 0.05,
+                f"gap_max {gaps.max():.4f} gap_mean {gaps.mean():.5f} tokens {gaps.size}")
+        modes = [e[3] for e in eng.sched_trace if e[0] == "admit"]
+        stats = eng.prefix_stats
+        verdict("kimi/prefix_paths",
+                modes == ["chunked", "cow_extend", "cow_extend", "cow"] * 3
+                and (stats["cold"], stats["extensions"], stats["hits"]) == (3, 6, 3)
+                and eng.decode_paths == {"mla": "gather"},
+                json.dumps(dict(stats, modes=modes, decode_paths=eng.decode_paths)))
+    finally:
+        eng.close()
+        shutil.rmtree(work, ignore_errors=True)
+    if rehearsal or not ok:
+        return 0 if ok else 1
+    _kimi_cell_check(verdict, "kimi-serve-agent")
+    return 0 if ok else 1
+
+
+def _kimi_cell_check(verdict, cell_name: str) -> None:
+    """The second half of ``child_kimi``, at the widths of ``cell_name``'s configuration."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import spec
+    from reference import kimi_k2 as reference
+
+    from datatunerx_tpu.models import forward
+    from datatunerx_tpu.models.config import PRESETS, mixer_kinds
+    from datatunerx_tpu.ops.paged_attention import init_paged_cache
+    from datatunerx_tpu.ops.rope import rope_cos_sin, yarn_mscale
+    from datatunerx_tpu.serving.batched_engine import BatchedEngine
+
+    cell = spec.load_cell(cell_name)
+    cfg = dataclasses.replace(spec.register_preset(cell), num_layers=2, layer_types=("mla",) * 2,
+                              ffn_types=("dense", "experts"))
+    kind = mixer_kinds(cfg)["mla"]
+    # YaRN's tables against the closed form in float64, below the trained length, at it, at the
+    # cell's longest context and at the model's. The chip's float32 power and product leave a
+    # frequency good to 7e-7 of itself (PR 45: 0.0087 rad at position 12,287, 0.186 at 262,143;
+    # the CPU's to 4e-7, tests/test_kimi_model.py): twice that is allowed
+    d, base, y = kind.rope_dim, kind.rope_theta, kind.yarn
+    corr = lambda n: d * math.log(y.original_max_len / (2 * math.pi * n)) / (2 * math.log(base))  # noqa: E731
+    low, high = max(math.floor(corr(y.beta_fast)), 0), min(math.ceil(corr(y.beta_slow)), d - 1)
+    i = np.arange(d // 2, dtype=np.float64)
+    ramp = np.clip((i - low) / (high - low), 0, 1)
+    freq = base ** (-2 * i / d) * ((1 - ramp) + ramp / y.factor)
+    pos = np.asarray([0, 1, 1000, 4095, 4096, 12287, 65536, 262143])
+    cos, sin = rope_cos_sin(jnp.asarray(pos[None], jnp.int32), d, theta=base, yarn=y)
+    err = np.maximum(np.abs(np.asarray(cos[0]) - np.cos(pos[:, None] * freq)),
+                     np.abs(np.asarray(sin[0]) - np.sin(pos[:, None] * freq))).max(axis=1)
+    verdict("kimi/cell/yarn_tables", bool((err <= 1.5e-6 * pos + 2e-6).all()) and (low, high) == (8, 20)
+            and abs(kind.score_scale - 192 ** -0.5 * yarn_mscale(64, 1) ** 2) < 1e-12,
+            f"low {low} high {high}; max |error| at positions {pos.tolist()}: "
+            f"{[float(f'{e:.2e}') for e in err]}; score scale {kind.score_scale:.6f}")
+
+    # the published widths, the configuration's first two layers, one slot through a paged cache
+    mc = dict(cell.model_fields, num_layers=2, layer_types=["mla"] * 2, ffn_types=["dense", "experts"])
+    weights = spec.load_module(cell.config["weights_module"])
+    params = weights.draw_params(mc, 4100000045)
+    bs, steps, marks = 16, 4, (1024, 4096, 12272)
+    T = marks[-1] + steps
+    blocks = -(-T // bs)
+    toks = np.random.default_rng(2).integers(10, cfg.vocab_size, size=T).tolist()
+    spans, at = [], 0
+    for mark in marks:
+        while at < mark:
+            spans.append((at, min(at + 256, mark)))
+            at = spans[-1][1]
+        spans += [(mark + j, mark + j + 1) for j in range(steps)]
+        at = mark + steps
+    step = jax.jit(lambda p, ids, cache, pos: forward(p, ids, cfg, cache=cache, positions=pos,
+                                                     compute_dtype=jnp.bfloat16), donate_argnums=(2,))
+    cache = init_paged_cache(cfg, 1, blocks + 48, bs, blocks, dtype=jnp.bfloat16)
+    table = np.random.default_rng(3).permutation(blocks + 48)[:blocks]
+    cache["block_tables"] = jnp.asarray(table[None], jnp.int32)
+    last = {}
+    for lo, hi in spans:
+        out, cache = step(params, jnp.asarray([toks[lo:hi]], jnp.int32), cache,
+                          jnp.arange(lo, hi, dtype=jnp.int32)[None])
+        if hi - lo == 1:
+            last[lo] = out[0, 0]
+    rows = sorted(last)
+    ref = reference.sequence_logits(params, mc, toks, rows)
+    rms = lambda a: float(jnp.sqrt(jnp.mean(a * a)))  # noqa: E731
+    for mark in marks:
+        pick = [rows.index(mark + j) for j in range(steps)]
+        got = jnp.stack([last[mark + j] for j in range(steps)]).astype(jnp.float32)
+        want = ref[jnp.asarray(pick)]
+        noise = rms(got - want) / rms(want - jnp.mean(want))
+        agree = float(jnp.mean(jnp.argmax(got, -1) == jnp.argmax(want, -1)))
+        # bf16 weights, activations and cached rows against float32: cell 7's steps without their
+        # selection read 0.010 of the logits' spread
+        verdict(f"kimi/cell/decode_logits[context {mark}]", noise <= 0.03,
+                f"rms (logit - reference) over the logits' spread {noise:.4f}, the same first token "
+                f"in {agree:.2f} of {steps} steps x {cfg.vocab_size} logits")
+    del cache, params
+
+    # a turn served through shared blocks against the same turn served cold
+    PRESETS["kimi-smoke-l2"] = dataclasses.replace(cfg, name="kimi-smoke-l2")
+    eng = BatchedEngine("preset:kimi-smoke-l2", slots=2, decode_chunk=8, kv_block_size=16, kv_blocks=256,
+                        max_seq_len=2048, prefill_chunk=256, kv_overcommit="on", prefix_cache=4)
+    try:
+        rng = np.random.default_rng(4)
+        first = rng.integers(10, cfg.vocab_size, size=700).tolist()
+        a = eng.submit(first, max_new_tokens=24)
+        assert a.done.wait(900) and a.error is None, a.error
+        turn = first + list(a.tokens) + rng.integers(10, cfg.vocab_size, size=150).tolist()
+        shared = eng.submit(turn, max_new_tokens=24)
+        assert shared.done.wait(900) and shared.error is None, shared.error
+        eng._prefix.drop_adapter(0)  # the base's entries: the same turn now finds nothing
+        cold = eng.submit(turn, max_new_tokens=24)
+        assert cold.done.wait(900) and cold.error is None, cold.error
+        modes = [e[3] for e in eng.sched_trace if e[0] == "admit"]
+        gaps = _served_gaps(eng, reference, [(turn, "", shared), (turn, "", cold)], verdict, "kimi/cell")
+        # bf16 rounds one way through pads mid-row and another through pads at the left: the
+        # tokens need not be the same, each has to be what the float32 reference puts first or near
+        verdict("kimi/cell/shared_vs_cold", modes == ["chunked", "cow_extend", "chunked"]
+                and float(gaps.max()) <= 0.25,
+                f"paths {modes}; same tokens {shared.tokens == cold.tokens}; gap_max {gaps.max():.4f} "
+                f"gap_mean {gaps.mean():.5f} over {gaps.size} tokens")
+    finally:
+        eng.close()
+
+
 def child_kernels(rehearsal: bool) -> int:
     """Runs IN the chip-holding child: compile every Pallas kernel with
     Mosaic and compare it with its oracle. On the CPU rehearsal the same
@@ -1624,8 +1804,8 @@ def main(argv=None) -> int:
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="debug-size run on the CPU to debug THIS SCRIPT; "
                          "proves nothing about the chip")
-    ap.add_argument("--phases", default="trainer,server,kernels,hybrid,ling,glm",
-                    help="comma list out of trainer,server,kernels,hybrid,ling,glm")
+    ap.add_argument("--phases", default="trainer,server,kernels,hybrid,ling,glm,kimi",
+                    help="comma list out of trainer,server,kernels,hybrid,ling,glm,kimi")
     ap.add_argument("--mesh", action="append", default=None,
                     help="trainer --mesh (e.g. dp=1,fsdp=4,tp=1); repeat to "
                          "run the trainer once per mesh. 'auto' (the "
@@ -1638,6 +1818,8 @@ def main(argv=None) -> int:
     ap.add_argument("--child-ling", action="store_true",
                     help=argparse.SUPPRESS)
     ap.add_argument("--child-glm", action="store_true",
+                    help=argparse.SUPPRESS)
+    ap.add_argument("--child-kimi", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
@@ -1655,12 +1837,14 @@ def main(argv=None) -> int:
         return child_ling(args.cpu_rehearsal)
     if args.child_glm:
         return child_glm(args.cpu_rehearsal)
+    if args.child_kimi:
+        return child_kimi(args.cpu_rehearsal)
     if not os.path.isdir(os.path.join(REPO, "datatunerx_tpu")):
         print("chip_smoke: no datatunerx_tpu package beside this script",
               file=sys.stderr)
         return 2
     phases = [p.strip() for p in args.phases.split(",") if p.strip()]
-    unknown = set(phases) - {"trainer", "server", "kernels", "hybrid", "ling", "glm"}
+    unknown = set(phases) - {"trainer", "server", "kernels", "hybrid", "ling", "glm", "kimi"}
     if unknown:
         ap.error(f"unknown phases {sorted(unknown)}")
 
@@ -1692,7 +1876,7 @@ def main(argv=None) -> int:
         runs.append(("server", lambda left: phase_server(rehearsal, left)))
     if "kernels" in phases:
         runs.append(("kernels", lambda left: phase_kernels(rehearsal, left)))
-    for name in ("hybrid", "ling", "glm"):
+    for name in ("hybrid", "ling", "glm", "kimi"):
         if name in phases:
             runs.append((name, lambda left, name=name: phase_kernels(rehearsal, left, name)))
 

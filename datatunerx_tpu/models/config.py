@@ -30,6 +30,14 @@ class ModelConfig:
     # (reference cmd/tuning/parser.py:57-60); None disables.
     rope_scaling_type: Optional[str] = None
     rope_scaling_factor: float = 1.0
+    # "yarn" (latent-attention layers only, ops/rope.py): the length the
+    # frequencies were trained at, the turns over it between which a pair's
+    # frequency is blended, and the two temperatures (``YarnScaling``)
+    rope_original_max_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     rms_norm_eps: float = 1e-5
     tie_word_embeddings: bool = False
     attention_bias: bool = False  # Qwen1.5 uses bias on q/k/v projections
@@ -134,7 +142,11 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.hidden_size // self.num_heads)
         assert self.num_heads % self.num_kv_heads == 0
         if self.rope_scaling_type is not None:
-            assert self.rope_scaling_type in ("linear", "dynamic"), self.rope_scaling_type
+            assert self.rope_scaling_type in ("linear", "dynamic", "yarn"), self.rope_scaling_type
+        if self.rope_scaling_type == "yarn":
+            assert self.layer_types and set(self.layer_types) == {"mla"}, (
+                "yarn scaling is implemented for latent-attention layers only")
+            assert self.rope_original_max_len > 0 and self.rope_scaling_factor >= 1
         for name in ("layer_types", "ffn_types", "expert_swiglu_limits"):
             value = getattr(self, name)
             if value is not None:  # a list from JSON: keep the config hashable
@@ -180,6 +192,7 @@ class AttentionKind:
     sink: bool
     value_scale: float
     scale: Optional[float] = None  # of the scores; ``head_dim ** -0.5`` if None
+    yarn = None
 
     def pools(self) -> dict:
         """{cache leaf: row width} of the rows this kind caches per token."""
@@ -191,12 +204,24 @@ class AttentionKind:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN on a kind's rope lanes (ops/rope.py:rope_cos_sin, yarn_mscale)."""
+    factor: float
+    original_max_len: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+@dataclasses.dataclass(frozen=True)
 class MlaKind:
     """Latent attention: one cached row per token, ``[c kv_lora_rank | kR
     rope_dim]``, shared by every head; no v pool (ops/mla.py). With an
     indexer (``index_topk``) a second row per token, the index key, in a pool
     of its own, and every query reads its ``index_topk`` best tokens
-    (ops/dsa.py)."""
+    (ops/dsa.py). ``whole_tiles``: the pool's rows are stored in whole lane
+    tiles (128 lanes; a row's tail is zeros)."""
     kv_lora_rank: int
     nope_dim: int
     rope_dim: int
@@ -207,12 +232,25 @@ class MlaKind:
     index_heads: int = 0
     index_dim: int = 0
     index_topk: int = 0  # 0: no indexer
+    yarn: Optional[YarnScaling] = None
+    whole_tiles: bool = False
     name: str = "mla"
     window = None
 
     @property
     def rotary_dim(self) -> int:
         return self.rope_dim
+
+    @property
+    def score_scale(self) -> float:
+        """``(nope + rope) ** -0.5``, times YaRN's temperature squared where
+        the model states one for all lanes (``mscale_all_dim``)."""
+        scale = (self.nope_dim + self.rope_dim) ** -0.5
+        if self.yarn is not None and self.yarn.mscale_all_dim:
+            from datatunerx_tpu.ops.rope import yarn_mscale
+
+            scale *= yarn_mscale(self.yarn.factor, self.yarn.mscale_all_dim) ** 2
+        return scale
 
     @property
     def index_rope_dim(self) -> int:
@@ -222,11 +260,9 @@ class MlaKind:
 
     def pools(self) -> dict:
         row = self.kv_lora_rank + self.rope_dim
-        if not self.index_topk:
-            return {"k_mla": row}
-        # a selecting kind gathers single rows: each starts on a lane tile
-        # (128 lanes; the row's tail is zeros)
-        return {"k_mla": -(-row // 128) * 128, "k_idx": self.index_dim}
+        if self.whole_tiles:
+            row = -(-row // 128) * 128
+        return {"k_mla": row, **({"k_idx": self.index_dim} if self.index_topk else {})}
 
     def states(self, cfg) -> dict:
         return {}
@@ -324,7 +360,18 @@ def mixer_kinds(cfg: ModelConfig) -> dict:
         if cfg.index_topk:  # the index query comes from the compressed query
             assert cfg.q_lora_rank > 0 and cfg.index_heads > 0
             assert cfg.index_head_dim >= cfg.qk_rope_head_dim
+        yarn = None
+        if cfg.rope_scaling_type == "yarn":
+            yarn = YarnScaling(
+                factor=cfg.rope_scaling_factor, original_max_len=cfg.rope_original_max_len,
+                beta_fast=cfg.rope_beta_fast, beta_slow=cfg.rope_beta_slow,
+                mscale=cfg.rope_mscale, mscale_all_dim=cfg.rope_mscale_all_dim)
         kinds["mla"] = MlaKind(
+            # whole lane tiles where single rows are gathered (a selecting
+            # kind) and where the latent pool is the model's whole cache (every
+            # layer latent): rows of 4.5 tiles make XLA keep the pool in a
+            # layout of its own and copy it in and out of every program
+            yarn=yarn, whole_tiles=bool(cfg.index_topk) or set(types) == {"mla"},
             kv_lora_rank=cfg.kv_lora_rank, nope_dim=cfg.qk_nope_head_dim,
             rope_dim=cfg.qk_rope_head_dim, rope_theta=cfg.rope_theta,
             v_head_dim=cfg.v_head_dim or cfg.head_dim,
@@ -494,6 +541,24 @@ PRESETS = {
         experts_total=16, experts_held=4, first_held=0, experts_per_token=2,
         expert_intermediate_size=32, shared_expert_intermediate_size=32,
         routed_scaling_factor=2.5,
+    ),
+    # Debug size of a model whose every mixer is DENSE latent attention (a
+    # low-rank query, no indexer, no head gate: every query reads every cached
+    # row) under YaRN, v heads narrower than q/k; one dense layer, then
+    # sigmoid-routed experts with a shared one.
+    "debug-kimi": ModelConfig(
+        name="debug-kimi", vocab_size=512, hidden_size=64, intermediate_size=128,
+        num_layers=5, num_heads=4, num_kv_heads=4, head_dim=24, v_head_dim=16,
+        max_seq_len=512, rope_theta=100.0,
+        rope_scaling_type="yarn", rope_scaling_factor=8.0, rope_original_max_len=64,
+        rope_mscale=1.0, rope_mscale_all_dim=1.0,
+        layer_types=("mla",) * 5,
+        ffn_types=("dense", "experts", "experts", "experts", "experts"),
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        q_lora_rank=48, mla_head_gate=False,
+        experts_total=16, experts_held=4, first_held=0, experts_per_token=2,
+        expert_intermediate_size=32, shared_expert_intermediate_size=32,
+        routed_scaling_factor=2.827,
     ),
     "qwen1.5-7b": ModelConfig(
         name="qwen1.5-7b", vocab_size=151936, hidden_size=4096,

@@ -27,7 +27,10 @@ Every layer is a pre-norm residual block (RMSNorm, no bias anywhere):
   (RMS-normed) and one rotated key ``kR`` for all heads (interleaved RoPE on
   it and on q's rope lanes); ``[c | kR]`` is the cached row; attention runs
   absorbed over those rows, scaled by ``(nope + rope) ** -0.5``; a sigmoid
-  gate per head on the output where the model has one (``head_gate``).
+  gate per head on the output where the model has one (``head_gate``). Under
+  YaRN (``rope_scaling_type`` "yarn", ops/rope.py) the rope lanes' frequencies
+  are blended with their own over the factor and the scores are scaled by the
+  temperature squared besides (``MlaKind.score_scale``).
 - learned selection (an ``mla`` kind with ``index_topk``, ops/dsa.py): an
   indexer beside the mixer (index queries ``wq_b`` from the compressed query,
   one index key a token ``k_norm(wk(x))`` with a LayerNorm's scale and bias,
@@ -454,7 +457,8 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
 
     kinds = mixer_kinds(cfg)
     attending = {name: kind for name, kind in kinds.items() if kind.pools()}
-    rope = {name: rope_cos_sin(positions, kind.rotary_dim, theta=kind.rope_theta)
+    rope = {name: rope_cos_sin(positions, kind.rotary_dim, theta=kind.rope_theta,
+                               yarn=kind.yarn)
             for name, kind in attending.items() if kind.rotary_dim}
     valid = attention_mask.astype(bool) if attention_mask is not None else None
     views, bias, cache_pos = {}, {}, None
@@ -629,8 +633,7 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
                 rows = row[:, :, None, :]
             with jax.named_scope("dtx.attn"):
                 o_lat = xla_attention(
-                    q_lat, rows, rows[..., :rank], mask,
-                    scale=(kind.nope_dim + kind.rope_dim) ** -0.5)
+                    q_lat, rows, rows[..., :rank], mask, scale=kind.score_scale)
             with jax.named_scope("dtx.mla_absorb"):
                 return mla.expand_value(o_lat, wvb)
 

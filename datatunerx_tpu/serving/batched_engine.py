@@ -212,15 +212,16 @@ class _PrefixCache:
         with self._lock:
             return list(reversed(list(self._d.items())))
 
-    def pop_lru_block_entry(self):
-        """Evict (and return) the least-recently-used BLOCK entry — the
+    def pop_lru_block_entry(self, idle=None):
+        """Evict (and return) the least-recently-used BLOCK entry (of those
+        ``idle(entry)`` holds for, where given) — the
         overcommit scheduler's first reclamation tier when growth finds
         the pool empty: cached prefixes are a performance tier, live
         sessions are the product. None when no block entries remain.
         The caller owns the entry's block refs (on_evict is NOT called)."""
         with self._lock:
             for key, ent in self._d.items():
-                if ent.get("blocks"):
+                if ent.get("blocks") and (idle is None or idle(ent)):
                     del self._d[key]
                     self._trie_remove(key)
                     self.evictions += 1
@@ -711,17 +712,16 @@ class BatchedEngine:
         self.slots = slots
         self.chunk = max(1, decode_chunk)
         if self.cfg.hybrid:
-            # layers of several kinds (models/hybrid.py): a window layer reads
-            # a window-wide view placed by the slot's linear cursor, which
-            # holds while a row's pads lie at its left; a prefix-cache
-            # extension puts pads mid-row, and the draft/verify programs
-            # carve windows of their own
-            for flag, on in (("prefix_cache", prefix_cache > 0),
-                             ("spec_draft", bool(spec_draft))):
-                if on:
-                    raise NotImplementedError(
-                        f"model {self.cfg.name!r} has layers of several "
-                        f"kinds: --{flag} does not handle it yet")
+            # layers of several kinds (models/hybrid.py): the draft/verify
+            # programs carve windows of their own; a prefix-cache extension
+            # puts a row's pads mid-row, which only kinds that mask by
+            # position over the whole table read rightly
+            if spec_draft:
+                raise NotImplementedError(
+                    f"model {self.cfg.name!r} has layers of several "
+                    f"kinds: --spec_draft does not handle it yet")
+            if prefix_cache > 0:
+                self._refuse_hybrid_prefix_cache(kv_block_size, kv_overcommit)
         # a prefix hit, a rejected draft, a preempted or a migrating session
         # all restart a slot at a cursor it has passed: rows are trimmed
         # there, a layer's recurrent state cannot be
@@ -1147,6 +1147,12 @@ class BatchedEngine:
         self.cow = self.overcommit and self._prefix is not None
         # observability: how admissions were served (tests + /metrics)
         self.prefill_stats = {"full": 0, "reuse": 0, "extend": 0}
+        # what the prefix cache gave admissions (dtx_serving_prefix_*_total):
+        # prompt tokens mapped from shared blocks or a cached row against
+        # prompt tokens prefilled; ``prefix_stats`` adds the evictions
+        self._prefix_stats = {"hits": 0, "extensions": 0, "cold": 0,
+                              "shared_tokens": 0, "prefilled_tokens": 0,
+                              "blocks_reclaimed_at_admission": 0}
         # Shared-registry latency histograms. Recording is BUFFERED off the
         # hot path: token stamps are plain attribute writes in Request.push;
         # the observes below fire once per completed request (TTFT/TPOT) —
@@ -1358,6 +1364,35 @@ class BatchedEngine:
         return {"live_bytes": live * per_block,
                 "behind_bytes": behind * per_block}
 
+    def _refuse_hybrid_prefix_cache(self, kv_block_size, kv_overcommit):
+        """A model of several layer kinds takes ``prefix_cache`` where every
+        attending kind reads its slot's whole table masked by position and
+        nothing else remembers the row: a window kind's view is placed by the
+        slot's linear cursor (pads at the row's left), a selecting kind sizes
+        its chunk's view and its counters by that cursor, a recurrent state
+        cannot be rewound to a shared prefix. Entries are blocks of the
+        kinds' pools (``kv_overcommit`` on), never dense rows."""
+        from datatunerx_tpu.models.config import mixer_kinds
+
+        why = {}
+        for name, kind in mixer_kinds(self.cfg).items():
+            if kind.states(self.cfg):
+                why[name] = "keeps a recurrent state per slot"
+            elif kind.window:
+                why[name] = "reads a window-wide view placed by the slot's cursor"
+            elif getattr(kind, "index_topk", 0):
+                why[name] = "selects the cached tokens it reads, a view sized by the cursor"
+        if why:
+            raise NotImplementedError(
+                f"model {self.cfg.name!r} has layers of several kinds, of "
+                f"which " + "; ".join(f"{n} {w}" for n, w in sorted(why.items()))
+                + ": --prefix_cache does not handle it yet")
+        if not (kv_block_size > 0 and str(kv_overcommit).lower() == "on"):
+            raise NotImplementedError(
+                f"model {self.cfg.name!r} has layers of several kinds: "
+                "--prefix_cache shares blocks of their pools and needs "
+                "--kv_block_size > 0 and --kv_overcommit on")
+
     def _free_prefix_entry(self, ent: dict):
         """Prefix-cache eviction hook: return a COW block entry's refs to
         the allocator (dense-row entries hold no pool resources). Runs on
@@ -1365,6 +1400,11 @@ class BatchedEngine:
         the allocator's own lock covers it."""
         if ent.get("blocks"):
             self._pool.free_entry(ent["blocks"])
+
+    @property
+    def prefix_stats(self) -> dict:
+        evicted = self._prefix.evictions if self._prefix is not None else 0
+        return dict(self._prefix_stats, entries_evicted=evicted)
 
     def _count_preempt(self, outcome: str):
         self.preempt_stats[outcome] = self.preempt_stats.get(outcome, 0) + 1
@@ -1603,7 +1643,7 @@ class BatchedEngine:
         # exact-hit fast path
         if (ent is not None and not ent.get("no_reuse")
                 and self.max_seq_len - ent["cursor"] >= need):
-            self.prefill_stats["reuse"] += 1
+            self._count_prefill("reuse", shared=len(used))
             return ent["logits"], ent["cache"], ent["cursor"]
         pkey, pent = self._prefix.longest_prefix(used, akey)
         if pent is not None:
@@ -1624,7 +1664,8 @@ class BatchedEngine:
                     jnp.asarray(adapter, jnp.int32),
                     suffix_len=len(stoks),
                 )
-                self.prefill_stats["extend"] += 1
+                self._count_prefill("extend", shared=n_pref,
+                                    prefilled=len(suffix))
                 self._prefix.put(key, {"cache": row_cache,
                                        "logits": row_logits,
                                        "cursor": cursor})
@@ -1645,7 +1686,7 @@ class BatchedEngine:
             jnp.asarray([mask], jnp.int32), jnp.asarray([positions], jnp.int32),
             jnp.asarray(adapter, jnp.int32), prompt_len=plen,
         )
-        self.prefill_stats["full"] += 1
+        self._count_prefill("full", prefilled=n_prompt)
         if self._prefix is not None:
             self._prefix.put(self._prefix_key(ids, plen, n_prompt, akey),
                              {"cache": row_cache, "logits": row_logits,
@@ -1850,8 +1891,8 @@ class BatchedEngine:
             ok = self._cow_map(req, slot, ent, n_prompt, m,
                                suffix=None, key=key)
             if ok:
-                self.prefill_stats["reuse"] += 1
-                self._admitted(req, slot, plen, "cow")
+                self._count_prefill("reuse", shared=len(used))
+                self._admitted(req, slot, plen, "cow", shared=len(used))
             return ok
         pkey, pent = self._prefix.longest_prefix(used, akey)
         if pent is not None and pent.get("blocks") is not None:
@@ -1867,8 +1908,10 @@ class BatchedEngine:
                 ok = self._cow_map(req, slot, pent, n_prompt, max_new,
                                    suffix=sfx, key=key)
                 if ok:
-                    self.prefill_stats["extend"] += 1
-                    self._admitted(req, slot, plen, "cow_extend")
+                    self._count_prefill("extend", shared=n_pref,
+                                        prefilled=len(suffix))
+                    self._admitted(req, slot, plen, "cow_extend",
+                                   shared=n_pref)
                 return ok
         return None
 
@@ -1959,7 +2002,17 @@ class BatchedEngine:
         if req is not None:
             self._mark(req, mark or name, **detail)
 
-    def _admitted(self, req: Request, slot: int, plen: int, mode: str):
+    def _count_prefill(self, kind: str, shared: int = 0, prefilled: int = 0):
+        """One admission served ``full`` / ``reuse`` / ``extend``, with the
+        prompt tokens it took from the prefix cache and those it prefilled."""
+        self.prefill_stats[kind] += 1
+        ps = self._prefix_stats
+        ps[{"full": "cold", "reuse": "hits", "extend": "extensions"}[kind]] += 1
+        ps["shared_tokens"] += shared
+        ps["prefilled_tokens"] += prefilled
+
+    def _admitted(self, req: Request, slot: int, plen: int, mode: str,
+                  shared: int = 0):
         """The admit event, with how long the request queued and for what:
         ``tick`` (no pass of the scheduler saw it and left it: it was
         admitted within a tick of its submission), what its own last failed
@@ -1974,8 +2027,16 @@ class BatchedEngine:
                 cause = self._blocked_cause
             else:
                 cause = "slot"
-        self._event("admit", slot, plen, mode, req=req, slot=slot, plen=plen,
-                    mode=mode, waited_ticks=ticks, waited_for=cause)
+        n_prompt = len(req.spec_prime_ids or ())
+        # inside the pass's ``dtx_engine_admit``: which path this admission
+        # took and what the prefix cache spared it
+        with jax.profiler.TraceAnnotation(
+                "dtx_engine_admitted", slot=slot, shared_tokens=shared,
+                path=mode if mode.startswith("cow") else "cold",
+                prefill_tokens=n_prompt - shared):
+            self._event("admit", slot, plen, mode, req=req, slot=slot,
+                        plen=plen, mode=mode, waited_ticks=ticks,
+                        waited_for=cause, shared_tokens=shared)
 
     def _complete(self, req: Request, error: Optional[str] = None):
         """Finish a request AND flush its buffered observability: one
@@ -2069,6 +2130,9 @@ class BatchedEngine:
                     continue  # try the next request for this slot
                 if ok:
                     break
+                if self._reclaim_idle_entry():
+                    self._requeue_front([req])
+                    continue  # the head tries again with what an entry held
                 req.waited_for = ("blocks" if self._admit_wait_reason
                                   == "blocks" else "adapter")
                 if self._admit_wait_reason == "adapter":
@@ -2082,6 +2146,26 @@ class BatchedEngine:
                 self._requeue_front(parked + [req])
                 return
         self._requeue_front(parked)
+
+    def _reclaim_idle_entry(self) -> bool:
+        """The queue's head found the pool short: free the least recently
+        used prefix-cache block entry that no live slot maps (an ended
+        session's: a cached prefix is a performance tier, a waiting request
+        is the product). An entry a live slot shares blocks with gives back
+        its tail block at most and costs that session its next hit, so it
+        stays. False: the cache is off, or every entry is mapped."""
+        if not (self._prefix is not None and self._admit_wait_reason == "blocks"):
+            return False
+        live = {b for slot in range(self.slots) for b in self._pool.held(slot)}
+        ent = self._prefix.pop_lru_block_entry(
+            lambda e: live.isdisjoint(e["blocks"]))
+        if ent is None:
+            return False
+        free = self._pool.free
+        self._pool.free_entry(ent["blocks"])
+        self._prefix_stats["blocks_reclaimed_at_admission"] += self._pool.free - free
+        self._event("reclaim_entry", len(ent["blocks"]))
+        return True
 
     def _prefill_tick(self):
         """Spend AT MOST ``prefill_token_budget`` prompt tokens on pending
@@ -2158,7 +2242,7 @@ class BatchedEngine:
         if not st.get("base") and st.get("key") is not None:
             # suffix extensions already counted as "extend" at admission;
             # imported mid-prefill tails (key None) are not cold prefills
-            self.prefill_stats["full"] += 1
+            self._count_prefill("full", prefilled=st["n_prompt"])
         if self._prefix is not None and st.get("key") is not None:
             if self.cow:
                 # publish refcounted blocks — no dense-row materialisation

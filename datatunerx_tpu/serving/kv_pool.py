@@ -166,10 +166,17 @@ class KVPool:
 
     def scrub(self, blocks: Sequence[int]):
         """Recycled blocks' positions to the sentinel: BEFORE a table names
-        them, wherever the install's own program does not scrub."""
+        them, wherever the install's own program does not scrub. The list is
+        padded with its last block to a table row's length, so that the eager
+        scatter is ONE program whatever the count (a suffix of any length, a
+        tick's growth): a count of its own compiled one each, mid-traffic."""
+        if not len(blocks):
+            return
+        ids = np.full((-(-len(blocks) // self.blocks_per_slot) * self.blocks_per_slot,),
+                      blocks[-1], np.int32)
+        ids[: len(blocks)] = blocks
         cache = self._cache()
-        cache["pos"] = cache["pos"].at[
-            jnp.asarray(blocks, jnp.int32)].set(POS_SENTINEL)
+        cache["pos"] = cache["pos"].at[jnp.asarray(ids)].set(POS_SENTINEL)
 
     def set_row(self, slot: int, row):
         """One eager write of ``slot``'s whole table row (an array, or -1)."""

@@ -137,6 +137,23 @@ def _metrics_text_locked(with_exemplars: bool = True) -> str:
         hits.set(stats.get("reuse", 0))
         partial.set(stats.get("extend", 0))
         misses.set(stats.get("full", 0))
+    # what the cache gave admissions, in prompt tokens (engine.prefix_stats)
+    given = {key: reg.counter(f"dtx_serving_prefix_{name}_total", text)
+             for key, name, text in (
+        ("shared_tokens", "shared_tokens",
+         "Prompt tokens admissions took from the prefix cache (shared "
+         "blocks mapped, a cached row inserted) instead of prefilling."),
+        ("prefilled_tokens", "prefilled_tokens",
+         "Prompt tokens admissions prefilled (whole cold prompts and the "
+         "suffixes of strict-prefix hits)."),
+        ("blocks_reclaimed_at_admission", "blocks_reclaimed",
+         "KV blocks freed from idle prefix-cache entries for a waiting "
+         "request the pool could not otherwise admit."))}
+    prefix_stats = getattr(eng, "prefix_stats", None)
+    for key, c in given.items():
+        c.clear()
+        if prefix_stats is not None:
+            c.set(prefix_stats.get(key, 0))
     prefix = getattr(eng, "_prefix", None)
     entries = reg.gauge("dtx_serving_prefix_cache_entries",
                         "Live prefix-cache entries.")

@@ -45,12 +45,15 @@ ENGINES = {
     for preset in ("debug", "debug-hybrid", "debug-ling", "debug-granite", "debug-glm")
     for mode in ("on", "off")
 }
-# 24 blocks under two slots of 16 columns: two sessions outgrow the pool. ONE
-# prefix entry: a cold admission never reclaims the blocks entries hold
-# (ROADMAP D20), so the next put has to
+# 24 blocks under two slots of 16 columns: two sessions outgrow the pool, and
+# a cold admission reclaims the blocks idle entries hold (``reclaim_entry``)
 ENGINES["debug.kernels+prefix"] = ("debug", "on", {"prefix_cache": 4})
 ENGINES["debug.kernels+overcommit"] = (
-    "debug", "on", {"prefix_cache": 1, "kv_overcommit": "on", "kv_blocks": 24})
+    "debug", "on", {"prefix_cache": 4, "kv_overcommit": "on", "kv_blocks": 24})
+# a model of several layer kinds under the prefix cache: blocks of a latent pool
+# (no kernel takes its token step, and this session runs what a plain one does)
+ENGINES["debug-kimi.gather+overcommit"] = (
+    "debug-kimi", "off", {"prefix_cache": 4, "kv_overcommit": "on", "kv_blocks": 24})
 # case -> keywords of ``paged_decode_attention`` over bf16 pools of one width
 KERNELS = {"kernel/paged_decode": {}, "kernel/paged_decode_window": {"window": 100}}
 PROGRAMS = "decode_impl|prefill_chunk_impl|activate_impl|install_table"

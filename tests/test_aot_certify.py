@@ -374,6 +374,8 @@ def test_flash_kernels_compile_for_v5e_at_the_training_cell_shape():
 # indexer over a dense layer, then over four expert layers), 16 slots of up to
 # 8,704 tokens, the latent pool [5, 8704, 16, 640] and the index-key pool
 # [5, 8704, 16, 128] donated with the cache, adapters on q_b_proj and o_proj.
+# And the cell kimi-serve-agent: the same two blocks without the indexer, under
+# YaRN, 16 slots of up to 12,288 tokens, the latent pool [5, 18432, 16, 640].
 _CELL_PROBE = r"""
 import json, os, re, sys
 os.environ["DTX_PALLAS_INTERPRET"] = "0"
@@ -561,6 +563,36 @@ def test_glm_cell_programs_compile_for_v5e_at_published_widths(program, temporar
     else:
         assert got["select_conds"] == [5, 5], got
         assert got["logit_widths"] == [2048, 4096, 6144, 8192, 8704], got
+
+@pytest.fixture(scope="module")
+def kimi_doc():
+    pytest.importorskip("libtpu")  # the TPU compiler; absent from jax[cpu]
+    return _run_probe(_CELL_PROBE, timeout=900, DTX_CELL="kimi-serve-agent")
+
+
+@pytest.mark.parametrize("program,temporaries,logits", [
+    ("decode", 0.6e9, []),                     # read: 8.891 + 0.572 GB
+    ("prefill_chunk_256", 0.85e9, [12288])])   # read: 8.889 + 0.829 GB
+def test_kimi_cell_programs_compile_for_v5e_at_published_widths(program, temporaries, logits, kimi_doc):
+    """Five layers at the published widths, 16 slots of 12,288 tokens over a
+    pool of 18,432 blocks: the numbers quoted in
+    ``benchmarks/workloads/kimi-serve-agent.json``'s ``engine_notes``."""
+    got = kimi_doc[program]
+    # one pool: 18,432 blocks x 16 tokens x 5 layers x 640 bf16 values (576 in whole lane tiles)
+    assert kimi_doc["pool_bytes"] == 18432 * 16 * 5 * 640 * 2 and kimi_doc["state_bytes"] == 0
+    assert got["arguments"] < 8.95e9 and got["temporaries"] < temporaries, got
+    assert got["live"] < 9.8e9, got  # one chip holds 16 GB
+    assert got["alias"] >= kimi_doc["pool_bytes"], got  # the donated pool is written in place
+    # neither program re-lays the pool: with rows of 576 lanes both copied all 1.7 GB in and out
+    # (2 copies each, temporaries 2.268 and 1.936 GB: compiled once with ``MlaKind.pools`` patched)
+    assert got["pool_copies"] == 0, got
+    assert got["gmm"] >= 2 and got["ragged"] == 0, got
+    # dense latent attention: no indexer, no sort, no conditional; a chunk's float32 logits span
+    # the slot's whole table whatever its context (ROADMAP M5: the kernel that reads what is written)
+    assert got["dsa_scopes"] == [] and got["dsa_sorts"] == 0 and got["select_conds"] == [], got
+    assert got["logit_widths"] == logits and got["paged_decode_in_scope"] == 0, got
+    assert got["scopes"] == ["dtx.mla_absorb", "dtx.moe_shared"], got
+
 
 @pytest.mark.slow
 def test_aot_pipeline_compiles_for_v5e_target():
