@@ -704,7 +704,7 @@ def child_glm(rehearsal: bool) -> int:
     tokens they read (latent attention with a learned indexer, ops/dsa.py).
     First ``preset:debug-glm`` with two adapters on ``q_b_proj`` / ``o_proj``,
     eight requests over three slots, the last a prompt of 250 tokens whose
-    chunks cross every step of 32 lanes of its view (ops/dsa.py:view_steps) up
+    chunks cross every step of 32 lanes of its view (ops/mla.py:view_steps) up
     to 256 of the table's 288; then (not in the CPU rehearsal), at the
     published widths: the selection's own ops on scores with ties at the cell's
     shapes against the reference's stable sort, EXACTLY; and the benchmark's
@@ -785,7 +785,7 @@ def _glm_cell_check(verdict, cell_name: str) -> None:
 
     from datatunerx_tpu.models import forward
     from datatunerx_tpu.models import hybrid
-    from datatunerx_tpu.ops import dsa
+    from datatunerx_tpu.ops import dsa, mla
     from datatunerx_tpu.ops.paged_attention import init_paged_cache
 
     cell = spec.load_cell(cell_name)
@@ -853,7 +853,7 @@ def _glm_cell_check(verdict, cell_name: str) -> None:
                           jnp.arange(lo, hi, dtype=jnp.int32)[None])
         jax.effects_barrier()
         # a chunk that reaches no further than the selection views one step, reads every
-        # visible token and never runs the indexer (ops/dsa.py:view_steps): nothing to spy
+        # visible token and never runs the indexer (ops/mla.py:view_steps): nothing to spy
         assert len(seen) == (2 if hi > K else 0), (lo, hi, len(seen))
         for j, t in enumerate(range(lo, hi)):
             sets[t] = {frozenset(np.flatnonzero(mask[0, j])) for mask in seen}
@@ -865,7 +865,7 @@ def _glm_cell_check(verdict, cell_name: str) -> None:
     dsa.top_lanes, dsa.top_mask, hybrid.xla_attention = real_lanes, real_mask, real_attention
     chosen = np.asarray(reference.sequence_selected(params, mc, toks))
     under = all(not sets[t] and chosen[:, t, :t + 1].all() for t in range(K))
-    views = [[dsa.view_lanes(lo, hi - lo, K, bs, blocks)] if hi - lo > 1 else [] for lo, hi in spans]
+    views = [[mla.view_lanes(lo, hi - lo, K, bs, blocks)] if hi - lo > 1 else [] for lo, hi in spans]
     verdict(f"glm/cell/selected_sets[context <= {K}]", under and widths == views,
             f"{K} rows in chunks that reach no further than the selection: every visible token, no indexer "
             f"run; lanes a chunk's attention read, of {blocks * bs}: {sorted({w for v in views for w in v})}")
@@ -916,8 +916,10 @@ def child_kimi(rehearsal: bool) -> int:
     benchmarks/reference/kimi_k2.py as logits, and the admissions' paths. Then
     (not in the CPU rehearsal), at the published widths of the benchmark's
     configuration cut to its first two layers: YaRN's tables against the closed
-    form in float64; one slot through a paged cache to 12k tokens, the token
-    steps at contexts of 1k, 4k and 12k against the reference's full forward;
+    form in float64; one slot through a paged cache to 12k tokens, the chunks
+    that end at contexts of 1k, 4k and 12k (viewing the first, the fourth and
+    the last of the table's twelve widths) and the token steps after them against
+    the reference's full forward;
     and a turn served through shared blocks against the same turn served cold."""
     import tempfile
 
@@ -990,6 +992,7 @@ def _kimi_cell_check(verdict, cell_name: str) -> None:
 
     from datatunerx_tpu.models import forward
     from datatunerx_tpu.models.config import PRESETS, mixer_kinds
+    from datatunerx_tpu.ops import mla
     from datatunerx_tpu.ops.paged_attention import init_paged_cache
     from datatunerx_tpu.ops.rope import rope_cos_sin, yarn_mscale
     from datatunerx_tpu.serving.batched_engine import BatchedEngine
@@ -1037,26 +1040,36 @@ def _kimi_cell_check(verdict, cell_name: str) -> None:
     cache = init_paged_cache(cfg, 1, blocks + 48, bs, blocks, dtype=jnp.bfloat16)
     table = np.random.default_rng(3).permutation(blocks + 48)[:blocks]
     cache["block_tables"] = jnp.asarray(table[None], jnp.int32)
-    last = {}
+    last, viewed = {}, {}
     for lo, hi in spans:
         out, cache = step(params, jnp.asarray([toks[lo:hi]], jnp.int32), cache,
                           jnp.arange(lo, hi, dtype=jnp.int32)[None])
         if hi - lo == 1:
             last[lo] = out[0, 0]
+        elif hi in marks:  # the chunk that ends at a mark: its last rows, and the lanes it viewed
+            last.update({hi - steps + j: out[0, hi - lo - steps + j] for j in range(steps)})
+            viewed[hi] = mla.view_lanes(lo, hi - lo, kind.index_topk, bs, blocks)
     rows = sorted(last)
     ref = reference.sequence_logits(params, mc, toks, rows)
     rms = lambda a: float(jnp.sqrt(jnp.mean(a * a)))  # noqa: E731
-    for mark in marks:
-        pick = [rows.index(mark + j) for j in range(steps)]
-        got = jnp.stack([last[mark + j] for j in range(steps)]).astype(jnp.float32)
+    # a chunk's view is as wide as its context reaches (ops/mla.py:view_steps): the chunks that end
+    # at the marks view the first, the fourth and the last of the table's twelve widths
+    for name, mark in [("chunk", mark) for mark in marks] + [("decode", mark) for mark in marks]:
+        first = mark - steps if name == "chunk" else mark  # a chunk's last rows; the token steps after it
+        pick = [rows.index(first + j) for j in range(steps)]
+        got = jnp.stack([last[first + j] for j in range(steps)]).astype(jnp.float32)
         want = ref[jnp.asarray(pick)]
         noise = rms(got - want) / rms(want - jnp.mean(want))
         agree = float(jnp.mean(jnp.argmax(got, -1) == jnp.argmax(want, -1)))
+        sound, view = noise <= 0.03, ""
+        if name == "chunk":
+            sound &= viewed[mark] == -(-mark // mla.VIEW_STEP_LANES) * mla.VIEW_STEP_LANES
+            view = f", a view of {viewed[mark]} of {blocks * bs} lanes"
         # bf16 weights, activations and cached rows against float32: cell 7's steps without their
         # selection read 0.010 of the logits' spread
-        verdict(f"kimi/cell/decode_logits[context {mark}]", noise <= 0.03,
+        verdict(f"kimi/cell/{name}_logits[context {mark}]", sound,
                 f"rms (logit - reference) over the logits' spread {noise:.4f}, the same first token "
-                f"in {agree:.2f} of {steps} steps x {cfg.vocab_size} logits")
+                f"in {agree:.2f} of {steps} rows x {cfg.vocab_size} logits{view}")
     del cache, params
 
     # a turn served through shared blocks against the same turn served cold

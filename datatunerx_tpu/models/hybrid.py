@@ -93,8 +93,8 @@ and every program converts the whole pool on its way in and out; 8 x 192 =
 1536 lanes pad nothing). ``len``, ``pos`` and ``block_tables`` are shared.
 Over a paged cache a window layer reads a window-wide view of each slot's
 table (ops/paged_attention.py:window_tables), a global layer the full width,
-a selecting latent layer's chunk as far as its longest row reaches, in whole
-steps of ``index_topk`` lanes (ops/dsa.py:view_steps). A TOKEN step of an
+a latent layer's chunk as far as its longest row reaches, in whole steps of
+the kind's own width (ops/mla.py:view_steps). A TOKEN step of an
 engine that asked for the paged kernels gathers no view of a softmax-attention
 kind the decode kernel can express (``in_place_kinds``: no sink, pool rows of
 whole lane tiles): it scatters the token's row and the kernel reads the kind's
@@ -477,15 +477,16 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
             views[name] = _View(cache, kind, positions, kv_pos_full, cache_pos, T,
                                 in_place=name in in_place)
             bias[name] = views[name].bias
-    # a selecting kind's step of several tokens views a paged cache as far as
-    # its longest row reaches, in whole steps (ops/dsa.py:view_steps): the
+    # a latent kind's step of several tokens views a paged cache as far as
+    # its longest row reaches, in whole steps (ops/mla.py:view_steps): the
     # widths it may take, in table columns, and which of them this step takes
     # (traced; a reach past the table takes the last); None where the view
-    # stays the table's
+    # stays the table's. ``len`` is the slot's LANE cursor: under the prefix
+    # cache a suffix whose pads lie mid-row still views every lane written
     reach = None
-    if cache is not None and "block_tables" in cache and "k_idx" in cache:
+    if cache is not None and "block_tables" in cache and "k_mla" in cache:
         columns, block_size = cache["block_tables"].shape[1], cache["pos"].shape[1]
-        widths = dsa.view_steps(T, columns, block_size, kinds["mla"].index_topk)
+        widths = mla.view_steps(T, columns, block_size, kinds["mla"].index_topk)
         if widths:
             reach = (widths, block_size,
                      (jnp.max(cache["len"]) + T - 1) // (widths[0] * block_size))
@@ -584,10 +585,11 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None,
                 if kind.index_topk:
                     leaves = (pool, view.step.write(
                         leaves[1], li, k_idx.astype(leaves[1].dtype)))
-        # a selecting chunk's view is as wide as its context reaches, a branch
-        # of static width a count of steps; every other step's is the table's
+        # a chunk's view is as wide as its context reaches, a branch of static
+        # width a count of steps; every other step's is the table's
         widths, block_size, taken = reach or ((), 0, None)
-        if widths or dsa.selection_path(T, bias["mla"].shape[-1], kind.index_topk) != "all":
+        if kind.index_topk and (
+                widths or dsa.selection_path(T, bias["mla"].shape[-1], kind.index_topk) != "all"):
             with jax.named_scope("dtx.dsa_index"):
                 q_idx = lead_rotated(_proj(c_q, ip["wq_b"], None, 0.0).reshape(
                     B, T, kind.index_heads, kind.index_dim))

@@ -410,12 +410,12 @@ def export_moe_stats(registry: Registry, engine) -> None:
                      "by phase."))}
     lanes = {name: registry.counter(f"dtx_serving_dsa_prefill_{name}_lanes_total", text)
              for name, text in (
-        ("view", "Lanes the prefill chunks of a selecting model viewed: as far "
-                 "as the slot's context reached, in whole steps of index_topk "
-                 "lanes, summed over chunks."),
+        ("view", "Lanes the prefill chunks of a model with latent attention "
+                 "viewed: as far as the slot's context reached, in whole steps "
+                 "(index_topk lanes where it selects), summed over chunks."),
         ("table", "Lanes of the slot's whole table, summed over the same "
                   "chunks: view over table is the share of the table a chunk "
-                  "scored, ranked and attended over."))}
+                  "attended (and a selecting one scored and ranked) over."))}
     index_pool = registry.gauge(
         "dtx_serving_index_pool_bytes",
         "Bytes of the index-key pool a selecting model keeps beside its "
@@ -439,6 +439,8 @@ def export_moe_stats(registry: Registry, engine) -> None:
         for phase in ("decode", "prefill"):
             for name, gauge in dsa.items():
                 gauge.set(engine.dsa_stats[f"{phase}_{name}"], {"phase": phase})
+    step_fn = getattr(engine, "prefill_view_step", None)
+    if callable(step_fn) and step_fn():  # its chunks take a stepped view
         for name, counter in lanes.items():
             counter.set(engine.dsa_stats[f"prefill_{name}_lanes"])
     for phase, (kernel, tm) in (getattr(engine, "moe_kernel", None) or {}).items():

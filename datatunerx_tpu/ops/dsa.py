@@ -26,15 +26,12 @@ rows out of the latent pool through the block table and attends to those,
 never building the table-wide view of latent rows; a chunk of several tokens
 keeps the view ``xla_attention`` takes and masks it a row at a time.
 
-A chunk's view is as wide as its context REACHES, not as its table: a slot's
-lanes are in the order its tokens were written, so every lane from ``len + T``
-on holds nothing and the causal bias hides it. The reach is rounded up to whole
-STEPS of the selection's own width (``index_topk`` lanes in whole blocks:
-``view_steps``), one branch of static width a count of steps inside the one
-program (``lax.switch``), and each branch asks ``selection_path`` for its own
-width: the first, no wider than the selection, is plain latent attention and
-never runs the indexer. The branches read the pools as operands and return
-the attention's output alone.
+A chunk's view is as wide as its context REACHES, not as its table (the rule
+is the latent kind's, ``ops/mla.py:view_steps``; a kind that selects steps by
+its ``index_topk``): one branch of static width a count of steps inside the
+one program, and each branch asks ``selection_path`` for its own width: the
+first, no wider than the selection, is plain latent attention and never runs
+the indexer.
 """
 
 from __future__ import annotations
@@ -57,30 +54,6 @@ def selection_path(tokens: int, view_width: int, index_topk: int) -> str:
     if not index_topk or view_width <= index_topk:
         return "all"
     return "gather" if tokens == 1 else "mask"
-
-
-def view_steps(tokens: int, columns: int, block_size: int, index_topk: int) -> tuple:
-    """The widths, in table columns, that a step of ``tokens`` tokens a row may
-    give its view of a block table of ``columns`` columns, one a count of
-    steps read: whole steps of ``index_topk`` lanes (in whole blocks, at least
-    one), the last cut at the table. ``()`` where the step keeps the
-    table-wide view: a token step (it gathers its picks), a kind that does
-    not select, a table of one step."""
-    step = max(1, index_topk // block_size)
-    if tokens == 1 or not index_topk or columns <= step:
-        return ()
-    return tuple(min(n, columns) for n in range(step, columns + step, step))
-
-
-def view_lanes(cursor: int, tokens: int, index_topk: int, block_size: int, columns: int) -> int:
-    """Lanes of the view of one row's step of ``tokens`` tokens at linear
-    cursor ``cursor``: what the scheduler counts, by the program's own rule
-    (its reach ``cursor + tokens`` in whole steps, cut at the table)."""
-    steps = view_steps(tokens, columns, block_size, index_topk)
-    if not steps:
-        return columns * block_size
-    step = steps[0] * block_size
-    return min(-(-(cursor + tokens) // step) * step, columns * block_size)
 
 
 def key_norm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray) -> jnp.ndarray:

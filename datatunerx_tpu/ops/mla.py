@@ -16,11 +16,56 @@ the width the model's own heads have (nope + rope), not the latent's.
 
 RoPE here is INTERLEAVED: the pairs are lanes ``(2i, 2i + 1)``, not the two
 halves of the head (ops/rope.py's convention).
+
+Over a paged cache a step of several tokens views its slot's table as far as
+its context REACHES, not as wide as the table: a slot's lanes are in the order
+its tokens were written, so every lane from ``len + T`` on holds nothing and
+the causal bias hides it, and scoring, normalising and weighing such lanes is
+work thrown away. The reach is rounded up to whole STEPS of the kind's own
+width (``view_steps``: ``index_topk`` lanes where the kind selects the tokens
+it reads, ops/dsa.py, else ``VIEW_STEP_LANES``; in whole blocks), one branch
+of static width a count of steps inside the one program (``lax.switch``,
+models/hybrid.py): one softmax a branch, the hidden lanes of its last step
+contributing exact zeros. The branches read the pools as operands and return
+the attention's output alone. A token step keeps the table-wide view: its
+slots share one program and the longest context decides.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
+
+# lanes a step of the view of a kind that does NOT select (Kimi's table of 12,288
+# lanes: twelve widths; Ling's of 1,536: two). On a v5e Kimi's 256-token chunk
+# program reads the same at equal widths under steps of 1,024 and of 2,048
+# (16.19 / 16.35 ms at 2,048 lanes, 31.16 / 31.38 at the table's) and 1.5 ms
+# less a 1,024 lanes not viewed: over cell 8's cursors 20.30 against 21.23 ms
+# a chunk, the table-wide view 31.12 (PERF.md §6, PR 46)
+VIEW_STEP_LANES = 1024
+
+
+def view_steps(tokens: int, columns: int, block_size: int, index_topk: int) -> tuple:
+    """The widths, in table columns, that a latent kind's step of ``tokens``
+    tokens a row may give its view of a block table of ``columns`` columns,
+    one a count of steps read: whole steps of ``index_topk`` lanes (of
+    ``VIEW_STEP_LANES`` where that is 0: a kind that does not select; in
+    whole blocks, at least one), the last cut at the table. ``()`` where the
+    step keeps the table-wide view: a token step, a table of one step."""
+    step = max(1, (index_topk or VIEW_STEP_LANES) // block_size)
+    if tokens == 1 or columns <= step:
+        return ()
+    return tuple(min(n, columns) for n in range(step, columns + step, step))
+
+
+def view_lanes(cursor: int, tokens: int, index_topk: int, block_size: int, columns: int) -> int:
+    """Lanes of the view of one row's step of ``tokens`` tokens at lane
+    cursor ``cursor``: what the scheduler counts, by the program's own rule
+    (its reach ``cursor + tokens`` in whole steps, cut at the table)."""
+    steps = view_steps(tokens, columns, block_size, index_topk)
+    if not steps:
+        return columns * block_size
+    step = steps[0] * block_size
+    return min(-(-(cursor + tokens) // step) * step, columns * block_size)
 
 
 def rope_interleaved(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:

@@ -60,7 +60,7 @@ from datatunerx_tpu.obs.metrics import (
 from datatunerx_tpu.obs.trace import TraceStore, build_request_span
 from datatunerx_tpu.models.llama import forward, init_cache
 from datatunerx_tpu.models.lora import LORA_TARGETS, lora_scaling
-from datatunerx_tpu.ops import dsa
+from datatunerx_tpu.ops import mla
 from datatunerx_tpu.ops._pallas import interpret_default
 from datatunerx_tpu.ops.paged_attention import (
     init_paged_cache,
@@ -887,9 +887,9 @@ class BatchedEngine:
         self.dsa_stats = {f"{phase}_{name}": 0
                           for phase in ("decode", "prefill")
                           for name in DSA_STAT_NAMES}
-        # and, counted here at dispatch, the lanes a selecting model's prefill
-        # chunks viewed (as far as the slot's context reached, in whole steps)
-        # beside the lanes of the table they would have viewed
+        # and, counted here at dispatch, the lanes the prefill chunks of a model
+        # with a latent kind viewed (as far as the slot's context reached, in
+        # whole steps) beside the lanes of the table they would have viewed
         self.dsa_stats.update(prefill_view_lanes=0, prefill_table_lanes=0)
         self._counters_seen = {}  # device counters at the last read, by leaf
         self._slot_cursor = None  # each slot's linear cursor then
@@ -1189,8 +1189,8 @@ class BatchedEngine:
             "state_bytes": self.state_bytes(),
             "index_topk": self.cfg.index_topk,
             "index_pool_bytes": self.index_pool_bytes(),
-            # lanes a selecting model's prefill chunk adds to its view a
-            # step of its context (0: every chunk views its whole table)
+            # lanes a latent kind's prefill chunk adds to its view a step of
+            # its context (0: every chunk views its whole table)
             "prefill_view_step": self.prefill_view_step(),
         }
         print("[engine] " + json.dumps(self.engine_line, sort_keys=True),
@@ -1297,11 +1297,13 @@ class BatchedEngine:
         return 0 if leaf is None else math.prod(leaf.shape) * leaf.dtype.itemsize
 
     def prefill_view_step(self) -> int:
-        """Lanes of one step of a prefill chunk's view (``ops/dsa.py:
-        view_steps``), 0 where a chunk views its whole table."""
-        if "k_idx" not in self._cache or not self.paged:
+        """Lanes of one step of a prefill chunk's view of a latent kind's
+        pool (``ops/mla.py:view_steps``: the kind's ``index_topk`` where it
+        selects, the module's constant where it does not), 0 where a chunk
+        views its whole table."""
+        if "k_mla" not in self._cache or not self.paged:
             return 0
-        steps = dsa.view_steps(self.prefill_chunk, self._cache["block_tables"].shape[1],
+        steps = mla.view_steps(self.prefill_chunk, self._cache["block_tables"].shape[1],
                                self.block_size, self.cfg.index_topk)
         return steps[0] * self.block_size if steps else 0
 
@@ -1316,14 +1318,16 @@ class BatchedEngine:
                 "dsa_selected": self.dsa_stats["decode_selected"]}
 
     def _dsa_chunk_marks(self, cursor: int, tokens: int) -> dict:
-        """Keywords of the chunk span of a model that selects, counted as the
-        chunk is dispatched: the lanes its program views at linear cursor
-        ``cursor`` (``ops/dsa.py:view_lanes``, the program's own rule) and
-        the lanes of the slot's table."""
-        if "dsa_stats" not in self._cache:
+        """Keywords of the chunk span of a model whose chunks take a stepped
+        view (``prefill_view_step``), counted as the chunk is dispatched: the
+        lanes its program views at the slot's LANE cursor ``cursor``
+        (``ops/mla.py:view_lanes``, the program's own rule: under the prefix
+        cache a suffix starts at its shared base, pads included) and the
+        lanes of the slot's table."""
+        if not self.prefill_view_step():
             return {}
         columns = self._cache["block_tables"].shape[1]
-        view = dsa.view_lanes(cursor, tokens, self.cfg.index_topk,
+        view = mla.view_lanes(cursor, tokens, self.cfg.index_topk,
                               self.block_size, columns)
         self.dsa_stats["prefill_view_lanes"] += view
         self.dsa_stats["prefill_table_lanes"] += columns * self.block_size
@@ -1370,8 +1374,13 @@ class BatchedEngine:
         nothing else remembers the row: a window kind's view is placed by the
         slot's linear cursor (pads at the row's left), a selecting kind sizes
         its chunk's view and its counters by that cursor, a recurrent state
-        cannot be rewound to a shared prefix. Entries are blocks of the
-        kinds' pools (``kv_overcommit`` on), never dense rows."""
+        cannot be rewound to a shared prefix. A latent kind that reads ALL it
+        sees is taken, though its chunk's view is sized by a cursor too
+        (``ops/mla.py:view_steps``): that is the slot's LANE cursor
+        (``cache["len"]``, a suffix's pads mid-row included), so the view
+        holds every lane written, nothing picks among them, and the lanes
+        past them are hidden by position as the table's are. Entries are
+        blocks of the kinds' pools (``kv_overcommit`` on), never dense rows."""
         from datatunerx_tpu.models.config import mixer_kinds
 
         why = {}

@@ -453,8 +453,8 @@ for name, lower in cases.items():
                                 if s in text],
                  "pool_copies": len(re.findall(pool_leaf + r"[^ ]* copy\(", text))
                  + len(re.findall(idx_leaf + r"[^ ]* copy\(", text)),
-                 # a selecting chunk: one conditional a run of layers, a branch of static width
-                 # a count of steps; the widths of its attention's float32 logits
+                 # a latent kind's chunk: one conditional a run of layers, a branch of static
+                 # width a count of steps; the widths of its attention's float32 logits
                  "select_conds": [len(m.split(",")) for m in re.findall(
                      r" conditional\(.*branch_computations=\{([^}]*)\}.*dtx\.layers", text)],
                  "logit_widths": sorted({int(w) for w in re.findall(
@@ -482,6 +482,9 @@ def test_ling_cell_programs_compile_for_v5e_at_published_widths(program, ling_do
     assert got["gmm"] >= 4 and got["ragged"] == 0, got
     assert got["scopes"] == ["dtx.kda_conv", "dtx.kda_state", "dtx.kda_out",
                              "dtx.mla_absorb", "dtx.moe_shared"], got
+    # the one latent layer's chunk attends over the lanes its context reaches (ops/mla.py:view_steps):
+    # a step of 1,024 lanes or the table's 1,536; the token step reads its table-wide view
+    assert got["logit_widths"] == ([1024, 1536] if program == "prefill_chunk_256" else []), got
 
 
 @pytest.fixture(scope="module")
@@ -572,7 +575,8 @@ def kimi_doc():
 
 @pytest.mark.parametrize("program,temporaries,logits", [
     ("decode", 0.6e9, []),                     # read: 8.891 + 0.572 GB
-    ("prefill_chunk_256", 0.85e9, [12288])])   # read: 8.889 + 0.829 GB
+    # read: 8.889 + 0.824 GB; 0.829 before the chunk's view followed its reach (its widest branch)
+    ("prefill_chunk_256", 0.85e9, list(range(1024, 13312, 1024)))])
 def test_kimi_cell_programs_compile_for_v5e_at_published_widths(program, temporaries, logits, kimi_doc):
     """Five layers at the published widths, 16 slots of 12,288 tokens over a
     pool of 18,432 blocks: the numbers quoted in
@@ -587,9 +591,14 @@ def test_kimi_cell_programs_compile_for_v5e_at_published_widths(program, tempora
     # (2 copies each, temporaries 2.268 and 1.936 GB: compiled once with ``MlaKind.pools`` patched)
     assert got["pool_copies"] == 0, got
     assert got["gmm"] >= 2 and got["ragged"] == 0, got
-    # dense latent attention: no indexer, no sort, no conditional; a chunk's float32 logits span
-    # the slot's whole table whatever its context (ROADMAP M5: the kernel that reads what is written)
-    assert got["dsa_scopes"] == [] and got["dsa_sorts"] == 0 and got["select_conds"] == [], got
+    # dense latent attention: no indexer, no sort. A chunk attends over the lanes its context
+    # reaches: ONE conditional a run of layers, a branch of static width a count of steps of
+    # 1,024 lanes (ops/mla.py:view_steps), each plain latent attention over its width; the
+    # branches read the pool as an operand (no copy above) and return the attention's output,
+    # so the temporaries are the widest branch's, the table-wide chunk's. The token step: no
+    # conditional, and no float32 logits a chunk wide
+    assert got["dsa_scopes"] == [] and got["dsa_sorts"] == 0, got
+    assert got["select_conds"] == ([12, 12] if logits else []), got
     assert got["logit_widths"] == logits and got["paged_decode_in_scope"] == 0, got
     assert got["scopes"] == ["dtx.mla_absorb", "dtx.moe_shared"], got
 

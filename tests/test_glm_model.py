@@ -28,7 +28,7 @@ from datatunerx_tpu.models import forward, get_config, init_params  # noqa: E402
 from datatunerx_tpu.models import hybrid  # noqa: E402
 from datatunerx_tpu.models.config import layer_runs, mixer_kinds  # noqa: E402
 from datatunerx_tpu.models.llama import init_cache  # noqa: E402
-from datatunerx_tpu.ops import dsa  # noqa: E402
+from datatunerx_tpu.ops import dsa, mla  # noqa: E402
 from datatunerx_tpu.ops.paged_attention import (  # noqa: E402
     init_paged_cache,
     kv_leaf_keys,
@@ -124,7 +124,7 @@ def test_the_mask_without_a_sort_is_the_sorts_set_on_any_float(seed):
 @pytest.mark.parametrize("tokens,width,topk,path", [
     (1, 8704, 2048, "gather"), (256, 8704, 2048, "mask"), (1, 2048, 2048, "all"),
     (256, 1024, 2048, "all"), (1, 2064, 2048, "gather"), (150, 150, 32, "mask"),
-    # the widths a chunk of cell 7 reaches (dsa.view_steps): only the first is within the selection
+    # the widths a chunk of cell 7 reaches (mla.view_steps): only the first is within the selection
     (256, 2048, 2048, "all"), (256, 4096, 2048, "mask"), (64, 6144, 2048, "mask"), (192, 8192, 2048, "mask")])
 def test_a_steps_path_follows_from_its_shapes(tokens, width, topk, path):
     assert dsa.selection_path(tokens, width, topk) == path
@@ -139,13 +139,20 @@ def test_a_steps_path_follows_from_its_shapes(tokens, width, topk, path):
     (256, 128, 16, 2048, ()),             # a table of one step: as before
     (256, 64, 16, 2048, ()),
     (1, 544, 16, 2048, ()),               # a token step gathers its picks
-    (256, 544, 16, 0, ()),                # no indexer
+    # no indexer (0): a latent kind that reads all it sees steps by the module's constant, 1,024 lanes
+    (256, 544, 16, 0, (64, 128, 192, 256, 320, 384, 448, 512, 544)),
+    (256, 768, 16, 0, tuple(range(64, 832, 64))),   # cell 8: Kimi's table of 12,288 lanes, twelve widths
+    (64, 768, 16, 0, tuple(range(64, 832, 64))),
+    (256, 96, 16, 0, (64, 96)),           # cell 5: Ling's table of 1,536 lanes, a step and a half
+    (256, 64, 16, 0, ()),                 # a table of one step
+    (256, 64, 8, 0, ()),                  # debug-kimi's 512 lanes
+    (1, 768, 16, 0, ()),                  # one token a row: sixteen slots share the program, the longest decides
     (64, 24, 8, 32, (4, 8, 12, 16, 20, 24)),    # debug-glm, the tests' table
     (8, 10, 16, 40, (2, 4, 6, 8, 10)),          # a step is whole blocks: 32 lanes of 40
     (8, 5, 16, 8, (1, 2, 3, 4, 5))])            # and at least one
 def test_a_chunks_view_grows_in_steps_of_the_selection(tokens, columns, block_size, topk, steps):
     """The widths, in table columns, a chunk's view may take."""
-    assert dsa.view_steps(tokens, columns, block_size, topk) == steps
+    assert mla.view_steps(tokens, columns, block_size, topk) == steps
 
 
 @pytest.mark.parametrize("cursor,tokens,lanes", [
@@ -157,12 +164,30 @@ def test_the_views_width_is_its_reach_in_whole_steps(cursor, tokens, lanes):
     """``len + T`` rounded up to whole steps of 2,048 lanes, cut at the
     table: what the scheduler counts is the branch the program's switch
     takes."""
-    assert dsa.view_lanes(cursor, tokens, 2048, 16, 544) == lanes
-    steps = dsa.view_steps(tokens, 544, 16, 2048)
+    assert mla.view_lanes(cursor, tokens, 2048, 16, 544) == lanes
+    steps = mla.view_steps(tokens, 544, 16, 2048)
     if steps:  # the program's branch, from a traced reach (models/hybrid.py); lax.switch holds it to the last
         taken = int(jnp.clip((jnp.asarray(cursor + tokens) - 1) // 2048, 0, len(steps) - 1))
         assert steps[taken] * 16 == lanes
-    assert dsa.view_lanes(cursor, tokens, 0, 16, 544) == 8704  # a kind that does not select
+    # a kind that does not select steps by the module's constant, half as many lanes
+    assert mla.VIEW_STEP_LANES == 1024 and mla.view_lanes(cursor, tokens, 0, 16, 544) in (lanes, lanes - 1024)
+
+
+@pytest.mark.parametrize("cursor,tokens,lanes", [
+    (0, 256, 1024), (768, 256, 1024), (769, 256, 2048), (1792, 256, 2048), (1793, 256, 3072), (6144, 256, 7168),
+    (7936, 256, 8192), (7937, 256, 9216), (10240, 256, 11264), (12032, 256, 12288), (12288, 256, 12288),  # past the table
+    # a suffix under the prefix cache starts at its shared base, a lane cursor with its pads: three chunks
+    (8448, 256, 9216), (8704, 256, 9216), (8960, 256, 9216), (10176, 64, 10240), (10177, 64, 11264),
+    (0, 1, 12288), (9000, 1, 12288)])                          # a token step views no less
+def test_a_kind_that_reads_all_it_sees_views_its_reach_in_steps_of_its_own(cursor, tokens, lanes):
+    """Cell 8's table (768 columns of 16) under a kind with no indexer: the
+    scheduler's count and the branch the program's switch takes."""
+    assert mla.view_lanes(cursor, tokens, 0, 16, 768) == lanes
+    steps = mla.view_steps(tokens, 768, 16, 0)
+    if steps:
+        taken = int(jnp.clip((jnp.asarray(cursor + tokens) - 1) // mla.VIEW_STEP_LANES, 0, len(steps) - 1))
+        assert steps[taken] * 16 == lanes
+    assert mla.view_lanes(cursor, tokens, 0, 16, 96) == (1024 if tokens > 1 and cursor + tokens <= 1024 else 1536)  # Ling's
 
 
 @pytest.mark.parametrize("prompt,chunk,share", [
@@ -172,7 +197,7 @@ def test_a_prompts_chunks_view_a_share_of_the_table_that_follows_from_its_length
     7's table: the lanes its chunks view over the lanes of as many tables, as
     the engine's counter adds them up; within the selection it is one step's."""
     cuts = list(range(0, prompt, chunk)) + [prompt]
-    views = [dsa.view_lanes(lo, hi - lo, 2048, 16, 544) for lo, hi in zip(cuts, cuts[1:])]
+    views = [mla.view_lanes(lo, hi - lo, 2048, 16, 544) for lo, hi in zip(cuts, cuts[1:])]
     assert all(v in (2048, 4096, 6144, 8192) for v in views) and views == sorted(views)
     assert views[0] == 2048 and views[-1] == min(-(-prompt // 2048) * 2048, 8704)
     assert round(sum(views) / (len(views) * 8704), 4) == share
@@ -405,7 +430,7 @@ def test_a_chunk_views_what_its_context_reaches(model, want, want_sets, picks, m
             jax.effects_barrier()
             reach = hi + n_pad
             if stepped:  # each of the five layers read as far as the chunk reached, in whole steps
-                assert read == [dsa.view_lanes(lo, reach - lo, TOPK, block_size, nbps)] * 5 == [-(-reach // 32) * 32] * 5
+                assert read == [mla.view_lanes(lo, reach - lo, TOPK, block_size, nbps)] * 5 == [-(-reach // 32) * 32] * 5
             else:
                 assert read == [nbps * block_size] * 5
             # the indexer ran over as many lanes as attention read (the whole view on the
@@ -427,7 +452,7 @@ def test_a_chunk_views_what_its_context_reaches(model, want, want_sets, picks, m
         for b in range(2):  # no indexer: every visible token, nothing to compare
             assert per_row[b] == (want_sets[b][t] if t >= cuts[1] or edge > 0 else set()), (b, t)
     # the table-wide path (as the step was before its view followed its reach): the same
-    monkeypatch.setattr(dsa, "view_steps", lambda *a: ())
+    monkeypatch.setattr(mla, "view_steps", lambda *a: ())
     wide, wide_sets = prefill(False)
     np.testing.assert_allclose(got, wide, atol=TOL)
     assert all(a[b] in (set(), w[b]) for a, w in zip(got_sets, wide_sets) for b in range(2))
@@ -780,7 +805,7 @@ def test_the_engine_line_and_metrics_name_the_selection(engine, capfd):
         engine._phase = real_phase
     chunks = [d for name, d in chunks if name == "dtx_engine_prefill_chunk"]
     assert [(d["tokens"], d["view"], d["table"]) for d in chunks] == [(64, 64, 256), (64, 128, 256), (64, 192, 256)]
-    assert all(d["view"] == dsa.view_lanes(64 * i, 64, TOPK, 8, 32) for i, d in enumerate(chunks))
+    assert all(d["view"] == mla.view_lanes(64 * i, 64, TOPK, 8, 32) for i, d in enumerate(chunks))
     assert [d for name, d in spans if name == "dtx_engine_prefill_chunk"] == [
         {"tokens": 64, "slot": chunks[0]["slot"], "view": 64, "table": 256}]
     assert engine.dsa_stats["prefill_view_lanes"] - before["prefill_view_lanes"] == 64 + 128 + 192

@@ -24,9 +24,9 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
 from reference import kimi_k2 as ref  # noqa: E402
 
-from datatunerx_tpu.models import forward, get_config, init_params  # noqa: E402
+from datatunerx_tpu.models import forward, get_config, hybrid, init_params  # noqa: E402
 from datatunerx_tpu.models.config import YarnScaling, layer_runs, mixer_kinds  # noqa: E402
-from datatunerx_tpu.ops import moe  # noqa: E402
+from datatunerx_tpu.ops import mla, moe  # noqa: E402
 from datatunerx_tpu.ops.paged_attention import init_paged_cache, kv_leaf_keys  # noqa: E402
 from datatunerx_tpu.ops.rope import rope_cos_sin, yarn_mscale, yarn_ramp  # noqa: E402
 
@@ -39,6 +39,16 @@ def _drop_compiled_programs():
     jax.clear_caches()
 
 
+@pytest.fixture(autouse=True)
+def _the_modules_step(request, monkeypatch):
+    """``engines`` holds the step of a chunk's view at 32 lanes for as long as
+    its second pair lives; a test that does not use the pair runs at the
+    module's own, wherever the order puts it."""
+    if "engines" not in request.fixturenames:
+        monkeypatch.setattr(mla, "VIEW_STEP_LANES", MODULE_STEP)
+
+
+MODULE_STEP = mla.VIEW_STEP_LANES
 TOL = 2e-5  # float32 program against float32 reference: rounding order only
 T = 150
 PUBLISHED = YarnScaling(factor=64.0, original_max_len=4096, beta_fast=32.0, beta_slow=1.0,
@@ -182,6 +192,69 @@ def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want):
     assert float(jnp.abs(cache["k_mla"][..., 40:]).max()) == 0.0
 
 
+STEP = 32  # lanes a step of a chunk's view in these tests (the module's constant is 1,024; debug-kimi's tables hold 512)
+
+
+@pytest.mark.parametrize("edge", [-1, 0, 1])
+def test_a_chunk_views_what_its_context_reaches(model, want, monkeypatch, edge):
+    """Chunks whose reach (``len + T``) sits one lane before, on and one lane
+    past each step of 32 lanes, the last one left-padded and reaching past the
+    table's last whole step (152 lanes: four steps and three quarters), in a table
+    whose columns past the prompt have no block, then eight token steps: the
+    lanes every layer reads follow the reach (a token step's stay the
+    table's), and the logits are the reference's and the table-wide view's."""
+    cfg, _, params, tokens = model
+    block_size, nbps, pad, n = 8, 19, 2, T - 8
+    cuts = [0] + [STEP * k + edge for k in range(1, 5)] + [n]
+    table = np.full((2, nbps), -1, np.int32)
+    held = -(-(T + pad) // block_size)
+    table[:, :held] = np.random.default_rng(edge + 1).permutation(2 * nbps)[:2 * held].reshape(2, held)
+    read, real_attention = [], hybrid.xla_attention  # the lanes each layer's attention read
+
+    def spy_attention(q, k, v, bias, **kw):
+        jax.debug.callback(lambda _: read.append(k.shape[1]), q[0, 0, 0, 0])  # the branch taken speaks
+        return real_attention(q, k, v, bias, **kw)
+
+    monkeypatch.setattr(hybrid, "xla_attention", spy_attention)
+    monkeypatch.setattr(mla, "VIEW_STEP_LANES", STEP)
+    assert mla.view_steps(64, nbps, block_size, 0) == (4, 8, 12, 16, 19)
+
+    def serve(stepped):
+        cache = init_paged_cache(cfg, 2, 2 * nbps, block_size, nbps, dtype=jnp.float32)
+        cache["block_tables"] = jnp.asarray(table)
+        cache["k_mla"] = cache["k_mla"] - 7.0  # what earlier requests left in the pool
+        outs = []
+        for lo, hi in zip(cuts, cuts[1:]):
+            n_pad = pad if hi == n else 0
+            ids = jnp.concatenate([jnp.full((2, n_pad), 7, tokens.dtype), tokens[:, lo:hi]], axis=1)
+            mask = jnp.concatenate([jnp.zeros((2, n_pad), jnp.int32), jnp.ones((2, hi - lo), jnp.int32)], axis=1)
+            pos = jnp.concatenate([jnp.zeros((2, n_pad), jnp.int32), _positions(lo, hi)], axis=1)
+            out, cache = forward(params, ids, cfg, cache=cache, positions=pos, attention_mask=mask)
+            outs.append(out[:, n_pad:])
+            jax.effects_barrier()
+            reach = hi + n_pad
+            lanes = mla.view_lanes(lo, reach - lo, 0, block_size, nbps) if stepped else nbps * block_size
+            assert read == [lanes] * 5 and lanes == (min(-(-reach // STEP) * STEP, 152) if stepped else 152), (lo, hi)
+            read.clear()
+        assert 4 * STEP < reach == n + pad and T + pad == nbps * block_size  # the last chunk: the branch cut at the table
+        for t in range(n, T):  # a token step: the table, whatever the cursor
+            out, cache = forward(params, tokens[:, t:t + 1], cfg, cache=cache, positions=_positions(t, t + 1))
+            outs.append(out)
+            jax.effects_barrier()
+            assert read == [nbps * block_size] * 5
+            read.clear()
+        assert int(cache["len"][0]) == T + pad
+        return jnp.concatenate(outs, axis=1)
+
+    got = serve(True)
+    np.testing.assert_allclose(got, want, atol=TOL)
+    # the rule turned off (as the step was before its view followed its reach): the same tokens
+    monkeypatch.setattr(mla, "view_steps", lambda *a: ())
+    wide = serve(False)
+    np.testing.assert_allclose(got, wide, atol=TOL)
+    np.testing.assert_array_equal(jnp.argmax(got, -1), jnp.argmax(wide, -1))
+
+
 @pytest.mark.parametrize("name,change,least", [
     ("the temperature on the scores", dict(rope_mscale_all_dim=0.0), 1e-3),
     ("yarn at all", dict(rope_scaling_type=None), 1e-3),
@@ -242,16 +315,30 @@ ENGINE = dict(slots=3, decode_chunk=4, kv_block_size=8, kv_blocks=160, max_seq_l
 GAP = 1e-4  # a float32 engine against the float32 reference: a served token is the reference's first
 
 
-@pytest.fixture(scope="module")
-def engines():
-    """(an engine with a prefix cache over copy-on-write blocks, one without)."""
+@pytest.fixture(scope="module", params=[0, STEP], ids=["table", f"step{STEP}"])
+def engines(request):
+    """(an engine with a prefix cache over copy-on-write blocks, one without),
+    once as the presets' tables give them (512 lanes are within one step of a
+    chunk's view: every chunk views its table) and once with the step set to
+    32 lanes: every chunk then views as far as its slot's lane cursor reaches.
+    The step stays set while the pair lives (an engine traces its programs
+    again after ``jax.clear_caches``), and the pair has a memo of programs of
+    its own."""
+    import collections
+
+    from datatunerx_tpu.serving import batched_engine
     from datatunerx_tpu.serving.batched_engine import BatchedEngine
 
-    cow = BatchedEngine("preset:debug-kimi", kv_overcommit="on", prefix_cache=6, **ENGINE)
-    cold = BatchedEngine("preset:debug-kimi", **ENGINE)
-    yield cow, cold
-    cow.close()
-    cold.close()
+    with pytest.MonkeyPatch.context() as patch:
+        if request.param:
+            patch.setattr(mla, "VIEW_STEP_LANES", request.param)
+            patch.setattr(batched_engine, "_PROGRAM_MEMO", collections.OrderedDict())
+        cow = BatchedEngine("preset:debug-kimi", kv_overcommit="on", prefix_cache=6, **ENGINE)
+        cold = BatchedEngine("preset:debug-kimi", **ENGINE)
+        assert cow.prefill_view_step() == cold.engine_line["prefill_view_step"] == request.param
+        yield cow, cold
+        cow.close()
+        cold.close()
 
 
 def _serve(engine, prompt, n=12):
@@ -276,6 +363,11 @@ def _idle(engine):
     time.sleep(0.1)
 
 
+def _up(n):
+    """``n`` tokens in whole buckets of 64 lanes."""
+    return -(-n // 64) * 64
+
+
 def _admits(engine, since=0):
     return [e[3] for e in list(engine.sched_trace)[since:] if e[0] == "admit"]
 
@@ -287,7 +379,7 @@ def session(engines):
     cow, cold = engines
     rng = np.random.default_rng(0)
     history, turns = rng.integers(10, 500, size=100).tolist(), []
-    before, since = cow.prefix_stats, len(cow.sched_trace)
+    before, since, lanes = cow.prefix_stats, len(cow.sched_trace), dict(cow.dsa_stats)
     for tool in (0, 37, 70):
         history = history + rng.integers(10, 500, size=tool).tolist()
         a, b = _serve(cow, history), _serve(cold, history)
@@ -295,13 +387,14 @@ def session(engines):
         history = history + list(a.tokens)
     _idle(cow)
     # tests of one module share the engines in whatever order a worker runs them: deltas
-    turns.append(({k: v - before[k] for k, v in cow.prefix_stats.items()}, _admits(cow, since)))
+    turns.append(({k: v - before[k] for k, v in cow.prefix_stats.items()}, _admits(cow, since),
+                  {k: cow.dsa_stats[k] - lanes[k] for k in ("prefill_view_lanes", "prefill_table_lanes")}))
     return turns
 
 
 def test_later_turns_extend_their_history_through_shared_blocks(engines, session):
     cow, _ = engines
-    got, admits = session[3]
+    got, admits, lanes = session[3]
     assert admits == ["chunked", "cow_extend", "cow_extend"]
     assert cow.decode_paths == {"mla": "gather"} and cow.cow
     # turn 2 shares turn 1's 100 tokens and prefills the answer and 37 more; turn 3 shares turn
@@ -315,6 +408,16 @@ def test_later_turns_extend_their_history_through_shared_blocks(engines, session
         # masked by position, the served tokens are the cold path's and the reference's first
         assert a.tokens == b.tokens and len(a.tokens) > 0
         assert _gaps(cow, prompt, a).max() < GAP, len(prompt)
+    # what the chunks viewed, counted as the program sizes it: by the slot's LANE cursor (a suffix
+    # starts at its shared base, a bucket's pads before it), in whole steps; a cursor on a block's
+    # edge and on a step's (64, 128, 192); nothing where every chunk views its table
+    step = cow.prefill_view_step()
+    cursors = [c for base, n in ((0, n1), (_up(n1), n2 - n1), (_up(n1) + _up(n2 - n1), n3 - n2))
+               for c in range(base, base + _up(n), 64)]
+    assert lanes == ({"prefill_view_lanes": sum(-(-(c + 64) // step) * step for c in cursors),
+                      "prefill_table_lanes": 512 * len(cursors)} if step else
+                     {"prefill_view_lanes": 0, "prefill_table_lanes": 0})
+    assert not step or cursors[:3] == [0, 64, 128] and lanes["prefill_view_lanes"] < lanes["prefill_table_lanes"]
 
 
 def test_logits_through_a_hit_equal_the_cold_paths(engines, session):
@@ -334,9 +437,8 @@ def test_logits_through_a_hit_equal_the_cold_paths(engines, session):
         assert _admits(fresh) == ["chunked"]
         # cold: the prompt in whole buckets, its pads at the left; through the extensions a
         # bucket's pads before each suffix too
-        up = lambda n: -(-n // 64) * 64  # noqa: E731
         n1, n2, n3 = (len(p) for p, _, _ in session[:3])
-        assert direct["cursor"] == up(n3) < through["cursor"] == up(n1) + up(n2 - n1) + up(n3 - n2)
+        assert direct["cursor"] == _up(n3) < through["cursor"] == _up(n1) + _up(n2 - n1) + _up(n3 - n2)
         np.testing.assert_allclose(np.asarray(through["logits"]), np.asarray(direct["logits"]), atol=TOL)
     finally:
         fresh.close()
@@ -404,6 +506,9 @@ def test_a_cold_admission_reclaims_the_blocks_idle_entries_hold(preset, kw):
         _serve(eng, rng.integers(10, 500, size=150).tolist(), 8)
         _idle(eng)
         assert eng._pool.free == 8  # the entry holds 12 of 20 blocks, no slot maps them
+        # a table within one step of a chunk's view (or a model with no latent kind): no stepped view, nothing counted
+        assert eng.engine_line["prefill_view_step"] == 0 and eng._dsa_chunk_marks(0, 64) == {}
+        assert eng.dsa_stats["prefill_table_lanes"] == 0
         req = eng.submit(rng.integers(10, 500, size=90).tolist(), max_new_tokens=8)
         assert req.done.wait(120) and req.error is None, "the head waited for blocks idle entries held"
         got = eng.prefix_stats
@@ -453,3 +558,44 @@ def test_spans_counters_and_metrics_name_the_prefix_cache(engines, session):
     assert f"dtx_serving_prefix_shared_tokens_total {got['shared_tokens']}" in text
     assert f"dtx_serving_prefix_prefilled_tokens_total {got['prefilled_tokens']}" in text
     assert "dtx_serving_prefix_blocks_reclaimed_total 0" in text
+
+
+def test_the_engine_line_spans_and_metrics_name_a_chunks_view(engines):
+    """What says how often the stepped view engages: the engine's line, the
+    chunk span's keywords, the engine's sums and ``/metrics``, counted by the
+    program's own rule; all silent where every chunk views its table. And the
+    program itself: one switch a run of like layers, over the table's steps."""
+    from datatunerx_tpu.obs.metrics import Registry, export_moe_stats
+
+    _, cold = engines
+    step = cold.prefill_view_step()
+    assert cold.engine_line["index_topk"] == 0 and cold.index_pool_bytes() == 0 and cold._dsa_marks() == {}
+    before, spans, real_phase = dict(cold.dsa_stats), [], cold._phase
+    cold._phase = lambda name, **detail: (spans.append((name, detail)), real_phase(name, **detail))[1]
+    try:
+        _serve(cold, list(range(30, 160)), 2)  # 130 tokens in 192 lanes: chunks of 64 at lanes 0, 64, 128
+    finally:
+        cold._phase = real_phase
+    _idle(cold)
+    chunks = [d for name, d in spans if name == "dtx_engine_prefill_chunk"]
+    reg = Registry()
+    export_moe_stats(reg, cold)
+    text = reg.expose()
+    lowered = cold._prefill_chunk_fn.lower(
+        cold.params, cold._lora_arg(), cold._cache, jnp.asarray(0, jnp.int32), *(jnp.zeros((1, 64), jnp.int32),) * 3,
+        jnp.asarray(0, jnp.int32), chunk_len=64).as_text()
+    if step:
+        assert [(d["tokens"], d["view"], d["table"]) for d in chunks] == [(64, 64, 512), (64, 128, 512), (64, 192, 512)]
+        assert all(d["view"] == mla.view_lanes(64 * i, 64, 0, 8, 64) for i, d in enumerate(chunks))
+        assert cold.dsa_stats["prefill_view_lanes"] - before["prefill_view_lanes"] == 64 + 128 + 192
+        assert cold.dsa_stats["prefill_table_lanes"] - before["prefill_table_lanes"] == 3 * 512
+        for name in ("view", "table"):
+            assert f"# TYPE dtx_serving_dsa_prefill_{name}_lanes_total counter" in text
+            assert f"dtx_serving_dsa_prefill_{name}_lanes_total {cold.dsa_stats[f'prefill_{name}_lanes']}\n" in text
+        assert lowered.count("stablehlo.case") == 2  # a dense run and a run with experts, sixteen widths each
+    else:
+        assert len(chunks) == 3 and all(d.keys() == {"tokens", "slot"} for d in chunks)
+        assert cold.dsa_stats == before and cold._dsa_chunk_marks(0, 64) == {}
+        assert "\ndtx_serving_dsa_prefill_view_lanes_total " not in text
+        assert "stablehlo.case" not in lowered
+    assert "dtx_serving_dsa_steps{" not in text and "\ndtx_serving_index_pool_bytes " not in text  # nothing selects

@@ -312,14 +312,23 @@ def test_dense_cache_prefill_then_decode_equals_reference(model, want):
     np.testing.assert_allclose(jnp.concatenate(outs, axis=1), want, atol=TOL)
 
 
-@pytest.mark.parametrize("block_size,chunks", [
-    (8, ((0, 64), (64, 130))),          # a sub-chunk whole, then 66 rows
-    (16, ((0, 130),)),                  # three sub-chunks in one program
-    (4, ((0, 3), (3, 70), (70, 130))),  # a chunk shorter than the convolution
+@pytest.mark.parametrize("block_size,chunks,view_step", [
+    (8, ((0, 64), (64, 130)), 0),          # a sub-chunk whole, then 66 rows
+    (16, ((0, 130),), 0),                  # three sub-chunks in one program
+    (4, ((0, 3), (3, 70), (70, 130)), 0),  # a chunk shorter than the convolution
+    # the one latent layer's chunk views the lanes its context reaches (ops/mla.py:view_steps; cell 5's
+    # table of 1,536 lanes is a step and a half of 1,024): here steps of 32 and of 128 lanes under 192
+    (8, ((0, 64), (64, 130)), 32), (16, ((0, 130),), 32), (4, ((0, 3), (3, 70), (70, 130)), 128),
 ])
-def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, block_size, chunks):
+def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, monkeypatch, block_size, chunks,
+                                                                 view_step):
     cfg, _, params, tokens = model
     nbps = 192 // block_size
+    step = _step
+    if view_step:  # a program of its own: the module's is traced under the module's step
+        monkeypatch.setattr(mla, "VIEW_STEP_LANES", view_step)
+        assert len(mla.view_steps(64, nbps, block_size, 0)) == -(-192 // view_step)
+        step = jax.jit(_step.__wrapped__)
     cache = init_paged_cache(cfg, 2, 2 * nbps + 3, block_size, nbps, dtype=jnp.float32)
     cache["block_tables"] = jnp.asarray(
         np.stack([np.arange(nbps) + nbps, np.arange(nbps)]), jnp.int32)
@@ -328,10 +337,10 @@ def test_paged_pool_chunked_prefill_then_decode_equals_reference(model, want, bl
     cache["state_kda_conv"] = cache["state_kda_conv"] - 2.0
     outs = []
     for lo, hi in chunks:
-        out, cache = _step(params, tokens[:, lo:hi], cache, _positions(lo, hi))
+        out, cache = step(params, tokens[:, lo:hi], cache, _positions(lo, hi))
         outs.append(out)
     for t in range(130, T):
-        out, cache = _step(params, tokens[:, t:t + 1], cache, _positions(t, t + 1))
+        out, cache = step(params, tokens[:, t:t + 1], cache, _positions(t, t + 1))
         outs.append(out)
     np.testing.assert_allclose(jnp.concatenate(outs, axis=1), want, atol=TOL)
 

@@ -439,6 +439,10 @@ def test_gateway_metrics_has_build_info_uptime_and_queue_wait():
 
 
 def test_serving_metrics_histograms_from_shared_registry(serving_http_url):
+    # the requests counter exists from the first request the process's registry
+    # records: this test may be the first on its worker to send one
+    with urllib.request.urlopen(serving_http_url + "/healthz", timeout=10) as r:
+        assert r.status == 200
     with urllib.request.urlopen(serving_http_url + "/metrics",
                                 timeout=10) as r:
         samples, types = parse_exposition(r.read().decode())
