@@ -420,6 +420,15 @@ def paged_install_table(cache: Dict, slot, table_row: jnp.ndarray) -> Dict:
     return out
 
 
+def paged_clear_rows(block_tables: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
+    """The table with the rows of ``slots`` cleared to ``-1``: what a
+    scheduler pass gives up, in ONE program whatever the count. ``slots`` is
+    ``int32[number of slots]``, padded with an index past the table, which is
+    dropped. A function of the table alone (one array in, one out, donated):
+    eagerly, ``.at[slot].set(-1)`` is a handful of small programs a slot."""
+    return block_tables.at[slots].set(-1, mode="drop")
+
+
 def paged_copy_block(cache: Dict, src, dst, keep) -> Dict:
     """Copy one physical block (K/V pools, int8 scales, pos row) onto
     another — the copy-on-write primitive. Position lanes at offset >=

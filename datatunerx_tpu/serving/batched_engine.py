@@ -2218,7 +2218,7 @@ class BatchedEngine:
                     # rather than go on with a pool it no longer has.
                     if self._cache_consumed():
                         raise
-                    self._release_slot(slot)
+                    self._release_slots([slot])
                     self._complete(req, error=str(e))
                     break
                 st["done"] += c
@@ -3010,26 +3010,32 @@ class BatchedEngine:
         return {"imported": True, "cursor": cursor,
                 "fingerprint": payload.get("fingerprint")}
 
-    def _release_slot(self, slot: int, note_session: bool = True):
+    def _release_slots(self, slots: Sequence[int], note_session: bool = True):
+        """Give up ``slots``, all that one pass of the scheduler ends (one,
+        from every caller but emission): each slot's host state, then the
+        pool's ONE program that clears their table rows, all under one
+        ``dtx_engine_release`` span."""
         paged = self._pool is not None
-        with self._phase("dtx_engine_release",
-                         blocks=len(self._pool.held(slot)) if paged else 0):
-            self._slot_req[slot] = None
-            self._pending.pop(slot, None)
-            self._decode_ready[slot] = False
-            if self.spec is not None:
-                self._spec_form[slot] = False
-                self._spec_primed[slot] = False
-                self.spec_ctrl.reset_slot(slot)
-                # prune-on-release, like the slot acceptance EMAs: per-slot
-                # tree-path series never outlive the tenant that produced
-                # them
-                self._spec_tree_slot_path.pop(slot, None)
-            name, self._slot_adapter[slot] = self._slot_adapter[slot], None
-            if name is not None and self.adapter_registry is not None:
-                self.adapter_registry.release(name)
+        with self._phase("dtx_engine_release", slots=len(slots),
+                         blocks=sum(len(self._pool.held(slot))
+                                    for slot in slots) if paged else 0):
+            for slot in slots:
+                self._slot_req[slot] = None
+                self._pending.pop(slot, None)
+                self._decode_ready[slot] = False
+                if self.spec is not None:
+                    self._spec_form[slot] = False
+                    self._spec_primed[slot] = False
+                    self.spec_ctrl.reset_slot(slot)
+                    # prune-on-release, like the slot acceptance EMAs:
+                    # per-slot tree-path series never outlive the tenant
+                    # that produced them
+                    self._spec_tree_slot_path.pop(slot, None)
+                name, self._slot_adapter[slot] = self._slot_adapter[slot], None
+                if name is not None and self.adapter_registry is not None:
+                    self.adapter_registry.release(name)
             if paged:
-                self._pool.release(slot, note_session)
+                self._pool.release(slots, note_session)
 
     def _vacate_slot(self, slot: int, note_session: bool = True):
         """Release a slot that is still ACTIVE on device (an export, a
@@ -3037,7 +3043,7 @@ class BatchedEngine:
         deactivation) and clear its mask and token budget NOW: an interleaved
         decode chunk would otherwise keep sampling it and write a stale token
         through the NEXT tenant's table while that tenant still prefills."""
-        self._release_slot(slot, note_session)
+        self._release_slots([slot], note_session)
         self._active = self._active.at[slot].set(False)
         self._remaining = self._remaining.at[slot].set(0)
 
@@ -3165,7 +3171,7 @@ class BatchedEngine:
         outcome that repays work, reachable only when nothing younger is
         decoding."""
         req = self._pending[slot]["req"]
-        self._release_slot(slot, note_session=False)
+        self._release_slots([slot], note_session=False)
         self._waiting_front = collections.deque(
             sorted([*self._waiting_front, req], key=lambda r: r.seq))
         self._count_preempt("requeued_prefill")
@@ -3545,8 +3551,8 @@ class BatchedEngine:
 
     def _stop_serving(self, error: Exception):
         """The pool went with a failed program: every live session's KV is
-        lost and the slot handlers themselves (``_release_slot`` clears a
-        row of the block table) raise on the deleted leaves. Fail what is in
+        lost and the slot handlers themselves (``_release_slots`` clears
+        rows of the block table) raise on the deleted leaves. Fail what is in
         flight or waiting and stop the scheduler: ``submit`` refuses from
         here on, as it does after ``close``."""
         msg = f"engine stopped, KV cache lost to a failed program: {error}"
@@ -3621,7 +3627,7 @@ class BatchedEngine:
                 raise  # the pool went with the program: _stop_serving
             for slot, req in enumerate(self._slot_req):
                 if req is not None:
-                    self._release_slot(slot)
+                    self._release_slots([slot])
                     self._complete(req, error=str(e))
             return
 
@@ -3638,13 +3644,15 @@ class BatchedEngine:
                         req = self._slot_req[slot]
                         if t >= 0 and req is not None:
                             req.push(t)
-            for slot in range(self.slots):
-                req = self._slot_req[slot]
-                # pending-prefill slots are inactive by design — only slots
-                # that entered this decode chunk can finish here
-                if (req is not None and self._decode_ready[slot]
-                        and not bool(active_np[slot])):
-                    self._release_slot(slot)
+            # pending-prefill slots are inactive by design — only slots
+            # that entered this decode chunk can finish here
+            ended = [(slot, req) for slot, req in enumerate(self._slot_req)
+                     if req is not None and self._decode_ready[slot]
+                     and not bool(active_np[slot])]
+            if ended:
+                # the slots of the chunk are given up together: one program
+                self._release_slots([slot for slot, _ in ended])
+                for slot, req in ended:
                     self._event("finish", slot, req=req, slot=slot)
                     self._complete(req)
 

@@ -4,12 +4,15 @@
 how a slot comes to hold blocks and gives them back: the refcounted
 ``BlockAllocator`` (ops/paged_attention.py), the reserve math (spec overshoot,
 ``max_seq_len`` cap, the overcommit rule), each slot's blocks and eager
-demand, the table-row format, and the three EAGER device writes the host makes
-to ``cache["block_tables"]`` and ``cache["pos"]`` (scrub, set a slot's row,
-clear it). Policy stays with the scheduler: who is admitted or preempted, when
-growth runs, what a migration payload holds. The pool writes the engine's
-cache dict in place, reached through the getter it was built with; the jitted
-programs keep taking and returning ``engine._cache`` whole.
+demand, the table-row format, and the device writes the host makes to
+``cache["block_tables"]`` and ``cache["pos"]``: two EAGER ones (scrub, set a
+slot's row) and ONE jitted, donated program a pass that clears the rows of
+every slot the pass gives up (``release``; an eager clear was 2.8 ms of an
+idle chip a finished request). Policy stays with the scheduler: who is
+admitted or preempted, when growth runs, what a migration payload holds. The
+pool writes the engine's cache dict in place, reached through the getter it
+was built with; the jitted programs keep taking and returning
+``engine._cache`` whole.
 
 The contract, which the methods' order of operations enforces (two slots that
 scatter into one physical block corrupt both sessions silently):
@@ -18,8 +21,8 @@ scatter into one physical block corrupt both sessions silently):
   them to attention (``grow``; ``scrub`` ahead of an install whose program
   does not scrub the blocks itself);
 - a slot's row is cleared BEFORE its blocks return to the allocator
-  (``release``): a masked decode write from the slot must never land in a
-  block already re-issued;
+  (``release``, for every slot of its list): a masked decode write from the
+  slot must never land in a block already re-issued;
 - a failed install returns what it took and leaves the slot's lists as they
   were (``occupy``: own blocks freed, shared ones decref'd);
 - a shared block is incref'd exactly once an owner (``take``), and every owner
@@ -32,6 +35,7 @@ import collections
 import contextlib
 from typing import Callable, Dict, Iterator, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -39,7 +43,12 @@ from datatunerx_tpu.ops.paged_attention import (
     POS_SENTINEL,
     BlockAllocator,
     blocks_for_depth,
+    paged_clear_rows,
 )
+
+# the table is consumed and written in place, as the engine's programs do
+# with the cache they return
+_clear_rows = jax.jit(paged_clear_rows, donate_argnums=(0,))
 
 
 class KVPool:
@@ -179,7 +188,7 @@ class KVPool:
         cache["pos"] = cache["pos"].at[jnp.asarray(ids)].set(POS_SENTINEL)
 
     def set_row(self, slot: int, row):
-        """One eager write of ``slot``'s whole table row (an array, or -1)."""
+        """One eager write of ``slot``'s whole table row."""
         cache = self._cache()
         cache["block_tables"] = cache["block_tables"].at[slot].set(row)
 
@@ -200,18 +209,32 @@ class KVPool:
         self.set_row(slot, self.row(held))
         return need
 
-    def release(self, slot: int, note_session: bool = True):
-        """Give ``slot``'s blocks back: clear its row FIRST, then free.
-        ``note_session`` records the count as a finished session's footprint
-        (preemptions pass False: the session isn't over)."""
-        self._demand[slot] = 0
-        blocks, self._held[slot] = self._held[slot], []
-        if not blocks:
+    def release(self, slots: Sequence[int], note_session: bool = True):
+        """Give the blocks of every slot of ``slots`` (what one scheduler
+        pass gives up) back: clear their rows FIRST, in one program for all
+        of them, then free. A slot that holds nothing is skipped, and a list
+        of such slots writes nothing. ``note_session`` records each count as
+        a finished session's footprint (preemptions pass False: the session
+        isn't over)."""
+        given = []
+        for slot in slots:
+            self._demand[slot] = 0
+            blocks, self._held[slot] = self._held[slot], []
+            if not blocks:
+                continue
+            if note_session:
+                self.session_blocks.append(len(blocks))
+            given.append((slot, blocks))
+        if not given:
             return
-        if note_session:
-            self.session_blocks.append(len(blocks))
-        self.set_row(slot, -1)
-        self.allocator.free(blocks)
+        n = len(self._held)
+        rows = np.full((n,), n, np.int32)  # past the table: dropped
+        rows[: len(given)] = [slot for slot, _ in given]
+        cache = self._cache()
+        cache["block_tables"] = _clear_rows(cache["block_tables"], rows)
+        # slot by slot: two of them may share a block, one reference each
+        for _, blocks in given:
+            self.allocator.free(blocks)
 
     @contextlib.contextmanager
     def mounted(self, slot: int,

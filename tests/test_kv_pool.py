@@ -15,14 +15,14 @@ from datatunerx_tpu.serving.kv_pool import KVPool
 SLOTS, BS, MAX_LEN, BLOCKS = 3, 4, 32, 12  # 8 table columns a slot
 
 
-def make(overshoot=0, advance=None, kv_blocks=BLOCKS):
+def make(overshoot=0, advance=None, kv_blocks=BLOCKS, slots=SLOTS):
     """A pool over a toy cache whose every position is 7 (a recycled block's
     stale content) and whose leaf ``k`` nothing here may touch."""
     holder = {}
-    pool = KVPool(SLOTS, MAX_LEN, BS, kv_blocks, overshoot=overshoot,
+    pool = KVPool(slots, MAX_LEN, BS, kv_blocks, overshoot=overshoot,
                   advance=advance, cache=lambda: holder["cache"])
     holder["cache"] = {
-        "block_tables": jnp.full((SLOTS, pool.blocks_per_slot), -1, jnp.int32),
+        "block_tables": jnp.full((slots, pool.blocks_per_slot), -1, jnp.int32),
         "pos": jnp.full((pool.total, BS), 7, jnp.int32),
         "k": jnp.arange(pool.total * BS, dtype=jnp.float32),
     }
@@ -111,9 +111,9 @@ def test_demand_and_ratio_after_admit_growth_and_release():
     assert pool.grow(0, 4 + 9) == 2
     assert pool.overcommit_ratio == round(16 / 12, 4)  # growth is not demand
     assert pool.free == 5
-    pool.release(0)
+    pool.release([0])
     assert pool.overcommit_ratio == round(8 / 12, 4)
-    pool.release(1, note_session=False)
+    pool.release([1], note_session=False)
     assert pool.overcommit_ratio == 0.0 and pool.free == pool.total
     assert list(pool.session_blocks) == [4]  # a preemption is no session's end
 
@@ -150,22 +150,48 @@ def test_grow_refused_takes_nothing_and_writes_nothing():
     assert pool.held(0) == held and pool.free == 1 and writes.log == []
 
 
-def test_release_clears_the_row_and_only_then_frees():
-    pool, holder = make()
-    blocks = pool.reserve(0, 10)
-    with pool.occupy(2, blocks, 10) as row:
-        pool.set_row(2, row)
-    assert table(holder, 2)[:3] == blocks
+@pytest.mark.parametrize("note_session", [True, False], ids=["end", "preempt"])
+@pytest.mark.parametrize("given", [[2], [3, 0, 1], [0, 1, 2, 3]],
+                         ids=["1", "3", "all"])
+def test_release_clears_the_row_and_only_then_frees(given, note_session):
+    pool, holder = make(slots=4, kv_blocks=16)
+    sizes = {0: 10, 1: 3, 2: 12, 3: 5}  # 3 + 1 + 3 + 2 blocks; slot 3 shares
+    for slot in (0, 1, 2):
+        with pool.occupy(slot, pool.reserve(0, sizes[slot]), sizes[slot]) as row:
+            pool.set_row(slot, row)
+    with pool.occupy(3, pool.reserve(4, 1, shared=pool.held(0)[:1]), 5) as row:
+        pool.set_row(3, row)
+    before = {slot: table(holder, slot) for slot in range(4)}
+    held = {slot: list(pool.held(slot)) for slot in range(4)}
+    out = 16 - pool.free
+    assert out == 3 + 1 + 3 + 1 and held[3][0] == held[0][0]
+    table_before = holder["cache"]["block_tables"]
     writes = Writes(pool, holder)
-    pool.release(2)
-    # ONE eager write, made while the blocks were still out
-    assert writes.log == [("block_tables", BLOCKS - 3)]
-    assert table(holder, 2) == [-1] * 8
-    assert pool.free == BLOCKS and pool.held(2) == []
-    assert list(pool.session_blocks) == [3]
-    pool.release(2)  # an empty slot: nothing to write, nothing to free
-    assert writes.log == [("block_tables", BLOCKS - 3)]
-    assert np.asarray(holder["cache"]["k"]).tolist() == list(range(48))
+    pool.release(given, note_session)
+    # ONE write for the whole list, made while every block was still out
+    assert writes.log == [("block_tables", 16 - out)]
+    assert table_before.is_deleted()  # the program consumed the table it was given
+    for slot in range(4):
+        assert table(holder, slot) == ([-1] * 8 if slot in given else before[slot])
+        assert pool.held(slot) == ([] if slot in given else held[slot])
+    # a block two slots share goes home with the second of them
+    kept = {b for slot in range(4) if slot not in given for b in held[slot]}
+    assert pool.free == 16 - len(kept)
+    assert list(pool.session_blocks) == (
+        [len(held[slot]) for slot in given] if note_session else [])
+    # an empty slot in a list is skipped; a list of only empty slots, and an
+    # empty list, write nothing and free nothing
+    rest = [slot for slot in range(4) if slot not in given]
+    if rest:
+        pool.release([given[0], rest[0]], note_session)
+        assert len(writes.log) == 2 and table(holder, rest[0]) == [-1] * 8
+        assert len(pool.session_blocks) == (len(given) + 1 if note_session else 0)
+    done, free = len(writes.log), pool.free
+    pool.release(given)
+    pool.release([])
+    assert (len(writes.log), pool.free) == (done, free)
+    assert len(pool.session_blocks) == (len(given) + len(rest[:1]) if note_session else 0)
+    assert np.asarray(holder["cache"]["k"]).tolist() == list(range(64))
 
 
 @pytest.mark.parametrize("shared", [False, True], ids=["own", "own+shared"])
@@ -201,9 +227,9 @@ def test_a_shared_block_survives_its_first_owner_and_returns_on_the_last():
         pass
     assert [pool.allocator.refcount(b) for b in donor[:2]] == [3, 3]
     assert pool.free == BLOCKS - 5
-    pool.release(0)
+    pool.release([0])
     assert pool.free == BLOCKS - 4  # the donor's own third block went home
-    pool.release(1)
+    pool.release([1])
     assert [pool.allocator.refcount(b) for b in donor[:2]] == [1, 1]
     assert pool.free == BLOCKS - 3  # the entry's blocks are still out
     pool.free_entry(entry)

@@ -5,6 +5,7 @@ greedy and fixed-seed sampled decode, across base and LoRA-adapter requests
 and through every prefix-cache path — while the allocator's free list and
 the scheduler's prefill-token budget deliver the HBM and latency wins."""
 
+import threading
 import time
 
 import numpy as np
@@ -19,6 +20,7 @@ from datatunerx_tpu.ops.paged_attention import (
     BlockAllocatorError,
     POS_SENTINEL,
     init_paged_cache,
+    paged_clear_rows,
     paged_install_table,
 )
 from datatunerx_tpu.serving.batched_engine import BatchedEngine
@@ -129,6 +131,27 @@ def test_install_table_is_the_three_eager_updates_in_one_program(blocks):
     np.testing.assert_array_equal(out["block_tables"], want_tables)
     np.testing.assert_array_equal(out["len"], [9, 0, 7])
     assert int(out["pos"][11, 0]) == 44 and set(out) == set(cache)
+
+
+_CLEAR = jax.jit(paged_clear_rows)
+
+
+@pytest.mark.parametrize("slots", [[1], [2, 0, 3], [0, 1, 2, 3]],
+                         ids=["1", "3", "all"])
+def test_clear_rows_is_the_eager_clears_in_one_program(slots):
+    """A pass gives its slots' rows up through ONE jitted program whatever
+    their number: what an eager ``.at[slot].set(-1)`` a slot gave (a handful
+    of small programs each); a padded index past the table writes nothing,
+    and the three list lengths are one compilation."""
+    tables = jnp.arange(4 * 8, dtype=jnp.int32).reshape(4, 8)
+    want = tables
+    for slot in slots:
+        want = want.at[slot].set(-1)
+    rows = np.full((4,), 4, np.int32)
+    rows[: len(slots)] = slots
+    np.testing.assert_array_equal(_CLEAR(tables, rows), want)
+    np.testing.assert_array_equal(_CLEAR(tables, np.full((4,), 4, np.int32)), tables)
+    assert _CLEAR._cache_size() == 1
 
 
 # ------------------------------------------------------- model primitive
@@ -719,6 +742,38 @@ def test_block_exhaustion_queues_drains_and_short_requests_reserve_few():
         assert 0 < peak_reserved <= 8, peak_reserved
     finally:
         eng.close()
+
+
+def test_slots_given_up_together_and_re_issued_blocks_keep_every_token(paged):
+    """Twelve clients over four slots and a pool that holds four requests and
+    no more: requests of one budget end in one decode chunk and are given up
+    by ONE program, their blocks go straight to the next admissions while
+    other slots still decode, and every request's tokens are those of the
+    same request served alone."""
+    eng = BatchedEngine(MODEL, template="vanilla", max_seq_len=256,
+                        slots=4, decode_chunk=4, kv_block_size=16,
+                        kv_blocks=20)
+    try:
+        tok = eng.tokenizer
+        asks = [(tok.encode(f"client {i} asks about block number {i * 7}"),
+                 8 if i % 4 < 2 else 12) for i in range(12)]
+        # the first pass sees all twelve: four are admitted together
+        all_in, tick = threading.Event(), eng._tick
+        eng._tick = lambda: all_in.wait(600) and tick()
+        reqs = [eng.submit(p, max_new_tokens=n) for p, n in asks]
+        all_in.set()
+        for r in reqs:
+            assert r.done.wait(300) and r.error is None, r.error
+    finally:
+        eng.close()
+    for r, (p, n) in zip(reqs, asks):
+        assert r.tokens == paged.generate(p, max_new_tokens=n), (p, n)
+    assert eng.free_kv_blocks == eng.total_kv_blocks == 20
+    # 12 requests of 5 blocks (64 + 8 or 12 tokens) through 20: blocks were
+    # re-issued; and fewer programs than finishes: slots went together
+    passes = eng.sched_stats["dtx_engine_release"][1]
+    assert list(eng._pool.session_blocks) == [5] * 12
+    assert passes < eng.sched_stats["dtx_engine_complete"][1] == 12
 
 
 # ------------------------------------------------------- scheduler bound

@@ -49,11 +49,35 @@ def _admit(req):
     return next(d for _, e, d in req.timeline if e == "admit")
 
 
+def _releases(eng):
+    """The ``slots=`` of every ``dtx_engine_release`` span ``eng`` opens from
+    here on (the always-on table keeps a span's seconds and count only)."""
+    seen, phase = [], eng._phase
+
+    def recording(name, **detail):
+        if name == P + "release":
+            seen.append(detail["slots"])
+        return phase(name, **detail)
+
+    eng._phase = recording
+    return seen
+
+
+def _hold_ticks_until(eng):
+    """The scheduler's next pass waits for the event this returns: a
+    submitting thread that stalls beside busy workers would else let the
+    first requests run on before the last arrives."""
+    all_in, tick = threading.Event(), eng._tick
+    eng._tick = lambda: all_in.wait(600) and tick()
+    return all_in
+
+
 @pytest.fixture(scope="module")
 def served():
     """An engine that served five requests over two slots and was closed:
     its table and its timelines are at rest."""
     eng = _engine()
+    eng.released = _releases(eng)
     tok = eng.tokenizer
     reqs = [eng.submit(tok.encode(f"phase probe number {i} " * 3),
                        max_new_tokens=10, trace_id=f"phase-{i}")
@@ -93,11 +117,45 @@ def test_the_phases_of_a_pass_fit_inside_it_and_a_child_inside_its_parent(served
             <= st["emit"][0])
 
 
+def _one_release_a_pass_and_one_complete_a_request(eng, reqs, released):
+    """One ``dtx_engine_complete`` and one ``finish`` a finished request; one
+    ``dtx_engine_release`` a pass that ended requests, their ``slots=``
+    summing to the finishes."""
+    st = _stats(eng)
+    assert st["complete"][1] == len(reqs)
+    finishes = [d["tick"] for r in reqs for _, e, d in r.timeline if e == "finish"]
+    assert sum(1 for e in eng.sched_trace if e[0] == "finish") == len(finishes) == len(reqs)
+    # the passes' spans, in order, each as wide as the requests its pass ended
+    assert released == [finishes.count(t) for t in sorted(set(finishes))]
+    assert st["release"][1] == len(released) and sum(released) == len(reqs)
+
+
 def test_one_release_and_one_complete_per_finished_request(served):
     eng, reqs = served
-    st = _stats(eng)
-    assert st["release"][1] == st["complete"][1] == len(reqs)
-    assert sum(1 for e in eng.sched_trace if e[0] == "finish") == len(reqs)
+    _one_release_a_pass_and_one_complete_a_request(eng, reqs, eng.released)
+
+
+@pytest.mark.parametrize("max_new, want", [
+    ([8, 8, 8, 8], [4]), ([8] * 6, [4, 2]), ([8, 3, 8, 3], [2, 2])],
+    ids=["4-at-once", "4-then-2", "2-and-2"])
+def test_requests_that_end_in_one_chunk_are_released_by_one_span(max_new, want):
+    eng = _engine(slots=4)
+    released = _releases(eng)
+    try:
+        all_in = _hold_ticks_until(eng)
+        ids = eng.tokenizer.encode("ending together")
+        reqs = [eng.submit(ids, max_new_tokens=n, trace_id=f"together-{i}")
+                for i, n in enumerate(max_new)]
+        all_in.set()
+        for r in reqs:
+            assert r.done.wait(300) and r.error is None
+    finally:
+        eng.close()
+    _one_release_a_pass_and_one_complete_a_request(eng, reqs, released)
+    # four are admitted and prefilled in one pass and decode chunk for chunk:
+    # those of one budget end in one chunk of four tokens
+    assert released == want
+    assert eng.free_kv_blocks == eng.total_kv_blocks
 
 
 def test_every_mark_carries_its_tick_and_ticks_never_decrease(served):
@@ -197,11 +255,7 @@ def test_the_17th_of_17_on_16_slots_waited_for_a_slot():
     eng = _engine(slots=16, max_seq_len=128)
     try:
         ids = eng.tokenizer.encode("seventeen on sixteen")
-        # the scheduler's next pass waits for all seventeen: a submitting
-        # thread that stalls beside busy workers would else let the first
-        # sixteen finish before the last arrives
-        all_in, tick = threading.Event(), eng._tick
-        eng._tick = lambda: all_in.wait(600) and tick()
+        all_in = _hold_ticks_until(eng)  # the next pass sees all seventeen
         reqs = [eng.submit(ids, max_new_tokens=24) for _ in range(17)]
         all_in.set()
         for r in reqs:
@@ -215,7 +269,8 @@ def test_the_17th_of_17_on_16_slots_waited_for_a_slot():
     assert last["waited_ticks"] >= 6
     assert {_admit(r)["waited_for"] for r in reqs[:16]} <= {"tick"}
     st = _stats(eng)
-    assert st["release"][1] == st["complete"][1] == 17
+    # the sixteen end in one chunk and are given up by one span, the last alone
+    assert (st["release"][1], st["complete"][1]) == (2, 17)
 
 
 def test_a_pool_too_small_for_two_makes_the_second_wait_for_blocks():
@@ -282,6 +337,7 @@ def test_a_failed_decode_releases_and_completes_every_live_request():
 
 def test_preemption_releases_without_completing():
     eng = _engine(slots=4, kv_blocks=20, kv_overcommit="on")
+    released = _releases(eng)
     try:
         prompts = [eng.tokenizer.encode(f"victim ordering probe {i}") for i in range(4)]
         reqs = [eng.submit(p, max_new_tokens=64) for p in prompts]
@@ -294,7 +350,10 @@ def test_preemption_releases_without_completing():
     assert parked >= 1, "the pool never contended: the test proves nothing"
     st = _stats(eng)
     assert st["complete"][1] == len(reqs)
-    assert st["release"][1] == len(reqs) + parked
+    # every slot given up is in one span: a preemption's alone, those of the
+    # requests a chunk ended together
+    assert sum(released) == len(reqs) + parked
+    assert parked + 1 <= st["release"][1] == len(released) <= len(reqs) + parked
     marks = [(e, d) for r in reqs for _, e, d in r.timeline]
     assert all("tick" in d for _, d in marks)
     assert sum(1 for e, _ in marks if e == "preempt") == parked
